@@ -1,0 +1,832 @@
+#!/usr/bin/env python3
+"""Quickest proof that the served path still starts on the chip.
+
+    python chip_smoke.py              one TPU chip: kernels, server, CLI
+    python chip_smoke.py --chips 4    four chips: the pp=4 pipeline engine
+                                      against pp=1, and nothing else
+    python chip_smoke.py --rehearse   the same control flow at tiny widths
+                                      on whatever backend is there (Pallas
+                                      kernels in interpret mode)
+
+The model is the Llama-3.2-3B-class config of bench.py at full width and
+depth in bf16, with random weights made from ``--seed``: a bring-up
+vehicle, not a benchmark cell. Every time printed here is a smoke timing
+(cold compiles and a checkpoint write included), never a metric.
+
+One process per chip: this parent never imports JAX, nor anything under
+mlx_sharding_tpu (whose ``__init__`` does); every phase is a child process
+and the children run one after another. Without ``--rehearse`` each child
+gets ``JAX_PLATFORMS=tpu``, so a missing chip is an error in JAX itself and
+never a silent CPU run. A failed phase fails the run.
+
+The last line of stdout is one JSON object,
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``,
+the device as a child's JAX reports it; the exit code is 0 only with
+``"ok": true``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import shutil
+import signal
+import socket
+import subprocess
+import sys
+import threading
+import time
+import traceback
+import urllib.request
+from concurrent.futures import ThreadPoolExecutor
+from pathlib import Path
+
+import numpy as np
+
+ROOT = Path(__file__).resolve().parent
+#: git-ignored, and NOT the directory the chip tool copies back: the
+#: checkpoint is ~6.4 GB. Input to nothing — rewritten from the seed each run.
+WORK = ROOT / ".chip_smoke"
+DEADLINE_S = 1150  # the driver allows 1200 s, compilation included
+
+LLAMA_3B = dict(  # bench.py BENCH_MODEL
+    model_type="llama", architectures=["LlamaForCausalLM"],
+    vocab_size=128256, hidden_size=3072, intermediate_size=8192,
+    num_hidden_layers=28, num_attention_heads=24, num_key_value_heads=8,
+    head_dim=128, tie_word_embeddings=True, max_position_embeddings=4096,
+    rms_norm_eps=1e-5, rope_theta=500000.0, torch_dtype="bfloat16",
+)
+TINY = dict(
+    LLAMA_3B, vocab_size=512, hidden_size=64, intermediate_size=128,
+    num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
+    head_dim=16,
+)
+
+# Tolerances. Each is a bound on |kernel − XLA path| (or |path A − path B|)
+# for the SAME mathematical function, so what it has to absorb is rounding.
+#
+# Attention (flash, paged): inputs are bf16 N(0,1), outputs are softmax
+# averages of V rounded to bf16 (8 significand bits → ≤ 2^-8 relative, and
+# |out| ≲ 4), the two paths accumulate in f32 in different orders, and the
+# XLA path rounds the probabilities to bf16 before the PV matmul where the
+# kernels keep them in f32. int8 pools add nothing: both sides dequantize
+# the same codes.
+ATTN_ATOL = 3e-2
+# 4-bit matmuls: |err| relative to max|ref|. The XLA fallback rounds the
+# dequantized weight to bf16 (2^-9 relative per element) before a bf16
+# matmul; the kernels keep scale·nibble+bias in f32. Over IN ≥ 2048 random
+# terms that is a few 1e-3 of the output scale.
+QUANT_RTOL = 2e-2
+# Served logprobs, one serving path against another (paged pool + ragged
+# kernel + batched slots vs dense cache + single request; pp=4 vs pp=1):
+# every layer rounds its activations to bf16, 28 layers compound that, and
+# the head projects to logits of O(1) magnitude — differences of a few
+# 1e-2 nats are rounding, a wrong page or a masked-in stale row is O(1).
+LOGPROB_ATOL = 0.15
+# of the two top-10 lists at one position, this many ids must coincide
+# (ranks 9-11 swap under rounding; a different distribution shares ~none)
+TOP10_MIN_COMMON = 7
+
+
+def say(msg: str) -> None:
+    print(f"chip_smoke: {msg}", flush=True)
+
+
+class PhaseFailed(Exception):
+    pass
+
+
+def need(cond, msg: str) -> None:
+    if not cond:
+        raise PhaseFailed(msg)
+
+
+# --------------------------------------------------------------------------
+# Checkpoint: HF layout, written tensor by tensor from a seeded generator.
+# --------------------------------------------------------------------------
+
+def _bf16_bits(x: np.ndarray) -> np.ndarray:
+    """float32 → bfloat16 bit patterns (round to nearest even), as uint16."""
+    u = x.view(np.uint32)
+    return ((u + 0x7FFF + ((u >> 16) & 1)) >> 16).astype(np.uint16)
+
+
+def _write_safetensors(path: Path, tensors: list[tuple[str, tuple, object]],
+                       seed_words: list[int], std: float) -> None:
+    """One safetensors file. ``tensors`` is (name, shape, fill) with fill
+    either a float (constant) or None (N(0, std²) from the seeded stream).
+    Rows are generated and written in blocks, so no tensor is ever whole in
+    memory as float32."""
+    header, offset = {"__metadata__": {"format": "pt"}}, 0
+    for name, shape, _ in tensors:
+        size = int(np.prod(shape)) * 2
+        header[name] = {"dtype": "BF16", "shape": list(shape),
+                        "data_offsets": [offset, offset + size]}
+        offset += size
+    blob = json.dumps(header, separators=(",", ":")).encode()
+    with open(path, "wb") as f:
+        f.write(len(blob).to_bytes(8, "little"))
+        f.write(blob)
+        for index, (_, shape, fill) in enumerate(tensors):
+            rng = np.random.default_rng([*seed_words, index])
+            rows, cols = (shape[0], int(np.prod(shape[1:])))
+            for r0 in range(0, rows, 4096):
+                n = min(4096, rows - r0)
+                if fill is None:
+                    block = rng.standard_normal((n, cols), np.float32) * std
+                else:
+                    block = np.full((n, cols), fill, np.float32)
+                f.write(_bf16_bits(block).tobytes())
+
+
+def _write_tokenizer(out: Path, vocab_size: int) -> None:
+    """A word-level tokenizer whose vocabulary IS the id range: id i decodes
+    to ``w<i>`` (0 is the unknown word), so any id the head can emit decodes,
+    ``"w5 w9"`` encodes to exactly [5, 9], and there is no EOS to end a
+    random-weight generation early."""
+    from tokenizers import Tokenizer, models, pre_tokenizers
+
+    vocab = {"<unk>": 0, **{f"w{i}": i for i in range(1, vocab_size)}}
+    tok = Tokenizer(models.WordLevel(vocab, unk_token="<unk>"))
+    tok.pre_tokenizer = pre_tokenizers.WhitespaceSplit()
+    tok.save(str(out / "tokenizer.json"))
+    (out / "tokenizer_config.json").write_text(
+        json.dumps({"tokenizer_class": "PreTrainedTokenizerFast"})
+    )
+
+
+def write_checkpoint(out: Path, cfg: dict, seed: int) -> None:
+    if out.exists():
+        shutil.rmtree(out)
+    out.mkdir(parents=True)
+    (out / "config.json").write_text(json.dumps(cfg, indent=1))
+    _write_tokenizer(out, cfg["vocab_size"])
+    h, i, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
+    hd = cfg["head_dim"]
+    nq, nkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    n_layers = cfg["num_hidden_layers"]
+    files = [[("model.embed_tokens.weight", (v, h), None),
+              ("model.norm.weight", (h,), 1.0)]]
+    for n in range(n_layers):
+        p = f"model.layers.{n}."
+        files.append([
+            (p + "self_attn.q_proj.weight", (nq * hd, h), None),
+            (p + "self_attn.k_proj.weight", (nkv * hd, h), None),
+            (p + "self_attn.v_proj.weight", (nkv * hd, h), None),
+            (p + "self_attn.o_proj.weight", (h, nq * hd), None),
+            (p + "mlp.gate_proj.weight", (i, h), None),
+            (p + "mlp.up_proj.weight", (i, h), None),
+            (p + "mlp.down_proj.weight", (h, i), None),
+            (p + "input_layernorm.weight", (h,), 1.0),
+            (p + "post_attention_layernorm.weight", (h,), 1.0),
+        ])
+    names = [f"model-{k + 1:05d}-of-{len(files):05d}.safetensors"
+             for k in range(len(files))]
+    # hidden^-0.5 (0.018 at width 3072, the usual 0.02) keeps every layer's
+    # output and the logits at O(1) at ANY width, so the tiny rehearsal
+    # model attends and mixes too instead of echoing its last token.
+    # The normal draws release the GIL, so threads scale; each file has its
+    # own seeded stream, so the bytes do not depend on the schedule.
+    with ThreadPoolExecutor(max_workers=os.cpu_count() or 4) as pool:
+        jobs = [pool.submit(_write_safetensors, out / name, tensors,
+                            [seed, k], h ** -0.5)
+                for k, (name, tensors) in enumerate(zip(names, files))]
+        for job in jobs:
+            job.result()
+    (out / "model.safetensors.index.json").write_text(json.dumps({
+        "metadata": {"total_size": sum(f.stat().st_size for f in out.glob("*.safetensors"))},
+        "weight_map": {t[0]: name for name, ts in zip(names, files) for t in ts},
+    }))
+
+
+def words(seed: int, tag: int, n: int, vocab_size: int) -> str:
+    """An n-token prompt as text (see _write_tokenizer)."""
+    ids = np.random.default_rng([seed, 1000 + tag]).integers(1, vocab_size, n)
+    return " ".join(f"w{t}" for t in ids)
+
+
+# --------------------------------------------------------------------------
+# Children
+# --------------------------------------------------------------------------
+
+class Run:
+    """What the phases share: the options, the child environment, the
+    deadline, and the device line once a child has reported it."""
+
+    def __init__(self, seed: int, rehearse: bool):
+        self.seed, self.rehearse = seed, rehearse
+        self.cfg = TINY if rehearse else LLAMA_3B
+        self.ckpt = WORK / ("ckpt-rehearse" if rehearse else "ckpt")
+        self.logs = WORK / "logs"
+        self.t_end = time.monotonic() + DEADLINE_S
+        self.device = None
+        self.procs: list[subprocess.Popen] = []
+        env = dict(os.environ)
+        env["PYTHONPATH"] = os.pathsep.join(
+            p for p in (str(ROOT), env.get("PYTHONPATH")) if p
+        )
+        env["PYTHONUNBUFFERED"] = "1"
+        # WARNING-level "Persistent compilation cache hit" lines in the
+        # child logs: how a later child shows it found an earlier one's work
+        env["JAX_LOG_COMPILES"] = "1"
+        if rehearse:
+            # four virtual devices if the backend turns out to be the CPU
+            # (the flag touches no other backend)
+            flags = env.get("XLA_FLAGS", "")
+            if "xla_force_host_platform_device_count" not in flags:
+                env["XLA_FLAGS"] = (
+                    flags + " --xla_force_host_platform_device_count=4"
+                ).strip()
+        else:
+            env["JAX_PLATFORMS"] = "tpu"
+        self.env = env
+
+    def left(self) -> float:
+        return max(1.0, self.t_end - time.monotonic())
+
+    def spawn(self, name: str, argv: list[str], pipe_stdout: bool = False):
+        """Start a child in its own process group; stderr (and stdout,
+        unless piped to the caller) goes to its log file."""
+        self.logs.mkdir(parents=True, exist_ok=True)
+        log = self.logs / f"{name}.log"
+        with open(log, "w") as f:
+            proc = subprocess.Popen(
+                [sys.executable, *argv], cwd=ROOT, env=self.env,
+                stdout=subprocess.PIPE if pipe_stdout else f, stderr=f,
+                text=True, start_new_session=True,
+            )
+        self.procs.append(proc)
+        return proc, log
+
+    def run(self, name: str, argv: list[str]) -> tuple[str, Path]:
+        """Run a child to its end; its stdout is echoed and returned."""
+        proc, log = self.spawn(name, argv, pipe_stdout=True)
+        out: list[str] = []
+
+        def pump():
+            for line in proc.stdout:
+                out.append(line)
+                print(f"  [{name}] {line}", end="", flush=True)
+                if line.startswith('{"device":'):
+                    # first line of a child that touches JAX: known from
+                    # here on, even if a later check fails
+                    self.device = json.loads(line)["device"]
+
+        t = threading.Thread(target=pump, daemon=True)
+        t.start()
+        try:
+            rc = proc.wait(timeout=self.left())
+        except subprocess.TimeoutExpired:
+            raise PhaseFailed(f"{name}: out of time") from None
+        t.join(timeout=10)
+        if rc != 0:
+            raise PhaseFailed(f"{name}: exit code {rc}\n{tail(log)}")
+        return "".join(out), log
+
+    def call(self, func: str, *args) -> None:
+        """Run ``chip_smoke.<func>(seed, rehearse, *args)`` in a child."""
+        args = (self.seed, self.rehearse, *args)
+        code = f"import chip_smoke; chip_smoke.{func}(*{args!r})"
+        self.run(func.removeprefix("child_"), ["-c", code])
+
+    def stop_all(self) -> None:
+        for proc in self.procs:
+            if proc.poll() is None:
+                try:
+                    os.killpg(proc.pid, signal.SIGKILL)
+                except ProcessLookupError:
+                    pass
+                proc.wait()
+
+
+def tail(log: Path, n: int = 40) -> str:
+    lines = log.read_text(errors="replace").splitlines()
+    keep = [ln for ln in lines if "Compiling " not in ln
+            and "Finished " not in ln]
+    return "\n".join(f"    | {ln}" for ln in keep[-n:])
+
+
+def cache_hits(log: Path) -> int:
+    return log.read_text(errors="replace").count(
+        "Persistent compilation cache hit"
+    )
+
+
+def _device_line() -> dict:
+    import jax
+
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
+def child_kernels(seed: int, rehearse: bool) -> None:
+    """Phases 1 and 2, in the one child that may touch the chip now: report
+    the device, then run every Pallas kernel of ops/ against the XLA path it
+    replaces. On the chip each call goes through the DISPATCHER the models
+    use and the compiled text must hold the kernel (``tpu_custom_call``),
+    so a predicate that quietly chose XLA fails here; the rehearsal calls
+    the kernels directly in interpret mode."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from mlx_sharding_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    device = _device_line()
+    print(json.dumps({"device": device}), flush=True)
+    if not rehearse and device["platform"] != "tpu":
+        raise SystemExit(f"platform is {device['platform']!r}, not 'tpu'")
+
+    from mlx_sharding_tpu.cache import quantize_kv_rows
+    from mlx_sharding_tpu.ops import quant
+    from mlx_sharding_tpu.ops.attention import (
+        _causal_attention_xla,
+        causal_attention,
+    )
+    from mlx_sharding_tpu.ops.flash_attention import flash_attention
+    from mlx_sharding_tpu.ops.paged_attention import (
+        _paged_attention_xla,
+        paged_attention,
+    )
+    from mlx_sharding_tpu.ops.quant_matmul import (
+        quant_gemv_pipelined,
+        quant_matmul_pallas,
+    )
+
+    def check(name, kernel_name, fn, ref_fn, args, tol, relative=False):
+        t0 = time.perf_counter()
+        if rehearse:
+            got = fn(*args)
+            how = "interpret"
+        else:
+            compiled = jax.jit(fn).lower(*args).compile()
+            text = compiled.as_text()
+            if "tpu_custom_call" not in text or kernel_name not in text:
+                raise SystemExit(
+                    f"{name}: the dispatcher did not select the Pallas "
+                    f"kernel {kernel_name!r} for this shape on the chip"
+                )
+            got = compiled(*args)
+            how = "tpu_custom_call"
+        want = jax.jit(ref_fn)(*args)
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        if not bool(jnp.isfinite(got).all()) or got.shape != want.shape:
+            raise SystemExit(f"{name}: non-finite output or wrong shape")
+        err = float(jnp.max(jnp.abs(got - want)))
+        if relative:
+            err /= float(jnp.max(jnp.abs(want))) + 1e-9
+        print(f"kernel {name}: {how}, err {err:.2e} (tol {tol:g}), "
+              f"{time.perf_counter() - t0:.1f} s smoke timing", flush=True)
+        if err > tol:
+            raise SystemExit(f"{name}: err {err:.3e} exceeds {tol:g}")
+
+    key = jax.random.PRNGKey(seed)
+    bf16 = jnp.bfloat16
+    if rehearse:
+        hq, hkv, d, s_len, t_len, pages = 4, 2, 16, 64, 16, (8, 16)
+        quant_shapes, m_big = [(128, 256), (256, 128)], 16
+    else:
+        hq, hkv, d, s_len, t_len, pages = 24, 8, 128, 4096, 256, (256, 128)
+        quant_shapes, m_big = [(8192, 3072), (3072, 8192), (128256, 3072)], 256
+    scale = d ** -0.5
+
+    # ---- flash attention: a prefill chunk deep in the cache, and T=1
+    kq, kk, kv, key = jax.random.split(key, 4)
+    k = jax.random.normal(kk, (1, s_len, hkv, d), bf16)
+    v = jax.random.normal(kv, (1, s_len, hkv, d), bf16)
+    for t, off in ((t_len, s_len - 2 * t_len), (1, s_len - 3)):
+        q = jax.random.normal(kq, (1, t, hq, d), bf16)
+        direct = functools.partial(flash_attention, scale=scale,
+                                   interpret=rehearse)
+        via_dispatch = functools.partial(causal_attention, scale=scale)
+        # T=1 is opt-in (MST_FLASH_DECODE), so it is off the dispatcher's
+        # default path: the kernel is called directly
+        fn = direct if (rehearse or t == 1) else via_dispatch
+        check(f"flash T={t} S={s_len}", "flash_attention", fn,
+              functools.partial(_causal_attention_xla, scale=scale),
+              (q, k, v, jnp.asarray(off, jnp.int32)), ATTN_ATOL)
+
+    # ---- ragged paged attention: bf16 and int8 pools, two page sizes,
+    # eight slots of uneven length (empty, page-boundary, full)
+    max_seq = s_len
+    for page in pages:
+        spg = max_seq // page
+        lengths = [0, 1, page, page + 1, 3 * page - 1, max_seq // 2 + 5,
+                   max_seq - 1, max_seq]
+        m = len(lengths)
+        kq, kk, kv, kp, key = jax.random.split(key, 5)
+        n_pages = m * spg
+        k_pool = jax.random.normal(kk, (n_pages + 1, page, hkv, d), bf16)
+        v_pool = jax.random.normal(kv, (n_pages + 1, page, hkv, d), bf16)
+        # each slot owns a shuffled set of pages; past its length, scratch
+        perm = np.asarray(jax.random.permutation(kp, n_pages)).reshape(m, spg)
+        tables = np.full((m, spg), n_pages, np.int32)
+        for i, ln in enumerate(lengths):
+            used = -(-ln // page)
+            tables[i, :used] = perm[i, :used]
+        q = jax.random.normal(kq, (m, hq, d), bf16)
+        tables, lens = jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+        kq8, vq8 = quantize_kv_rows(k_pool), quantize_kv_rows(v_pool)
+        for label, args in (
+            ("bf16", (q, k_pool, v_pool, tables, lens)),
+            ("int8", (q, kq8["d"], vq8["d"], tables, lens,
+                      kq8["s"], vq8["s"])),
+        ):
+            def fn(q, kp_, vp_, tb, ln, ks=None, vs=None):
+                return paged_attention(q, kp_, vp_, tb, ln, scale, k_scale=ks,
+                                       v_scale=vs, interpret=rehearse)
+
+            def ref(q, kp_, vp_, tb, ln, ks=None, vs=None):
+                return _paged_attention_xla(q, kp_, vp_, tb, ln, scale,
+                                            None, None, None, ks, vs)
+
+            check(f"paged {label} page={page}", "paged_attention", fn, ref,
+                  args, ATTN_ATOL)
+
+    # ---- 4-bit matmuls: the batch kernel at M=m_big, the GEMV at M=1 and 8
+    for out_dim, in_dim in quant_shapes:
+        kw, key = jax.random.split(key)
+        w = jax.random.normal(kw, (out_dim, in_dim), jnp.float32) * 0.02
+        qw, sc, bi = jax.jit(quant.quantize_jax)(w)
+        del w
+        for m in (m_big, 1, 8):
+            kx, key = jax.random.split(key)
+            x = jax.random.normal(kx, (m, in_dim), bf16)
+            kernel = quant_matmul_pallas if m == m_big else quant_gemv_pipelined
+            name = "quant_matmul" if m == m_big else "quant_gemv_pipelined"
+            if rehearse:
+                fn = functools.partial(kernel, interpret=True)
+            else:
+                fn = functools.partial(quant._quant_matmul, group_size=64,
+                                       bits=4)
+            check(f"{name} M={m} {in_dim}->{out_dim}", name, fn,
+                  functools.partial(quant._quant_matmul_xla, group_size=64,
+                                    bits=4),
+                  (x, qw, sc, bi), QUANT_RTOL, relative=True)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
+def _compare_top10(where: str, a: dict, b: dict) -> float:
+    """Two top-10 {token id: logprob} maps for the same position and the
+    same context. Returns the largest difference over the shared ids."""
+    a = {int(k): float(v) for k, v in a.items()}
+    b = {int(k): float(v) for k, v in b.items()}
+    common = set(a) & set(b)
+    need(len(common) >= TOP10_MIN_COMMON,
+         f"{where}: only {len(common)} of the top-10 ids coincide")
+    worst = max(abs(a[t] - b[t]) for t in common)
+    need(worst <= LOGPROB_ATOL,
+         f"{where}: logprobs differ by {worst:.3f} (tol {LOGPROB_ATOL})")
+    return worst
+
+
+def child_pipeline(seed: int, rehearse: bool, ckpt: str) -> None:
+    """``--chips 4``: the fused SPMD pipeline at pp=4 (as ``--num-stages 4``
+    builds it) against the pp=1 engine on one of the four chips — prefill
+    plus 32 decode steps, compared on logprobs — and where the bytes sit."""
+    import jax
+    import jax.numpy as jnp
+
+    from mlx_sharding_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    device = _device_line()
+    print(json.dumps({"device": device}), flush=True)
+    if not rehearse and device["platform"] != "tpu":
+        raise SystemExit(f"platform is {device['platform']!r}, not 'tpu'")
+    if device["count"] < 4:
+        raise SystemExit(f"--chips 4 needs four devices, JAX has {device['count']}")
+
+    from mlx_sharding_tpu.loading import load_model
+    from mlx_sharding_tpu.parallel.mesh import make_mesh
+    from mlx_sharding_tpu.parallel.pipeline import PipelineEngine
+
+    devices = jax.devices()
+    t0 = time.perf_counter()
+    write_checkpoint(Path(ckpt), TINY if rehearse else LLAMA_3B, seed)
+    print(f"checkpoint written in {time.perf_counter() - t0:.1f} s smoke "
+          "timing", flush=True)
+    t0 = time.perf_counter()
+    model, params = load_model(ckpt)
+    cfg = model.config
+    kw = dict(max_seq=1024, prefill_chunk=256)
+    # the reference sits on chip 1, so chip 0 — where the loader put the
+    # checkpoint — never holds the loaded tree and two engines' copies at once
+    one = PipelineEngine(model, params, make_mesh(pp=1, devices=devices[1:2]), **kw)
+    four = PipelineEngine(model, params, make_mesh(pp=4, devices=devices[:4]), **kw)
+    del params
+    print(f"engines built in {time.perf_counter() - t0:.1f} s smoke timing",
+          flush=True)
+
+    # ---- placement: code that has only seen virtual devices may leave
+    # everything on device 0
+    def shares(tree):
+        per = {d.id: 0 for d in devices[:4]}
+        for leaf in jax.tree.leaves(tree):
+            for sh in leaf.addressable_shards:
+                per[sh.device.id] += sh.data.nbytes
+        total = sum(per.values())
+        return {k: v / total for k, v in per.items()}, total
+
+    cache = four.init_cache()
+    for what, tree in (("layer parameters", four.layer_params),
+                       ("embedding/head", four.vocab_parts),
+                       ("KV cache", (cache.k, cache.v))):
+        share, total = shares(tree)
+        print(f"placement {what}: {total / 2**20:.0f} MiB, per-device share "
+              + ", ".join(f"{s:.3f}" for s in share.values()), flush=True)
+        if any(abs(s - 0.25) > 0.02 for s in share.values()):
+            raise SystemExit(f"{what} are not spread a quarter per device")
+    del cache
+    if not rehearse:
+        for d in devices[:4]:
+            print(f"device {d.id}: {d.memory_stats()['bytes_in_use'] / 2**30:.2f}"
+                  " GiB in use", flush=True)
+
+    # ---- prefill (two chunks) plus 32 decode steps, pp=4 against pp=1.
+    # Sampled ids are compared only to keep the two contexts equal: where
+    # rounding flips an argmax the run restarts from the reference's prefix.
+    def run(engine, prompt, n):
+        toks, tops = [], []
+        for tok, lp in engine.generate_step(prompt, max_tokens=n,
+                                            want_logprobs=True):
+            toks.append(int(tok))
+            tops.append(dict(zip(lp.top_indices.tolist(), lp.top_values.tolist())))
+        return toks, tops
+
+    rng = np.random.default_rng([seed, 4])
+    prompt = [int(t) for t in rng.integers(1, cfg.vocab_size, 300)]
+    total, done, worst, restarts = 33, 0, 0.0, 0
+    t0 = time.perf_counter()
+    try:
+        while done < total:
+            ref_t, ref_p = run(one, prompt, total - done)
+            got_t, got_p = run(four, prompt, total - done)
+            for i in range(total - done):
+                worst = max(worst, _compare_top10(
+                    f"pp=4 vs pp=1 at generated position {done + i}",
+                    got_p[i], ref_p[i]))
+                if got_t[i] != ref_t[i]:
+                    break
+            done += i + 1
+            prompt = prompt + ref_t[: i + 1]
+            restarts += done < total
+            if restarts > 8:
+                raise SystemExit("pp=4 and pp=1 keep choosing different tokens")
+    except PhaseFailed as e:
+        raise SystemExit(str(e)) from None
+    print(f"pp=4 vs pp=1: {total} positions, worst logprob difference "
+          f"{worst:.4f} (tol {LOGPROB_ATOL}), {restarts} restarts, "
+          f"{time.perf_counter() - t0:.1f} s smoke timing", flush=True)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
+# --------------------------------------------------------------------------
+# The server, through its normal entry point
+# --------------------------------------------------------------------------
+
+class Server:
+    def __init__(self, run: Run, name: str, extra: list[str]):
+        with socket.socket() as s:
+            s.bind(("127.0.0.1", 0))
+            self.port = s.getsockname()[1]
+        self.run, self.name = run, name
+        self.proc, self.log = run.spawn(name, [
+            "-m", "mlx_sharding_tpu.server.openai_api",
+            "--model", str(run.ckpt), "--max-seq", "4096",
+            "--port", str(self.port), *extra,
+        ])
+
+    def url(self, path: str) -> str:
+        return f"http://127.0.0.1:{self.port}{path}"
+
+    def get(self, path: str) -> str:
+        with urllib.request.urlopen(self.url(path), timeout=30) as r:
+            need(r.status == 200, f"{self.name} {path}: HTTP {r.status}")
+            return r.read().decode()
+
+    def post(self, path: str, body: dict):
+        req = urllib.request.Request(
+            self.url(path), json.dumps(body).encode(),
+            {"Content-Type": "application/json"},
+        )
+        return urllib.request.urlopen(req, timeout=self.run.left())
+
+    def wait_healthy(self) -> None:
+        while True:
+            need(self.proc.poll() is None,
+                 f"{self.name} exited during start-up\n{tail(self.log)}")
+            need(self.run.left() > 1, f"{self.name}: out of time starting")
+            try:
+                self.get("/health")
+                return
+            except (OSError, PhaseFailed):
+                time.sleep(1.0)
+
+    def complete(self, prompt: str, max_tokens: int, **kw) -> dict:
+        with self.post("/v1/completions", dict(
+            prompt=prompt, max_tokens=max_tokens, temperature=0.0, **kw
+        )) as r:
+            need(r.status == 200, f"{self.name} completion: HTTP {r.status}")
+            body = json.loads(r.read())
+        got = body["usage"]["completion_tokens"]
+        need(got == max_tokens,
+             f"{self.name}: asked for {max_tokens} tokens, got {got}")
+        return body
+
+    def stop(self) -> None:
+        """SIGTERM, then a clean exit with code 0 — which also releases the
+        chip for the next child."""
+        self.proc.send_signal(signal.SIGTERM)
+        try:
+            rc = self.proc.wait(timeout=min(120, self.run.left()))
+        except subprocess.TimeoutExpired:
+            raise PhaseFailed(f"{self.name} did not stop on SIGTERM") from None
+        need(rc == 0, f"{self.name} exited with code {rc}\n{tail(self.log)}")
+
+
+def metric(text: str, name: str):
+    for line in text.splitlines():
+        if line.startswith(name + " "):
+            return float(line.split()[-1])
+    return None
+
+
+def phase_server(run: Run) -> None:
+    v = run.cfg["vocab_size"]
+    t0 = time.monotonic()
+    srv = Server(run, "server-batched",
+                 ["--concurrent", "8", "--paged-pool", "128"])
+    srv.wait_healthy()
+    say(f"server up (checkpoint load + placement) in {time.monotonic() - t0:.1f} s"
+        " smoke timing")
+
+    # one non-streamed completion, with logprobs: the comparison's left side
+    t0 = time.monotonic()
+    short = words(run.seed, 0, 64, v)
+    a = srv.complete(short, 32, logprobs=10)
+    say(f"non-streamed completion: 64 in, 32 out, {time.monotonic() - t0:.1f} s"
+        " smoke timing (cold compiles)")
+
+    # one streamed chat completion
+    t0 = time.monotonic()
+    with srv.post("/v1/chat/completions", dict(
+        messages=[{"role": "user", "content": words(run.seed, 1, 100, v)}],
+        max_tokens=48, temperature=0.0, stream=True,
+    )) as r:
+        need(r.status == 200, f"streamed chat: HTTP {r.status}")
+        events = [ln[6:].strip() for ln in r.read().decode().splitlines()
+                  if ln.startswith("data: ")]
+    need(events and events[-1] == "[DONE]", "SSE stream did not end in [DONE]")
+    chunks = [json.loads(e) for e in events[:-1]]
+    need(all("error" not in c for c in chunks), "SSE stream carried an error")
+    text = "".join(c["choices"][0].get("delta", {}).get("content") or ""
+                   for c in chunks)
+    need(len(text.split()) == 48, f"streamed {len(text.split())} tokens, not 48")
+    need(chunks[-1]["choices"][0]["finish_reason"] == "length",
+         "stream did not finish on length")
+    say(f"streamed chat completion: 48 tokens, terminated, "
+        f"{time.monotonic() - t0:.1f} s smoke timing")
+
+    # eight at once: prompts of 64–2048 tokens, 64–256 out — chunked
+    # prefill, slot interleaving and decode across page boundaries
+    t0 = time.monotonic()
+    mix = [(64, 256), (2048, 64), (300, 128), (1000, 96),
+           (128, 200), (1500, 64), (700, 160), (256, 256)]
+    prompts = [words(run.seed, 10 + j, n_in, v)
+               for j, (n_in, _) in enumerate(mix)]
+    with ThreadPoolExecutor(max_workers=len(mix)) as pool:
+        jobs = [pool.submit(srv.complete, prompt, n_out, logprobs=10)
+                for prompt, (_, n_out) in zip(prompts, mix)]
+        bodies = [job.result() for job in jobs]
+    long_prompt, b = prompts[6], bodies[6]  # 700 in (three chunks), 160 out
+    say(f"eight concurrent completions in {time.monotonic() - t0:.1f} s"
+        " smoke timing")
+
+    metrics = srv.get("/metrics")
+    for _ in range(20):  # a finished slot frees its pages on the next tick
+        if metric(metrics, "mst_kv_pool_pages_in_use") == 0:
+            break
+        time.sleep(0.5)
+        metrics = srv.get("/metrics")
+    need(metric(metrics, "mst_paged_attention_ragged") == 1,
+         "/metrics does not report the ragged paged-attention path")
+    need(metric(metrics, "mst_requests_failed_total") == 0,
+         "/metrics reports failed requests")
+    need(not metric(metrics, "mst_preemptions_total"),
+         "/metrics reports preemptions")
+    need(metric(metrics, "mst_requests_total") == 10, "request count is off")
+    need(metric(metrics, "mst_kv_pool_pages") == 128
+         and metric(metrics, "mst_kv_pool_pages_in_use") == 0,
+         "page pool is not 128 pages, all free again")
+    health = json.loads(srv.get("/health"))
+    need(health.get("status") == "ok", f"/health says {health}")
+    srv.stop()
+    say("ragged path in /metrics, no failures, pool drained, /health ok, "
+        "clean exit 0")
+
+    # The same server without --concurrent serves through generate.Generator
+    # and the dense cache: the plain single-request path. Positions of the
+    # batched server's greedy runs are re-asked here with the context forced
+    # to be the same (the prompt plus the batched server's own tokens), so
+    # an argmax that rounding flipped cannot derail the comparison.
+    t0 = time.monotonic()
+    ref = Server(run, "server-single", [])
+    ref.wait_healthy()
+    worst, n = 0.0, 0
+    for label, prompt, body, positions in (
+        ("64-token prompt", short, a, (0, 1, 9, 17, 31)),
+        ("700-token prompt", long_prompt, b, (0, 16, 159)),
+    ):
+        lp = body["choices"][0]["logprobs"]
+        for i in positions:
+            forced = " ".join([prompt] + [f"w{t}" for t in lp["tokens"][:i]])
+            got = ref.complete(forced, 1, logprobs=10)
+            worst = max(worst, _compare_top10(
+                f"{label}, generated position {i}",
+                lp["top_logprobs"][i],
+                got["choices"][0]["logprobs"]["top_logprobs"][0]))
+            n += 1
+    # and once unforced, without logprobs: the decode program the CLI shares
+    ref.complete(short, 20)
+    ref.stop()
+    say(f"batched server vs single-request path: {n} positions, worst "
+        f"logprob difference {worst:.4f} (tol {LOGPROB_ATOL}), "
+        f"{time.monotonic() - t0:.1f} s smoke timing; "
+        f"{cache_hits(ref.log)} persistent-cache hits in the second server")
+
+
+def phase_cli(run: Run) -> None:
+    t0 = time.monotonic()
+    out, log = run.run("cli", [
+        "-m", "mlx_sharding_tpu.cli.generate", "--model", str(run.ckpt),
+        "--prompt", words(run.seed, 2, 32, run.cfg["vocab_size"]),
+        "--max-tokens", "64",
+    ])
+    err = log.read_text(errors="replace")
+    need("tokens-per-sec" in err and "TTFT" in err,
+         "the CLI did not print its tok/s and TTFT lines")
+    need(len(out.split()) == 64, f"the CLI printed {len(out.split())} tokens")
+    hits = cache_hits(log)
+    say(f"cli: 64 tokens, {hits} persistent-cache hits for programs the "
+        f"single-request server compiled, {time.monotonic() - t0:.1f} s "
+        "smoke timing")
+    # tiny programs compile in under the cache's one-second floor
+    need(hits > 0 or run.rehearse,
+         "the CLI found nothing in the compile cache the server shares")
+
+
+def main() -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--seed", type=int, default=0)
+    parser.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    parser.add_argument("--rehearse", action="store_true")
+    args = parser.parse_args()
+    run = Run(args.seed, args.rehearse)
+    t_start = time.monotonic()
+    ok = False
+
+    def phase(name, fn):
+        t0 = time.monotonic()
+        say(f"phase {name} ...")
+        result = fn()
+        say(f"phase {name}: ok, {time.monotonic() - t0:.1f} s smoke timing")
+        return result
+
+    def checkpoint():
+        write_checkpoint(run.ckpt, run.cfg, run.seed)
+        size = sum(f.stat().st_size for f in run.ckpt.iterdir())
+        say(f"wrote {size / 2**30:.2f} GiB to {run.ckpt}")
+
+    # In either mode no model is built before a child has seen the device.
+    try:
+        if args.chips == 4:
+            phase("pipeline pp=4 vs pp=1",
+                  lambda: run.call("child_pipeline", str(run.ckpt)))
+        else:
+            phase("device + kernels", lambda: run.call("child_kernels"))
+            phase("checkpoint", checkpoint)
+            phase("server", lambda: phase_server(run))
+            phase("cli", lambda: phase_cli(run))
+        ok = True
+    except PhaseFailed as e:
+        say(f"FAILED: {e}")
+    except Exception:  # the last line and the exit code still have to say so
+        say("FAILED:\n" + traceback.format_exc())
+        for log in sorted(run.logs.glob("*.log")):
+            say(f"end of {log.name}:\n{tail(log, 25)}")
+    finally:
+        run.stop_all()
+        if run.ckpt.exists():
+            shutil.rmtree(run.ckpt)
+    say(f"total {time.monotonic() - t_start:.1f} s smoke timing")
+    print(json.dumps({"ok": ok, "device": run.device}), flush=True)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -17,6 +17,9 @@ import sys
 
 
 def main(argv=None):
+    from mlx_sharding_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description="Generate text with mlx_sharding_tpu")
     parser.add_argument("--model", required=True, help="model path or HF repo")
     parser.add_argument("--prompt", default="hello")

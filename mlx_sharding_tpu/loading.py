@@ -89,20 +89,29 @@ def load_config(
     return config
 
 
-def load_raw_weights(model_path: Path) -> dict[str, jnp.ndarray]:
-    """Read every *.safetensors in the directory (ref: shard/utils.py:40-45).
-    framework="flax" so bf16 tensors load without a numpy detour."""
+def load_raw_weights(model_path: Path) -> dict[str, np.ndarray]:
+    """Read every *.safetensors in the directory (ref: shard/utils.py:40-45)
+    into HOST memory (bf16 arrives as ml_dtypes.bfloat16). The weight mapper
+    transposes and stacks on the host and the finished tree is placed on the
+    device once: read straight onto the device, the raw tensors, their
+    transposed copies and the stacks are all resident at once — three times
+    a 3B bf16 model, which a 16 GB chip does not hold."""
     from safetensors import safe_open
 
     files = sorted(model_path.glob("*.safetensors"))
     if not files:
         raise FileNotFoundError(f"No safetensors found in {model_path}")
-    weights: dict[str, jnp.ndarray] = {}
+    weights: dict[str, np.ndarray] = {}
     for file in files:
-        with safe_open(file, framework="flax") as f:
+        with safe_open(file, framework="numpy") as f:
             for k in f.keys():
                 weights[k] = f.get_tensor(k)
     return weights
+
+
+def _kernel_readable(x):
+    """fp16 quantization scales/biases → f32 (exact); anything else as is."""
+    return x.astype(jnp.float32) if x.dtype == jnp.float16 else x
 
 
 def dequantize_weights(
@@ -141,14 +150,17 @@ def dequantize_weights(
                 )
                 and not (dense_re and dense_re.search(name))
             ):
-                # scales/biases stay in the checkpoint dtype (fp16 for
-                # published 4-bit checkpoints) — both matmul paths cast to
-                # f32 on the fly, and f32 residency would add ~11% to the
-                # weight bytes streamed per decode step for nothing
+                # scales/biases stay in the checkpoint dtype where the
+                # kernels can read it (bf16, f32) — both matmul paths cast
+                # to f32 on the fly, and f32 residency would add ~11% to
+                # the weight bytes streamed per decode step for nothing.
+                # fp16 is the exception: Mosaic has no f16 vector loads on
+                # a v5e (both Pallas kernels are refused at compile), and
+                # bf16 would round the scales, so fp16 pairs widen to f32.
                 out[name] = {
                     "q": value,
-                    "scales": weights[f"{base}.scales"],
-                    "biases": weights[f"{base}.biases"],
+                    "scales": _kernel_readable(weights[f"{base}.scales"]),
+                    "biases": _kernel_readable(weights[f"{base}.biases"]),
                 }
                 continue
             value = dequantize(
@@ -232,7 +244,8 @@ def load_model(
             keep_dense_re=model.packed_keep_dense_re(),
         )
     weights = filter_stage_weights(weights, config)
-    params = model.map_weights(weights, dtype)
+    # host-built leaves (see load_raw_weights) land on the device here
+    params = jax.device_put(model.map_weights(weights, dtype))
     # paths that must materialize dense values from packed params (embed
     # row dequant) produce this dtype, so packed and dense loads agree
     model.compute_dtype = dtype
@@ -252,15 +265,24 @@ def fetch_weight(weights: dict, key: str, dtype, transpose: bool = True):
     w = weights[key]
     if isinstance(w, dict):
         return w
-    w = jnp.asarray(w, dtype)
+    # host arrays stay on the host (the transpose is a view): stack_tree
+    # makes the one contiguous copy and load_model places it
+    w = w.astype(dtype, copy=False)
     return w.T if transpose else w
 
 
 def stack_tree(items: list):
     """Stack a list of same-structure packed-or-dense entries on a new
     leading axis: a plain array is a single-leaf tree, a packed triple
-    stacks per leaf into {q: (N, …), scales: (N, …), biases: (N, …)}."""
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *items)
+    stacks per leaf into {q: (N, …), scales: (N, …), biases: (N, …)}.
+    Host arrays stack on the host."""
+
+    def stack(*xs):
+        if all(isinstance(x, np.ndarray) for x in xs):
+            return np.stack(xs)
+        return jnp.stack(xs)
+
+    return jax.tree.map(stack, *items)
 
 
 def collect_layer_stack(

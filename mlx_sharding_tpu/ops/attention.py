@@ -70,6 +70,16 @@ def causal_attention(
         from mlx_sharding_tpu.ops.flash_attention import flash_attention
 
         return flash_attention(q, k, v, offset, scale)
+    return _causal_attention_xla(
+        q, k, v, offset, scale, logit_softcap, sliding_window
+    )
+
+
+def _causal_attention_xla(
+    q, k, v, offset, scale, logit_softcap=None, sliding_window=None
+):
+    """The fused-XLA path: every backend's fallback and the reference the
+    flash kernel is checked against on the chip (chip_smoke.py)."""
     b, t, hq, dk = q.shape
     s, hkv = k.shape[1], k.shape[2]
     groups = hq // hkv

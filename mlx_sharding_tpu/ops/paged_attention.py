@@ -11,14 +11,20 @@ FLOPs past its offset. No contiguous copy of the cache ever exists.
 
 Two paths, selected the same way ops/flash_attention.py picks its path:
 
-- the Pallas kernel (`_paged_kernel`): grid (slot, kv-head, page); the page
-  to fetch is data-dependent, so the page table and lengths ride in as
+- the Pallas kernel (`_paged_attention_kernel`): grid (slot, page); the
+  page to fetch is data-dependent, so the page table and lengths ride in as
   scalar-prefetch operands and the K/V BlockSpec index maps read them —
-  Pallas double-buffers exactly the pages named by the table. A slot's
+  Pallas double-buffers exactly the pages named by the table. A block is
+  one whole pool page, every KV head side by side on the lane axis (the
+  pool viewed as (P+1, page, Hkv·D), a free reshape): Mosaic takes a block
+  whose last two dims are tile-aligned or whole, and one head out of Hkv in
+  the pool's native (page, Hkv, D) layout is neither. The kernel walks the
+  heads as static 128-aligned lane slices. A slot's
   scratch-page tail (table rows past its length all point at the same
   scratch id) collapses to one redundant fetch: consecutive grid steps with
   an identical block index skip the DMA. Online-softmax state (running max,
-  normalizer, fp32 accumulator) lives in VMEM scratch across the page walk.
+  normalizer, fp32 accumulator, per head) lives in VMEM scratch across the
+  page walk.
 - a fused-XLA fallback for CPU / odd shapes / softcap / sliding-window /
   MLA latent-as-values, mirroring ops/attention.py's masking semantics but
   gathering only the slot's own table row (slot_pages × page rows), never
@@ -73,22 +79,25 @@ def kernel_eligible(
 def _kernel_body(
     tables_ref,  # (M, SPG) int32 — scalar-prefetch
     lens_ref,  # (M,) int32 — scalar-prefetch
-    q_ref,  # (1, 1, G, Dk) block
-    k_ref,  # (1, page, 1, Dk) block — the page named by tables[m, j]
-    v_ref,  # (1, page, 1, Dv) block
-    ks_ref,  # (1, page, 1, 1) per-row K scales (int8 pool) or None
-    vs_ref,  # (1, page, 1, 1) per-row V scales (int8 pool) or None
-    o_ref,  # (1, 1, G, Dv) block
-    m_scr,  # (G, 128) f32 VMEM — running max, lane-replicated
-    l_scr,  # (G, 128) f32 VMEM — running normalizer
-    acc_scr,  # (G, Dv) f32 VMEM — unnormalized output accumulator
+    q_ref,  # (1, Hkv, G, Dk) block — one slot's query heads
+    k_ref,  # (1, page, Hkv*Dk) block — the page named by tables[m, j]
+    v_ref,  # (1, page, Hkv*Dv) block
+    ks_ref,  # (1, page, Hkv) per-row K scales (int8 pool) or None
+    vs_ref,  # (1, page, Hkv) per-row V scales (int8 pool) or None
+    o_ref,  # (1, Hkv, G, Dv) block
+    m_scr,  # (Hkv, G, 128) f32 VMEM — running max, lane-replicated
+    l_scr,  # (Hkv, G, 128) f32 VMEM — running normalizer
+    acc_scr,  # (Hkv, G, Dv) f32 VMEM — unnormalized output accumulator
     *,
     scale: float,
     page_size: int,
     pages_per_slot: int,
+    hkv: int,
+    dk: int,
+    dv: int,
 ):
     m = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     length = lens_ref[m]
 
     @pl.when(j == 0)
@@ -101,41 +110,44 @@ def _kernel_body(
     # all compute (their DMA already collapsed to the repeated scratch id)
     @pl.when(j * page_size < length)
     def _attend():
-        q = q_ref[0, 0].astype(jnp.float32)  # (G, Dk)
-        kblk = k_ref[0, :, 0, :].astype(jnp.float32)  # (page, Dk)
-        vblk = v_ref[0, :, 0, :].astype(jnp.float32)  # (page, Dv)
-        if ks_ref is not None:
-            # int8 pool: dequant fused into the page read — the pool's
-            # HBM→VMEM traffic is the int8 bytes; the (page, 1) scale
-            # broadcasts over the head dim in registers
-            kblk = kblk * ks_ref[0, :, 0, :]
-            vblk = vblk * vs_ref[0, :, 0, :]
-        s = jax.lax.dot_general(
-            q, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (G, page)
         k_pos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1
         )
-        s = jnp.where(k_pos < length, s, NEG_INF)
-        m_prev = m_scr[:, :1]  # (G, 1)
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, vblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        # the page block carries every KV head side by side on the lane
+        # axis; head h is the static lane slice [h*D, (h+1)*D)
+        for h in range(hkv):
+            q = q_ref[0, h].astype(jnp.float32)  # (G, Dk)
+            kblk = k_ref[0, :, h * dk:(h + 1) * dk].astype(jnp.float32)
+            vblk = v_ref[0, :, h * dv:(h + 1) * dv].astype(jnp.float32)
+            if ks_ref is not None:
+                # int8 pool: dequant fused into the page read — the pool's
+                # HBM→VMEM traffic is the int8 bytes; the (page, 1) scale
+                # broadcasts over the head dim in registers
+                kblk = kblk * ks_ref[0, :, h:h + 1]
+                vblk = vblk * vs_ref[0, :, h:h + 1]
+            s = jax.lax.dot_general(
+                q, kblk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # (G, page)
+            s = jnp.where(k_pos < length, s, NEG_INF)
+            m_prev = m_scr[h, :, :1]  # (G, 1)
+            l_prev = l_scr[h, :, :1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+                p, vblk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
     @pl.when(j == pages_per_slot - 1)
     def _finish():
         # empty slot (length 0, the garbage lane): l stays 0 → zeros out
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_scr[:, :, :1], 1e-30)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def _kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
@@ -163,45 +175,57 @@ def _paged_attention_kernel(
     qg = q.reshape(m, hkv, g, dk)
     quant = k_scale is not None
 
-    def page_spec(d):
+    def page_spec(width):
         # data-dependent page fetch: the block index comes from the
-        # prefetched table row — this is the whole point of the kernel
+        # prefetched table row — this is the whole point of the kernel.
+        # The block is one whole pool page with (Hkv, D) flattened onto the
+        # lane axis (a free reshape of the contiguous pool): Mosaic wants
+        # the last two block dims tile-aligned or whole, which a
+        # one-head-of-Hkv block in the pool's native layout is not.
         return pl.BlockSpec(
-            (1, page_size, 1, d),
-            lambda mi, hi, ji, t, ln: (t[mi, ji], 0, hi, 0),
+            (1, page_size, width), lambda mi, ji, t, ln: (t[mi, ji], 0, 0)
         )
 
     in_specs = [
-        pl.BlockSpec((1, 1, g, dk), lambda mi, hi, ji, t, ln: (mi, hi, 0, 0)),
-        page_spec(dk),
-        page_spec(dv),
+        pl.BlockSpec((1, hkv, g, dk), lambda mi, ji, t, ln: (mi, 0, 0, 0)),
+        page_spec(hkv * dk),
+        page_spec(hkv * dv),
     ]
-    operands = [qg, k_pool, v_pool]
+    operands = [
+        qg,
+        k_pool.reshape(pages, page_size, hkv * dk),
+        v_pool.reshape(pages, page_size, hkv * dv),
+    ]
     if quant:  # the scale planes ride the same table-indexed fetch
-        in_specs += [page_spec(1), page_spec(1)]
-        operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        in_specs += [page_spec(hkv), page_spec(hkv)]
+        operands += [
+            k_scale.astype(jnp.float32).reshape(pages, page_size, hkv),
+            v_scale.astype(jnp.float32).reshape(pages, page_size, hkv),
+        ]
 
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(m, hkv, spg),
+        grid=(m, spg),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, g, dv), lambda mi, hi, ji, t, ln: (mi, hi, 0, 0)
+            (1, hkv, g, dv), lambda mi, ji, t, ln: (mi, 0, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((g, 128), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
-            pltpu.VMEM((g, dv), jnp.float32),
+            pltpu.VMEM((hkv, g, 128), jnp.float32),
+            pltpu.VMEM((hkv, g, 128), jnp.float32),
+            pltpu.VMEM((hkv, g, dv), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
             _kernel_int8 if quant else _kernel,
             scale=scale, page_size=page_size, pages_per_slot=spg,
+            hkv=hkv, dk=dk, dv=dv,
         ),
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((m, hkv, g, dv), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
     return out.reshape(m, hq, dv)
 

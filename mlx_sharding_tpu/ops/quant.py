@@ -117,30 +117,27 @@ def _pallas_ok(m, in_dim, out_dim, group_size, bits) -> bool:
 def _gemv_ok(m, in_dim, out_dim, group_size, bits) -> bool:
     """Decode shapes route to the pipelined GEMV: M ≤ 8, TPU backend (or
     MST_QMM_GEMV=interpret, which forces the kernel in interpret mode for
-    end-to-end parity tests on CPU), blocks dividing cleanly with
-    128-aligned word lanes (Mosaic's DMA tiling)."""
+    end-to-end parity tests on CPU), and blocks the kernel's own contract
+    (quant_matmul.gemv_blocks_ok) admits."""
     import os
 
     mode = os.environ.get("MST_QMM_GEMV", "1")
     if mode == "0" or os.environ.get("MST_QMM", "1") == "0":
         return False
-    from mlx_sharding_tpu.ops.quant_matmul import GEMV_MAX_M, get_gemv_blocks
+    from mlx_sharding_tpu.ops.quant_matmul import (
+        GEMV_MAX_M,
+        gemv_blocks_ok,
+        get_gemv_blocks,
+    )
 
     if m > GEMV_MAX_M:
         return False
     if mode != "interpret" and jax.default_backend() != "tpu":
         return False
-    per_word = 32 // bits
     block_out, block_in = get_gemv_blocks(m, out_dim, in_dim, group_size, bits)
-    words_ok = mode == "interpret" or (
-        (block_in // per_word) % 128 == 0 and block_out % 128 == 0
-    )
-    return (
-        out_dim % block_out == 0
-        and in_dim % block_in == 0
-        and block_in % group_size == 0
-        and block_in % per_word == 0
-        and words_ok
+    return gemv_blocks_ok(
+        m, out_dim, in_dim, block_out, block_in, group_size, bits,
+        hardware=mode != "interpret",
     )
 
 
@@ -162,7 +159,12 @@ def _quant_matmul(x2, q, scales, biases, group_size, bits):
         return quant_matmul_pallas(
             x2, q, scales, biases, group_size=group_size, bits=bits
         )
-    # Guarded XLA fallback: only shapes/backends no kernel serves reach it.
+    return _quant_matmul_xla(x2, q, scales, biases, group_size, bits)
+
+
+def _quant_matmul_xla(x2, q, scales, biases, group_size, bits):
+    """Guarded XLA fallback: only shapes/backends no kernel serves reach it
+    (and chip_smoke.py, as the reference both kernels are checked against)."""
     # mst: allow(MST105): dense tile is transient inside this one matmul
     w = dequantize(q, scales, biases, group_size, bits, jnp.float32)
     return (x2 @ w.astype(x2.dtype).T).astype(x2.dtype)

@@ -44,12 +44,16 @@ Two kernels share that math:
   per OUT tile and streams the IN reduction through a manual
   double-buffered HBM→VMEM DMA pipeline (``pltpu.make_async_copy`` into
   2-slot scratch buffers): while the MXU chews IN-block ``i``, the DMAs
-  for block ``i+1``'s packed words / scales / biases / activation planes
-  are already in flight, so the sub-dots overlap the next tile's weight
-  fetch instead of stalling on it. ``q``/``scales``/``biases`` are
-  sliced straight out of their checkpoint layouts (no host-side
-  relayout of multi-GB weight stacks); only the tiny activation is
-  pre-permuted to word-major planes.
+  for block ``i+1``'s packed words / activation planes are already in
+  flight, so the sub-dots overlap the next tile's weight fetch instead of
+  stalling on it. ``q`` is sliced straight out of its checkpoint layout
+  (no host-side relayout of multi-GB weight stacks); only the tiny
+  activation is pre-permuted to word-major planes. An IN block's scales
+  are ``block_in/group_size`` lanes wide — 16 at the default — and Mosaic
+  refuses a DMA slice that is not 128-lane aligned, so scales/biases are
+  not sliced: each OUT tile's whole (block_out, IN/group_size) rows ride
+  in once through the BlockSpec, and the group→word expansion matrix of
+  block ``i`` picks that block's groups out of them.
 
 Block sizes come from :func:`get_gemv_blocks`: a shape-keyed autotune
 cache (populated by :func:`autotune_gemv` — engines sweep each distinct
@@ -219,12 +223,11 @@ def quant_matmul_pallas(
         out_specs=pl.BlockSpec((block_m, block_out), lambda mi, oi, ii: (mi, oi)),
         out_shape=jax.ShapeDtypeStruct((m, out_dim), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_out), jnp.float32)],
-        compiler_params=getattr(
-            pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-        )(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="quant_matmul",
     )(x_r, q, s3, b3)
 
 
@@ -251,27 +254,72 @@ def pick_decode_block_in(in_dim: int) -> int:
     return in_dim
 
 
+def _gemv_vmem_bytes(
+    m: int, block_out: int, block_in: int, in_dim: int,
+    group_size: int = 64, bits: int = 4,
+) -> int:
+    """Working-set estimate of one :func:`quant_gemv_pipelined` grid step.
+    Per out row: both packed-word slots (2·4 bytes per word lane) plus ~8
+    bytes per word lane of nibble-plane and scale-expansion temporaries;
+    and the tile's whole scale + bias rows — lane-padded to 128, double
+    buffered by the block pipeline, plus their fp32 copies (~16 bytes per
+    padded group lane). AOT compiles for a v5e (tests/test_tpu_compile.py)
+    put Mosaic's real ceiling 1.3–2.6x above what this estimate admits
+    under ``_GEMV_VMEM_BUDGET_BYTES``."""
+    per_word = 32 // bits
+    words = block_in // per_word
+    g_pad = -(-(in_dim // group_size) // 128) * 128
+    fixed = 2 * m * per_word * words * 4 + m * 128 * 4  # x slots + acc tile
+    return fixed + block_out * (words * (2 * 4 + 8) + g_pad * 16)
+
+
+def gemv_blocks_ok(
+    m: int, out_dim: int, in_dim: int, block_out: int, block_in: int,
+    group_size: int = 64, bits: int = 4, *, hardware: bool = True,
+) -> bool:
+    """The contract of :func:`quant_gemv_pipelined`: blocks divide the shape
+    on quant-group and nibble-word boundaries and — on ``hardware``, which
+    interpret mode waives — every DMA slice is 128-lane aligned (``words``
+    lanes of packed weights and activation planes; the (M, block_out)
+    output tile) and the working set fits the VMEM budget. The dispatch
+    predicate (ops/quant._gemv_ok) and the autotune sweep both go through
+    here — a pair this admits and Mosaic refuses is a bug in this
+    function."""
+    per_word = 32 // bits
+    divides = (
+        out_dim % block_out == 0
+        and in_dim % block_in == 0
+        and block_in % group_size == 0
+        and block_in % per_word == 0
+    )
+    if not (divides and hardware):
+        return divides
+    return (
+        (block_in // per_word) % 128 == 0
+        and block_out % 128 == 0
+        and _gemv_vmem_bytes(m, block_out, block_in, in_dim, group_size, bits)
+        <= _GEMV_VMEM_BUDGET_BYTES
+    )
+
+
 def pick_decode_blocks(
     m: int, out_dim: int, in_dim: int, group_size: int = 64, bits: int = 4
 ) -> tuple[int, int]:
     """(block_out, block_in) heuristic for the decode GEMV: block_in from
     :func:`pick_decode_block_in`, then the largest 128-multiple divisor of
-    OUT whose TWO buffer slots (packed words + scales + biases + activation
-    planes) and unpack temporaries fit the VMEM budget."""
-    per_word = 32 // bits
+    OUT whose working set (:func:`_gemv_vmem_bytes`) fits the VMEM budget."""
     block_in = pick_decode_block_in(in_dim)
-    words = block_in // per_word
-    gpb = block_in // group_size
-    # per out row, both slots: q 2·4 + s/b 2·2·4 bytes-per-lane, plus ~8
-    # bytes/word of nibble-plane and scale-expansion temporaries
-    per_row = words * (2 * 4 + 8) + gpb * 16
-    fixed = 2 * m * per_word * words * 4 + m * 128 * 4  # x slots + acc tile
-    limit = max((_GEMV_VMEM_BUDGET_BYTES - fixed) // per_row, 128)
-    if out_dim <= limit:
+
+    def fits(block_out):
+        return _gemv_vmem_bytes(
+            m, block_out, block_in, in_dim, group_size, bits
+        ) <= _GEMV_VMEM_BUDGET_BYTES
+
+    if fits(out_dim):
         return out_dim, block_in
     best = None
     d = 128
-    while d <= limit:
+    while d < out_dim and fits(d):
         if out_dim % d == 0:
             best = d
         d += 128
@@ -282,14 +330,12 @@ def pick_decode_blocks(
 def _gemv_kernel(
     x_hbm,  # (M, per_word, W_total) — stays in HBM (memory_space=ANY)
     q_hbm,  # (OUT, W_total) uint32 — checkpoint layout, HBM
-    s_hbm,  # (OUT, G_total) — checkpoint layout, HBM
-    b_hbm,  # (OUT, G_total) — checkpoint layout, HBM
+    s_ref,  # (block_out, G_total) — this OUT tile's whole scale rows, VMEM
+    b_ref,  # (block_out, G_total) — this OUT tile's whole bias rows, VMEM
     o_ref,  # (M, block_out) output tile
     xbuf,  # (2, M, per_word, words) VMEM double buffer
     qbuf,  # (2, block_out, words) VMEM double buffer
-    sbuf,  # (2, block_out, gpb) VMEM double buffer
-    bbuf,  # (2, block_out, gpb) VMEM double buffer
-    sems,  # (4, 2) DMA semaphores: one per (operand, slot)
+    sems,  # (2, 2) DMA semaphores: one per (operand, slot)
     *,
     bits: int,
     group_size: int,
@@ -299,15 +345,18 @@ def _gemv_kernel(
     per_word = 32 // bits
     mask = (1 << bits) - 1
     words = qbuf.shape[-1]
-    gpb = sbuf.shape[-1]
+    g_total = s_ref.shape[-1]
+    gpb = g_total // n_in
     wpg = group_size // per_word
     m = x_hbm.shape[0]
     o0 = pl.program_id(0) * block_out
 
     def copies(i, slot):
-        """The four HBM→VMEM DMAs that land IN-block ``i`` in ``slot`` —
-        sliced straight from the checkpoint layouts (2-D strided DMA), no
-        relayout of the weight stack ever happens."""
+        """The HBM→VMEM DMAs that land IN-block ``i`` in ``slot`` — sliced
+        straight from the checkpoint layout (2-D strided DMA), no relayout
+        of the weight stack ever happens. Both slices are ``words`` lanes
+        wide, a multiple of 128 (the dispatch predicate's contract): Mosaic
+        refuses a DMA slice that is not aligned to the 128-lane tiling."""
         return (
             pltpu.make_async_copy(
                 x_hbm.at[:, :, pl.ds(i * words, words)],
@@ -317,24 +366,18 @@ def _gemv_kernel(
                 q_hbm.at[pl.ds(o0, block_out), pl.ds(i * words, words)],
                 qbuf.at[slot], sems.at[1, slot],
             ),
-            pltpu.make_async_copy(
-                s_hbm.at[pl.ds(o0, block_out), pl.ds(i * gpb, gpb)],
-                sbuf.at[slot], sems.at[2, slot],
-            ),
-            pltpu.make_async_copy(
-                b_hbm.at[pl.ds(o0, block_out), pl.ds(i * gpb, gpb)],
-                bbuf.at[slot], sems.at[3, slot],
-            ),
         )
 
     # warm-up: block 0's fetch starts before any compute
     for c in copies(0, 0):
         c.start()
 
-    # group→word lane expansion (identical for every IN block)
-    gi = jax.lax.broadcasted_iota(jnp.int32, (gpb, words), 0)
-    wi = jax.lax.broadcasted_iota(jnp.int32, (gpb, words), 1)
-    expand = (wi // wpg == gi).astype(jnp.float32)
+    # whole scale rows (see the module docstring): block i's groups are
+    # selected by its expansion matrix, E_i[g, w] = [w // wpg + i·gpb == g]
+    s_all = s_ref[...].astype(jnp.float32)
+    b_all = b_ref[...].astype(jnp.float32)
+    gi = jax.lax.broadcasted_iota(jnp.int32, (g_total, words), 0)
+    wi = jax.lax.broadcasted_iota(jnp.int32, (g_total, words), 1)
     dot = functools.partial(
         jax.lax.dot_general, preferred_element_type=jnp.float32
     )
@@ -354,8 +397,9 @@ def _gemv_kernel(
         for c in copies(i, slot):
             c.wait()
 
-        s_w = dot(sbuf[slot].astype(jnp.float32), expand, expand_c)
-        b_w = dot(bbuf[slot].astype(jnp.float32), expand, expand_c)
+        expand = (wi // wpg + i * gpb == gi).astype(jnp.float32)
+        s_w = dot(s_all, expand, expand_c)
+        b_w = dot(b_all, expand, expand_c)
         wq = qbuf[slot]  # (block_out, words) uint32
         x_sum = jnp.zeros((m, words), jnp.float32)
         for j in range(per_word):
@@ -417,33 +461,31 @@ def quant_gemv_pipelined(
 
     n_in = in_dim // block_in
     words = block_in // per_word
-    gpb = block_in // group_size
+    g_total = in_dim // group_size
     # only the activation is relayouted: (M, IN) → word-major planes
     x_r = x.reshape(m, in_dim // per_word, per_word).transpose(0, 2, 1)
 
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    row_spec = pl.BlockSpec((block_out, g_total), lambda oi: (oi, 0))
     return pl.pallas_call(
         functools.partial(
             _gemv_kernel, bits=bits, group_size=group_size, n_in=n_in,
             block_out=block_out,
         ),
         grid=(out_dim // block_out,),
-        in_specs=[any_spec, any_spec, any_spec, any_spec],
+        in_specs=[any_spec, any_spec, row_spec, row_spec],
         out_specs=pl.BlockSpec((m, block_out), lambda oi: (0, oi)),
         out_shape=jax.ShapeDtypeStruct((m, out_dim), x.dtype),
         scratch_shapes=[
             pltpu.VMEM((2, m, per_word, words), x_r.dtype),
             pltpu.VMEM((2, block_out, words), jnp.uint32),
-            pltpu.VMEM((2, block_out, gpb), scales.dtype),
-            pltpu.VMEM((2, block_out, gpb), biases.dtype),
-            pltpu.SemaphoreType.DMA((4, 2)),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
-        compiler_params=getattr(
-            pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-        )(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        name="quant_gemv_pipelined",
     )(x_r, q, scales, biases)
 
 
@@ -481,15 +523,12 @@ def _gemv_candidates(
     m: int, out_dim: int, in_dim: int, group_size: int, bits: int
 ) -> list[tuple[int, int]]:
     h_out, h_in = pick_decode_blocks(m, out_dim, in_dim, group_size, bits)
-    outs = {h_out}
-    for d in (h_out // 2, h_out * 2, out_dim):
-        if d and d % 128 == 0 and out_dim % d == 0:
-            outs.add(d)
-    ins = {h_in}
-    for d in (1024, 2048, 4096, in_dim):
-        if d and d % group_size == 0 and d % (32 // bits) == 0 and in_dim % d == 0:
-            ins.add(d)
-    return [(bo, bi) for bo in sorted(outs) for bi in sorted(ins)]
+    outs = {h_out, h_out // 2, h_out * 2, out_dim}
+    ins = {h_in, 1024, 2048, 4096, in_dim}
+    return [
+        (bo, bi) for bo in sorted(outs) for bi in sorted(ins)
+        if bo and gemv_blocks_ok(m, out_dim, in_dim, bo, bi, group_size, bits)
+    ]
 
 
 def autotune_gemv(
@@ -503,8 +542,10 @@ def autotune_gemv(
 
     Measured on a real TPU backend only — timing interpret-mode or CPU
     runs would tune for the wrong machine; those stay on the heuristic.
-    Returns the winning pair, or None when not swept (non-TPU backend or
-    MST_QMM_AUTOTUNE=0)."""
+    Every candidate passed :func:`gemv_blocks_ok`, so a compiler refusal
+    here is a bug in that predicate and propagates. Returns the winning
+    pair, or None when not swept (non-TPU backend, MST_QMM_AUTOTUNE=0, or a
+    shape no candidate serves — the dispatch sends that one to XLA)."""
     key = (_m_bucket(m), out_dim, in_dim, group_size, bits)
     if key in _GEMV_AUTOTUNE:
         return _GEMV_AUTOTUNE[key]
@@ -520,19 +561,16 @@ def autotune_gemv(
     b = jnp.zeros((out_dim, in_dim // group_size), jnp.float32)
     best, best_t = None, float("inf")
     for bo, bi in _gemv_candidates(mb, out_dim, in_dim, group_size, bits):
-        try:
-            run = functools.partial(
-                quant_gemv_pipelined, x, qw, s, b, group_size=group_size,
-                bits=bits, block_out=bo, block_in=bi,
-            )
-            run().block_until_ready()  # compile outside the timed window
-            t0 = time.perf_counter()
-            for _ in range(repeats):
-                out = run()
-            out.block_until_ready()
-            elapsed = time.perf_counter() - t0
-        except Exception:
-            continue  # candidate rejected by Mosaic/VMEM: skip, keep going
+        run = functools.partial(
+            quant_gemv_pipelined, x, qw, s, b, group_size=group_size,
+            bits=bits, block_out=bo, block_in=bi,
+        )
+        run().block_until_ready()  # compile outside the timed window
+        t0 = time.perf_counter()
+        for _ in range(repeats):
+            out = run()
+        out.block_until_ready()
+        elapsed = time.perf_counter() - t0
         if elapsed < best_t:
             best, best_t = (bo, bi), elapsed
     if best is not None:

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from mlx_sharding_tpu.parallel.mesh import AXIS_EP, shard_map
+from mlx_sharding_tpu.parallel.mesh import AXIS_EP
 
 
 def expert_parallel_apply(
@@ -58,7 +58,7 @@ def expert_parallel_apply(
 
     expert_spec = P(axis_name)
     rep = P()
-    f = shard_map(
+    f = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(rep, rep, rep, expert_spec, expert_spec, expert_spec),

@@ -77,32 +77,3 @@ def pipeline_mesh(num_stages: int, devices=None) -> Mesh:
     """1-D pipeline mesh — the parity topology (reference §2.3: PP is the
     only strategy)."""
     return make_mesh(pp=num_stages, devices=devices)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` with a fallback for jax installs that predate its
-    promotion out of ``jax.experimental`` (where the replication-check
-    kwarg was still called ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
-def axis_size(axis_name) -> int:
-    """Static mesh-axis size inside a shard_map body. ``jax.lax.axis_size``
-    only exists on newer jax; older installs expose the same integer via
-    ``jax.core.axis_frame`` (an int there, a frame object elsewhere)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    import jax.core as _core
-
-    frame = _core.axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size

@@ -49,7 +49,6 @@ from mlx_sharding_tpu.parallel.mesh import (
     AXIS_PP,
     AXIS_TP,
     same_mesh_devices,
-    shard_map,
 )
 from mlx_sharding_tpu.weights import ResidentWeights
 from mlx_sharding_tpu.sample import (
@@ -681,9 +680,9 @@ class PipelineEngine:
     def decode_block_prog(self, k_steps: int, want_lp: bool):
         """K single-token decode steps scanned into ONE program — the host
         pulls tokens once per block instead of once per token (see
-        generate.Generator: over a network-attached chip the per-token host
-        pull dominates the device step). Logprob summaries (chosen + top-10
-        via lax.top_k) are computed inside the scan when requested."""
+        generate.Generator: a per-token host pull can dominate the device
+        step). Logprob summaries (chosen + top-10 via lax.top_k) are
+        computed inside the scan when requested."""
         cache_key = (k_steps, want_lp)
         if cache_key not in self._decode_blocks:
             step, M, B = self._decode, self.microbatches, self.batch
@@ -1089,7 +1088,7 @@ class PipelineEngine:
             body = body_s1
 
         spec_stage, spec_rep = P(AXIS_PP), P()
-        inner = shard_map(
+        inner = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(
@@ -1308,7 +1307,7 @@ class PipelineEngine:
             )
 
         spec_stage, spec_rep = P(AXIS_PP), P()
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(
@@ -1721,7 +1720,7 @@ class PipelineEngine:
             )
 
         spec_stage, spec_rep = P(AXIS_PP), P()
-        smapped = shard_map(
+        smapped = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from mlx_sharding_tpu.parallel.mesh import AXIS_SP, shard_map
+from mlx_sharding_tpu.parallel.mesh import AXIS_SP
 
 
 def _block_update(scores, v_blk, o, m, l):
@@ -70,9 +70,7 @@ def ring_attention_local(
     b, t, hq, dk = q.shape
     hkv = k.shape[2]
     groups = hq // hkv
-    from mlx_sharding_tpu.parallel.mesh import axis_size
-
-    size = axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
 
     qg = q.reshape(b, t, hkv, groups, dk)
@@ -150,7 +148,7 @@ def ring_attention(q, k, v, scale: float, mesh: Mesh, axis_name: str = AXIS_SP):
     on their sequence dim and attended exactly. T must divide by the axis
     size."""
     spec = P(None, axis_name)
-    f = shard_map(
+    f = jax.shard_map(
         lambda q, k, v: ring_attention_local(q, k, v, scale, axis_name),
         mesh=mesh,
         in_specs=(spec, spec, spec),

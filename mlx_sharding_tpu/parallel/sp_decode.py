@@ -38,7 +38,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mlx_sharding_tpu.cache import KVCache
-from mlx_sharding_tpu.parallel.mesh import AXIS_SP, shard_map
+from mlx_sharding_tpu.parallel.mesh import AXIS_SP
 from mlx_sharding_tpu.sample import sample_token, update_recent_tokens
 
 
@@ -259,7 +259,7 @@ class SpDecode:
             rep = P()
             kv = P(None, None, AXIS_SP)
             self._blocks[want_lp] = jax.jit(
-                shard_map(
+                jax.shard_map(
                     block_body,
                     mesh=self.mesh,
                     in_specs=(rep, rep, kv, kv, rep, rep, rep, rep),

@@ -33,7 +33,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from mlx_sharding_tpu.cache import KVCache
-from mlx_sharding_tpu.parallel.mesh import AXIS_SP, shard_map
+from mlx_sharding_tpu.parallel.mesh import AXIS_SP
 from mlx_sharding_tpu.parallel.ring_attention import ring_attention_local
 
 
@@ -125,7 +125,7 @@ def build_sp_prefill(model, mesh: Mesh, gather: bool = True):
 
     def make(params_tree):
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(jax.tree.map(lambda _: rep, params_tree), seq_spec, rep),

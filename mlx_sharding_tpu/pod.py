@@ -1474,10 +1474,7 @@ def _selftest_main(argv=None):  # pragma: no cover — driven by the slow test
     import jax
 
     if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 — older/newer jax: best effort
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(args.coordinator,
                                num_processes=args.num_processes,
                                process_id=args.process_id)

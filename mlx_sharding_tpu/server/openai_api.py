@@ -25,6 +25,7 @@ import heapq
 import json
 import logging
 import os
+import signal
 import threading
 import time
 import uuid
@@ -645,6 +646,7 @@ class ModelProvider:
 
                     def build_engine(dev_slice, *, weights_lease=None,
                                      speculate=True):
+                        nonlocal params
                         if weights_lease is not None:
                             engine = PipelineEngine(
                                 model, None, weights_lease.weights.mesh,
@@ -693,6 +695,13 @@ class ModelProvider:
                                 if self.paged_pool and self.concurrent > 1
                                 else None,
                             )
+                            if want == 1:
+                                # the one engine holds its own placed copy
+                                # of the weights and no fleet will spawn a
+                                # second: drop the loader's tree BEFORE the
+                                # batcher allocates the KV pool, or the
+                                # model is resident twice beside it
+                                params = None
                         if self.concurrent > 1 and not self.multihost:
                             from mlx_sharding_tpu.scheduler import (
                                 ContinuousBatcher,
@@ -1910,6 +1919,9 @@ def make_server(
 def main(argv=None):
     import argparse
 
+    from mlx_sharding_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description="OpenAI-compatible API server")
     parser.add_argument("--model", default=None, help="default model path/repo")
     parser.add_argument("--host", default="127.0.0.1")
@@ -2263,14 +2275,8 @@ def main(argv=None):
 
         if os.environ.get("JAX_PLATFORMS", "") == "cpu":
             # CPU ranks (the multi-host tests, or a smoke deployment) need
-            # an explicit cross-process collectives implementation on jax
-            # versions where the CPU backend doesn't default to one
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo"
-                )
-            except Exception:  # noqa: BLE001 — older/newer jax: best effort
-                pass
+            # an explicit cross-process collectives implementation
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             args.coordinator, num_processes=args.num_processes,
             process_id=args.process_id,
@@ -2609,7 +2615,26 @@ def main(argv=None):
                          request_timeout=args.request_timeout,
                          ttft_timeout=args.ttft_timeout)
     logger.info("serving on http://%s:%d", args.host, args.port)
-    server.serve_forever()
+
+    def _stop(signum, frame):
+        # shutdown() waits for serve_forever to return, which it cannot do
+        # from the thread serve_forever runs on — hand it to another one
+        threading.Thread(
+            target=server.shutdown, name="mst-server-stop", daemon=True
+        ).start()
+
+    # SIGTERM/SIGINT end the serve loop and close the generator (scheduler
+    # thread, device state): the process exits 0 and the next one can have
+    # the chip
+    signal.signal(signal.SIGTERM, _stop)
+    signal.signal(signal.SIGINT, _stop)
+    try:
+        server.serve_forever()
+    finally:
+        server.server_close()
+        if hasattr(provider.generator, "close"):
+            provider.generator.close()
+    logger.info("server stopped")
 
 
 if __name__ == "__main__":

@@ -60,7 +60,7 @@ def save_sharded_weights(
     weights = load_raw_weights(model_path)
     kept = filter_stage_weights(weights, config)
 
-    from safetensors.flax import save_file
+    from safetensors.numpy import save_file
 
     shard_name = f"model-{start_layer:05d}-{end_layer:05d}.safetensors"
     save_file(kept, output_dir / shard_name, metadata={"format": "flax"})
@@ -78,6 +78,7 @@ def save_sharded_weights(
     copy_other_files(model_path, output_dir)
 
     if emit_native:
+        import jax
         import jax.numpy as jnp
 
         from mlx_sharding_tpu.checkpoint import save_native_checkpoint
@@ -88,7 +89,9 @@ def save_sharded_weights(
         if config.quantization is not None:
             weights_for_map = dequantize_weights(kept, config.quantization)
         model = get_model_class(config.model_type)(config)
-        params = model.map_weights(weights_for_map, jnp.bfloat16)
+        params = jax.device_put(
+            model.map_weights(weights_for_map, jnp.bfloat16)
+        )
         native_dir = output_dir / "native"
         save_native_checkpoint(native_dir, params, config)
         copy_other_files(model_path, native_dir)
@@ -139,6 +142,9 @@ def shard_all_stages(
 def main(argv=None):
     import argparse
 
+    from mlx_sharding_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     parser = argparse.ArgumentParser(
         description="Partition a checkpoint into pipeline-stage checkpoints "
         "(TPU-native equivalent of the reference's sharding_weight.py)"

@@ -7,7 +7,7 @@ latent geometry folded into the block fingerprint so mismatched layouts
 fail closed; (2) calibrated low-rank transport for GQA pools is opt-in
 and bounded by the error stamped into the artifact at calibration time;
 (3) every ``cache.compress`` fault degrades inside the existing counted
-taxonomy — encode faults ship the block RAW, decode faults land on the
+classification — encode faults ship the block RAW, decode faults land on the
 consumer's re-prefill path, streams never drop and greedy streams stay
 bit-identical on every exact path; (4) the spill tier re-accounts bytes
 after the flusher compresses, turning compression into spill capacity.

@@ -1,0 +1,137 @@
+"""Every Pallas kernel of ops/, compiled for a TPU v5e at the real widths.
+
+Interpret mode checks a kernel's arithmetic; it cannot see what Mosaic
+refuses — a block shape off the (8, 128) tiling, a DMA slice that is not
+128-lane aligned, more VMEM than a kernel may use. The TPU compiler is
+installed here and compiles for a chip that is described, not attached, so
+these cases cost no chip time. The shapes are the ones chip_smoke.py runs on
+the chip (Llama-3.2-3B widths) plus the MLA attention shapes and
+DeepSeek-V2-Lite's packed projections. A compile that passes is not a chip
+run; chip_smoke.py is.
+
+The dispatch predicates ask ``jax.default_backend()``, which stays ``cpu``
+here, so the test answers ``tpu`` in their place and compiles the
+dispatcher itself: a shape the predicate admits and Mosaic refuses fails,
+and so does a smoke shape the predicate sends to XLA.
+"""
+
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mlx_sharding_tpu.ops import quant
+from mlx_sharding_tpu.ops.attention import causal_attention
+from mlx_sharding_tpu.ops.flash_attention import flash_attention
+from mlx_sharding_tpu.ops.paged_attention import paged_attention
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip. The persistent compile cache is off while
+    these run: an entry written for a described chip cannot be read back
+    without one, and the next compile would warn and start over."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu, or it cannot describe
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _flash(t, s, hq, hkv, dk, dv, via_dispatch=True):
+    # T=1 is opt-in (MST_FLASH_DECODE), off the dispatcher's default path
+    fn = causal_attention if via_dispatch else flash_attention
+    return (
+        functools.partial(fn, scale=dk ** -0.5),
+        [((1, t, hq, dk), BF16), ((1, s, hkv, dk), BF16),
+         ((1, s, hkv, dv), BF16), ((), I32)],
+        "flash_attention",
+    )
+
+
+def _paged(page, int8, slots=8, hq=24, hkv=8, d=128, max_seq=4096):
+    pool = ((slots * max_seq // page + 1, page, hkv, d), jnp.int8 if int8 else BF16)
+    scale = ((pool[0][0], page, hkv, 1), F32)
+
+    def fn(q, k, v, tables, lengths, ks=None, vs=None):
+        return paged_attention(q, k, v, tables, lengths, d ** -0.5,
+                               k_scale=ks, v_scale=vs)
+
+    return (
+        fn,
+        [((slots, hq, d), BF16), pool, pool,
+         ((slots, max_seq // page), I32), ((slots,), I32)]
+        + ([scale, scale] if int8 else []),
+        "paged_attention",
+    )
+
+
+def _quant(m, out_dim, in_dim, kernel, scale_dtype=F32):
+    """The packed-matmul DISPATCHER at one decode or prefill shape;
+    ``kernel`` names what it must select (None: the XLA fallback)."""
+    return (
+        functools.partial(quant._quant_matmul, group_size=64, bits=4),
+        [((m, in_dim), BF16), ((out_dim, in_dim // 8), jnp.uint32),
+         ((out_dim, in_dim // 64), scale_dtype),
+         ((out_dim, in_dim // 64), scale_dtype)],
+        kernel,
+    )
+
+
+LLAMA_3B = [(8192, 3072), (3072, 8192), (128256, 3072)]
+CASES = {
+    # flash prefill chunk and T=1 at Llama-3B heads; the MLA shapes
+    # (full mode 16/16/192/128, compressed 16/1/576/512)
+    "flash-prefill-3b": _flash(256, 4096, 24, 8, 128, 128),
+    "flash-decode-3b": _flash(1, 4096, 24, 8, 128, 128, via_dispatch=False),
+    "flash-prefill-mla-full": _flash(256, 4096, 16, 16, 192, 128),
+    "flash-prefill-mla-compressed": _flash(256, 4096, 16, 1, 576, 512),
+    # ragged paged decode at the server's default page (the prefill chunk)
+    # and at 128, bf16 and int8 pools
+    **{f"paged-{'int8' if q else 'bf16'}-page{p}": _paged(p, q)
+       for p in (256, 128) for q in (False, True)},
+    # 4-bit projections of the 3B model: batch kernel at M=256, GEMV at 1, 8
+    **{f"quant-M{m}-{i}x{o}": _quant(
+        m, o, i, "quant_matmul" if m == 256 else "quant_gemv_pipelined")
+       for o, i in LLAMA_3B for m in (256, 1, 8)},
+    # scales as a bf16 checkpoint stores them (fp16 widens to f32 at load)
+    "quant-M1-3072x8192-bf16-scales": _quant(
+        1, 8192, 3072, "quant_gemv_pipelined", BF16),
+    # DeepSeek-V2-Lite experts and dense MLP at decode: the GEMV where its
+    # DMA slices align; 1408 inputs are 176 word lanes (not 128-aligned), so
+    # that one takes the 3-D-grid kernel whole-IN; 10944 rows do not divide
+    # into 128-row tiles, so the predicate must send it to XLA
+    "quant-M1-dsv2-2048x1408": _quant(1, 1408, 2048, "quant_gemv_pipelined"),
+    "quant-M1-dsv2-1408x2048": _quant(1, 2048, 1408, "quant_matmul"),
+    "quant-M1-dsv2-2048x10944": _quant(1, 10944, 2048, None),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_compiles_for_v5e(case, chip, monkeypatch):
+    fn, shapes, kernel = CASES[case]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    # raises what the chip's compiler would raise
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    if kernel is None:
+        assert "tpu_custom_call" not in text
+    else:
+        assert "tpu_custom_call" in text and kernel in text
