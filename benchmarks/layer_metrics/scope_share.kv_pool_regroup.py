@@ -1,0 +1,8 @@
+"""Engine: device self time under ``mst.kv_pool.regroup``: the pool slices, concatenations and the layer scan's own stacking, percent of device busy time
+(``benchmarks/scope_reduce.py``: the deepest ``mst.*`` component of each
+operation's ``tf_op``)."""
+from benchmarks import scope_reduce
+
+
+def read(ctx):
+    return scope_reduce.share(ctx, exact=("mst.kv_pool.regroup",))

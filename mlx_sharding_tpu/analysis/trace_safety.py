@@ -342,7 +342,7 @@ def _traced_roots(tree: ast.Module, table: dict) -> list[ast.AST]:
             name = dotted_name(arg)
             if name is None:
                 return
-            bare = name.split(".")[-1]  # self._first_sample_fn -> method name
+            bare = name.split(".")[-1]  # self.first_sample -> method name
             roots.extend(table.get(bare, ()))
 
     for node in ast.walk(tree):

@@ -97,6 +97,7 @@ def init_cache(
     )
 
 
+@jax.named_scope("mst.attn.kv_write")
 def write_layer_kv(
     k_buf: jax.Array,
     v_buf: jax.Array,

@@ -78,7 +78,9 @@ from mlx_sharding_tpu.resilience import (
 )
 from mlx_sharding_tpu.testing.faults import inject
 from mlx_sharding_tpu.utils.clock import MONOTONIC, Clock
-from mlx_sharding_tpu.utils.observability import HANDOFF_BUCKETS_MS, Histogram
+from mlx_sharding_tpu.utils.observability import (
+    HANDOFF_BUCKETS_MS, Histogram, sum_counter_dicts,
+)
 
 
 def _pct(sorted_ms: list, q: float) -> Optional[float]:
@@ -419,6 +421,15 @@ class DisaggCoordinator:
             return None
         return {k: Histogram.merge_dicts([s[k] for s in per if k in s])
                 for k in set().union(*per)}
+
+    def tick_phase_stats(self) -> Optional[dict]:
+        """Both pools' scheduler-tick accounts summed — same shape as a
+        single batcher's."""
+        per = [s for s in (
+            getattr(self.prefill, "tick_phase_stats", lambda: None)(),
+            getattr(self.decode, "tick_phase_stats", lambda: None)(),
+        ) if s]
+        return sum_counter_dicts(per) if per else None
 
     def stats(self):
         """(slots, active, queued) summed over both pools."""

@@ -59,6 +59,7 @@ class TokenLogprobs:
     top_values: np.ndarray
 
 
+@jax.named_scope("mst.sample")
 def block_lp_outputs(tok_flat, logprobs):
     """Per-step scan outputs for a decode block when logprobs are wanted:
     ``(tokens, chosen, top_values, top_indices)``. Single source of the

@@ -64,7 +64,10 @@ def scan_layers(layer_fn, h, layer_params, k, v, mask=None):
         )
 
     xs = (layer_params, k, v) if mask is None else (layer_params, k, v, mask)
-    h, (k, v) = jax.lax.scan(body, h, xs)
+    # the scan's own work is slicing the cache per layer and stacking it
+    # back; the layer body opens deeper scopes for everything it does
+    with jax.named_scope("mst.kv_pool.regroup"):
+        h, (k, v) = jax.lax.scan(body, h, xs)
     return h, k, v
 
 
@@ -281,9 +284,11 @@ class BaseModel:
         """True when logits project through the embedding table transposed."""
         return bool(getattr(self.config, "tie_word_embeddings", False))
 
+    @jax.named_scope("mst.embed")
     def embed(self, params, tokens):
         return self.embed_transform(self.embed_tokens(params, tokens))
 
+    @jax.named_scope("mst.head")
     def apply_head(self, params, h):
         h = self.head_input(params, h)
         w = (

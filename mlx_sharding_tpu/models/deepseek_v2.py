@@ -148,6 +148,7 @@ class DeepseekV2Model(BaseModel):
         return out
 
     # ------------------------------------------------------------------
+    @jax.named_scope("mst.attn.qkv")
     def _attn_qkv(self, p, h, offset):
         """Shared MLA projection math of the causal and sequence-parallel
         attention paths. Compressed mode returns ``(q_cat (B,T,H,rank+rope),
@@ -220,16 +221,29 @@ class DeepseekV2Model(BaseModel):
             out_lat = causal_attention(
                 q, k_buf, k_buf[..., :rank], offset, self.scale
             )  # (B,T,H,rank)
-            attn = jnp.einsum(
-                "bthr,rhv->bthv", out_lat, w_bv, preferred_element_type=jnp.float32
-            ).astype(h.dtype)
+            attn = self._absorb_values(out_lat, w_bv, h.dtype)
         else:
             k_buf, v_buf = write_layer_kv(k_buf, v_buf, k_new, v_new, offset)
             attn = causal_attention(q, k_buf, v_buf, offset, self.scale)
+        return self._attn_out(h, attn, p, tp_axis), k_buf, v_buf
+
+    @staticmethod
+    @jax.named_scope("mst.attn.core")
+    def _absorb_values(out_lat, w_bv, dtype):
+        """Compressed mode's value side: kv_b's V half applied to the
+        attention output over the latent."""
+        return jnp.einsum(
+            "bthr,rhv->bthv", out_lat, w_bv, preferred_element_type=jnp.float32
+        ).astype(dtype)
+
+    @jax.named_scope("mst.attn.core")
+    def _attn_out(self, h, attn, p, tp_axis=None):
+        """Output projection and the residual add."""
+        b, t, _ = h.shape
         attn_out = self._linear(attn.reshape(b, t, -1), p["o_proj"])
         if tp_axis is not None:
             attn_out = jax.lax.psum(attn_out, tp_axis)
-        return h + attn_out, k_buf, v_buf
+        return h + attn_out
 
     def sp_groups(self):
         return list(self.layer_group_ranges().keys())
@@ -248,32 +262,43 @@ class DeepseekV2Model(BaseModel):
         if cfg.mla_cache_mode == "compressed":
             v_new = jnp.zeros((b, t, 1, 1), h.dtype)
             out_lat = attn_fn(q, k_new, v_new, values_from_k=rank)
-            attn = jnp.einsum(
-                "bthr,rhv->bthv", out_lat, w_bv, preferred_element_type=jnp.float32
-            ).astype(h.dtype)
+            attn = self._absorb_values(out_lat, w_bv, h.dtype)
         else:
             attn = attn_fn(q, k_new, v_new)
-        h = h + self._linear(attn.reshape(b, t, -1), p["o_proj"])
+        h = self._attn_out(h, attn, p)
         r = rms_norm(h, p["post_norm"], cfg.rms_norm_eps)
         if group == "moe":
-            ff = self._moe_mlp(r.reshape(b * t, -1), p).reshape(b, t, -1)
+            h = self._moe_residual(h, r, p)
         else:
-            ff = self._swiglu(r, p["gate_proj"], p["up_proj"], p["down_proj"])
-        return h + ff, k_new, v_new
+            h = self._dense_residual(h, r, p)
+        return h, k_new, v_new
 
     def _swiglu(self, r, gate, up, down):
         return self._linear(
             jax.nn.silu(self._linear(r, gate)) * self._linear(r, up), down
         )
 
+    @jax.named_scope("mst.mlp.dense")
+    def _dense_residual(self, h, r, p, tp_axis=None):
+        ff = self._swiglu(r, p["gate_proj"], p["up_proj"], p["down_proj"])
+        if tp_axis is not None:
+            ff = jax.lax.psum(ff, tp_axis)
+        return h + ff
+
+    @jax.named_scope("mst.moe.shared")
+    def _moe_residual(self, h, r, p, tp_axis=None, ep_axis=None):
+        # router and routed experts open their own deeper scopes inside
+        # _moe_mlp; what is left (routed + shared + residual) counts with
+        # the always-on half of the block
+        b, t, hidden = h.shape
+        combined = self._moe_mlp(r.reshape(b * t, hidden), p, tp_axis, ep_axis)
+        return h + combined.reshape(b, t, hidden)
+
     def _dense_layer(self, h, p, k_buf, v_buf, offset, tp_axis=None):
         cfg = self.config
         h, k_buf, v_buf = self._attention(h, p, k_buf, v_buf, offset, tp_axis)
         r = rms_norm(h, p["post_norm"], cfg.rms_norm_eps)
-        ff = self._swiglu(r, p["gate_proj"], p["up_proj"], p["down_proj"])
-        if tp_axis is not None:
-            ff = jax.lax.psum(ff, tp_axis)
-        return h + ff, k_buf, v_buf
+        return self._dense_residual(h, r, p, tp_axis), k_buf, v_buf
 
     def _moe_mlp(self, flat, p, tp_axis=None, ep_axis=None):
         """Routed + shared experts over (N, hidden) rows. Routing is
@@ -310,11 +335,9 @@ class DeepseekV2Model(BaseModel):
 
     def _moe_layer(self, h, p, k_buf, v_buf, offset, tp_axis=None, ep_axis=None):
         cfg = self.config
-        b, t, hidden = h.shape
         h, k_buf, v_buf = self._attention(h, p, k_buf, v_buf, offset, tp_axis)
         r = rms_norm(h, p["post_norm"], cfg.rms_norm_eps)
-        combined = self._moe_mlp(r.reshape(b * t, hidden), p, tp_axis, ep_axis)
-        return h + combined.reshape(b, t, hidden), k_buf, v_buf
+        return self._moe_residual(h, r, p, tp_axis, ep_axis), k_buf, v_buf
 
     # ------------------------------------------------------------------
     def _layer_split(self) -> tuple[int, int]:
@@ -344,26 +367,31 @@ class DeepseekV2Model(BaseModel):
         )
         ks, vs = [], []
         if "dense" in layer_params:
+            with jax.named_scope("mst.kv_pool.regroup"):
+                k_d, v_d = k[:n_dense], v[:n_dense]
             h, kd, vd = scan_layers(
                 lambda h, p, kb, vb: self._dense_layer(
                     h, p, kb, vb, offset, tp_axis=tp_axis
                 ),
-                h, layer_params["dense"], k[:n_dense], v[:n_dense],
+                h, layer_params["dense"], k_d, v_d,
                 None if mask is None else mask["dense"],
             )
             ks.append(kd)
             vs.append(vd)
         if "moe" in layer_params:
+            with jax.named_scope("mst.kv_pool.regroup"):
+                k_m, v_m = k[n_dense:], v[n_dense:]
             h, km, vm = scan_layers(
                 lambda h, p, kb, vb: self._moe_layer(
                     h, p, kb, vb, offset, tp_axis=tp_axis, ep_axis=ep_axis
                 ),
-                h, layer_params["moe"], k[n_dense:], v[n_dense:],
+                h, layer_params["moe"], k_m, v_m,
                 None if mask is None else mask["moe"],
             )
             ks.append(km)
             vs.append(vm)
-        return h, jnp.concatenate(ks, axis=0), jnp.concatenate(vs, axis=0)
+        with jax.named_scope("mst.kv_pool.regroup"):
+            return h, jnp.concatenate(ks, axis=0), jnp.concatenate(vs, axis=0)
 
     def head_input(self, params, h):
         return rms_norm(h, params["final_norm"]["weight"], self.config.rms_norm_eps)

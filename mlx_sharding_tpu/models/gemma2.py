@@ -45,6 +45,7 @@ class Gemma2Model(BaseModel):
             layer_idx % 2 == 0, self.config.sliding_window, _GLOBAL_WINDOW
         )
 
+    @jax.named_scope("mst.attn.qkv")
     def layer_attn_inputs(self, p, h, offset):
         """Pre-attention half: zero-centered norm + QKV + RoPE. Head counts
         derive from the projection shards, so the same code runs the full
@@ -66,22 +67,25 @@ class Gemma2Model(BaseModel):
         cfg = self.config
         b, t, _ = h.shape
         eps = cfg.rms_norm_eps
-        attn_out = self._linear(attn.reshape(b, t, -1), p["o_proj"])
-        if tp_axis is not None:
-            # the post-attention norm is NONLINEAR: partial row-parallel
-            # products must be summed BEFORE it, unlike Llama's plain residual
-            attn_out = jax.lax.psum(attn_out, tp_axis)
-        h = h + rms_norm(attn_out, p["post_attn_norm"], eps, offset=1.0)
+        with jax.named_scope("mst.attn.core"):
+            attn_out = self._linear(attn.reshape(b, t, -1), p["o_proj"])
+            if tp_axis is not None:
+                # the post-attention norm is NONLINEAR: partial row-parallel
+                # products must be summed BEFORE it, unlike Llama's plain
+                # residual
+                attn_out = jax.lax.psum(attn_out, tp_axis)
+            h = h + rms_norm(attn_out, p["post_attn_norm"], eps, offset=1.0)
 
         r = rms_norm(h, p["pre_ffw_norm"], eps, offset=1.0)
-        ff = self._linear(
-            jax.nn.gelu(self._linear(r, p["gate_proj"]), approximate=True)
-            * self._linear(r, p["up_proj"]),
-            p["down_proj"],
-        )
-        if tp_axis is not None:
-            ff = jax.lax.psum(ff, tp_axis)
-        return h + rms_norm(ff, p["post_ffw_norm"], eps, offset=1.0)
+        with jax.named_scope("mst.mlp.dense"):
+            ff = self._linear(
+                jax.nn.gelu(self._linear(r, p["gate_proj"]), approximate=True)
+                * self._linear(r, p["up_proj"]),
+                p["down_proj"],
+            )
+            if tp_axis is not None:
+                ff = jax.lax.psum(ff, tp_axis)
+            return h + rms_norm(ff, p["post_ffw_norm"], eps, offset=1.0)
 
     def sp_layer(self, p, h, offset, attn_fn, group=None):
         """Sequence-parallel layer: the injected attention gets Gemma-2's

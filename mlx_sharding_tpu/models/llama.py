@@ -38,6 +38,7 @@ class LlamaModel(BaseModel):
         self.scale = config.head_dim ** -0.5
 
     # ------------------------------------------------------------------
+    @jax.named_scope("mst.attn.qkv")
     def layer_attn_inputs(self, p, h, offset):
         """Pre-attention half of a decoder layer: norm + QKV + RoPE at
         absolute positions ``offset..offset+T``. Split out so the sequence-
@@ -82,24 +83,26 @@ class LlamaModel(BaseModel):
         (Megatron-style column/row split), riding ICI."""
         cfg = self.config
         b, t, _ = h.shape
-        attn_out = self._linear(attn.reshape(b, t, -1), p["o_proj"])
-        if tp_axis is not None:
-            attn_out = jax.lax.psum(attn_out, tp_axis)
-        h = h + attn_out
+        with jax.named_scope("mst.attn.core"):
+            attn_out = self._linear(attn.reshape(b, t, -1), p["o_proj"])
+            if tp_axis is not None:
+                attn_out = jax.lax.psum(attn_out, tp_axis)
+            h = h + attn_out
         r = rms_norm(h, p["post_norm"], cfg.rms_norm_eps)
-        if "gate_up_proj" in p:  # build-time fused packed gate+up (tp == 1)
-            gu = self._linear(r, p["gate_up_proj"])
-            gate, up = jnp.split(gu, [cfg.intermediate_size], axis=-1)
-            ff = self._linear(jax.nn.silu(gate) * up, p["down_proj"])
-        else:
-            ff = self._linear(
-                jax.nn.silu(self._linear(r, p["gate_proj"]))
-                * self._linear(r, p["up_proj"]),
-                p["down_proj"],
-            )
-        if tp_axis is not None:
-            ff = jax.lax.psum(ff, tp_axis)
-        return h + ff
+        with jax.named_scope("mst.mlp.dense"):
+            if "gate_up_proj" in p:  # build-time fused packed gate+up (tp == 1)
+                gu = self._linear(r, p["gate_up_proj"])
+                gate, up = jnp.split(gu, [cfg.intermediate_size], axis=-1)
+                ff = self._linear(jax.nn.silu(gate) * up, p["down_proj"])
+            else:
+                ff = self._linear(
+                    jax.nn.silu(self._linear(r, p["gate_proj"]))
+                    * self._linear(r, p["up_proj"]),
+                    p["down_proj"],
+                )
+            if tp_axis is not None:
+                ff = jax.lax.psum(ff, tp_axis)
+            return h + ff
 
     def _layer(self, h, p, k_buf, v_buf, offset, tp_axis=None):
         q, k, v = self.layer_attn_inputs(p, h, offset)

@@ -39,6 +39,7 @@ class MixtralModel(BaseModel):
         self.scale = config.head_dim**-0.5
 
     # ------------------------------------------------------------------
+    @jax.named_scope("mst.attn.qkv")
     def layer_attn_inputs(self, p, h, offset):
         """Pre-attention half: norm + QKV + RoPE. Head counts derive from
         the projection shards, so the same code runs the full model and any
@@ -58,10 +59,11 @@ class MixtralModel(BaseModel):
         """Post-attention half: O projection + routed top-k expert MLP."""
         cfg = self.config
         b, t, hidden = h.shape
-        attn_out = self._linear(attn.reshape(b, t, -1), p["o_proj"])
-        if tp_axis is not None:
-            attn_out = jax.lax.psum(attn_out, tp_axis)
-        h = h + attn_out
+        with jax.named_scope("mst.attn.core"):
+            attn_out = self._linear(attn.reshape(b, t, -1), p["o_proj"])
+            if tp_axis is not None:
+                attn_out = jax.lax.psum(attn_out, tp_axis)
+            h = h + attn_out
 
         r = rms_norm(h, p["post_norm"], cfg.rms_norm_eps)
         flat = r.reshape(b * t, hidden)

@@ -50,6 +50,7 @@ def _flash_eligible(q, k, v, logit_softcap, sliding_window, sinks) -> bool:
     )
 
 
+@jax.named_scope("mst.attn.core")
 def causal_attention(
     q: jax.Array,  # (B, T, Hq, Dk)
     k: jax.Array,  # (B, S, Hkv, Dk) — full cache buffer

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 GATHER_PATH_MAX_TOKENS = 16
 
 
+@jax.named_scope("mst.moe.router")
 def mixtral_routing(x, router_w, k: int):
     """HF Mixtral semantics: softmax over ALL expert logits, take top-k,
     renormalize the kept mass. Returns (weights (N,K) f32, idx (N,K))."""
@@ -37,6 +38,7 @@ def mixtral_routing(x, router_w, k: int):
     return topv, topi
 
 
+@jax.named_scope("mst.moe.router")
 def deepseek_routing(
     x,
     router_w,
@@ -73,6 +75,7 @@ def deepseek_routing(
     return topv * routed_scaling_factor, topi
 
 
+@jax.named_scope("mst.moe.experts")
 def apply_experts(
     x, weights, idx, w_gate, w_up, w_down, ep_axis=None,
     group_size: int = 64, bits: int = 4,
@@ -114,9 +117,10 @@ def _apply_gather(x, weights, idx, w_gate, w_up, w_down):
     wg = w_gate[idx]  # (N, K, H, I)
     wu = w_up[idx]
     wd = w_down[idx]  # (N, K, I, H)
-    g = jnp.einsum("nh,nkhi->nki", x, wg)
-    u = jnp.einsum("nh,nkhi->nki", x, wu)
-    y = jnp.einsum("nki,nkih->nkh", jax.nn.silu(g) * u, wd)
+    with jax.named_scope("mst.moe.experts.matmul"):
+        g = jnp.einsum("nh,nkhi->nki", x, wg)
+        u = jnp.einsum("nh,nkhi->nki", x, wu)
+        y = jnp.einsum("nki,nkih->nkh", jax.nn.silu(g) * u, wd)
     return (y * weights[..., None].astype(y.dtype)).sum(axis=1).astype(x.dtype)
 
 
@@ -127,17 +131,21 @@ def _apply_gather_packed(x, weights, idx, w_gate, w_up, w_down, gs, bits):
     so the einsums contract the LAST dim."""
     from mlx_sharding_tpu.ops.quant import dequantize
 
+    @jax.named_scope("mst.moe.experts.gather_dequant")
     def gathered(w):  # → (N, K, out, in) dense in x.dtype
         return dequantize(
             w["q"][idx], w["scales"][idx], w["biases"][idx], gs, bits, x.dtype
         )
 
-    g = jnp.einsum("nh,nkih->nki", x, gathered(w_gate))
-    u = jnp.einsum("nh,nkih->nki", x, gathered(w_up))
-    y = jnp.einsum("nki,nkhi->nkh", jax.nn.silu(g) * u, gathered(w_down))
+    # gathered() opens its own, deeper scope inside this one
+    with jax.named_scope("mst.moe.experts.matmul"):
+        g = jnp.einsum("nh,nkih->nki", x, gathered(w_gate))
+        u = jnp.einsum("nh,nkih->nki", x, gathered(w_up))
+        y = jnp.einsum("nki,nkhi->nkh", jax.nn.silu(g) * u, gathered(w_down))
     return (y * weights[..., None].astype(y.dtype)).sum(axis=1).astype(x.dtype)
 
 
+@jax.named_scope("mst.moe.experts.scan")
 def _apply_scan(x, weights, idx, w_gate, w_up, w_down, gs=64, bits=4):
     from mlx_sharding_tpu.ops.quant import is_quantized, linear
 

@@ -8,9 +8,11 @@ casts into neighbouring ops).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("mst.norm")
 def rms_norm(x, weight, eps: float = 1e-5, *, offset: float = 0.0):
     """RMSNorm. ``offset=1.0`` gives Gemma-style ``(1 + w) * x_hat``."""
     dtype = x.dtype

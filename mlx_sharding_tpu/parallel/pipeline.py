@@ -688,7 +688,7 @@ class PipelineEngine:
             step, M, B = self._decode, self.microbatches, self.batch
             one = jnp.asarray(1, jnp.int32)
 
-            def block(layer_params, masks, vparts, shared, tok, cache, recent, key, sp):
+            def solo_block(layer_params, masks, vparts, shared, tok, cache, recent, key, sp):
                 def body(carry, _):
                     tok, cache, recent, key = carry
                     tok, logprobs, cache, recent, key = step(
@@ -708,7 +708,11 @@ class PipelineEngine:
                 )
                 return outs, tok, cache, recent, key
 
-            self._decode_blocks[cache_key] = jax.jit(block, donate_argnums=(5, 6))
+            # one name per program (jit_<name> in a profile): the block with
+            # log-probabilities is another program than the one without
+            if want_lp:
+                solo_block.__name__ = solo_block.__qualname__ = "solo_block_lp"
+            self._decode_blocks[cache_key] = jax.jit(solo_block, donate_argnums=(5, 6))
         return self._decode_blocks[cache_key]
 
     def prefill_slot(self):
@@ -817,6 +821,7 @@ class PipelineEngine:
         return self.kv_codec.stats() if self.kv_codec is not None else None
 
     # ----------------------------------------------------- vocab sharding
+    @jax.named_scope("mst.embed")
     def _vs_embed(self, s, vparts, ids):
         """Embedding lookup against this device's vocab shard + psum to
         assemble full rows (only the owner contributes non-zeros)."""
@@ -828,6 +833,7 @@ class PipelineEngine:
         rows = jnp.where(owned[..., None], rows, jnp.zeros((), rows.dtype))
         return self.model.embed_transform(jax.lax.psum(rows, AXIS_PP))
 
+    @jax.named_scope("mst.head")
     def _vs_head(self, shared, vparts, h):
         """Final norm + per-shard vocab projection + all-gather. ``h`` must
         already be replicated (post-psum of the banked hidden states)."""
@@ -909,6 +915,7 @@ class PipelineEngine:
                 )
         return pool
 
+    @jax.named_scope("mst.kv_pool.regroup")
     def _kv_read(self, paged, k, v, table, m_write):
         """One slot's contiguous KV view: page-table gather (paged) or
         slot-axis index (dense). Returns (k_m, v_m, table_row)."""
@@ -920,6 +927,7 @@ class PipelineEngine:
         v_m = jax.lax.dynamic_index_in_dim(v, m_write, 1, keepdims=False)
         return k_m, v_m, None
 
+    @jax.named_scope("mst.attn.kv_write")
     def _kv_write(self, paged, k, v, k_m, v_m, row, m_write, offset, n_pages=1):
         """Inverse of _kv_read: scatter the dirty page(s) back (paged) or
         update the slot slice (dense)."""
@@ -1163,7 +1171,10 @@ class PipelineEngine:
             (layer_params, gids, own) if mask is None
             else (layer_params, gids, own, mask)
         )
-        (h, k_pool, v_pool), _ = jax.lax.scan(body, (h, k_pool, v_pool), xs)
+        with jax.named_scope("mst.kv_pool.regroup"):
+            (h, k_pool, v_pool), _ = jax.lax.scan(
+                body, (h, k_pool, v_pool), xs
+            )
         return h, k_pool, v_pool
 
     def _build_smapped_ragged(self):
@@ -1234,22 +1245,24 @@ class PipelineEngine:
                                 pool, new,
                             )
 
-                        kl = put(kl, k_new[:, 0])
-                        vl = put(vl, v_new[:, 0])
-                        done["k"] = jax.tree.map(lambda x: x[:, None], kl)
-                        done["v"] = jax.tree.map(lambda x: x[:, None], vl)
-                        out = paged_attention(
-                            q[:, 0],
-                            kl["d"] if kv_quant else kl,
-                            vl["d"] if kv_quant else vl,
-                            rows, lengths, model.scale,
-                            logit_softcap=logit_softcap,
-                            sliding_window=sliding_window,
-                            values_from_k=values_from_k,
-                            k_scale=kl["s"] if kv_quant else None,
-                            v_scale=vl["s"] if kv_quant else None,
-                        )
-                        return out[:, None]  # (M, T=1, Hq, Dv)
+                        with jax.named_scope("mst.attn.kv_write"):
+                            kl = put(kl, k_new[:, 0])
+                            vl = put(vl, v_new[:, 0])
+                            done["k"] = jax.tree.map(lambda x: x[:, None], kl)
+                            done["v"] = jax.tree.map(lambda x: x[:, None], vl)
+                        with jax.named_scope("mst.attn.core"):
+                            out = paged_attention(
+                                q[:, 0],
+                                kl["d"] if kv_quant else kl,
+                                vl["d"] if kv_quant else vl,
+                                rows, lengths, model.scale,
+                                logit_softcap=logit_softcap,
+                                sliding_window=sliding_window,
+                                values_from_k=values_from_k,
+                                k_scale=kl["s"] if kv_quant else None,
+                                v_scale=vl["s"] if kv_quant else None,
+                            )
+                            return out[:, None]  # (M, T=1, Hq, Dv)
 
                     h2, _, _ = model.sp_layer(p, h, offset_m, attn_fn, group=g)
                     return h2, done["k"], done["v"]
@@ -1280,11 +1293,11 @@ class PipelineEngine:
                         mask_g,
                     )
                 else:
+                    with jax.named_scope("mst.kv_pool.regroup"):
+                        k_in = jax.tree.map(lambda x: x[lo : lo + n_g], k)
+                        v_in = jax.tree.map(lambda x: x[lo : lo + n_g], v)
                     h, k_g, v_g = scan_layers(
-                        make_layer(g), h, stack,
-                        jax.tree.map(lambda x: x[lo : lo + n_g], k),
-                        jax.tree.map(lambda x: x[lo : lo + n_g], v),
-                        mask_g,
+                        make_layer(g), h, stack, k_in, v_in, mask_g,
                     )
                     k_parts.append(k_g)
                     v_parts.append(v_g)
@@ -1293,8 +1306,9 @@ class PipelineEngine:
                 cat = lambda *xs: (  # noqa: E731
                     jnp.concatenate(xs, axis=0) if len(xs) > 1 else xs[0]
                 )
-                k = jax.tree.map(cat, *k_parts)
-                v = jax.tree.map(cat, *v_parts)
+                with jax.named_scope("mst.kv_pool.regroup"):
+                    k = jax.tree.map(cat, *k_parts)
+                    v = jax.tree.map(cat, *v_parts)
 
             out = jnp.where(active[:, None, None], h, 0).astype(cdt)
             out = jax.lax.psum(out, AXIS_PP)  # identity at S=1; keeps the
@@ -1333,7 +1347,7 @@ class PipelineEngine:
 
         if with_sampling:
 
-            def step(layer_params, masks, vparts, shared, tokens, cache, recent, key, sp, n_valid):
+            def forward_sample(layer_params, masks, vparts, shared, tokens, cache, recent, key, sp, n_valid):
                 logits, k, v = smapped(
                     layer_params, masks, vparts, shared, tokens, cache.k, cache.v,
                     cache.offset, all_active, n_valid,
@@ -1345,9 +1359,9 @@ class PipelineEngine:
                 new_cache = KVCache(k=k, v=v, offset=cache.offset + n_valid)
                 return tok.reshape(M, B), logprobs, new_cache, recent, key
 
-            return jax.jit(step, donate_argnums=(5, 6))
+            return jax.jit(forward_sample, donate_argnums=(5, 6))
 
-        def step(layer_params, masks, vparts, shared, tokens, cache, n_valid):
+        def forward_logits(layer_params, masks, vparts, shared, tokens, cache, n_valid):
             logits, k, v = smapped(
                 layer_params, masks, vparts, shared, tokens, cache.k, cache.v,
                 cache.offset, all_active, n_valid,
@@ -1355,7 +1369,7 @@ class PipelineEngine:
             new_cache = KVCache(k=k, v=v, offset=cache.offset + n_valid)
             return logits, new_cache
 
-        return jax.jit(step, donate_argnums=(5,))
+        return jax.jit(forward_logits, donate_argnums=(5,))
 
     # ---------------------------------------------------- continuous batching
     def _build_decode_cb(self):
@@ -1383,7 +1397,7 @@ class PipelineEngine:
             dense = self._smapped_decode
             inner = lambda *args: dense(*args[:-1])  # drop the table arg
 
-        def step(
+        def decode_step(
             layer_params, masks, vparts, shared, tokens, cache, active, recent,
             keys, sp, rep_sizes, table,
         ):
@@ -1392,23 +1406,26 @@ class PipelineEngine:
                 layer_params, masks, vparts, shared, tokens, cache.k, cache.v,
                 cache.offset, active, one, table,
             )
-            split = jax.vmap(jax.random.split)(keys)  # (M, 2, 2)
-            keys, subs = split[:, 0], split[:, 1]
-            # per-slot effective repetition window: only the last rep_sizes[m]
-            # entries of the fixed-width buffer participate, so each slot's
-            # penalty semantics match a solo run with that context size
-            W = recent.shape[1]
-            valid = jnp.arange(W)[None, :] >= (W - rep_sizes)[:, None]
-            tok, logprobs = sample_token_batched(
-                subs, logits.reshape(M, -1), sp, jnp.where(valid, recent, -1)
-            )
-            recent = update_recent_tokens(recent, tok)
+            with jax.named_scope("mst.sample"):
+                split = jax.vmap(jax.random.split)(keys)  # (M, 2, 2)
+                keys, subs = split[:, 0], split[:, 1]
+                # per-slot effective repetition window: only the last
+                # rep_sizes[m] entries of the fixed-width buffer participate,
+                # so each slot's penalty semantics match a solo run with that
+                # context size
+                W = recent.shape[1]
+                valid = jnp.arange(W)[None, :] >= (W - rep_sizes)[:, None]
+                tok, logprobs = sample_token_batched(
+                    subs, logits.reshape(M, -1), sp,
+                    jnp.where(valid, recent, -1),
+                )
+                recent = update_recent_tokens(recent, tok)
             new_cache = KVCache(
                 k=k, v=v, offset=cache.offset + active.astype(jnp.int32)
             )
             return tok.reshape(M, B), logprobs, new_cache, recent, keys
 
-        return jax.jit(step, donate_argnums=(5, 7, 8))
+        return jax.jit(decode_step, donate_argnums=(5, 7, 8))
 
     # ------------------------------------ speculative continuous batching
     def spec_propose_cb(self, K: int):
@@ -1480,6 +1497,7 @@ class PipelineEngine:
                 )
                 return drafts, qlps, KVCache(k=k, v=v, offset=offsets)
 
+            prog.__name__ = prog.__qualname__ = f"spec_propose_k{K}"
             self._spec_progs[key] = jax.jit(prog, donate_argnums=(5,))
         return self._spec_progs[key]
 
@@ -1506,9 +1524,9 @@ class PipelineEngine:
         recent)``."""
         cache_key = ("verify", K)
         if cache_key not in self._spec_progs:
-            self._spec_progs[cache_key] = jax.jit(
-                self._spec_verify_fn(K), donate_argnums=(7, 9)
-            )
+            prog = self._spec_verify_fn(K)
+            prog.__name__ = prog.__qualname__ = f"spec_verify_k{K}"
+            self._spec_progs[cache_key] = jax.jit(prog, donate_argnums=(7, 9))
         return self._spec_progs[cache_key]
 
     def spec_verify_ngram_cb(self, K: int):
@@ -1534,6 +1552,7 @@ class PipelineEngine:
                            qlps, cache, active, recent, vkeys, sp, rep_sizes,
                            wcap, table)
 
+            prog.__name__ = prog.__qualname__ = f"spec_verify_ngram_k{K}"
             self._spec_progs[cache_key] = jax.jit(
                 prog, donate_argnums=(6, 8)
             )
@@ -1660,6 +1679,7 @@ class PipelineEngine:
                 )
                 return KVCache(k=k, v=v, offset=offsets)
 
+            prog.__name__ = prog.__qualname__ = f"spec_replay_k{K}"
             self._spec_progs[key] = jax.jit(prog, donate_argnums=(5,))
         return self._spec_progs[key]
 
@@ -1741,8 +1761,8 @@ class PipelineEngine:
         )
         dummy_table = jnp.zeros((1, 1), jnp.int32)
 
-        def step(layer_params, masks, vparts, shared, tokens, slot, cache, n_valid,
-                 table=None):
+        def prefill_chunk(layer_params, masks, vparts, shared, tokens, slot,
+                          cache, n_valid, table=None):
             logits, k, v = smapped(
                 layer_params, masks, vparts, shared, tokens, slot, cache.k, cache.v,
                 cache.offset, n_valid, dummy_table if table is None else table,
@@ -1750,7 +1770,7 @@ class PipelineEngine:
             offsets = cache.offset.at[slot].add(n_valid)
             return logits, KVCache(k=k, v=v, offset=offsets)
 
-        return jax.jit(step, donate_argnums=(6,))
+        return jax.jit(prefill_chunk, donate_argnums=(6,))
 
     @staticmethod
     def _sample_fn(logits, recent, key, sp):

@@ -61,7 +61,7 @@ from mlx_sharding_tpu import tracing
 from mlx_sharding_tpu.analysis.runtime import make_lock, note_acquire, note_release
 from mlx_sharding_tpu.utils.clock import MONOTONIC, WALL_SLEEP, Clock, SleepFn
 from mlx_sharding_tpu.utils.digests import chunk_digests
-from mlx_sharding_tpu.utils.observability import Histogram
+from mlx_sharding_tpu.utils.observability import Histogram, sum_counter_dicts
 from mlx_sharding_tpu.resilience import (
     HandoffReadyError,
     QueueFullError,
@@ -846,6 +846,19 @@ class ReplicaSet:
             return None
         return {k: Histogram.merge_dicts([s[k] for s in per if k in s])
                 for k in set().union(*per)}
+
+    def tick_phase_stats(self) -> Optional[dict]:
+        """The scheduler ticks' cumulative accounts (phase seconds, decode
+        blocks and tokens, pipeline drains) summed across replica batchers.
+        None when no replica keeps one (plain engines)."""
+        with self._lock:
+            reps = list(self.replicas)
+        per = [
+            r.tick_phase_stats() for r in reps
+            if hasattr(r, "tick_phase_stats")
+        ]
+        per = [s for s in per if s is not None]
+        return sum_counter_dicts(per) if per else None
 
     def spill_stats(self) -> Optional[dict]:
         """KV spill/migration counters summed across replica batchers (the

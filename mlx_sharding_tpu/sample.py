@@ -142,6 +142,7 @@ def nucleus_logits(lo: jax.Array, params: SamplerParams) -> jax.Array:
     return top_p_filter(lo / safe_temp, params.top_p)
 
 
+@jax.named_scope("mst.sample")
 def sample_token(
     key: jax.Array,
     logits: jax.Array,  # (B, V) f32
@@ -233,6 +234,7 @@ def nucleus_logits_batched(lo: jax.Array, params: SamplerParams) -> jax.Array:
     return jax.vmap(top_p_filter)(lo / safe_temp, params.top_p)
 
 
+@jax.named_scope("mst.sample")
 def sample_token_batched(
     keys: jax.Array,  # (B, 2) uint32 — one PRNG key per row
     logits: jax.Array,  # (B, V) f32
@@ -253,6 +255,7 @@ def sample_token_batched(
     return token.astype(jnp.int32), logprobs
 
 
+@jax.named_scope("mst.sample")
 def update_recent_tokens(recent: jax.Array, token: jax.Array) -> jax.Array:
     """Shift the (B, W) window left and append the new token — the device-side
     version of the reference's ``repetition_context`` deque trim

@@ -207,6 +207,8 @@ class _InflightBlock:
     live: list                       # [(slot, req)] snapshot at dispatch
     want_lp: bool
     prev_tok: Optional[object] = None  # block's first input (draft replay)
+    seq: int = 0                     # block number (mst.decode_block's seq)
+    positions: int = 0               # steps x live rows: tokens it computes
 
 
 @dataclass
@@ -581,13 +583,23 @@ class ContinuousBatcher:
             self._put = lambda x: put_global(x, rep)
         else:
             self._put = lambda x: x
-        self._row_set = jax.jit(lambda arr, slot, val: arr.at[slot].set(val))
-        self._sp_set = jax.jit(
-            lambda batched, one, slot: jax.tree.map(
+        # every jitted helper is a named function: the name is the program's
+        # in a profile (``jit_<name>``) and in a compile log, and no two
+        # served programs share one (tests/test_program_names.py)
+        def row_set(arr, slot, val):
+            return arr.at[slot].set(val)
+
+        def sp_set(batched, one, slot):
+            return jax.tree.map(
                 lambda full, x: full.at[slot].set(x), batched, one
             )
-        )
-        self._set_last = jax.jit(lambda lt, slot, tok: lt.at[slot, 0].set(tok))
+
+        def set_last(lt, slot, tok):
+            return lt.at[slot, 0].set(tok)
+
+        self._row_set = jax.jit(row_set)
+        self._sp_set = jax.jit(sp_set)
+        self._set_last = jax.jit(set_last)
         self._zeros_like = jax.jit(jnp.zeros_like)
         self._rewind_offset = jax.jit(rewind_slot_offset)
 
@@ -738,14 +750,42 @@ class ContinuousBatcher:
         # block or, in async ngram mode, a speculative round. Owned by the
         # scheduler thread, always None in sync mode outside _decode_once
         self._inflight: Optional[object] = None  # _InflightBlock | _InflightSpec
-        # per-tick timing (racy gauges by design, like kv_bytes_read_*):
-        # device_blocked measures the harvest device_get; host is the rest
-        # of the tick's wall time — the work the async path overlaps
-        self.tick_host_ms_last = 0.0
-        self.tick_device_blocked_ms_last = 0.0
-        self._tick_host_s_total = 0.0
-        self._tick_blocked_s_total = 0.0
-        self._tick_count = 0  # ticks that harvested a block
+        # --trace-profile resolved once at construction (serving configures
+        # tracing before building engines): True also opens every tick
+        # phase as a jax.profiler.TraceAnnotation (mst.tick, mst.<phase>,
+        # mst.decode_block) so the host's spans sit on the device trace's
+        # clock in a profiler capture
+        self._trace_profile = tracing.profile_enabled()
+        # where the tick thread's time goes (always on, cumulative): every
+        # part of _tick/_tick_async runs inside one phase of
+        # tracing.TICK_PHASES; harvest_wait is the harvest device_get (what
+        # the async path overlaps), idle_wait the blocking submission wait,
+        # the rest is host work
+        self._phases = tracing.TickPhases(profile=self._trace_profile)
+        self._tick_timing_base = self._phases.snapshot()
+        # plain decode blocks, counted where they happen (tick thread only).
+        # Identities: dispatched = harvested + abandoned + in flight, and
+        # positions computed = tokens emitted + dropped + positions in
+        # flight. Speculative rounds keep spec_stats() as their account.
+        self._blocks_dispatched = 0
+        self._blocks_harvested = 0
+        self._blocks_abandoned = 0
+        self._positions_computed = 0  # steps x live rows, at dispatch
+        self._tokens_emitted = 0      # decode-block tokens handed to _emit
+        # slot_finished: positions past the last token of a stream that ran
+        # to its max_tokens (the rest of its block, and its whole lookahead
+        # block); cancelled: the same for a slot given up earlier — the
+        # consumer stopped reading (stop sequence, disconnect, timeout) or
+        # the slot was preempted; abandoned_block: a block whose futures
+        # were dropped unharvested (shutdown, _fail_all)
+        self._tokens_dropped = {
+            "slot_finished": 0, "cancelled": 0, "abandoned_block": 0,
+        }
+        # _quiesce calls that found a block in flight, by call site: each is
+        # one lost overlap of the double-buffered pipeline
+        self._drains = dict.fromkeys(
+            ("admit", "prefilling", "cold", "growth", "migrate", "idle"), 0
+        )
         # always-on latency histograms (/metrics): inter-token latency at
         # the emit path, admission queue wait at slot assignment. These are
         # the metric itself (a lock + bisect per observation, same grade as
@@ -755,17 +795,6 @@ class ContinuousBatcher:
         self._h_queue_wait = Histogram(
             LATENCY_BUCKETS_S, "ContinuousBatcher._h_queue_wait"
         )
-        # --trace-profile resolved once at construction (serving configures
-        # tracing before building engines): True wraps each dispatched
-        # decode block in jax.profiler.TraceAnnotation so host spans line
-        # up with the XLA timeline in an on-chip profile capture
-        self._trace_profile = tracing.profile_enabled()
-        # time the tick spent inside import_block (device blocked on the
-        # resume path): ~0 when prefetch staged the pages, the full
-        # host→device marshal on a demand import — the number that makes
-        # resume stalls visible next to the async-sched gauges
-        self.tick_kv_import_ms_last = 0.0
-        self._tick_kv_import_s_total = 0.0
         # adaptive window control: an AcceptanceTracker drives per-slot
         # windows for ngram mode always, and for engine mode when the
         # operator opts in with spec_window_max (without it the engine path
@@ -805,22 +834,25 @@ class ContinuousBatcher:
             self.spec_draft_faults = 0
         if draft_engine is not None:
             self.dcache = draft_engine.init_cache()
-            self._split3 = jax.jit(
-                lambda ks: jax.vmap(lambda k: jax.random.split(k, 3))(ks)
-            )
+            def split3(ks):
+                return jax.vmap(lambda k: jax.random.split(k, 3))(ks)
+
+            self._split3 = jax.jit(split3)
             # draft consumed [t0, d1..d_{K-1}] = K rows; keep the verified
             # prefix (the accepted tokens ARE the draft's inputs there).
             # k is the ROUND's width — adaptive rounds can run narrower
             # than spec_k
-            self._drewind = jax.jit(
-                lambda off, count, act, k: off + jnp.where(act, count - k, 0)
-            )
+            def draft_rewind(off, count, act, k):
+                return off + jnp.where(act, count - k, 0)
+
+            self._drewind = jax.jit(draft_rewind)
         elif spec_mode == "ngram":
             # sampled ngram rounds split each slot's key once for the
             # verify (no draft-side key, unlike the engine path's 3-way)
-            self._split2 = jax.jit(
-                lambda ks: jax.vmap(lambda k: jax.random.split(k, 2))(ks)
-            )
+            def split2(ks):
+                return jax.vmap(lambda k: jax.random.split(k, 2))(ks)
+
+            self._split2 = jax.jit(split2)
         if self.paged:
             self.cache, self.table = engine.init_cache_paged()
             # analytic per-tick KV-read accounting (the HBM story behind the
@@ -882,7 +914,7 @@ class ContinuousBatcher:
         self._slots: list[Optional[_Request]] = [None] * self.M
         self._prefill_rr = 0  # round-robin cursor for admission fairness
 
-        self._first_sample = jax.jit(self._first_sample_fn)
+        self._first_sample = jax.jit(self.first_sample)
 
     # ------------------------------------------------------------- public
     def generate_step(
@@ -1308,18 +1340,47 @@ class ContinuousBatcher:
         async pipeline shrinks by overlapping it with the next block's
         compute), ``host_ms`` is the rest of the tick's wall time. Racy
         snapshot by design — a gauge, not a decision input."""
-        n = max(1, self._tick_count)
+        snap, base = self._phases.snapshot(), self._tick_timing_base
+        secs = {
+            ph: s - base["seconds"][ph] for ph, s in snap["seconds"].items()
+        }
+        # ticks that harvested a block
+        harvests = (
+            snap["entries"]["harvest_wait"] - base["entries"]["harvest_wait"]
+        )
+        n = max(1, harvests)
+        host_s = sum(
+            s for ph, s in secs.items()
+            if ph not in ("harvest_wait", "idle_wait")
+        )
         return {
             "path": "async" if self._async else "sync",
-            "host_ms_last": self.tick_host_ms_last,
-            "device_blocked_ms_last": self.tick_device_blocked_ms_last,
-            "host_ms_avg": 1000.0 * self._tick_host_s_total / n,
-            "device_blocked_ms_avg": 1000.0 * self._tick_blocked_s_total / n,
-            "ticks": self._tick_count,
-            # resume-path import stall (kv_import): ~0 when prefetch staged
-            # the pages, the full host→device marshal on a demand import
-            "kv_import_ms_last": self.tick_kv_import_ms_last,
-            "kv_import_s_total": self._tick_kv_import_s_total,
+            "host_ms_avg": 1000.0 * host_s / n,
+            "device_blocked_ms_avg": 1000.0 * secs["harvest_wait"] / n,
+            "ticks": harvests,
+            # resume-path import stall (the kv_import phase): ~0 when
+            # prefetch staged the pages, the full host→device marshal on a
+            # demand import
+            "kv_import_s_total": secs["kv_import"],
+        }
+
+    def tick_phase_stats(self) -> dict:
+        """The tick's cumulative account for /metrics (every value a
+        counter, so ReplicaSet/DisaggCoordinator sum them): seconds and
+        entries per phase, ticks, and the decode blocks' and tokens' fate.
+        Racy snapshot of tick-thread-owned counters by design."""
+        snap = self._phases.snapshot()
+        return {
+            "ticks": snap["ticks"],
+            "phase_seconds": snap["seconds"],
+            "phase_entries": snap["entries"],
+            "blocks_dispatched": self._blocks_dispatched,
+            "blocks_harvested": self._blocks_harvested,
+            "blocks_abandoned": self._blocks_abandoned,
+            "positions_computed": self._positions_computed,
+            "tokens_emitted": self._tokens_emitted,
+            "tokens_dropped": dict(self._tokens_dropped),
+            "drains": dict(self._drains),
         }
 
     def latency_stats(self) -> dict:
@@ -1334,19 +1395,13 @@ class ContinuousBatcher:
         }
 
     def reset_tick_timing(self):
-        """Zero the tick-timing accumulators. The first ticks after
-        construction pay jit compilation (dispatch-side, so it lands in
-        host_ms) — benchmarks reset after their warmup request so the
-        averages reflect steady state only."""
-        # mst: allow(MST501): advisory reset racing a tick skews one sample
-        self.tick_host_ms_last = 0.0
-        self.tick_device_blocked_ms_last = 0.0
-        # mst: allow(MST501): advisory reset racing a tick skews one sample
-        self._tick_host_s_total = 0.0
-        self._tick_blocked_s_total = 0.0
-        self._tick_count = 0
-        self.tick_kv_import_ms_last = 0.0
-        self._tick_kv_import_s_total = 0.0
+        """Restart :meth:`tick_timing_stats`' averages from now. The first
+        ticks after construction pay jit compilation (dispatch-side, so it
+        lands in host_ms) — benchmarks reset after their warmup request so
+        the averages reflect steady state only. The counters themselves
+        (:meth:`tick_phase_stats`, /metrics) are cumulative and never go
+        back: only the baseline the averages are taken from moves."""
+        self._tick_timing_base = self._phases.snapshot()
 
     def _account_kv_read(self, live, steps: int, path: Optional[str] = None):
         if not self.paged or not live:
@@ -1573,15 +1628,13 @@ class ContinuousBatcher:
         try:
             was_staged = block.is_prefetched
             t0 = time.perf_counter()
-            with tracing.bind(req._trace):
+            with self._phases.span("kv_import"), tracing.bind(req._trace):
                 self.cache = import_block(
                     self.cache, block, pages[:cover],
                     share_hash=self._share_hash, codec=self._kv_codec,
                     scatter=self._import_pages, put=self._put,
                 )
             dt = time.perf_counter() - t0
-            self.tick_kv_import_ms_last = dt * 1e3
-            self._tick_kv_import_s_total += dt
             tr = req._trace
             if tr is not None:
                 tr.add("handoff_import", t0, t0 + dt, kind="prefix_store",
@@ -1849,7 +1902,7 @@ class ContinuousBatcher:
                 )
                 self._thread.start()
 
-    def _first_sample_fn(self, logits, keys, sp, recent, rep_sizes, slot):
+    def first_sample(self, logits, keys, sp, recent, rep_sizes, slot):
         """Sample the first token of the request in ``slot`` from its prefill
         logits, using the same split-then-sample key chain as the decode
         step, leaving other slots' keys untouched. ``logits`` is the (1, V)
@@ -2050,15 +2103,13 @@ class ContinuousBatcher:
             was_host = block.is_host
             was_staged = block.is_prefetched
             t0 = time.perf_counter()
-            with tracing.bind(req._trace):
+            with self._phases.span("kv_import"), tracing.bind(req._trace):
                 self.cache = import_block(
                     self.cache, block, pages[:data_pages],
                     share_hash=self._share_hash, codec=self._kv_codec,
                     scatter=self._import_pages, put=self._put,
                 )
             dt = time.perf_counter() - t0
-            self.tick_kv_import_ms_last = dt * 1e3
-            self._tick_kv_import_s_total += dt
             tr = req._trace
             if tr is not None:
                 tr.add("handoff_import", t0, t0 + dt, pages=data_pages,
@@ -2376,6 +2427,10 @@ class ContinuousBatcher:
             # there it aliases the cache buffers without blocking; on CPU
             # skip it so dispatch stays async and the overlap is real.
             donate = () if jax.default_backend() == "cpu" else (5, 7, 8)
+            # two programs, two names: a profile's ``jit_block`` is the
+            # streamed block, ``jit_block_lp`` the one with log-probabilities
+            if want_lp:
+                block.__name__ = block.__qualname__ = "block_lp"
             self._decode_block_progs[want_lp] = jax.jit(
                 block, donate_argnums=donate
             )
@@ -2924,7 +2979,8 @@ class ContinuousBatcher:
         them a block apart so the device never waits on host work."""
         eng = self.engine
         if self.paged and self.overcommit:
-            self._grow_for_decode()
+            with self._phases.span("housekeeping"):
+                self._grow_for_decode()
         # snapshot of slots active for this block, in slot order
         live = [
             (slot, req) for slot, req in enumerate(self._slots)
@@ -2939,23 +2995,35 @@ class ContinuousBatcher:
         # the exact chain the target consumed (sync/spec fallback only)
         prev_tok = self.last_tok if self.draft is not None else None
         block = self._decode_block_prog(want_lp)
+        seq = self._blocks_dispatched
+        self._blocks_dispatched += 1
+        positions = self.decode_block * len(live)
+        self._positions_computed += positions
+        args = {}
         if self._trace_profile:
-            # --trace-profile: annotate the dispatched block so the host
-            # span lines up with the XLA timeline in a profiler capture
-            with tracing.profile_span("mst.decode_block"):
-                outs, self.last_tok, self.cache, self.recent, self.keys = block(
-                    eng.layer_params, eng.layer_masks, eng.vocab_parts,
-                    eng.shared_params, self.last_tok, self.cache, self.active,
-                    self.recent, self.keys, self.sp, self.rep_sizes, self.table,
-                )
-        else:
+            # what could tell two executions of one program apart: rows
+            # active, the log-probability variant, the longest page chain
+            args = dict(
+                seq=seq, live=len(live), want_lp=int(want_lp),
+                pages=max(
+                    len(self._pages_of.get(slot, ())) for slot, _ in live
+                ) if self.paged else 0,
+            )
+        with self._phases.span("dispatch", **args):  # mst.decode_block
             outs, self.last_tok, self.cache, self.recent, self.keys = block(
                 eng.layer_params, eng.layer_masks, eng.vocab_parts,
                 eng.shared_params, self.last_tok, self.cache, self.active,
                 self.recent, self.keys, self.sp, self.rep_sizes, self.table,
             )
         return _InflightBlock(outs=outs, live=live, want_lp=want_lp,
-                              prev_tok=prev_tok)
+                              prev_tok=prev_tok, seq=seq, positions=positions)
+
+    def _abandon(self, inf):
+        """A decode block's futures are dropped unharvested: its positions
+        were computed for nobody."""
+        if isinstance(inf, _InflightBlock):
+            self._blocks_abandoned += 1
+            self._tokens_dropped["abandoned_block"] += inf.positions
 
     def _harvest(self, inf: Optional[_InflightBlock]):
         """Pull a dispatched block's tokens to the host and run all of its
@@ -2966,22 +3034,28 @@ class ContinuousBatcher:
         point (MST104)."""
         if inf is None:
             return
-        inject("scheduler.harvest")  # fault harness: kill the harvest
-        t0 = time.perf_counter()
-        # mst: allow(MST102): THE tick sync — tokens must reach the host
-        outs, prev = jax.device_get((inf.outs, inf.prev_tok))
-        blocked = time.perf_counter() - t0
-        self.tick_device_blocked_ms_last = blocked * 1000.0
-        self._tick_blocked_s_total += blocked
-        self._tick_count += 1
+        try:
+            inject("scheduler.harvest")  # fault harness: kill the harvest
+            with self._phases.span("harvest_wait", seq=inf.seq):
+                # mst: allow(MST102): THE tick sync — tokens must reach the host
+                outs, prev = jax.device_get((inf.outs, inf.prev_tok))
+        except BaseException:
+            self._abandon(inf)
+            raise
+        self._blocks_harvested += 1
+        with self._phases.span("emit"):
+            self._emit_block(inf, outs, prev, *self._phases.last)
+
+    def _emit_block(self, inf: _InflightBlock, outs, prev, t0, t1):
+        """The host-side consequences of a harvested block. ``t0``/``t1``
+        are the harvest wait's own stamps, reused for the traced requests'
+        spans — no extra clock reads on this path."""
         toks = outs[0]  # (K, M, 1)
         live = inf.live
-        # per-tick spans for traced requests, reusing the tick-timing
-        # stamps above (t0/blocked) — no extra clock reads on this path
         for _, _req in live:
             _tr = _req._trace
             if _tr is not None:
-                _tr.add("decode_tick", t0, t0 + blocked, slot=_req.slot,
+                _tr.add("decode_tick", t0, t1, slot=_req.slot,
                         block=self.decode_block)
         if self.draft is not None and live:
             # This tick fell back to plain decode (spec paused — logprobs
@@ -3000,14 +3074,31 @@ class ContinuousBatcher:
             )
             self.fallback_ticks += 1
             self.replayed_tokens += self.decode_block * len(live)
-        for j in range(toks.shape[0]):
-            for slot, req in live:
-                if req.slot != slot:  # finished (max_tokens) earlier in block
-                    continue
-                lp = None
-                if inf.want_lp and req.want_logprobs:
-                    lp = block_token_logprobs(outs, j, slot)
-                self._emit(req, int(toks[j, slot, 0]), lp)
+        # every position of the block is emitted or dropped, counted here
+        left, emitted, finished, cancelled = inf.positions, 0, 0, 0
+        try:
+            for j in range(toks.shape[0]):
+                for slot, req in live:
+                    left -= 1
+                    if req.slot != slot:  # the slot was left earlier
+                        # (a consumer's exit sets req.cancelled on every
+                        # stream, finished ones too: go by the count)
+                        if req.produced >= req.max_tokens:
+                            finished += 1
+                        else:
+                            cancelled += 1
+                        continue
+                    lp = None
+                    if inf.want_lp and req.want_logprobs:
+                        lp = block_token_logprobs(outs, j, slot)
+                    emitted += 1
+                    self._emit(req, int(toks[j, slot, 0]), lp)
+        finally:
+            self._tokens_emitted += emitted
+            self._tokens_dropped["slot_finished"] += finished
+            self._tokens_dropped["cancelled"] += cancelled
+            # an emit that raised leaves the rest of the block undelivered
+            self._tokens_dropped["abandoned_block"] += left
 
     def _decode_once(self):
         # the sync composition point — MultiHostBatcher overrides THIS to
@@ -3201,19 +3292,18 @@ class ContinuousBatcher:
         harvest point, spec flavor)."""
         if inf is None:
             return
-        t0 = time.perf_counter()
-        # mst: allow(MST102): the spec round's one consolidated harvest
-        counts, gs_h = jax.device_get(inf.outs)
-        blocked = time.perf_counter() - t0
-        self.tick_device_blocked_ms_last = blocked * 1000.0
-        self._tick_blocked_s_total += blocked
-        self._tick_count += 1
+        with self._phases.span("harvest_wait"):
+            # mst: allow(MST102): the spec round's one consolidated harvest
+            counts, gs_h = jax.device_get(inf.outs)
+        with self._phases.span("emit"):
+            self._emit_spec(inf, counts, gs_h, *self._phases.last)
+
+    def _emit_spec(self, inf: _InflightSpec, counts, gs_h, t0, t1):
         self.rounds += len(inf.live)
         for _, _req in inf.live:
             _tr = _req._trace
             if _tr is not None:
-                _tr.add("spec_round", t0, t0 + blocked, slot=_req.slot,
-                        window=inf.K)
+                _tr.add("spec_round", t0, t1, slot=_req.slot, window=inf.K)
         for slot, req in inf.live:
             emitted = 0
             for j in range(int(counts[slot])):
@@ -3239,7 +3329,9 @@ class ContinuousBatcher:
     def _spec_once(self):
         """One synchronous speculative round: dispatch + immediate harvest
         (the sync composition point, like _decode_once for plain ticks)."""
-        self._harvest_spec(self._dispatch_spec())
+        with self._phases.span("dispatch"):
+            inf = self._dispatch_spec()
+        self._harvest_spec(inf)
 
     def _spec_tick(self) -> bool:
         """Try to make this sync tick a speculative round. False means the
@@ -3250,7 +3342,8 @@ class ContinuousBatcher:
             return False
         if not (self._spec_ok() and self._spec_draft_ok()):
             return False
-        inf = self._dispatch_spec()
+        with self._phases.span("dispatch"):
+            inf = self._dispatch_spec()
         if inf is None:
             return False
         self._harvest_spec(inf)
@@ -3394,14 +3487,18 @@ class ContinuousBatcher:
             r is not None and self._prefill_done(r) for r in self._slots
         )
 
-    def _quiesce(self):
+    def _quiesce(self, reason: str):
         """Drain the pipeline: harvest the in-flight block (if any) so every
         host-visible consequence of it — emitted tokens, finishes, freed
         pages — has landed and the device is idle. Required before anything
         that reads device state or host token counts the lookahead block is
         still mutating: admission prefill, preemption, pool-pressure growth
-        that might preempt, shutdown."""
+        that might preempt, shutdown. ``reason`` names the call site; a
+        call that finds something in flight counts as one drain under it
+        (``mst_pipeline_drains_total``)."""
         inf, self._inflight = self._inflight, None
+        if inf is not None:
+            self._drains[reason] += 1
         self._harvest_any(inf)
 
     def _growth_fits(self) -> bool:
@@ -3441,54 +3538,49 @@ class ContinuousBatcher:
         idle path quiesce the pipeline first (one-block drain), then the
         double-buffering resumes on the next tick."""
         inject("scheduler.tick", engine=id(self))  # fault harness: wedge/delay/fail a tick (match engine= to target one batcher)
+        phase = self._phases.span  # every part of the tick runs in a phase
         if self._migrate_requested:
             # drain: finish the in-flight block, then end every stream with
             # its ResumeState; the idle wait keeps the loop from spinning
             # while the dispatcher re-places the migrated requests
-            self._quiesce()
-            self._migrate_all_out()
-            self._drain_submissions(block=True)
+            with phase("housekeeping"):
+                self._quiesce("migrate")
+                self._migrate_all_out()
+            self._idle_wait()
             return
-        self._reap_cancelled()
-        self._drain_submissions()
-        cold = self._cold_candidates()
-        if cold:
-            # suspension device_gets sampler rows and rewrites page tables:
-            # drain the lookahead block first
-            self._quiesce()
-            self._spill_cold(cold)
-        self._wake_parked()
-        self._prefetch_waiting()
-        if (self._waiting and None in self._slots) or any(
-            r is not None and not self._prefill_done(r) for r in self._slots
-        ):
-            # prefill (admission or mid-admission chunks) samples the first
-            # token host-side and rewrites slot state: drain the lookahead
-            # block before touching the engine
-            self._quiesce()
-        self._admit_waiting()
-        prefilling = [
-            r for r in self._slots
-            if r is not None and not self._prefill_done(r)
-        ]
-        if prefilling:
-            if self._decoding():
-                self._prefill_rr += 1
-                self._prefill_one_chunk(
-                    prefilling[self._prefill_rr % len(prefilling)]
-                )
-            else:
-                for req in prefilling:
-                    self._prefill_one_chunk(req)
+        with phase("housekeeping"):
+            self._reap_cancelled()
+            self._drain_submissions()
+            cold = self._cold_candidates()
+            if cold:
+                # suspension device_gets sampler rows and rewrites page
+                # tables: drain the lookahead block first
+                self._quiesce("cold")
+                self._spill_cold(cold)
+            self._wake_parked()
+            self._prefetch_waiting()
+        with phase("admit"):
+            admitting = bool(self._waiting) and None in self._slots
+            if admitting or any(
+                r is not None and not self._prefill_done(r)
+                for r in self._slots
+            ):
+                # prefill (admission or mid-admission chunks) samples the
+                # first token host-side and rewrites slot state: drain the
+                # lookahead block before touching the engine
+                self._quiesce("admit" if admitting else "prefilling")
+            self._admit_waiting()
+        self._prefill_round()
         if self._handoff_ready:
             # prefill-only completions: export + end those streams BEFORE
             # dispatch (pipeline still quiesced from the prefill above)
-            self._handoff_out()
+            with phase("handoff"):
+                self._handoff_out()
         if self._decoding():
             if self.paged and self.overcommit and not self._growth_fits():
                 # growth might preempt (device_get of sampler rows + page
                 # reshuffle): only safe against a drained pipeline
-                self._quiesce()
+                self._quiesce("growth")
             prev, self._inflight = self._inflight, None
             nxt = None
             if (
@@ -3500,21 +3592,59 @@ class ContinuousBatcher:
                 # yet): extend it with prev's optimistic guess so the
                 # n-gram match sees the tokens prev is about to emit. A
                 # wrong guess only costs acceptance, never exactness.
-                nxt = self._dispatch_spec(
-                    prev.guess if isinstance(prev, _InflightSpec) else None
-                )
+                with phase("dispatch"):
+                    nxt = self._dispatch_spec(
+                        prev.guess if isinstance(prev, _InflightSpec) else None
+                    )
             if nxt is None:
                 nxt = self._dispatch_block()
             self._inflight = nxt
             self._harvest_any(prev)
         else:
-            self._quiesce()  # leftover lookahead block of finished slots
+            # leftover lookahead block of finished slots
+            self._quiesce("idle")
             if not any(self._slots):
-                # idle: block until the next request arrives (bounded wait,
-                # so parked cold sessions still get their wake poll)
-                self._drain_submissions(block=True)
-                self._wake_parked()
-                self._admit_waiting()
+                self._idle()
+
+    def _idle_wait(self):
+        with self._phases.span("idle_wait"):
+            self._drain_submissions(block=True)
+
+    def _idle(self):
+        """Nothing holds a slot: block until the next request arrives
+        (bounded wait, so parked cold sessions still get their wake poll)."""
+        self._idle_wait()
+        with self._phases.span("housekeeping"):
+            self._wake_parked()
+        with self._phases.span("admit"):
+            self._admit_waiting()
+
+    def _prefill_round(self):
+        """This tick's share of admission prefill: every prefill chunk
+        stalls every decoding slot for its duration, so while anything is
+        decoding at most ONE chunk runs per tick (round-robin across
+        admitting requests); with nothing decoding, all of them advance."""
+        prefilling = [
+            r for r in self._slots
+            if r is not None and not self._prefill_done(r)
+        ]
+        if not prefilling:
+            return
+        if self._decoding():
+            self._prefill_rr += 1
+            prefilling = [prefilling[self._prefill_rr % len(prefilling)]]
+        for req in prefilling:
+            args = {}
+            if self._trace_profile:
+                tr = req._trace
+                args = dict(
+                    rid=req.slot if tr is None else tr.request_id,
+                    pos=req.prefill_pos,
+                    n_valid=max(0, min(self.engine.prefill_chunk,
+                                       req.prompt.size - req.prefill_pos)),
+                )
+            with self._phases.span("prefill_chunk", **args):
+                self._prefill_one_chunk(req)
 
     def _tick(self):
         """One scheduler iteration: reap, admit waiting requests into free
@@ -3528,45 +3658,33 @@ class ContinuousBatcher:
         one chunk per block. With nothing decoding, all admitting requests
         advance at full rate."""
         inject("scheduler.tick", engine=id(self))  # fault harness: wedge/delay/fail a tick (match engine= to target one batcher)
+        phase = self._phases.span  # every part of the tick runs in a phase
         if self._migrate_requested:
-            self._quiesce()  # no-op in sync mode (nothing in flight)
-            self._migrate_all_out()
-            self._drain_submissions(block=True)
+            with phase("housekeeping"):
+                self._quiesce("migrate")  # sync mode: nothing in flight
+                self._migrate_all_out()
+            self._idle_wait()
             return
-        self._reap_cancelled()
-        self._drain_submissions()
-        cold = self._cold_candidates()
-        if cold:
-            self._spill_cold(cold)  # sync mode: nothing in flight to drain
-        self._wake_parked()
-        self._prefetch_waiting()
-        self._admit_waiting()
-        prefilling = [
-            r for r in self._slots
-            if r is not None and not self._prefill_done(r)
-        ]
-        decoding = self._decoding()
-        if prefilling:
-            if decoding:
-                self._prefill_rr += 1
-                self._prefill_one_chunk(
-                    prefilling[self._prefill_rr % len(prefilling)]
-                )
-            else:
-                for req in prefilling:
-                    self._prefill_one_chunk(req)
+        with phase("housekeeping"):
+            self._reap_cancelled()
+            self._drain_submissions()
+            cold = self._cold_candidates()
+            if cold:
+                self._spill_cold(cold)  # sync mode: nothing in flight to drain
+            self._wake_parked()
+            self._prefetch_waiting()
+        with phase("admit"):
+            self._admit_waiting()
+        self._prefill_round()
         if self._handoff_ready:
             # prefill-only completions leave before the decode block
-            self._handoff_out()
+            with phase("handoff"):
+                self._handoff_out()
         if self._decoding():
             if not self._spec_tick():
                 self._decode_once()
         elif not any(self._slots):
-            # idle: block until the next request arrives (bounded wait,
-            # so parked cold sessions still get their wake poll)
-            self._drain_submissions(block=True)
-            self._wake_parked()
-            self._admit_waiting()
+            self._idle()
 
     def _fail_all(self, exc: BaseException):
         # a scheduler-thread failure is an incident: snapshot the flight
@@ -3574,7 +3692,8 @@ class ContinuousBatcher:
         tracing.auto_snapshot("scheduler_fail")
         # drop the lookahead block's futures (host-side); the wholesale
         # pool reset below reclaims whatever it was still writing
-        self._inflight = None
+        inf, self._inflight = self._inflight, None
+        self._abandon(inf)
         failed: list = []
         for slot, req in enumerate(self._slots):
             if req is not None:
@@ -3625,30 +3744,22 @@ class ContinuousBatcher:
 
     def _loop(self):
         tick = self._tick_async if self._async else self._tick
+        self._phases.start()
         while not self._stop:
             try:
-                t0 = time.perf_counter()
-                b0 = self._tick_blocked_s_total
-                c0 = self._tick_count
-                tick()
-                if self._tick_count > c0:
-                    # only ticks that harvested a block carry the timing
-                    # signal (idle waits would swamp the host-side average)
-                    host = max(
-                        0.0,
-                        (time.perf_counter() - t0)
-                        - (self._tick_blocked_s_total - b0),
-                    )
-                    self.tick_host_ms_last = host * 1000.0
-                    self._tick_host_s_total += host
+                with self._phases.tick():  # mst.tick
+                    tick()
             except Exception as exc:  # noqa: BLE001 — a dead scheduler thread
                 # would hang every consumer; surface the error to them instead
                 self._fail_all(exc)
+        self._phases.stop()
         # graceful shutdown: end every in-flight and queued request's stream.
         # Host-side only — no device ops here: the engine is being dropped,
         # and in multi-host serving a device op after the final broadcast
         # would be a one-rank collective entry (a hang, not a cleanup).
-        self._inflight = None  # abandon the lookahead block's futures
+        # abandon the lookahead block's futures
+        inf, self._inflight = self._inflight, None
+        self._abandon(inf)
         for slot, req in enumerate(self._slots):
             if req is not None:
                 self._slots[slot] = None

@@ -2216,10 +2216,12 @@ def main(argv=None):
     parser.add_argument("--trace-sample", type=int, default=8,
                         help="with --trace sample: trace every Nth request")
     parser.add_argument("--trace-profile", action="store_true",
-                        help="with --trace: wrap traced decode blocks in "
-                             "jax.profiler.TraceAnnotation so host spans "
-                             "line up with the XLA timeline under "
-                             "--profile-dir")
+                        help="with --trace: open the scheduler tick and "
+                             "its phases (mst.tick, mst.<phase>, "
+                             "mst.decode_block) as "
+                             "jax.profiler.TraceAnnotation, so the host's "
+                             "spans sit on the XLA timeline's clock in a "
+                             "profiler capture")
     parser.add_argument("--chat-template", default=None,
                         help="jinja chat template (inline, or @/path/to/file) "
                         "overriding the tokenizer's")

@@ -60,6 +60,56 @@ SPAN_TYPES = (
     "sse_write",
 )
 
+# the scheduler tick's phases: they partition the tick thread's wall time
+# (``other`` is what no named phase covers). Each is a key of
+# ``ContinuousBatcher.tick_phase_stats()`` / ``mst_tick_phase_seconds_total``
+# and, with ``--trace-profile``, a ``mst.<phase>`` span on the profiler's
+# clock — except ``dispatch``, whose span keeps the name ``mst.decode_block``
+TICK_PHASES = (
+    "housekeeping",
+    "admit",
+    "prefill_chunk",
+    "handoff",
+    "dispatch",
+    "harvest_wait",
+    "emit",
+    "kv_import",
+    "idle_wait",
+    "other",
+)
+TICK_SPAN = "mst.tick"
+
+
+def phase_span_name(phase: str) -> str:
+    return "mst.decode_block" if phase == "dispatch" else f"mst.{phase}"
+
+
+_PHASE_SPAN = {phase: phase_span_name(phase) for phase in TICK_PHASES}
+_NO_SPAN = contextlib.nullcontext()
+
+
+# ``jax.named_scope`` names inside the served programs: one flat vocabulary
+# shared by the models, tests/test_program_names.py and the benchmark's
+# scope reduction. An operation belongs to the DEEPEST ``mst.*`` component
+# of its ``op_name``, so the nested ``mst.moe.experts.*`` refine their parent
+MODEL_SCOPES = (
+    "mst.embed",
+    "mst.attn.qkv",
+    "mst.attn.kv_write",
+    "mst.attn.core",
+    "mst.moe.router",
+    "mst.moe.experts",
+    "mst.moe.experts.gather_dequant",
+    "mst.moe.experts.matmul",
+    "mst.moe.experts.scan",
+    "mst.moe.shared",
+    "mst.mlp.dense",
+    "mst.norm",
+    "mst.kv_pool.regroup",
+    "mst.head",
+    "mst.sample",
+)
+
 # hard bound per trace: a runaway stream degrades to a truncated timeline
 # (with a drop counter), never to unbounded memory
 MAX_SPANS_PER_TRACE = 4096
@@ -466,13 +516,98 @@ def profile_enabled() -> bool:
     return bool(t is not None and t.enabled and t.profile)
 
 
-def profile_span(name: str):
-    """``jax.profiler.TraceAnnotation`` context for a sampled decode block
-    (``--trace-profile``), so host spans line up with the XLA timeline in
-    an on-chip ``profile_trace`` capture. Null context when jax's profiler
-    is unavailable — tracing must not create a jax dependency."""
+def profile_span(name: str, **args):
+    """``jax.profiler.TraceAnnotation`` context (``--trace-profile``), so
+    the scheduler's host spans sit on the same clock as the XLA timeline in
+    a profiler capture; ``args`` become the event's stats. Null context
+    when jax's profiler is unavailable — tracing must not create a jax
+    dependency."""
     try:
         from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
+        return TraceAnnotation(name, **args)
     except Exception:
         return contextlib.nullcontext()
+
+
+class TickPhases:
+    """Where the scheduler tick thread's wall time goes: cumulative seconds
+    and entry counts per phase of :data:`TICK_PHASES`, always on, and — with
+    ``--trace-profile`` — one profiler span per phase, from the same place.
+
+    A phase opened inside another SUSPENDS the outer one (a drain's
+    ``harvest_wait`` inside ``admit`` is harvest time, not admission time)
+    and time no named phase covers is charged to ``other``, so the phases
+    partition the thread's wall time since :meth:`start`. Entering or
+    leaving a phase is one ``perf_counter()`` read. Only the tick thread
+    writes; :meth:`snapshot` may be called from any thread."""
+
+    def __init__(self, profile: bool = False):
+        self.profile = bool(profile)
+        self.seconds = dict.fromkeys(TICK_PHASES, 0.0)
+        self.entries = dict.fromkeys(TICK_PHASES, 0)
+        self.ticks = 0
+        # (start, end) of the span that closed last, perf_counter seconds:
+        # the harvest reuses its wait's stamps for the per-request spans
+        self.last = (0.0, 0.0)
+        # (phase being charged, since when); a fresh tuple at every switch,
+        # which is what lets snapshot() detect a switch under its feet
+        self._open = None
+
+    def _switch(self, phase, now: float):
+        cur = self._open
+        if cur is not None:
+            self.seconds[cur[0]] += now - cur[1]
+        self._open = None if phase is None else (phase, now)
+
+    def start(self):
+        """The tick thread's loop begins: the clock runs from here."""
+        self._switch("other", time.perf_counter())
+
+    def stop(self):
+        """The loop ended: close the open phase, stop the clock."""
+        self._switch(None, time.perf_counter())
+
+    def _annotation(self, name: str, **args):
+        return profile_span(name, **args) if self.profile else _NO_SPAN
+
+    @contextlib.contextmanager
+    def tick(self):
+        """One loop iteration: the ``mst.tick`` span, whose ``pc`` is this
+        process's ``perf_counter()`` at entry — the flight recorder's
+        timebase, so a ``/admin/trace/dump`` lines up with a profiler
+        capture by one subtraction."""
+        with self._annotation(TICK_SPAN, pc=time.perf_counter()):
+            self.ticks += 1
+            yield
+
+    @contextlib.contextmanager
+    def span(self, phase: str, **args):
+        """Charge the enclosed time to ``phase``; ``args`` (cause, shared
+        identifiers) go on the profiler span and cost nothing otherwise."""
+        with self._annotation(_PHASE_SPAN[phase], **args):
+            cur = self._open
+            outer = cur[0] if cur is not None else "other"
+            t_in = time.perf_counter()
+            self._switch(phase, t_in)
+            self.entries[phase] += 1
+            try:
+                yield
+            finally:
+                t_out = time.perf_counter()
+                self._switch(outer, t_out)
+                self.last = (t_in, t_out)
+
+    def snapshot(self) -> dict:
+        """``{"ticks", "seconds": {phase: s}, "entries": {phase: n}}`` with
+        the open phase's elapsed part included, so two snapshots bracket a
+        window exactly. Lock-free: re-read when the tick thread switched
+        phases meanwhile."""
+        for _ in range(8):
+            cur = self._open
+            seconds = dict(self.seconds)
+            if self._open is cur:
+                break
+        if cur is not None:
+            seconds[cur[0]] += max(0.0, time.perf_counter() - cur[1])
+        return {"ticks": self.ticks, "seconds": seconds,
+                "entries": dict(self.entries)}

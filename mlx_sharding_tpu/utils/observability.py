@@ -150,6 +150,51 @@ class Histogram:
         lines.append(f"{family}_count {snap['count']}")
 
 
+def sum_counter_dicts(per: list) -> dict:
+    """Key-wise sum of (nested) dicts of counters — how ReplicaSet and
+    DisaggCoordinator fold their batchers' ``tick_phase_stats()``."""
+    out: dict = {}
+    for d in per:
+        for k, v in d.items():
+            if isinstance(v, dict):
+                out[k] = sum_counter_dicts([out.get(k, {}), v])
+            else:
+                out[k] = out.get(k, 0) + v
+    return out
+
+
+def _render_tick_phases(lines: list, t: dict):
+    """The scheduler tick's cumulative account (``tick_phase_stats()``):
+    where its time went, and what became of the decode blocks it
+    dispatched. All counters: a reader takes the delta over its window."""
+
+    def labelled(family: str, label: str, values: dict, fmt: str = "{}"):
+        for k in sorted(values):
+            lines.append(f'{family}{{{label}="{k}"}} ' + fmt.format(values[k]))
+
+    lines.append("# TYPE mst_tick_phase_seconds_total counter")
+    labelled("mst_tick_phase_seconds_total", "phase", t["phase_seconds"],
+             "{:.6f}")
+    lines.append("# TYPE mst_tick_phase_total counter")
+    labelled("mst_tick_phase_total", "phase", t["phase_entries"])
+    lines += [
+        "# TYPE mst_ticks_total counter",
+        f"mst_ticks_total {t['ticks']}",
+        "# TYPE mst_decode_blocks_dispatched_total counter",
+        f"mst_decode_blocks_dispatched_total {t['blocks_dispatched']}",
+        "# TYPE mst_decode_blocks_harvested_total counter",
+        f"mst_decode_blocks_harvested_total {t['blocks_harvested']}",
+        "# TYPE mst_decode_positions_computed_total counter",
+        f"mst_decode_positions_computed_total {t['positions_computed']}",
+        "# TYPE mst_decode_tokens_emitted_total counter",
+        f"mst_decode_tokens_emitted_total {t['tokens_emitted']}",
+        "# TYPE mst_decode_tokens_dropped_total counter",
+    ]
+    labelled("mst_decode_tokens_dropped_total", "reason", t["tokens_dropped"])
+    lines.append("# TYPE mst_pipeline_drains_total counter")
+    labelled("mst_pipeline_drains_total", "reason", t["drains"])
+
+
 def _render_spec_family(lines: list, spec: dict):
     """Append the adaptive-speculation gauge family from a ``spec_stats()``
     dict: whether this generator drafts at all, how wide, and whether it
@@ -431,14 +476,12 @@ class ServingMetrics:
                             ]
                     kv = getattr(b, "kv_read_stats", lambda: None)()
                     if kv is not None:
-                        path, last_tick, total_bytes = kv
+                        path, _last_tick, total_bytes = kv
                         lines += [
                             # 1 = ragged in-place paged attention, 0 = the
                             # gather/scatter path — which kernel decode is on
                             "# TYPE mst_paged_attention_ragged gauge",
                             f"mst_paged_attention_ragged {int(path == 'ragged')}",
-                            "# TYPE mst_kv_bytes_read_last_tick gauge",
-                            f"mst_kv_bytes_read_last_tick {last_tick}",
                             "# TYPE mst_kv_bytes_read_total counter",
                             f"mst_kv_bytes_read_total {total_bytes}",
                         ]
@@ -466,24 +509,14 @@ class ServingMetrics:
                     tick = getattr(b, "tick_timing_stats", lambda: None)()
                     if tick is not None:
                         # which run-loop the batcher is on (1 = double-buffered
-                        # async pipeline, 0 = classic dispatch-then-harvest) and
-                        # where each tick's wall time went: blocked on the
-                        # harvest device_get vs. doing host-side scheduling work
-                        path = tick["path"]
+                        # async pipeline, 0 = classic dispatch-then-harvest)
                         lines += [
                             "# TYPE mst_sched_async gauge",
-                            f"mst_sched_async {int(path == 'async')}",
-                            "# TYPE mst_tick_host_ms gauge",
-                            f'mst_tick_host_ms{{path="{path}"}} '
-                            f"{tick['host_ms_last']:.3f}",
-                            "# TYPE mst_tick_device_blocked_ms gauge",
-                            f'mst_tick_device_blocked_ms{{path="{path}"}} '
-                            f"{tick['device_blocked_ms_last']:.3f}",
-                            # resume-path import stall: ~0 when prefetch staged
-                            # the pages, the full host→device marshal on demand
-                            f'mst_tick_device_blocked_ms{{path="kv_import"}} '
-                            f"{tick.get('kv_import_ms_last', 0.0):.3f}",
+                            f"mst_sched_async {int(tick['path'] == 'async')}",
                         ]
+                    phases = getattr(b, "tick_phase_stats", lambda: None)()
+                    if phases is not None:
+                        _render_tick_phases(lines, phases)
                     spec = getattr(b, "spec_stats", lambda: None)()
                     if spec is not None:
                         _render_spec_family(lines, spec)
@@ -1015,9 +1048,25 @@ _HELP = {
     "mst_disagg_handoff_ms":
         "Prefill-to-decode KV handoff latency, milliseconds.",
     "mst_decode_tokens_per_second": "Per-request decode rate summary.",
-    "mst_tick_host_ms": "Host-side scheduler work per tick, ms.",
-    "mst_tick_device_blocked_ms":
-        "Per-tick wall time blocked on the device, ms.",
+    "mst_tick_phase_seconds_total":
+        "Scheduler tick thread wall time by phase, seconds; the phases "
+        "partition the thread's time (harvest_wait = blocked on the device).",
+    "mst_tick_phase_total": "Times the tick entered each phase.",
+    "mst_ticks_total": "Scheduler loop iterations.",
+    "mst_decode_blocks_dispatched_total": "Plain decode blocks dispatched.",
+    "mst_decode_blocks_harvested_total":
+        "Plain decode blocks whose tokens reached the host.",
+    "mst_decode_positions_computed_total":
+        "Token positions dispatched decode blocks compute (steps x rows).",
+    "mst_decode_tokens_emitted_total":
+        "Decode-block tokens delivered to their streams.",
+    "mst_decode_tokens_dropped_total":
+        "Computed positions no stream received: slot_finished (past the "
+        "last token of a stream that reached max_tokens), cancelled (the "
+        "slot was given up earlier: consumer gone, preempted) or "
+        "abandoned_block (futures dropped).",
+    "mst_pipeline_drains_total":
+        "Pipeline drains (a block was in flight at a quiesce), by call site.",
     "mst_faults_armed":
         "Currently armed fault-injection sites (should be 0 in prod).",
     "mst_faults_malformed_total":

@@ -241,8 +241,11 @@ def test_cold_spill_prefetch_resume_exact(residency_env, async_sched):
         assert s["hit_rate"] > 0.0
         total, in_use, _ = batcher.page_stats()
         assert in_use == 0 and s["bytes_in_use"] == 0
-        # demand/prefetch wait time is folded into the tick gauges
-        assert "kv_import_ms_last" in batcher.tick_timing_stats()
+        # the resume's import is a tick phase of its own: entered, timed
+        phases = batcher.tick_phase_stats()
+        assert phases["phase_entries"]["kv_import"] > 0
+        assert phases["phase_seconds"]["kv_import"] > 0.0
+        assert batcher.tick_timing_stats()["kv_import_s_total"] > 0.0
     finally:
         batcher.close()
 
@@ -321,21 +324,27 @@ def test_cancel_while_parked_reaps_cleanly(residency_env):
         gen = batcher.generate_step([9, 4, 4, 6], max_tokens=40)
         next(gen)  # first token, then stop pulling: the slot goes cold
         deadline = time.monotonic() + 90
+        # wait until the request IS parked: cold_spills counts the decision,
+        # a moment before the slot is suspended and the request is on the
+        # parked list — a cancel in that moment is the slot reap's case, not
+        # this test's (and "nothing parked, nothing in the tier" is then
+        # true before the spill as well as after the reap)
         while time.monotonic() < deadline:
-            if batcher.spill_stats()["cold_spills"] > 0:
-                break
-            time.sleep(0.02)
-        assert batcher.spill_stats()["cold_spills"] > 0
-        gen.close()  # cancel the parked stream
-        while time.monotonic() < deadline:
-            s = batcher.spill_stats()
-            if s["parked"] == 0 and s["bytes_in_use"] == 0:
+            if batcher.spill_stats()["parked"] > 0:
                 break
             time.sleep(0.02)
         s = batcher.spill_stats()
-        assert s["parked"] == 0 and s["bytes_in_use"] == 0
-        total, in_use, _ = batcher.page_stats()
-        assert in_use == 0
+        assert s["cold_spills"] > 0 and s["parked"] == 1
+        gen.close()  # cancel the parked stream
+
+        def drained():
+            s = batcher.spill_stats()
+            return (s["parked"] == 0 and s["bytes_in_use"] == 0
+                    and batcher.page_stats()[1] == 0)
+
+        while time.monotonic() < deadline and not drained():
+            time.sleep(0.02)
+        assert drained()
     finally:
         batcher.close()
 

@@ -99,10 +99,27 @@ def test_metrics_expose_batcher_slots():
     assert "mst_batch_slots" not in ServingMetrics().render()
 
 
+def _fake_tick_phase_stats():
+    from mlx_sharding_tpu.tracing import TICK_PHASES
+
+    seconds = dict.fromkeys(TICK_PHASES, 0.0)
+    seconds.update(harvest_wait=9.5, emit=0.25, kv_import=2.125)
+    entries = dict.fromkeys(TICK_PHASES, 0)
+    entries.update(harvest_wait=7, emit=7, kv_import=1)
+    return {
+        "ticks": 9, "phase_seconds": seconds, "phase_entries": entries,
+        "blocks_dispatched": 8, "blocks_harvested": 7, "blocks_abandoned": 0,
+        "positions_computed": 128, "tokens_emitted": 100,
+        "tokens_dropped": {"slot_finished": 12, "abandoned_block": 0},
+        "drains": {"admit": 2, "idle": 1},
+    }
+
+
 def test_metrics_expose_tick_timing():
     """/metrics reports the scheduler path (sync vs async tick pipeline)
-    and the per-tick host/device-blocked split (tick_timing_stats()
-    contract)."""
+    and the tick's cumulative account: seconds and entries per phase,
+    blocks dispatched and harvested, positions computed against tokens
+    emitted and dropped, pipeline drains (tick_phase_stats() contract)."""
     from mlx_sharding_tpu.utils.observability import ServingMetrics
 
     class _FakeBatcher:
@@ -112,17 +129,28 @@ def test_metrics_expose_tick_timing():
         def tick_timing_stats(self):
             return {
                 "path": "async",
-                "host_ms_last": 1.25,
-                "device_blocked_ms_last": 0.5,
                 "host_ms_avg": 1.0,
                 "device_blocked_ms_avg": 0.75,
                 "ticks": 7,
             }
 
+        def tick_phase_stats(self):
+            return _fake_tick_phase_stats()
+
     text = ServingMetrics(batcher_fn=lambda: _FakeBatcher()).render()
     assert "mst_sched_async 1" in text
-    assert 'mst_tick_host_ms{path="async"} 1.250' in text
-    assert 'mst_tick_device_blocked_ms{path="async"} 0.500' in text
+    assert 'mst_tick_phase_seconds_total{phase="harvest_wait"} 9.500000' in text
+    assert 'mst_tick_phase_total{phase="emit"} 7' in text
+    assert "mst_ticks_total 9" in text
+    assert "mst_decode_blocks_dispatched_total 8" in text
+    assert "mst_decode_blocks_harvested_total 7" in text
+    assert "mst_decode_positions_computed_total 128" in text
+    assert "mst_decode_tokens_emitted_total 100" in text
+    assert 'mst_decode_tokens_dropped_total{reason="slot_finished"} 12' in text
+    assert 'mst_pipeline_drains_total{reason="admit"} 2' in text
+    # the one-tick gauges are gone: nothing could read them soundly
+    assert "mst_tick_host_ms" not in text
+    assert "mst_tick_device_blocked_ms" not in text
 
     class _SyncBatcher(_FakeBatcher):
         def tick_timing_stats(self):
@@ -130,15 +158,14 @@ def test_metrics_expose_tick_timing():
 
     text = ServingMetrics(batcher_fn=lambda: _SyncBatcher()).render()
     assert "mst_sched_async 0" in text
-    assert 'mst_tick_host_ms{path="sync"} 1.250' in text
 
     class _NoTickBatcher:
         def stats(self):
             return (2, 1, 0)
 
-    # a batcher without the accessor (or a plain fake) emits no tick gauges
+    # a batcher without the accessors (or a plain fake) emits no tick families
     text = ServingMetrics(batcher_fn=lambda: _NoTickBatcher()).render()
-    assert "mst_tick_host_ms" not in text
+    assert "mst_tick_phase" not in text
     assert "mst_sched_async" not in text
 
 def test_metrics_expose_kv_residency_and_prefetch():
@@ -167,11 +194,13 @@ def test_metrics_expose_kv_residency_and_prefetch():
 
         def tick_timing_stats(self):
             return {
-                "path": "async", "host_ms_last": 1.0,
-                "device_blocked_ms_last": 0.5, "host_ms_avg": 1.0,
+                "path": "async", "host_ms_avg": 1.0,
                 "device_blocked_ms_avg": 0.5, "ticks": 3,
-                "kv_import_ms_last": 2.125,
+                "kv_import_s_total": 2.125,
             }
+
+        def tick_phase_stats(self):
+            return _fake_tick_phase_stats()
 
     text = ServingMetrics(batcher_fn=lambda: _FakeBatcher()).render()
     assert "mst_kv_spill_cold_total 5" in text
@@ -185,7 +214,7 @@ def test_metrics_expose_kv_residency_and_prefetch():
     assert "mst_kv_prefetch_hits_total 3" in text
     assert "mst_kv_prefetch_demand_total 1" in text
     assert "mst_kv_prefetch_faults_total 1" in text
-    assert 'mst_tick_device_blocked_ms{path="kv_import"} 2.125' in text
+    assert 'mst_tick_phase_seconds_total{phase="kv_import"} 2.125000' in text
 
     class _LegacySpill(_FakeBatcher):
         # a ReplicaSet aggregation that predates the residency keys
@@ -198,15 +227,13 @@ def test_metrics_expose_kv_residency_and_prefetch():
                 del s[k]
             return s
 
-        def tick_timing_stats(self):
-            t = _FakeBatcher.tick_timing_stats(self)
-            del t["kv_import_ms_last"]
-            return t
+        def tick_phase_stats(self):  # ... and the tick's phase account
+            return None
 
     text = ServingMetrics(batcher_fn=lambda: _LegacySpill()).render()
     assert "mst_kv_spill_cold_total 0" in text
     assert "mst_kv_prefetch_enabled 0" in text
-    assert 'mst_tick_device_blocked_ms{path="kv_import"} 0.000' in text
+    assert "mst_tick_phase_seconds_total" not in text
 
 
 def _rich_metrics():
@@ -230,10 +257,12 @@ def _rich_metrics():
             return (2, 1, 3)
 
         def tick_timing_stats(self):
-            return {"path": "async", "host_ms_last": 1.0,
-                    "device_blocked_ms_last": 0.5, "host_ms_avg": 1.0,
+            return {"path": "async", "host_ms_avg": 1.0,
                     "device_blocked_ms_avg": 0.5, "ticks": 3,
-                    "kv_import_ms_last": 2.0}
+                    "kv_import_s_total": 2.0}
+
+        def tick_phase_stats(self):
+            return _fake_tick_phase_stats()
 
         def spill_stats(self):
             return {"enabled": True, "spills": 4, "spill_hits": 3,

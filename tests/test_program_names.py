@@ -1,0 +1,203 @@
+"""Every jitted program of the served path has a name of its own (it is
+``jit_<name>`` in a profile and in a compile log, and the benchmark finds
+the decode block and the prefill chunk by it), and the served programs of a
+DeepSeek-V2 carry the ``mst.*`` scope vocabulary of ``tracing.MODEL_SCOPES``
+at their layer boundaries — and no ``mst.*`` name outside it."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+pytestmark = pytest.mark.quick
+
+from mlx_sharding_tpu import tracing
+from mlx_sharding_tpu.config import DeepseekV2Config, LlamaConfig
+from mlx_sharding_tpu.models.deepseek_v2 import DeepseekV2Model
+from mlx_sharding_tpu.models.llama import LlamaModel
+from mlx_sharding_tpu.parallel.mesh import pipeline_mesh
+from mlx_sharding_tpu.parallel.pipeline import PipelineEngine
+from mlx_sharding_tpu.scheduler import ContinuousBatcher
+from tests.helpers import hard_timeout
+
+TINY = dict(vocab_size=256, hidden_size=32, intermediate_size=64,
+            num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2)
+
+
+class JitLog:
+    """Stands in for ``jax.jit`` and records what was handed to it."""
+
+    def __init__(self, real):
+        self.real = real
+        self.seen = []  # (name, file, first line, id of the function)
+
+    def __call__(self, fun, *args, **kwargs):
+        code = getattr(fun, "__code__", None) or getattr(
+            getattr(fun, "__func__", None), "__code__", None)
+        if code is not None and "mlx_sharding_tpu" in code.co_filename:
+            self.seen.append((fun.__name__, code.co_filename,
+                              code.co_firstlineno, id(fun)))
+        return self.real(fun, *args, **kwargs)
+
+
+@hard_timeout(420)
+def test_every_served_program_has_a_name_of_its_own(monkeypatch):
+    """Build the served path with every option that adds programs — paged
+    pool, a draft engine, log-probabilities, the solo generator — and run
+    it, so that the lazily built programs exist too."""
+    log = JitLog(jax.jit)
+    monkeypatch.setattr(jax, "jit", log)
+    model = LlamaModel(LlamaConfig(**TINY))
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    mesh = pipeline_mesh(1)
+    kw = dict(microbatches=2, max_seq=64, cache_dtype=jnp.float32,
+              prefill_chunk=8)
+    target = PipelineEngine(model, params, mesh, pool_pages=20, page_size=8, **kw)
+    draft = PipelineEngine(model, params, mesh, **kw)
+    spec = ContinuousBatcher(target, decode_block=3, draft_engine=draft,
+                             spec_k=3, async_sched="off")
+    ngram = ContinuousBatcher(target, decode_block=3, draft="ngram")
+    try:
+        for batcher in (spec, ngram):
+            assert len(list(batcher.generate_step([3, 4, 5, 3, 4, 5, 3], max_tokens=9))) == 9
+            # log-probabilities pause speculation: the plain block, its
+            # _lp variant and the draft's replay
+            assert len(list(batcher.generate_step(
+                [3, 4, 5], max_tokens=5, want_logprobs=True))) == 5
+    finally:
+        spec.close()
+        ngram.close()
+    solo = PipelineEngine(model, params, mesh, microbatches=1, **{
+        k: v for k, v in kw.items() if k != "microbatches"})
+    assert len(list(solo.generate_step([3, 4, 5], max_tokens=12))) == 12
+    assert len(list(solo.generate_step([3, 4, 5], max_tokens=12, want_logprobs=True))) == 12
+
+    by_name: dict = {}
+    for name, file, line, ident in log.seen:
+        assert name != "<lambda>", f"anonymous program at {file}:{line}"
+        by_name.setdefault(name, set()).add((file, line))
+    shared = {n: sorted(w) for n, w in by_name.items() if len(w) > 1}
+    assert not shared, f"one name, several programs: {shared}"
+    # one function jitted under two names is two programs (block, block_lp):
+    # fine; one function OBJECT jitted twice under one name is one program
+    # built twice (two engines): fine too. What the benchmark finds by name:
+    for name in ("block", "block_lp", "decode_step", "prefill_chunk",
+                 "first_sample", "row_set", "sp_set", "set_last",
+                 "forward_sample", "forward_logits", "solo_block",
+                 "solo_block_lp", "spec_propose_k3", "spec_verify_k3",
+                 "spec_replay_k3", "export_pool_pages", "import_pool_pages",
+                 "rewind_slot_offset"):
+        assert name in by_name, f"{name} missing from {sorted(by_name)}"
+    assert any(n.startswith("spec_verify_ngram_k") for n in by_name)
+    assert "step" not in by_name and "prog" not in by_name
+
+
+def _scopes_in(text: str) -> set:
+    return set(re.findall(r"mst\.[A-Za-z0-9_.]*[A-Za-z0-9_]", text))
+
+
+@pytest.fixture(scope="module")
+def dsv2_batcher():
+    cfg = DeepseekV2Config(
+        vocab_size=128, hidden_size=32, intermediate_size=64,
+        moe_intermediate_size=16, num_hidden_layers=3,
+        num_attention_heads=4, num_key_value_heads=4, kv_lora_rank=16,
+        q_lora_rank=None, qk_rope_head_dim=8, qk_nope_head_dim=16,
+        v_head_dim=12, n_routed_experts=4, n_shared_experts=1,
+        num_experts_per_tok=2, first_k_dense_replace=1,
+        mla_cache_mode="compressed",
+    )
+    model = DeepseekV2Model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    eng = PipelineEngine(
+        model, params, pipeline_mesh(1), microbatches=2, max_seq=64,
+        cache_dtype=jnp.float32, prefill_chunk=8, pool_pages=10, page_size=8,
+        paged_attention="ragged",
+    )
+    batcher = ContinuousBatcher(eng, decode_block=3)
+    yield batcher
+    batcher.close()
+
+
+@hard_timeout(420)
+def test_served_deepseek_programs_carry_the_scope_vocabulary(dsv2_batcher):
+    b, eng = dsv2_batcher, dsv2_batcher.engine
+    assert len(list(b.generate_step([3, 4, 5, 6], max_tokens=5))) == 5
+    lowered = b._decode_block_prog(False).lower(
+        eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+        b.last_tok, b.cache, b.active, b.recent, b.keys, b.sp, b.rep_sizes,
+        b.table,
+    )
+    block = lowered.as_text(debug_info=True)
+    prefill = eng.prefill_slot().lower(
+        eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+        jnp.zeros((1, 8), jnp.int32), jnp.asarray(0, jnp.int32), b.cache,
+        jnp.asarray(8, jnp.int32), b.table,
+    ).as_text(debug_info=True)
+    assert "module @jit_block " in block
+    assert "module @jit_prefill_chunk " in prefill
+    vocabulary = set(tracing.MODEL_SCOPES)
+    for text in (block, prefill):
+        assert _scopes_in(text) <= vocabulary, _scopes_in(text) - vocabulary
+    layers = {"mst.embed", "mst.attn.qkv", "mst.attn.kv_write", "mst.attn.core",
+              "mst.moe.router", "mst.moe.experts", "mst.moe.shared",
+              "mst.mlp.dense", "mst.norm", "mst.kv_pool.regroup", "mst.head"}
+    # decode: 2 rows take the gather path; the sampler is in the block
+    assert _scopes_in(block) == layers | {"mst.moe.experts.matmul", "mst.sample"}
+    # prefill: 8 rows <= GATHER_PATH_MAX_TOKENS still gather; the first
+    # token's sampler is a program of its own (first_sample)
+    assert _scopes_in(prefill) == layers | {"mst.moe.experts.matmul"}
+    # in the compiled program an operation's op_name holds the whole path,
+    # and its scope is the deepest mst.* component: the layers' scopes sit
+    # inside the scan's (what the trace reader, benchmarks/scope_reduce.py,
+    # goes by)
+    compiled = lowered.compile().as_text()
+    assert re.search(
+        r'op_name="jit\(block\)/[^"]*mst\.kv_pool\.regroup/[^"]*mst\.attn\.core/',
+        compiled)
+    assert _scopes_in(compiled) <= vocabulary
+
+
+def test_packed_experts_and_the_scan_path_have_their_scopes():
+    """The two expert paths the tiny dense model above does not take."""
+    from mlx_sharding_tpu.ops import moe
+
+    x = jnp.ones((20, 32), jnp.float32)  # > GATHER_PATH_MAX_TOKENS rows
+    w = jnp.ones((4, 32, 16), jnp.float32)
+    wd = jnp.ones((4, 16, 32), jnp.float32)
+    weights = jnp.full((20, 2), 0.5, jnp.float32)
+    idx = jnp.zeros((20, 2), jnp.int32)
+    scan = jax.jit(moe.apply_experts).lower(x, weights, idx, w, w, wd).as_text(
+        debug_info=True)
+    assert {"mst.moe.experts", "mst.moe.experts.scan"} <= _scopes_in(scan)
+
+    def packed(out_dim, in_dim):  # MLX orientation (E, out, in * bits / 32)
+        return {"q": jnp.zeros((4, out_dim, in_dim // 8), jnp.uint32),
+                "scales": jnp.ones((4, out_dim, in_dim // 64), jnp.float32),
+                "biases": jnp.zeros((4, out_dim, in_dim // 64), jnp.float32)}
+
+    x8 = jnp.ones((8, 64), jnp.float32)
+    gather = jax.jit(moe.apply_experts).lower(
+        x8, weights[:8], idx[:8], packed(128, 64), packed(128, 64), packed(64, 128),
+    ).as_text(debug_info=True)
+    assert {"mst.moe.experts.gather_dequant", "mst.moe.experts.matmul"} <= _scopes_in(gather)
+    assert _scopes_in(scan) | _scopes_in(gather) <= set(tracing.MODEL_SCOPES)
+
+
+def test_no_scope_outside_the_vocabulary_in_the_source():
+    """Every ``mst.*`` scope literal in the package is in the vocabulary,
+    and every name of the vocabulary is used somewhere."""
+    from pathlib import Path
+
+    root = Path(tracing.__file__).parent
+    used = set()
+    for path in root.rglob("*.py"):
+        if path.name == "tracing.py":
+            continue
+        used |= set(re.findall(r'named_scope\("(mst\.[^"]+)"\)', path.read_text()))
+    assert used == set(tracing.MODEL_SCOPES)
+    assert len(set(tracing.MODEL_SCOPES)) == len(tracing.MODEL_SCOPES)
+    assert set(tracing.TICK_PHASES) >= {"harvest_wait", "idle_wait", "other"}
+    assert tracing.phase_span_name("dispatch") == "mst.decode_block"
+    assert tracing.phase_span_name("emit") == "mst.emit"

@@ -1,0 +1,329 @@
+"""The scheduler tick accounts for its time and its decode blocks
+(``ContinuousBatcher.tick_phase_stats``): phases partition the tick
+thread's wall time; every position a dispatched block computes is emitted,
+dropped for a reason, or still in flight; every dispatched block is
+harvested, abandoned, or in flight; every pipeline drain is counted under
+its call site; and ``/metrics`` renders the families."""
+
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+pytestmark = pytest.mark.quick
+
+from mlx_sharding_tpu import tracing
+from mlx_sharding_tpu.config import LlamaConfig
+from mlx_sharding_tpu.models.llama import LlamaModel
+from mlx_sharding_tpu.parallel.mesh import pipeline_mesh
+from mlx_sharding_tpu.parallel.pipeline import PipelineEngine
+from mlx_sharding_tpu.replicas import ReplicaSet
+from mlx_sharding_tpu.scheduler import ContinuousBatcher, _InflightBlock
+from mlx_sharding_tpu.testing import faults
+from mlx_sharding_tpu.utils.observability import ServingMetrics
+from tests.helpers import hard_timeout
+
+TINY = dict(vocab_size=256, hidden_size=32, intermediate_size=64,
+            num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2)
+BLOCK = 4
+
+
+@pytest.fixture(scope="module")
+def engine():
+    model = LlamaModel(LlamaConfig(**TINY))
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    return PipelineEngine(
+        model, params, pipeline_mesh(1), microbatches=3, max_seq=64,
+        cache_dtype=jnp.float32, prefill_chunk=8, pool_pages=20, page_size=8,
+    )
+
+
+@pytest.fixture(autouse=True)
+def _disarm():
+    yield
+    faults.disarm()
+
+
+def _drive(batcher):
+    """A join while another stream decodes, finishes in the middle of a
+    block (41 and 26 decode tokens are no multiples of BLOCK) and a cancel."""
+    done, decoding = {}, threading.Event()
+
+    def run(i, prompt, n):
+        done[i] = []
+        for t, _ in batcher.generate_step(prompt, max_tokens=n):
+            done[i].append(t)
+            if len(done[i]) == 3:
+                decoding.set()
+
+    threads = [threading.Thread(target=run, args=(0, [3, 17, 42], 42))]
+    threads[0].start()
+    assert decoding.wait(timeout=60)  # the second joins a decoding batch
+    threads.append(threading.Thread(target=run, args=(1, [9, 1, 4, 7], 27)))
+    threads[1].start()
+    # the third is cancelled after its second token: the consumer walks
+    # away and the slot is reaped on a later tick
+    gen = batcher.generate_step([5, 6, 2, 8, 8], max_tokens=40)
+    next(gen), next(gen)
+    gen.close()
+    for th in threads:
+        th.join(timeout=120)
+        assert not th.is_alive()
+    assert len(done[0]) == 42 and len(done[1]) == 27
+    _settle(batcher)
+
+
+def _settle(batcher):
+    """Wait for the reap and the last drain to land: no slot active and no
+    block between dispatch and harvest (a block the tick has taken out of
+    ``_inflight`` to harvest is in neither place for a moment)."""
+    def busy():
+        s = batcher.tick_phase_stats()
+        return batcher.stats()[1] or batcher._inflight is not None or (
+            s["blocks_dispatched"] != s["blocks_harvested"] + s["blocks_abandoned"]
+        )
+
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline and busy():
+        time.sleep(0.02)
+    assert not busy()
+
+
+def _in_flight(batcher):
+    inf = batcher._inflight
+    return (1, inf.positions) if isinstance(inf, _InflightBlock) else (0, 0)
+
+
+def _assert_identities(batcher):
+    s = batcher.tick_phase_stats()
+    blocks, positions = _in_flight(batcher)
+    assert s["blocks_dispatched"] == (
+        s["blocks_harvested"] + s["blocks_abandoned"] + blocks
+    )
+    assert s["positions_computed"] == (
+        s["tokens_emitted"] + sum(s["tokens_dropped"].values()) + positions
+    )
+    return s
+
+
+@hard_timeout(240)
+@pytest.mark.parametrize("mode", ["on", "off"])
+def test_blocks_and_tokens_are_all_accounted_for(engine, mode):
+    batcher = ContinuousBatcher(engine, decode_block=BLOCK, async_sched=mode)
+    try:
+        t_start = time.perf_counter()
+        _drive(batcher)
+        s = _assert_identities(batcher)
+        wall = time.perf_counter() - t_start
+        # 42 + 27 tokens, less the first token of each (prefill's), plus
+        # whatever the cancelled stream decoded before it was reaped
+        assert s["tokens_emitted"] >= 41 + 26 + 1
+        assert s["blocks_harvested"] >= 3
+        # a slot that finishes inside a block leaves the rest of it unread
+        assert s["tokens_dropped"]["slot_finished"] > 0
+        # ... and so does a consumer that walks away, under its own reason:
+        # the block in flight when its slot is reaped (none in sync mode)
+        assert (s["tokens_dropped"]["cancelled"] > 0) == (mode == "on")
+        assert s["tokens_dropped"]["abandoned_block"] == 0
+        assert s["positions_computed"] % BLOCK == 0
+        assert s["phase_entries"]["dispatch"] == s["blocks_dispatched"]
+        assert s["phase_entries"]["harvest_wait"] == s["blocks_harvested"]
+        assert s["phase_entries"]["prefill_chunk"] >= 3
+        assert s["phase_entries"]["idle_wait"] >= 1
+        assert s["ticks"] >= s["blocks_dispatched"]
+        # the phases partition the tick thread's time: they sum to the wall
+        # time since its loop began (the thread starts with the first
+        # request), whatever the mix of work and waiting was
+        assert sum(s["phase_seconds"].values()) == pytest.approx(wall, rel=0.05)
+        assert set(s["phase_seconds"]) == set(tracing.TICK_PHASES)
+        if mode == "on":
+            # joins and prefill chunks drain the lookahead block, and the
+            # block dispatched past the last finish drains at idle
+            d = s["drains"]
+            assert d["admit"] + d["prefilling"] >= 1 and d["idle"] >= 1
+            assert d["cold"] == d["growth"] == d["migrate"] == 0
+        else:
+            assert sum(s["drains"].values()) == 0  # nothing ever in flight
+        # the derived averages keep their keys (bench.py reads them)
+        t = batcher.tick_timing_stats()
+        assert set(t) == {"path", "host_ms_avg", "device_blocked_ms_avg",
+                          "ticks", "kv_import_s_total"}
+        assert t["ticks"] == s["blocks_harvested"]
+        batcher.reset_tick_timing()
+        assert batcher.tick_timing_stats()["ticks"] == 0
+        assert batcher.tick_phase_stats()["blocks_harvested"] == t["ticks"]
+    finally:
+        batcher.close()
+    s = batcher.tick_phase_stats()
+    assert s["blocks_dispatched"] == s["blocks_harvested"] + s["blocks_abandoned"]
+
+
+def _stream(batcher, prompt, n, out, started=None):
+    """Consume one stream into ``out``; a stream ended early (migrated,
+    failed) leaves its exception there instead."""
+    try:
+        for t, _ in batcher.generate_step(prompt, max_tokens=n):
+            out.append(t)
+            if started is not None and len(out) == 3:
+                started.set()
+    except Exception as e:  # noqa: BLE001 — recorded for the caller
+        out.append(e)
+
+
+@hard_timeout(240)
+def test_growth_drain_is_counted_under_its_call_site(engine):
+    """Over-commit: 3 x (7 + 50) tokens want 24 pages of the pool's 20, so
+    growth has to preempt, and only a drained pipeline may."""
+    batcher = ContinuousBatcher(engine, decode_block=BLOCK, async_sched="on",
+                                overcommit=True)
+    try:
+        outs = [[], [], []]
+        threads = [
+            threading.Thread(
+                target=_stream,
+                args=(batcher, [3 + i, 17, 42, 5, 9, 11, 2], 50, outs[i]))
+            for i in range(3)
+        ]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+            assert not th.is_alive()
+        assert all(len(o) == 50 for o in outs)
+        _settle(batcher)
+        s = _assert_identities(batcher)
+        assert s["drains"]["growth"] >= 1
+        assert s["drains"]["migrate"] == s["drains"]["cold"] == 0
+    finally:
+        batcher.close()
+
+
+@hard_timeout(240)
+def test_migrate_drain_is_counted_under_its_call_site(engine):
+    """A stream in steady decode always has its lookahead block in flight
+    between two ticks: migrate_out has exactly that one to drain."""
+    batcher = ContinuousBatcher(engine, decode_block=BLOCK, async_sched="on")
+    try:
+        out, started = [], threading.Event()
+        th = threading.Thread(
+            target=_stream, args=(batcher, [3, 17, 42], 55, out, started))
+        th.start()
+        assert started.wait(timeout=60)
+        batcher.migrate_out(deadline=30.0)
+        th.join(timeout=120)
+        assert not th.is_alive()
+        assert isinstance(out[-1], Exception) and len(out) < 56
+        _settle(batcher)
+        s = _assert_identities(batcher)
+        assert s["drains"]["migrate"] == 1
+    finally:
+        batcher.close()
+
+
+@hard_timeout(240)
+def test_failed_harvest_counts_its_blocks_abandoned(engine):
+    """A harvest that dies drops its block, and _fail_all drops the
+    lookahead block dispatched after it: both are abandoned, with their
+    positions, and the identities still hold."""
+    batcher = ContinuousBatcher(engine, decode_block=BLOCK, async_sched="on")
+    try:
+        assert len(list(batcher.generate_step([3, 4, 5], max_tokens=6))) == 6
+        _settle(batcher)  # the leftover lookahead block drains unharmed
+        before = batcher.tick_phase_stats()
+        faults.arm("scheduler.harvest", exc=faults.FaultError, times=1)
+        with pytest.raises(faults.FaultError):
+            list(batcher.generate_step([3, 4, 5], max_tokens=30))
+        faults.disarm()
+        # the consumer hears of the failure while _fail_all is still at work
+        # (it sheds whatever is submitted until it ends): the tick after
+        # next has certainly begun after it
+        seen = batcher.tick_phase_stats()["ticks"]
+        deadline = time.monotonic() + 30
+        while (batcher.tick_phase_stats()["ticks"] < seen + 2
+               and time.monotonic() < deadline):
+            time.sleep(0.02)
+        _settle(batcher)
+        s = _assert_identities(batcher)
+        assert s["blocks_abandoned"] - before["blocks_abandoned"] == 2
+        assert (s["tokens_dropped"]["abandoned_block"]
+                - before["tokens_dropped"]["abandoned_block"]) == 2 * BLOCK
+        # the batcher serves on
+        assert len(list(batcher.generate_step([3, 4, 5], max_tokens=5))) == 5
+        _settle(batcher)
+        _assert_identities(batcher)
+    finally:
+        batcher.close()
+
+
+@hard_timeout(240)
+def test_metrics_render_the_tick_families_summed_over_replicas(engine):
+    batcher = ContinuousBatcher(engine, decode_block=BLOCK, async_sched="on")
+    try:
+        assert len(list(batcher.generate_step([3, 4, 5], max_tokens=9))) == 9
+        fleet = ReplicaSet([batcher, batcher])  # the same account, twice
+        one, both = batcher.tick_phase_stats(), fleet.tick_phase_stats()
+        assert both["positions_computed"] == 2 * one["positions_computed"]
+        assert both["tokens_dropped"]["slot_finished"] == (
+            2 * one["tokens_dropped"]["slot_finished"])
+        assert both["phase_entries"]["dispatch"] == (
+            2 * one["phase_entries"]["dispatch"])
+        text = ServingMetrics(batcher_fn=lambda: batcher).render()
+        for phase in tracing.TICK_PHASES:
+            assert f'mst_tick_phase_seconds_total{{phase="{phase}"}}' in text
+            assert f'mst_tick_phase_total{{phase="{phase}"}}' in text
+        for family in (
+            "mst_ticks_total", "mst_decode_blocks_dispatched_total",
+            "mst_decode_blocks_harvested_total",
+            "mst_decode_positions_computed_total",
+            "mst_decode_tokens_emitted_total",
+        ):
+            assert f"\n{family} " in text and f"# HELP {family} " in text
+        assert f"mst_decode_positions_computed_total {one['positions_computed']}" in text
+        for reason in ("slot_finished", "cancelled", "abandoned_block"):
+            assert f'mst_decode_tokens_dropped_total{{reason="{reason}"}}' in text
+        for reason in ("admit", "prefilling", "cold", "growth", "migrate", "idle"):
+            assert f'mst_pipeline_drains_total{{reason="{reason}"}}' in text
+        for gone in ("mst_tick_host_ms", "mst_tick_device_blocked_ms",
+                     "mst_kv_bytes_read_last_tick"):
+            assert gone not in text
+        assert "mst_kv_bytes_read_total" in text
+    finally:
+        batcher.close()
+
+
+def test_phases_suspend_their_outer_phase_and_leftover_is_other():
+    """The accounting rule on its own, with no batcher: a nested phase
+    takes its time out of the outer one, uncovered time is ``other``, and a
+    snapshot includes the part of the open phase that has passed."""
+    ph = tracing.TickPhases()
+    ph.start()
+    t0 = time.perf_counter()
+    with ph.tick():
+        time.sleep(0.02)  # other
+        with ph.span("admit"):
+            time.sleep(0.02)
+            with ph.span("harvest_wait"):
+                time.sleep(0.03)
+            inner = ph.last
+            time.sleep(0.01)
+        with ph.span("idle_wait"):
+            mid = ph.snapshot()  # taken from inside an open phase
+            time.sleep(0.02)
+    snap = ph.snapshot()
+    wall = time.perf_counter() - t0
+    secs = snap["seconds"]
+    # a partition: nothing lost, nothing counted twice (lower bounds on
+    # each part and the exact sum bound every part from above as well)
+    assert sum(secs.values()) == pytest.approx(wall, abs=5e-3)
+    assert secs["harvest_wait"] >= 0.03 and secs["admit"] >= 0.03
+    assert secs["other"] >= 0.02 and secs["idle_wait"] >= 0.02
+    assert inner[1] - inner[0] == pytest.approx(secs["harvest_wait"], abs=1e-4)
+    assert snap["entries"]["admit"] == snap["entries"]["harvest_wait"] == 1
+    assert snap["ticks"] == 1
+    assert mid["seconds"]["idle_wait"] < secs["idle_wait"] - 0.015
+    ph.stop()
+    frozen = ph.snapshot()
+    time.sleep(0.01)
+    assert ph.snapshot() == frozen  # a stopped clock does not run

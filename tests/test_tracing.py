@@ -413,3 +413,111 @@ def test_admin_trace_endpoints(tmp_path):
     finally:
         srv.shutdown()
         batcher.close()
+
+
+# ------------------------------------------ tick spans on the profiler's clock
+def _host_spans(profile_dir):
+    """``{name: [stats dict, ...]}`` of the ``mst.*`` events on the host
+    plane of the one ``.xplane.pb`` under ``profile_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(str(profile_dir / "**" / "*.xplane.pb"), recursive=True)
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("mst."):
+                    out.setdefault(ev.name, []).append(
+                        dict(ev.stats, _t0=ev.start_ns,
+                             _t1=ev.start_ns + ev.duration_ns))
+    return out
+
+
+@hard_timeout(300)
+def test_trace_profile_puts_tick_spans_on_the_profilers_clock(tmp_path):
+    """``--trace on --trace-profile`` with ``jax.profiler`` open: the host
+    plane holds the tick, its phases and the dispatched block, with the
+    arguments that say what each one was (cause, shared identifiers)."""
+    model = LlamaModel(LlamaConfig(**TINY))
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    tracing.configure("on", profile=True)
+    batcher = _mk_batcher(model, params, 0, async_sched="on")
+    try:
+        assert batcher._trace_profile
+        list(batcher.generate_step([3, 4, 5], max_tokens=4))  # compiles
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        pc0 = time.perf_counter()
+        try:
+            toks = list(batcher.generate_step(
+                [3, 4, 5, 6, 7, 8, 9, 1, 2, 3], max_tokens=11, _trace=tracing.begin("r-prof")))
+        finally:
+            pc1 = time.perf_counter()
+            jax.profiler.stop_trace()
+        assert len(toks) == 11
+    finally:
+        batcher.close()
+    spans = _host_spans(tmp_path)
+    for name in ("mst.tick", "mst.housekeeping", "mst.admit",
+                 "mst.prefill_chunk", "mst.decode_block", "mst.harvest_wait",
+                 "mst.emit"):
+        assert name in spans, sorted(spans)
+    assert "mst.dispatch" not in spans  # the dispatch keeps its old name
+    # mst.tick's pc is this process's perf_counter at the tick's entry: the
+    # flight recorder's clock, on the profiler's timeline
+    pcs = [float(s["pc"]) for s in spans["mst.tick"]]
+    assert pcs == sorted(pcs) and pc0 - 1.0 < pcs[0] and pcs[-1] <= pc1
+    t0s = [s["_t0"] for s in spans["mst.tick"]]
+    assert (pcs[-1] - pcs[0]) == pytest.approx((t0s[-1] - t0s[0]) / 1e9, abs=5e-3)
+    blocks = spans["mst.decode_block"]
+    seqs = [int(s["seq"]) for s in blocks]
+    assert seqs == list(range(seqs[0], seqs[0] + len(seqs)))
+    assert all(int(s["live"]) == 1 and int(s["want_lp"]) == 0 for s in blocks)
+    assert all(1 <= int(s["pages"]) <= 3 for s in blocks)  # 10 + 11 tokens, 8 a page
+    # every wait names the block it waits for
+    assert {int(s["seq"]) for s in spans["mst.harvest_wait"]} <= set(seqs) | {seqs[0] - 1}
+    chunks = spans["mst.prefill_chunk"]
+    assert [(s["rid"], int(s["pos"]), int(s["n_valid"])) for s in chunks] == [
+        ("r-prof", 0, 8), ("r-prof", 8, 2)]
+    # phases nest inside their tick
+    tick_of = lambda s: [t for t in spans["mst.tick"]  # noqa: E731
+                         if t["_t0"] <= s["_t0"] and s["_t1"] <= t["_t1"]]
+    assert all(len(tick_of(s)) == 1 for s in blocks + chunks)
+
+
+@hard_timeout(240)
+def test_trace_off_constructs_no_annotation(monkeypatch):
+    """``--trace off`` (and ``--trace on`` without ``--trace-profile``):
+    the tick never builds a ``TraceAnnotation``; its phase table runs."""
+    built = []
+    monkeypatch.setattr(
+        tracing, "profile_span",
+        lambda name, **args: built.append(name) or __import__("contextlib").nullcontext())
+    model = LlamaModel(LlamaConfig(**TINY))
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    for mode in ("off", "on"):
+        tracing.configure(mode)
+        batcher = _mk_batcher(model, params, 0, async_sched="on")
+        try:
+            assert not batcher._trace_profile
+            assert len(list(batcher.generate_step([3, 4, 5], max_tokens=7))) == 7
+            stats = batcher.tick_phase_stats()
+        finally:
+            batcher.close()
+        assert built == []
+        assert stats["blocks_harvested"] >= 2 and stats["ticks"] >= 2
+        assert stats["phase_seconds"]["harvest_wait"] > 0.0
+    # and with the profile bridge on, the same path does build them
+    tracing.configure("on", profile=True)
+    batcher = _mk_batcher(model, params, 0, async_sched="on")
+    try:
+        list(batcher.generate_step([3, 4, 5], max_tokens=4))
+    finally:
+        batcher.close()
+    assert {"mst.tick", "mst.decode_block", "mst.harvest_wait"} <= set(built)
