@@ -23,7 +23,6 @@ import http.client
 import importlib.util
 import json
 import math
-import os
 import signal
 import socket
 import subprocess
@@ -41,23 +40,43 @@ if str(ROOT) not in sys.path:
 
 from benchmarks import stats, traffic  # noqa: E402
 from benchmarks.config import server_flag  # noqa: E402
+from benchmarks.programs import durations  # noqa: E402
+from benchmarks.reduction import RunFailed, reduce_profile  # noqa: E402
 
 BENCH_DIR = ROOT / "benchmarks"
+LAUNCHER = BENCH_DIR / "launcher.py"  # the tests of the waits put a fake in its place
 WORK = ROOT / ".bench_work"  # git-ignored: model dir, logs, profile
 T_PROCESS_START = time.monotonic()
 
 HEALTH_TIMEOUT_S = 1000.0  # a cold first run compiles and autotunes
 DRAIN_S = 80.0  # after the window, for streams that are still open
-TRACE_S = 20.0  # the profiler's share of a traced window: some twenty decode blocks
+CLIENT_LIMIT_S = 120.0  # a closed-loop client drops its stream at the first chunk past the window
 SAMPLE_HZ = 10.0  # gauges, traced run only
+# The profiler opens at TRACE_FROM of a traced window and stays open until the
+# first of TRACE_S seconds and TRACE_BLOCKS decode blocks harvested since
+# (BLOCKS_COUNTER in the 10 Hz samples; a program without it: the time bound
+# alone). What the stop has to collect and write grows with what the chip ran
+# while it was open, not with the seconds: at the accepted program's 1.34 s
+# a block the 20 s end the trace; a faster program's trace is shorter and
+# holds as much.
+TRACE_FROM = 0.4
+TRACE_S = 20.0
+TRACE_BLOCKS = 16
+BLOCKS_COUNTER = "mst_decode_blocks_harvested_total"
+# Every wait that scales with the trace, seconds: what a chip run of
+# dsv2-lite-q4.decode-sat took at the two bounds above (PR 26; the law over
+# four trace lengths is in PERF.md section 7) times the factor beside it.
+# Past its limit a wait fails the run and names its stage. Each alone, the
+# others as measured, does so inside the 360 s a run may take.
+PROFILE_START_LIMIT_S = 10.0  # 0.07 s measured: only a hang gets here
+PROFILE_STOP_LIMIT_S = 110.0  # 2.0 x 53.8 s (16 blocks in the trace, 29 MB; 3.4 s a block)
+TRACE_REDUCE_LIMIT_S = 30.0  # 5 x 6.0 s (2.9 s + 0.11 s a MB of .xplane.pb)
+SCRAPE_LIMIT_S = 30.0  # one GET of /metrics or /compiles: milliseconds when the server lives
+OBSERVER_LIMIT_S = 2 * SCRAPE_LIMIT_S + 5  # past the window's end: its two last GETs
 
 
 def say(msg: str) -> None:
     print(f"[bench {time.monotonic() - T_PROCESS_START:7.1f}s] {msg}", flush=True)
-
-
-class RunFailed(Exception):
-    pass
 
 
 def load_json(path: Path) -> dict:
@@ -100,7 +119,7 @@ class Launcher:
         self.log_path = work / "launcher.log"
         self._log = open(self.log_path, "w")
         self.proc = subprocess.Popen(
-            [sys.executable, str(BENCH_DIR / "launcher.py"),
+            [sys.executable, str(LAUNCHER),
              "--config", str(config_path), "--seed", str(seed),
              "--port", str(self.port), "--control-port", str(self.control_port),
              "--work", str(work), "--trace", str(trace)],
@@ -117,6 +136,10 @@ class Launcher:
             conn.request(method, path, data, {"Content-Type": "application/json"})
             r = conn.getresponse()
             return r.status, r.read()
+        except TimeoutError:
+            raise RunFailed(f"{path}: no answer after {timeout:g} s") from None
+        except (OSError, http.client.HTTPException) as e:
+            raise RunFailed(f"{path}: {type(e).__name__}: {e}") from None
         finally:
             conn.close()
 
@@ -130,7 +153,7 @@ class Launcher:
         return json.loads(data)
 
     def metrics(self) -> dict:
-        status, data = self.server("GET", "/metrics", timeout=30.0)
+        status, data = self.server("GET", "/metrics", timeout=SCRAPE_LIMIT_S)
         if status != 200:
             raise RunFailed(f"/metrics: HTTP {status}")
         return stats.parse_prometheus(data.decode())
@@ -146,7 +169,7 @@ class Launcher:
             try:
                 if self.server("GET", "/health", timeout=5.0)[0] == 200:
                     return
-            except OSError:
+            except RunFailed:  # not listening yet
                 pass
             time.sleep(0.5)
 
@@ -380,18 +403,57 @@ def stream_request(port: int, req: traffic.Planned, due_abs: float,
 # the measured window
 
 
+class Profiler(threading.Thread):
+    """The launcher's ``jax.profiler``, opened and closed from a thread of
+    its own: the stop collects and writes the whole trace before it answers
+    (tens of seconds), and neither call may hold up the observer's timeline.
+    It opens when the thread starts and closes when ``close`` is set."""
+
+    def __init__(self, launcher: Launcher):
+        super().__init__(name="bench-profiler", daemon=True)
+        self.launcher = launcher
+        self.close = threading.Event()
+        self.t_on = self.t_off = self.stop_s = None
+        self.error = None
+
+    def run(self) -> None:
+        try:
+            self.launcher.control("POST", "/profile/start", timeout=PROFILE_START_LIMIT_S)
+            self.t_on = time.monotonic()
+            self.close.wait()
+            self.t_off = time.monotonic()
+            self.launcher.control("POST", "/profile/stop", timeout=PROFILE_STOP_LIMIT_S)
+            self.stop_s = time.monotonic() - self.t_off
+        except Exception as e:  # noqa: BLE001 — surfaced by finish()
+            self.error = e
+
+    def finish(self) -> None:
+        """Wait for the stop's answer. The two calls carry their own limits,
+        so the join's is only a backstop."""
+        self.close.set()
+        self.join(timeout=PROFILE_START_LIMIT_S + PROFILE_STOP_LIMIT_S + 5)
+        if self.is_alive():
+            raise RunFailed("profiler: its thread did not end")
+        if self.error is not None:
+            raise RunFailed(f"profiler: {self.error}")
+
+
 class Observer(threading.Thread):
-    """Scrapes ``/metrics`` and the compile count at the window's edges
-    and, in the traced run, samples the gauges at ``SAMPLE_HZ`` and holds
-    the profiler open for ``TRACE_S`` seconds in the middle."""
+    """Scrapes ``/metrics`` and the compile count at the window's two edges,
+    on its own timeline whatever else is outstanding. In the traced run it
+    also samples the gauges at ``SAMPLE_HZ`` from edge to edge, and decides
+    from the samples when the profiler closes."""
 
     def __init__(self, launcher: Launcher, w0: float, w1: float, trace: bool):
         super().__init__(name="bench-observer", daemon=True)
-        self.launcher, self.w0, self.w1, self.trace = launcher, w0, w1, trace
+        self.launcher, self.w0, self.w1 = launcher, w0, w1
+        self.profiler = Profiler(launcher) if trace else None
         self.before = self.after = None
         self.compiles_before = self.compiles_after = None
+        self.t_after = None  # when the second /metrics scrape answered
         self.samples: list[dict] = []
-        self.trace_window = None
+        self.blocks_traced = None  # decode blocks harvested while the profiler was open
+        self.closed_by = None  # the bound that closed it: "blocks", "seconds" or "window"
         self.error = None
 
     def _sleep_until(self, t: float) -> None:
@@ -399,38 +461,67 @@ class Observer(threading.Thread):
         if d > 0:
             time.sleep(d)
 
+    def _compiles(self) -> dict:
+        return self.launcher.control("GET", "/compiles", timeout=SCRAPE_LIMIT_S)
+
+    def _sample_and_trace(self) -> None:
+        prof = self.profiler
+        t_open = self.w0 + TRACE_FROM * (self.w1 - self.w0)
+        blocks_at_open = None
+        while (now := time.monotonic()) < self.w1:
+            if now >= t_open and prof.ident is None:
+                prof.start()
+            m = self.launcher.metrics()
+            blocks = stats.scalar(m, BLOCKS_COUNTER)
+            self.samples.append({
+                "t": now, "blocks_harvested": blocks,
+                "slots_active": stats.scalar(m, "mst_batch_slots_active"),
+                "pages_in_use": stats.scalar(m, "mst_kv_pool_pages_in_use"),
+                "queue_depth": stats.scalar(m, "mst_batch_queue_depth"),
+            })
+            if prof.t_on is not None and now >= prof.t_on and not prof.close.is_set():
+                if blocks is not None:
+                    if blocks_at_open is None:
+                        blocks_at_open = blocks
+                    self.blocks_traced = blocks - blocks_at_open
+                if self.blocks_traced is not None and self.blocks_traced >= TRACE_BLOCKS:
+                    self.closed_by = "blocks"
+                elif now >= t_open + TRACE_S:
+                    self.closed_by = "seconds"
+                elif now >= self.w1 - 0.5:
+                    self.closed_by = "window"
+                if self.closed_by:
+                    prof.close.set()
+            time.sleep(max(0.0, 1.0 / SAMPLE_HZ - (time.monotonic() - now)))
+
     def run(self) -> None:
         try:
             self._sleep_until(self.w0)
-            self.before = self.launcher.metrics()
-            self.compiles_before = self.launcher.control("GET", "/compiles")
-            if self.trace:
-                t_start = self.w0 + 0.4 * (self.w1 - self.w0)
-                t_stop = min(t_start + TRACE_S, self.w1 - 0.5)
-                started = stopped = False
-                while time.monotonic() < self.w1:
-                    now = time.monotonic()
-                    if not started and now >= t_start:
-                        self.launcher.control("POST", "/profile/start")
-                        started, t_on = True, time.monotonic()
-                    if started and not stopped and now >= t_stop:
-                        t_off = time.monotonic()
-                        self.launcher.control("POST", "/profile/stop")
-                        stopped = True
-                        self.trace_window = (t_on, t_off)
-                    m = self.launcher.metrics()
-                    self.samples.append({
-                        "t": now,
-                        "slots_active": stats.scalar(m, "mst_batch_slots_active"),
-                        "pages_in_use": stats.scalar(m, "mst_kv_pool_pages_in_use"),
-                        "queue_depth": stats.scalar(m, "mst_batch_queue_depth"),
-                    })
-                    time.sleep(max(0.0, 1.0 / SAMPLE_HZ - (time.monotonic() - now)))
+            self.before, self.compiles_before = self.launcher.metrics(), self._compiles()
+            if self.profiler is not None:
+                self._sample_and_trace()
             self._sleep_until(self.w1)
             self.after = self.launcher.metrics()
-            self.compiles_after = self.launcher.control("GET", "/compiles")
-        except Exception as e:  # noqa: BLE001 — surfaced by the caller
+            self.t_after = time.monotonic()
+            self.compiles_after = self._compiles()
+        except Exception as e:  # noqa: BLE001 — surfaced by finish()
             self.error = e
+        finally:
+            if self.profiler is not None:
+                self.profiler.close.set()
+
+    def finish(self) -> None:
+        """Both scrapes are there when this returns, and the profiler has
+        written its trace; a ``RunFailed`` that names the stage otherwise."""
+        self.join(timeout=max(0.0, self.w1 - time.monotonic()) + OBSERVER_LIMIT_S)
+        if self.is_alive():
+            raise RunFailed(f"observer: no second scrape {OBSERVER_LIMIT_S:g} s after the window")
+        if self.error is not None:
+            raise RunFailed(f"observer: {self.error}")
+        if self.profiler is not None:
+            if self.profiler.ident is None:
+                raise RunFailed("profiler: the window ended before it opened")
+            self.profiler.finish()
 
 
 UNSENT = {"ok": False, "cut": False, "sent": None, "first": None, "last": None,
@@ -481,7 +572,11 @@ def send_closed_loop(pool, port: int, planned, clients: int, w1: float) -> list[
                 time.sleep(0.2)  # a refusing server must not be hammered
 
     for fut in [pool.submit(client) for _ in range(clients)]:
-        fut.result(timeout=max(0.1, w1 - time.monotonic()) + 120.0)
+        try:
+            fut.result(timeout=max(0.1, w1 - time.monotonic()) + CLIENT_LIMIT_S)
+        except TimeoutError:
+            raise RunFailed(f"closed loop: a client had not returned {CLIENT_LIMIT_S:g} s "
+                            "after the window") from None
     return sorted(records, key=lambda r: r["due"])
 
 
@@ -500,9 +595,7 @@ def drive(launcher: Launcher, planned: list[traffic.Planned], lead: float,
             records = send_closed_loop(pool, launcher.port, planned, clients, w1)
         else:
             records = send_open_loop(pool, launcher.port, planned, t_base, w1)
-        observer.join(timeout=max(1.0, w1 - time.monotonic()) + 60)
-        if observer.error is not None:
-            raise RunFailed(f"observer: {observer.error}")
+        observer.finish()
     finally:
         # a stream that never ends would hold the pool open: the caller
         # stops the server, which ends every open socket
@@ -520,19 +613,6 @@ def load_reader(kind: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
-
-
-def reduce_trace(profile_dir: Path) -> dict:
-    """The ``.xplane.pb`` reduction runs in a process of its own, held to
-    the CPU: this one never imports JAX, and the chip is free by now."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, str(BENCH_DIR / "trace_reduce.py"), str(profile_dir)],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=300,
-    )
-    if out.returncode != 0:
-        raise RunFailed(f"trace reduction failed:\n{out.stderr[-2000:]}")
-    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 # --------------------------------------------------------------------------
@@ -611,6 +691,9 @@ def main(argv=None) -> int:
                 for a, b in zip(r["chunks"], r["chunks"][1:])]
         late = [(r["sent"] - r["due"]) * 1e3 for r in in_window if r["sent"]]
         compiles = obs.compiles_after["count"] - obs.compiles_before["count"]
+        say(f"second scrape {obs.t_after - w1:+.3f} s from the window's end" + (
+            f"; {len(obs.samples)} gauge samples, the last {w1 - obs.samples[-1]['t']:.3f} s "
+            "before it" if obs.samples else ""))
         say("after the window: " + json.dumps({
             "server_failed_delta": stats.scalar(obs.after, "mst_requests_failed_total", 0)
             - stats.scalar(obs.before, "mst_requests_failed_total", 0),
@@ -645,7 +728,9 @@ def main(argv=None) -> int:
                "samples": obs.samples, "device": device, "compiles_in_window": compiles,
                "config": config, "cell": cell, "mix": mix, "load": load, "trace": None}
         if args.trace:
-            trace = ctx["trace"] = reduce_trace(work / "profile")
+            trace, secs = reduce_profile("trace reduction", BENCH_DIR / "trace_reduce.py",
+                                         work / "profile", TRACE_REDUCE_LIMIT_S)
+            ctx["trace"], ctx["reduce_seconds"] = trace, {"trace_reduce": secs}
             result["device"]["busy_s"] = trace["busy_s"]
             result["device"]["window_s"] = trace["window_s"]
             result["breakdown"] = trace["breakdown"]
@@ -659,6 +744,17 @@ def main(argv=None) -> int:
                                 meta["name"])(ctx)
             if value is not None:
                 result["metrics"][meta["name"]] = {"value": value, "unit": meta["unit"]}
+        if args.trace:  # what the next reader needs to see the margin of each limit above
+            prof = obs.profiler
+            say("profile: " + json.dumps({
+                "open_s": prof.t_off - prof.t_on, "closed_by": obs.closed_by,
+                "blocks_harvested_while_open": obs.blocks_traced,
+                "decode_blocks_in_trace": len(durations(trace, "decode_block")),
+                "stop_s": prof.stop_s,
+                "xplane_bytes": sum(f.stat().st_size for f in
+                                    (work / "profile").glob("**/*.xplane.pb")),
+                "reduce_s": ctx["reduce_seconds"],
+            }))
         print(json.dumps(result), flush=True)
         return 0
     except RunFailed as e:

@@ -37,13 +37,20 @@ called in, whatever it computes).
 from __future__ import annotations
 
 import json
-import os
 import re
-import subprocess
 import sys
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:  # run as a script, by for_run
+    sys.path.insert(0, str(ROOT))
+
+from benchmarks.reduction import reduce_profile  # noqa: E402
+
+#: the wait for this file's own process in ``for_run``, seconds: 2.8 x the
+#: 21.1 s of a chip run's 29 MB profile (20.6-22.5 s from 7 to 44 MB: most of
+#: it is importing the protobuf's module; PERF.md section 7)
+SCOPE_REDUCE_LIMIT_S = 60.0
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 UNSCOPED = "unscoped"
@@ -308,14 +315,9 @@ def for_run(ctx: dict):
     if key not in _RUNS:
         _RUNS[key] = None
         if sorted(where.glob("**/*.xplane.pb")):
-            out = subprocess.run(
-                [sys.executable, str(Path(__file__).resolve()), key],
-                cwd=ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-                capture_output=True, text=True, timeout=600,
-            )
-            if out.returncode != 0:
-                raise RuntimeError(f"scope reduction failed:\n{out.stderr[-2000:]}")
-            result = json.loads(out.stdout.strip().splitlines()[-1])
+            result, secs = reduce_profile("scope reduction", Path(__file__).resolve(),
+                                          where, SCOPE_REDUCE_LIMIT_S)
+            ctx.setdefault("reduce_seconds", {})["scope_reduce"] = secs
             for line in result["notes"]:
                 print(f"[scope_reduce] {line}", flush=True)
             _RUNS[key] = result

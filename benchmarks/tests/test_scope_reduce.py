@@ -7,7 +7,7 @@ from pathlib import Path
 
 import pytest
 
-from benchmarks import scope_reduce, trace_reduce
+from benchmarks import reduction, scope_reduce, trace_reduce
 
 DATA = Path(__file__).resolve().parents[1] / "testdata"
 
@@ -127,7 +127,7 @@ def test_readers_share_one_reduction_and_return_none_without_scopes(recorded, mo
         return Out()
 
     monkeypatch.setattr(scope_reduce, "ROOT", tmp_path)
-    monkeypatch.setattr(scope_reduce.subprocess, "run", fake_run)
+    monkeypatch.setattr(reduction.subprocess, "run", fake_run)
     monkeypatch.setattr(scope_reduce, "_RUNS", {})
     ctx = {"cell": {"name": "some.cell"}, "trace": {"busy_s": 1.0}}
     assert scope_reduce.share(ctx, "mst.moe.experts") is None  # no profile on disk
@@ -157,7 +157,7 @@ def test_readers_share_one_reduction_and_return_none_without_scopes(recorded, mo
     # a program without any mst.* scope or span (the parent commit): nothing to read
     bare = dict(recorded, scoped=False, tick_spans={})
     monkeypatch.setattr(scope_reduce, "_RUNS", {})
-    monkeypatch.setattr(scope_reduce.subprocess, "run", lambda cmd, **kw: type(
+    monkeypatch.setattr(reduction.subprocess, "run", lambda cmd, **kw: type(
         "Out", (), {"returncode": 0, "stderr": "", "stdout": json.dumps(bare)})())
     assert scope_reduce.share(ctx, "mst.moe.experts") is None
     assert load_reader("layer_metrics", "tick_blocked_share")(ctx) is None
