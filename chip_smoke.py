@@ -467,6 +467,50 @@ def child_kernels(seed: int, rehearse: bool) -> None:
                   functools.partial(quant._quant_matmul_xla, group_size=64,
                                     bits=4),
                   (x, qw, sc, bi), QUANT_RTOL, relative=True)
+    # ---- routed experts at decode: 16 rows x top-6 on 64 packed experts of
+    # DeepSeek-V2-Lite's widths through ops.moe.apply_experts, against the
+    # same experts dequantized by XLA one at a time (_quant_matmul_xla)
+    from mlx_sharding_tpu.ops import moe
+
+    if rehearse:
+        n, k, e, hidden, width = 8, 2, 4, 128, 64
+    else:
+        n, k, e, hidden, width = 16, 6, 64, 2048, 1408
+    stacks = []
+    for out_dim, in_dim in ((width, hidden), (width, hidden), (hidden, width)):
+        kw, key = jax.random.split(key)
+        w = jax.random.normal(kw, (e, out_dim, in_dim), jnp.float32) * 0.02
+        stacks.append(dict(zip(("q", "scales", "biases"),
+                               jax.jit(quant.quantize_jax)(w))))
+        del w
+    kx, kr, key = jax.random.split(key, 3)
+    x = jax.random.normal(kx, (n, hidden), bf16)
+    topv, idx = jax.lax.top_k(jax.random.uniform(kr, (n, e)), k)
+    weights = topv / topv.sum(-1, keepdims=True)
+
+    def experts_ref(x, weights, idx, w_gate, w_up, w_down):
+        mm = functools.partial(quant._quant_matmul_xla, group_size=64, bits=4)
+
+        def one(carry, ws):
+            wg, wu, wd, i = ws
+            h = jax.nn.silu(mm(x, wg["q"], wg["scales"], wg["biases"])) * mm(
+                x, wu["q"], wu["scales"], wu["biases"])
+            y = mm(h, wd["q"], wd["scales"], wd["biases"]).astype(jnp.float32)
+            coef = ((idx == i) * weights).sum(-1)
+            return carry + coef[:, None] * y, None
+
+        acc, _ = jax.lax.scan(one, jnp.zeros(x.shape, jnp.float32),
+                              (w_gate, w_up, w_down, jnp.arange(e)))
+        return acc
+
+    if rehearse:
+        fn = functools.partial(moe._apply_packed_kernel, gs=64, bits=4,
+                               interpret=True)
+    else:
+        fn = moe.apply_experts
+    check(f"quant_matmul_experts N={n} top-{k} of {e} {hidden}x{width}",
+          "quant_matmul_experts", fn, experts_ref,
+          (x, weights, idx, *stacks), QUANT_RTOL, relative=True)
     print(json.dumps({"ok": True, "device": device}), flush=True)
 
 

@@ -3,16 +3,25 @@
 The reference keeps experts fused and stage-local — per-expert weights are
 stacked into one ``switch_mlp`` tensor at load time
 (ref: shard/server/model/deepseek_v2.py:101-112) and routing happens inside
-the owning pipeline stage (SURVEY §2.3 "EP"). Same policy here, with two
-TPU execution paths chosen by token count at trace time:
+the owning pipeline stage (SURVEY §2.3 "EP"). Same policy here, with the
+execution path chosen at trace time from what ``apply_experts`` can
+observe — token count, packed or dense stacks, backend, shapes:
 
-- **decode (few tokens)**: gather the top-k experts' weights per token and
-  batch the tiny matmuls — HBM traffic is k/E of the expert weights, which
-  is what decode is bound by;
-- **prefill (many tokens)**: ``lax.scan`` over experts with masked
-  accumulation — every matmul is a full-width MXU op with static shapes, no
-  sorting, no capacity overflow. (A Pallas ragged-dispatch kernel is the
-  planned upgrade for very large E.)
+- **decode (N <= GATHER_PATH_MAX_TOKENS rows), packed stacks, on a TPU**:
+  the expert-indexed 4-bit kernel (``quant_matmul.quant_matmul_experts``).
+  The step's DISTINCT expert ids go to the kernel as a scalar-prefetched
+  table; each grid step reads one expert's packed tile straight out of the
+  (E, out, in*bits/32) stack, unpacks it in VMEM and multiplies all N rows
+  by it. HBM traffic is each chosen expert's packed bytes once, which is
+  what decode is bound by; no dense expert tensor is written to HBM.
+- **decode, packed stacks, elsewhere** (off the chip, MST_QMM=0, a shape
+  outside the kernel's contract): gather the packed leaves per pick and
+  dequantize the gathered slices (N x K whole experts, dense, in HBM).
+- **decode, dense stacks**: gather the top-k experts' weights per token and
+  batch the tiny matmuls.
+- **prefill (many tokens) and expert-parallel**: ``lax.scan`` over experts
+  with masked accumulation — every matmul is a full-width MXU op with
+  static shapes, no sorting, no capacity overflow.
 
 Routing is parameterized so Mixtral (softmax→topk→renorm) and DeepSeek-V2
 (softmax scoring→greedy topk, optional renorm + scaling factor) share the
@@ -21,8 +30,14 @@ dispatch machinery.
 
 from __future__ import annotations
 
+import functools
+import logging
+import os
+
 import jax
 import jax.numpy as jnp
+
+logger = logging.getLogger(__name__)
 
 GATHER_PATH_MAX_TOKENS = 16
 
@@ -103,13 +118,18 @@ def apply_experts(
         return jax.lax.psum(acc, ep_axis)
     if n <= GATHER_PATH_MAX_TOKENS:
         # decode path: HBM traffic is k/E of the stacks — and 4x less again
-        # when they are packed (gather the packed leaves, dequantize the
-        # gathered slice in-register)
-        if is_quantized(w_gate):
-            return _apply_gather_packed(
-                x, weights, idx, w_gate, w_up, w_down, group_size, bits
-            )
-        return _apply_gather(x, weights, idx, w_gate, w_up, w_down)
+        # when they are packed: the kernel reads each chosen expert's packed
+        # bytes once; off the chip, or at shapes it does not serve, gather
+        # the packed leaves and dequantize the gathered slice
+        if not is_quantized(w_gate):
+            return _apply_gather(x, weights, idx, w_gate, w_up, w_down)
+        path = (
+            _apply_packed_kernel
+            if packed_kernel_ok(n, w_gate, w_up, w_down, group_size, bits)
+            else _apply_gather_packed
+        )
+        _log_path_once(path.__name__, n, idx.shape[1], tuple(w_gate["q"].shape))
+        return path(x, weights, idx, w_gate, w_up, w_down, group_size, bits)
     return _apply_scan(x, weights, idx, w_gate, w_up, w_down, group_size, bits)
 
 
@@ -122,6 +142,77 @@ def _apply_gather(x, weights, idx, w_gate, w_up, w_down):
         u = jnp.einsum("nh,nkhi->nki", x, wu)
         y = jnp.einsum("nki,nkih->nkh", jax.nn.silu(g) * u, wd)
     return (y * weights[..., None].astype(y.dtype)).sum(axis=1).astype(x.dtype)
+
+
+def packed_kernel_ok(n, w_gate, w_up, w_down, gs, bits) -> bool:
+    """Whether ``n`` rows over these packed stacks run the expert-indexed
+    4-bit kernel: a TPU backend, 4-bit kernels not switched off (MST_QMM=0,
+    as ops/quant._pallas_ok), and all three projections inside the kernel's
+    own contract (quant_matmul.experts_blocks)."""
+    from mlx_sharding_tpu.ops.quant_matmul import experts_blocks
+
+    if os.environ.get("MST_QMM", "1") == "0" or jax.default_backend() != "tpu":
+        return False
+    return all(
+        experts_blocks(
+            n, w["q"].shape[-2], w["q"].shape[-1] * 32 // bits, gs, bits
+        ) is not None
+        for w in (w_gate, w_up, w_down)
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _log_path_once(path: str, n: int, k: int, stack_shape: tuple) -> None:
+    """One line per distinct (path, shape): the choice is made at trace time
+    from static shapes, so this is once per compiled program, at its build."""
+    logger.info(
+        "moe experts: %s for %d rows x top-%d over packed stacks %s",
+        path, n, k, stack_shape,
+    )
+
+
+def distinct_experts(idx, num_experts: int):
+    """``(ids (T,), live (1,))``, T = min(E, N*K) static: the distinct expert
+    ids among the picks in ascending order, padded with the last real one;
+    ``live`` counts the real ones."""
+    t = min(num_experts, idx.size)
+    present = (idx.reshape(-1, 1) == jnp.arange(num_experts)).any(axis=0)
+    live = present.sum(dtype=jnp.int32)
+    ids = jnp.nonzero(present, size=t, fill_value=0)[0].astype(jnp.int32)
+    ids = jnp.where(jnp.arange(t) < live, ids, ids[live - 1])
+    return ids, live[None]
+
+
+def _apply_packed_kernel(
+    x, weights, idx, w_gate, w_up, w_down, gs, bits, interpret=False
+):
+    """Each distinct expert of the step once: the three projections run as
+    ``quant_matmul_experts`` over the packed stacks as they lie in HBM, all
+    N rows against one expert's tile a grid step, and the down projection
+    combines with ``coef[t, n] = sum_k weights[n, k] * (idx[n, k] == ids[t])``
+    — the arithmetic of ``_apply_scan``'s body, skipping the experts nobody
+    chose. No dense expert tensor is written to HBM."""
+    from mlx_sharding_tpu.ops.quant_matmul import quant_matmul_experts
+
+    per_word = 32 // bits
+    ids, live = distinct_experts(idx, w_gate["q"].shape[0])
+    coef = ((idx == ids[:, None, None]) * weights).sum(axis=-1)  # (T, N)
+
+    def planes(a):  # (..., N, IN) -> (..., per_word, N, IN / per_word)
+        a = a.reshape(*a.shape[:-1], a.shape[-1] // per_word, per_word)
+        return jnp.moveaxis(a, -1, -3)
+
+    def experts(x_planes, w, coef=None):
+        return quant_matmul_experts(
+            x_planes, ids, live, w["q"], w["scales"], w["biases"], coef,
+            group_size=gs, bits=bits, interpret=interpret,
+        )
+
+    with jax.named_scope("mst.moe.experts.matmul"):
+        xp = planes(x)[None]
+        h = jax.nn.silu(experts(xp, w_gate)) * experts(xp, w_up)  # (T, N, I) f32
+        y = experts(planes(h.astype(x.dtype)), w_down, coef)
+    return y.astype(x.dtype)
 
 
 def _apply_gather_packed(x, weights, idx, w_gate, w_up, w_down, gs, bits):

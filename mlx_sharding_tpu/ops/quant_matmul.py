@@ -34,9 +34,17 @@ ref shard/utils.py:54-65): ``q`` (out, in*bits/32) LSB-first nibbles,
 ``scales``/``biases`` (out, in/group_size) — validated bit-exactly by
 tests/test_quant_golden.py.
 
-Two kernels share that math:
+Three kernels share that math:
 
 - :func:`quant_matmul_pallas` — the 3-D-grid prefill/batch kernel above.
+- :func:`quant_matmul_experts` — the same grid with an expert axis, for
+  the routed experts of a decode step (ops/moe.py): the weight operand is
+  the whole (E, out, in*bits/32) stack as it lies in HBM, and a
+  scalar-prefetched table of the step's distinct expert ids picks each
+  grid step's tile, so each chosen expert's packed bytes are read once and
+  no dense expert tensor is written to HBM. Scales and biases (and a
+  packed leaf whose word count is not a multiple of 128) are read with OUT
+  in the lanes, the orientation a TPU stores them in.
 - :func:`quant_gemv_pipelined` — the decode (M ≤ 8) specialization. At
   M=1 the 3-D grid's per-program overhead dominates: each (OUT, IN) tile
   is one tiny MXU burst and the automatic pipeline re-fetches the scale
@@ -229,6 +237,244 @@ def quant_matmul_pallas(
         interpret=interpret,
         name="quant_matmul",
     )(x_r, q, s3, b3)
+
+
+# ---------------------------------------------------------------------------
+# Expert stacks: the same arithmetic with an expert axis on the grid.
+# ---------------------------------------------------------------------------
+
+
+def experts_q_transposed(out_dim: int, words: int) -> bool:
+    """Whether the kernel reads an expert's packed words as (words, OUT).
+    A TPU lays an array whose minor dimension is not a multiple of 128 out
+    with a dimension that is in the lanes instead (DeepSeek-V2-Lite's
+    ``w_down.q`` u32[E, 2048, 176] sits in HBM as [E, 176, 2048]; every
+    scales leaf as [E, groups, OUT]). Asked for in that orientation, the
+    operand is the leaf as it lies there and the transpose the wrapper
+    writes is a bitcast; asked for row-major, XLA copies the stack into a
+    lane-padded one first. Either way the result is the same."""
+    return words % 128 != 0 and out_dim % 128 == 0
+
+
+def experts_blocks(
+    n: int, out_dim: int, in_dim: int, group_size: int = 64, bits: int = 4,
+    *, hardware: bool = True,
+) -> tuple[int, int] | None:
+    """(block_out, block_in) of :func:`quant_matmul_experts` for ``n`` rows
+    against (E, out_dim, in_dim) packed stacks, or None where its contract
+    does not admit the shape: blocks divide the shape on quant-group and
+    nibble-word boundaries and — on ``hardware``, which interpret mode
+    waives — OUT tiles are whole 128-lane columns (scales, biases and the
+    output carry OUT in their lanes) and an IN block is the whole dimension
+    or whole 128-lane columns of words. The dispatch (ops/moe.py) and the
+    tests go through here."""
+    per_word = 32 // bits
+    block_in = pick_block_in(in_dim)
+    block_out = pick_block_out(out_dim, block_in // per_word, n, per_word)
+    if (
+        out_dim % block_out or in_dim % block_in
+        or block_in % group_size or group_size % per_word
+    ):
+        return None
+    if hardware and (
+        block_out % 128
+        or (block_in != in_dim and (block_in // per_word) % 128)
+    ):
+        return None
+    return block_out, block_in
+
+
+def _experts_kernel(
+    ids_ref,  # (T,) SMEM: the step's distinct expert ids, padded with the last
+    live_ref,  # (1,) SMEM: how many of them are real
+    x_ref,  # (per_word, N, words) activation planes, x_ref[j][n, w] = x[n, 8w+j]
+    q_ref,  # (block_out, words) packed words of expert ids[e]; (words, block_out) if q_t
+    s_ref,  # (G, block_out) that expert's scales, OUT in the lanes
+    b_ref,  # (G, block_out) its biases
+    *rest,  # [coef_ref (N, 1)], o_ref (N, block_out), acc_ref (N, block_out) f32
+    bits: int,
+    group_size: int,
+    q_t: bool,
+    combine: bool,
+):
+    del ids_ref  # read by the index maps
+    if combine:
+        coef_ref, o_ref, acc_ref = rest
+    else:
+        o_ref, acc_ref = rest
+    per_word = 32 // bits
+    mask = (1 << bits) - 1
+    words = q_ref.shape[0] if q_t else q_ref.shape[1]
+    g_total = s_ref.shape[0]
+    wpg = group_size // per_word
+    e, ii = pl.program_id(1), pl.program_id(2)
+    first, last = ii == 0, ii == pl.num_programs(2) - 1
+    if combine:  # one output tile accumulates over every expert
+        first = first & (e == 0)
+        last = last & (e == pl.num_programs(1) - 1)
+
+    @pl.when(first)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(e < live_ref[0])
+    def _expert():
+        xdt = x_ref.dtype
+        # every dot names its precision: bf16 operands take one exact MXU
+        # pass (and Mosaic refuses them a process-wide "highest"); f32 rows
+        # ask for the f32 passes Mosaic's default would not make
+        dot = functools.partial(
+            jax.lax.dot_general, preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT,
+        )
+        plane_dot = dot if xdt == jnp.bfloat16 else functools.partial(
+            dot, precision=jax.lax.Precision.HIGHEST
+        )
+
+        def pieces(a):
+            """f32 (G, bo) as three bf16 pieces stacked on the group axis,
+            hi + mid + lo == a exactly: against the 0/1 expansion matrix
+            tiled three times they rebuild a's f32 values in ONE bf16 MXU
+            pass (an f32 dot at Mosaic's default precision rounds them to
+            bf16; K = 3 G still fits the array's depth)."""
+            a = a.astype(jnp.float32)
+            hi = a.astype(jnp.bfloat16)
+            r = a - hi.astype(jnp.float32)
+            mid = r.astype(jnp.bfloat16)
+            lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+            return jnp.concatenate([hi, mid, lo], axis=0)
+
+        # group→word expansion on the MXU as in _kernel, selecting this IN
+        # block's groups out of the whole rows as _gemv_kernel does
+        g0 = ii * (words // wpg)
+        s3, b3 = pieces(s_ref[...]), pieces(b_ref[...])
+        gi = jax.lax.broadcasted_iota(jnp.int32, (3 * g_total, words), 0)
+        wi = jax.lax.broadcasted_iota(jnp.int32, (3 * g_total, words), 1)
+        expand = (wi // wpg + g0 == gi % g_total).astype(jnp.bfloat16)
+        over_groups = (((0,), (0,)), ((), ()))
+        if q_t:  # everything with OUT in the lanes: plain (K, N) matmuls
+            s_w = dot(expand, s3, over_groups)  # (words, bo)
+            b_w = dot(expand, b3, over_groups)
+            contract = (((1,), (0,)), ((), ()))
+        else:
+            s_w = dot(s3, expand, over_groups)  # (bo, words)
+            b_w = dot(b3, expand, over_groups)
+            contract = (((1,), (1,)), ((), ()))
+        wq = q_ref[...]
+        part = jnp.zeros(acc_ref.shape, jnp.float32)
+        for j in range(per_word):
+            nib = ((wq >> (j * bits)) & mask).astype(jnp.int32).astype(jnp.float32)
+            # the dequantized plane in the activations' dtype, exactly as
+            # ops.quant.dequantize makes it: bf16 planes take one MXU pass
+            part = part + plane_dot(
+                x_ref[j], (nib * s_w + b_w).astype(xdt), contract
+            )
+        if combine:
+            part = part * coef_ref[...]
+        acc_ref[...] += part
+
+    @pl.when(last)
+    def _done():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("group_size", "bits", "interpret"),
+)
+def quant_matmul_experts(
+    x_planes: jax.Array,  # (1 | T, per_word, N, IN/per_word): shared, or one per id
+    ids: jax.Array,  # (T,) int32
+    live: jax.Array,  # (1,) int32
+    q: jax.Array,  # (E, OUT, IN * bits / 32) uint32
+    scales: jax.Array,  # (E, OUT, IN / group_size)
+    biases: jax.Array,  # (E, OUT, IN / group_size)
+    coef: jax.Array | None = None,  # (T, N) f32
+    *,
+    group_size: int = 64,
+    bits: int = 4,
+    interpret: bool = False,
+) -> jax.Array:
+    """``x @ dequant(q[ids[t]], ...).T`` for the first ``live[0]`` entries of
+    ``ids``, each expert's packed tile read straight out of the stack by
+    its id (scalar-prefetched into the index maps) and unpacked in VMEM.
+    Without ``coef``: (T, N, OUT) f32, zero rows past ``live``. With it: the
+    (N, OUT) f32 sum over t of ``coef[t, n]`` times expert t's rows,
+    accumulated across the expert axis of the grid. Entries past
+    ``live`` repeat the last real id, so the pipeline fetches nothing new
+    for them, and their compute is skipped."""
+    lead, per_word, n, w_total = x_planes.shape
+    t = ids.shape[0]
+    out_dim = q.shape[1]
+    in_dim = w_total * per_word
+    blocks = experts_blocks(
+        n, out_dim, in_dim, group_size, bits, hardware=not interpret
+    )
+    if (
+        blocks is None or per_word != 32 // bits or lead not in (1, t)
+        or q.shape[2] != w_total
+    ):
+        raise ValueError(
+            f"quant_matmul_experts does not serve N={n}, OUT={out_dim}, "
+            f"IN={in_dim}, planes {x_planes.shape} for {t} ids"
+        )
+    block_out, block_in = blocks
+    words = block_in // per_word
+    g_total = in_dim // group_size
+    q_t = experts_q_transposed(out_dim, w_total)
+    if q_t:
+        q = jnp.swapaxes(q, -1, -2)
+        q_spec = pl.BlockSpec(
+            (None, words, block_out), lambda oi, e, ii, ids, live: (ids[e], ii, oi)
+        )
+    else:
+        q_spec = pl.BlockSpec(
+            (None, block_out, words), lambda oi, e, ii, ids, live: (ids[e], oi, ii)
+        )
+    row_spec = pl.BlockSpec(
+        (None, g_total, block_out), lambda oi, e, ii, ids, live: (ids[e], 0, oi)
+    )
+    x_spec = pl.BlockSpec(
+        (None, per_word, n, words),
+        lambda oi, e, ii, ids, live: (e if lead > 1 else 0, 0, 0, ii),
+    )
+    in_specs = [x_spec, q_spec, row_spec, row_spec]
+    operands = [
+        x_planes, q, jnp.swapaxes(scales, -1, -2), jnp.swapaxes(biases, -1, -2)
+    ]
+    if coef is None:
+        out_spec = pl.BlockSpec(
+            (None, n, block_out), lambda oi, e, ii, ids, live: (e, 0, oi)
+        )
+        out_shape = jax.ShapeDtypeStruct((t, n, out_dim), jnp.float32)
+    else:
+        in_specs.append(pl.BlockSpec(
+            (None, n, 1), lambda oi, e, ii, ids, live: (e, 0, 0)
+        ))
+        operands.append(coef.astype(jnp.float32)[..., None])
+        out_spec = pl.BlockSpec(
+            (n, block_out), lambda oi, e, ii, ids, live: (0, oi)
+        )
+        out_shape = jax.ShapeDtypeStruct((n, out_dim), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(
+            _experts_kernel, bits=bits, group_size=group_size, q_t=q_t,
+            combine=coef is not None,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(out_dim // block_out, t, in_dim // block_in),
+            in_specs=in_specs,
+            out_specs=out_spec,
+            scratch_shapes=[pltpu.VMEM((n, block_out), jnp.float32)],
+        ),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        ),
+        interpret=interpret,
+        name="quant_matmul_experts",
+    )(ids, live, *operands)
 
 
 # ---------------------------------------------------------------------------

@@ -159,8 +159,9 @@ def test_served_deepseek_programs_carry_the_scope_vocabulary(dsv2_batcher):
     assert _scopes_in(compiled) <= vocabulary
 
 
-def test_packed_experts_and_the_scan_path_have_their_scopes():
-    """The two expert paths the tiny dense model above does not take."""
+def test_packed_experts_and_the_scan_path_have_their_scopes(monkeypatch):
+    """The expert paths the tiny dense model above does not take: the scan,
+    the packed gather (off the chip) and the expert-indexed kernel (on it)."""
     from mlx_sharding_tpu.ops import moe
 
     x = jnp.ones((20, 32), jnp.float32)  # > GATHER_PATH_MAX_TOKENS rows
@@ -182,6 +183,15 @@ def test_packed_experts_and_the_scan_path_have_their_scopes():
         x8, weights[:8], idx[:8], packed(128, 64), packed(128, 64), packed(64, 128),
     ).as_text(debug_info=True)
     assert {"mst.moe.experts.gather_dequant", "mst.moe.experts.matmul"} <= _scopes_in(gather)
+    # on a TPU the same call is the 4-bit kernel, under the matmul scope
+    # alone: gather_dequant is the fallback's, and reads 0 in a chip trace
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kernel = jax.jit(moe.apply_experts).trace(
+        jnp.ones((8, 128), jnp.float32), weights[:8], idx[:8],
+        packed(128, 128), packed(128, 128), packed(128, 128),
+    ).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "quant_matmul_experts" in kernel
+    assert _scopes_in(kernel) == {"mst.moe.experts", "mst.moe.experts.matmul"}
     assert _scopes_in(scan) | _scopes_in(gather) <= set(tracing.MODEL_SCOPES)
 
 

@@ -256,20 +256,177 @@ def test_packed_gather_and_scan_paths_agree(tmp_path):
     x = jnp.asarray(rng.normal(size=(n, h)), jnp.float32)
     router = jnp.asarray(rng.normal(size=(h, e)), jnp.float32)
 
-    def packed_stack(out_d, in_d):
-        ws = [
-            quantize((rng.normal(size=(out_d, in_d)) * 0.1).astype(np.float32), gs, 4)
-            for _ in range(e)
-        ]
-        return {
-            "q": jnp.stack([jnp.asarray(w[0]) for w in ws]),
-            "scales": jnp.stack([jnp.asarray(w[1], jnp.float32) for w in ws]),
-            "biases": jnp.stack([jnp.asarray(w[2], jnp.float32) for w in ws]),
-        }
-
-    wg, wu = packed_stack(mi, h), packed_stack(mi, h)
-    wd = packed_stack(h, mi)
+    wg, wu = _packed_stack(rng, e, mi, h, gs), _packed_stack(rng, e, mi, h, gs)
+    wd = _packed_stack(rng, e, h, mi, gs)
     weights, idx = mixtral_routing(x, router, k)
     got_g = _apply_gather_packed(x, weights, idx, wg, wu, wd, gs, 4)
     got_s = _apply_scan(x, weights, idx, wg, wu, wd, gs, 4)
     np.testing.assert_allclose(np.asarray(got_g), np.asarray(got_s), rtol=1e-4, atol=1e-5)
+
+
+def _packed_stack(rng, e, out_d, in_d, gs, zero=False):
+    """(E, out, in) random weights as packed MLX-orientation leaves (f32
+    scales and biases, as the loader widens them); ``zero``: a padded layer
+    slot of the fused engine, every leaf all zeros."""
+    ws = [
+        quantize((rng.normal(size=(out_d, in_d)) * 0.1).astype(np.float32), gs, 4)
+        for _ in range(e)
+    ]
+    stack = {
+        "q": jnp.stack([jnp.asarray(w[0]) for w in ws]),
+        "scales": jnp.stack([jnp.asarray(w[1], jnp.float32) for w in ws]),
+        "biases": jnp.stack([jnp.asarray(w[2], jnp.float32) for w in ws]),
+    }
+    return jax.tree.map(jnp.zeros_like, stack) if zero else stack
+
+
+def _routing(rng, n, e, k, picks):
+    """(weights (N, K) f32, idx (N, K) int32) for a named pattern of picks."""
+    if picks == "random":
+        idx = np.stack([rng.permutation(e)[:k] for _ in range(n)])
+    elif picks == "same":  # every row chooses the same experts
+        idx = np.tile(rng.permutation(e)[:k], (n, 1))
+    elif picks == "distinct":  # no expert is picked twice in the step
+        assert n * k <= e
+        idx = rng.permutation(e)[: n * k].reshape(n, k)
+    elif picks == "neighbours":  # row r and row r+1 share one expert
+        idx = np.stack([(np.arange(k) + r * (k - 1)) % e for r in range(n)])
+    else:
+        raise ValueError(picks)
+    w = rng.uniform(0.1, 1.0, size=(n, k)).astype(np.float32)
+    return jnp.asarray(w / w.sum(-1, keepdims=True)), jnp.asarray(idx, jnp.int32)
+
+
+#: name → (N, hidden, expert width, E, K, group size, picks, zero params)
+KERNEL_CASES = {
+    **{f"n{n}-e4k2": (n, 64, 32, 4, 2, 16, "random", False) for n in (1, 2, 8, 16)},
+    "n16-e64k6": (16, 128, 64, 64, 6, 16, "random", False),
+    "n2-e64k6": (2, 128, 64, 64, 6, 16, "random", False),
+    "same-expert": (8, 64, 32, 4, 2, 16, "same", False),
+    "all-picks-distinct": (8, 128, 64, 64, 6, 16, "distinct", False),
+    "neighbours-share-one": (8, 64, 32, 8, 2, 16, "neighbours", False),
+    # expert width 1408 -> 176 words in small: 176 words of 8 inputs a row,
+    # not a multiple of 128, which the down projection reads transposed
+    "words-not-128": (16, 256, 1408, 4, 2, 64, "random", False),
+    "zero-params": (16, 64, 32, 4, 2, 16, "random", True),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_expert_kernel_matches_gather_and_scan(case):
+    """The expert-indexed 4-bit kernel (interpret mode) against the gather
+    fallback and the prefill scan on the same packed stacks."""
+    from mlx_sharding_tpu.ops.moe import (
+        _apply_gather_packed,
+        _apply_packed_kernel,
+        _apply_scan,
+        distinct_experts,
+    )
+    from mlx_sharding_tpu.ops.quant_matmul import experts_blocks
+
+    n, h, mi, e, k, gs, picks, zero = KERNEL_CASES[case]
+    rng = np.random.default_rng(sum(map(ord, case)))
+    x = jnp.asarray(rng.normal(size=(n, h)), jnp.float32)
+    wg, wu = (_packed_stack(rng, e, mi, h, gs, zero) for _ in range(2))
+    wd = _packed_stack(rng, e, h, mi, gs, zero)
+    weights, idx = _routing(rng, n, e, k, picks)
+    for out_d, in_d in ((mi, h), (h, mi)):
+        assert experts_blocks(n, out_d, in_d, gs, 4, hardware=False) is not None
+
+    ids, live = distinct_experts(idx, e)
+    want_ids = np.unique(np.asarray(idx))
+    assert int(live[0]) == len(want_ids) and ids.shape == (min(e, n * k),)
+    np.testing.assert_array_equal(np.asarray(ids)[: len(want_ids)], want_ids)
+    assert (np.asarray(ids)[len(want_ids):] == want_ids[-1]).all()
+
+    got = _apply_packed_kernel(x, weights, idx, wg, wu, wd, gs, 4, interpret=True)
+    assert got.shape == x.shape and got.dtype == x.dtype
+    assert bool(jnp.isfinite(got).all())
+    if zero:
+        assert not np.asarray(got).any()
+    for ref in (_apply_gather_packed, _apply_scan):
+        want = ref(x, weights, idx, wg, wu, wd, gs, 4)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-4, atol=2e-5
+        )
+
+
+def test_expert_kernel_bf16_rows_round_as_the_gather_path_does():
+    """bf16 activations, as served: the kernel rounds each dequantized plane
+    to bf16 before its sub-dot like ``dequantize(..., bf16)``, accumulates in
+    f32, and casts once."""
+    from mlx_sharding_tpu.ops.moe import _apply_gather_packed, _apply_packed_kernel
+
+    rng = np.random.default_rng(3)
+    n, h, mi, e, k, gs = 16, 128, 64, 8, 2, 64
+    x = jnp.asarray(rng.normal(size=(n, h)), jnp.bfloat16)
+    wg, wu = (_packed_stack(rng, e, mi, h, gs) for _ in range(2))
+    wd = _packed_stack(rng, e, h, mi, gs)
+    weights, idx = _routing(rng, n, e, k, "random")
+    got = _apply_packed_kernel(x, weights, idx, wg, wu, wd, gs, 4, interpret=True)
+    want = _apply_gather_packed(x, weights, idx, wg, wu, wd, gs, 4)
+    exact = _apply_gather_packed(x.astype(jnp.float32), weights, idx, wg, wu, wd, gs, 4)
+    assert got.dtype == jnp.bfloat16
+    scale = float(jnp.abs(exact).max())
+    err = lambda a: float(jnp.abs(a.astype(jnp.float32) - exact).max()) / scale  # noqa: E731
+    assert err(got) <= max(err(want), 2 ** -7)
+
+
+def test_apply_experts_dispatch(monkeypatch):
+    """What ``apply_experts`` chooses from what it can observe: on the CPU
+    the gather fallback; with the backend answered as ``tpu`` (as
+    tests/test_tpu_compile.py does) the kernel; MST_QMM=0, 17 rows, a shape
+    outside the kernel's contract and ``ep_axis`` keep the paths they had."""
+    from mlx_sharding_tpu.ops import moe
+
+    rng = np.random.default_rng(9)
+    e, k, h, mi, gs = 4, 2, 256, 128, 64
+    wg, wu = (_packed_stack(rng, e, mi, h, gs) for _ in range(2))
+    wd = _packed_stack(rng, e, h, mi, gs)
+
+    def paths(n, **kw):
+        x = jnp.ones((n, h), jnp.float32)
+        weights, idx = _routing(rng, n, e, k, "random")
+        text = str(jax.make_jaxpr(
+            lambda *a: moe.apply_experts(*a, group_size=gs, **kw)
+        )(x, weights, idx, wg, wu, wd))
+        return {
+            "kernel": "quant_matmul_experts" in text,
+            "gather": "gather" in text,
+            "scan": "scan" in text or "while" in text,
+        }
+
+    assert paths(16) == {"kernel": False, "gather": True, "scan": False}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe.packed_kernel_ok(16, wg, wu, wd, gs, 4)
+    assert paths(16) == {"kernel": True, "gather": False, "scan": False}
+    assert paths(1)["kernel"]
+    assert paths(17)["kernel"] is False and paths(17)["scan"]
+    # expert-parallel: each device scans its residents, whatever the rows
+    from jax.sharding import PartitionSpec as P
+
+    from mlx_sharding_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(pp=1, ep=2)
+    rep, split = P(), jax.tree.map(lambda _: P("ep"), wg)
+    x = jnp.ones((4, h), jnp.float32)
+    weights, idx = _routing(rng, 4, e, k, "random")
+    text = str(jax.make_jaxpr(jax.shard_map(
+        lambda *a: moe.apply_experts(*a, ep_axis="ep", group_size=gs),
+        mesh=mesh, in_specs=(rep, rep, rep, split, split, split), out_specs=rep,
+        check_vma=False,
+    ))(x, weights, idx, wg, wu, wd))
+    assert "quant_matmul_experts" not in text and "psum" in text
+    monkeypatch.setenv("MST_QMM", "0")
+    assert not moe.packed_kernel_ok(16, wg, wu, wd, gs, 4)
+    monkeypatch.delenv("MST_QMM")
+    # an OUT of 10944 rows has no 128-row tiling the pickers accept
+    odd = _packed_stack(rng, 2, 96, 64, 64)
+    assert not moe.packed_kernel_ok(16, odd, odd, _packed_stack(rng, 2, 64, 96, 32), 64, 4)
+    # dense stacks gather as before
+    dense = jnp.ones((e, h, mi), jnp.float32)
+    x = jnp.ones((4, h), jnp.float32)
+    weights, idx = _routing(rng, 4, e, k, "random")
+    text = str(jax.make_jaxpr(moe.apply_experts)(
+        x, weights, idx, dense, dense, jnp.ones((e, mi, h), jnp.float32)))
+    assert "quant_matmul_experts" not in text

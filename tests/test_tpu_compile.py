@@ -6,8 +6,8 @@ refuses — a block shape off the (8, 128) tiling, a DMA slice that is not
 installed here and compiles for a chip that is described, not attached, so
 these cases cost no chip time. The shapes are the ones chip_smoke.py runs on
 the chip (Llama-3.2-3B widths) plus the MLA attention shapes and
-DeepSeek-V2-Lite's packed projections. A compile that passes is not a chip
-run; chip_smoke.py is.
+DeepSeek-V2-Lite's packed projections and expert stacks. A compile that
+passes is not a chip run; chip_smoke.py is.
 
 The dispatch predicates ask ``jax.default_backend()``, which stays ``cpu``
 here, so the test answers ``tpu`` in their place and compiles the
@@ -95,6 +95,30 @@ def _quant(m, out_dim, in_dim, kernel, scale_dtype=F32):
     )
 
 
+def _experts(n, k, e, hidden, width):
+    """``ops.moe.apply_experts`` — the DISPATCHER — for ``n`` rows x top-``k``
+    over packed (E, out, in) expert stacks with f32 scales and biases: it
+    must select the expert-indexed kernel for all three projections."""
+    from mlx_sharding_tpu.ops.moe import apply_experts
+
+    def stack(out_dim, in_dim):
+        return [((e, out_dim, in_dim // 8), jnp.uint32),
+                ((e, out_dim, in_dim // 64), F32), ((e, out_dim, in_dim // 64), F32)]
+
+    def fn(x, weights, idx, *leaves):
+        gate, up, down = (
+            dict(zip(("q", "scales", "biases"), leaves[i:i + 3])) for i in (0, 3, 6)
+        )
+        return apply_experts(x, weights, idx, gate, up, down)
+
+    return (
+        fn,
+        [((n, hidden), BF16), ((n, k), F32), ((n, k), I32)]
+        + stack(width, hidden) * 2 + stack(hidden, width),
+        "quant_matmul_experts",
+    )
+
+
 LLAMA_3B = [(8192, 3072), (3072, 8192), (128256, 3072)]
 CASES = {
     # flash prefill chunk and T=1 at Llama-3B heads; the MLA shapes
@@ -121,6 +145,13 @@ CASES = {
     "quant-M1-dsv2-2048x1408": _quant(1, 1408, 2048, "quant_gemv_pipelined"),
     "quant-M1-dsv2-1408x2048": _quant(1, 2048, 1408, "quant_matmul"),
     "quant-M1-dsv2-2048x10944": _quant(1, 10944, 2048, None),
+    # the routed experts at decode, published widths: DeepSeek-V2-Lite's
+    # (64, 1408, 256) / (64, 2048, 176) stacks at the cell's 16 rows x top-6
+    # (the 176-word leaf is read transposed, as it lies in HBM), at one row,
+    # and Mixtral-8x7B's 8 experts of 14336 x 4096, top-2 (two IN blocks)
+    "experts-dsv2-16x6of64": _experts(16, 6, 64, 2048, 1408),
+    "experts-dsv2-1x6of64": _experts(1, 6, 64, 2048, 1408),
+    "experts-mixtral-16x2of8": _experts(16, 2, 8, 4096, 14336),
 }
 
 
