@@ -16,11 +16,20 @@ causal-mask shift at shard/server/model/llama.py:48-53).
 MLA models cache differently-shaped tensors (tuple head dims,
 ref: shard/server/model/deepseek_v2.py:120-125); they use the same structure
 with their own head dims per tensor.
+
+A model with recurrent layers (``models/nemotron_h.py``: Mamba-2) keeps a
+second kind of per-sequence state beside the K/V rows: ``state``, a pytree
+of per-layer, per-slot arrays the model defines (an SSM state in float32, a
+convolution's last inputs) — donated and carried exactly as ``k``/``v`` are.
+It is not addressed by ``offset``: lowering an offset rewinds K/V rows and
+NOT the state, so every path that puts a sequence back that way either
+starts over from position 0 (where the programs take the state as zero) or
+refuses such a model at start-up (:func:`refuse_recurrent`).
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +39,9 @@ class KVCache(NamedTuple):
     k: jax.Array  # (L, B, S, H_kv, D_k) — or {"d": int8, "s": f32} (paged int8)
     v: jax.Array  # (L, B, S, H_kv, D_v) — same
     offset: jax.Array  # scalar int32 — number of valid positions
+    # recurrent per-sequence state ({name: (L_state, slots, …)}) of models
+    # that have any; None — no leaves, the same programs as before — otherwise
+    state: Optional[dict] = None
 
     @property
     def max_seq(self) -> int:
@@ -38,6 +50,21 @@ class KVCache(NamedTuple):
     @property
     def num_layers(self) -> int:
         return kv_data(self.k).shape[0]
+
+
+def has_recurrent_state(model) -> bool:
+    return bool(getattr(model, "has_recurrent_state", False))
+
+
+def refuse_recurrent(model, flag: str, why: str) -> None:
+    """The one start-up error of every feature that moves or rewinds a
+    sequence as pages of K/V only: it names the flag and says "recurrent
+    state". No-op for a model without such state."""
+    if has_recurrent_state(model):
+        raise ValueError(
+            f"{flag} cannot serve {type(model).__name__}: it has recurrent "
+            f"state beside its K/V pages, and {why}"
+        )
 
 
 def is_quantized_kv(buf) -> bool:

@@ -170,6 +170,74 @@ class MixtralConfig(BaseConfig):
     sliding_window: Optional[int] = None
 
 
+@dataclass
+class NemotronHConfig(BaseConfig):
+    """Nemotron-H / Nemotron-3 hybrids: every block is one mixer behind one
+    RMSNorm, the kind chosen per layer by ``hybrid_override_pattern`` — ``M``
+    Mamba-2, ``*`` attention (GQA, no rotary), ``E`` latent MoE (sigmoid
+    router with a selection bias, un-gated relu^2 experts in a
+    ``moe_latent_size``-wide space, one shared expert at full width).
+
+    A layer may hold one chip's SHARE of the routed experts:
+    ``n_routed_experts`` counts the experts held, ``moe_expert_share`` says
+    over how many holders a layer's experts are divided (the router has
+    ``n_routed_experts * moe_expert_share`` outputs) and
+    ``moe_expert_share_index`` which of them this is. A checkpoint's own
+    config (no share keys) is the whole model."""
+
+    model_type: str = "nemotron_h"
+    hybrid_override_pattern: str = ""
+    layer_norm_epsilon: float = 1e-5
+    # Mamba-2
+    mamba_num_heads: int = 128
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    use_conv_bias: bool = True
+    # latent MoE
+    n_routed_experts: int = 512
+    num_experts_per_tok: int = 22
+    moe_intermediate_size: int = 2688
+    moe_latent_size: int = 1024
+    moe_shared_expert_intermediate_size: int = 5376
+    n_shared_experts: int = 1
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 5.0
+    moe_expert_share: int = 1
+    moe_expert_share_index: int = 0
+    max_position_embeddings: int = 262144
+
+    def __post_init__(self):
+        if not self.hybrid_override_pattern:
+            raise ValueError("nemotron_h needs hybrid_override_pattern")
+        if len(self.hybrid_override_pattern) != self.num_hidden_layers:
+            raise ValueError(
+                f"hybrid_override_pattern has "
+                f"{len(self.hybrid_override_pattern)} characters for "
+                f"{self.num_hidden_layers} layers"
+            )
+        bad = set(self.hybrid_override_pattern) - set("M*E")
+        if bad:
+            raise ValueError(
+                f"hybrid_override_pattern: layer kinds {sorted(bad)} are not "
+                "wired (M Mamba-2, * attention, E latent MoE are)"
+            )
+        if self.n_group != 1 or self.topk_group != 1:
+            raise ValueError("nemotron_h routing is wired for n_group = topk_group = 1")
+        if not 0 <= self.moe_expert_share_index < self.moe_expert_share:
+            raise ValueError("moe_expert_share_index must lie in [0, moe_expert_share)")
+        super().__post_init__()
+        self.rms_norm_eps = self.layer_norm_epsilon
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed_experts * self.moe_expert_share
+
+
 # Arch-name resolution. Mirrors the reference's MODEL_REMAPPING
 # (shard/utils.py:14-17): mistral runs through the llama implementation.
 MODEL_REMAPPING = {
@@ -183,6 +251,7 @@ CONFIG_REGISTRY: dict[str, type] = {
     "gemma2": Gemma2Config,
     "deepseek_v2": DeepseekV2Config,
     "mixtral": MixtralConfig,
+    "nemotron_h": NemotronHConfig,
 }
 
 

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from mlx_sharding_tpu.cache import KVCache, reset
+from mlx_sharding_tpu.cache import KVCache, refuse_recurrent, reset
 from mlx_sharding_tpu.sample import (
     SamplerParams,
     init_recent_tokens,
@@ -181,6 +181,11 @@ class Generator:
         # offset), the same invariant the speculative rollback leans on.
         # The reference resets every remote cache per request instead
         # (shard/utils.py:122-124).
+        if prompt_cache:
+            refuse_recurrent(
+                model, "--prompt-cache",
+                "a prefix hit rewinds the cache by lowering its offset",
+            )
         self._prompt_cache = bool(prompt_cache)
         self._pc = None  # {"tokens": np (T,), "cache": KVCache}
         self.last_prefix_hit = 0  # observability + tests

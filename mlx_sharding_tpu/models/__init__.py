@@ -19,6 +19,7 @@ MODEL_REGISTRY: dict[str, tuple[str, str]] = {
     "gemma2": ("mlx_sharding_tpu.models.gemma2", "Gemma2Model"),
     "deepseek_v2": ("mlx_sharding_tpu.models.deepseek_v2", "DeepseekV2Model"),
     "mixtral": ("mlx_sharding_tpu.models.mixtral", "MixtralModel"),
+    "nemotron_h": ("mlx_sharding_tpu.models.nemotron_h", "NemotronHModel"),
 }
 
 

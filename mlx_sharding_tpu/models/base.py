@@ -206,6 +206,9 @@ class BaseModel:
         stacks with masked padding for uneven/heterogeneous splits."""
         return {None: (0, self.config.num_hidden_layers)}
 
+    #: per-sequence state beside the K/V rows (cache.KVCache.state)
+    has_recurrent_state = False
+
     # -- sequence parallelism ---------------------------------------------
     #: architectures wired for the sequence-parallel paths (sp_prefill's
     #: ring attention, sp_decode's partial-softmax merge) set this True

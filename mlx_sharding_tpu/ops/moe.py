@@ -23,9 +23,18 @@ observe — token count, packed or dense stacks, backend, shapes:
   with masked accumulation — every matmul is a full-width MXU op with
   static shapes, no sorting, no capacity overflow.
 
-Routing is parameterized so Mixtral (softmax→topk→renorm) and DeepSeek-V2
-(softmax scoring→greedy topk, optional renorm + scaling factor) share the
-dispatch machinery.
+Routing is parameterized so Mixtral (softmax→topk→renorm), DeepSeek-V2
+(softmax scoring→greedy topk, optional renorm + scaling factor) and
+Nemotron-H (sigmoid scoring, a selection bias that chooses but does not
+weigh) share the dispatch machinery. Experts are SwiGLU (``w_gate`` given)
+or un-gated ``relu(x W_up)^2 W_down`` (``w_gate=None``), on every path.
+
+A layer that holds only a RANGE of the routed experts — one chip's share
+of a layer divided over several — is told ``expert_base`` (the global id
+of its first expert): routing stays over all experts, the layer computes
+its own experts' part, and what the absent experts would add is left out.
+That is the ``ep_axis`` branch without ``axis_index`` and without the
+``psum``; under ``ep_axis`` the same code runs with both.
 """
 
 from __future__ import annotations
@@ -90,16 +99,52 @@ def deepseek_routing(
     return topv * routed_scaling_factor, topi
 
 
+@jax.named_scope("mst.moe.router")
+def nemotron_routing(
+    x, router_w, select_bias, k: int, *, norm_topk_prob: bool,
+    routed_scaling_factor: float,
+):
+    """Nemotron-H gate (``n_group == topk_group == 1``: no group limit):
+    sigmoid scores in fp32; the top-k of ``scores + select_bias`` CHOOSES,
+    the chosen experts' own scores WEIGH (the bias never enters a weight),
+    renormalized to sum 1 when ``norm_topk_prob``, times
+    ``routed_scaling_factor``."""
+    logits = jnp.einsum(
+        "nh,he->ne", x.astype(jnp.float32), router_w.astype(jnp.float32)
+    )
+    scores = jax.nn.sigmoid(logits)
+    _, topi = jax.lax.top_k(scores + select_bias.astype(jnp.float32), k)
+    topv = jnp.take_along_axis(scores, topi, axis=-1)
+    if norm_topk_prob:
+        topv = topv / (topv.sum(axis=-1, keepdims=True) + 1e-20)
+    return topv * routed_scaling_factor, topi
+
+
+def _activate(gate, up):
+    """SwiGLU where the experts are gated, ``relu(up)^2`` where not."""
+    if gate is None:
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.silu(gate) * up
+
+
 @jax.named_scope("mst.moe.experts")
 def apply_experts(
     x, weights, idx, w_gate, w_up, w_down, ep_axis=None,
-    group_size: int = 64, bits: int = 4,
+    group_size: int = 64, bits: int = 4, expert_base=None, layer=None,
 ):
-    """SwiGLU expert application. x (N, H); w_* stacked (E, H, I)/(E, I, H)
+    """Expert application: SwiGLU, or ``relu^2`` un-gated with
+    ``w_gate=None``. x (N, H); w_* stacked (E, H, I)/(E, I, H)
     dense, or packed ``{q, scales, biases}`` triples with MLX-orientation
     leaves (E, out, in*bits/32) — 4-bit expert stacks stay resident in HBM
     and dequantize on the fly (ref quant predicate: shard/utils.py:54-65).
     weights/idx (N, K). Returns (N, H).
+
+    ``expert_base``: the stacks hold experts ``base .. base + E_local`` of a
+    wider routing (``idx`` are global ids): only their part is computed.
+    ``layer`` (with ``expert_base`` or ``ep_axis``): the stacks keep their
+    leading layer axis ``(L, E, …)`` and the scan reads expert ``(layer,
+    e)`` out of them where they lie — slicing one layer's experts out first
+    would copy them (gigabytes a step) before the scan could read them.
 
     ``ep_axis``: inside shard_map with the expert stacks sharded over that
     mesh axis, each device holds E/ep experts whose GLOBAL ids start at
@@ -109,38 +154,45 @@ def apply_experts(
     from mlx_sharding_tpu.ops.quant import is_quantized
 
     n = x.shape[0]
-    e_local = (w_gate["q"] if is_quantized(w_gate) else w_gate).shape[0]
-    if ep_axis is not None:
-        base = jax.lax.axis_index(ep_axis) * e_local
+    e_local = (w_up["q"] if is_quantized(w_up) else w_up).shape[
+        0 if layer is None else 1
+    ]
+    if ep_axis is not None or expert_base is not None:
+        base = 0 if expert_base is None else expert_base
+        if ep_axis is not None:
+            base = base + jax.lax.axis_index(ep_axis) * e_local
         acc = _apply_scan(
-            x, weights, idx - base, w_gate, w_up, w_down, group_size, bits
+            x, weights, idx - base, w_gate, w_up, w_down, group_size, bits,
+            layer=layer,
         )
-        return jax.lax.psum(acc, ep_axis)
+        return acc if ep_axis is None else jax.lax.psum(acc, ep_axis)
+    if layer is not None:
+        raise ValueError("apply_experts: `layer` needs expert_base or ep_axis")
     if n <= GATHER_PATH_MAX_TOKENS:
         # decode path: HBM traffic is k/E of the stacks — and 4x less again
         # when they are packed: the kernel reads each chosen expert's packed
         # bytes once; off the chip, or at shapes it does not serve, gather
         # the packed leaves and dequantize the gathered slice
-        if not is_quantized(w_gate):
+        if not is_quantized(w_up):
             return _apply_gather(x, weights, idx, w_gate, w_up, w_down)
         path = (
             _apply_packed_kernel
             if packed_kernel_ok(n, w_gate, w_up, w_down, group_size, bits)
             else _apply_gather_packed
         )
-        _log_path_once(path.__name__, n, idx.shape[1], tuple(w_gate["q"].shape))
+        _log_path_once(path.__name__, n, idx.shape[1], tuple(w_up["q"].shape))
         return path(x, weights, idx, w_gate, w_up, w_down, group_size, bits)
     return _apply_scan(x, weights, idx, w_gate, w_up, w_down, group_size, bits)
 
 
 def _apply_gather(x, weights, idx, w_gate, w_up, w_down):
-    wg = w_gate[idx]  # (N, K, H, I)
+    wg = None if w_gate is None else w_gate[idx]  # (N, K, H, I)
     wu = w_up[idx]
     wd = w_down[idx]  # (N, K, I, H)
     with jax.named_scope("mst.moe.experts.matmul"):
-        g = jnp.einsum("nh,nkhi->nki", x, wg)
+        g = None if wg is None else jnp.einsum("nh,nkhi->nki", x, wg)
         u = jnp.einsum("nh,nkhi->nki", x, wu)
-        y = jnp.einsum("nki,nkih->nkh", jax.nn.silu(g) * u, wd)
+        y = jnp.einsum("nki,nkih->nkh", _activate(g, u), wd)
     return (y * weights[..., None].astype(y.dtype)).sum(axis=1).astype(x.dtype)
 
 
@@ -157,7 +209,7 @@ def packed_kernel_ok(n, w_gate, w_up, w_down, gs, bits) -> bool:
         experts_blocks(
             n, w["q"].shape[-2], w["q"].shape[-1] * 32 // bits, gs, bits
         ) is not None
-        for w in (w_gate, w_up, w_down)
+        for w in (w_gate, w_up, w_down) if w is not None
     )
 
 
@@ -186,7 +238,8 @@ def distinct_experts(idx, num_experts: int):
 def _apply_packed_kernel(
     x, weights, idx, w_gate, w_up, w_down, gs, bits, interpret=False
 ):
-    """Each distinct expert of the step once: the three projections run as
+    """Each distinct expert of the step once: the projections (three, or
+    two for un-gated experts) run as
     ``quant_matmul_experts`` over the packed stacks as they lie in HBM, all
     N rows against one expert's tile a grid step, and the down projection
     combines with ``coef[t, n] = sum_k weights[n, k] * (idx[n, k] == ids[t])``
@@ -195,7 +248,7 @@ def _apply_packed_kernel(
     from mlx_sharding_tpu.ops.quant_matmul import quant_matmul_experts
 
     per_word = 32 // bits
-    ids, live = distinct_experts(idx, w_gate["q"].shape[0])
+    ids, live = distinct_experts(idx, w_up["q"].shape[0])
     coef = ((idx == ids[:, None, None]) * weights).sum(axis=-1)  # (T, N)
 
     def planes(a):  # (..., N, IN) -> (..., per_word, N, IN / per_word)
@@ -210,7 +263,8 @@ def _apply_packed_kernel(
 
     with jax.named_scope("mst.moe.experts.matmul"):
         xp = planes(x)[None]
-        h = jax.nn.silu(experts(xp, w_gate)) * experts(xp, w_up)  # (T, N, I) f32
+        g = None if w_gate is None else experts(xp, w_gate)
+        h = _activate(g, experts(xp, w_up))  # (T, N, I) f32
         y = experts(planes(h.astype(x.dtype)), w_down, coef)
     return y.astype(x.dtype)
 
@@ -230,29 +284,53 @@ def _apply_gather_packed(x, weights, idx, w_gate, w_up, w_down, gs, bits):
 
     # gathered() opens its own, deeper scope inside this one
     with jax.named_scope("mst.moe.experts.matmul"):
-        g = jnp.einsum("nh,nkih->nki", x, gathered(w_gate))
+        g = (
+            None if w_gate is None
+            else jnp.einsum("nh,nkih->nki", x, gathered(w_gate))
+        )
         u = jnp.einsum("nh,nkih->nki", x, gathered(w_up))
-        y = jnp.einsum("nki,nkhi->nkh", jax.nn.silu(g) * u, gathered(w_down))
+        y = jnp.einsum("nki,nkhi->nkh", _activate(g, u), gathered(w_down))
     return (y * weights[..., None].astype(y.dtype)).sum(axis=1).astype(x.dtype)
 
 
 @jax.named_scope("mst.moe.experts.scan")
-def _apply_scan(x, weights, idx, w_gate, w_up, w_down, gs=64, bits=4):
+def _apply_scan(x, weights, idx, w_gate, w_up, w_down, gs=64, bits=4,
+                layer=None):
     from mlx_sharding_tpu.ops.quant import is_quantized, linear
 
-    num_experts = (w_gate["q"] if is_quantized(w_gate) else w_gate).shape[0]
+    num_experts = (w_up["q"] if is_quantized(w_up) else w_up).shape[
+        0 if layer is None else 1
+    ]
+
+    if layer is not None:
+        # (L, E, …) → (L*E, …), a view: ONE index on ONE axis is what the
+        # compiler fuses into the matmul's operand read. Two nested indices
+        # let it hoist the layer's and copy that layer's whole expert stack
+        # every iteration; one slice over two axes it materializes per expert
+        flat = lambda w: jax.tree.map(  # noqa: E731
+            lambda a: a.reshape((-1,) + a.shape[2:]), w
+        )
+        w_gate, w_up, w_down = flat(w_gate), flat(w_up), flat(w_down)
 
     def body(acc, xs):
-        wg, wu, wd, e = xs
+        wg, wu, wd, e = xs  # wg is None (an empty pytree) for un-gated experts
+        if layer is not None:  # stacks closed over whole: read (layer, e) in place
+            at = lambda w: jax.tree.map(  # noqa: E731
+                lambda a: jax.lax.dynamic_index_in_dim(
+                    a, layer * num_experts + e, 0, keepdims=False
+                ),
+                w,
+            )
+            wg, wu, wd = at(w_gate), at(w_up), at(w_down)
         coef = ((idx == e) * weights).sum(axis=-1)  # (N,) routing mass for e
         # linear() serves dense (in, out) slices and packed (out, in)
         # triples alike — the prefill path streams every expert's packed
         # bytes once, full-width MXU matmuls, no sorting
-        y = linear(jax.nn.silu(linear(x, wg, gs, bits)) * linear(x, wu, gs, bits), wd, gs, bits)
+        g = None if wg is None else linear(x, wg, gs, bits)
+        y = linear(_activate(g, linear(x, wu, gs, bits)), wd, gs, bits)
         return acc + coef[:, None].astype(y.dtype) * y, None
 
     acc0 = jnp.zeros_like(x)
-    acc, _ = jax.lax.scan(
-        body, acc0, (w_gate, w_up, w_down, jnp.arange(num_experts))
-    )
+    stacks = (w_gate, w_up, w_down) if layer is None else (None, None, None)
+    acc, _ = jax.lax.scan(body, acc0, (*stacks, jnp.arange(num_experts)))
     return acc

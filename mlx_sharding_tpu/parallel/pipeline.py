@@ -41,7 +41,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from mlx_sharding_tpu.cache import (
     KVCache,
     dequantize_kv,
+    has_recurrent_state,
     quantize_kv_rows,
+    refuse_recurrent,
 )
 from mlx_sharding_tpu.ops.quant import dequantize, is_quantized
 from mlx_sharding_tpu.parallel.mesh import (
@@ -113,9 +115,15 @@ def split_stage_stacks(model, layer_params: dict, stage_bounds) -> tuple[dict, d
     splits (e.g. the BASELINE DeepSeek 0-14/14-27 config,
     /root/reference/shard/utils.py:36-39) without per-stage programs.
 
+    Groups may interleave (``model.layer_group_layers``: Nemotron-H's
+    Mamba, attention and expert layers): a stage then gets, per group, the
+    rows of that group's layers inside its bounds — still contiguous in the
+    group's own stack — and no group is padded with a layer of another kind.
+
     Returns ``(stacked_params, masks, total_slots)`` where ``masks`` mirrors
     the group structure of ``stacked_params`` ((S, slots) bool arrays) and
-    ``total_slots`` is the per-stage KV-cache layer count.
+    ``total_slots`` is the per-stage KV-cache layer count (the slots of the
+    groups that keep K/V: all of them unless ``model.kv_groups`` says).
     """
     stage_bounds = list(stage_bounds)
     S = len(stage_bounds)
@@ -127,11 +135,20 @@ def split_stage_stacks(model, layer_params: dict, stage_bounds) -> tuple[dict, d
     if any(e <= s for s, e in stage_bounds):
         raise ValueError(f"stage bounds {stage_bounds} contain an empty stage")
 
-    ranges = model.layer_group_ranges()
+    # {group: [global layer indices]}: a model whose groups interleave lists
+    # them itself, every other gives contiguous ranges
+    if hasattr(model, "layer_group_layers"):
+        groups = model.layer_group_layers()
+    else:
+        groups = {
+            g: list(range(g0, g1))
+            for g, (g0, g1) in model.layer_group_ranges().items()
+        }
 
-    def split_group(stack: dict, g0: int, g1: int):
+    def split_group(stack: dict, layers: list):
+        # the group's rows that fall inside each stage's [s, e)
         rows_per_stage = [
-            (min(max(s, g0), g1) - g0, min(max(e, g0), g1) - g0)
+            (sum(i < s for i in layers), sum(i < e for i in layers))
             for s, e in stage_bounds
         ]
         slots = max(hi - lo for lo, hi in rows_per_stage)
@@ -155,15 +172,17 @@ def split_stage_stacks(model, layer_params: dict, stage_bounds) -> tuple[dict, d
             mask[si, : hi - lo] = True
         return stacked, jnp.asarray(mask), slots
 
-    if list(ranges) == [None]:
-        stacked, mask, slots = split_group(layer_params, *ranges[None])
+    if list(groups) == [None]:
+        stacked, mask, slots = split_group(layer_params, groups[None])
         return stacked, mask, slots
+    kv_groups = getattr(model, "kv_groups", lambda: tuple(groups))()
     stacked_all, masks_all, total = {}, {}, 0
-    for key, (g0, g1) in ranges.items():
-        stacked, mask, slots = split_group(layer_params[key], g0, g1)
+    for key, layers in groups.items():
+        stacked, mask, slots = split_group(layer_params[key], layers)
         stacked_all[key] = stacked
         masks_all[key] = mask
-        total += slots
+        if key in kv_groups:
+            total += slots
     return stacked_all, masks_all, total
 
 
@@ -486,7 +505,12 @@ class PipelineEngine:
         # so legacy exported blocks compose). Validation against the
         # engine's LOCAL layer count happens below, once the resident
         # weights resolve the stage split.
+        self.has_state = has_recurrent_state(model)
         if kv_share_map is not None:
+            refuse_recurrent(
+                model, "--kv-share-map",
+                "a share map groups the layers of one K/V pool",
+            )
             if not self.paged:
                 raise ValueError(
                     "kv_share_map requires a paged engine (pool_pages)"
@@ -541,7 +565,9 @@ class PipelineEngine:
             and self.tp == 1
             and self.ep == 1
             and self.batch == 1
-            and getattr(model, "supports_sp", False)
+            # the sp_layer hook, or a layer walk of the model's own that
+            # takes the pool attention (run_layers(paged_attn=...))
+            and (getattr(model, "supports_sp", False) or self.has_state)
         )
         if paged_attention == "ragged" and not ragged_ok:
             raise ValueError(
@@ -609,6 +635,15 @@ class PipelineEngine:
         self.layer_masks = weights.layer_masks
         self.layers_per_stage = weights.layers_per_stage
         self.fused_projections = list(weights.fused_projections)
+        if self.has_state:
+            # the model walks its interleaved layer groups itself: tell it
+            # what each stage runs at each position, and how many rows the
+            # stage's state pool has (the slots of the groups that keep state)
+            self._rl_kwargs["plan"] = model.stage_plan(self.stage_bounds)
+            self._rl_kwargs["stage_axis"] = AXIS_PP
+            self.state_layers = sum(
+                self.layer_masks[g].shape[1] for g in model.state_groups()
+            )
         self.vocab_size = weights.vocab_size
         self._head_tied = weights.head_tied
         self.vocab_parts = weights.vocab_parts
@@ -626,6 +661,11 @@ class PipelineEngine:
         # federation blobs, and handoff wires all move the compact form.
         from mlx_sharding_tpu.kv_compress import build_codec
 
+        if kv_compress_map is not None:
+            refuse_recurrent(
+                model, "--kv-compress-map",
+                "the codec transports pages of K/V only",
+            )
         pool_layers = (
             self.kv_share.num_groups if self._share_active
             else self.layers_per_stage
@@ -741,6 +781,37 @@ class PipelineEngine:
             offset=put_global(
                 jnp.zeros((M,), jnp.int32), NamedSharding(self.mesh, P())
             ),
+            state=self._init_state(),
+        )
+
+    def _init_state(self):
+        """The per-slot recurrent state pool of a model that has one:
+        ``{name: (S, state layers, (M+1) * B, …)}`` — slot m's sequences are
+        rows ``m * B .. (m+1) * B``, the last B rows the scratch slot that
+        garbage ticks read and write; sharded over pp like the K/V pool.
+        (Slot and batch share ONE axis: with a B == 1 axis of its own between
+        them the chip lays the array out with that axis outermost, and the
+        decode block's loop then copies the whole pool in and out of its
+        carry every step.) None otherwise."""
+        if not self.has_state:
+            return None
+        S, rows = self.num_stages, (self.microbatches + 1) * self.batch
+        pool = {
+            name: jnp.zeros(
+                (S, self.state_layers, rows, *shape[1:]), dt or self.cache_dtype
+            )
+            for name, (shape, dt) in self.model.state_shapes(self.batch).items()
+        }
+        return put_global(pool, NamedSharding(self.mesh, P(AXIS_PP)))
+
+    def state_bytes(self) -> int:
+        """Bytes of the recurrent state pool (0 without one)."""
+        if not self.has_state:
+            return 0
+        return sum(
+            self.num_stages * self.state_layers * (self.microbatches + 1)
+            * int(np.prod(shape)) * jnp.dtype(dt or self.cache_dtype).itemsize
+            for shape, dt in self.model.state_shapes(self.batch).values()
         )
 
     def init_cache_paged(self) -> tuple[KVCache, jax.Array]:
@@ -788,6 +859,7 @@ class PipelineEngine:
             offset=put_global(
                 jnp.zeros((M,), jnp.int32), NamedSharding(self.mesh, P())
             ),
+            state=self._init_state(),
         )
         table = put_global(
             jnp.full((M + 1, self.slot_pages), self.pool_pages, jnp.int32),
@@ -941,6 +1013,50 @@ class PipelineEngine:
             jax.lax.dynamic_update_index_in_dim(v, v_m, m_write, 1),
         )
 
+    @jax.named_scope("mst.state_pool.regroup")
+    def _state_read(self, state, m_write, offset):
+        """One slot's recurrent state out of the local pool ``(L, (M+1) * B,
+        …)``. Position 0 has no history: whatever the row holds then (the
+        slot's last occupant, a block that ran past its end) reads as zero —
+        which is what resets a reused slot, chained on the device after
+        anything in flight, with no dispatch of its own."""
+        return jax.tree.map(
+            lambda x: jnp.where(
+                offset == 0, jnp.zeros((), x.dtype),
+                jax.lax.dynamic_slice_in_dim(
+                    x, m_write * self.batch, self.batch, axis=1
+                ),
+            ),
+            state,
+        )
+
+    @jax.named_scope("mst.state_pool.regroup")
+    def _state_write(self, state, new, m_write):
+        return jax.tree.map(
+            lambda x, n: jax.lax.dynamic_update_slice_in_dim(
+                x, n.astype(x.dtype), m_write * self.batch, axis=1
+            ),
+            state, new,
+        )
+
+    def _run_slot(self, layer_params, masks, h_in, k_m, v_m, offset, state,
+                  m_write, n_valid):
+        """One slot's pass through this stage's layers: ``(h, k_m, v_m,
+        state)``. With a state pool the slot's rows are read (zero at
+        position 0), advanced over the ``n_valid`` real rows of the chunk and
+        written back; without one ``state`` stays None."""
+        if state is None:
+            return (*self.model.run_layers(
+                layer_params, h_in, k_m, v_m, offset, mask=masks,
+                **self._rl_kwargs,
+            ), None)
+        h_out, k_m, v_m, st_m = self.model.run_layers(
+            layer_params, h_in, k_m, v_m, offset, mask=masks,
+            state=self._state_read(state, m_write, offset), n_valid=n_valid,
+            **self._rl_kwargs,
+        )
+        return h_out, k_m, v_m, self._state_write(state, st_m, m_write)
+
     def _build_step(self, t_len: int, with_sampling: bool):
         smapped = self._build_smapped(t_len)
         return self._finish_step(smapped, t_len, with_sampling)
@@ -961,7 +1077,8 @@ class PipelineEngine:
         unstack = lambda t: jax.tree.map(lambda x: x[0], t)  # noqa: E731
         restack = lambda t: jax.tree.map(lambda x: x[None], t)  # noqa: E731
 
-        def body(layer_params, masks, vparts, shared, tokens, k, v, offsets, active, n_valid, table):
+        def body(layer_params, masks, vparts, shared, tokens, k, v, offsets,
+                 active, n_valid, table, state):
             # Per-device views: layer_params (1, L, …) → (L, …); k/v
             # (1, L, M+1, B, seq, H, D) → (L, M+1, …). ``offsets`` is (M,) —
             # each slot's sequence position — and ``active`` (M,) bool marks
@@ -971,7 +1088,7 @@ class PipelineEngine:
             layer_params = jax.tree.map(lambda x: x[0], layer_params)
             masks = jax.tree.map(lambda x: x[0], masks)
             vparts = jax.tree.map(lambda x: x[0], vparts)
-            k, v = unstack(k), unstack(v)
+            k, v, state = unstack(k), unstack(v), unstack(state)
             s = jax.lax.axis_index(AXIS_PP)
             h0 = jnp.zeros((B, t_len, model.config.hidden_size), cdt)
             # bank HIDDEN states, not logits: the vocab projection runs once
@@ -980,7 +1097,7 @@ class PipelineEngine:
             offsets_pad = jnp.concatenate([offsets, jnp.zeros((1,), jnp.int32)])
 
             def tick(carry, t):
-                h_buf, k, v, out = carry
+                h_buf, k, v, state, out = carry
                 m = jnp.clip(t - s, 0, M - 1)
                 is_real = (t >= s) & (t - s < M) & active[m]
 
@@ -995,9 +1112,9 @@ class PipelineEngine:
                 m_write = jnp.where(is_real, m, M)
                 offset = offsets_pad[m_write]
                 k_m, v_m, row = self._kv_read(paged, k, v, table, m_write)
-                h_out, k_m, v_m = model.run_layers(
-                    layer_params, h_in, k_m, v_m, offset, mask=masks,
-                    **rl_kwargs,
+                h_out, k_m, v_m, state = self._run_slot(
+                    layer_params, masks, h_in, k_m, v_m, offset, state,
+                    m_write, n_valid,
                 )
                 k, v = self._kv_write(paged, k, v, k_m, v_m, row, m_write, offset)
 
@@ -1013,17 +1130,17 @@ class PipelineEngine:
                 h_next = jax.lax.ppermute(
                     h_out, AXIS_PP, [(i, (i + 1) % S) for i in range(S)]
                 )
-                return (h_next, k, v, out), None
+                return (h_next, k, v, state, out), None
 
-            (h_buf, k, v, out), _ = jax.lax.scan(
-                tick, (h0, k, v, out0), jnp.arange(S + M - 1)
+            (h_buf, k, v, state, out), _ = jax.lax.scan(
+                tick, (h0, k, v, state, out0), jnp.arange(S + M - 1)
             )
             out = jax.lax.psum(out, AXIS_PP)  # only stage S-1 contributed
             logits = self._vs_head(shared, vparts, out)  # (M, B, V) f32
-            return logits, restack(k), restack(v)
+            return logits, restack(k), restack(v), restack(state)
 
         def body_s1(layer_params, masks, vparts, shared, tokens, k, v,
-                    offsets, active, n_valid, table):
+                    offsets, active, n_valid, table, state):
             """S == 1 fast path: every microbatch is resident on the one
             stage, so the tick rotation above — which would run M sequential
             forwards, streaming the weights M times — collapses to ONE
@@ -1038,7 +1155,7 @@ class PipelineEngine:
             layer_params = jax.tree.map(lambda x: x[0], layer_params)
             masks = jax.tree.map(lambda x: x[0], masks)
             vparts = jax.tree.map(lambda x: x[0], vparts)
-            k, v = unstack(k), unstack(v)
+            k, v, state = unstack(k), unstack(v), unstack(state)
             s = jax.lax.axis_index(AXIS_PP)
             offsets_pad = jnp.concatenate([offsets, jnp.zeros((1,), jnp.int32)])
             m_write = jnp.where(active, jnp.arange(M), M)  # inactive → scratch
@@ -1056,12 +1173,24 @@ class PipelineEngine:
 
             k_ms, v_ms, rows = jax.vmap(read)(m_write)
 
-            def micro(h_m, k_m, v_m, off):
-                return model.run_layers(
-                    layer_params, h_m, k_m, v_m, off, mask=masks, **rl_kwargs
-                )
+            if state is None:
+                def micro(h_m, k_m, v_m, off):
+                    return model.run_layers(
+                        layer_params, h_m, k_m, v_m, off, mask=masks, **rl_kwargs
+                    )
 
-            h_outs, k_ms, v_ms = jax.vmap(micro)(h_all, k_ms, v_ms, offset_m)
+                h_outs, k_ms, v_ms = jax.vmap(micro)(h_all, k_ms, v_ms, offset_m)
+            else:
+                def micro_st(h_m, k_m, v_m, off, mw):
+                    return model.run_layers(
+                        layer_params, h_m, k_m, v_m, off, mask=masks,
+                        state=self._state_read(state, mw, off),
+                        n_valid=n_valid, **rl_kwargs,
+                    )
+
+                h_outs, k_ms, v_ms, st_ms = jax.vmap(micro_st)(
+                    h_all, k_ms, v_ms, offset_m, m_write
+                )
 
             # T=K writes at a decode (non-chunk-aligned) offset can straddle
             # pages; prefill/decode offsets never do (page % chunk == 0)
@@ -1070,14 +1199,18 @@ class PipelineEngine:
                 if paged and keep_all else 1
             )
 
-            def wr(i, kv):
-                k, v = kv
-                return self._kv_write(
+            def wr(i, kvs):
+                k, v, state = kvs
+                if state is not None:
+                    state = self._state_write(
+                        state, jax.tree.map(lambda x: x[i], st_ms), m_write[i]
+                    )
+                return (*self._kv_write(
                     paged, k, v, k_ms[i], v_ms[i],
                     rows[i] if paged else None, m_write[i], offset_m[i], wb,
-                )
+                ), state)
 
-            k, v = jax.lax.fori_loop(0, M, wr, (k, v))
+            k, v, state = jax.lax.fori_loop(0, M, wr, (k, v, state))
             if keep_all:
                 out = jnp.where(
                     active[:, None, None, None], h_outs, 0
@@ -1090,7 +1223,7 @@ class PipelineEngine:
             out = jax.lax.psum(out, AXIS_PP)  # identity at S=1; keeps the
             # body shape identical to the rotated one
             logits = self._vs_head(shared, vparts, out)
-            return logits, restack(k), restack(v)
+            return logits, restack(k), restack(v), restack(state)
 
         if S == 1:
             body = body_s1
@@ -1111,8 +1244,9 @@ class PipelineEngine:
                 spec_rep,  # active (M,)
                 spec_rep,  # n_valid
                 spec_rep,  # page table (paged mode; dummy otherwise)
+                spec_stage,  # recurrent state pool (None: no leaves)
             ),
-            out_specs=(spec_rep, self._kv_spec, self._kv_spec),
+            out_specs=(spec_rep, self._kv_spec, self._kv_spec, spec_stage),
             check_vma=False,
         )
         if paged:
@@ -1120,10 +1254,10 @@ class PipelineEngine:
         dummy_table = jnp.zeros((1, 1), jnp.int32)
 
         def smapped(layer_params, masks, vparts, shared, tokens, k, v, offsets,
-                    active, n_valid):
+                    active, n_valid, state=None):
             return inner(
                 layer_params, masks, vparts, shared, tokens, k, v, offsets,
-                active, n_valid, dummy_table,
+                active, n_valid, dummy_table, state,
             )
 
         if t_len == 1 and not keep_all:
@@ -1197,13 +1331,20 @@ class PipelineEngine:
         from mlx_sharding_tpu.ops.paged_attention import paged_attention
 
         def body(layer_params, masks, vparts, shared, tokens, k, v,
-                 offsets, active, n_valid, table):
+                 offsets, active, n_valid, table, state):
             layer_params = jax.tree.map(lambda x: x[0], layer_params)
             masks = jax.tree.map(lambda x: x[0], masks)
             vparts = jax.tree.map(lambda x: x[0], vparts)
             # (L, P+1, B, page, H, D) — int8 pools are {d, s} leaf pairs
             k = jax.tree.map(lambda x: x[0], k)
             v = jax.tree.map(lambda x: x[0], v)
+            # the barrier keeps the compiler from moving the first layer's
+            # read of the pool in front of this reshape: read and in-place
+            # write then name two views of one buffer, and the whole pool is
+            # copied in and out of the decode block's carry every step
+            state = jax.lax.optimization_barrier(
+                jax.tree.map(lambda x: x[0], state)
+            )
             s = jax.lax.axis_index(AXIS_PP)
 
             offsets_pad = jnp.concatenate([offsets, jnp.zeros((1,), jnp.int32)])
@@ -1222,52 +1363,81 @@ class PipelineEngine:
             # embed straight to (M, T=1, hidden)
             h = self._vs_embed(s, vparts, tokens).astype(cdt)
 
+            def pool_attn(k_buf, v_buf):
+                """``(attn_fn, done)`` over one layer's pool: ``attn_fn``
+                scatters the M new rows and attends over the pool in place;
+                the updated pool escapes through ``done`` (sp_decode.py's
+                closure idiom)."""
+                done = {}
+
+                def attn_fn(q, k_new, v_new, logit_softcap=None,
+                            sliding_window=None, values_from_k=None):
+                    # drop the B == 1 axis per leaf → (P+1, page, H, D)
+                    kl = jax.tree.map(lambda x: x[:, 0], k_buf)
+                    vl = jax.tree.map(lambda x: x[:, 0], v_buf)
+
+                    def put(pool, new):
+                        if kv_quant:  # quantize the M rows, scatter both
+                            new = quantize_kv_rows(new)
+                        return jax.tree.map(
+                            lambda p, n: p.at[page_ids, row_pos].set(
+                                n.astype(p.dtype)
+                            ),
+                            pool, new,
+                        )
+
+                    with jax.named_scope("mst.attn.kv_write"):
+                        kl = put(kl, k_new[:, 0])
+                        vl = put(vl, v_new[:, 0])
+                        done["k"] = jax.tree.map(lambda x: x[:, None], kl)
+                        done["v"] = jax.tree.map(lambda x: x[:, None], vl)
+                    with jax.named_scope("mst.attn.core"):
+                        out = paged_attention(
+                            q[:, 0],
+                            kl["d"] if kv_quant else kl,
+                            vl["d"] if kv_quant else vl,
+                            rows, lengths, model.scale,
+                            logit_softcap=logit_softcap,
+                            sliding_window=sliding_window,
+                            values_from_k=values_from_k,
+                            k_scale=kl["s"] if kv_quant else None,
+                            v_scale=vl["s"] if kv_quant else None,
+                        )
+                        return out[:, None]  # (M, T=1, Hq, Dv)
+
+                return attn_fn, done
+
             def make_layer(g):
                 def layer(h, p, k_buf, v_buf):
-                    # scatter the M new rows, attend over the pool in place;
                     # updated pool escapes through ``done`` as the scan ys
-                    # (sp_decode.py's closure idiom)
-                    done = {}
-
-                    def attn_fn(q, k_new, v_new, logit_softcap=None,
-                                sliding_window=None, values_from_k=None):
-                        # drop the B == 1 axis per leaf → (P+1, page, H, D)
-                        kl = jax.tree.map(lambda x: x[:, 0], k_buf)
-                        vl = jax.tree.map(lambda x: x[:, 0], v_buf)
-
-                        def put(pool, new):
-                            if kv_quant:  # quantize the M rows, scatter both
-                                new = quantize_kv_rows(new)
-                            return jax.tree.map(
-                                lambda p, n: p.at[page_ids, row_pos].set(
-                                    n.astype(p.dtype)
-                                ),
-                                pool, new,
-                            )
-
-                        with jax.named_scope("mst.attn.kv_write"):
-                            kl = put(kl, k_new[:, 0])
-                            vl = put(vl, v_new[:, 0])
-                            done["k"] = jax.tree.map(lambda x: x[:, None], kl)
-                            done["v"] = jax.tree.map(lambda x: x[:, None], vl)
-                        with jax.named_scope("mst.attn.core"):
-                            out = paged_attention(
-                                q[:, 0],
-                                kl["d"] if kv_quant else kl,
-                                vl["d"] if kv_quant else vl,
-                                rows, lengths, model.scale,
-                                logit_softcap=logit_softcap,
-                                sliding_window=sliding_window,
-                                values_from_k=values_from_k,
-                                k_scale=kl["s"] if kv_quant else None,
-                                v_scale=vl["s"] if kv_quant else None,
-                            )
-                            return out[:, None]  # (M, T=1, Hq, Dv)
-
+                    attn_fn, done = pool_attn(k_buf, v_buf)
                     h2, _, _ = model.sp_layer(p, h, offset_m, attn_fn, group=g)
                     return h2, done["k"], done["v"]
 
                 return layer
+
+            if state is not None:
+                # the model walks its own interleaved groups: the slot axis
+                # is its batch axis, the pool attention is handed in, and
+                # its state rows 0..M-1 (B == 1: a row is a slot) advance
+                # where ``active`` (row M, the scratch row, is not a decode
+                # step's to touch)
+                h, k, v, st = model.run_layers(
+                    layer_params, h, k, v, offset_m, mask=masks, state=state,
+                    active=active, paged_attn=pool_attn, **self._rl_kwargs,
+                )
+                out = jnp.where(active[:, None, None], h, 0).astype(cdt)
+                logits = self._vs_head(
+                    shared, vparts, jax.lax.psum(out, AXIS_PP)
+                )
+                return (
+                    logits,
+                    jax.tree.map(lambda x: x[None], k),
+                    jax.tree.map(lambda x: x[None], v),
+                    jax.tree.map(
+                        lambda x: x[None], jax.lax.optimization_barrier(st)
+                    ),
+                )
 
             # per-group scans over the stacked layer sub-trees: unshared,
             # the pool slices to each group's layer range (run_layers'
@@ -1318,6 +1488,7 @@ class PipelineEngine:
                 logits,
                 jax.tree.map(lambda x: x[None], k),
                 jax.tree.map(lambda x: x[None], v),
+                None,
             )
 
         spec_stage, spec_rep = P(AXIS_PP), P()
@@ -1336,8 +1507,9 @@ class PipelineEngine:
                 spec_rep,  # active (M,)
                 spec_rep,  # n_valid
                 spec_rep,  # page table
+                spec_stage,  # recurrent state pool (None: no leaves)
             ),
-            out_specs=(spec_rep, self._kv_spec, self._kv_spec),
+            out_specs=(spec_rep, self._kv_spec, self._kv_spec, spec_stage),
             check_vma=False,
         )
 
@@ -1348,25 +1520,29 @@ class PipelineEngine:
         if with_sampling:
 
             def forward_sample(layer_params, masks, vparts, shared, tokens, cache, recent, key, sp, n_valid):
-                logits, k, v = smapped(
+                logits, k, v, state = smapped(
                     layer_params, masks, vparts, shared, tokens, cache.k, cache.v,
-                    cache.offset, all_active, n_valid,
+                    cache.offset, all_active, n_valid, cache.state,
                 )
                 key, sub = jax.random.split(key)
                 flat = logits.reshape(M * B, -1)
                 tok, logprobs = sample_token(sub, flat, sp, recent)
                 recent = update_recent_tokens(recent, tok)
-                new_cache = KVCache(k=k, v=v, offset=cache.offset + n_valid)
+                new_cache = KVCache(
+                    k=k, v=v, offset=cache.offset + n_valid, state=state
+                )
                 return tok.reshape(M, B), logprobs, new_cache, recent, key
 
             return jax.jit(forward_sample, donate_argnums=(5, 6))
 
         def forward_logits(layer_params, masks, vparts, shared, tokens, cache, n_valid):
-            logits, k, v = smapped(
+            logits, k, v, state = smapped(
                 layer_params, masks, vparts, shared, tokens, cache.k, cache.v,
-                cache.offset, all_active, n_valid,
+                cache.offset, all_active, n_valid, cache.state,
             )
-            new_cache = KVCache(k=k, v=v, offset=cache.offset + n_valid)
+            new_cache = KVCache(
+                k=k, v=v, offset=cache.offset + n_valid, state=state
+            )
             return logits, new_cache
 
         return jax.jit(forward_logits, donate_argnums=(5,))
@@ -1395,16 +1571,17 @@ class PipelineEngine:
             if self._smapped_decode is None:
                 self._build_step(t_len=1, with_sampling=True)
             dense = self._smapped_decode
-            inner = lambda *args: dense(*args[:-1])  # drop the table arg
+            # drop the table arg, keep the state
+            inner = lambda *args: dense(*args[:-2], args[-1])  # noqa: E731
 
         def decode_step(
             layer_params, masks, vparts, shared, tokens, cache, active, recent,
             keys, sp, rep_sizes, table,
         ):
             one = jnp.asarray(1, jnp.int32)
-            logits, k, v = inner(
+            logits, k, v, state = inner(
                 layer_params, masks, vparts, shared, tokens, cache.k, cache.v,
-                cache.offset, active, one, table,
+                cache.offset, active, one, table, cache.state,
             )
             with jax.named_scope("mst.sample"):
                 split = jax.vmap(jax.random.split)(keys)  # (M, 2, 2)
@@ -1421,7 +1598,8 @@ class PipelineEngine:
                 )
                 recent = update_recent_tokens(recent, tok)
             new_cache = KVCache(
-                k=k, v=v, offset=cache.offset + active.astype(jnp.int32)
+                k=k, v=v, offset=cache.offset + active.astype(jnp.int32),
+                state=state,
             )
             return tok.reshape(M, B), logprobs, new_cache, recent, keys
 
@@ -1464,7 +1642,7 @@ class PipelineEngine:
 
                 def step(carry, _):
                     tok, k, v, offsets, recent, dkeys = carry
-                    logits, k, v = dense(
+                    logits, k, v, _ = dense(
                         layer_params, masks, vparts, shared, tok, k, v,
                         offsets, active, one,
                     )
@@ -1566,8 +1744,15 @@ class PipelineEngine:
         M, B = self.microbatches, self.batch
         if B != 1:
             raise ValueError("continuous batching expects batch=1 per slot")
+        refuse_recurrent(
+            self.model, "--draft",
+            "a rejected draft is undone by lowering the slot's offset",
+        )
         inner = self._build_smapped(t_len=K, paged=self.paged, keep_all=True)
-        if not self.paged:
+        if self.paged:
+            paged_inner = inner
+            inner = lambda *args: paged_inner(*args, None)  # noqa: E731
+        else:
             dense = inner
             inner = lambda *args: dense(*args[:-1])  # drop the table arg
         n_valid = jnp.asarray(K, jnp.int32)
@@ -1576,7 +1761,7 @@ class PipelineEngine:
                  cache, active, recent, vkeys, sp, rep_sizes, wcap, table):
             x = jnp.concatenate([tok, drafts[:-1].T], axis=1)  # (M, K)
             off0 = cache.offset
-            logits_all, k, v = inner(
+            logits_all, k, v, _ = inner(
                 layer_params, masks, vparts, shared, x[:, None, :],
                 cache.k, cache.v, off0, active, n_valid, table,
             )  # (M, 1, K, V)
@@ -1668,7 +1853,7 @@ class PipelineEngine:
             def prog(layer_params, masks, vparts, shared, toks, cache, active):
                 def step(carry, tok):
                     k, v, offsets = carry
-                    _, k, v = dense(
+                    _, k, v, _ = dense(
                         layer_params, masks, vparts, shared, tok, k, v,
                         offsets, active, one,
                     )
@@ -1690,33 +1875,36 @@ class PipelineEngine:
         slice ``slot`` at that slot's offset, last stage banks the
         last-valid-position logits."""
         model, S, M, B = self.model, self.num_stages, self.microbatches, self.batch
-        rl_kwargs = self._rl_kwargs
         t_len = self.prefill_chunk
 
         paged = self.paged
 
-        def body(layer_params, masks, vparts, shared, tokens, slot, k, v, offsets, n_valid, table):
+        def body(layer_params, masks, vparts, shared, tokens, slot, k, v,
+                 offsets, n_valid, table, state):
             layer_params = jax.tree.map(lambda x: x[0], layer_params)
             masks = jax.tree.map(lambda x: x[0], masks)
             vparts = jax.tree.map(lambda x: x[0], vparts)
             k = jax.tree.map(lambda x: x[0], k)
             v = jax.tree.map(lambda x: x[0], v)
+            state = jax.tree.map(lambda x: x[0], state)
             s = jax.lax.axis_index(AXIS_PP)
             h0 = jnp.zeros((B, t_len, model.config.hidden_size), self.cache_dtype)
             out0 = jnp.zeros((B, model.config.hidden_size), self.cache_dtype)
             offsets_pad = jnp.concatenate([offsets, jnp.zeros((1,), jnp.int32)])
 
             def tick(carry, t):
-                h_buf, k, v, out = carry
+                h_buf, k, v, state, out = carry
                 is_real = t == s
                 h_first = self._vs_embed(s, vparts, tokens).astype(h_buf.dtype)
                 h_in = jnp.where(s == 0, h_first, h_buf)
                 m_write = jnp.where(is_real, slot, M)
                 offset = offsets_pad[m_write]
                 k_m, v_m, row = self._kv_read(paged, k, v, table, m_write)
-                h_out, k_m, v_m = model.run_layers(
-                    layer_params, h_in, k_m, v_m, offset, mask=masks,
-                    **rl_kwargs,
+                # chunk k+1 starts from chunk k's state, a first chunk
+                # (offset 0) from zero; padded rows do not advance it
+                h_out, k_m, v_m, state = self._run_slot(
+                    layer_params, masks, h_in, k_m, v_m, offset, state,
+                    m_write, n_valid,
                 )
                 k, v = self._kv_write(paged, k, v, k_m, v_m, row, m_write, offset)
 
@@ -1728,15 +1916,18 @@ class PipelineEngine:
                 h_next = jax.lax.ppermute(
                     h_out, AXIS_PP, [(i, (i + 1) % S) for i in range(S)]
                 )
-                return (h_next, k, v, out), None
+                return (h_next, k, v, state, out), None
 
-            (_, k, v, out), _ = jax.lax.scan(tick, (h0, k, v, out0), jnp.arange(S))
+            (_, k, v, state, out), _ = jax.lax.scan(
+                tick, (h0, k, v, state, out0), jnp.arange(S)
+            )
             out = jax.lax.psum(out, AXIS_PP)
             logits = self._vs_head(shared, vparts, out)  # (B, V) f32
             return (
                 logits,
                 jax.tree.map(lambda x: x[None], k),
                 jax.tree.map(lambda x: x[None], v),
+                jax.tree.map(lambda x: x[None], state),
             )
 
         spec_stage, spec_rep = P(AXIS_PP), P()
@@ -1755,20 +1946,22 @@ class PipelineEngine:
                 spec_rep,  # offsets
                 spec_rep,  # n_valid
                 spec_rep,  # page table (paged mode; dummy otherwise)
+                spec_stage,  # recurrent state pool (None: no leaves)
             ),
-            out_specs=(spec_rep, self._kv_spec, self._kv_spec),
+            out_specs=(spec_rep, self._kv_spec, self._kv_spec, spec_stage),
             check_vma=False,
         )
         dummy_table = jnp.zeros((1, 1), jnp.int32)
 
         def prefill_chunk(layer_params, masks, vparts, shared, tokens, slot,
                           cache, n_valid, table=None):
-            logits, k, v = smapped(
+            logits, k, v, state = smapped(
                 layer_params, masks, vparts, shared, tokens, slot, cache.k, cache.v,
                 cache.offset, n_valid, dummy_table if table is None else table,
+                cache.state,
             )
             offsets = cache.offset.at[slot].add(n_valid)
-            return logits, KVCache(k=k, v=v, offset=offsets)
+            return logits, KVCache(k=k, v=v, offset=offsets, state=state)
 
         return jax.jit(prefill_chunk, donate_argnums=(6,))
 

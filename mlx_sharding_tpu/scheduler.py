@@ -72,6 +72,8 @@ from mlx_sharding_tpu.cache import (
     KVCache,
     export_pool_pages,
     import_pool_pages,
+    has_recurrent_state,
+    refuse_recurrent,
     rewind_slot_offset,
 )
 from mlx_sharding_tpu.generate import block_lp_outputs, block_token_logprobs
@@ -306,6 +308,28 @@ class ContinuousBatcher:
                  sleep: SleepFn = WALL_SLEEP):
         if engine.batch != 1:
             raise ValueError("continuous batching expects engine batch=1")
+        # A model with recurrent state beside its K/V pages (cache.py): a
+        # slot that starts over at position 0 reads its state as zero inside
+        # the programs (chained after any block in flight), chunk k+1 starts
+        # from chunk k's, an inactive slot's is not touched — so admission,
+        # a freed slot's offset rewind, discard preemption (fold and
+        # re-prefill from 0) and a blockless migration all carry it. What
+        # re-enters a sequence at a LATER position from pages alone cannot.
+        self._recurrent = has_recurrent_state(engine.model)
+        for flag, on, why in (
+            ("--prompt-cache", prefix_cache,
+             "a prefix hit starts a slot past pages whose state nobody kept"),
+            ("--prefix-store", prefix_store is not None,
+             "a store hit starts a slot past pages whose state nobody kept"),
+            ("--spill-bytes", spill_bytes is not None,
+             "a spilled block holds pages of K/V only"),
+            ("--draft", draft not in ("auto", "off") or draft_engine is not None,
+             "a rejected draft is undone by lowering the slot's offset"),
+        ):
+            if on:
+                refuse_recurrent(engine.model, flag, why)
+                if flag == "--draft" and draft_engine is not None:  # either side
+                    refuse_recurrent(draft_engine.model, flag, why)
         if max_queue is not None and (not isinstance(max_queue, int) or max_queue < 1):
             raise ValueError(f"max_queue must be a positive int, got {max_queue!r}")
         if draft not in ("auto", "off", "ngram", "engine"):
@@ -781,6 +805,9 @@ class ContinuousBatcher:
         self._tokens_dropped = {
             "slot_finished": 0, "cancelled": 0, "abandoned_block": 0,
         }
+        # first prefill chunks dispatched for a model with recurrent state:
+        # each starts its slot's state from zero (inside the chunk's program)
+        self.state_resets = 0
         # _quiesce calls that found a block in flight, by call site: each is
         # one lost overlap of the double-buffered pipeline
         self._drains = dict.fromkeys(
@@ -1042,6 +1069,11 @@ class ContinuousBatcher:
             logit_bias=logit_bias,
             prefill_only=bool(_prefill_only),
         )
+        if _prefill_only:
+            refuse_recurrent(
+                self.engine.model, "--disagg",
+                "the prefill-to-decode hand-off moves pages of K/V only",
+            )
         if _resume is not None:
             req.produced = produced0
             req.history = hist
@@ -1381,6 +1413,18 @@ class ContinuousBatcher:
             "tokens_emitted": self._tokens_emitted,
             "tokens_dropped": dict(self._tokens_dropped),
             "drains": dict(self._drains),
+        }
+
+    def state_stats(self) -> Optional[dict]:
+        """The recurrent state pool for /metrics (None for a model without
+        one): slots whose state belongs to a request, the pool's bytes, and
+        how many times a slot's state was started from zero."""
+        if not self._recurrent:
+            return None
+        return {
+            "slots_in_use": sum(r is not None for r in self._slots),
+            "bytes": self.engine.state_bytes(),
+            "resets": self.state_resets,
         }
 
     def latency_stats(self) -> dict:
@@ -2073,7 +2117,8 @@ class ContinuousBatcher:
         and returns False: the caller falls back to normal re-prefill
         admission, which can never double-emit because nothing was queued
         to the consumer here."""
-        if not self.paged or self.draft is not None:
+        if not self.paged or self.draft is not None or self._recurrent:
+            # (a block holds pages of K/V only: no recurrent state)
             self._fold_history(req)
             return False
         page = self.engine.page_size
@@ -2198,6 +2243,8 @@ class ContinuousBatcher:
         t0 = time.perf_counter() if tr is not None else 0.0
         if req.prefill_pos < req.prompt.size:
             chunk, n_valid = self._chunk_at(req.prompt, req.prefill_pos, c)
+            if self._recurrent and req.prefill_pos == 0:
+                self.state_resets += 1
             logits, self.cache = eng.prefill_slot()(
                 eng.layer_params, eng.layer_masks, eng.vocab_parts,
                 eng.shared_params, self._put(jnp.asarray(chunk[None])),
@@ -2805,8 +2852,8 @@ class ContinuousBatcher:
             req.spilled = False
             block = self.spill.take(req) if self.spill is not None else None
         if (block is None and slot >= 0 and self.paged
-                and self.draft is None and self._prefill_done(req)
-                and req.history):
+                and self.draft is None and not self._recurrent
+                and self._prefill_done(req) and req.history):
             page = self.engine.page_size
             n_tokens = req.prompt.size + max(0, len(req.history) - 1)
             n_pages = -(-max(1, n_tokens) // page)

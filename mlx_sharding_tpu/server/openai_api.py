@@ -37,6 +37,7 @@ import numpy as np
 
 from mlx_sharding_tpu import tracing
 from mlx_sharding_tpu.analysis.runtime import make_lock
+from mlx_sharding_tpu.cache import refuse_recurrent
 from mlx_sharding_tpu.generate import TokenLogprobs
 from mlx_sharding_tpu.kv_compress import load_compress_map
 from mlx_sharding_tpu.kv_share import load_share_map
@@ -503,6 +504,11 @@ class ModelProvider:
                     len(self.stage_bounds) if self.stage_bounds
                     else (self.num_stages or 1)
                 )
+                if self.disagg:
+                    refuse_recurrent(
+                        model, "--disagg",
+                        "the prefill-to-decode hand-off moves pages of K/V only",
+                    )
                 if (
                     stages > 1 or self.concurrent > 1 or self.tp > 1
                     or self.ep > 1 or self.replicas > 1 or self.disagg

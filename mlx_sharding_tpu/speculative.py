@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from mlx_sharding_tpu.cache import refuse_recurrent
 from mlx_sharding_tpu.generate import (
     REPETITION_WINDOW,
     Generator,
@@ -323,6 +324,16 @@ class AcceptanceTracker:
         }
 
 
+def _refuse_recurrent_pair(*models) -> None:
+    """A rejected draft is undone by lowering the cache's offset, which
+    does not rewind a recurrent state: neither side may have one."""
+    for m in models:
+        refuse_recurrent(
+            m, "--draft",
+            "a rejected draft is undone by lowering the cache's offset",
+        )
+
+
 class SpeculativeGenerator:
     """``generate_step`` contract over a (target, draft) model pair.
 
@@ -346,6 +357,7 @@ class SpeculativeGenerator:
     ):
         if spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
+        _refuse_recurrent_pair(model, draft_model)
         tv = getattr(model.config, "vocab_size", None)
         dv = getattr(draft_model.config, "vocab_size", None)
         if tv != dv:
@@ -622,6 +634,7 @@ class NgramSpeculativeGenerator:
             raise ValueError(
                 f"spec_window_max must be >= 2, got {spec_window_max}"
             )
+        _refuse_recurrent_pair(model)
         if not (model.config.is_first_stage and model.config.is_last_stage):
             raise ValueError(
                 "speculative decoding needs the FULL model on one program "

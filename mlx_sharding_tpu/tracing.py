@@ -103,6 +103,13 @@ MODEL_SCOPES = (
     "mst.moe.experts.matmul",
     "mst.moe.experts.scan",
     "mst.moe.shared",
+    "mst.moe.latent",
+    "mst.ssm.in_proj",
+    "mst.ssm.conv",
+    "mst.ssm.scan",
+    "mst.ssm.step",
+    "mst.ssm.out_proj",
+    "mst.state_pool.regroup",
     "mst.mlp.dense",
     "mst.norm",
     "mst.kv_pool.regroup",

@@ -396,6 +396,16 @@ class ServingMetrics:
                             "# TYPE mst_kv_pool_pages_high_water gauge",
                             f"mst_kv_pool_pages_high_water {high}",
                         ]
+                    state = getattr(b, "state_stats", lambda: None)()
+                    if state is not None:
+                        lines += [
+                            "# TYPE mst_state_slots_in_use gauge",
+                            f"mst_state_slots_in_use {state['slots_in_use']}",
+                            "# TYPE mst_state_bytes gauge",
+                            f"mst_state_bytes {state['bytes']}",
+                            "# TYPE mst_state_resets_total counter",
+                            f"mst_state_resets_total {state['resets']}",
+                        ]
                     if pages is not None and getattr(b, "overcommit", False):
                         lines += [
                             "# TYPE mst_preemptions_total counter",
@@ -1067,6 +1077,13 @@ _HELP = {
         "abandoned_block (futures dropped).",
     "mst_pipeline_drains_total":
         "Pipeline drains (a block was in flight at a quiesce), by call site.",
+    "mst_state_slots_in_use":
+        "Slots whose recurrent state (Mamba-2 SSM state and convolution "
+        "tail) belongs to an admitted request.",
+    "mst_state_bytes": "Bytes of the per-slot recurrent state pool.",
+    "mst_state_resets_total":
+        "First prefill chunks dispatched: each starts its slot's recurrent "
+        "state from zero, inside the chunk's program.",
     "mst_faults_armed":
         "Currently armed fault-injection sites (should be 0 in prod).",
     "mst_faults_malformed_total":

@@ -211,3 +211,58 @@ def test_no_scope_outside_the_vocabulary_in_the_source():
     assert set(tracing.TICK_PHASES) >= {"harvest_wait", "idle_wait", "other"}
     assert tracing.phase_span_name("dispatch") == "mst.decode_block"
     assert tracing.phase_span_name("emit") == "mst.emit"
+
+
+@pytest.fixture(scope="module")
+def nemotron_batcher():
+    from mlx_sharding_tpu.models import build_model
+
+    model, _ = build_model(dict(
+        model_type="nemotron_h", vocab_size=128, hidden_size=32,
+        num_hidden_layers=4, hybrid_override_pattern="ME*M",
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        mamba_num_heads=4, mamba_head_dim=8, n_groups=2, ssm_state_size=8,
+        conv_kernel=4, chunk_size=8, n_routed_experts=2,
+        num_experts_per_tok=2, moe_intermediate_size=16, moe_latent_size=16,
+        moe_shared_expert_intermediate_size=16, moe_expert_share=2,
+    ))
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    eng = PipelineEngine(
+        model, params, pipeline_mesh(1), microbatches=2, max_seq=64,
+        cache_dtype=jnp.float32, prefill_chunk=8, pool_pages=10, page_size=8,
+    )
+    batcher = ContinuousBatcher(eng, decode_block=3)
+    yield batcher
+    batcher.close()
+
+
+@hard_timeout(420)
+def test_served_nemotron_programs_carry_the_scope_vocabulary(nemotron_batcher):
+    """The second family: its Mamba-2 layers, latent projections and state
+    pool open the scopes PR 28 added, under the same program names."""
+    b, eng = nemotron_batcher, nemotron_batcher.engine
+    assert len(list(b.generate_step([3, 4, 5, 6], max_tokens=5))) == 5
+    block = b._decode_block_prog(False).lower(
+        eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+        b.last_tok, b.cache, b.active, b.recent, b.keys, b.sp, b.rep_sizes,
+        b.table,
+    ).as_text(debug_info=True)
+    prefill = eng.prefill_slot().lower(
+        eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+        jnp.zeros((1, 8), jnp.int32), jnp.asarray(0, jnp.int32), b.cache,
+        jnp.asarray(8, jnp.int32), b.table,
+    ).as_text(debug_info=True)
+    assert "module @jit_block " in block
+    assert "module @jit_prefill_chunk " in prefill
+    vocabulary = set(tracing.MODEL_SCOPES)
+    for text in (block, prefill):
+        assert _scopes_in(text) <= vocabulary, _scopes_in(text) - vocabulary
+    layers = {"mst.embed", "mst.attn.qkv", "mst.attn.kv_write", "mst.attn.core",
+              "mst.moe.router", "mst.moe.experts", "mst.moe.experts.scan",
+              "mst.moe.shared", "mst.moe.latent", "mst.norm", "mst.head",
+              "mst.ssm.in_proj", "mst.ssm.conv", "mst.ssm.out_proj",
+              "mst.state_pool.regroup", "mst.kv_pool.regroup"}
+    # decode: the one-step recurrence; the sampler is in the block
+    assert _scopes_in(block) == layers | {"mst.ssm.step", "mst.sample"}
+    # prefill: the chunked (SSD) form
+    assert _scopes_in(prefill) == layers | {"mst.ssm.scan"}

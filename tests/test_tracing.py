@@ -473,8 +473,17 @@ def test_trace_profile_puts_tick_spans_on_the_profilers_clock(tmp_path):
     # flight recorder's clock, on the profiler's timeline
     pcs = [float(s["pc"]) for s in spans["mst.tick"]]
     assert pcs == sorted(pcs) and pc0 - 1.0 < pcs[0] and pcs[-1] <= pc1
-    t0s = [s["_t0"] for s in spans["mst.tick"]]
-    assert (pcs[-1] - pcs[0]) == pytest.approx((t0s[-1] - t0s[0]) / 1e9, abs=5e-3)
+    # What the mechanism guarantees, whatever the machine's load: ``pc`` is
+    # read BEFORE its tick's span opens and AFTER the tick before it closed,
+    # so ONE offset between the two clocks puts every pc inside that gap.
+    # (The gap itself is as long as the scheduler keeps the thread off the
+    # CPU there: comparing the two clocks' elapsed times to 5 ms, as this
+    # test did, failed under six workers.)
+    ticks = spans["mst.tick"]
+    assert len(ticks) >= 3
+    latest_close = max(a["_t1"] / 1e9 - pc for a, pc in zip(ticks, pcs[1:]))
+    earliest_open = min(t["_t0"] / 1e9 - pc for t, pc in zip(ticks, pcs))
+    assert latest_close <= earliest_open, (latest_close, earliest_open)
     blocks = spans["mst.decode_block"]
     seqs = [int(s["seq"]) for s in blocks]
     assert seqs == list(range(seqs[0], seqs[0] + len(seqs)))
