@@ -14,6 +14,14 @@ shard/server/model/llama.py:92-107, applied at load time.
 Models here are *functional*: a model object holds only the (static) config;
 parameters and KV cache are explicit pytree arguments. That is what makes
 them jit/pjit/shard_map-transparent.
+
+Which leaves ride the layer scan (``scan_layers``): every leaf of a group's
+stack is a scanned ``xs`` and reaches the layer body as that layer's slice,
+except the leaves a model names through ``BaseModel.scan_in_place`` —
+DeepSeek-V2's packed expert stacks ``w_gate``, ``w_up``, ``w_down`` — which
+stay whole ``(L, E, …)`` beside the layer's index and are read in place by
+``(layer, expert)``. No other model names any, so their scans are as they
+were.
 """
 
 from __future__ import annotations
@@ -38,7 +46,12 @@ def dense_init(key, in_dim: int, out_dim: int, dtype, scale: float | None = None
     return (jax.random.normal(key, (in_dim, out_dim), jnp.float32) * scale).astype(dtype)
 
 
-def scan_layers(layer_fn, h, layer_params, k, v, mask=None):
+#: the key under which a layer body finds its index when some of its leaves
+#: are whole stacks (scan_layers' ``in_place``)
+LAYER_INDEX = "layer"
+
+
+def scan_layers(layer_fn, h, layer_params, k, v, mask=None, in_place=()):
     """``lax.scan`` over a stacked layer group with optional per-layer
     active masking.
 
@@ -47,15 +60,33 @@ def scan_layers(layer_fn, h, layer_params, k, v, mask=None):
     slots leave both the hidden state and their cache rows untouched, which is
     what lets the fused SPMD engine pad uneven/heterogeneous stages to a
     uniform per-stage slot count: padding slots carry zero params and scan
-    through as no-ops regardless of architecture semantics."""
+    through as no-ops regardless of architecture semantics.
+
+    ``in_place`` names the leaves of ``layer_params`` that do NOT ride the
+    scan. A scanned leaf reaches the body as that layer's slice, which the
+    compiler materializes: a copy of the slice out of the stack, every
+    iteration. That is right for the small leaves (norms, attention and
+    shared projections, the router) and ruinous for a layer's packed expert
+    stacks, of which the body reads a few experts. Such leaves are closed
+    over whole, the scan counts the layers, and the body's ``p`` holds them
+    as ``(L, …)`` stacks beside ``p[LAYER_INDEX]``, the index to read them
+    by where they lie (``ops.moe.apply_experts(layer=…)``). A padding slot's
+    index is still a valid row, of zero parameters."""
+    whole = {name: layer_params[name] for name in in_place}
+    index = None
+    if whole:
+        layer_params = {n: w for n, w in layer_params.items() if n not in whole}
+        index = jnp.arange(jax.tree.leaves(whole)[0].shape[0])
 
     def body(h, xs):
-        if mask is None:
-            p, k_buf, v_buf = xs
-            h, k_buf, v_buf = layer_fn(h, p, k_buf, v_buf)
-            return h, (k_buf, v_buf)
-        p, k_buf, v_buf, m = xs
+        # ``m`` and ``i`` are None (empty pytrees, no scan operands) when
+        # there is no mask and nothing is read in place
+        p, k_buf, v_buf, m, i = xs
+        if whole:
+            p = {**p, **whole, LAYER_INDEX: i}
         h2, k2, v2 = layer_fn(h, p, k_buf, v_buf)
+        if m is None:
+            return h2, (k2, v2)
         # tree-map: K/V buffers may be int8 {d, s} leaf pairs (paged pools)
         sel = lambda a, b: jnp.where(m, a, b)  # noqa: E731
         return jnp.where(m, h2, h), (
@@ -63,7 +94,7 @@ def scan_layers(layer_fn, h, layer_params, k, v, mask=None):
             jax.tree.map(sel, v2, v_buf),
         )
 
-    xs = (layer_params, k, v) if mask is None else (layer_params, k, v, mask)
+    xs = (layer_params, k, v, mask, index)
     # the scan's own work is slicing the cache per layer and stacking it
     # back; the layer body opens deeper scopes for everything it does
     with jax.named_scope("mst.kv_pool.regroup"):
@@ -205,6 +236,13 @@ class BaseModel:
         The fused pipeline engine uses this to build per-stage uniform
         stacks with masked padding for uneven/heterogeneous splits."""
         return {None: (0, self.config.num_hidden_layers)}
+
+    def scan_in_place(self, group, stack: dict) -> tuple:
+        """Names of ``stack``'s leaves (``group`` as in ``sp_groups``) that a
+        layer scan over it leaves where they lie instead of slicing per
+        layer (``scan_layers``' ``in_place``): the model's layer body must
+        then read them by ``p[LAYER_INDEX]``. Default: none."""
+        return ()
 
     #: per-sequence state beside the K/V rows (cache.KVCache.state)
     has_recurrent_state = False

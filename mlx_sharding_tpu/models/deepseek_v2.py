@@ -18,6 +18,9 @@ head-dim cache shape (ref :120-125). Here the architecture is first-party:
 
 The stage's layers run as TWO scans (dense prefix, then MoE) since their
 param trees differ; the KV cache is one stacked buffer sliced between them.
+The MoE scan's packed expert stacks do not ride it: they stay ``(L, E, …)``
+and each layer reads its chosen experts out of them by ``(layer, expert)``
+(``scan_in_place``; models/base.py says why).
 """
 
 from __future__ import annotations
@@ -27,9 +30,16 @@ import jax.numpy as jnp
 
 from mlx_sharding_tpu.cache import KVCache, advance, write_layer_kv
 from mlx_sharding_tpu.config import DeepseekV2Config
-from mlx_sharding_tpu.models.base import BaseModel, dense_init, stack_layers
+from mlx_sharding_tpu.models.base import (
+    LAYER_INDEX,
+    BaseModel,
+    dense_init,
+    scan_layers,
+    stack_layers,
+)
 from mlx_sharding_tpu.ops import causal_attention, rms_norm
 from mlx_sharding_tpu.ops.moe import apply_experts, deepseek_routing
+from mlx_sharding_tpu.ops.quant import is_quantized
 from mlx_sharding_tpu.ops.rope import (
     apply_rope_interleaved,
     rope_frequencies,
@@ -100,6 +110,19 @@ class DeepseekV2Model(BaseModel):
         if fk < cfg.num_hidden_layers:
             out["moe"] = (fk, cfg.num_hidden_layers)
         return out
+
+    def scan_in_place(self, group, stack):
+        """The moe group's routed expert stacks, when they are packed: a
+        layer's three are 345 MB at V2-Lite's widths, of which a decode step
+        reads the chosen experts' share and a prefill chunk every expert
+        once — never a reason to copy them out of the stack first. One form
+        for every row count: the 256-row prefill chunk (``_apply_scan``
+        indexing the same flat view) compiles to smaller temporaries than
+        with scanned slices and runs faster (PERF.md section 6, PR 29)."""
+        names = ("w_gate", "w_up", "w_down")
+        if group == "moe" and all(is_quantized(stack[n]) for n in names):
+            return names
+        return ()
 
     def ep_layer_axes(self) -> dict:
         """Nested (per-group) map: only the moe group's routed expert
@@ -316,6 +339,8 @@ class DeepseekV2Model(BaseModel):
         routed = apply_experts(
             flat, weights, idx, p["w_gate"], p["w_up"], p["w_down"],
             ep_axis=ep_axis, group_size=self._gs, bits=self._bits,
+            # whole (L, E, …) stacks beside the layer's index (scan_in_place)
+            layer=p.get(LAYER_INDEX),
         )
         # shared experts are always-on and replicated across ep — their
         # contribution must NOT enter the ep psum
@@ -357,8 +382,6 @@ class DeepseekV2Model(BaseModel):
         (not the config bounds), so the fused engine's padded uniform stacks
         and the single-program/chained stage params both work; ``mask`` is a
         matching {group: (L,) bool} dict for padded slots."""
-        from mlx_sharding_tpu.models.base import scan_layers
-
         n_dense = (
             # tree.leaves: group values may be packed {q, scales, biases}
             jax.tree.leaves(layer_params["dense"])[0].shape[0]
@@ -387,6 +410,7 @@ class DeepseekV2Model(BaseModel):
                 ),
                 h, layer_params["moe"], k_m, v_m,
                 None if mask is None else mask["moe"],
+                in_place=self.scan_in_place("moe", layer_params["moe"]),
             )
             ks.append(km)
             vs.append(vm)

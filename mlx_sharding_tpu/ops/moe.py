@@ -35,6 +35,16 @@ of its first expert): routing stays over all experts, the layer computes
 its own experts' part, and what the absent experts would add is left out.
 That is the ``ep_axis`` branch without ``axis_index`` and without the
 ``psum``; under ``ep_axis`` the same code runs with both.
+
+Which leaves ride a layer scan and which are read in place: a caller that
+walks stacked layers may keep the expert stacks whole, ``(L, E, …)``, and
+pass ``layer`` — the kernel and the scan then read expert ``(layer, e)``
+as row ``layer * E + e`` of the stacks flattened to ``(L*E, …)`` (a view:
+only the two minor dimensions are tiled), where they lie in HBM. Handing
+them one layer's ``(E, …)`` slice instead makes the layer scan copy that
+slice out of the stack every iteration (345 MB a layer for
+DeepSeek-V2-Lite's packed experts). The off-chip fallbacks take the
+layer's slice first and then do what they did.
 """
 
 from __future__ import annotations
@@ -141,10 +151,11 @@ def apply_experts(
 
     ``expert_base``: the stacks hold experts ``base .. base + E_local`` of a
     wider routing (``idx`` are global ids): only their part is computed.
-    ``layer`` (with ``expert_base`` or ``ep_axis``): the stacks keep their
-    leading layer axis ``(L, E, …)`` and the scan reads expert ``(layer,
-    e)`` out of them where they lie — slicing one layer's experts out first
-    would copy them (gigabytes a step) before the scan could read them.
+    ``layer``: the stacks keep their leading layer axis ``(L, E, …)`` and
+    the kernel and the scan read expert ``(layer, e)`` out of them where
+    they lie — slicing one layer's experts out first would copy them
+    (gigabytes a step) before anything could read them. The gather
+    fallbacks (off the chip, dense stacks) do slice the layer first.
 
     ``ep_axis``: inside shard_map with the expert stacks sharded over that
     mesh axis, each device holds E/ep experts whose GLOBAL ids start at
@@ -166,23 +177,35 @@ def apply_experts(
             layer=layer,
         )
         return acc if ep_axis is None else jax.lax.psum(acc, ep_axis)
-    if layer is not None:
-        raise ValueError("apply_experts: `layer` needs expert_base or ep_axis")
-    if n <= GATHER_PATH_MAX_TOKENS:
-        # decode path: HBM traffic is k/E of the stacks — and 4x less again
-        # when they are packed: the kernel reads each chosen expert's packed
-        # bytes once; off the chip, or at shapes it does not serve, gather
-        # the packed leaves and dequantize the gathered slice
-        if not is_quantized(w_up):
-            return _apply_gather(x, weights, idx, w_gate, w_up, w_down)
-        path = (
-            _apply_packed_kernel
-            if packed_kernel_ok(n, w_gate, w_up, w_down, group_size, bits)
-            else _apply_gather_packed
+    if n > GATHER_PATH_MAX_TOKENS:
+        return _apply_scan(
+            x, weights, idx, w_gate, w_up, w_down, group_size, bits, layer=layer
         )
-        _log_path_once(path.__name__, n, idx.shape[1], tuple(w_up["q"].shape))
-        return path(x, weights, idx, w_gate, w_up, w_down, group_size, bits)
-    return _apply_scan(x, weights, idx, w_gate, w_up, w_down, group_size, bits)
+    # decode path: HBM traffic is k/E of the stacks — and 4x less again
+    # when they are packed: the kernel reads each chosen expert's packed
+    # bytes once; off the chip, or at shapes it does not serve, gather
+    # the packed leaves and dequantize the gathered slice
+    packed = is_quantized(w_up)
+    kernel = packed and packed_kernel_ok(n, w_gate, w_up, w_down, group_size, bits)
+    if packed:
+        _log_path_once(
+            (_apply_packed_kernel if kernel else _apply_gather_packed).__name__,
+            n, idx.shape[1], tuple(w_up["q"].shape), kernel and layer is not None,
+        )
+    if kernel:
+        return _apply_packed_kernel(
+            x, weights, idx, w_gate, w_up, w_down, group_size, bits, layer=layer
+        )
+    if layer is not None:  # the gathers index one layer's (E, …) stacks
+        w_gate, w_up, w_down = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+            (w_gate, w_up, w_down),
+        )
+    if packed:
+        return _apply_gather_packed(
+            x, weights, idx, w_gate, w_up, w_down, group_size, bits
+        )
+    return _apply_gather(x, weights, idx, w_gate, w_up, w_down)
 
 
 def _apply_gather(x, weights, idx, w_gate, w_up, w_down):
@@ -214,13 +237,28 @@ def packed_kernel_ok(n, w_gate, w_up, w_down, gs, bits) -> bool:
 
 
 @functools.lru_cache(maxsize=None)
-def _log_path_once(path: str, n: int, k: int, stack_shape: tuple) -> None:
+def _log_path_once(
+    path: str, n: int, k: int, stack_shape: tuple, in_place: bool
+) -> None:
     """One line per distinct (path, shape): the choice is made at trace time
-    from static shapes, so this is once per compiled program, at its build."""
+    from static shapes, so this is once per compiled program, at its build —
+    ``in_place`` included: whether the kernel reads ``(layer, expert)`` out
+    of the whole ``(L, E, …)`` stacks or is handed one layer's slice."""
     logger.info(
-        "moe experts: %s for %d rows x top-%d over packed stacks %s",
+        "moe experts: %s for %d rows x top-%d over packed stacks %s, %s",
         path, n, k, stack_shape,
+        "read in place by (layer, expert)" if in_place
+        else "one layer's stacks as handed in",
     )
+
+
+def _flat_layers(*stacks):
+    """``(L, E, …)`` stacks as ``(L*E, …)``, a view: ONE index on ONE axis
+    is what a Pallas index map takes and what the compiler fuses into a
+    matmul's operand read. Two nested indices let it hoist the layer's and
+    copy that layer's whole expert stack every iteration; one slice over
+    two axes it materializes per expert."""
+    return jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), stacks)
 
 
 def distinct_experts(idx, num_experts: int):
@@ -236,7 +274,8 @@ def distinct_experts(idx, num_experts: int):
 
 
 def _apply_packed_kernel(
-    x, weights, idx, w_gate, w_up, w_down, gs, bits, interpret=False
+    x, weights, idx, w_gate, w_up, w_down, gs, bits, interpret=False,
+    layer=None,
 ):
     """Each distinct expert of the step once: the projections (three, or
     two for un-gated experts) run as
@@ -244,12 +283,18 @@ def _apply_packed_kernel(
     N rows against one expert's tile a grid step, and the down projection
     combines with ``coef[t, n] = sum_k weights[n, k] * (idx[n, k] == ids[t])``
     — the arithmetic of ``_apply_scan``'s body, skipping the experts nobody
-    chose. No dense expert tensor is written to HBM."""
+    chose. No dense expert tensor is written to HBM. With ``layer`` the
+    stacks are ``(L, E, …)`` and the kernel's id table names row ``layer *
+    E + id`` of their ``(L*E, …)`` view: the same bytes by another index."""
     from mlx_sharding_tpu.ops.quant_matmul import quant_matmul_experts
 
     per_word = 32 // bits
-    ids, live = distinct_experts(idx, w_up["q"].shape[0])
+    num_experts = w_up["q"].shape[0 if layer is None else 1]
+    ids, live = distinct_experts(idx, num_experts)
     coef = ((idx == ids[:, None, None]) * weights).sum(axis=-1)  # (T, N)
+    if layer is not None:
+        w_gate, w_up, w_down = _flat_layers(w_gate, w_up, w_down)
+        ids = layer * num_experts + ids
 
     def planes(a):  # (..., N, IN) -> (..., per_word, N, IN / per_word)
         a = a.reshape(*a.shape[:-1], a.shape[-1] // per_word, per_word)
@@ -303,14 +348,7 @@ def _apply_scan(x, weights, idx, w_gate, w_up, w_down, gs=64, bits=4,
     ]
 
     if layer is not None:
-        # (L, E, …) → (L*E, …), a view: ONE index on ONE axis is what the
-        # compiler fuses into the matmul's operand read. Two nested indices
-        # let it hoist the layer's and copy that layer's whole expert stack
-        # every iteration; one slice over two axes it materializes per expert
-        flat = lambda w: jax.tree.map(  # noqa: E731
-            lambda a: a.reshape((-1,) + a.shape[2:]), w
-        )
-        w_gate, w_up, w_down = flat(w_gate), flat(w_up), flat(w_down)
+        w_gate, w_up, w_down = _flat_layers(w_gate, w_up, w_down)
 
     def body(acc, xs):
         wg, wu, wd, e = xs  # wg is None (an empty pytree) for un-gated experts

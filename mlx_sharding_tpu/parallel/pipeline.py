@@ -1468,6 +1468,7 @@ class PipelineEngine:
                         v_in = jax.tree.map(lambda x: x[lo : lo + n_g], v)
                     h, k_g, v_g = scan_layers(
                         make_layer(g), h, stack, k_in, v_in, mask_g,
+                        in_place=model.scan_in_place(g, stack),
                     )
                     k_parts.append(k_g)
                     v_parts.append(v_g)

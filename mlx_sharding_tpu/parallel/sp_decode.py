@@ -218,6 +218,7 @@ class SpDecode:
                     h, k_g, v_g = scan_layers(
                         make_layer(g), h, stack,
                         k_c[lo : lo + n_g], v_c[lo : lo + n_g],
+                        in_place=model.scan_in_place(g, stack),
                     )
                     k_parts.append(k_g)
                     v_parts.append(v_g)

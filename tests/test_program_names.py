@@ -266,3 +266,107 @@ def test_served_nemotron_programs_carry_the_scope_vocabulary(nemotron_batcher):
     assert _scopes_in(block) == layers | {"mst.ssm.step", "mst.sample"}
     # prefill: the chunked (SSD) form
     assert _scopes_in(prefill) == layers | {"mst.ssm.scan"}
+
+
+# ------------------------------------------- what rides the layer scan
+
+
+def _walk(jaxpr, scans=()):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it, each with the
+    ``scan`` equations that enclose it, outermost first."""
+    for eqn in jaxpr.eqns:
+        yield eqn, scans
+        inner = scans + (eqn,) if eqn.primitive.name == "scan" else scans
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub, inner)
+
+
+def _scanned(eqn):
+    """Avals of a ``scan`` equation's ``xs`` (not its consts, not its carry)."""
+    skip = eqn.params["num_consts"] + eqn.params["num_carry"]
+    return [v.aval for v in eqn.invars[skip:]]
+
+
+@hard_timeout(420)
+def test_packed_expert_stacks_do_not_ride_the_layer_scan(monkeypatch):
+    """The tiny packed DeepSeek decode block, traced as on a TPU: the MoE
+    layer scan's ``xs`` hold the small leaves, the pool and the layer
+    counter — no leaf of the expert stacks — and the expert-indexed kernel's
+    ``q`` operand is the whole stack as ``(L*E, out, words)``."""
+    from mlx_sharding_tpu.models import build_model
+    from mlx_sharding_tpu.ops.quant import quantize_jax
+
+    n_moe, n_exp, width = 2, 4, 128
+    model, _ = build_model(dict(
+        model_type="deepseek_v2", vocab_size=128, hidden_size=width,
+        intermediate_size=64, moe_intermediate_size=width,
+        num_hidden_layers=1 + n_moe, num_attention_heads=4,
+        num_key_value_heads=4, kv_lora_rank=16, q_lora_rank=None,
+        qk_rope_head_dim=8, qk_nope_head_dim=16, v_head_dim=12,
+        n_routed_experts=n_exp, n_shared_experts=1, num_experts_per_tok=2,
+        first_k_dense_replace=1, mla_cache_mode="compressed",
+        quantization={"group_size": 64, "bits": 4},
+    ))
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    moe = params["layers"]["moe"]
+    for name in ("w_gate", "w_up", "w_down"):  # (L, E, in, out) -> MLX triples
+        moe[name] = dict(zip(
+            ("q", "scales", "biases"),
+            quantize_jax(jnp.swapaxes(moe[name], -1, -2), 64, 4),
+        ))
+    eng = PipelineEngine(
+        model, params, pipeline_mesh(1), microbatches=2, max_seq=64,
+        cache_dtype=jnp.float32, prefill_chunk=8, pool_pages=10, page_size=8,
+        paged_attention="ragged",
+    )
+    b = ContinuousBatcher(eng, decode_block=3)
+    try:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        jaxpr = jax.make_jaxpr(b._decode_block_prog(False))(
+            eng.layer_params, eng.layer_masks, eng.vocab_parts,
+            eng.shared_params, b.last_tok, b.cache, b.active, b.recent,
+            b.keys, b.sp, b.rep_sizes, b.table,
+        )
+    finally:
+        b.close()
+    kernels = [
+        (eqn, scans) for eqn, scans in _walk(jaxpr.jaxpr)
+        if eqn.primitive.name == "pallas_call"
+        and eqn.params["name"] == "quant_matmul_experts"
+    ]
+    assert len(kernels) == 3  # gate, up, down: one layer body, traced once
+    words = width // 8
+    for eqn, scans in kernels:
+        q = eqn.invars[3].aval  # ids, live, x_planes, q, scales, biases
+        # 16 words are no multiple of 128: the kernel reads them transposed
+        assert q.dtype == jnp.uint32 and q.shape == (n_moe * n_exp, words, width)
+        layer_scan = scans[-1]  # the innermost: decode block > layer scan
+        assert len(scans) == 2
+        for aval in _scanned(layer_scan):
+            assert aval.dtype != jnp.uint32 and aval.shape[1:2] != (n_exp,), aval
+        assert any(  # the counter the kernel's id table is built from
+            a.shape == (n_moe,) and a.dtype == jnp.int32 for a in _scanned(layer_scan)
+        )
+        whole = [v.aval for v in layer_scan.invars[: layer_scan.params["num_consts"]]]
+        assert sum(a.shape == (n_moe, n_exp, width, words) for a in whole) == 3
+
+
+@pytest.mark.parametrize("family", ["llama", "mixtral"])
+def test_other_models_layer_scans_are_as_they_were(family):
+    """No read-in-place leaves named: every leaf of the stack, K and V are
+    the scan's ``xs``, the hidden state its one carry, and nothing else."""
+    from mlx_sharding_tpu.models import build_model
+
+    extra = dict(num_local_experts=4, num_experts_per_tok=2) if family == "mixtral" else {}
+    model, cfg = build_model(dict(model_type=family, **TINY, **extra))
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    cache = model.make_cache(1, 16, jnp.float32)
+    h = jnp.zeros((1, 4, cfg.hidden_size), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda lp, h, k, v: model.run_layers(lp, h, k, v, jnp.asarray(0, jnp.int32))
+    )(params["layers"], h, cache.k, cache.v)
+    outer = [eqn for eqn, scans in _walk(jaxpr.jaxpr)
+             if eqn.primitive.name == "scan" and not scans]
+    assert len(outer) == 1
+    assert outer[0].params["num_carry"] == 1
+    assert len(_scanned(outer[0])) == len(jax.tree.leaves(params["layers"])) + 2

@@ -10,6 +10,7 @@ stream of the dequantize-at-load path, solo and on every engine/mesh.
 """
 
 import json
+import re
 from pathlib import Path
 
 import jax
@@ -351,6 +352,71 @@ def test_expert_kernel_matches_gather_and_scan(case):
         )
 
 
+#: one case per shape family of KERNEL_CASES, gated and un-gated
+LAYER_CASES = [
+    (case, gated)
+    for case in ("n1-e4k2", "n16-e4k2", "n2-e64k6", "same-expert",
+                 "neighbours-share-one", "words-not-128", "zero-params")
+    for gated in (True, False)
+]
+
+
+@pytest.mark.parametrize(
+    "case,gated", LAYER_CASES,
+    ids=[f"{c}-{'gated' if g else 'ungated'}" for c, g in LAYER_CASES],
+)
+def test_expert_kernel_reads_a_layer_in_place(case, gated):
+    """``layer=i`` over whole ``(L, E, …)`` stacks against the same call on
+    layer i's slice: the kernel sees the same bytes through another index,
+    so the answers are equal bit for bit — for every layer in turn, with a
+    Python ``layer``, a traced one under ``jax.jit`` and a ``lax.scan``
+    counter."""
+    from mlx_sharding_tpu.ops.moe import _apply_packed_kernel
+
+    n, h, mi, e, k, gs, picks, zero = KERNEL_CASES[case]
+    n_layers = 3
+    rng = np.random.default_rng(sum(map(ord, case)) + gated)
+    x = jnp.asarray(rng.normal(size=(n, h)), jnp.float32)
+
+    def stacks(out_d, in_d):  # (L, E, out, in) packed; layer 1 the padded slot
+        per_layer = [
+            _packed_stack(rng, e, out_d, in_d, gs, zero or i == 1)
+            for i in range(n_layers)
+        ]
+        return jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
+
+    wg = stacks(mi, h) if gated else None
+    wu, wd = stacks(mi, h), stacks(h, mi)
+    weights, idx = _routing(rng, n, e, k, picks)
+
+    def at(w, i):
+        return jax.tree.map(lambda a: a[i], w)
+
+    def in_place(i):
+        return _apply_packed_kernel(
+            x, weights, idx, wg, wu, wd, gs, 4, interpret=True, layer=i
+        )
+
+    want = [
+        np.asarray(_apply_packed_kernel(
+            x, weights, idx, at(wg, i), at(wu, i), at(wd, i), gs, 4,
+            interpret=True,
+        ))
+        for i in range(n_layers)
+    ]
+    assert not want[1].any() and (zero or want[0].any() and want[2].any())
+    traced = jax.jit(in_place)
+    _, scanned = jax.lax.scan(
+        lambda c, i: (c, in_place(i)), 0, jnp.arange(n_layers)
+    )
+    for i in range(n_layers):
+        np.testing.assert_array_equal(np.asarray(in_place(i)), want[i])
+        np.testing.assert_array_equal(
+            np.asarray(traced(jnp.asarray(i, jnp.int32))), want[i]
+        )
+        np.testing.assert_array_equal(np.asarray(scanned[i]), want[i])
+
+
 def test_expert_kernel_bf16_rows_round_as_the_gather_path_does():
     """bf16 activations, as served: the kernel rounds each dequantized plane
     to bf16 before its sub-dot like ``dequantize(..., bf16)``, accumulates in
@@ -402,6 +468,30 @@ def test_apply_experts_dispatch(monkeypatch):
     assert paths(16) == {"kernel": True, "gather": False, "scan": False}
     assert paths(1)["kernel"]
     assert paths(17)["kernel"] is False and paths(17)["scan"]
+    # whole (L, E, …) stacks and a layer's index: the same choices, and the
+    # kernel's operand is the (L*E, …) view of the stack, not a layer's slice
+    one = (wg, wu, wd)
+    wg, wu, wd = jax.tree.map(lambda a: jnp.stack([a, a, a]), one)
+    assert paths(16, layer=1) == {"kernel": True, "gather": False, "scan": False}
+    assert paths(17, layer=1) == {"kernel": False, "gather": False, "scan": True}
+    assert paths(16, layer=1, expert_base=0) == {
+        "kernel": False, "gather": False, "scan": True}
+    text = str(jax.make_jaxpr(
+        lambda *a: moe.apply_experts(*a[:-1], group_size=gs, layer=a[-1])
+    )(jnp.ones((16, h), jnp.float32), *_routing(rng, 16, e, k, "random"),
+      wg, wu, wd, jnp.asarray(2, jnp.int32)))
+    assert f"u32[{3 * e},{mi},{h // 8}]" in text
+    assert not re.search(r":u32\[[^\]]*\] = (dynamic_slice|gather)", text)
+    monkeypatch.undo()  # off the chip: the layer's slice, then the gather
+    assert paths(16, layer=1) == {"kernel": False, "gather": True, "scan": False}
+    x16 = jnp.asarray(rng.normal(size=(16, h)), jnp.float32)
+    weights, idx = _routing(rng, 16, e, k, "random")
+    np.testing.assert_array_equal(
+        np.asarray(moe.apply_experts(x16, weights, idx, wg, wu, wd, group_size=gs, layer=2)),
+        np.asarray(moe.apply_experts(x16, weights, idx, *one, group_size=gs)),
+    )
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    wg, wu, wd = one
     # expert-parallel: each device scans its residents, whatever the rows
     from jax.sharding import PartitionSpec as P
 
@@ -430,3 +520,133 @@ def test_apply_experts_dispatch(monkeypatch):
     text = str(jax.make_jaxpr(moe.apply_experts)(
         x, weights, idx, dense, dense, jnp.ones((e, mi, h), jnp.float32)))
     assert "quant_matmul_experts" not in text
+
+
+# ------------------------ the layer scan that leaves the expert stacks whole
+
+
+def _scanned_slices(monkeypatch):
+    """The layer scan as it was before the stacks stayed whole: every leaf a
+    scanned ``xs``, each layer handed its own ``(E, …)`` slice."""
+    from mlx_sharding_tpu.models.deepseek_v2 import DeepseekV2Model
+
+    monkeypatch.setattr(
+        DeepseekV2Model, "scan_in_place", lambda self, group, stack: ()
+    )
+
+
+@pytest.mark.parametrize("padded", [False, True], ids=["unpadded", "padded"])
+@pytest.mark.parametrize("rows", [2, 20], ids=["decode-2-rows", "chunk-20-rows"])
+def test_run_layers_in_place_equals_scanned_slices(tmp_path, monkeypatch, padded, rows):
+    """``run_layers`` over the tiny packed DeepSeek, expert stacks read in
+    place by ``(layer, expert)``, against the scan that slices them per
+    layer: hidden state, K and V equal to the last bit on the CPU (gather
+    fallback at 2 rows, expert scan at 20), with and without a padded,
+    masked-out layer slot of zero parameters."""
+    from mlx_sharding_tpu.loading import load_model
+
+    path = _quantized_tiny_deepseek(tmp_path)
+    model, params = load_model(str(path), dtype=jnp.float32, keep_quantized=True)
+    layers, mask = params["layers"], None
+    assert model.scan_in_place("moe", layers["moe"]) == ("w_gate", "w_up", "w_down")
+    assert model.scan_in_place("dense", layers["dense"]) == ()
+    if padded:  # a zero slot between the two MoE layers, as the fused engine pads
+        layers = {**layers, "moe": jax.tree.map(
+            lambda a: jnp.stack([a[0], jnp.zeros_like(a[0]), a[1]]), layers["moe"]
+        )}
+        mask = {"dense": jnp.asarray([True]), "moe": jnp.asarray([True, False, True])}
+    n_layers = 3 + padded
+    rng = np.random.default_rng(rows + padded)
+    t = 1 if rows <= 16 else rows
+    h = jnp.asarray(rng.normal(size=(rows // t, t, 64)), jnp.float32)
+    cache = model.make_cache(rows // t, 32, jnp.float32)
+    k = jnp.asarray(rng.normal(size=(n_layers, *cache.k.shape[1:])), jnp.float32)
+    v = jnp.zeros((n_layers, *cache.v.shape[1:]), jnp.float32)
+
+    def run():
+        return jax.jit(
+            lambda lp, h, k, v: model.run_layers(
+                lp, h, k, v, jnp.asarray(5, jnp.int32), mask=mask)
+        )(layers, h, k, v)
+
+    got = run()
+    _scanned_slices(monkeypatch)
+    want = run()
+    assert float(jnp.abs(want[0] - h).max()) > 0
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize(
+    "served", ["ragged-pp1", "fused-pp2-padded", "fused-pp1-ep2", "sp2-decode"]
+)
+def test_served_logprobs_in_place_equal_scanned_slices(tmp_path, monkeypatch, served):
+    """The served tiny packed DeepSeek — the ragged paged decode body behind
+    the batcher (pp=1), the fused pp=2 engine whose stages are padded with
+    masked slots, the fused engine under ``--ep 2`` (each device reads
+    ``(layer, e)`` of its half of the E axis), and decode over the
+    sp-sharded cache (``sp_decode.py``'s body; the ring prefill in front of
+    it has a scan of its own, ``sp_prefill.py``, which slices) — gives the
+    same tokens and the same top-10 log-probabilities, bit for bit, as with
+    the scanned slices."""
+    from mlx_sharding_tpu.generate import Generator, TokenLogprobs
+    from mlx_sharding_tpu.loading import load_model
+    from mlx_sharding_tpu.parallel.mesh import make_mesh
+    from mlx_sharding_tpu.parallel.pipeline import PipelineEngine
+    from mlx_sharding_tpu.scheduler import ContinuousBatcher
+
+    path = _quantized_tiny_deepseek(tmp_path)
+    model, params = load_model(str(path), dtype=jnp.float32, keep_quantized=True)
+    prompt = [5, 9, 2, 61, 17]
+
+    def serve():
+        kw = dict(max_seq=64, cache_dtype=jnp.float32, prefill_chunk=8)
+        if served == "ragged-pp1":
+            eng = PipelineEngine(
+                model, params, make_mesh(pp=1), microbatches=2, pool_pages=20,
+                page_size=8, paged_attention="ragged", **kw)
+            assert eng.paged_attention == "ragged"
+            b = ContinuousBatcher(eng, decode_block=3)
+            try:
+                out = list(b.generate_step(prompt, max_tokens=7, want_logprobs=True))
+            finally:
+                b.close()
+        elif served == "fused-pp2-padded":
+            eng = PipelineEngine(model, params, make_mesh(pp=2), **kw)
+            assert not all(np.asarray(m).all() for m in jax.tree.leaves(eng.layer_masks))
+            out = list(eng.generate_step(prompt, max_tokens=7, want_logprobs=True))
+        elif served == "sp2-decode":
+            gen = Generator(
+                model, params, sp_mesh=make_mesh(sp=2), sp_decode=True,
+                decode_block=3, **kw)
+            out = list(gen.generate_step(prompt, max_tokens=7, want_logprobs=True))
+        else:
+            eng = PipelineEngine(model, params, make_mesh(pp=1, ep=2), **kw)
+            wq = eng.layer_params["moe"]["w_gate"]["q"]
+            assert wq.sharding.shard_shape(wq.shape)[2] == wq.shape[2] // 2
+            out = list(eng.generate_step(prompt, max_tokens=7, want_logprobs=True))
+        # the first token carries its whole row, a block's tokens a summary
+        return [
+            (int(t), [np.asarray(a).tolist() for a in (
+                (lp.chosen, lp.top_indices, lp.top_values)
+                if isinstance(lp, TokenLogprobs) else (lp,))])
+            for t, lp in out
+        ]
+
+    from mlx_sharding_tpu.models import deepseek_v2
+
+    layers_seen = []  # apply_experts(layer=…) at trace time: the form taken
+
+    def spy(*a, **kw):
+        layers_seen.append(kw.get("layer") is not None)
+        return apply_experts(*a, **kw)
+
+    apply_experts = deepseek_v2.apply_experts
+    monkeypatch.setattr(deepseek_v2, "apply_experts", spy)
+    got = serve()
+    assert layers_seen and (any if served == "sp2-decode" else all)(layers_seen)
+    del layers_seen[:]
+    _scanned_slices(monkeypatch)
+    want = serve()
+    assert layers_seen and not any(layers_seen)
+    assert len(want) == 7 and got == want

@@ -16,7 +16,9 @@ and so does a smoke shape the predicate sends to XLA.
 """
 
 import functools
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
 
@@ -166,3 +168,80 @@ def test_compiles_for_v5e(case, chip, monkeypatch):
         assert "tpu_custom_call" not in text
     else:
         assert "tpu_custom_call" in text and kernel in text
+
+
+def _arrays_made(text: str, floor: int) -> list:
+    """``(opcode, shape)`` of every instruction of a compiled module that
+    makes a new array of ``floor`` bytes or more: parameters, tuple plumbing
+    and bitcasts make none."""
+    size = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2}
+    made = []
+    for dtype, dims, op in re.findall(
+        r"= ([a-z]+[0-9]*)\[([0-9,]*)\]\S* ([a-z-]+)\(", text
+    ):
+        if op in ("parameter", "get-tuple-element", "bitcast"):
+            continue
+        n = math.prod(int(d) for d in dims.split(",") if d)
+        if n * size.get(dtype, 4) >= floor:
+            made.append((op, f"{dtype}[{dims}]"))
+    return made
+
+
+def _loop_bodies(text: str) -> str:
+    """The text of every computation of a compiled module that runs inside
+    a ``while``: the loops' bodies and conditions and whatever they call."""
+    comps = dict(re.findall(
+        r"^(?:ENTRY )?%(\S+) \([^\n]*\{\n(.*?)^\}", text, flags=re.M | re.S
+    ))
+    todo = re.findall(r"(?:body|condition)=%([\w.-]+)", text)
+    assert todo and set(todo) <= set(comps), todo
+    inside = set()
+    while todo:
+        name = todo.pop()
+        if name not in inside:
+            inside.add(name)
+            todo += re.findall(r"=\{?%([\w.-]+)", comps[name])
+    return "\n".join(comps[name] for name in sorted(inside & set(comps)))
+
+
+def test_experts_read_in_place_copy_nothing_of_stack_size(chip, monkeypatch):
+    """A layer scan that carries the layer's index and calls
+    ``apply_experts(layer=i)`` on DeepSeek-V2-Lite's ``(13, 64, …)`` packed
+    stacks at the cell's 16 rows: the kernel's operands are the stacks where
+    they lie, so nothing the program makes is as large as the smallest of a
+    layer's expert leaves — the compile-time witness that no layer's stack
+    is sliced or copied (the scanned-slice form needs a layer's 345 MB a
+    step). What is left is XLA's own: ``w_down``'s scales and biases
+    (f32[13,64,2048,22], 22 groups in the minor dimension) are laid out
+    once a call, outside the loop, into the orientation the kernel reads."""
+    from mlx_sharding_tpu.ops.moe import apply_experts
+
+    layers, e, hidden, width, n, k = 13, 64, 2048, 1408, 16, 6
+    fn, shapes, kernel = _experts(n, k, e, hidden, width)
+    shapes = shapes[:3] + [((layers, *s), d) for s, d in shapes[3:]]
+
+    def scanned(x, weights, idx, *leaves):
+        gate, up, down = (
+            dict(zip(("q", "scales", "biases"), leaves[i:i + 3])) for i in (0, 3, 6)
+        )
+
+        def body(h, i):
+            return h + apply_experts(h, weights, idx, gate, up, down, layer=i), None
+
+        return jax.lax.scan(body, x, jnp.arange(2))[0]
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    compiled = jax.jit(scanned).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3 and kernel in text
+    assert f"u32[{layers * e},{width},{hidden // 8}]" in text  # the (L*E, …) view
+    # a layer's smallest expert leaf (w_up's scales, 64 x 1408 x 32 f32) is
+    # 11.5 MB: nothing of that size is made but the two relayouts named above
+    relayout = ("copy", f"f32[{layers},{e},{hidden},{width // 64}]")
+    made = _arrays_made(text, e * width * (hidden // 64) * 4)
+    assert set(made) <= {relayout} and len(made) <= 2, made
+    # … and those two are made before the loop: inside it, nothing
+    assert _arrays_made(_loop_bodies(text), e * width * (hidden // 64) * 4) == []
+    once = 2 * layers * e * hidden * 24 * 4  # 22 groups on 24 sublanes
+    assert compiled.memory_analysis().temp_size_in_bytes < once + 64 * 2**20

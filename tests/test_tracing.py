@@ -497,7 +497,11 @@ def test_trace_profile_puts_tick_spans_on_the_profilers_clock(tmp_path):
     # phases nest inside their tick
     tick_of = lambda s: [t for t in spans["mst.tick"]  # noqa: E731
                          if t["_t0"] <= s["_t0"] and s["_t1"] <= t["_t1"]]
-    assert all(len(tick_of(s)) == 1 for s in blocks + chunks)
+    # (a tick still open at ``stop_trace`` leaves no span, its closed phases
+    # do: under load the scheduler's thread is inside one more tick by then)
+    last = max(t["_t1"] for t in spans["mst.tick"])
+    nested = [len(tick_of(s)) for s in blocks + chunks if s["_t0"] < last]
+    assert len(nested) >= len(blocks + chunks) - 1 and set(nested) == {1}
 
 
 @hard_timeout(240)
