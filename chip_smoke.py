@@ -8,8 +8,8 @@
                                       on whatever backend is there (Pallas
                                       kernels in interpret mode)
 
-The model is the Llama-3.2-3B-class config of bench.py at full width and
-depth in bf16, with random weights made from ``--seed``: a bring-up
+The model is a Llama-3.2-3B-class config (``LLAMA_3B`` below) at full width
+and depth in bf16, with random weights made from ``--seed``: a bring-up
 vehicle, not a benchmark cell. Every time printed here is a smoke timing
 (cold compiles and a checkpoint write included), never a metric.
 
@@ -50,7 +50,7 @@ ROOT = Path(__file__).resolve().parent
 WORK = ROOT / ".chip_smoke"
 DEADLINE_S = 1150  # the driver allows 1200 s, compilation included
 
-LLAMA_3B = dict(  # bench.py BENCH_MODEL
+LLAMA_3B = dict(
     model_type="llama", architectures=["LlamaForCausalLM"],
     vocab_size=128256, hidden_size=3072, intermediate_size=8192,
     num_hidden_layers=28, num_attention_heads=24, num_key_value_heads=8,
@@ -403,8 +403,8 @@ def child_kernels(seed: int, rehearse: bool) -> None:
         direct = functools.partial(flash_attention, scale=scale,
                                    interpret=rehearse)
         via_dispatch = functools.partial(causal_attention, scale=scale)
-        # T=1 is opt-in (MST_FLASH_DECODE), so it is off the dispatcher's
-        # default path: the kernel is called directly
+        # the dispatcher sends T=1 to the XLA path: the kernel's one-row
+        # tile is called directly
         fn = direct if (rehearse or t == 1) else via_dispatch
         check(f"flash T={t} S={s_len}", "flash_attention", fn,
               functools.partial(_causal_attention_xla, scale=scale),

@@ -102,8 +102,7 @@
   trace-ish identifier counts as the guard; ``time.perf_counter()`` is the
   sanctioned timestamp and is never flagged). An unguarded call runs its
   argument marshalling and lock traffic on every decode block even with
-  ``--trace off`` — exactly the regression the ``trace_overhead`` bench
-  phase exists to catch, caught here statically instead.
+  ``--trace off``; the rule catches it statically.
 - **MST113 control-plane-in-tick** — a blocking control-plane collective
   (``<plane>.exchange(...)``, ``<plane>.heartbeat(...)``,
   ``<plane>.pod_exchange(...)``) inside a tick-hot function. A collective

@@ -6,7 +6,7 @@ resulting cache, every layer pair ranked by dissimilarity (1 − cosine;
 KVSharer's counterintuitive finding is that the MOST dissimilar pairs are
 the safe ones to share), then a greedy merge of the top ``--num-share``
 pairs under the ``--max-group`` cap. The resulting
-``mst-kv-share-map-v1`` JSON (kv_share.py) is what the server, bench, and
+``mst-kv-share-map-v1`` JSON (kv_share.py) is what the server and the
 CLI load with ``--kv-share-map PATH``; its ``share_hash`` joins the
 ``KVPageBlock`` export/import fingerprint so a pool can never scatter a
 block laid out under a different map.

@@ -39,7 +39,7 @@ from mlx_sharding_tpu.ops.quant import (
 
 
 def dense_init(key, in_dim: int, out_dim: int, dtype, scale: float | None = None):
-    """Random (in, out) weight for x @ W. Used by tests/bench only —
+    """Random (in, out) weight for x @ W. Used by tests only —
     real weights come from checkpoints."""
     if scale is None:
         scale = 1.0 / np.sqrt(in_dim)

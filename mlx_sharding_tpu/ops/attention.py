@@ -24,26 +24,22 @@ import jax.numpy as jnp
 
 
 def _flash_eligible(q, k, v, logit_softcap, sliding_window, sinks) -> bool:
-    """Use the Pallas kernel on TPU for standard causal GQA (no softcap/
-    window/sinks): prefill chunks with T a multiple of 128, and — opt-in via
-    MST_FLASH_DECODE=1 until measured on hardware — T=1 decode steps.
-
-    Head dims need only 64-alignment (Mosaic pads sub-128 lane tails): this
-    admits DeepSeek MLA's dk=192 full-mode and dk=rank+rope / dv=rank
-    compressed-mode shapes, not just the 128-multiples of round 1. Opt out
-    entirely with MST_FLASH=0."""
+    """Whether a call takes the Pallas flash kernel: on TPU, standard causal
+    GQA (no softcap, window or sinks), a prefill chunk with T a multiple of
+    128 over a cache of S a multiple of 128, head dims 64-aligned (Mosaic
+    pads sub-128 lane tails, which admits DeepSeek MLA's dk=192 full-mode
+    and dk=rank+rope / dv=rank compressed-mode shapes). Every other call,
+    T=1 decode among them, takes the XLA path. ``MST_FLASH=0`` opts out."""
     if os.environ.get("MST_FLASH", "1") == "0":
         return False
     if logit_softcap is not None or sliding_window is not None or sinks is not None:
         return False
     b, t, hq, dk = q.shape
     s, dv = k.shape[1], v.shape[-1]
-    t_ok = (t >= 128 and t % 128 == 0) or (
-        t == 1 and os.environ.get("MST_FLASH_DECODE", "0") == "1"
-    )
     return (
         jax.default_backend() == "tpu"
-        and t_ok
+        and t >= 128
+        and t % 128 == 0
         and s % 128 == 0
         and dk % 64 == 0
         and dv % 64 == 0

@@ -13,17 +13,12 @@ Gemma-2's softcap + sliding-window layers stay on the XLA path. K/V arrive
 as the full-capacity cache buffers; blocks entirely in the future of the
 query tile are skipped without compute.
 
-Chip status: the one record (BENCH_DETAIL.json kernels block, v5e) shows
-flash prefill 880.4 µs vs fused-XLA 338.3 µs and T=1 decode 951.5 vs
-310.0 µs at the bench shapes, from a kernel that predates the adaptive
-q-tile below; nothing has been measured on this code (ROADMAP S2). T=1
-decode stays OFF by default (its end-to-end A/B in the same record was a
-loss: 97.6 vs 101.6 tok/s — MST_FLASH_DECODE=1 to opt in); whether prefill
-keeps the kernel is for an end-to-end prompt-tps/TTFT A/B on the chip to
-decide. The adaptive VMEM-budget q-tile attacks the per-program-overhead
-failure mode the fixed 128-tile dequant-matmul had before its picker
-(ops/quant_matmul.py): every query tile of a head re-streams the whole
-(S, Dk+Dv) K/V row, so fewer/larger tiles amortize it.
+The q-tile is picked against a VMEM budget (``pick_block_q``): every query
+tile of a head re-streams the whole (S, Dk+Dv) K/V row, so fewer and larger
+tiles amortize it. Only prefill chunks reach this kernel
+(``ops.attention._flash_eligible``); T=1 decode takes the XLA path. Whether
+prefill keeps the kernel is for a prefill-heavy benchmark cell to decide
+(ROADMAP S6); ``chip_smoke.py`` checks its numbers on the chip.
 """
 
 from __future__ import annotations

@@ -173,8 +173,8 @@ def _quant_matmul_xla(x2, q, scales, biases, group_size, bits):
 def quantize_jax(w: jax.Array, group_size: int = 64, bits: int = 4):
     """Device-side mlx-layout packer: (…, out, in) → (q (…, out, in*bits/32)
     uint32, scales, biases (…, out, in/group_size) f32). Same math as
-    :func:`quantize`, jittable — benchmarks quantize multi-GB weight stacks
-    in place on the chip instead of round-tripping them to host."""
+    :func:`quantize`, jittable: ``chip_smoke.py`` packs its check matrices
+    on the chip instead of round-tripping them through the host."""
     w = jnp.asarray(w, jnp.float32)
     *lead, out_dim, in_dim = w.shape
     if in_dim % group_size:

@@ -29,7 +29,7 @@ module stitches N of those hosts into a pod with three coordinated pieces:
 
 Transports: :class:`LoopbackTransport` is the in-process fabric (N
 simulated hosts in one process — deterministic, fast, what the quick-tier
-tests and the bench smoke drive); :class:`CollectiveTransport` is the real
+tests drive); :class:`CollectiveTransport` is the real
 one, riding ``parallel.multihost.PodControlPlane``'s symmetric allgather
 over the same gloo/ICI substrate the SPMD control plane uses. Both speak
 the same 4-call surface (publish / peers / send / handler), so every pod

@@ -786,7 +786,6 @@ class ContinuousBatcher:
         # the async path overlaps), idle_wait the blocking submission wait,
         # the rest is host work
         self._phases = tracing.TickPhases(profile=self._trace_profile)
-        self._tick_timing_base = self._phases.snapshot()
         # plain decode blocks, counted where they happen (tick thread only).
         # Identities: dispatched = harvested + abandoned + in flight, and
         # positions computed = tokens emitted + dropped + positions in
@@ -1086,7 +1085,7 @@ class ContinuousBatcher:
                 self.migrations_in += 1
         # Bind (or self-begin) the request's span timeline. The server and
         # disagg coordinator pass _trace so one timeline spans the whole
-        # path; direct scheduler users (bench, tests) get a trace from the
+        # path; direct scheduler users (tests) get a trace from the
         # process tracer when one is configured — begin() returns None when
         # tracing is off or this request falls outside the sample.
         tr = _trace
@@ -1367,19 +1366,15 @@ class ContinuousBatcher:
         )
 
     def tick_timing_stats(self) -> dict:
-        """Per-tick host/device-blocked timing for /metrics and the bench:
-        ``device_blocked_ms`` is the harvest ``device_get`` wait (what the
-        async pipeline shrinks by overlapping it with the next block's
-        compute), ``host_ms`` is the rest of the tick's wall time. Racy
-        snapshot by design — a gauge, not a decision input."""
-        snap, base = self._phases.snapshot(), self._tick_timing_base
-        secs = {
-            ph: s - base["seconds"][ph] for ph, s in snap["seconds"].items()
-        }
-        # ticks that harvested a block
-        harvests = (
-            snap["entries"]["harvest_wait"] - base["entries"]["harvest_wait"]
-        )
+        """Per-tick host/device-blocked timing for /metrics, averaged over
+        the batcher's life: ``device_blocked_ms`` is the harvest
+        ``device_get`` wait (what the async pipeline shrinks by overlapping
+        it with the next block's compute), ``host_ms`` is the rest of the
+        tick's wall time. Racy snapshot by design — a gauge, not a decision
+        input."""
+        snap = self._phases.snapshot()
+        secs = snap["seconds"]
+        harvests = snap["entries"]["harvest_wait"]  # ticks that harvested a block
         n = max(1, harvests)
         host_s = sum(
             s for ph, s in secs.items()
@@ -1437,15 +1432,6 @@ class ContinuousBatcher:
             "itl": self._h_itl.to_dict(),
             "queue_wait": self._h_queue_wait.to_dict(),
         }
-
-    def reset_tick_timing(self):
-        """Restart :meth:`tick_timing_stats`' averages from now. The first
-        ticks after construction pay jit compilation (dispatch-side, so it
-        lands in host_ms) — benchmarks reset after their warmup request so
-        the averages reflect steady state only. The counters themselves
-        (:meth:`tick_phase_stats`, /metrics) are cumulative and never go
-        back: only the baseline the averages are taken from moves."""
-        self._tick_timing_base = self._phases.snapshot()
 
     def _account_kv_read(self, live, steps: int, path: Optional[str] = None):
         if not self.paged or not live:
@@ -2487,7 +2473,7 @@ class ContinuousBatcher:
         """Legacy discard-preemption bookkeeping: fold the emitted tokens
         into the prompt so resume re-prefills them (the recompute strategy —
         the KV is gone). Clears any stale migration state; counts the
-        re-prefill work for the spill-vs-discard bench story."""
+        re-prefill work (``reprefill_tokens``)."""
         req.spilled = False
         req._block = None
         if req.history:

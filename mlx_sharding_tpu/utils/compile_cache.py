@@ -1,8 +1,8 @@
 """Where JAX's persistent compilation cache lives.
 
 One rule for every entry point (the server, the CLI generator, the shard
-tool, ``bench.py``, ``chip_smoke.py``): if ``JAX_COMPILATION_CACHE_DIR`` is
-set, JAX already reads it and nothing here sets another path; otherwise the
+tool, ``chip_smoke.py``, the benchmark's launcher): if
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and nothing here sets another path; otherwise the
 cache goes to one fixed, git-ignored directory inside the checkout. The
 path is part of the cache key, so it must never move between processes —
 no ``tempfile``, no pid, no timestamp. Processes started one after another

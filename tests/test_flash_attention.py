@@ -70,8 +70,9 @@ def test_flash_mla_head_dims(b, t, s, hq, hkv, dk, dv, offset):
     [(256, 8, 2, 64, 17), (256, 16, 1, 576, 40), (128, 4, 4, 192, 127)],
 )
 def test_flash_decode_step(s, hq, hkv, dk, offset):
-    """T=1 decode variant: one query row against a long cache, offset mid-
-    buffer — positions beyond the offset must contribute nothing."""
+    """One query row, the kernel's smallest tile, against a long cache,
+    offset mid-buffer — positions beyond the offset must contribute
+    nothing. Called directly: the dispatcher sends T=1 to the XLA path."""
     rng = np.random.default_rng(2)
     dv = 512 if dk == 576 else dk
     q = jnp.asarray(rng.normal(size=(1, 1, hq, dk)), jnp.float32)
@@ -98,9 +99,7 @@ def test_eligibility_gates(monkeypatch):
     # softcap/window stay on XLA
     assert not _flash_eligible(q192, k192, v128, 30.0, None, None)
     assert not _flash_eligible(q192, k192, v128, None, 4096, None)
-    # decode is opt-in until measured on hardware
+    # T=1 decode is never eligible: it takes the XLA path
     assert not _flash_eligible(qd, k192, v128, None, None, None)
-    monkeypatch.setenv("MST_FLASH_DECODE", "1")
-    assert _flash_eligible(qd, k192, v128, None, None, None)
     monkeypatch.setenv("MST_FLASH", "0")
     assert not _flash_eligible(q192, k192, v128, None, None, None)

@@ -477,23 +477,38 @@ def _llama_map(rank=4):
 
 
 def test_engine_builds_codec_mla_native():
+    def engine(model, params, **kw):
+        return PipelineEngine(
+            model, params, make_mesh(pp=1, devices=jax.devices()[:1]),
+            microbatches=2, max_seq=64, cache_dtype=jnp.float32,
+            prefill_chunk=8, pool_pages=10, page_size=8, **kw,
+        )
+
     model, params = _dsv2_model()
-    eng = PipelineEngine(
-        model, params, make_mesh(pp=1, devices=jax.devices()[:1]),
-        microbatches=2, max_seq=64, cache_dtype=jnp.float32,
-        prefill_chunk=8, pool_pages=10, page_size=8,
-    )
+    eng = engine(model, params)
     assert eng.kv_codec is not None and eng.kv_codec.mode == "latent"
     assert eng.kv_compress_hash == eng.kv_codec.compress_hash
     assert eng.kv_compress_stats()["mode"] == "latent"
+    # what the latent saves on the wire, counted from shapes: the same
+    # pages of a full-mode pool ship every head's keys and values
+    full = engine(*_dsv2_model(mla_cache_mode="full"))
+    assert full.kv_codec is None
+    moved = {
+        e: export_block(
+            e.init_cache_paged()[0], [1, 2, 3, 4], page_size=8, n_tokens=32,
+            prompt=[1, 2, 3], history=[1] * 29, produced=29,
+            resume_keys=None, resume_recent=None, codec=e.kv_codec,
+        ).to_host().nbytes
+        for e in (eng, full)
+    }
+    c = model.config
+    assert moved[full] * (c.kv_lora_rank + c.qk_rope_head_dim) == moved[eng] * (
+        c.num_attention_heads
+        * (c.qk_nope_head_dim + c.qk_rope_head_dim + c.v_head_dim)
+    )
     # a map on an MLA-native pool is redundant, not silently layered
     with pytest.raises(CompressError, match="redundant"):
-        PipelineEngine(
-            model, params, make_mesh(pp=1, devices=jax.devices()[:1]),
-            microbatches=2, max_seq=64, cache_dtype=jnp.float32,
-            prefill_chunk=8, pool_pages=10, page_size=8,
-            kv_compress_map=_llama_map(),
-        )
+        engine(model, params, kv_compress_map=_llama_map())
 
 
 def test_engine_codec_gates(tiny_llama):
@@ -567,8 +582,8 @@ def test_mla_spill_preempt_resume_bitexact():
         assert cs["blocks_compressed"] > 0
         assert cs["blocks_reconstructed"] > 0
         # the pool already holds the latent; the codec's own saving here
-        # is just the dummy-v leaf. The big (~num_heads×) win vs a
-        # full-mode pool is measured by the kv_compressed_transport bench.
+        # is just the dummy-v leaf. What a latent pool saves against a
+        # full-mode one: test_engine_builds_codec_mla_native.
         assert cs["bytes_wire_total"] < cs["bytes_raw_total"]
         assert cs["compress_faults"] == 0 and cs["reconstruct_faults"] == 0
     finally:

@@ -13,8 +13,7 @@ batcher's.
 
 Unit tests drive :class:`PodPrefixFederation` directly over a fake
 transport (the pod view is just ``peers()`` + ``send``); the end-to-end
-test runs two real batchers over the :class:`LoopbackHub` exactly the
-way ``bench.py``'s ``pod_prefix_federation`` phase does.
+test runs two real batchers over the :class:`LoopbackHub`.
 """
 
 import pickle

@@ -130,7 +130,7 @@ def test_dequant_rejects_non_uint32():
 
 def test_quantize_jax_matches_numpy_packer():
     """Device-side packer must produce the identical mlx-layout triple as the
-    host packer (bench and tests both rely on it)."""
+    host packer (chip_smoke.py and tests both rely on it)."""
     import jax.numpy as jnp
 
     from mlx_sharding_tpu.ops.quant import quantize, quantize_jax

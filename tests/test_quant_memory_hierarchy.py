@@ -239,6 +239,18 @@ def test_int8_writeback_reuse_roundtrip():
     serial runs through one batcher must reproduce their own streams."""
     batcher = _paged_pair("int8")
     try:
+        # what an int8 page costs, counted on the pool's own leaves: D codes
+        # and one f32 scale a row-head (D + 4 bytes where bf16 holds 2D, so
+        # equal bytes hold 2D / (D + 4) times the tokens)
+        codes, scales = batcher.cache.k["d"], batcher.cache.k["s"]
+        d = codes.shape[-1]
+        assert codes.dtype == jnp.int8 and scales.dtype == jnp.float32
+        assert scales.shape == codes.shape[:-1] + (1,)
+        pool_bytes = sum(
+            leaf.nbytes
+            for leaf in jax.tree.leaves((batcher.cache.k, batcher.cache.v))
+        )
+        assert pool_bytes == 2 * (codes.size // d) * (d + 4)
         first = [
             [t for t, _ in batcher.generate_step(p, **kw)] for p, kw in JOBS
         ]

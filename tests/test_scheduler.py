@@ -647,15 +647,20 @@ def test_kv_read_accounting_ragged_below_gather():
     """Same short run on both paths: the ragged analytic KV-bytes-read must
     come in strictly below gather's (gather always reads every slot's full
     slot_pages regardless of true length)."""
-    totals = {}
+    totals, per_token = {}, {}
     for path in ("ragged", "gather"):
         batcher, _ = _ragged_batcher(path)
         try:
             _run(batcher, [5, 3], max_tokens=8)
             totals[path] = batcher.kv_read_stats()[2]
+            per_token[path] = batcher.hbm_bytes_per_token_stats()
         finally:
             batcher.close()
     assert 0 < totals["ragged"] < totals["gather"]
+    # the per-token gauges /metrics exports: the same weights either way
+    # (one live slot, so the whole stream is its token's), less KV when ragged
+    assert per_token["ragged"]["weights"] == per_token["gather"]["weights"] > 0
+    assert 0 < per_token["ragged"]["kv"] < per_token["gather"]["kv"]
 
 
 def test_overcommit_pool_exhaustion_errors_not_wedges():
@@ -941,8 +946,8 @@ def test_async_overcommit_preemption_matches_sync():
 
 
 def test_async_tick_timing_stats_populated(setup):
-    """The per-tick host / device-blocked split feeding /metrics and the
-    bench's async_tick_overlap phase: ticks counted, averages finite."""
+    """The per-tick host / device-blocked split feeding /metrics: ticks
+    counted, averages finite."""
     batcher, _ = setup
     _run(batcher, [2, 9, 5], max_tokens=6)
     t = batcher.tick_timing_stats()

@@ -9,6 +9,7 @@ minimal known-bad snippet: exactly one finding, with the expected span.
 """
 
 import json
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -82,6 +83,31 @@ def test_repo_self_scan_is_clean():
         "--write-baseline`."
     )
     assert report.files_scanned > 40  # the scan actually covered the tree
+
+
+def test_one_benchmark_program_and_one_account_of_speed():
+    """``benchmarks/run.py`` is the one benchmark and ``PERF.md`` the one
+    account of speed (PR 30). No source file or user-facing document names
+    the deleted second instrument, the records it wrote or the switch only
+    its A/B set. The history (``CHANGES.md``, ``PERF.md``, ``ROADMAP.md``,
+    ``SURVEY.md``, ``ISSUE.md``) may; ``benchmarks/`` has its own tests."""
+    gone = ("bench" + ".py", "BENCH" + "_DETAIL", "MULTICHIP" + "_r",
+            "MST_FLASH" + "_DECODE")
+    skip = {"benchmarks", "chiprun_out", "__pycache__"}
+    named = []
+    for root, dirs, files in os.walk(REPO):
+        dirs[:] = [d for d in dirs if d not in skip
+                   and (not d.startswith(".") or d == ".claude")]
+        for name in files:
+            if name.endswith(".py") or name in ("README.md", "PARITY.md",
+                                                "SKILL.md"):
+                path = Path(root, name)
+                text = path.read_text(errors="replace")
+                named += [f"{path.relative_to(REPO)}: {g}"
+                          for g in gone if g in text]
+    assert not named, named
+    for f in ("bench" + ".py", "BENCH" + "_DETAIL.json", "ADVICE.md"):
+        assert not (REPO / f).exists(), f
 
 
 def test_static_lock_graph_is_acyclic_with_expected_edges():

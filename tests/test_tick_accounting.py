@@ -146,14 +146,11 @@ def test_blocks_and_tokens_are_all_accounted_for(engine, mode):
             assert d["cold"] == d["growth"] == d["migrate"] == 0
         else:
             assert sum(s["drains"].values()) == 0  # nothing ever in flight
-        # the derived averages keep their keys (bench.py reads them)
+        # the derived averages /metrics exports keep their keys
         t = batcher.tick_timing_stats()
         assert set(t) == {"path", "host_ms_avg", "device_blocked_ms_avg",
                           "ticks", "kv_import_s_total"}
         assert t["ticks"] == s["blocks_harvested"]
-        batcher.reset_tick_timing()
-        assert batcher.tick_timing_stats()["ticks"] == 0
-        assert batcher.tick_phase_stats()["blocks_harvested"] == t["ticks"]
     finally:
         batcher.close()
     s = batcher.tick_phase_stats()

@@ -57,14 +57,15 @@ def chip():
     compilation_cache.reset_cache()
 
 
-def _flash(t, s, hq, hkv, dk, dv, via_dispatch=True):
-    # T=1 is opt-in (MST_FLASH_DECODE), off the dispatcher's default path
+def _flash(t, s, hq, hkv, dk, dv, via_dispatch=True, kernel="flash_attention"):
+    # the dispatcher sends T=1 to the XLA path: the kernel's one-row tile
+    # is compiled directly
     fn = causal_attention if via_dispatch else flash_attention
     return (
         functools.partial(fn, scale=dk ** -0.5),
         [((1, t, hq, dk), BF16), ((1, s, hkv, dk), BF16),
          ((1, s, hkv, dv), BF16), ((), I32)],
-        "flash_attention",
+        kernel,
     )
 
 
@@ -127,6 +128,7 @@ CASES = {
     # (full mode 16/16/192/128, compressed 16/1/576/512)
     "flash-prefill-3b": _flash(256, 4096, 24, 8, 128, 128),
     "flash-decode-3b": _flash(1, 4096, 24, 8, 128, 128, via_dispatch=False),
+    "decode-3b-takes-xla": _flash(1, 4096, 24, 8, 128, 128, kernel=None),
     "flash-prefill-mla-full": _flash(256, 4096, 16, 16, 192, 128),
     "flash-prefill-mla-compressed": _flash(256, 4096, 16, 1, 576, 512),
     # ragged paged decode at the server's default page (the prefill chunk)
