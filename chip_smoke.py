@@ -353,6 +353,7 @@ def child_kernels(seed: int, rehearse: bool) -> None:
         paged_attention,
     )
     from mlx_sharding_tpu.ops.quant_matmul import (
+        GEMV_MAX_M,
         quant_gemv_pipelined,
         quant_matmul_pallas,
     )
@@ -388,10 +389,14 @@ def child_kernels(seed: int, rehearse: bool) -> None:
     bf16 = jnp.bfloat16
     if rehearse:
         hq, hkv, d, s_len, t_len, pages = 4, 2, 16, 64, 16, (8, 16)
-        quant_shapes, m_big = [(128, 256), (256, 128)], 16
+        quant_shapes, batch_rows, batch_only = [(128, 256), (256, 128)], (16,), []
     else:
         hq, hkv, d, s_len, t_len, pages = 24, 8, 128, 4096, 256, (256, 128)
-        quant_shapes, m_big = [(8192, 3072), (3072, 8192), (128256, 3072)], 256
+        quant_shapes, batch_rows = [(8192, 3072), (3072, 8192), (128256, 3072)], (256, 16)
+        # DeepSeek-V2-Lite's dense MLP, the benchmark's own shapes: 10944 =
+        # 64 x 171 rows end in a ragged OUT tile, and as an IN they are 1368
+        # word lanes, which no GEMV slice takes: the batch kernel at every M
+        batch_only = [(10944, 2048), (2048, 10944)]
     scale = d ** -0.5
 
     # ---- flash attention: a prefill chunk deep in the cache, and T=1
@@ -447,17 +452,20 @@ def child_kernels(seed: int, rehearse: bool) -> None:
             check(f"paged {label} page={page}", "paged_attention", fn, ref,
                   args, ATTN_ATOL)
 
-    # ---- 4-bit matmuls: the batch kernel at M=m_big, the GEMV at M=1 and 8
-    for out_dim, in_dim in quant_shapes:
+    # ---- 4-bit matmuls: the batch kernel at a prefill chunk's rows and a
+    # 16-slot decode step's, the GEMV at M=1 and 8
+    for out_dim, in_dim in quant_shapes + batch_only:
         kw, key = jax.random.split(key)
         w = jax.random.normal(kw, (out_dim, in_dim), jnp.float32) * 0.02
         qw, sc, bi = jax.jit(quant.quantize_jax)(w)
         del w
-        for m in (m_big, 1, 8):
+        gemv_rows = () if (out_dim, in_dim) in batch_only else (1, 8)
+        for m in (*batch_rows, *gemv_rows):
             kx, key = jax.random.split(key)
             x = jax.random.normal(kx, (m, in_dim), bf16)
-            kernel = quant_matmul_pallas if m == m_big else quant_gemv_pipelined
-            name = "quant_matmul" if m == m_big else "quant_gemv_pipelined"
+            batch = m > GEMV_MAX_M
+            kernel = quant_matmul_pallas if batch else quant_gemv_pipelined
+            name = "quant_matmul" if batch else "quant_gemv_pipelined"
             if rehearse:
                 fn = functools.partial(kernel, interpret=True)
             else:

@@ -17,6 +17,8 @@ Pallas fused dequant-matmul is the follow-up optimization path.
 
 from __future__ import annotations
 
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -98,16 +100,14 @@ def _pallas_ok(m, in_dim, out_dim, group_size, bits) -> bool:
     from mlx_sharding_tpu.ops.quant_matmul import (
         DEFAULT_BLOCK_M,
         pick_block_in,
-        pick_block_out,
     )
 
     per_word = 32 // bits
     block_in = min(pick_block_in(in_dim), in_dim)
-    block_out = pick_block_out(out_dim, block_in // per_word, min(DEFAULT_BLOCK_M, m), per_word)
+    # OUT need not divide by its block: the kernel takes a ragged last tile
     return (
         jax.default_backend() == "tpu"
         and m % min(DEFAULT_BLOCK_M, m) == 0
-        and out_dim % block_out == 0
         and in_dim % block_in == 0
         and block_in % group_size == 0
         and block_in % per_word == 0
@@ -141,6 +141,26 @@ def _gemv_ok(m, in_dim, out_dim, group_size, bits) -> bool:
     )
 
 
+# Which path _quant_matmul chose, one count per call: a jitted program asks
+# while it is traced, so a compiled program's steps add nothing and the
+# count costs the device nothing. /metrics shows it as
+# ``mst_quant_dispatch_total{path}``: "xla" above 0 on a chip says some
+# packed projection is dequantized in HBM every step.
+_DISPATCHED = {"gemv": 0, "matmul": 0, "xla": 0}
+_DISPATCHED_LOCK = threading.Lock()
+
+
+def dispatch_counts() -> dict[str, int]:
+    """Lifetime count of packed matmuls dispatched to each path."""
+    with _DISPATCHED_LOCK:
+        return dict(_DISPATCHED)
+
+
+def _count_dispatch(path: str) -> None:
+    with _DISPATCHED_LOCK:
+        _DISPATCHED[path] += 1
+
+
 def _quant_matmul(x2, q, scales, biases, group_size, bits):
     import os
 
@@ -149,6 +169,7 @@ def _quant_matmul(x2, q, scales, biases, group_size, bits):
     if _gemv_ok(m, in_dim, out_dim, group_size, bits):
         from mlx_sharding_tpu.ops.quant_matmul import quant_gemv_pipelined
 
+        _count_dispatch("gemv")
         return quant_gemv_pipelined(
             x2, q, scales, biases, group_size=group_size, bits=bits,
             interpret=os.environ.get("MST_QMM_GEMV") == "interpret",
@@ -156,15 +177,21 @@ def _quant_matmul(x2, q, scales, biases, group_size, bits):
     if _pallas_ok(m, in_dim, out_dim, group_size, bits):
         from mlx_sharding_tpu.ops.quant_matmul import quant_matmul_pallas
 
+        _count_dispatch("matmul")
         return quant_matmul_pallas(
             x2, q, scales, biases, group_size=group_size, bits=bits
         )
+    _count_dispatch("xla")
     return _quant_matmul_xla(x2, q, scales, biases, group_size, bits)
 
 
 def _quant_matmul_xla(x2, q, scales, biases, group_size, bits):
-    """Guarded XLA fallback: only shapes/backends no kernel serves reach it
-    (and chip_smoke.py, as the reference both kernels are checked against)."""
+    """The plain form: dequantize the whole weight to f32 in HBM, then one
+    matmul. It is what runs off the chip (the CPU tests and rehearsals) and
+    under ``MST_QMM=0``, and the reference ``chip_smoke.py`` checks every
+    kernel against. On a TPU ``_pallas_ok`` refuses only a row count above
+    128 that is no multiple of it, which no program of the served path has:
+    ``mst_quant_dispatch_total{path="xla"}`` says if one ever does."""
     # mst: allow(MST105): dense tile is transient inside this one matmul
     w = dequantize(q, scales, biases, group_size, bits, jnp.float32)
     return (x2 @ w.astype(x2.dtype).T).astype(x2.dtype)

@@ -15,7 +15,9 @@ inference, so neither an in-kernel ``fori_loop`` over the reduction nor a
 
 - 3-D grid (M tiles, OUT tiles, IN blocks); the IN axis is a sequential
   reduction dimension — partials accumulate into an fp32 VMEM scratch,
-  written to the output tile on the last IN step.
+  written to the output tile on the last IN step. The last OUT tile may be
+  partial (an OUT with no divisor that is a multiple of 128): Mosaic masks
+  its write, and what it reads past the edge reaches no column that is kept.
 - The unpack never materializes an (out, in) tile. Each uint32 word holds 8
   nibbles; the kernel processes 8 *nibble planes* ``(q >> 4j) & 0xF`` of
   shape (out, words) and runs one MXU sub-dot per plane against the
@@ -114,7 +116,10 @@ def pick_block_out(out_dim: int, words: int, block_m: int = 1, per_word: int = 8
     """Largest divisor of OUT (a multiple of 128, or the whole dim) whose
     working set fits the per-program VMEM budget: per out row ~16 bytes per
     word lane (q 4 + s_w/b_w 8 + one nibble plane 4), plus the activation
-    tile and accumulator scaling with block_m."""
+    tile and accumulator scaling with block_m. Where no multiple of 128
+    divides OUT (DeepSeek-V2-Lite's 10944 = 64 x 171), the largest multiple
+    of 128 that fits: :func:`quant_matmul_pallas` takes a ragged last tile,
+    the one kernel that does."""
     fixed = block_m * (words * per_word + words) * 4  # x_r tile + x_sum
     limit = max((_VMEM_BUDGET_BYTES - fixed) // (16 * words + 4 * block_m), 128)
     if out_dim <= limit:
@@ -125,7 +130,7 @@ def pick_block_out(out_dim: int, words: int, block_m: int = 1, per_word: int = 8
         if out_dim % d == 0:
             best = d
         d += 128
-    return best if best is not None else min(out_dim, DEFAULT_BLOCK_OUT)
+    return best if best is not None else limit // 128 * 128
 
 
 def _kernel(x_ref, q_ref, s_ref, b_ref, o_ref, acc_ref, *, bits, group_size):
@@ -186,7 +191,11 @@ def quant_matmul_pallas(
     interpret: bool = False,
 ) -> jax.Array:
     """x @ dequant(q, scales, biases).T without materializing the dense
-    weight. M and OUT must divide by their block sizes; IN by block_in."""
+    weight. M must divide by block_m and IN by block_in: a ragged IN block
+    would add what lies past the edge into every column. OUT need not
+    divide by block_out: an output column depends on its own weight row
+    alone, so the rows the last tile reads past the end give columns that
+    are never written back."""
     m, in_dim = x.shape
     out_dim = q.shape[0]
     per_word = 32 // bits
@@ -202,10 +211,10 @@ def quant_matmul_pallas(
             f"block_in {block_in} must be a multiple of group_size "
             f"{group_size} and {per_word}"
         )
-    if m % block_m or out_dim % block_out or in_dim % block_in:
+    if m % block_m or in_dim % block_in:
         raise ValueError(
-            f"shapes (M={m}, OUT={out_dim}, IN={in_dim}) must divide block "
-            f"sizes ({block_m}, {block_out}, {block_in})"
+            f"shapes (M={m}, IN={in_dim}) must divide block sizes "
+            f"({block_m}, {block_in})"
         )
 
     n_in = in_dim // block_in
@@ -218,7 +227,7 @@ def quant_matmul_pallas(
     s3 = scales.reshape(out_dim, n_in, gpb).transpose(1, 0, 2)
     b3 = biases.reshape(out_dim, n_in, gpb).transpose(1, 0, 2)
 
-    grid = (m // block_m, out_dim // block_out, n_in)
+    grid = (m // block_m, pl.cdiv(out_dim, block_out), n_in)
     return pl.pallas_call(
         functools.partial(_kernel, bits=bits, group_size=group_size),
         grid=grid,

@@ -16,6 +16,7 @@ from __future__ import annotations
 import bisect
 import contextlib
 import random
+import sys
 import threading
 from dataclasses import dataclass, field
 
@@ -368,6 +369,16 @@ class ServingMetrics:
                 ]
             except Exception:  # noqa: BLE001 — scrape must not 500
                 del lines[lmark:]
+            # which path each packed matmul took while its program was
+            # traced. Only where the ops are loaded: a process that never
+            # imported them dispatched nothing, and a scrape imports no JAX.
+            quant = sys.modules.get("mlx_sharding_tpu.ops.quant")
+            if quant is not None:
+                lines.append("# TYPE mst_quant_dispatch_total counter")
+                lines += [
+                    f'mst_quant_dispatch_total{{path="{path}"}} {n}'
+                    for path, n in sorted(quant.dispatch_counts().items())
+                ]
             # any engine accessor can die mid-scrape (replica torn
             # down, pool closing); drop the whole engine section
             # cleanly rather than 500 or emit a half-rendered family
@@ -1084,6 +1095,10 @@ _HELP = {
     "mst_state_resets_total":
         "First prefill chunks dispatched: each starts its slot's recurrent "
         "state from zero, inside the chunk's program.",
+    "mst_quant_dispatch_total":
+        "Packed 4-bit matmuls by the path ops/quant chose, one count per "
+        "traced call: gemv and matmul are the Pallas kernels; xla "
+        "dequantizes the whole weight in HBM every step (0 on a chip).",
     "mst_faults_armed":
         "Currently armed fault-injection sites (should be 0 in prod).",
     "mst_faults_malformed_total":

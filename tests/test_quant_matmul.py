@@ -19,15 +19,22 @@ from mlx_sharding_tpu.ops.quant_matmul import quant_matmul_pallas
 
 
 @pytest.mark.parametrize(
-    "m,in_dim,out_dim,gs,bits",
+    "m,in_dim,out_dim,gs,bits,block_out",
     [
-        (128, 512, 128, 64, 4),
-        (1, 512, 256, 64, 4),  # decode-shaped: one row
-        (64, 1024, 128, 128, 4),
-        (8, 512, 128, 64, 8),
+        (128, 512, 128, 64, 4, 64),
+        (1, 512, 256, 64, 4, 64),  # decode-shaped: one row
+        (64, 1024, 128, 128, 4, 64),
+        (8, 512, 128, 64, 8, 64),
+        # a ragged last OUT tile: 64 x 3 and 64 x 5 rows are 1.5 and 2.5
+        # tiles of 128 (DeepSeek-V2-Lite's dense width is 64 x 171), at a
+        # decode batch's and a prefill chunk's rows
+        (16, 512, 192, 64, 4, 128),
+        (16, 512, 320, 64, 4, 128),
+        (256, 512, 192, 64, 4, 128),
+        (256, 512, 320, 64, 4, 128),
     ],
 )
-def test_pallas_kernel_matches_dense(m, in_dim, out_dim, gs, bits):
+def test_pallas_kernel_matches_dense(m, in_dim, out_dim, gs, bits, block_out):
     rng = np.random.default_rng(3)
     w = rng.normal(size=(out_dim, in_dim)).astype(np.float32)
     q, s, b = quantize(w, group_size=gs, bits=bits)
@@ -40,9 +47,120 @@ def test_pallas_kernel_matches_dense(m, in_dim, out_dim, gs, bits):
     got = quant_matmul_pallas(
         jnp.asarray(x), jnp.asarray(q), jnp.asarray(s, jnp.float32),
         jnp.asarray(b, jnp.float32), group_size=gs, bits=bits,
-        block_m=64, block_out=64, block_in=256, interpret=True,
+        block_m=64, block_out=block_out, block_in=256, interpret=True,
     )
+    assert got.shape == want.shape
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-4)
+
+
+def test_pallas_kernel_refuses_ragged_in():
+    """OUT may end in a partial tile, IN may not: what lies past the edge
+    of a ragged IN block would be added into every output column."""
+    rng = np.random.default_rng(3)
+    q, s, b = quantize(rng.normal(size=(192, 512)).astype(np.float32))
+    x = jnp.asarray(rng.normal(size=(16, 512)), jnp.float32)
+    with pytest.raises(ValueError, match="IN=512"):
+        quant_matmul_pallas(
+            x, jnp.asarray(q), jnp.asarray(s, jnp.float32),
+            jnp.asarray(b, jnp.float32), block_out=128, block_in=384,
+            interpret=True,
+        )
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, nested programs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for v in eqn.params.values():
+            for inner in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _pallas_calls(inner)
+
+
+def _dsv2_lite_projections():
+    """``pytest.param(name, OUT, IN)`` of every packed projection the served
+    programs of ``dsv2-lite-q4`` run, from the configuration's own widths."""
+    cfg = json.loads(
+        (Path(__file__).parent.parent / "benchmarks/configs/dsv2-lite-q4.json")
+        .read_text()
+    )
+    hidden, heads = cfg["hidden_size"], cfg["num_attention_heads"]
+    dense = cfg["intermediate_size"]
+    shared = cfg["n_shared_experts"] * cfg["moe_intermediate_size"]
+    return [pytest.param(*p, id=p[0]) for p in (
+        ("q", heads * (cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"]), hidden),
+        ("kv_a", cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"], hidden),
+        ("o", hidden, heads * cfg["v_head_dim"]),
+        ("dense_gate", dense, hidden),
+        ("dense_up", dense, hidden),
+        ("dense_down", hidden, dense),
+        ("shared_gate", shared, hidden),
+        ("shared_up", shared, hidden),
+        ("shared_down", hidden, shared),
+        ("head", cfg["vocab_size"], hidden),
+    )]
+
+
+# block_out of each projection before the kernel took a ragged OUT tile, at
+# a decode batch's 16 rows and a prefill chunk's 256: what
+# ``pick_block_out`` gave at the commit before, for every shape that passed
+# ``_pallas_ok`` there. None: refused there (10944 = 64 x 171 has no
+# divisor that is a multiple of 128), fell to ``_quant_matmul_xla``.
+_DSV2_BLOCK_OUT_BEFORE = {
+    16: dict(q=1024, kv_a=576, o=1024, dense_gate=None, dense_up=None,
+             dense_down=128, shared_gate=1408, shared_up=1408,
+             shared_down=1024, head=1280),
+    256: dict(q=1024, kv_a=576, o=1024, dense_gate=None, dense_up=None,
+              dense_down=128, shared_gate=256, shared_up=256,
+              shared_down=512, head=1024),
+}
+
+
+@pytest.mark.parametrize("m", [16, 256])
+@pytest.mark.parametrize("name,out_dim,in_dim", _dsv2_lite_projections())
+def test_dsv2_lite_projections_reach_a_kernel(name, out_dim, in_dim, m, monkeypatch):
+    """On a TPU every packed projection of ``dsv2-lite-q4`` is served by a
+    Pallas kernel at the cell's rows, none by the f32 dequantization in HBM
+    that ``gate_proj`` and ``up_proj`` took for thirty PRs; and every shape
+    that was served before is tiled as it was."""
+    from mlx_sharding_tpu.ops import quant
+
+    def refuse(*a, **k):
+        raise AssertionError(f"{name} ({in_dim} -> {out_dim}) at M={m} fell to XLA")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(quant, "_quant_matmul_xla", refuse)
+    before = quant.dispatch_counts()
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in (
+            ((m, in_dim), jnp.bfloat16), ((out_dim, in_dim // 8), jnp.uint32),
+            ((out_dim, in_dim // 64), jnp.float32),
+            ((out_dim, in_dim // 64), jnp.float32),
+        )
+    ]
+    # traced, not compiled: the program as the chip's compiler would get it
+    jaxpr = jax.make_jaxpr(
+        lambda *a: quant._quant_matmul(*a, group_size=64, bits=4)
+    )(*args)
+    after = quant.dispatch_counts()
+    assert after["xla"] == before["xla"]
+    assert after["matmul"] == before["matmul"] + 1  # 16 rows: past the GEMV
+
+    (call,) = _pallas_calls(jaxpr.jaxpr)
+    assert call.params["name"] == "quant_matmul"
+    mapping = call.params["grid_mapping"]
+    out_block = [
+        getattr(b, "block_size", b) for b in mapping.block_mappings[-1].block_shape
+    ]
+    block_m, block_out = out_block
+    assert mapping.grid[:2] == (m // block_m, -(-out_dim // block_out))
+    was = _DSV2_BLOCK_OUT_BEFORE[m][name]
+    if was is None:  # the ragged arm: whole 128-lane tiles, the last partial
+        assert block_out % 128 == 0 and out_dim % block_out
+    else:
+        assert block_out == was and out_dim % block_out == 0
 
 
 def test_linear_dispatch_packed_vs_dense():
@@ -63,6 +181,30 @@ def test_linear_dispatch_packed_vs_dense():
     assert is_quantized(packed) and not is_quantized(jnp.asarray(dense))
     got = np.asarray(linear(x, packed, 64, 4))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_dispatch_is_counted_once_a_traced_call_and_shown_on_metrics():
+    """``mst_quant_dispatch_total{path}`` counts where ``_quant_matmul``
+    chooses: off the chip that is the XLA path, once per traced call and
+    not once per run of the compiled program."""
+    from mlx_sharding_tpu.ops import quant
+    from mlx_sharding_tpu.utils.observability import ServingMetrics
+
+    rng = np.random.default_rng(5)
+    q, s, b = quantize(rng.normal(size=(96, 128)).astype(np.float32))
+    packed = {"q": jnp.asarray(q), "scales": jnp.asarray(s, jnp.float32),
+              "biases": jnp.asarray(b, jnp.float32)}
+    fn = jax.jit(lambda x: linear(x, packed))
+    before = quant.dispatch_counts()
+    for _ in range(3):
+        fn(jnp.ones((16, 128), jnp.float32)).block_until_ready()
+    after = quant.dispatch_counts()
+    assert after == {**before, "xla": before["xla"] + 1}
+    text = ServingMetrics().render()
+    assert "# TYPE mst_quant_dispatch_total counter" in text
+    assert "# HELP mst_quant_dispatch_total" in text
+    for path, n in after.items():
+        assert f'mst_quant_dispatch_total{{path="{path}"}} {n}' in text
 
 
 def _quantized_tiny_llama(tmp_path: Path, group_size: int = 64):

@@ -144,11 +144,17 @@ CASES = {
         1, 8192, 3072, "quant_gemv_pipelined", BF16),
     # DeepSeek-V2-Lite experts and dense MLP at decode: the GEMV where its
     # DMA slices align; 1408 inputs are 176 word lanes (not 128-aligned), so
-    # that one takes the 3-D-grid kernel whole-IN; 10944 rows do not divide
-    # into 128-row tiles, so the predicate must send it to XLA
+    # that one takes the 3-D-grid kernel whole-IN; 10944 rows (64 x 171) do
+    # not divide into 128-row tiles, so the 3-D-grid kernel takes them with
+    # a ragged last OUT tile: a partial write of the output's lane dimension
+    # and edge reads of q, scales and biases, which Mosaic must accept
     "quant-M1-dsv2-2048x1408": _quant(1, 1408, 2048, "quant_gemv_pipelined"),
     "quant-M1-dsv2-1408x2048": _quant(1, 2048, 1408, "quant_matmul"),
-    "quant-M1-dsv2-2048x10944": _quant(1, 10944, 2048, None),
+    "quant-M1-dsv2-2048x10944": _quant(1, 10944, 2048, "quant_matmul"),
+    # ... and at the served rows: a 16-slot decode step and a 256-row chunk,
+    # gate/up (ragged OUT) and down (IN 10944 whole, 1368 word lanes)
+    **{f"quant-M{m}-dsv2-{i}x{o}": _quant(m, o, i, "quant_matmul")
+       for o, i in ((10944, 2048), (2048, 10944)) for m in (16, 256)},
     # the routed experts at decode, published widths: DeepSeek-V2-Lite's
     # (64, 1408, 256) / (64, 2048, 176) stacks at the cell's 16 rows x top-6
     # (the 176-word leaf is read transposed, as it lies in HBM), at one row,
