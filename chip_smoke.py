@@ -329,6 +329,7 @@ def child_kernels(seed: int, rehearse: bool) -> None:
     so a predicate that quietly chose XLA fails here; the rehearsal calls
     the kernels directly in interpret mode."""
     import functools
+    import itertools
 
     import jax
     import jax.numpy as jnp
@@ -416,24 +417,30 @@ def child_kernels(seed: int, rehearse: bool) -> None:
               (q, k, v, jnp.asarray(off, jnp.int32)), ATTN_ATOL)
 
     # ---- ragged paged attention: bf16 and int8 pools, two page sizes,
-    # eight slots of uneven length (empty, page-boundary, full)
+    # eight slots of uneven length (empty, page-boundary, full); the GQA
+    # layout and MLA's latent one (one 576-lane latent head whose first 512
+    # lanes are the values, V a (…, 1, 1) dummy: DeepSeek-V2-Lite's widths)
     max_seq = s_len
-    for page in pages:
+    layouts = {"": (hq, hkv, d, d, None),
+               "latent ": (4, 1, 24, 1, 16) if rehearse else (16, 1, 576, 1, 512)}
+    for page, (layout, (lhq, lhkv, dk, dv, rank)) in itertools.product(
+        pages, layouts.items()
+    ):
         spg = max_seq // page
         lengths = [0, 1, page, page + 1, 3 * page - 1, max_seq // 2 + 5,
                    max_seq - 1, max_seq]
         m = len(lengths)
         kq, kk, kv, kp, key = jax.random.split(key, 5)
         n_pages = m * spg
-        k_pool = jax.random.normal(kk, (n_pages + 1, page, hkv, d), bf16)
-        v_pool = jax.random.normal(kv, (n_pages + 1, page, hkv, d), bf16)
+        k_pool = jax.random.normal(kk, (n_pages + 1, page, lhkv, dk), bf16)
+        v_pool = jax.random.normal(kv, (n_pages + 1, page, lhkv, dv), bf16)
         # each slot owns a shuffled set of pages; past its length, scratch
         perm = np.asarray(jax.random.permutation(kp, n_pages)).reshape(m, spg)
         tables = np.full((m, spg), n_pages, np.int32)
         for i, ln in enumerate(lengths):
             used = -(-ln // page)
             tables[i, :used] = perm[i, :used]
-        q = jax.random.normal(kq, (m, hq, d), bf16)
+        q = jax.random.normal(kq, (m, lhq, dk), bf16)
         tables, lens = jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
         kq8, vq8 = quantize_kv_rows(k_pool), quantize_kv_rows(v_pool)
         for label, args in (
@@ -442,15 +449,16 @@ def child_kernels(seed: int, rehearse: bool) -> None:
                       kq8["s"], vq8["s"])),
         ):
             def fn(q, kp_, vp_, tb, ln, ks=None, vs=None):
-                return paged_attention(q, kp_, vp_, tb, ln, scale, k_scale=ks,
+                return paged_attention(q, kp_, vp_, tb, ln, dk ** -0.5,
+                                       values_from_k=rank, k_scale=ks,
                                        v_scale=vs, interpret=rehearse)
 
             def ref(q, kp_, vp_, tb, ln, ks=None, vs=None):
-                return _paged_attention_xla(q, kp_, vp_, tb, ln, scale,
-                                            None, None, None, ks, vs)
+                return _paged_attention_xla(q, kp_, vp_, tb, ln, dk ** -0.5,
+                                            None, None, rank, ks, vs)
 
-            check(f"paged {label} page={page}", "paged_attention", fn, ref,
-                  args, ATTN_ATOL)
+            check(f"paged {layout}{label} page={page}", "paged_attention", fn,
+                  ref, args, ATTN_ATOL)
 
     # ---- 4-bit matmuls: the batch kernel at a prefill chunk's rows and a
     # 16-slot decode step's, the GEMV at M=1 and 8
@@ -767,6 +775,13 @@ def phase_server(run: Run) -> None:
         metrics = srv.get("/metrics")
     need(metric(metrics, "mst_paged_attention_ragged") == 1,
          "/metrics does not report the ragged paged-attention path")
+    # every ragged attention call of the served programs took the kernel
+    # (the rehearsal has no chip: there every one takes the XLA path)
+    took = {path: metric(metrics, f'mst_paged_attention_dispatch_total{{path="{path}"}}')
+            for path in ("kernel", "xla")}
+    need(took["xla" if run.rehearse else "kernel"]
+         and not took["kernel" if run.rehearse else "xla"],
+         f"/metrics: ragged paged attention dispatched {took}")
     need(metric(metrics, "mst_requests_failed_total") == 0,
          "/metrics reports failed requests")
     need(not metric(metrics, "mst_preemptions_total"),

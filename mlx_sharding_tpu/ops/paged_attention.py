@@ -24,15 +24,19 @@ Two paths, selected the same way ops/flash_attention.py picks its path:
   scratch id) collapses to one redundant fetch: consecutive grid steps with
   an identical block index skip the DMA. Online-softmax state (running max,
   normalizer, fp32 accumulator, per head) lives in VMEM scratch across the
-  page walk.
-- a fused-XLA fallback for CPU / odd shapes / softcap / sliding-window /
-  MLA latent-as-values, mirroring ops/attention.py's masking semantics but
-  gathering only the slot's own table row (slot_pages × page rows), never
-  a max_seq-dense buffer per layer stack.
+  page walk. MLA's latent-as-values (``values_from_k``) is the same kernel
+  with one operand fewer: the value block is the first ``values_from_k``
+  lanes of the key block already in VMEM, and the latent pool's dummy
+  (…, 1, 1) V is never fetched.
+- a fused-XLA fallback for CPU / odd shapes / softcap / sliding-window,
+  mirroring ops/attention.py's masking semantics. It gathers every slot's
+  WHOLE table row (slot_pages × page rows, i.e. max_seq) and masks it, so
+  its cost follows max_seq and not the caches' lengths: 75 MB a layer at
+  16 slots × 16 pages of 256 × 576 bf16, whatever the slots hold.
 
 Both are token-exact vs the gather path; tests/test_paged_attention.py holds
 the parity matrix (uneven lengths, page-boundary offsets, empty slots, GQA/
-MQA head counts, kernel-in-interpret vs XLA).
+MQA head counts and the latent layout, kernel-in-interpret vs XLA).
 """
 
 from __future__ import annotations
@@ -46,7 +50,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mlx_sharding_tpu.ops.dispatch import DispatchCounter
+
 NEG_INF = -1e30
+
+# Which path paged_attention chose, once per traced call (ops/dispatch.py).
+# /metrics shows it as ``mst_paged_attention_dispatch_total{path}``: "xla"
+# above 0 on a chip says some layer gathers its slots' whole table rows
+# every step.
+_DISPATCHED = DispatchCounter("kernel", "xla")
+dispatch_counts = _DISPATCHED.counts
+_count_dispatch = _DISPATCHED.count
 
 
 def kernel_eligible(
@@ -56,46 +70,63 @@ def kernel_eligible(
     sliding_window,
     values_from_k,
     interpret: bool,
+    hkv: int = 1,
 ) -> bool:
-    """Pallas path: TPU backend (or interpret mode on any backend), standard
-    GQA only — softcap/window/latent-values stay on the XLA path, like
+    """Pallas path: TPU backend (or interpret mode on any backend), GQA and
+    MLA's latent-as-values — softcap and windows stay on the XLA path, like
     ops/attention.py's _flash_eligible. Head dims need 64-alignment on real
-    hardware (Mosaic pads sub-128 lane tails); interpret mode takes any
-    shape so CPU tests exercise the kernel logic itself. Opt out entirely
-    with MST_PAGED_KERNEL=0."""
+    hardware (Mosaic pads sub-128 lane tails); with ``values_from_k`` the
+    values are the first lanes of each head's key slice, so their width is
+    a multiple of 128 inside ``dk`` (``dv``, the dummy V pool's, is not
+    asked) and several heads need a 128-aligned ``dk`` to start each slice
+    on a lane tile. int8 pools follow the same rules in both layouts.
+    Interpret mode takes any shape so CPU tests exercise the kernel logic
+    itself. Opt out entirely with MST_PAGED_KERNEL=0."""
     if os.environ.get("MST_PAGED_KERNEL", "1") == "0":
         return False
-    if (
-        logit_softcap is not None
-        or sliding_window is not None
-        or values_from_k is not None
-    ):
+    if logit_softcap is not None or sliding_window is not None:
         return False
     if interpret:
         return True
-    return jax.default_backend() == "tpu" and dk % 64 == 0 and dv % 64 == 0
+    if jax.default_backend() != "tpu" or dk % 64:
+        return False
+    if values_from_k is None:
+        return dv % 64 == 0
+    return (
+        values_from_k % 128 == 0
+        and values_from_k <= dk
+        and (hkv == 1 or dk % 128 == 0)
+    )
 
 
-def _kernel_body(
+def _kernel(
     tables_ref,  # (M, SPG) int32 — scalar-prefetch
     lens_ref,  # (M,) int32 — scalar-prefetch
     q_ref,  # (1, Hkv, G, Dk) block — one slot's query heads
-    k_ref,  # (1, page, Hkv*Dk) block — the page named by tables[m, j]
-    v_ref,  # (1, page, Hkv*Dv) block
-    ks_ref,  # (1, page, Hkv) per-row K scales (int8 pool) or None
-    vs_ref,  # (1, page, Hkv) per-row V scales (int8 pool) or None
-    o_ref,  # (1, Hkv, G, Dv) block
-    m_scr,  # (Hkv, G, 128) f32 VMEM — running max, lane-replicated
-    l_scr,  # (Hkv, G, 128) f32 VMEM — running normalizer
-    acc_scr,  # (Hkv, G, Dv) f32 VMEM — unnormalized output accumulator
-    *,
+    *refs,
+    # k_ref (1, page, Hkv*Dk) block — the page named by tables[m, j]
+    # v_ref (1, page, Hkv*Dv) block — absent where ``latent``
+    # ks_ref, vs_ref (1, page, Hkv) per-row scales — int8 pools only, and
+    #   vs_ref only beside v_ref
+    # o_ref (1, Hkv, G, Dv) block
+    # m_scr, l_scr (Hkv, G, 128) f32 VMEM — running max and normalizer,
+    #   lane-replicated
+    # acc_scr (Hkv, G, Dv) f32 VMEM — unnormalized output accumulator
     scale: float,
     page_size: int,
     pages_per_slot: int,
     hkv: int,
     dk: int,
     dv: int,
+    quant: bool,
+    latent: bool,
 ):
+    *pages, o_ref, m_scr, l_scr, acc_scr = refs
+    pages = iter(pages)
+    k_ref = next(pages)
+    v_ref = None if latent else next(pages)
+    ks_ref = next(pages) if quant else None
+    vs_ref = next(pages) if quant and not latent else None
     m = pl.program_id(0)
     j = pl.program_id(1)
     length = lens_ref[m]
@@ -118,13 +149,19 @@ def _kernel_body(
         for h in range(hkv):
             q = q_ref[0, h].astype(jnp.float32)  # (G, Dk)
             kblk = k_ref[0, :, h * dk:(h + 1) * dk].astype(jnp.float32)
-            vblk = v_ref[0, :, h * dv:(h + 1) * dv].astype(jnp.float32)
             if ks_ref is not None:
                 # int8 pool: dequant fused into the page read — the pool's
                 # HBM→VMEM traffic is the int8 bytes; the (page, 1) scale
                 # broadcasts over the head dim in registers
                 kblk = kblk * ks_ref[0, :, h:h + 1]
-                vblk = vblk * vs_ref[0, :, h:h + 1]
+            if latent:
+                # MLA: the values are the latent prefix of the key rows
+                # already in VMEM — no second page is fetched
+                vblk = kblk[:, :dv]
+            else:
+                vblk = v_ref[0, :, h * dv:(h + 1) * dv].astype(jnp.float32)
+                if vs_ref is not None:
+                    vblk = vblk * vs_ref[0, :, h:h + 1]
             s = jax.lax.dot_general(
                 q, kblk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -150,26 +187,14 @@ def _kernel_body(
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-            m_scr, l_scr, acc_scr, **kw):
-    _kernel_body(tables_ref, lens_ref, q_ref, k_ref, v_ref, None, None,
-                 o_ref, m_scr, l_scr, acc_scr, **kw)
-
-
-def _kernel_int8(tables_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                 o_ref, m_scr, l_scr, acc_scr, **kw):
-    _kernel_body(tables_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                 o_ref, m_scr, l_scr, acc_scr, **kw)
-
-
 def _paged_attention_kernel(
     q, k_pool, v_pool, tables, lengths, scale, interpret,
-    k_scale=None, v_scale=None,
+    k_scale=None, v_scale=None, values_from_k=None,
 ):
     m, hq, dk = q.shape
-    pages, page_size, hkv, dv = (
-        k_pool.shape[0], k_pool.shape[1], k_pool.shape[2], v_pool.shape[-1],
-    )
+    pages, page_size, hkv = k_pool.shape[:3]
+    latent = values_from_k is not None
+    dv = values_from_k if latent else v_pool.shape[-1]
     spg = tables.shape[1]
     g = hq // hkv
     qg = q.reshape(m, hkv, g, dk)
@@ -186,22 +211,20 @@ def _paged_attention_kernel(
             (1, page_size, width), lambda mi, ji, t, ln: (t[mi, ji], 0, 0)
         )
 
+    # every operand after q is a pool fetched page by page through the
+    # table. The latent layout's V pool is a (…, 1, 1) dummy: it is no
+    # operand, so no page of it is fetched
+    kv = [(k_pool, dk)] if latent else [(k_pool, dk), (v_pool, dv)]
+    if quant:  # the scale planes ride the same table-indexed fetch
+        scales = (k_scale,) if latent else (k_scale, v_scale)
+        kv += [(s.astype(jnp.float32), 1) for s in scales]
     in_specs = [
         pl.BlockSpec((1, hkv, g, dk), lambda mi, ji, t, ln: (mi, 0, 0, 0)),
-        page_spec(hkv * dk),
-        page_spec(hkv * dv),
+        *(page_spec(hkv * width) for _, width in kv),
     ]
     operands = [
-        qg,
-        k_pool.reshape(pages, page_size, hkv * dk),
-        v_pool.reshape(pages, page_size, hkv * dv),
+        qg, *(x.reshape(pages, page_size, hkv * width) for x, width in kv)
     ]
-    if quant:  # the scale planes ride the same table-indexed fetch
-        in_specs += [page_spec(hkv), page_spec(hkv)]
-        operands += [
-            k_scale.astype(jnp.float32).reshape(pages, page_size, hkv),
-            v_scale.astype(jnp.float32).reshape(pages, page_size, hkv),
-        ]
 
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -218,9 +241,9 @@ def _paged_attention_kernel(
     )
     out = pl.pallas_call(
         functools.partial(
-            _kernel_int8 if quant else _kernel,
+            _kernel,
             scale=scale, page_size=page_size, pages_per_slot=spg,
-            hkv=hkv, dk=dk, dv=dv,
+            hkv=hkv, dk=dk, dv=dv, quant=quant, latent=latent,
         ),
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((m, hkv, g, dv), q.dtype),
@@ -307,12 +330,15 @@ def paged_attention(
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
     if kernel_eligible(
-        dk, dv, logit_softcap, sliding_window, values_from_k, interpret
+        dk, dv, logit_softcap, sliding_window, values_from_k, interpret,
+        hkv=k_pool.shape[2],
     ):
+        _count_dispatch("kernel")
         return _paged_attention_kernel(
             q, k_pool, v_pool, tables, lengths, scale, interpret,
-            k_scale, v_scale,
+            k_scale, v_scale, values_from_k,
         )
+    _count_dispatch("xla")
     return _paged_attention_xla(
         q, k_pool, v_pool, tables, lengths, scale,
         logit_softcap, sliding_window, values_from_k, k_scale, v_scale,

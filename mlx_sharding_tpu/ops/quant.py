@@ -17,11 +17,11 @@ Pallas fused dequant-matmul is the follow-up optimization path.
 
 from __future__ import annotations
 
-import threading
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from mlx_sharding_tpu.ops.dispatch import DispatchCounter
 
 
 def dequantize(
@@ -141,24 +141,12 @@ def _gemv_ok(m, in_dim, out_dim, group_size, bits) -> bool:
     )
 
 
-# Which path _quant_matmul chose, one count per call: a jitted program asks
-# while it is traced, so a compiled program's steps add nothing and the
-# count costs the device nothing. /metrics shows it as
-# ``mst_quant_dispatch_total{path}``: "xla" above 0 on a chip says some
-# packed projection is dequantized in HBM every step.
-_DISPATCHED = {"gemv": 0, "matmul": 0, "xla": 0}
-_DISPATCHED_LOCK = threading.Lock()
-
-
-def dispatch_counts() -> dict[str, int]:
-    """Lifetime count of packed matmuls dispatched to each path."""
-    with _DISPATCHED_LOCK:
-        return dict(_DISPATCHED)
-
-
-def _count_dispatch(path: str) -> None:
-    with _DISPATCHED_LOCK:
-        _DISPATCHED[path] += 1
+# Which path _quant_matmul chose, once per traced call (ops/dispatch.py).
+# /metrics shows it as ``mst_quant_dispatch_total{path}``: "xla" above 0 on
+# a chip says some packed projection is dequantized in HBM every step.
+_DISPATCHED = DispatchCounter("gemv", "matmul", "xla")
+dispatch_counts = _DISPATCHED.counts
+_count_dispatch = _DISPATCHED.count
 
 
 def _quant_matmul(x2, q, scales, biases, group_size, bits):

@@ -369,16 +369,23 @@ class ServingMetrics:
                 ]
             except Exception:  # noqa: BLE001 — scrape must not 500
                 del lines[lmark:]
-            # which path each packed matmul took while its program was
-            # traced. Only where the ops are loaded: a process that never
-            # imported them dispatched nothing, and a scrape imports no JAX.
-            quant = sys.modules.get("mlx_sharding_tpu.ops.quant")
-            if quant is not None:
-                lines.append("# TYPE mst_quant_dispatch_total counter")
-                lines += [
-                    f'mst_quant_dispatch_total{{path="{path}"}} {n}'
-                    for path, n in sorted(quant.dispatch_counts().items())
-                ]
+            # which path each packed matmul and each ragged paged-attention
+            # call took while its program was traced. Only where the ops are
+            # loaded: a process that never imported them dispatched nothing,
+            # and a scrape imports no JAX.
+            for type_line, module in (
+                ("# TYPE mst_quant_dispatch_total counter", "quant"),
+                ("# TYPE mst_paged_attention_dispatch_total counter",
+                 "paged_attention"),
+            ):
+                ops = sys.modules.get(f"mlx_sharding_tpu.ops.{module}")
+                if ops is not None:
+                    family = type_line.split()[2]
+                    lines.append(type_line)
+                    lines += [
+                        f'{family}{{path="{path}"}} {n}'
+                        for path, n in sorted(ops.dispatch_counts().items())
+                    ]
             # any engine accessor can die mid-scrape (replica torn
             # down, pool closing); drop the whole engine section
             # cleanly rather than 500 or emit a half-rendered family
@@ -1099,6 +1106,11 @@ _HELP = {
         "Packed 4-bit matmuls by the path ops/quant chose, one count per "
         "traced call: gemv and matmul are the Pallas kernels; xla "
         "dequantizes the whole weight in HBM every step (0 on a chip).",
+    "mst_paged_attention_dispatch_total":
+        "Ragged paged-attention calls by the path ops/paged_attention "
+        "chose, one count per traced call: kernel walks each slot's live "
+        "pages in place; xla gathers every slot's whole table row (0 on a "
+        "chip unless a layer has a softcap or a window).",
     "mst_faults_armed":
         "Currently armed fault-injection sites (should be 0 in prod).",
     "mst_faults_malformed_total":

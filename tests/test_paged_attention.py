@@ -1,10 +1,14 @@
 """Ragged paged-attention parity matrix (ISSUE 1 tentpole). Op level: the
 Pallas kernel (interpret mode) and the fused-XLA fallback must both match a
 straight-line numpy reference over uneven lengths, page-boundary offsets,
-empty slots, and GQA/MQA head layouts. Engine level: a mixed-length
+empty slots, and GQA/MQA head layouts; the kernel in MLA's latent layout
+(values = the first lanes of the key rows, no V operand) must match the
+fallback at the published widths. Engine level: a mixed-length
 continuous-batching run on the ragged path must be token-exact vs the
-gather path and vs the serial generator."""
+gather path and vs the serial generator, for a GQA model and for a
+compressed-MLA one whose ragged body goes through the kernel."""
 
+import functools
 import threading
 
 import jax
@@ -12,10 +16,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from mlx_sharding_tpu.config import LlamaConfig
+from mlx_sharding_tpu.cache import quantize_kv_rows
+from mlx_sharding_tpu.config import DeepseekV2Config, LlamaConfig
 from mlx_sharding_tpu.generate import Generator
+from mlx_sharding_tpu.models.deepseek_v2 import DeepseekV2Model
 from mlx_sharding_tpu.models.llama import LlamaModel
+from mlx_sharding_tpu.ops import paged_attention as paged_ops
 from mlx_sharding_tpu.ops.paged_attention import (
+    _paged_attention_xla,
     kernel_eligible,
     paged_attention,
 )
@@ -27,6 +35,17 @@ PAGE = 8
 SPG = 4  # slot pages — virtual max of 32 positions per slot
 
 
+def _own_pages(lengths, page, spg):
+    """A page table as init_cache_paged lays it out: each slot owns distinct
+    pages for its live prefix, the scratch page (last pool id) past it."""
+    n_pages = len(lengths) * spg
+    tables = np.full((len(lengths), spg), n_pages, np.int32)
+    for i, ln in enumerate(lengths):
+        used = -(-ln // page)
+        tables[i, :used] = np.arange(i * spg, i * spg + used)
+    return tables
+
+
 def _make_case(rng, lengths, hq, hkv, dk, dv):
     """Build a pool where each slot owns distinct pages for its live prefix
     and the scratch page (last pool id) past it, exactly like
@@ -36,10 +55,7 @@ def _make_case(rng, lengths, hq, hkv, dk, dv):
     n_pages = m * SPG
     k_pool = rng.standard_normal((n_pages + 1, PAGE, hkv, dk), np.float32)
     v_pool = rng.standard_normal((n_pages + 1, PAGE, hkv, dv), np.float32)
-    tables = np.full((m, SPG), n_pages, np.int32)  # scratch everywhere
-    for i, ln in enumerate(lengths):
-        used = -(-ln // PAGE)
-        tables[i, :used] = np.arange(i * SPG, i * SPG + used)
+    tables = _own_pages(lengths, PAGE, SPG)
     q = rng.standard_normal((m, hq, dk), np.float32)
     dense_k = k_pool[tables].reshape(m, SPG * PAGE, hkv, dk)
     dense_v = v_pool[tables].reshape(m, SPG * PAGE, hkv, dv)
@@ -138,6 +154,125 @@ def test_op_sliding_window_and_softcap_stay_xla():
         sliding_window=window,
     )
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------- latent ---
+
+
+def _latent_case(rng, lengths, hq, hkv, dk, page, spg, dtype):
+    """An MLA-shaped pool: ``hkv`` latent heads of width ``dk`` in K, the
+    dummy ``(…, 1, 1)`` V the compressed cache mode allocates."""
+    m = len(lengths)
+    n_pages = m * spg
+    k_pool = rng.standard_normal((n_pages + 1, page, hkv, dk), np.float32)
+    q = rng.standard_normal((m, hq, dk), np.float32)
+    return (
+        jnp.asarray(q, dtype), jnp.asarray(k_pool, dtype),
+        jnp.zeros((n_pages + 1, page, 1, 1), dtype),
+        jnp.asarray(_own_pages(lengths, page, spg)),
+        jnp.asarray(lengths, jnp.int32),
+    )
+
+
+# lengths: empty, one row, a page boundary, mid third page / 770 (the
+# cell's longest cache), a full table row
+LATENT = {
+    # DeepSeek-V2-Lite's latent head as published: 16 query heads on one
+    # latent head, 512 + 64 rope lanes, values the first 512, 256-token pages
+    "published-f32": (16, 1, 576, 512, 256, 4, [0, 1, 256, 770, 1024], jnp.float32, 1e-5),
+    "published-bf16": (16, 1, 576, 512, 256, 4, [0, 1, 256, 770, 1024], jnp.bfloat16, 2e-2),
+    "small": (4, 1, 24, 16, 8, 4, [0, 1, 8, 19, 32], jnp.float32, 1e-5),
+    "small-two-heads": (4, 2, 24, 16, 8, 4, [0, 1, 8, 19, 32], jnp.float32, 1e-5),
+}
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["pool", "int8-pool"])
+@pytest.mark.parametrize("case", list(LATENT))
+def test_latent_kernel_matches_xla(case, int8):
+    """``values_from_k``: the kernel (interpret mode) takes its value block
+    from the key block's first lanes and is handed no V pool; it must equal
+    the fallback, which slices the gathered keys. int8 pools take the same
+    kernel: the key block is scaled, then sliced."""
+    hq, hkv, dk, vfk, page, spg, lengths, dtype, tol = LATENT[case]
+    q, k_pool, v_pool, tables, lens = _latent_case(
+        np.random.default_rng(3), lengths, hq, hkv, dk, page, spg, dtype
+    )
+    ks = vs = None
+    if int8:
+        kq, vq = quantize_kv_rows(k_pool), quantize_kv_rows(v_pool)
+        k_pool, ks, v_pool, vs = kq["d"], kq["s"], vq["d"], vq["s"]
+    scale = dk ** -0.5
+    before = paged_ops.dispatch_counts()
+    got = paged_attention(
+        q, k_pool, v_pool, tables, lens, scale, values_from_k=vfk,
+        k_scale=ks, v_scale=vs, interpret=True,
+    )
+    after = paged_ops.dispatch_counts()
+    assert after == {**before, "kernel": before["kernel"] + 1}
+    want = _paged_attention_xla(
+        q, k_pool, v_pool, tables, lens, scale, None, None, vfk, ks, vs
+    )
+    assert got.shape == want.shape == (len(lengths), hq, vfk)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+    assert not got[0].any()  # the empty slot: zeros, on both paths
+    assert np.abs(want[1:]).max() > 0.5
+
+
+# (dk, dv, softcap, window, values_from_k, hkv) -> the kernel on a chip?
+ELIGIBLE = {
+    "gqa-128": ((128, 128, None, None, None, 8), True),
+    "gqa-dv-1": ((576, 1, None, None, None, 1), False),
+    "latent-published": ((576, 1, None, None, 512, 1), True),
+    "latent-whole-key": ((512, 1, None, None, 512, 1), True),
+    "latent-two-heads-aligned": ((256, 1, None, None, 128, 2), True),
+    "latent-two-heads-unaligned-dk": ((576, 1, None, None, 512, 2), False),
+    "latent-values-unaligned": ((576, 1, None, None, 500, 1), False),
+    "latent-values-wider-than-key": ((576, 1, None, None, 640, 1), False),
+    "latent-dk-unaligned": ((552, 1, None, None, 512, 1), False),
+    "latent-softcap": ((576, 1, 30.0, None, 512, 1), False),
+    "latent-window": ((576, 1, None, 128, 512, 1), False),
+}
+
+
+@pytest.mark.parametrize("case", list(ELIGIBLE))
+def test_kernel_eligible_table(case, monkeypatch):
+    """The predicate on a chip: the latent layout is admitted where its
+    lane slices are tile-aligned; softcap and windows stay on the XLA path
+    whatever the layout. An int8 pool changes nothing in it (the case above
+    runs int8 + latent through the kernel)."""
+    (dk, dv, softcap, window, vfk, hkv), want = ELIGIBLE[case]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kernel_eligible(
+        dk, dv, softcap, window, vfk, interpret=False, hkv=hkv
+    ) is want
+    # off the chip nothing reaches the kernel but in interpret mode
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert not kernel_eligible(
+        dk, dv, softcap, window, vfk, interpret=False, hkv=hkv
+    )
+
+
+def test_dispatch_is_counted_once_a_traced_call_and_shown_on_metrics():
+    """``mst_paged_attention_dispatch_total{path}`` counts where
+    ``paged_attention`` chooses: once per traced call, not once per run of
+    the compiled program. Off the chip that is the XLA path."""
+    from mlx_sharding_tpu.utils.observability import ServingMetrics
+
+    args = _latent_case(
+        np.random.default_rng(4), [3, 0, 9], 4, 1, 24, 8, 4, jnp.float32
+    )
+    fn = jax.jit(functools.partial(paged_attention, scale=0.2, values_from_k=16))
+    before = paged_ops.dispatch_counts()
+    for _ in range(3):
+        fn(*args).block_until_ready()
+    after = paged_ops.dispatch_counts()
+    assert after == {**before, "xla": before["xla"] + 1}
+    text = ServingMetrics().render()
+    assert "# TYPE mst_paged_attention_dispatch_total counter" in text
+    assert "# HELP mst_paged_attention_dispatch_total" in text
+    for path, n in after.items():
+        assert f'mst_paged_attention_dispatch_total{{path="{path}"}} {n}' in text
 
 
 def test_kernel_env_opt_out(monkeypatch):
@@ -239,4 +374,58 @@ def test_engine_mixed_length_cb_parity_ragged_vs_gather():
             ]
             assert streams[path] == want
 
+    assert streams["ragged"] == streams["gather"]
+
+
+DSV2_TINY = dict(
+    vocab_size=300, hidden_size=32, intermediate_size=64,
+    moe_intermediate_size=16, num_hidden_layers=3,
+    num_attention_heads=4, num_key_value_heads=4, kv_lora_rank=16,
+    q_lora_rank=None, qk_rope_head_dim=8, qk_nope_head_dim=16,
+    v_head_dim=12, n_routed_experts=4, n_shared_experts=1,
+    num_experts_per_tok=2, first_k_dense_replace=1,
+    mla_cache_mode="compressed",
+)
+
+
+def test_engine_mixed_length_cb_parity_latent_kernel_vs_gather(monkeypatch):
+    """The twin of the test above for a compressed-MLA model: the served
+    ragged body, its attention through the KERNEL (interpret mode: the
+    engine's calls are handed ``interpret=True``), gives the token streams
+    of the gather body and of the serial generator. Every ragged-attention
+    call of the run is a latent one and none takes the XLA path."""
+    monkeypatch.setattr(
+        paged_ops, "paged_attention",
+        functools.partial(paged_attention, interpret=True),
+    )
+    model = DeepseekV2Model(DeepseekV2Config(**DSV2_TINY))
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    rng = np.random.default_rng(11)
+    jobs = []
+    for i, plen in enumerate([3, 8, 13, 17]):  # mid/boundary/multi-page
+        prompt = [int(t) for t in rng.integers(1, 300, size=plen)]
+        jobs.append((prompt, dict(max_tokens=int(6 + 3 * i), seed=i)))
+
+    streams = {}
+    for path in ("ragged", "gather"):
+        eng = PipelineEngine(
+            model, params, pipeline_mesh(1), microbatches=2, max_seq=64,
+            cache_dtype=jnp.float32, prefill_chunk=8,
+            pool_pages=10, page_size=8, paged_attention=path,
+        )
+        before = paged_ops.dispatch_counts()
+        batcher = ContinuousBatcher(eng, decode_block=3)
+        try:
+            streams[path] = _concurrent(batcher, jobs)
+        finally:
+            batcher.close()
+        after = paged_ops.dispatch_counts()
+        assert after["xla"] == before["xla"]
+        assert (after["kernel"] > before["kernel"]) == (path == "ragged")
+
+    ref = Generator(
+        model, params, max_seq=64, cache_dtype=jnp.float32, prefill_chunk=8
+    )
+    want = [[t for t, _ in ref.generate_step(p, **kw)] for p, kw in jobs]
+    assert streams["ragged"] == want
     assert streams["ragged"] == streams["gather"]

@@ -69,19 +69,26 @@ def _flash(t, s, hq, hkv, dk, dv, via_dispatch=True, kernel="flash_attention"):
     )
 
 
-def _paged(page, int8, slots=8, hq=24, hkv=8, d=128, max_seq=4096):
-    pool = ((slots * max_seq // page + 1, page, hkv, d), jnp.int8 if int8 else BF16)
-    scale = ((pool[0][0], page, hkv, 1), F32)
+def _paged(page, int8, slots=8, hq=24, hkv=8, d=128, max_seq=4096,
+           pages=None, rank=None):
+    """``rank``: MLA's latent layout — the values are the first ``rank``
+    lanes of the ``d``-wide key rows (``values_from_k``) and V is the dummy
+    ``(…, 1, 1)`` pool the kernel is never handed."""
+    pages = pages or slots * max_seq // page + 1
+    dtype = jnp.int8 if int8 else BF16
+    k_pool = ((pages, page, hkv, d), dtype)
+    v_pool = k_pool if rank is None else ((pages, page, 1, 1), dtype)
+    scales = [((pages, page, p[0][2], 1), F32) for p in (k_pool, v_pool)]
 
     def fn(q, k, v, tables, lengths, ks=None, vs=None):
         return paged_attention(q, k, v, tables, lengths, d ** -0.5,
-                               k_scale=ks, v_scale=vs)
+                               values_from_k=rank, k_scale=ks, v_scale=vs)
 
     return (
         fn,
-        [((slots, hq, d), BF16), pool, pool,
+        [((slots, hq, d), BF16), k_pool, v_pool,
          ((slots, max_seq // page), I32), ((slots,), I32)]
-        + ([scale, scale] if int8 else []),
+        + (scales if int8 else []),
         "paged_attention",
     )
 
@@ -135,6 +142,12 @@ CASES = {
     # and at 128, bf16 and int8 pools
     **{f"paged-{'int8' if q else 'bf16'}-page{p}": _paged(p, q)
        for p in (256, 128) for q in (False, True)},
+    # ... and in MLA's latent layout (values_from_k): 16 slots of the
+    # dsv2-lite-q4 cell, 16 query heads on the 576-lane latent head, a
+    # 576-wide contraction and a [0:512] lane slice of the key block
+    **{f"paged-mla-latent-{'int8-' if q else ''}page256": _paged(
+        256, q, slots=16, hq=16, hkv=1, d=576, pages=145, rank=512)
+       for q in (False, True)},
     # 4-bit projections of the 3B model: batch kernel at M=256, GEMV at 1, 8
     **{f"quant-M{m}-{i}x{o}": _quant(
         m, o, i, "quant_matmul" if m == 256 else "quant_gemv_pipelined")
@@ -253,3 +266,40 @@ def test_experts_read_in_place_copy_nothing_of_stack_size(chip, monkeypatch):
     assert _arrays_made(_loop_bodies(text), e * width * (hidden // 64) * 4) == []
     once = 2 * layers * e * hidden * 24 * 4  # 22 groups on 24 sublanes
     assert compiled.memory_analysis().temp_size_in_bytes < once + 64 * 2**20
+
+
+def test_latent_attention_gathers_no_table_and_copies_no_pool(chip, monkeypatch):
+    """The ragged decode body's write-then-attend in MLA's latent layout,
+    scanned over a two-layer ``(L, 145, 1, 256, 1, 576)`` pool (16 slots,
+    a table 16 pages wide): each layer scatters the slots' new rows into its
+    pool and hands the pool to ``paged_attention(values_from_k=512)``. With
+    the kernel the loop makes nothing as large as one gathered table
+    (16 x 16 pages x 256 x 576 bf16, 75.5 MB: the fallback makes a gather,
+    a transposed copy, a select and a value slice of it, per layer per
+    step), and the only pool-sized thing in it is the scan's own in-place
+    write of the layer back into its stack: no ``copy`` of the pool."""
+    layers, pages, page, dk, rank, slots, hq, spg = 2, 145, 256, 576, 512, 16, 16, 16
+
+    def step(q, k, v, rows, page_ids, row_pos, lengths):
+        def layer(h, kv):
+            kl, vl = kv[0][:, 0], kv[1][:, 0]  # drop the B == 1 axis
+            kl = kl.at[page_ids, row_pos].set(h[:, :1])
+            vl = vl.at[page_ids, row_pos].set(jnp.zeros((slots, 1, 1), vl.dtype))
+            out = paged_attention(h, kl, vl, rows, lengths, dk ** -0.5,
+                                  values_from_k=rank)
+            h = h + jnp.pad(out, ((0, 0), (0, 0), (0, dk - rank)))
+            return h, (kl[:, None], vl[:, None])
+
+        return jax.lax.scan(layer, q, (k, v))
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pool = (layers, pages, 1, page, 1)
+    shapes = [((slots, hq, dk), BF16), ((*pool, dk), BF16), ((*pool, 1), BF16),
+              ((slots, spg), I32), ((slots,), I32), ((slots,), I32), ((slots,), I32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    text = jax.jit(step, donate_argnums=(1, 2)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and "paged_attention" in text
+    whole = f"bf16[{layers},{pages},1,{page},1,{dk}]"
+    made = _arrays_made(_loop_bodies(text), slots * spg * page * dk * 2)
+    assert set(made) <= {("dynamic-update-slice", whole), ("fusion", whole)}, made
+    assert not re.search(r"\[\d+,4096,", _loop_bodies(text))  # no max_seq-dense view
