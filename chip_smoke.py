@@ -460,6 +460,35 @@ def child_kernels(seed: int, rehearse: bool) -> None:
             check(f"paged {layout}{label} page={page}", "paged_attention", fn,
                   ref, args, ATTN_ATOL)
 
+    # ---- ... with a window, over a ring of window pages, and without one
+    # over full-length pages, both at the trinity-large-bf16-ep16 cell's
+    # shapes: 32 slots, 48 query heads on 8 K/V heads of 128 whose rows keep
+    # the heads merged on the lane axis (kv_heads), 512-token pages, lengths
+    # 8192-16000 (every slot past the 4096 window; the ring of 10 pages has
+    # wrapped one to three times)
+    if rehearse:
+        w_slots, w_hq, w_page, w_seq, w_win, w_ring = 4, 12, 8, 64, 16, 4
+    else:
+        w_slots, w_hq, w_page, w_seq, w_win, w_ring = 32, 48, 512, 16384, 4096, 10
+    w_spg = w_seq // w_page
+    lens = jnp.asarray(np.linspace(w_seq // 2, w_seq - w_seq // 42, w_slots), jnp.int32)
+    kq, kk, kv, key = jax.random.split(key, 4)
+    q = jax.random.normal(kq, (w_slots, w_hq, d), bf16)
+    slot = np.arange(w_slots)[:, None]
+    for name, n_pages, table, window in (
+        ("window ring", w_slots * w_ring, slot * w_ring + np.arange(w_spg)[None] % w_ring, w_win),
+        ("full", w_slots * w_spg, slot * w_spg + np.arange(w_spg)[None], None),
+    ):
+        k_pool = jax.random.normal(kk, (n_pages + 1, w_page, 1, hkv * d), bf16)
+        v_pool = jax.random.normal(kv, (n_pages + 1, w_page, 1, hkv * d), bf16)
+        check(f"paged {name} merged heads page={w_page}", "paged_attention",
+              functools.partial(paged_attention, scale=scale, sliding_window=window,
+                                kv_heads=hkv, interpret=rehearse),
+              lambda q_, k_, v_, tb, ln, w=window: _paged_attention_xla(
+                  q_, k_, v_, tb, ln, scale, None, w, None, kv_heads=hkv),
+              (q, k_pool, v_pool, jnp.asarray(table, jnp.int32), lens), ATTN_ATOL)
+        del k_pool, v_pool
+
     # ---- 4-bit matmuls: the batch kernel at a prefill chunk's rows and a
     # 16-slot decode step's, the GEMV at M=1 and 8
     for out_dim, in_dim in quant_shapes + batch_only:

@@ -25,6 +25,16 @@ It is not addressed by ``offset``: lowering an offset rewinds K/V rows and
 NOT the state, so every path that puts a sequence back that way either
 starts over from position 0 (where the programs take the state as zero) or
 refuses such a model at start-up (:func:`refuse_recurrent`).
+
+A model with sliding-window layers (``models/afmoe.py``) keeps those layers'
+K/V in ``state`` too: per window layer and slot a RING of ``ring_rows``
+rows (:func:`window_ring_rows`: the window, one prefill chunk and one page,
+in whole pages) in which position ``p`` lives at row ``p % ring_rows``, so
+a window layer's bytes do not grow with the context; only the full-attention
+layers have pages in ``k``/``v``. A row is overwritten ``ring_rows``
+positions later, when no query can see it any more. The same refusals hold:
+what re-enters or moves a sequence as full-length pages only cannot serve
+such a model.
 """
 
 from __future__ import annotations
@@ -56,15 +66,39 @@ def has_recurrent_state(model) -> bool:
     return bool(getattr(model, "has_recurrent_state", False))
 
 
+def has_window_layers(model) -> bool:
+    return bool(getattr(model, "has_window_layers", False))
+
+
+def has_slot_state(model) -> bool:
+    """Whether engines carry ``KVCache.state`` for this model: recurrent
+    state, or window layers' rings."""
+    return has_recurrent_state(model) or has_window_layers(model)
+
+
+def window_ring_rows(window: int, chunk: int, page: int, max_seq: int) -> int:
+    """Rows of one slot's ring in one window layer: the window and one
+    prefill chunk (a chunk's queries reach ``window - 1`` rows behind its
+    first row while its last row is already written) rounded up to pages,
+    plus one page; never more than the context."""
+    pages = -(-(window + chunk) // page) + 1
+    return min(pages * page, max_seq)
+
+
 def refuse_recurrent(model, flag: str, why: str) -> None:
     """The one start-up error of every feature that moves or rewinds a
-    sequence as pages of K/V only: it names the flag and says "recurrent
-    state". No-op for a model without such state."""
+    sequence as full-length pages of K/V only: it names the flag and says
+    "recurrent state" or "window layers". No-op for a model with neither."""
     if has_recurrent_state(model):
-        raise ValueError(
-            f"{flag} cannot serve {type(model).__name__}: it has recurrent "
-            f"state beside its K/V pages, and {why}"
-        )
+        kind = "recurrent state"
+    elif has_window_layers(model):
+        kind = "window layers whose K/V is a ring"
+    else:
+        return
+    raise ValueError(
+        f"{flag} cannot serve {type(model).__name__}: it has {kind} beside "
+        f"its K/V pages, and {why}"
+    )
 
 
 def is_quantized_kv(buf) -> bool:

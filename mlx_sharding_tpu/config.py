@@ -238,6 +238,73 @@ class NemotronHConfig(BaseConfig):
         return self.n_routed_experts * self.moe_expert_share
 
 
+@dataclass
+class AfmoeConfig(BaseConfig):
+    """Arcee Trinity (``afmoe``): gated GQA attention with QK-norm, sliding-
+    window layers (rotary) and full-attention layers (no rotary) mixed by
+    ``layer_types``, sandwich norms, ``num_dense_layers`` SwiGLU layers and
+    then MoE layers (sigmoid router with a selection bias, top-k normalised
+    and scaled by ``route_scale``, one shared expert).
+
+    A layer may hold one chip's SHARE of the routed experts, as
+    :class:`NemotronHConfig` says: ``num_experts`` counts the experts held,
+    ``moe_expert_share`` the holders, ``moe_expert_share_index`` which one
+    this is. A checkpoint's own config (no share keys) is the whole model."""
+
+    model_type: str = "afmoe"
+    layer_types: Optional[list] = None
+    global_attn_every_n_layers: int = 4
+    sliding_window: int = 4096
+    num_dense_layers: int = 0
+    moe_intermediate_size: int = 3072
+    num_experts: int = 256
+    num_experts_per_tok: int = 4
+    num_shared_experts: int = 1
+    n_group: int = 1
+    topk_group: int = 1
+    route_norm: bool = True
+    route_scale: float = 1.0
+    score_func: str = "sigmoid"
+    mup_enabled: bool = True
+    moe_expert_share: int = 1
+    moe_expert_share_index: int = 0
+    max_position_embeddings: int = 262144
+
+    def __post_init__(self):
+        if self.layer_types is None:
+            n = self.global_attn_every_n_layers
+            self.layer_types = [
+                "full_attention" if (i + 1) % n == 0 else "sliding_attention"
+                for i in range(self.num_hidden_layers)
+            ]
+        self.layer_types = list(self.layer_types)
+        if len(self.layer_types) != self.num_hidden_layers:
+            raise ValueError(
+                f"layer_types has {len(self.layer_types)} entries for "
+                f"{self.num_hidden_layers} layers"
+            )
+        bad = set(self.layer_types) - {"sliding_attention", "full_attention"}
+        if bad:
+            raise ValueError(f"layer_types: {sorted(bad)} are not wired")
+        if self.n_group != 1 or self.topk_group != 1:
+            raise ValueError("afmoe routing is wired for n_group = topk_group = 1")
+        if self.score_func != "sigmoid":
+            raise ValueError("afmoe routing is wired for score_func sigmoid")
+        if self.num_shared_experts != 1:
+            raise ValueError("afmoe is wired for one shared expert")
+        if self.rope_scaling is not None:
+            raise ValueError("afmoe is wired for rope_scaling null")
+        if not 0 <= self.num_dense_layers <= self.num_hidden_layers:
+            raise ValueError("num_dense_layers must lie in [0, num_hidden_layers]")
+        if not 0 <= self.moe_expert_share_index < self.moe_expert_share:
+            raise ValueError("moe_expert_share_index must lie in [0, moe_expert_share)")
+        super().__post_init__()
+
+    @property
+    def router_width(self) -> int:
+        return self.num_experts * self.moe_expert_share
+
+
 # Arch-name resolution. Mirrors the reference's MODEL_REMAPPING
 # (shard/utils.py:14-17): mistral runs through the llama implementation.
 MODEL_REMAPPING = {
@@ -252,6 +319,7 @@ CONFIG_REGISTRY: dict[str, type] = {
     "deepseek_v2": DeepseekV2Config,
     "mixtral": MixtralConfig,
     "nemotron_h": NemotronHConfig,
+    "afmoe": AfmoeConfig,
 }
 
 

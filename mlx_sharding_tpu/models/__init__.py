@@ -20,6 +20,7 @@ MODEL_REGISTRY: dict[str, tuple[str, str]] = {
     "deepseek_v2": ("mlx_sharding_tpu.models.deepseek_v2", "DeepseekV2Model"),
     "mixtral": ("mlx_sharding_tpu.models.mixtral", "MixtralModel"),
     "nemotron_h": ("mlx_sharding_tpu.models.nemotron_h", "NemotronHModel"),
+    "afmoe": ("mlx_sharding_tpu.models.afmoe", "AfmoeModel"),
 }
 
 

@@ -102,6 +102,28 @@ def scan_layers(layer_fn, h, layer_params, k, v, mask=None, in_place=()):
     return h, k, v
 
 
+def take_row(x, i):
+    return jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False)
+
+
+def put_row(x, i, val):
+    return jax.lax.dynamic_update_index_in_dim(x, val.astype(x.dtype), i, 0)
+
+
+class LayerRow:
+    """One layer's leaves out of its group's stacks, each sliced where it is
+    USED: the compiler copies a layer's matrix out of the stack (a fusion of
+    its own), and that copy then carries the scope of the projection that
+    reads it, not nobody's. For models that walk interleaved layer groups
+    by an unrolled loop (``models/nemotron_h.py``, ``models/afmoe.py``)."""
+
+    def __init__(self, stacks: dict, rank):
+        self.stacks, self.rank = stacks, rank
+
+    def __getitem__(self, name):
+        return jax.tree.map(lambda x: take_row(x, self.rank), self.stacks[name])
+
+
 def stack_layers(per_layer: list[dict]) -> dict:
     """[{name: (…)}, …] → {name: (L, …)} for lax.scan consumption."""
     out = {}

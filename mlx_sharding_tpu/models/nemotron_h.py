@@ -45,33 +45,19 @@ import numpy as np
 
 from mlx_sharding_tpu.cache import KVCache, advance, init_cache, write_layer_kv
 from mlx_sharding_tpu.config import NemotronHConfig
-from mlx_sharding_tpu.models.base import BaseModel, dense_init, stack_layers
+from mlx_sharding_tpu.models.base import (
+    BaseModel,
+    LayerRow as _LayerRow,
+    dense_init,
+    put_row as _put,
+    stack_layers,
+    take_row as _take,
+)
 from mlx_sharding_tpu.ops import causal_attention, rms_norm
 from mlx_sharding_tpu.ops.moe import apply_experts, nemotron_routing
 
 GROUP_OF = {"M": "mamba", "*": "attn", "E": "moe"}
 _HI = jax.lax.Precision.HIGHEST
-
-
-def _take(x, i):
-    return jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False)
-
-
-def _put(x, i, val):
-    return jax.lax.dynamic_update_index_in_dim(x, val.astype(x.dtype), i, 0)
-
-
-class _LayerRow:
-    """One layer's leaves out of its group's stacks, each sliced where it is
-    USED: the compiler copies a layer's matrix out of the stack (a fusion of
-    its own), and that copy then carries the scope of the projection that
-    reads it, not nobody's."""
-
-    def __init__(self, stacks: dict, rank):
-        self.stacks, self.rank = stacks, rank
-
-    def __getitem__(self, name):
-        return jax.tree.map(lambda x: _take(x, self.rank), self.stacks[name])
 
 
 def ssd_chunked(x, dt, a_head, b_mat, c_mat, state, chunk: int):

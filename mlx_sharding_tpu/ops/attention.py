@@ -46,8 +46,7 @@ def _flash_eligible(q, k, v, logit_softcap, sliding_window, sinks) -> bool:
     )
 
 
-@jax.named_scope("mst.attn.core")
-def causal_attention(
+def attend(
     q: jax.Array,  # (B, T, Hq, Dk)
     k: jax.Array,  # (B, S, Hkv, Dk) — full cache buffer
     v: jax.Array,  # (B, S, Hkv, Dv)
@@ -70,6 +69,12 @@ def causal_attention(
     return _causal_attention_xla(
         q, k, v, offset, scale, logit_softcap, sliding_window
     )
+
+
+#: :func:`attend` under the scope every model's attention call has had; a
+#: model that names its layer kinds (``mst.attn.window``, ``mst.attn.full``)
+#: calls :func:`attend` under its own
+causal_attention = jax.named_scope("mst.attn.core")(attend)
 
 
 def _causal_attention_xla(

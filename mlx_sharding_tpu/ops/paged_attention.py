@@ -27,8 +27,15 @@ Two paths, selected the same way ops/flash_attention.py picks its path:
   page walk. MLA's latent-as-values (``values_from_k``) is the same kernel
   with one operand fewer: the value block is the first ``values_from_k``
   lanes of the key block already in VMEM, and the latent pool's dummy
-  (…, 1, 1) V is never fetched.
-- a fused-XLA fallback for CPU / odd shapes / softcap / sliding-window,
+  (…, 1, 1) V is never fetched. A static ``sliding_window`` w masks keys at
+  ``k_pos <= length - 1 - w``; a grid step whose page lies wholly behind
+  the window or past the length computes nothing, and its block index is
+  clamped to the slot's first and last visible page, so it repeats a
+  neighbour's and fetches nothing new. The table may map positions to a
+  ring of pages (``cache.window_ring_rows``): logical page ``j`` is then
+  ring page ``j % R`` of the slot, and the clamp keeps the walk off the
+  ring pages that hold positions outside the window.
+- a fused-XLA fallback for CPU / odd shapes / softcap / a traced window,
   mirroring ops/attention.py's masking semantics. It gathers every slot's
   WHOLE table row (slot_pages × page rows, i.e. max_seq) and masks it, so
   its cost follows max_seq and not the caches' lengths: 75 MB a layer at
@@ -36,7 +43,8 @@ Two paths, selected the same way ops/flash_attention.py picks its path:
 
 Both are token-exact vs the gather path; tests/test_paged_attention.py holds
 the parity matrix (uneven lengths, page-boundary offsets, empty slots, GQA/
-MQA head counts and the latent layout, kernel-in-interpret vs XLA).
+MQA head counts, the latent layout and windows over plain and ring tables,
+kernel-in-interpret vs XLA).
 """
 
 from __future__ import annotations
@@ -73,8 +81,9 @@ def kernel_eligible(
     hkv: int = 1,
 ) -> bool:
     """Pallas path: TPU backend (or interpret mode on any backend), GQA and
-    MLA's latent-as-values — softcap and windows stay on the XLA path, like
-    ops/attention.py's _flash_eligible. Head dims need 64-alignment on real
+    MLA's latent-as-values, with or without a window known at trace time —
+    softcap and a traced window (one model's layers alternating inside one
+    scan) stay on the XLA path. Head dims need 64-alignment on real
     hardware (Mosaic pads sub-128 lane tails); with ``values_from_k`` the
     values are the first lanes of each head's key slice, so their width is
     a multiple of 128 inside ``dk`` (``dv``, the dummy V pool's, is not
@@ -84,7 +93,9 @@ def kernel_eligible(
     itself. Opt out entirely with MST_PAGED_KERNEL=0."""
     if os.environ.get("MST_PAGED_KERNEL", "1") == "0":
         return False
-    if logit_softcap is not None or sliding_window is not None:
+    if logit_softcap is not None:
+        return False
+    if sliding_window is not None and not isinstance(sliding_window, int):
         return False
     if interpret:
         return True
@@ -120,6 +131,7 @@ def _kernel(
     dv: int,
     quant: bool,
     latent: bool,
+    window=None,
 ):
     *pages, o_ref, m_scr, l_scr, acc_scr = refs
     pages = iter(pages)
@@ -138,12 +150,20 @@ def _kernel(
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     # pages entirely past this slot's length are scratch-table tails: skip
-    # all compute (their DMA already collapsed to the repeated scratch id)
-    @pl.when(j * page_size < length)
+    # all compute (their DMA already collapsed to the repeated scratch id);
+    # so are pages wholly behind the window (the first visible key is
+    # ``length - window``)
+    live = j * page_size < length
+    if window is not None:
+        live &= (j + 1) * page_size > length - window
+
+    @pl.when(live)
     def _attend():
         k_pos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1
         )
+        if window is not None:
+            visible = (k_pos < length) & (k_pos >= length - window)
         # the page block carries every KV head side by side on the lane
         # axis; head h is the static lane slice [h*D, (h+1)*D)
         for h in range(hkv):
@@ -166,7 +186,9 @@ def _kernel(
                 q, kblk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * scale  # (G, page)
-            s = jnp.where(k_pos < length, s, NEG_INF)
+            s = jnp.where(
+                k_pos < length if window is None else visible, s, NEG_INF
+            )
             m_prev = m_scr[h, :, :1]  # (G, 1)
             l_prev = l_scr[h, :, :1]
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -189,16 +211,28 @@ def _kernel(
 
 def _paged_attention_kernel(
     q, k_pool, v_pool, tables, lengths, scale, interpret,
-    k_scale=None, v_scale=None, values_from_k=None,
+    k_scale=None, v_scale=None, values_from_k=None, window=None,
+    kv_heads=None,
 ):
     m, hq, dk = q.shape
     pages, page_size, hkv = k_pool.shape[:3]
     latent = values_from_k is not None
     dv = values_from_k if latent else v_pool.shape[-1]
+    if kv_heads is not None:  # (pages, page, 1, Hkv * D): already the block's layout
+        hkv, dv = kv_heads, dv // kv_heads
     spg = tables.shape[1]
     g = hq // hkv
     qg = q.reshape(m, hkv, g, dk)
     quant = k_scale is not None
+
+    def page_id(mi, ji, t, ln):
+        if window is None:
+            return t[mi, ji]
+        # a step outside [first, last] visible page names its neighbour
+        # inside: the same block index as the step beside it, so no DMA
+        first = jnp.maximum(ln[mi] - window, 0) // page_size
+        last = jnp.maximum(ln[mi] - 1, 0) // page_size
+        return t[mi, jnp.clip(ji, first, last)]
 
     def page_spec(width):
         # data-dependent page fetch: the block index comes from the
@@ -208,7 +242,8 @@ def _paged_attention_kernel(
         # the last two block dims tile-aligned or whole, which a
         # one-head-of-Hkv block in the pool's native layout is not.
         return pl.BlockSpec(
-            (1, page_size, width), lambda mi, ji, t, ln: (t[mi, ji], 0, 0)
+            (1, page_size, width),
+            lambda mi, ji, t, ln: (page_id(mi, ji, t, ln), 0, 0),
         )
 
     # every operand after q is a pool fetched page by page through the
@@ -243,7 +278,7 @@ def _paged_attention_kernel(
         functools.partial(
             _kernel,
             scale=scale, page_size=page_size, pages_per_slot=spg,
-            hkv=hkv, dk=dk, dv=dv, quant=quant, latent=latent,
+            hkv=hkv, dk=dk, dv=dv, quant=quant, latent=latent, window=window,
         ),
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((m, hkv, g, dv), q.dtype),
@@ -256,8 +291,11 @@ def _paged_attention_kernel(
 def _paged_attention_xla(
     q, k_pool, v_pool, tables, lengths, scale,
     logit_softcap, sliding_window, values_from_k,
-    k_scale=None, v_scale=None,
+    k_scale=None, v_scale=None, kv_heads=None,
 ):
+    if kv_heads is not None:  # heads merged on the lane axis: split them
+        split = lambda x: x.reshape(*x.shape[:2], kv_heads, -1)  # noqa: E731
+        k_pool, v_pool = split(k_pool), split(v_pool)
     m, hq, dk = q.shape
     page_size, hkv = k_pool.shape[1], k_pool.shape[2]
     spg = tables.shape[1]
@@ -312,10 +350,11 @@ def paged_attention(
     scale: float,
     *,
     logit_softcap: Optional[float] = None,
-    sliding_window=None,  # int or traced scalar
+    sliding_window=None,  # int (either path) or traced scalar (XLA)
     values_from_k: Optional[int] = None,  # MLA latent-as-values
     k_scale: Optional[jax.Array] = None,  # (P+1, page, Hkv, 1) int8-pool scales
     v_scale: Optional[jax.Array] = None,
+    kv_heads: Optional[int] = None,  # pools are (P+1, page, 1, Hkv * D)
     interpret: bool = False,
 ) -> jax.Array:
     """Ragged decode attention over one layer's page pool. Returns
@@ -325,21 +364,35 @@ def paged_attention(
     row before calling this). With ``k_scale``/``v_scale`` the pools are
     int8 codes and dequant (code × per-row-per-head scale) fuses into the
     page reads — both paths stream the int8 bytes, never a dense bf16 copy
-    of the pages."""
+    of the pages. ``kv_heads``: the pools keep their heads MERGED on the
+    lane axis, ``(P+1, page, 1, Hkv * D)`` — the layout of the kernel's
+    page block. On a TPU an array is tiled over its two minor dimensions,
+    so the ``(page, Hkv, D)`` pool's view as ``(page, Hkv * D)`` blocks is
+    a relayout of the whole pool, every call, once ``Hkv > 1``; a model
+    that stores its rows merged pays none (bf16 pools only)."""
     dk, dv = q.shape[-1], v_pool.shape[-1]
+    hkv = k_pool.shape[2]
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
+    if kv_heads is not None:
+        if k_scale is not None or values_from_k is not None or hkv != 1:
+            raise ValueError(
+                "kv_heads wants bf16 (P+1, page, 1, Hkv * D) pools, no "
+                "int8 scales and no values_from_k"
+            )
+        hkv, dv = kv_heads, dv // kv_heads
     if kernel_eligible(
         dk, dv, logit_softcap, sliding_window, values_from_k, interpret,
-        hkv=k_pool.shape[2],
+        hkv=hkv,
     ):
         _count_dispatch("kernel")
         return _paged_attention_kernel(
             q, k_pool, v_pool, tables, lengths, scale, interpret,
-            k_scale, v_scale, values_from_k,
+            k_scale, v_scale, values_from_k, sliding_window, kv_heads,
         )
     _count_dispatch("xla")
     return _paged_attention_xla(
         q, k_pool, v_pool, tables, lengths, scale,
         logit_softcap, sliding_window, values_from_k, k_scale, v_scale,
+        kv_heads,
     )

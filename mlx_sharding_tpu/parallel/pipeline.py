@@ -42,8 +42,11 @@ from mlx_sharding_tpu.cache import (
     KVCache,
     dequantize_kv,
     has_recurrent_state,
+    has_slot_state,
+    has_window_layers,
     quantize_kv_rows,
     refuse_recurrent,
+    window_ring_rows,
 )
 from mlx_sharding_tpu.ops.quant import dequantize, is_quantized
 from mlx_sharding_tpu.parallel.mesh import (
@@ -183,6 +186,10 @@ def split_stage_stacks(model, layer_params: dict, stage_bounds) -> tuple[dict, d
         masks_all[key] = mask
         if key in kv_groups:
             total += slots
+    if hasattr(model, "kv_layer_slots"):
+        # not every layer of a K/V group keeps full-length rows (window
+        # layers keep a ring in the state pool)
+        total = model.kv_layer_slots(stage_bounds)
     return stacked_all, masks_all, total
 
 
@@ -505,7 +512,16 @@ class PipelineEngine:
         # so legacy exported blocks compose). Validation against the
         # engine's LOCAL layer count happens below, once the resident
         # weights resolve the stage split.
-        self.has_state = has_recurrent_state(model)
+        self.has_state = has_slot_state(model)
+        self.has_recurrent = has_recurrent_state(model)
+        # window layers keep their K/V as one ring per slot in the state
+        # pool (cache.py): rows that do not grow with the context
+        self.ring_rows = (
+            window_ring_rows(
+                cfg.sliding_window, prefill_chunk, self.page_size, self.max_seq
+            )
+            if has_window_layers(model) else 0
+        )
         if kv_share_map is not None:
             refuse_recurrent(
                 model, "--kv-share-map",
@@ -641,8 +657,12 @@ class PipelineEngine:
             # stage's state pool has (the slots of the groups that keep state)
             self._rl_kwargs["plan"] = model.stage_plan(self.stage_bounds)
             self._rl_kwargs["stage_axis"] = AXIS_PP
-            self.state_layers = sum(
-                self.layer_masks[g].shape[1] for g in model.state_groups()
+            self.state_layers = (
+                model.state_layer_slots(self.stage_bounds)
+                if hasattr(model, "state_layer_slots")
+                else sum(
+                    self.layer_masks[g].shape[1] for g in model.state_groups()
+                )
             )
         self.vocab_size = weights.vocab_size
         self._head_tied = weights.head_tied
@@ -800,18 +820,23 @@ class PipelineEngine:
             name: jnp.zeros(
                 (S, self.state_layers, rows, *shape[1:]), dt or self.cache_dtype
             )
-            for name, (shape, dt) in self.model.state_shapes(self.batch).items()
+            for name, (shape, dt) in self._state_shapes().items()
         }
         return put_global(pool, NamedSharding(self.mesh, P(AXIS_PP)))
 
+    def _state_shapes(self) -> dict:
+        sized = {"ring_rows": self.ring_rows} if self.ring_rows else {}
+        return self.model.state_shapes(self.batch, **sized)
+
     def state_bytes(self) -> int:
-        """Bytes of the recurrent state pool (0 without one)."""
+        """Bytes of the state pool — recurrent state, or window layers'
+        rings (0 without one)."""
         if not self.has_state:
             return 0
         return sum(
             self.num_stages * self.state_layers * (self.microbatches + 1)
             * int(np.prod(shape)) * jnp.dtype(dt or self.cache_dtype).itemsize
-            for shape, dt in self.model.state_shapes(self.batch).values()
+            for shape, dt in self._state_shapes().values()
         )
 
     def init_cache_paged(self) -> tuple[KVCache, jax.Array]:
@@ -1019,14 +1044,15 @@ class PipelineEngine:
         …)``. Position 0 has no history: whatever the row holds then (the
         slot's last occupant, a block that ran past its end) reads as zero —
         which is what resets a reused slot, chained on the device after
-        anything in flight, with no dispatch of its own."""
+        anything in flight, with no dispatch of its own. (A ring of window
+        K/V needs no reset: its rows are addressed by position.)"""
+        rows = lambda x: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+            x, m_write * self.batch, self.batch, axis=1
+        )
+        if not self.has_recurrent:
+            return jax.tree.map(rows, state)
         return jax.tree.map(
-            lambda x: jnp.where(
-                offset == 0, jnp.zeros((), x.dtype),
-                jax.lax.dynamic_slice_in_dim(
-                    x, m_write * self.batch, self.batch, axis=1
-                ),
-            ),
+            lambda x: jnp.where(offset == 0, jnp.zeros((), x.dtype), rows(x)),
             state,
         )
 
@@ -1358,29 +1384,57 @@ class PipelineEngine:
             # valid prefix incl. the row written this tick; 0 zeroes the
             # garbage lanes' attention outright
             lengths = jnp.where(active, offset_m + 1, 0).astype(jnp.int32)
+            if self.ring_rows:
+                # a window layer's ring pool viewed as pages: slot m's ring
+                # is pages m * R .. (m + 1) * R, logical page j its page
+                # j % R (row M is the scratch ring of the garbage lanes)
+                ring_pages = self.ring_rows // page
+                ring_rows_t = m_write[:, None] * ring_pages + (
+                    jnp.arange(self.slot_pages, dtype=jnp.int32) % ring_pages
+                )[None, :]
+                ring_ids = m_write * ring_pages + (offset_m // page) % ring_pages
 
             # B == 1: treat the slot axis as the batch axis, (M, 1) tokens
             # embed straight to (M, T=1, hidden)
             h = self._vs_embed(s, vparts, tokens).astype(cdt)
 
-            def pool_attn(k_buf, v_buf):
+            def pool_attn(k_buf, v_buf, ring=None, scope="mst.attn.core"):
                 """``(attn_fn, done)`` over one layer's pool: ``attn_fn``
                 scatters the M new rows and attends over the pool in place;
                 the updated pool escapes through ``done`` (sp_decode.py's
-                closure idiom)."""
+                closure idiom). ``ring``: the buffers are the WHOLE ring
+                pool of the window layers ``(layers, M+1, ring rows, H, D)``
+                (never int8) and this is layer ``ring`` of it: taking the
+                layer's rings out and putting them back would copy them
+                (0.35 GB each way a layer at 32 slots of 5120 rows), so the
+                pool is viewed as pages where it lies and the layer is an
+                offset into the ring table. ``scope`` names the attention
+                call."""
                 done = {}
+                quant = kv_quant and ring is None
+                if ring is None:
+                    ids, tbl = page_ids, rows
+                else:
+                    first = ring * (M + 1) * ring_pages
+                    ids, tbl = ring_ids + first, ring_rows_t + first
 
                 def attn_fn(q, k_new, v_new, logit_softcap=None,
-                            sliding_window=None, values_from_k=None):
-                    # drop the B == 1 axis per leaf → (P+1, page, H, D)
-                    kl = jax.tree.map(lambda x: x[:, 0], k_buf)
-                    vl = jax.tree.map(lambda x: x[:, 0], v_buf)
+                            sliding_window=None, values_from_k=None,
+                            **layout):
+                    if ring is not None:  # → (layers * (M+1) * R, page, H, D)
+                        as_pages = lambda x: x.reshape(-1, page, *x.shape[3:])  # noqa: E731
+                        as_given = lambda x: x.reshape(k_buf.shape)  # noqa: E731
+                    else:  # drop the B == 1 axis per leaf → (P+1, page, H, D)
+                        as_pages = lambda x: x[:, 0]  # noqa: E731
+                        as_given = lambda x: x[:, None]  # noqa: E731
+                    kl = jax.tree.map(as_pages, k_buf)
+                    vl = jax.tree.map(as_pages, v_buf)
 
                     def put(pool, new):
-                        if kv_quant:  # quantize the M rows, scatter both
+                        if quant:  # quantize the M rows, scatter both
                             new = quantize_kv_rows(new)
                         return jax.tree.map(
-                            lambda p, n: p.at[page_ids, row_pos].set(
+                            lambda p, n: p.at[ids, row_pos].set(
                                 n.astype(p.dtype)
                             ),
                             pool, new,
@@ -1389,19 +1443,20 @@ class PipelineEngine:
                     with jax.named_scope("mst.attn.kv_write"):
                         kl = put(kl, k_new[:, 0])
                         vl = put(vl, v_new[:, 0])
-                        done["k"] = jax.tree.map(lambda x: x[:, None], kl)
-                        done["v"] = jax.tree.map(lambda x: x[:, None], vl)
-                    with jax.named_scope("mst.attn.core"):
+                        done["k"] = jax.tree.map(as_given, kl)
+                        done["v"] = jax.tree.map(as_given, vl)
+                    with jax.named_scope(scope):
                         out = paged_attention(
                             q[:, 0],
-                            kl["d"] if kv_quant else kl,
-                            vl["d"] if kv_quant else vl,
-                            rows, lengths, model.scale,
+                            kl["d"] if quant else kl,
+                            vl["d"] if quant else vl,
+                            tbl, lengths, model.scale,
                             logit_softcap=logit_softcap,
                             sliding_window=sliding_window,
                             values_from_k=values_from_k,
-                            k_scale=kl["s"] if kv_quant else None,
-                            v_scale=vl["s"] if kv_quant else None,
+                            k_scale=kl["s"] if quant else None,
+                            v_scale=vl["s"] if quant else None,
+                            **layout,  # kv_heads: rows with merged heads
                         )
                         return out[:, None]  # (M, T=1, Hq, Dv)
 

@@ -73,6 +73,7 @@ from mlx_sharding_tpu.cache import (
     export_pool_pages,
     import_pool_pages,
     has_recurrent_state,
+    has_slot_state,
     refuse_recurrent,
     rewind_slot_offset,
 )
@@ -316,6 +317,12 @@ class ContinuousBatcher:
         # re-prefill from 0) and a blockless migration all carry it. What
         # re-enters a sequence at a LATER position from pages alone cannot.
         self._recurrent = has_recurrent_state(engine.model)
+        # ... and so does one whose window layers keep their K/V in per-slot
+        # rings (cache.py): a ring is addressed by position, so it needs no
+        # reset, and nothing that moves full-length pages carries it either
+        self._slot_state = has_slot_state(engine.model)
+        self._ring_pages = getattr(engine, "ring_rows", 0) // engine.page_size
+        self.ring_wraps = 0  # ring pages overwritten (one per slot and page)
         for flag, on, why in (
             ("--prompt-cache", prefix_cache,
              "a prefix hit starts a slot past pages whose state nobody kept"),
@@ -1422,6 +1429,33 @@ class ContinuousBatcher:
             "resets": self.state_resets,
         }
 
+    def window_stats(self) -> Optional[dict]:
+        """The window layers' rings for /metrics (None for a model without
+        window layers): the rings' bytes, which no request changes; the rows
+        inside the windows of the slots in use (per slot ``min(positions,
+        window)``, times the window layers); ring pages overwritten so far."""
+        if not self._ring_pages:
+            return None
+        eng = self.engine
+        window = eng.model.config.sliding_window
+        rows = sum(
+            min(r.prefill_pos + max(0, r.produced - 1), window)
+            for r in self._slots if r is not None
+        )
+        return {
+            "bytes": eng.state_bytes(),
+            "rows_live": rows * eng.state_layers,
+            "ring_wraps": self.ring_wraps,
+        }
+
+    def _note_ring_page(self, position: int) -> None:
+        """``position`` is about to be written: where it opens a page past
+        the ring's first lap, that page overwrites one."""
+        if self._ring_pages and position % self.engine.page_size == 0 and (
+            position // self.engine.page_size >= self._ring_pages
+        ):
+            self.ring_wraps += 1
+
     def latency_stats(self) -> dict:
         """Bucketed latency snapshots for /metrics: inter-token latency
         (observed at the emit path) and admission queue wait (submit →
@@ -2103,8 +2137,9 @@ class ContinuousBatcher:
         and returns False: the caller falls back to normal re-prefill
         admission, which can never double-emit because nothing was queued
         to the consumer here."""
-        if not self.paged or self.draft is not None or self._recurrent:
-            # (a block holds pages of K/V only: no recurrent state)
+        if not self.paged or self.draft is not None or self._slot_state:
+            # (a block holds full-length pages of K/V only: no recurrent
+            # state, no window layer's ring)
             self._fold_history(req)
             return False
         page = self.engine.page_size
@@ -2231,6 +2266,7 @@ class ContinuousBatcher:
             chunk, n_valid = self._chunk_at(req.prompt, req.prefill_pos, c)
             if self._recurrent and req.prefill_pos == 0:
                 self.state_resets += 1
+            self._note_ring_page(req.prefill_pos)
             logits, self.cache = eng.prefill_slot()(
                 eng.layer_params, eng.layer_masks, eng.vocab_parts,
                 eng.shared_params, self._put(jnp.asarray(chunk[None])),
@@ -2343,6 +2379,8 @@ class ContinuousBatcher:
             self._h_itl.observe(now - req._t_last_emit)
         req._t_last_emit = now
         req.produced += 1
+        if req.produced < req.max_tokens:  # the token goes back in as a row
+            self._note_ring_page(req.prefill_pos + req.produced - 1)
         # history is the tokens emitted since the last prompt fold — the
         # overcommit preempt/resume bookkeeping, and (always, since drain
         # can migrate any request) the payload a ResumeState ships so the
@@ -2838,7 +2876,7 @@ class ContinuousBatcher:
             req.spilled = False
             block = self.spill.take(req) if self.spill is not None else None
         if (block is None and slot >= 0 and self.paged
-                and self.draft is None and not self._recurrent
+                and self.draft is None and not self._slot_state
                 and self._prefill_done(req) and req.history):
             page = self.engine.page_size
             n_tokens = req.prompt.size + max(0, len(req.history) - 1)

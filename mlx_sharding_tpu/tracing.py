@@ -97,6 +97,14 @@ MODEL_SCOPES = (
     "mst.attn.qkv",
     "mst.attn.kv_write",
     "mst.attn.core",
+    # a model that names its attention layer kinds (models/afmoe.py): the
+    # attention call of a window / a full layer in place of mst.attn.core's,
+    # the output gate, the QK-norm, and the window layers' ring pool
+    "mst.attn.window",
+    "mst.attn.full",
+    "mst.attn.gate",
+    "mst.attn.qk_norm",
+    "mst.kv_ring.regroup",
     "mst.moe.router",
     "mst.moe.experts",
     "mst.moe.experts.gather_dequant",

@@ -424,6 +424,16 @@ class ServingMetrics:
                             "# TYPE mst_state_resets_total counter",
                             f"mst_state_resets_total {state['resets']}",
                         ]
+                    win = getattr(b, "window_stats", lambda: None)()
+                    if win is not None:
+                        lines += [
+                            "# TYPE mst_kv_window_bytes gauge",
+                            f"mst_kv_window_bytes {win['bytes']}",
+                            "# TYPE mst_kv_window_rows_live gauge",
+                            f"mst_kv_window_rows_live {win['rows_live']}",
+                            "# TYPE mst_kv_ring_wraps_total counter",
+                            f"mst_kv_ring_wraps_total {win['ring_wraps']}",
+                        ]
                     if pages is not None and getattr(b, "overcommit", False):
                         lines += [
                             "# TYPE mst_preemptions_total counter",
@@ -1102,6 +1112,16 @@ _HELP = {
     "mst_state_resets_total":
         "First prefill chunks dispatched: each starts its slot's recurrent "
         "state from zero, inside the chunk's program.",
+    "mst_kv_window_bytes":
+        "Bytes of the window layers' per-slot K/V rings: window + one "
+        "prefill chunk + one page of rows a slot and layer, whatever the "
+        "contexts hold.",
+    "mst_kv_window_rows_live":
+        "Rows inside the windows of the slots in use: per slot min(positions, "
+        "sliding_window), times the window layers.",
+    "mst_kv_ring_wraps_total":
+        "Ring pages overwritten: pages a slot opened past its ring's first "
+        "lap (one count stands for every window layer's page).",
     "mst_quant_dispatch_total":
         "Packed 4-bit matmuls by the path ops/quant chose, one count per "
         "traced call: gemv and matmul are the Pallas kernels; xla "

@@ -62,8 +62,9 @@ def _make_case(rng, lengths, hq, hkv, dk, dv):
     return q, k_pool, v_pool, tables, dense_k, dense_v
 
 
-def _ref(q, dense_k, dense_v, lengths, scale):
-    """Per-slot numpy softmax attention over the first length rows."""
+def _ref(q, dense_k, dense_v, lengths, scale, window=None):
+    """Per-slot numpy softmax attention over the first length rows (the
+    last ``window`` of them, with a window)."""
     m, hq, dk = q.shape
     hkv, dv = dense_k.shape[2], dense_v.shape[3]
     g = hq // hkv
@@ -71,9 +72,10 @@ def _ref(q, dense_k, dense_v, lengths, scale):
     for i, ln in enumerate(lengths):
         if ln == 0:
             continue  # inactive slot: contract is zeros
+        lo = 0 if window is None else max(0, ln - window)
         for h in range(hq):
-            k = dense_k[i, :ln, h // g]  # (ln, dk)
-            v = dense_v[i, :ln, h // g]
+            k = dense_k[i, lo:ln, h // g]  # (ln - lo, dk)
+            v = dense_v[i, lo:ln, h // g]
             s = (k @ q[i, h]) * scale
             p = np.exp(s - s.max())
             out[i, h] = (p / p.sum()) @ v
@@ -89,19 +91,80 @@ LENGTHS = [5, PAGE, 2 * PAGE, 0, 27, SPG * PAGE]
     "hq,hkv", [(4, 4), (4, 2), (4, 1)], ids=["mha", "gqa", "mqa"]
 )
 @pytest.mark.parametrize("interpret", [False, True], ids=["xla", "kernel"])
-def test_op_parity_matrix(hq, hkv, interpret):
+# no window; shorter than most lengths with its edge inside a page (5, 11),
+# a whole page, equal to the longest length, longer than every length
+@pytest.mark.parametrize(
+    "window", [None, 5, PAGE, 11, SPG * PAGE, 100],
+    ids=["full", "w5", "w-page", "w11", "w-longest", "w-longer"],
+)
+def test_op_parity_matrix(hq, hkv, interpret, window):
     rng = np.random.default_rng(0)
     dk = dv = 16
     scale = dk ** -0.5
     q, k_pool, v_pool, tables, dense_k, dense_v = _make_case(
         rng, LENGTHS, hq, hkv, dk, dv
     )
-    want = _ref(q, dense_k, dense_v, LENGTHS, scale)
+    want = _ref(q, dense_k, dense_v, LENGTHS, scale, window)
     got = paged_attention(
         jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
         jnp.asarray(tables), jnp.asarray(LENGTHS, jnp.int32), scale,
-        interpret=interpret,
+        sliding_window=window, interpret=interpret,
     )
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
+    assert not np.asarray(got)[LENGTHS.index(0)].any()  # the empty slot
+
+
+# (ring pages R, window, lengths): position p of slot m lives at ring page
+# m * R + (p // PAGE) % R, as the engine lays a window layer out; the table
+# is the ring repeated over the slot's logical pages. Lengths that wrap the
+# ring more than twice, a window edge inside a page, an empty slot.
+RINGS = {
+    "wrapped-twice": (3, 12, [70, 0, 53, 24, 9]),
+    "window-is-a-page": (3, PAGE, [64, 0, 17, 8, 3]),
+    "never-wrapped": (4, 20, [32, 0, 21, 8, 1]),
+}
+
+
+@pytest.mark.parametrize("case", list(RINGS))
+@pytest.mark.parametrize("merged", [False, True], ids=["heads-apart", "heads-merged"])
+@pytest.mark.parametrize("hq,hkv", [(6, 1), (12, 2)], ids=["gqa6", "gqa6x2"])
+def test_window_kernel_over_a_ring_matches_xla(case, hq, hkv, merged):
+    """The kernel (interpret mode) with a window over a RING table equals
+    the fallback over the same table, and both equal plain attention over
+    the last ``window`` positions of a dense history: a ring page that has
+    been overwritten holds positions no query can see. ``merged``: the
+    pools keep a row's heads on the lane axis, ``(pages, page, 1, Hkv *
+    D)``, and ``kv_heads`` says how many."""
+    ring, window, lengths = RINGS[case]
+    rng = np.random.default_rng(5)
+    m, d, spg = len(lengths), 16, 9
+    assert ring * PAGE >= window + PAGE  # what the engine guarantees
+    hist_k = rng.standard_normal((m, spg * PAGE, hkv, d), np.float32)
+    hist_v = rng.standard_normal((m, spg * PAGE, hkv, d), np.float32)
+    k_pool = np.zeros((m * ring + 1, PAGE, hkv, d), np.float32)
+    v_pool = np.zeros_like(k_pool)
+    for i, ln in enumerate(lengths):
+        for p in range(ln):  # later positions overwrite earlier ones
+            page = i * ring + (p // PAGE) % ring
+            k_pool[page, p % PAGE] = hist_k[i, p]
+            v_pool[page, p % PAGE] = hist_v[i, p]
+    tables = (np.arange(m)[:, None] * ring + np.arange(spg)[None, :] % ring)
+    q = rng.standard_normal((m, hq, d), np.float32)
+    scale = d ** -0.5
+    layout = {}
+    if merged:
+        k_pool, v_pool = (x.reshape(-1, PAGE, 1, hkv * d) for x in (k_pool, v_pool))
+        layout = {"kv_heads": hkv}
+    args = (
+        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+        jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32), scale,
+    )
+    before = paged_ops.dispatch_counts()
+    got = paged_attention(*args, sliding_window=window, interpret=True, **layout)
+    assert paged_ops.dispatch_counts()["kernel"] == before["kernel"] + 1
+    xla = _paged_attention_xla(*args, None, window, None, **layout)
+    want = _ref(q, hist_k, hist_v, lengths, scale, window)
+    np.testing.assert_allclose(np.asarray(xla), want, atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
 
 
@@ -121,11 +184,13 @@ def test_op_parity_uneven_head_dims_xla():
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
 
 
-def test_op_sliding_window_and_softcap_stay_xla():
-    """Softcap / window force the fallback (kernel_eligible says no) and the
-    window semantics match a masked reference."""
+def test_op_softcap_and_traced_window_stay_xla():
+    """Softcap and a window that is a traced scalar force the fallback
+    (kernel_eligible says no; a window known at trace time is the
+    kernel's) and the window semantics match a masked reference."""
     assert not kernel_eligible(64, 64, 30.0, None, None, interpret=True)
-    assert not kernel_eligible(64, 64, None, 4, None, interpret=True)
+    assert kernel_eligible(64, 64, None, 4, None, interpret=True)
+    assert not kernel_eligible(64, 64, None, jnp.asarray(4), None, interpret=True)
     rng = np.random.default_rng(2)
     lengths = [13, 7]
     window = 4
@@ -231,15 +296,17 @@ ELIGIBLE = {
     "latent-values-wider-than-key": ((576, 1, None, None, 640, 1), False),
     "latent-dk-unaligned": ((552, 1, None, None, 512, 1), False),
     "latent-softcap": ((576, 1, 30.0, None, 512, 1), False),
-    "latent-window": ((576, 1, None, 128, 512, 1), False),
+    "latent-window": ((576, 1, None, 128, 512, 1), True),
+    "gqa-window": ((128, 128, None, 4096, None, 8), True),
+    "gqa-window-softcap": ((128, 128, 50.0, 4096, None, 8), False),
 }
 
 
 @pytest.mark.parametrize("case", list(ELIGIBLE))
 def test_kernel_eligible_table(case, monkeypatch):
     """The predicate on a chip: the latent layout is admitted where its
-    lane slices are tile-aligned; softcap and windows stay on the XLA path
-    whatever the layout. An int8 pool changes nothing in it (the case above
+    lane slices are tile-aligned; a window known at trace time is the
+    kernel's in either layout, softcap stays on the XLA path. An int8 pool changes nothing in it (the case above
     runs int8 + latent through the kernel)."""
     (dk, dv, softcap, window, vfk, hkv), want = ELIGIBLE[case]
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")

@@ -268,6 +268,54 @@ def test_served_nemotron_programs_carry_the_scope_vocabulary(nemotron_batcher):
     assert _scopes_in(prefill) == layers | {"mst.ssm.scan"}
 
 
+@hard_timeout(420)
+def test_served_afmoe_programs_carry_the_scope_vocabulary():
+    """The third family: window and full attention layers name their
+    attention calls, the gate, the QK-norm and the ring pool."""
+    from mlx_sharding_tpu.models import build_model
+
+    s_, f_ = "sliding_attention", "full_attention"
+    model, _ = build_model(dict(
+        model_type="afmoe", vocab_size=128, hidden_size=32, intermediate_size=48,
+        num_hidden_layers=3, num_dense_layers=1, layer_types=[s_, f_, s_],
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        sliding_window=8, num_experts=2, num_experts_per_tok=2,
+        moe_intermediate_size=16, moe_expert_share=2,
+    ))
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    eng = PipelineEngine(
+        model, params, pipeline_mesh(1), microbatches=2, max_seq=64,
+        cache_dtype=jnp.float32, prefill_chunk=8, pool_pages=10, page_size=8,
+    )
+    b = ContinuousBatcher(eng, decode_block=3)
+    try:
+        assert len(list(b.generate_step([3, 4, 5, 6], max_tokens=5))) == 5
+        block = b._decode_block_prog(False).lower(
+            eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+            b.last_tok, b.cache, b.active, b.recent, b.keys, b.sp, b.rep_sizes,
+            b.table,
+        ).as_text(debug_info=True)
+        prefill = eng.prefill_slot().lower(
+            eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+            jnp.zeros((1, 8), jnp.int32), jnp.asarray(0, jnp.int32), b.cache,
+            jnp.asarray(8, jnp.int32), b.table,
+        ).as_text(debug_info=True)
+    finally:
+        b.close()
+    assert "module @jit_block " in block
+    assert "module @jit_prefill_chunk " in prefill
+    layers = {"mst.embed", "mst.attn.qkv", "mst.attn.kv_write", "mst.attn.core",
+              "mst.attn.window", "mst.attn.full", "mst.attn.gate", "mst.attn.qk_norm",
+              "mst.moe.router", "mst.moe.experts", "mst.moe.experts.scan",
+              "mst.moe.shared", "mst.mlp.dense", "mst.norm", "mst.head",
+              "mst.kv_pool.regroup"}
+    # decode reads the ring pool where it lies (no regroup); the sampler is in the block
+    assert _scopes_in(block) == layers | {"mst.sample"}
+    # prefill takes the slot's rings out of the pool and puts them back
+    assert _scopes_in(prefill) == layers | {"mst.kv_ring.regroup", "mst.state_pool.regroup"}
+    assert _scopes_in(block) | _scopes_in(prefill) <= set(tracing.MODEL_SCOPES)
+
+
 # ------------------------------------------- what rides the layer scan
 
 

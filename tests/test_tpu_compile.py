@@ -70,19 +70,24 @@ def _flash(t, s, hq, hkv, dk, dv, via_dispatch=True, kernel="flash_attention"):
 
 
 def _paged(page, int8, slots=8, hq=24, hkv=8, d=128, max_seq=4096,
-           pages=None, rank=None):
+           pages=None, rank=None, window=None, merged=False):
     """``rank``: MLA's latent layout — the values are the first ``rank``
     lanes of the ``d``-wide key rows (``values_from_k``) and V is the dummy
-    ``(…, 1, 1)`` pool the kernel is never handed."""
+    ``(…, 1, 1)`` pool the kernel is never handed. ``window``: a sliding
+    window known at trace time (the block index clamps to the visible
+    pages). ``merged``: the pools keep a row's heads on the lane axis,
+    ``(pages, page, 1, Hkv * D)`` (``kv_heads``)."""
     pages = pages or slots * max_seq // page + 1
     dtype = jnp.int8 if int8 else BF16
-    k_pool = ((pages, page, hkv, d), dtype)
+    k_pool = ((pages, page, 1, hkv * d) if merged else (pages, page, hkv, d), dtype)
     v_pool = k_pool if rank is None else ((pages, page, 1, 1), dtype)
     scales = [((pages, page, p[0][2], 1), F32) for p in (k_pool, v_pool)]
 
     def fn(q, k, v, tables, lengths, ks=None, vs=None):
         return paged_attention(q, k, v, tables, lengths, d ** -0.5,
-                               values_from_k=rank, k_scale=ks, v_scale=vs)
+                               values_from_k=rank, k_scale=ks, v_scale=vs,
+                               sliding_window=window,
+                               kv_heads=hkv if merged else None)
 
     return (
         fn,
@@ -148,6 +153,17 @@ CASES = {
     **{f"paged-mla-latent-{'int8-' if q else ''}page256": _paged(
         256, q, slots=16, hq=16, hkv=1, d=576, pages=145, rank=512)
        for q in (False, True)},
+    # ... and with a window, at the trinity-large-bf16-ep16 cell's shapes:
+    # 32 slots, 48 query heads on 8 K/V heads of 128, 512-token pages (a
+    # 1 MB block a pool), a table 32 pages wide; the window layers' pool is
+    # 33 rings of 10 pages
+    # (with the scratch ring, four layers in one pool: 1320), the full
+    # layer's 1024 pages and the scratch one; a row's heads merged on lanes
+    "paged-window-ring-page512": _paged(
+        512, False, slots=32, hq=48, max_seq=16384, pages=1320, window=4096,
+        merged=True),
+    "paged-full-page512": _paged(
+        512, False, slots=32, hq=48, max_seq=16384, pages=1025, merged=True),
     # 4-bit projections of the 3B model: batch kernel at M=256, GEMV at 1, 8
     **{f"quant-M{m}-{i}x{o}": _quant(
         m, o, i, "quant_matmul" if m == 256 else "quant_gemv_pipelined")
