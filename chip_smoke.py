@@ -78,6 +78,11 @@ ATTN_ATOL = 3e-2
 # matmul; the kernels keep scale·nibble+bias in f32. Over IN ≥ 2048 random
 # terms that is a few 1e-3 of the output scale.
 QUANT_RTOL = 2e-2
+# bf16 routed experts, the loop over the picked experts against the gather
+# path: each rounds every product and the running sum to bf16 (2^-9
+# relative) in its own order over up to 22 terms a row; a wrong expert or
+# layer read is O(1) of the output scale.
+EXPERTS_RTOL = 4e-2
 # Served logprobs, one serving path against another (paged pool + ragged
 # kernel + batched slots vs dense cache + single request; pp=4 vs pp=1):
 # every layer rounds its activations to bf16, 28 layers compound that, and
@@ -359,7 +364,8 @@ def child_kernels(seed: int, rehearse: bool) -> None:
         quant_matmul_pallas,
     )
 
-    def check(name, kernel_name, fn, ref_fn, args, tol, relative=False):
+    def check(name, kernel_name, fn, ref_fn, args, tol, relative=False,
+              temp_below=None):
         t0 = time.perf_counter()
         if rehearse:
             got = fn(*args)
@@ -367,13 +373,21 @@ def child_kernels(seed: int, rehearse: bool) -> None:
         else:
             compiled = jax.jit(fn).lower(*args).compile()
             text = compiled.as_text()
-            if "tpu_custom_call" not in text or kernel_name not in text:
+            how = "tpu_custom_call" if kernel_name else "xla"
+            if kernel_name and (how not in text or kernel_name not in text):
                 raise SystemExit(
                     f"{name}: the dispatcher did not select the Pallas "
                     f"kernel {kernel_name!r} for this shape on the chip"
                 )
+            temp = compiled.memory_analysis().temp_size_in_bytes
+            if temp_below is not None:
+                how += f", {temp} B of temporaries"
+                if temp >= temp_below:
+                    raise SystemExit(
+                        f"{name}: {temp} B of temporaries, {temp_below} B "
+                        "or more: the program copies what it should read in place"
+                    )
             got = compiled(*args)
-            how = "tpu_custom_call"
         want = jax.jit(ref_fn)(*args)
         got, want = got.astype(jnp.float32), want.astype(jnp.float32)
         if not bool(jnp.isfinite(got).all()) or got.shape != want.shape:
@@ -556,6 +570,55 @@ def child_kernels(seed: int, rehearse: bool) -> None:
     check(f"quant_matmul_experts N={n} top-{k} of {e} {hidden}x{width}",
           "quant_matmul_experts", fn, experts_ref,
           (x, weights, idx, *stacks), QUANT_RTOL, relative=True)
+    # ---- routed experts under a resident range, 32 rows at the two bf16
+    # cells' expert widths: bf16 (L, E, ...) stacks read in place by an
+    # expert id LOADED from the step's list of distinct picks (plain XLA: the
+    # matmuls read their operand out of the stack where it lies), against
+    # the gather path over the same layer's experts, four rows at a time
+    if rehearse:
+        cases = [("gated", True, 8, 2, 4, 16, 128, 64),
+                 ("un-gated", False, 8, 3, 8, 32, 64, 128)]
+    else:  # Trinity-Large: top-4 of 256, 16 held; Nemotron-3: top-22 of 512, 128 held
+        cases = [("gated", True, 32, 4, 16, 256, 3072, 3072),
+                 ("un-gated", False, 32, 22, 128, 512, 1024, 2688)]
+    for tag, gated, n, k, held, routed, hidden, width in cases:
+        kg, ku, kd, kx, kr, key = jax.random.split(key, 6)
+        w_up = jax.random.normal(ku, (2, held, hidden, width), bf16) * 0.02
+        w_gate = jax.random.normal(kg, w_up.shape, bf16) * 0.02 if gated else None
+        w_down = jax.random.normal(kd, (2, held, width, hidden), bf16) * 0.02
+        x = jax.random.normal(kx, (n, hidden), bf16)
+        topv, idx = jax.lax.top_k(jax.random.uniform(kr, (n, routed)), k)
+        weights = topv / topv.sum(-1, keepdims=True)
+        base = held  # the layer's second holder: picks fall below, inside, above
+        inside = (idx >= base) & (idx < base + held)
+        need(bool(inside.any() & (idx < base).any() & (idx >= base + held).any()),
+             f"held experts {tag}: the picks do not straddle the held range")
+
+        def held_ref(x, weights, idx, w_gate, w_up, w_down):
+            local = jnp.clip(idx - base, 0, held - 1)
+            wg, wu, wd = jax.tree.map(lambda w: w[1], (w_gate, w_up, w_down))
+            return jnp.concatenate([
+                moe._apply_gather(x[r:r + 4], (weights * inside)[r:r + 4],
+                                  local[r:r + 4], wg, wu, wd)
+                for r in range(0, n, 4)
+            ])
+
+        def rows(x, weights, idx, *stacks):
+            return moe.apply_experts(x, weights, idx, *stacks, expert_base=base, layer=1)
+
+        def lanes(x, weights, idx, *stacks):
+            # the engine's vectorized decode step (--ep, --paged-attention
+            # gather): vmap over the slots, one row a lane
+            return jax.vmap(lambda *row: rows(*row, *stacks))(
+                x[:, None], weights[:, None], idx[:, None])[:, 0]
+
+        # no copy of an expert: less in temporaries than ONE matrix of one
+        for how, fn in (("", rows), (", one row a lane", lanes)):
+            check(f"held experts {tag}{how} N={n} top-{k} of {routed}, {held} held "
+                  f"{hidden}x{width}", None, fn, held_ref,
+                  (x, weights, idx, w_gate, w_up, w_down), EXPERTS_RTOL,
+                  relative=True, temp_below=hidden * width * 2)
+        del w_gate, w_up, w_down
     print(json.dumps({"ok": True, "device": device}), flush=True)
 
 

@@ -19,9 +19,12 @@ observe — token count, packed or dense stacks, backend, shapes:
   dequantize the gathered slices (N x K whole experts, dense, in HBM).
 - **decode, dense stacks**: gather the top-k experts' weights per token and
   batch the tiny matmuls.
-- **prefill (many tokens) and expert-parallel**: ``lax.scan`` over experts
-  with masked accumulation — every matmul is a full-width MXU op with
-  static shapes, no sorting, no capacity overflow.
+- **prefill (many tokens), a resident range and expert-parallel**: a loop
+  over the DISTINCT held experts the rows picked, ascending, with masked
+  accumulation — every matmul is a full-width MXU op with static shapes,
+  read out of the stacks where they lie; no sorting, no capacity overflow.
+  A held expert no row picked is not read: in a chunk every expert is hit,
+  in a 32-row decode step under a resident range a part of them.
 
 Routing is parameterized so Mixtral (softmax→topk→renorm), DeepSeek-V2
 (softmax scoring→greedy topk, optional renorm + scaling factor) and
@@ -56,9 +59,20 @@ import os
 import jax
 import jax.numpy as jnp
 
+from mlx_sharding_tpu.ops.dispatch import DispatchCounter
+
 logger = logging.getLogger(__name__)
 
 GATHER_PATH_MAX_TOKENS = 16
+
+# Which path apply_experts chose, once per traced call (ops/dispatch.py).
+# /metrics shows it as ``mst_moe_dispatch_total{path}``: "gather" or
+# "gather_packed" above 0 on a chip is a decode step that copies N x K whole
+# experts out of the stacks (dense ones; packed ones outside the kernel's
+# contract) before it multiplies.
+_DISPATCHED = DispatchCounter("kernel", "scan", "gather_packed", "gather")
+dispatch_counts = _DISPATCHED.counts
+_count_dispatch = _DISPATCHED.count
 
 
 @jax.named_scope("mst.moe.router")
@@ -172,12 +186,14 @@ def apply_experts(
         base = 0 if expert_base is None else expert_base
         if ep_axis is not None:
             base = base + jax.lax.axis_index(ep_axis) * e_local
+        _count_dispatch("scan")
         acc = _apply_scan(
             x, weights, idx - base, w_gate, w_up, w_down, group_size, bits,
             layer=layer,
         )
         return acc if ep_axis is None else jax.lax.psum(acc, ep_axis)
     if n > GATHER_PATH_MAX_TOKENS:
+        _count_dispatch("scan")
         return _apply_scan(
             x, weights, idx, w_gate, w_up, w_down, group_size, bits, layer=layer
         )
@@ -192,6 +208,9 @@ def apply_experts(
             (_apply_packed_kernel if kernel else _apply_gather_packed).__name__,
             n, idx.shape[1], tuple(w_up["q"].shape), kernel and layer is not None,
         )
+    _count_dispatch(
+        "kernel" if kernel else "gather_packed" if packed else "gather"
+    )
     if kernel:
         return _apply_packed_kernel(
             x, weights, idx, w_gate, w_up, w_down, group_size, bits, layer=layer
@@ -282,8 +301,8 @@ def _apply_packed_kernel(
     ``quant_matmul_experts`` over the packed stacks as they lie in HBM, all
     N rows against one expert's tile a grid step, and the down projection
     combines with ``coef[t, n] = sum_k weights[n, k] * (idx[n, k] == ids[t])``
-    — the arithmetic of ``_apply_scan``'s body, skipping the experts nobody
-    chose. No dense expert tensor is written to HBM. With ``layer`` the
+    — the arithmetic of ``_apply_scan``'s body over the same list of
+    experts. No dense expert tensor is written to HBM. With ``layer`` the
     stacks are ``(L, E, …)`` and the kernel's id table names row ``layer *
     E + id`` of their ``(L*E, …)`` view: the same bytes by another index."""
     from mlx_sharding_tpu.ops.quant_matmul import quant_matmul_experts
@@ -341,34 +360,80 @@ def _apply_gather_packed(x, weights, idx, w_gate, w_up, w_down, gs, bits):
 @jax.named_scope("mst.moe.experts.scan")
 def _apply_scan(x, weights, idx, w_gate, w_up, w_down, gs=64, bits=4,
                 layer=None):
-    from mlx_sharding_tpu.ops.quant import is_quantized, linear
+    """Each DISTINCT held expert the rows picked, in ascending order: all N
+    rows against its matrices, read out of the stacks where they lie, times
+    the rows' routing mass for it. An expert nobody picked is not read — its
+    term would be ``0 * y``. ``idx`` may name experts the stacks do not hold
+    (below 0, at or above E: a resident range, ``ep_axis``); they match no
+    held expert and are not visited.
+
+    Under ``jax.vmap`` over the rows (the engine's M decode lanes: ``--ep``,
+    ``--paged-attention gather``) the lanes are ONE walk over the experts
+    any of them picked (``_distinct_walk``'s batching rule)."""
+    from mlx_sharding_tpu.ops.quant import is_quantized
 
     num_experts = (w_up["q"] if is_quantized(w_up) else w_up).shape[
         0 if layer is None else 1
     ]
-
+    first = 0  # the stacks' row of this layer's expert 0
     if layer is not None:
         w_gate, w_up, w_down = _flat_layers(w_gate, w_up, w_down)
+        first = layer * num_experts
+    return _distinct_walk(num_experts, gs, bits)(
+        x, weights, idx, (w_gate, w_up, w_down), jnp.asarray(first, jnp.int32)
+    )
 
-    def body(acc, xs):
-        wg, wu, wd, e = xs  # wg is None (an empty pytree) for un-gated experts
-        if layer is not None:  # stacks closed over whole: read (layer, e) in place
-            at = lambda w: jax.tree.map(  # noqa: E731
+
+@functools.lru_cache(maxsize=None)
+def _distinct_walk(num_experts: int, gs: int, bits: int):
+    """``walk(x (N, H), weights (N, K), idx (N, K), stacks, first)``: the
+    loop of ``_apply_scan`` over rows ``first .. first + num_experts`` of the
+    stacks, with a batching rule of its own. The loop's bound and the row it
+    reads are loaded from the picks, so ``jax.vmap`` as it stands would give
+    every lane its own bound and its own row: a batched ``while`` that
+    gathers one whole expert PER LANE an iteration. The rule folds the
+    lanes into the rows instead — one list of the experts any lane picked,
+    each read once for all lanes, as a scan by the loop's counter was."""
+    from mlx_sharding_tpu.ops.quant import linear
+
+    @jax.custom_batching.custom_vmap
+    def walk(x, weights, idx, stacks, first):
+        ids, live = distinct_experts(idx, num_experts)
+
+        def body(t, acc):
+            e = ids[t]
+            wg, wu, wd = jax.tree.map(  # w_gate None (no leaves): un-gated
                 lambda a: jax.lax.dynamic_index_in_dim(
-                    a, layer * num_experts + e, 0, keepdims=False
+                    a, first + e, 0, keepdims=False
                 ),
-                w,
+                stacks,
             )
-            wg, wu, wd = at(w_gate), at(w_up), at(w_down)
-        coef = ((idx == e) * weights).sum(axis=-1)  # (N,) routing mass for e
-        # linear() serves dense (in, out) slices and packed (out, in)
-        # triples alike — the prefill path streams every expert's packed
-        # bytes once, full-width MXU matmuls, no sorting
-        g = None if wg is None else linear(x, wg, gs, bits)
-        y = linear(_activate(g, linear(x, wu, gs, bits)), wd, gs, bits)
-        return acc + coef[:, None].astype(y.dtype) * y, None
+            coef = ((idx == e) * weights).sum(axis=-1)  # (N,) mass for e
+            # linear() serves dense (in, out) slices and packed (out, in)
+            # triples alike — full-width MXU matmuls, no sorting
+            g = None if wg is None else linear(x, wg, gs, bits)
+            y = linear(_activate(g, linear(x, wu, gs, bits)), wd, gs, bits)
+            return acc + coef[:, None].astype(y.dtype) * y
 
-    acc0 = jnp.zeros_like(x)
-    stacks = (w_gate, w_up, w_down) if layer is None else (None, None, None)
-    acc, _ = jax.lax.scan(body, acc0, (*stacks, jnp.arange(num_experts)))
-    return acc
+        # a traced trip count: a ``while`` over the step's ``live`` experts
+        return jax.lax.fori_loop(0, live[0], body, jnp.zeros_like(x))
+
+    @walk.def_vmap
+    def walk_lanes(axis_size, in_batched, *args):
+        *rows_batched, stacks_batched, first_batched = in_batched
+        if first_batched or any(jax.tree.leaves(stacks_batched)):
+            # stacks or a layer of a lane's own (nothing served): lane by lane
+            lane = lambda i: walk(*jax.tree.map(  # noqa: E731
+                lambda a, b: a[i] if b else a, args, tuple(in_batched)
+            ))
+            return jax.lax.map(lane, jnp.arange(axis_size)), True
+        x, weights, idx = (
+            (a if b else jnp.broadcast_to(a, (axis_size, *a.shape))).reshape(
+                -1, a.shape[-1]
+            )
+            for a, b in zip(args[:3], rows_batched)
+        )
+        out = walk(x, weights, idx, *args[3:])
+        return out.reshape(axis_size, -1, out.shape[-1]), True
+
+    return walk

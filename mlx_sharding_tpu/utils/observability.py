@@ -369,14 +369,15 @@ class ServingMetrics:
                 ]
             except Exception:  # noqa: BLE001 — scrape must not 500
                 del lines[lmark:]
-            # which path each packed matmul and each ragged paged-attention
-            # call took while its program was traced. Only where the ops are
-            # loaded: a process that never imported them dispatched nothing,
-            # and a scrape imports no JAX.
+            # which path each packed matmul, each ragged paged-attention call
+            # and each routed-expert call took while its program was traced.
+            # Only where the ops are loaded: a process that never imported
+            # them dispatched nothing, and a scrape imports no JAX.
             for type_line, module in (
                 ("# TYPE mst_quant_dispatch_total counter", "quant"),
                 ("# TYPE mst_paged_attention_dispatch_total counter",
                  "paged_attention"),
+                ("# TYPE mst_moe_dispatch_total counter", "moe"),
             ):
                 ops = sys.modules.get(f"mlx_sharding_tpu.ops.{module}")
                 if ops is not None:
@@ -1131,6 +1132,13 @@ _HELP = {
         "chose, one count per traced call: kernel walks each slot's live "
         "pages in place; xla gathers every slot's whole table row (0 on a "
         "chip unless a layer has a softcap or a window).",
+    "mst_moe_dispatch_total":
+        "Routed-expert calls by the path ops/moe chose, one count per traced "
+        "call: kernel is the expert-indexed 4-bit kernel, scan walks the "
+        "distinct held experts the rows picked (prefill, a resident range, "
+        "expert parallelism); gather_packed and gather copy every pick's "
+        "whole expert out of the stacks first (0 on a chip where the decode "
+        "step is packed and inside the kernel's contract).",
     "mst_faults_armed":
         "Currently armed fault-injection sites (should be 0 in prod).",
     "mst_faults_malformed_total":

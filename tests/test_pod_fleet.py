@@ -224,6 +224,11 @@ def test_registry_teardown_broadcast_over_fabric():
 
 def test_cross_host_handoff_parity(pod):
     for (prompt, kw), ref in zip(JOBS, pod.refs):
+        # host 1 is alive: it heartbeats (PodFleet.start()'s thread in a
+        # deployment). Without it a first job that compiles for more than
+        # HEARTBEAT_TIMEOUT_S on a loaded CPU leaves the peer stale, and the
+        # second job is rightly served locally
+        pod.f1.tick()
         got = [t for t, _ in pod.co.generate_step(prompt, **kw)]
         assert got == ref
     h = pod.f0.handoff.stats()

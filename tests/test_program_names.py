@@ -171,7 +171,14 @@ def test_packed_experts_and_the_scan_path_have_their_scopes(monkeypatch):
     idx = jnp.zeros((20, 2), jnp.int32)
     scan = jax.jit(moe.apply_experts).lower(x, weights, idx, w, w, wd).as_text(
         debug_info=True)
-    assert {"mst.moe.experts", "mst.moe.experts.scan"} <= _scopes_in(scan)
+    # the loop, its id list and its routing mass: nothing of the scan sits
+    # under another scope, so scope_share.moe_experts reads all of it
+    assert _scopes_in(scan) == {"mst.moe.experts", "mst.moe.experts.scan"}
+    held = jax.jit(
+        lambda *a: moe.apply_experts(*a, expert_base=2, layer=1)
+    ).lower(x[:8], weights[:8], idx[:8] + 3, None, w[None].repeat(2, 0),
+            wd[None].repeat(2, 0)).as_text(debug_info=True)
+    assert _scopes_in(held) == {"mst.moe.experts", "mst.moe.experts.scan"}
 
     def packed(out_dim, in_dim):  # MLX orientation (E, out, in * bits / 32)
         return {"q": jnp.zeros((4, out_dim, in_dim // 8), jnp.uint32),

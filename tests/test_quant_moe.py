@@ -522,6 +522,298 @@ def test_apply_experts_dispatch(monkeypatch):
     assert "quant_matmul_experts" not in text
 
 
+def test_moe_dispatch_is_counted_once_a_traced_call_and_shown_on_metrics(monkeypatch):
+    """``mst_moe_dispatch_total{path}`` counts where ``apply_experts``
+    chooses: once per traced call, not once per run of the compiled program.
+    Off the chip a packed decode step is ``gather_packed`` (``kernel`` with
+    the backend answered as ``tpu``), a dense one ``gather``, 17 rows or a
+    resident range ``scan``."""
+    from mlx_sharding_tpu.ops import moe
+    from mlx_sharding_tpu.utils.observability import ServingMetrics
+
+    rng = np.random.default_rng(3)
+    e, k, h, mi, gs = 4, 2, 64, 32, 16
+    packed = (_packed_stack(rng, e, mi, h, gs), _packed_stack(rng, e, mi, h, gs),
+              _packed_stack(rng, e, h, mi, gs))
+    dense = tuple(jnp.ones(shape, jnp.float32) for shape in ((e, h, mi), (e, h, mi), (e, mi, h)))
+
+    def run(n, stacks, **kw):
+        fn = jax.jit(lambda *a: moe.apply_experts(*a, group_size=gs, **kw))
+        for _ in range(3):
+            fn(jnp.ones((n, h), jnp.float32), *_routing(rng, n, e, k, "random"),
+               *stacks).block_until_ready()
+
+    before = moe.dispatch_counts()
+    assert set(before) == {"kernel", "scan", "gather_packed", "gather"}
+    run(8, packed)
+    run(8, dense)
+    run(17, packed)
+    run(8, dense, expert_base=0)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    wide = tuple(_packed_stack(rng, e, 128, 128, 64) for _ in range(3))
+    jax.make_jaxpr(lambda *a: moe.apply_experts(*a))(
+        jnp.ones((8, 128), jnp.float32), *_routing(rng, 8, e, k, "random"), *wide)
+    monkeypatch.undo()
+    after = moe.dispatch_counts()
+    assert after == {"kernel": before["kernel"] + 1, "scan": before["scan"] + 2,
+                     "gather_packed": before["gather_packed"] + 1,
+                     "gather": before["gather"] + 1}
+    text = ServingMetrics().render()
+    assert "# TYPE mst_moe_dispatch_total counter" in text
+    assert "# HELP mst_moe_dispatch_total" in text
+    for path, n in after.items():
+        assert f'mst_moe_dispatch_total{{path="{path}"}} {n}' in text
+
+
+# ----------------------- the scan that visits the experts the rows picked
+
+
+def _full_walk(x, weights, idx, w_gate, w_up, w_down, gs=64, bits=4, layer=None):
+    """``_apply_scan`` as it was before it skipped: EVERY held expert in
+    ascending order, an unpicked one's product scaled by a routing mass of
+    exactly 0. The reference the distinct-expert loop must equal bit for
+    bit, and the one a NaN in an unpicked expert poisons."""
+    from mlx_sharding_tpu.ops.moe import _activate
+    from mlx_sharding_tpu.ops.quant import linear
+
+    if layer is not None:
+        w_gate, w_up, w_down = jax.tree.map(lambda a: a[layer], (w_gate, w_up, w_down))
+    num_experts = (w_up["q"] if is_quantized(w_up) else w_up).shape[0]
+
+    def body(acc, xs):
+        wg, wu, wd, e = xs
+        coef = ((idx == e) * weights).sum(axis=-1)
+        g = None if wg is None else linear(x, wg, gs, bits)
+        y = linear(_activate(g, linear(x, wu, gs, bits)), wd, gs, bits)
+        return acc + coef[:, None].astype(y.dtype) * y, None
+
+    acc, _ = jax.lax.scan(
+        body, jnp.zeros_like(x), (w_gate, w_up, w_down, jnp.arange(num_experts)))
+    return acc
+
+
+#: global picks of 4 rows x top-3 over 16 experts of which 4..11 are held:
+#: held 1, 2, 5, 6 are picked, held 0, 3, 4, 7 are not; with ``base`` 4 three
+#: picks fall below 0 and five at or above E_local
+_SCAN_PICKS = np.array([[1, 5, 14], [5, 9, 15], [0, 9, 10], [2, 6, 13]])
+_SCAN_HELD, _SCAN_BASE = 8, 4
+
+
+def _scan_case(packed, gated, layered, ranged):
+    """``(x, weights, local idx, stacks, poisoned stacks, layer)``: the held
+    stacks of one case and the same with every expert the rows did NOT pick
+    (and every other layer) turned to NaN."""
+    rng = np.random.default_rng(17)
+    n, h, mi, gs = _SCAN_PICKS.shape[0], 64, 32, 16
+    x = jnp.asarray(rng.normal(size=(n, h)), jnp.float32)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, size=_SCAN_PICKS.shape), jnp.float32)
+    if ranged:  # global ids less the range's base, as apply_experts hands them
+        local = _SCAN_PICKS - _SCAN_BASE
+    else:  # every pick inside the stacks: the same held experts hit
+        local = np.where(
+            (_SCAN_PICKS >= _SCAN_BASE) & (_SCAN_PICKS < _SCAN_BASE + _SCAN_HELD),
+            _SCAN_PICKS - _SCAN_BASE, np.array([[1], [5], [6], [2]]))
+    picked = np.isin(np.arange(_SCAN_HELD), local)
+    assert picked.tolist() == [False, True, True, False, False, True, True, False]
+    layers = 3 if layered else 1
+
+    def stack(out_d, in_d):
+        if packed:  # (L, E, out, in*bits/32) leaves
+            per = [_packed_stack(rng, _SCAN_HELD, out_d, in_d, gs) for _ in range(layers)]
+            return jax.tree.map(lambda *a: jnp.stack(a), *per)
+        return jnp.asarray(
+            rng.normal(size=(layers, _SCAN_HELD, in_d, out_d)) * 0.1, jnp.float32)
+
+    stacks = (stack(mi, h) if gated else None, stack(mi, h), stack(h, mi))
+    layer = 1 if layered else None
+    keep = np.zeros((layers, _SCAN_HELD), bool)
+    keep[1 if layered else 0] = picked
+
+    def poison(a):
+        if not jnp.issubdtype(a.dtype, jnp.floating):
+            return a  # packed words: their scales and biases carry the NaN
+        return jnp.where(keep.reshape(keep.shape + (1,) * (a.ndim - 2)), a, jnp.nan)
+
+    poisoned = jax.tree.map(poison, stacks)
+    if not layered:
+        stacks, poisoned = jax.tree.map(lambda a: a[0], (stacks, poisoned))
+    return x, weights, jnp.asarray(local, jnp.int32), stacks, poisoned, layer, gs
+
+
+@pytest.mark.parametrize("ranged", [False, True], ids=["all-held", "resident-range"])
+@pytest.mark.parametrize("layered", [False, True], ids=["one-layer", "in-place"])
+@pytest.mark.parametrize("gated", [True, False], ids=["swiglu", "relu2"])
+@pytest.mark.parametrize("packed", [False, True], ids=["dense", "packed"])
+def test_scan_visits_only_the_picked_experts(packed, gated, layered, ranged):
+    """The loop over the step's distinct held experts (a) equals the walk
+    over EVERY held expert bit for bit — it leaves out only terms that are
+    ``0 * y`` — and (b) does not READ an expert nobody picked: with those
+    turned to NaN the full walk is poisoned and the loop's result stays what
+    it was. Picks a resident range does not hold (below 0, at or above
+    E_local) are not visited."""
+    from mlx_sharding_tpu.ops import moe
+
+    x, weights, idx, stacks, poisoned, layer, gs = _scan_case(
+        packed, gated, layered, ranged)
+    want = np.asarray(jax.jit(
+        lambda *a: _full_walk(*a, gs, 4, layer=layer))(x, weights, idx, *stacks))
+    scan = jax.jit(lambda *a: moe._apply_scan(*a, gs, 4, layer=layer))
+    got = np.asarray(scan(x, weights, idx, *stacks))
+    assert np.isfinite(want).all() and np.abs(want).max() > 0
+    assert np.array_equal(got, want)
+    assert np.isnan(np.asarray(jax.jit(
+        lambda *a: _full_walk(*a, gs, 4, layer=layer))(x, weights, idx, *poisoned))).any()
+    assert np.array_equal(np.asarray(scan(x, weights, idx, *poisoned)), want)
+    if ranged:  # the dispatcher subtracts the base and comes here, at any row count
+        before = moe.dispatch_counts()
+        via = moe.apply_experts(
+            x, weights, idx + _SCAN_BASE, *poisoned, group_size=gs,
+            expert_base=_SCAN_BASE, layer=layer)
+        assert np.array_equal(np.asarray(via), want)
+        assert moe.dispatch_counts() == {**before, "scan": before["scan"] + 1}
+
+
+@pytest.mark.parametrize("picks", ["below", "above", "both"])
+def test_scan_with_no_held_expert_picked_gives_zeros(picks):
+    """``live == 0``: every pick of the step belongs to another holder of
+    the layer. Nothing is read (the stacks are all NaN) and the part is 0."""
+    from mlx_sharding_tpu.ops import moe
+
+    e, h, mi = 4, 16, 8
+    idx = {"below": [[-3, -1], [-2, -1]], "above": [[4, 9], [7, 5]],
+           "both": [[-1, 4], [6, -5]]}[picks]
+    nan = lambda *shape: jnp.full(shape, jnp.nan, jnp.float32)  # noqa: E731
+    got = moe._apply_scan(
+        jnp.ones((2, h), jnp.float32), jnp.full((2, 2), 0.5, jnp.float32),
+        jnp.asarray(idx, jnp.int32), nan(e, h, mi), nan(e, h, mi), nan(e, mi, h))
+    assert np.array_equal(np.asarray(got), np.zeros((2, h), np.float32))
+
+
+@pytest.mark.parametrize("ep", [2, 4])
+def test_scan_under_ep_axis_equals_one_device(ep):
+    """Expert-parallel: each device walks the picks among ITS residents (one
+    holds none of them here, one holds one) and the psum follows the loop."""
+    from jax.sharding import PartitionSpec as P
+
+    from mlx_sharding_tpu.ops import moe
+    from mlx_sharding_tpu.parallel.mesh import make_mesh
+
+    rng = np.random.default_rng(23)
+    n, h, mi, e, k = 6, 16, 24, 8, 2
+    x = jnp.asarray(rng.normal(size=(n, h)), jnp.float32)
+    wg, wu = (jnp.asarray(rng.normal(size=(e, h, mi)) * 0.1, jnp.float32) for _ in range(2))
+    wd = jnp.asarray(rng.normal(size=(e, mi, h)) * 0.1, jnp.float32)
+    idx = jnp.asarray([[0, 1], [1, 5], [0, 5], [5, 1], [1, 0], [5, 0]], jnp.int32)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, size=(n, k)), jnp.float32)
+    want = _full_walk(x, weights, idx, wg, wu, wd)
+    rep, split = P(), P("ep")
+    sharded = jax.jit(jax.shard_map(
+        lambda *a: moe.apply_experts(*a, ep_axis="ep"), mesh=make_mesh(pp=1, ep=ep),
+        in_specs=(rep, rep, rep, split, split, split), out_specs=rep, check_vma=False,
+    ))
+    # the unpicked experts are never read, on any device
+    picked = jnp.isin(jnp.arange(e), idx)[:, None, None]
+    for stacks in ((wg, wu, wd), [jnp.where(picked, w, jnp.nan) for w in (wg, wu, wd)]):
+        np.testing.assert_allclose(
+            np.asarray(sharded(x, weights, idx, *stacks)), np.asarray(want),
+            rtol=1e-5, atol=1e-6)
+
+
+def _largest_read(jaxpr):
+    """Elements of the largest array any ``gather`` / ``dynamic_slice`` of
+    ``jaxpr`` (sub-jaxprs included) produces, and whether any ``while`` in
+    it has a per-lane predicate (a condition that is not a scalar)."""
+    largest, lane_bound = 0, False
+    stack = [jaxpr]
+    while stack:
+        jp = stack.pop()
+        for eqn in jp.eqns:
+            if eqn.primitive.name in ("gather", "dynamic_slice"):
+                largest = max([largest] + [int(np.prod(v.aval.shape)) for v in eqn.outvars])
+            if eqn.primitive.name == "while":
+                lane_bound |= any(v.aval.shape != () for v in eqn.params["cond_jaxpr"].jaxpr.outvars)
+            for sub in jax.tree.leaves(
+                    list(eqn.params.values()),
+                    is_leaf=lambda x: hasattr(x, "eqns") or hasattr(x, "jaxpr")):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    stack.append(sub)
+    return largest, lane_bound
+
+
+@pytest.mark.parametrize("caller", ["resident-range", "ep-axis"])
+@pytest.mark.parametrize("packed", [False, True], ids=["dense", "packed"])
+def test_scan_under_vmap_is_one_walk_for_all_lanes(packed, caller):
+    """The engine's vectorized decode step (``--ep``, ``--paged-attention
+    gather``) calls the scan under ``jax.vmap`` over its M lanes of one row
+    each. The lanes are folded into the rows: ONE list of the experts any
+    lane picked, each read once — no ``gather`` of M whole experts an
+    iteration and no per-lane loop bound, which is what ``vmap`` makes of a
+    bound and an index that are loaded from the picks. The result is the
+    walk over every held expert, and an expert NO lane picked is not read."""
+    from jax.sharding import PartitionSpec as P
+
+    from mlx_sharding_tpu.ops import moe
+    from mlx_sharding_tpu.parallel.mesh import make_mesh
+
+    rng = np.random.default_rng(29)
+    m, h, mi, e, k, gs = 5, 64, 32, 8, 2, 16
+    x = jnp.asarray(rng.normal(size=(m, 1, h)), jnp.float32)
+    idx = jnp.asarray([[[1, 6]], [[6, 2]], [[1, 2]], [[5, 1]], [[2, 6]]], jnp.int32)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, size=(m, 1, k)), jnp.float32)
+    if packed:
+        stacks = (_packed_stack(rng, e, mi, h, gs), _packed_stack(rng, e, mi, h, gs),
+                  _packed_stack(rng, e, h, mi, gs))
+    else:
+        stacks = tuple(jnp.asarray(rng.normal(size=s) * 0.1, jnp.float32)
+                       for s in ((e, h, mi), (e, h, mi), (e, mi, h)))
+    want = np.asarray(_full_walk(x[:, 0], weights[:, 0], idx[:, 0], *stacks, gs))
+    picked = np.isin(np.arange(e), np.asarray(idx))
+    poisoned = jax.tree.map(
+        lambda a: jnp.where(picked.reshape((e,) + (1,) * (a.ndim - 1)), a, jnp.nan)
+        if jnp.issubdtype(a.dtype, jnp.floating) else a, stacks)
+
+    if caller == "resident-range":  # global ids 3..10 are the held 0..7
+        def lanes(x, weights, idx, *stacks):
+            return jax.vmap(
+                lambda *rows: moe.apply_experts(*rows, *stacks, group_size=gs, expert_base=3)
+            )(x, weights, idx + 3)
+    else:
+        def per_device(x, weights, idx, *stacks):
+            return jax.vmap(
+                lambda *rows: moe.apply_experts(*rows, *stacks, group_size=gs, ep_axis="ep")
+            )(x, weights, idx)
+        rep, split = P(), P("ep")
+        lanes = jax.shard_map(
+            per_device, mesh=make_mesh(pp=1, ep=2), in_specs=(rep, rep, rep, split, split, split),
+            out_specs=rep, check_vma=False)
+    largest, lane_bound = _largest_read(jax.make_jaxpr(lanes)(x, weights, idx, *stacks).jaxpr)
+    assert 0 < largest <= h * mi, "the lanes gather an expert each"
+    assert not lane_bound, "the loop's bound is per lane"
+    for given in (stacks, poisoned):
+        got = np.asarray(jax.jit(lanes)(x, weights, idx, *given))
+        assert got.shape == (m, 1, h)
+        np.testing.assert_allclose(got[:, 0], want, rtol=1e-5, atol=1e-6)
+
+
+def test_scan_under_vmap_over_the_stacks_goes_lane_by_lane():
+    """Lanes with stacks of their own (no served path) are walked one after
+    another, each over its own picks."""
+    from mlx_sharding_tpu.ops import moe
+
+    rng = np.random.default_rng(31)
+    m, n, h, mi, e, k = 3, 2, 16, 8, 4, 2
+    x = jnp.asarray(rng.normal(size=(m, n, h)), jnp.float32)
+    weights, idx = (jnp.stack(a) for a in zip(*(_routing(rng, n, e, k, "random") for _ in range(m))))
+    wg, wu = (jnp.asarray(rng.normal(size=(m, e, h, mi)) * 0.1, jnp.float32) for _ in range(2))
+    wd = jnp.asarray(rng.normal(size=(m, e, mi, h)) * 0.1, jnp.float32)
+    got = jax.vmap(moe._apply_scan)(x, weights, idx, wg, wu, wd)
+    for i in range(m):
+        assert np.array_equal(
+            np.asarray(got[i]),
+            np.asarray(moe._apply_scan(x[i], weights[i], idx[i], wg[i], wu[i], wd[i])))
+
+
 # ------------------------ the layer scan that leaves the expert stacks whole
 
 
