@@ -198,6 +198,7 @@ class _Request:
     _trace: Optional[object] = None
     _trace_own: bool = False
     _t_submit: float = 0.0
+    _t_join: float = 0.0  # slot claimed (mst_join_seconds runs from here)
     _t_last_emit: float = 0.0
 
 
@@ -791,7 +792,13 @@ class ContinuousBatcher:
         # part of _tick/_tick_async runs inside one phase of
         # tracing.TICK_PHASES; harvest_wait is the harvest device_get (what
         # the async path overlaps), idle_wait the blocking submission wait,
-        # the rest is host work
+        # the rest is host work. It also keeps, per phase, the seconds the
+        # device had nothing to run: _phases.device(True) stands right
+        # before the dispatch call of every served program (decode block,
+        # speculative round, prefill chunk; its arguments are made first:
+        # they are host work), _phases.device(False) follows a blocking
+        # read that leaves none dispatched and unread — and nothing else
+        # touches the bit
         self._phases = tracing.TickPhases(profile=self._trace_profile)
         # plain decode blocks, counted where they happen (tick thread only).
         # Identities: dispatched = harvested + abandoned + in flight, and
@@ -828,6 +835,10 @@ class ContinuousBatcher:
         self._h_queue_wait = Histogram(
             LATENCY_BUCKETS_S, "ContinuousBatcher._h_queue_wait"
         )
+        # one observation a slot claim that reached decode: where the queue
+        # wait ends (_assign_slot's stamp) to the slot turning active (its
+        # last prefill chunk's first token, or the end of a block import)
+        self._h_join = Histogram(LATENCY_BUCKETS_S, "ContinuousBatcher._h_join")
         # adaptive window control: an AcceptanceTracker drives per-slot
         # windows for ngram mode always, and for engine mode when the
         # operator opts in with spec_window_max (without it the engine path
@@ -1372,41 +1383,19 @@ class ContinuousBatcher:
             self.kv_bytes_read_total,
         )
 
-    def tick_timing_stats(self) -> dict:
-        """Per-tick host/device-blocked timing for /metrics, averaged over
-        the batcher's life: ``device_blocked_ms`` is the harvest
-        ``device_get`` wait (what the async pipeline shrinks by overlapping
-        it with the next block's compute), ``host_ms`` is the rest of the
-        tick's wall time. Racy snapshot by design — a gauge, not a decision
-        input."""
-        snap = self._phases.snapshot()
-        secs = snap["seconds"]
-        harvests = snap["entries"]["harvest_wait"]  # ticks that harvested a block
-        n = max(1, harvests)
-        host_s = sum(
-            s for ph, s in secs.items()
-            if ph not in ("harvest_wait", "idle_wait")
-        )
-        return {
-            "path": "async" if self._async else "sync",
-            "host_ms_avg": 1000.0 * host_s / n,
-            "device_blocked_ms_avg": 1000.0 * secs["harvest_wait"] / n,
-            "ticks": harvests,
-            # resume-path import stall (the kv_import phase): ~0 when
-            # prefetch staged the pages, the full host→device marshal on a
-            # demand import
-            "kv_import_s_total": secs["kv_import"],
-        }
-
     def tick_phase_stats(self) -> dict:
         """The tick's cumulative account for /metrics (every value a
-        counter, so ReplicaSet/DisaggCoordinator sum them): seconds and
-        entries per phase, ticks, and the decode blocks' and tokens' fate.
-        Racy snapshot of tick-thread-owned counters by design."""
+        counter, so ReplicaSet/DisaggCoordinator sum them): seconds, the
+        part of them the device had nothing to run, and entries per phase,
+        ticks, and the decode blocks' and tokens' fate; ``path`` names the
+        run-loop (``mst_sched_async``). Racy snapshot of tick-thread-owned
+        counters by design."""
         snap = self._phases.snapshot()
         return {
+            "path": "async" if self._async else "sync",
             "ticks": snap["ticks"],
             "phase_seconds": snap["seconds"],
+            "device_empty_seconds": snap["empty_seconds"],
             "phase_entries": snap["entries"],
             "blocks_dispatched": self._blocks_dispatched,
             "blocks_harvested": self._blocks_harvested,
@@ -1458,13 +1447,15 @@ class ContinuousBatcher:
 
     def latency_stats(self) -> dict:
         """Bucketed latency snapshots for /metrics: inter-token latency
-        (observed at the emit path) and admission queue wait (submit →
-        slot assignment), as :meth:`Histogram.to_dict` snapshots — the
-        mergeable currency ReplicaSet/DisaggCoordinator aggregate across
-        replicas with :meth:`Histogram.merge_dicts`."""
+        (observed at the emit path), admission queue wait (submit → slot
+        assignment) and the join (slot assignment → the slot decoding), as
+        :meth:`Histogram.to_dict` snapshots — the mergeable currency
+        ReplicaSet/DisaggCoordinator aggregate across replicas with
+        :meth:`Histogram.merge_dicts`."""
         return {
             "itl": self._h_itl.to_dict(),
             "queue_wait": self._h_queue_wait.to_dict(),
+            "join": self._h_join.to_dict(),
         }
 
     def _account_kv_read(self, live, steps: int, path: Optional[str] = None):
@@ -2001,6 +1992,7 @@ class ContinuousBatcher:
         now = time.perf_counter()
         if req._t_submit:
             self._h_queue_wait.observe(max(0.0, now - req._t_submit))
+        req._t_join = now
         tr = req._trace
         if tr is not None:
             tr.add("queue_wait", req._t_submit or now, now, slot=slot)
@@ -2230,6 +2222,7 @@ class ContinuousBatcher:
         req.draft_pos = req.prompt.size
         with self._admission_lock:
             self.spill_hits += 1
+        self._h_join.observe(time.perf_counter() - req._t_join)
         return True
 
     @staticmethod
@@ -2267,11 +2260,12 @@ class ContinuousBatcher:
             if self._recurrent and req.prefill_pos == 0:
                 self.state_resets += 1
             self._note_ring_page(req.prefill_pos)
+            tokens = self._put(jnp.asarray(chunk[None]))
+            valid = self._put(jnp.asarray(n_valid, jnp.int32))
+            self._phases.device(True)  # from here the device has the chunk
             logits, self.cache = eng.prefill_slot()(
                 eng.layer_params, eng.layer_masks, eng.vocab_parts,
-                eng.shared_params, self._put(jnp.asarray(chunk[None])),
-                slot_arr, self.cache,
-                self._put(jnp.asarray(n_valid, jnp.int32)),
+                eng.shared_params, tokens, slot_arr, self.cache, valid,
                 self.table if self.paged else None,
             )
             req.prefill_pos += n_valid
@@ -2280,10 +2274,12 @@ class ContinuousBatcher:
         if self.draft is not None and req.draft_pos < req.prompt.size:
             d = self.draft
             chunk, n_valid = self._chunk_at(req.prompt, req.draft_pos, c)
+            tokens = self._put(jnp.asarray(chunk[None]))
+            valid = self._put(jnp.asarray(n_valid, jnp.int32))
+            self._phases.device(True)
             _, self.dcache = d.prefill_slot()(
                 d.layer_params, d.layer_masks, d.vocab_parts, d.shared_params,
-                self._put(jnp.asarray(chunk[None])), slot_arr, self.dcache,
-                self._put(jnp.asarray(n_valid, jnp.int32)), None,
+                tokens, slot_arr, self.dcache, valid, None,
             )
             req.draft_pos += n_valid
         if tr is not None:
@@ -2356,7 +2352,12 @@ class ContinuousBatcher:
         self.active = self._row_set(
             self.active, slot_arr, self._put(jnp.asarray(True))
         )
-        self._emit(req, int(tok), logprobs)
+        # the blocking read of the chunk and its sample; the pipeline was
+        # drained before this chunk, so nothing is left dispatched and unread
+        tok = int(tok)
+        self._phases.device(False)
+        self._emit(req, tok, logprobs)
+        self._h_join.observe(time.perf_counter() - req._t_join)
         if req.prefill_only and req.slot >= 0:
             # disaggregated handoff: the first token is the prefill
             # replica's whole deliverable — park the request; the tick
@@ -3081,6 +3082,7 @@ class ContinuousBatcher:
                 ) if self.paged else 0,
             )
         with self._phases.span("dispatch", **args):  # mst.decode_block
+            self._phases.device(True)
             outs, self.last_tok, self.cache, self.recent, self.keys = block(
                 eng.layer_params, eng.layer_masks, eng.vocab_parts,
                 eng.shared_params, self.last_tok, self.cache, self.active,
@@ -3096,7 +3098,7 @@ class ContinuousBatcher:
             self._blocks_abandoned += 1
             self._tokens_dropped["abandoned_block"] += inf.positions
 
-    def _harvest(self, inf: Optional[_InflightBlock]):
+    def _harvest(self, inf: Optional[_InflightBlock], **drain):
         """Pull a dispatched block's tokens to the host and run all of its
         host-side consequences: emit per slot (lookahead tokens of a slot
         that finished after dispatch are dropped by the ``req.slot != slot``
@@ -3107,15 +3109,24 @@ class ContinuousBatcher:
             return
         try:
             inject("scheduler.harvest")  # fault harness: kill the harvest
-            with self._phases.span("harvest_wait", seq=inf.seq):
+            with self._phases.span("harvest_wait", seq=inf.seq, **drain):
                 # mst: allow(MST102): THE tick sync — tokens must reach the host
                 outs, prev = jax.device_get((inf.outs, inf.prev_tok))
         except BaseException:
             self._abandon(inf)
             raise
+        self._harvested()
         self._blocks_harvested += 1
         with self._phases.span("emit"):
             self._emit_block(inf, outs, prev, *self._phases.last)
+
+    def _harvested(self):
+        """A harvest's blocking read has returned, its ``harvest_wait`` span
+        closed (nobody waits on an empty device). With no lookahead block
+        behind it — every quiesce, every harvest of the sync tick — the
+        device has nothing to run until the next dispatch."""
+        if self._inflight is None:
+            self._phases.device(False)
 
     def _emit_block(self, inf: _InflightBlock, outs, prev, t0, t1):
         """The host-side consequences of a harvested block. ``t0``/``t1``
@@ -3137,11 +3148,14 @@ class ContinuousBatcher:
             # toks[j-1] (step 0 consumed prev_tok), so the replay chain is
             # [prev_tok, toks[:-1]]. Deterministic device ops only — every
             # multi-host mirror computes the identical replay in lockstep.
-            chain = np.concatenate([prev[None], toks[:-1]], 0)
+            chain = self._put(
+                jnp.asarray(np.concatenate([prev[None], toks[:-1]], 0))
+            )
+            self._phases.device(True)
             self.dcache = self.draft.spec_replay_cb(self.decode_block)(
                 self.draft.layer_params, self.draft.layer_masks,
                 self.draft.vocab_parts, self.draft.shared_params,
-                self._put(jnp.asarray(chain)), self.dcache, self.active,
+                chain, self.dcache, self.active,
             )
             self.fallback_ticks += 1
             self.replayed_tokens += self.decode_block * len(live)
@@ -3318,13 +3332,14 @@ class ContinuousBatcher:
             keys2 = self._split2(self.keys)
             self.keys, vkeys = keys2[:, 0], keys2[:, 1]
             drafts = self._put(jnp.asarray(drafts_np))
+            caps = self._put(jnp.asarray(wcaps))
+            self._phases.device(True)
             gs, count, self.last_tok, self.cache, self.recent = \
                 eng.spec_verify_ngram_cb(K)(
                     eng.layer_params, eng.layer_masks, eng.vocab_parts,
                     eng.shared_params, self.last_tok, drafts, self.cache,
                     self.active, self.recent, vkeys, self.sp,
-                    self.rep_sizes, self._put(jnp.asarray(wcaps)),
-                    self.table,
+                    self.rep_sizes, caps, self.table,
                 )
         else:
             d = self.draft
@@ -3332,6 +3347,7 @@ class ContinuousBatcher:
                 wcaps[slot] = max(1, wins.get(slot, 0))
             keys3 = self._split3(self.keys)
             self.keys, dkeys, vkeys = keys3[:, 0], keys3[:, 1], keys3[:, 2]
+            self._phases.device(True)
             drafts, qlps, self.dcache = d.spec_propose_cb(K)(
                 d.layer_params, d.layer_masks, d.vocab_parts, d.shared_params,
                 self.last_tok, self.dcache, self.active, self.recent, dkeys,
@@ -3354,7 +3370,7 @@ class ContinuousBatcher:
         return _InflightSpec(outs=(count, gs), live=live, wins=wins,
                              wcaps=wcaps, K=K, guess=guess)
 
-    def _harvest_spec(self, inf: Optional[_InflightSpec]):
+    def _harvest_spec(self, inf: Optional[_InflightSpec], **drain):
         """Pull a dispatched speculative round's (counts, tokens) to the
         host and run its host-side consequences: per-slot emit of the
         accepted prefix + correction token, acceptance accounting, and the
@@ -3363,9 +3379,10 @@ class ContinuousBatcher:
         harvest point, spec flavor)."""
         if inf is None:
             return
-        with self._phases.span("harvest_wait"):
+        with self._phases.span("harvest_wait", **drain):
             # mst: allow(MST102): the spec round's one consolidated harvest
             counts, gs_h = jax.device_get(inf.outs)
+        self._harvested()
         with self._phases.span("emit"):
             self._emit_spec(inf, counts, gs_h, *self._phases.last)
 
@@ -3420,14 +3437,15 @@ class ContinuousBatcher:
         self._harvest_spec(inf)
         return True
 
-    def _harvest_any(self, inf):
+    def _harvest_any(self, inf, **drain):
         """Harvest whichever flavor of in-flight work ``inf`` is — the
         async tick's lookahead slot can hold a plain decode block or a
-        speculative round (ngram mode) interchangeably."""
+        speculative round (ngram mode) interchangeably. ``drain=<reason>``
+        (a quiesce's) goes on the wait's profiler span."""
         if isinstance(inf, _InflightSpec):
-            self._harvest_spec(inf)
+            self._harvest_spec(inf, **drain)
         else:
-            self._harvest(inf)
+            self._harvest(inf, **drain)
 
     def _fits(self, req: _Request) -> bool:
         if not self.paged:
@@ -3535,7 +3553,22 @@ class ContinuousBatcher:
                     return  # head of line doesn't fit; hold the line
             if pick is None:
                 return  # first_fit: nothing waiting fits right now
-            self._assign_slot(self._waiting.pop(pick), self._slots.index(None))
+            req, slot = self._waiting.pop(pick), self._slots.index(None)
+            args = {}
+            if self._trace_profile:
+                # the identifier the join's prefill chunks carry, and what
+                # the claim is sized for: pages to map, and how many of them
+                # the admission pass found mapped already (prefix chain or
+                # store plan), in tokens
+                tr = req._trace
+                args = dict(rid=slot if tr is None else tr.request_id,
+                            slot=slot, pages=0, reused=0)
+                if self.paged:
+                    held = len(req._chain or ()) or (req._splan or (0, 0))[1]
+                    args.update(pages=self._need_pages(req),
+                                reused=held * self.engine.page_size)
+            with self._phases.span("assign_slot", **args):
+                self._assign_slot(req, slot)
 
     def _drain_submissions(self, block: bool = False):
         try:
@@ -3570,7 +3603,7 @@ class ContinuousBatcher:
         inf, self._inflight = self._inflight, None
         if inf is not None:
             self._drains[reason] += 1
-        self._harvest_any(inf)
+        self._harvest_any(inf, drain=reason)
 
     def _growth_fits(self) -> bool:
         """True iff the next ``_grow_for_decode`` is guaranteed to cover
@@ -3678,6 +3711,10 @@ class ContinuousBatcher:
                 self._idle()
 
     def _idle_wait(self):
+        # whatever is still dispatched (a cancelled joiner's chunk, a block
+        # a failed harvest abandoned) nobody will read: the device is as
+        # good as empty for as long as this thread blocks on the queue
+        self._phases.device(False)
         with self._phases.span("idle_wait"):
             self._drain_submissions(block=True)
 

@@ -68,6 +68,9 @@ SPAN_TYPES = (
 TICK_PHASES = (
     "housekeeping",
     "admit",
+    # the slot claim of one admitted request (pages, table row, offset and
+    # sampler rows), nested in ``admit``
+    "assign_slot",
     "prefill_chunk",
     "handoff",
     "dispatch",
@@ -554,25 +557,49 @@ class TickPhases:
     and time no named phase covers is charged to ``other``, so the phases
     partition the thread's wall time since :meth:`start`. Entering or
     leaving a phase is one ``perf_counter()`` read. Only the tick thread
-    writes; :meth:`snapshot` may be called from any thread."""
+    writes; :meth:`snapshot` may be called from any thread.
+
+    Beside each phase's seconds stands the part of them the device had
+    nothing to run (``empty_seconds``): the scheduler tells :meth:`device`
+    right before it dispatches a served program and when a blocking read
+    leaves none dispatched and unread. Host work under a lookahead block
+    costs nothing; the same work with the bit clear is capacity lost."""
 
     def __init__(self, profile: bool = False):
         self.profile = bool(profile)
         self.seconds = dict.fromkeys(TICK_PHASES, 0.0)
+        self.empty_seconds = dict.fromkeys(TICK_PHASES, 0.0)
         self.entries = dict.fromkeys(TICK_PHASES, 0)
         self.ticks = 0
+        # the device has a served program dispatched and unread
+        self.busy = False
         # (start, end) of the span that closed last, perf_counter seconds:
         # the harvest reuses its wait's stamps for the per-request spans
         self.last = (0.0, 0.0)
-        # (phase being charged, since when); a fresh tuple at every switch,
-        # which is what lets snapshot() detect a switch under its feet
+        # (phase being charged, since when, the device's bit meanwhile); a
+        # fresh tuple at every switch, which is what lets snapshot() detect
+        # a switch under its feet
         self._open = None
 
     def _switch(self, phase, now: float):
         cur = self._open
         if cur is not None:
-            self.seconds[cur[0]] += now - cur[1]
-        self._open = None if phase is None else (phase, now)
+            dt = now - cur[1]
+            self.seconds[cur[0]] += dt
+            if not cur[2]:
+                self.empty_seconds[cur[0]] += dt
+        self._open = None if phase is None else (phase, now, self.busy)
+
+    def device(self, busy: bool):
+        """The device's state changed (see the class docstring). A change
+        inside a phase closes the open interval first, as a phase switch
+        does; telling it what it knows already costs one comparison."""
+        if busy == self.busy:
+            return
+        self.busy = busy
+        cur = self._open
+        if cur is not None:
+            self._switch(cur[0], time.perf_counter())
 
     def start(self):
         """The tick thread's loop begins: the clock runs from here."""
@@ -590,10 +617,26 @@ class TickPhases:
         """One loop iteration: the ``mst.tick`` span, whose ``pc`` is this
         process's ``perf_counter()`` at entry — the flight recorder's
         timebase, so a ``/admin/trace/dump`` lines up with a profiler
-        capture by one subtraction."""
-        with self._annotation(TICK_SPAN, pc=time.perf_counter()):
+        capture by one subtraction — and whose ``empty`` is the cumulative
+        empty seconds (all phases) at entry: the difference between two
+        ticks of a capture is what the host says the device idled between
+        them, beside the gaps on the capture's ``XLA Ops`` line."""
+        if self.profile:
+            now = time.perf_counter()
+            ann = profile_span(TICK_SPAN, pc=now, empty=self._empty_at(now))
+        else:
+            ann = _NO_SPAN
+        with ann:
             self.ticks += 1
             yield
+
+    def _empty_at(self, now: float) -> float:
+        """Empty seconds of all phases up to ``now`` (tick thread only)."""
+        cur = self._open
+        total = sum(self.empty_seconds.values())
+        if cur is not None and not cur[2]:
+            total += now - cur[1]
+        return total
 
     @contextlib.contextmanager
     def span(self, phase: str, **args):
@@ -613,16 +656,20 @@ class TickPhases:
                 self.last = (t_in, t_out)
 
     def snapshot(self) -> dict:
-        """``{"ticks", "seconds": {phase: s}, "entries": {phase: n}}`` with
-        the open phase's elapsed part included, so two snapshots bracket a
-        window exactly. Lock-free: re-read when the tick thread switched
-        phases meanwhile."""
+        """``{"ticks", "seconds": {phase: s}, "empty_seconds": {phase: s},
+        "entries": {phase: n}}`` with the open phase's elapsed part
+        included, so two snapshots bracket a window exactly. Lock-free:
+        re-read when the tick thread switched phases meanwhile."""
         for _ in range(8):
             cur = self._open
             seconds = dict(self.seconds)
+            empty = dict(self.empty_seconds)
             if self._open is cur:
                 break
         if cur is not None:
-            seconds[cur[0]] += max(0.0, time.perf_counter() - cur[1])
+            dt = max(0.0, time.perf_counter() - cur[1])
+            seconds[cur[0]] += dt
+            if not cur[2]:
+                empty[cur[0]] += dt
         return {"ticks": self.ticks, "seconds": seconds,
-                "entries": dict(self.entries)}
+                "empty_seconds": empty, "entries": dict(self.entries)}

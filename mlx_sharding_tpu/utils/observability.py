@@ -153,12 +153,15 @@ class Histogram:
 
 def sum_counter_dicts(per: list) -> dict:
     """Key-wise sum of (nested) dicts of counters — how ReplicaSet and
-    DisaggCoordinator fold their batchers' ``tick_phase_stats()``."""
+    DisaggCoordinator fold their batchers' ``tick_phase_stats()``. A name
+    among them (``path``) stays where all agree and reads ``mixed`` else."""
     out: dict = {}
     for d in per:
         for k, v in d.items():
             if isinstance(v, dict):
                 out[k] = sum_counter_dicts([out.get(k, {}), v])
+            elif isinstance(v, str):
+                out[k] = v if out.get(k, v) == v else "mixed"
             else:
                 out[k] = out.get(k, 0) + v
     return out
@@ -166,16 +169,27 @@ def sum_counter_dicts(per: list) -> dict:
 
 def _render_tick_phases(lines: list, t: dict):
     """The scheduler tick's cumulative account (``tick_phase_stats()``):
-    where its time went, and what became of the decode blocks it
-    dispatched. All counters: a reader takes the delta over its window."""
+    which run-loop it is on, where its time went and how much of it the
+    device had nothing to run, and what became of the decode blocks it
+    dispatched. All but the first are counters: a reader takes the delta
+    over its window."""
 
     def labelled(family: str, label: str, values: dict, fmt: str = "{}"):
         for k in sorted(values):
             lines.append(f'{family}{{{label}="{k}"}} ' + fmt.format(values[k]))
 
-    lines.append("# TYPE mst_tick_phase_seconds_total counter")
+    lines += [
+        # which run-loop the batcher is on (1 = double-buffered async
+        # pipeline, 0 = classic dispatch-then-harvest)
+        "# TYPE mst_sched_async gauge",
+        f"mst_sched_async {int(t['path'] == 'async')}",
+        "# TYPE mst_tick_phase_seconds_total counter",
+    ]
     labelled("mst_tick_phase_seconds_total", "phase", t["phase_seconds"],
              "{:.6f}")
+    lines.append("# TYPE mst_device_empty_seconds_total counter")
+    labelled("mst_device_empty_seconds_total", "phase",
+             t["device_empty_seconds"], "{:.6f}")
     lines.append("# TYPE mst_tick_phase_total counter")
     labelled("mst_tick_phase_total", "phase", t["phase_entries"])
     lines += [
@@ -545,14 +559,9 @@ class ServingMetrics:
                             lines, "mst_queue_wait_seconds",
                             lat.get("queue_wait")
                         )
-                    tick = getattr(b, "tick_timing_stats", lambda: None)()
-                    if tick is not None:
-                        # which run-loop the batcher is on (1 = double-buffered
-                        # async pipeline, 0 = classic dispatch-then-harvest)
-                        lines += [
-                            "# TYPE mst_sched_async gauge",
-                            f"mst_sched_async {int(tick['path'] == 'async')}",
-                        ]
+                        Histogram.render_into(
+                            lines, "mst_join_seconds", lat.get("join")
+                        )
                     phases = getattr(b, "tick_phase_stats", lambda: None)()
                     if phases is not None:
                         _render_tick_phases(lines, phases)
@@ -1090,6 +1099,12 @@ _HELP = {
     "mst_tick_phase_seconds_total":
         "Scheduler tick thread wall time by phase, seconds; the phases "
         "partition the thread's time (harvest_wait = blocked on the device).",
+    "mst_device_empty_seconds_total":
+        "The part of each tick phase's seconds with no served program "
+        "dispatched and unread on the device: host time the chip idles for.",
+    "mst_join_seconds":
+        "Slot assignment to the slot decoding (last prefill chunk's first "
+        "token, or a block import's end), seconds; one a join that got there.",
     "mst_tick_phase_total": "Times the tick entered each phase.",
     "mst_ticks_total": "Scheduler loop iterations.",
     "mst_decode_blocks_dispatched_total": "Plain decode blocks dispatched.",

@@ -99,15 +99,18 @@ def test_metrics_expose_batcher_slots():
     assert "mst_batch_slots" not in ServingMetrics().render()
 
 
-def _fake_tick_phase_stats():
+def _fake_tick_phase_stats(path="async"):
     from mlx_sharding_tpu.tracing import TICK_PHASES
 
     seconds = dict.fromkeys(TICK_PHASES, 0.0)
     seconds.update(harvest_wait=9.5, emit=0.25, kv_import=2.125)
+    empty = dict.fromkeys(TICK_PHASES, 0.0)
+    empty.update(emit=0.125, kv_import=2.125)
     entries = dict.fromkeys(TICK_PHASES, 0)
     entries.update(harvest_wait=7, emit=7, kv_import=1)
     return {
-        "ticks": 9, "phase_seconds": seconds, "phase_entries": entries,
+        "path": path, "ticks": 9, "phase_seconds": seconds,
+        "device_empty_seconds": empty, "phase_entries": entries,
         "blocks_dispatched": 8, "blocks_harvested": 7, "blocks_abandoned": 0,
         "positions_computed": 128, "tokens_emitted": 100,
         "tokens_dropped": {"slot_finished": 12, "abandoned_block": 0},
@@ -126,20 +129,14 @@ def test_metrics_expose_tick_timing():
         def stats(self):
             return (2, 1, 0)
 
-        def tick_timing_stats(self):
-            return {
-                "path": "async",
-                "host_ms_avg": 1.0,
-                "device_blocked_ms_avg": 0.75,
-                "ticks": 7,
-            }
-
         def tick_phase_stats(self):
             return _fake_tick_phase_stats()
 
     text = ServingMetrics(batcher_fn=lambda: _FakeBatcher()).render()
     assert "mst_sched_async 1" in text
     assert 'mst_tick_phase_seconds_total{phase="harvest_wait"} 9.500000' in text
+    assert 'mst_device_empty_seconds_total{phase="harvest_wait"} 0.000000' in text
+    assert 'mst_device_empty_seconds_total{phase="emit"} 0.125000' in text
     assert 'mst_tick_phase_total{phase="emit"} 7' in text
     assert "mst_ticks_total 9" in text
     assert "mst_decode_blocks_dispatched_total 8" in text
@@ -153,8 +150,8 @@ def test_metrics_expose_tick_timing():
     assert "mst_tick_device_blocked_ms" not in text
 
     class _SyncBatcher(_FakeBatcher):
-        def tick_timing_stats(self):
-            return dict(_FakeBatcher.tick_timing_stats(self), path="sync")
+        def tick_phase_stats(self):
+            return _fake_tick_phase_stats(path="sync")
 
     text = ServingMetrics(batcher_fn=lambda: _SyncBatcher()).render()
     assert "mst_sched_async 0" in text
@@ -171,8 +168,8 @@ def test_metrics_expose_tick_timing():
 def test_metrics_expose_kv_residency_and_prefetch():
     """/metrics reports the proactive-residency split: cold-spill/wake
     activity, tier lookup quality, reject reasons, the prefetch-vs-demand
-    resume counters, and the per-tick kv_import stall gauge
-    (spill_stats() / tick_timing_stats() contracts)."""
+    resume counters, and the kv_import phase's stall seconds, all of them
+    with the device empty (spill_stats() / tick_phase_stats() contracts)."""
     from mlx_sharding_tpu.utils.observability import ServingMetrics
 
     class _FakeBatcher:
@@ -192,13 +189,6 @@ def test_metrics_expose_kv_residency_and_prefetch():
                 "prefetch_faults": 1,
             }
 
-        def tick_timing_stats(self):
-            return {
-                "path": "async", "host_ms_avg": 1.0,
-                "device_blocked_ms_avg": 0.5, "ticks": 3,
-                "kv_import_s_total": 2.125,
-            }
-
         def tick_phase_stats(self):
             return _fake_tick_phase_stats()
 
@@ -215,6 +205,7 @@ def test_metrics_expose_kv_residency_and_prefetch():
     assert "mst_kv_prefetch_demand_total 1" in text
     assert "mst_kv_prefetch_faults_total 1" in text
     assert 'mst_tick_phase_seconds_total{phase="kv_import"} 2.125000' in text
+    assert 'mst_device_empty_seconds_total{phase="kv_import"} 2.125000' in text
 
     class _LegacySpill(_FakeBatcher):
         # a ReplicaSet aggregation that predates the residency keys
@@ -256,11 +247,6 @@ def _rich_metrics():
         def stats(self):
             return (2, 1, 3)
 
-        def tick_timing_stats(self):
-            return {"path": "async", "host_ms_avg": 1.0,
-                    "device_blocked_ms_avg": 0.5, "ticks": 3,
-                    "kv_import_s_total": 2.0}
-
         def tick_phase_stats(self):
             return _fake_tick_phase_stats()
 
@@ -277,7 +263,8 @@ def _rich_metrics():
                     "prefetch_faults": 1}
 
         def latency_stats(self):
-            return {"itl": itl.to_dict(), "queue_wait": qw.to_dict()}
+            return {"itl": itl.to_dict(), "queue_wait": qw.to_dict(),
+                    "join": qw.to_dict()}
 
         def fleet_stats(self):
             return {"size": 2, "sticky_hits": 1, "affinity_hits": 2,
@@ -332,7 +319,8 @@ def test_metrics_help_type():
         assert fam in helped, f"sample {name} has no # HELP"
     # the histogram-grade latency families are really histograms
     for fam in ("mst_ttft_seconds", "mst_itl_seconds",
-                "mst_queue_wait_seconds", "mst_disagg_handoff_ms"):
+                "mst_queue_wait_seconds", "mst_join_seconds",
+                "mst_disagg_handoff_ms"):
         assert typed.get(fam) == "histogram", f"{fam} should be a histogram"
         assert f'{fam}_bucket{{le="+Inf"}}' in text
         assert f"{fam}_sum " in text and f"{fam}_count " in text

@@ -745,10 +745,10 @@ def test_async_sched_validation(setup, spec_setup):
     off = ContinuousBatcher(batcher.engine, async_sched="off")
     try:
         assert not off._async
-        assert off.tick_timing_stats()["path"] == "sync"
+        assert off.tick_phase_stats()["path"] == "sync"
     finally:
         off.close()
-    assert batcher.tick_timing_stats()["path"] == "async"
+    assert batcher.tick_phase_stats()["path"] == "async"
 
 
 def test_async_matches_sync_token_exact_matrix():
@@ -941,14 +941,16 @@ def test_async_overcommit_preemption_matches_sync():
     assert streams["on"] == streams["off"]
 
 
-def test_async_tick_timing_stats_populated(setup):
-    """The per-tick host / device-blocked split feeding /metrics: ticks
-    counted, averages finite."""
+def test_async_tick_phase_stats_populated(setup):
+    """The host / device-blocked split feeding /metrics: harvests
+    counted, both sides timed, no import on this path."""
     batcher, _ = setup
     _run(batcher, [2, 9, 5], max_tokens=6)
-    t = batcher.tick_timing_stats()
+    t = batcher.tick_phase_stats()
+    secs = t["phase_seconds"]
     assert t["path"] == "async"
-    assert t["ticks"] > 0
-    assert t["device_blocked_ms_avg"] >= 0.0
-    assert t["host_ms_avg"] >= 0.0
-    assert t["kv_import_s_total"] == 0.0
+    assert t["phase_entries"]["harvest_wait"] > 0
+    assert secs["harvest_wait"] >= 0.0
+    assert sum(s for ph, s in secs.items()
+               if ph not in ("harvest_wait", "idle_wait")) >= 0.0
+    assert secs["kv_import"] == 0.0

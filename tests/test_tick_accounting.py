@@ -3,7 +3,9 @@
 thread's wall time; every position a dispatched block computes is emitted,
 dropped for a reason, or still in flight; every dispatched block is
 harvested, abandoned, or in flight; every pipeline drain is counted under
-its call site; and ``/metrics`` renders the families."""
+its call site; beside each phase's seconds stands the part of them the
+device had nothing to run, and every join that reaches decode is timed
+once; and ``/metrics`` renders the families."""
 
 import threading
 import time
@@ -22,7 +24,10 @@ from mlx_sharding_tpu.parallel.pipeline import PipelineEngine
 from mlx_sharding_tpu.replicas import ReplicaSet
 from mlx_sharding_tpu.scheduler import ContinuousBatcher, _InflightBlock
 from mlx_sharding_tpu.testing import faults
-from mlx_sharding_tpu.utils.observability import ServingMetrics
+from mlx_sharding_tpu.utils.observability import (
+    ServingMetrics,
+    _render_tick_phases,
+)
 from tests.helpers import hard_timeout
 
 TINY = dict(vocab_size=256, hidden_size=32, intermediate_size=64,
@@ -146,11 +151,14 @@ def test_blocks_and_tokens_are_all_accounted_for(engine, mode):
             assert d["cold"] == d["growth"] == d["migrate"] == 0
         else:
             assert sum(s["drains"].values()) == 0  # nothing ever in flight
-        # the derived averages /metrics exports keep their keys
-        t = batcher.tick_timing_stats()
-        assert set(t) == {"path", "host_ms_avg", "device_blocked_ms_avg",
-                          "ticks", "kv_import_s_total"}
-        assert t["ticks"] == s["blocks_harvested"]
+        # the one account /metrics exports keeps its keys
+        assert set(s) == {
+            "path", "ticks", "phase_seconds", "device_empty_seconds",
+            "phase_entries", "blocks_dispatched", "blocks_harvested",
+            "blocks_abandoned", "positions_computed", "tokens_emitted",
+            "tokens_dropped", "drains"}
+        assert s["path"] == ("async" if mode == "on" else "sync")
+        assert set(s["device_empty_seconds"]) == set(tracing.TICK_PHASES)
     finally:
         batcher.close()
     s = batcher.tick_phase_stats()
@@ -324,3 +332,232 @@ def test_phases_suspend_their_outer_phase_and_leftover_is_other():
     frozen = ph.snapshot()
     time.sleep(0.01)
     assert ph.snapshot() == frozen  # a stopped clock does not run
+
+
+# ------------------------------------------ the device's bit, by phase
+def test_empty_seconds_follow_the_device_bit_on_tick_phases_alone():
+    """The rule on its own: a phase's time with the bit clear is empty
+    time; a change of the bit inside a phase closes the open interval, so a
+    phase holds both kinds; a wait entered with the bit set is never empty;
+    a snapshot from inside an open phase carries the part that has passed."""
+    ph = tracing.TickPhases()
+    assert not ph.busy
+    ph.device(True)  # before start(): nothing to close, the bit is kept
+    ph.device(False)
+    ph.start()
+    with ph.tick():
+        with ph.span("admit"):
+            time.sleep(0.02)  # empty
+            with ph.span("assign_slot"):
+                time.sleep(0.01)  # empty
+            with ph.span("prefill_chunk"):
+                time.sleep(0.01)  # empty: before the dispatch
+                ph.device(True)
+                ph.device(True)  # told twice: nothing changes
+                time.sleep(0.03)  # the device has the chunk
+            with ph.span("harvest_wait"):
+                time.sleep(0.02)
+            ph.device(False)  # the read returned: cleared OUTSIDE the wait
+            time.sleep(0.01)  # empty, in admit again
+        with ph.span("idle_wait"):
+            mid = ph.snapshot()
+            time.sleep(0.02)
+    snap = ph.snapshot()
+    secs, empty = snap["seconds"], snap["empty_seconds"]
+    assert set(empty) == set(tracing.TICK_PHASES)
+    assert empty["harvest_wait"] == 0.0 and secs["harvest_wait"] >= 0.02
+    assert empty["assign_slot"] == secs["assign_slot"] >= 0.01
+    assert empty["idle_wait"] == secs["idle_wait"] >= 0.02
+    # (admit resumed under the bit for the instant before device(False))
+    assert 0.03 <= empty["admit"] < secs["admit"]
+    assert 0.01 <= empty["prefill_chunk"] <= secs["prefill_chunk"] - 0.03
+    for phase in tracing.TICK_PHASES:
+        assert 0.0 <= empty[phase] <= secs[phase]
+    assert 0.0 < mid["empty_seconds"]["idle_wait"] == mid["seconds"]["idle_wait"]
+    assert mid["seconds"]["idle_wait"] < secs["idle_wait"] - 0.015
+    ph.stop()
+    frozen = ph.snapshot()
+    time.sleep(0.01)
+    assert ph.snapshot() == frozen
+
+
+def _joins(batcher):
+    return batcher.latency_stats()["join"]
+
+
+@hard_timeout(240)
+@pytest.mark.parametrize("mode", ["on", "off"])
+def test_device_empty_identities_hold_on_a_batcher(engine, mode):
+    """A join while another stream decodes: nobody waits on an empty
+    device, no phase is emptier than it is long, the joins' host work is
+    exposed, and with nothing in any slot the whole idle wait is empty."""
+    batcher = ContinuousBatcher(engine, decode_block=BLOCK, async_sched=mode)
+    try:
+        _drive(batcher)
+        time.sleep(0.05)  # into the idle wait that follows the last finish
+        s = batcher.tick_phase_stats()
+        secs, empty = s["phase_seconds"], s["device_empty_seconds"]
+        assert empty["harvest_wait"] == 0.0 and secs["harvest_wait"] > 0.0
+        for phase in tracing.TICK_PHASES:
+            assert 0.0 <= empty[phase] <= secs[phase], phase
+        # a joiner's slot claim and the host side of its chunk run against
+        # a drained pipeline (sync: against no pipeline at all)
+        assert empty["assign_slot"] > 0.0 and empty["prefill_chunk"] > 0.0
+        assert empty["assign_slot"] == secs["assign_slot"]
+        assert empty["prefill_chunk"] < secs["prefill_chunk"]
+        assert empty["idle_wait"] == secs["idle_wait"] > 0.0
+        # three requests were admitted and none gave up before its first
+        # token (the third's consumer left after two)
+        assert s["phase_entries"]["assign_slot"] == 3
+        j = _joins(batcher)
+        assert j["count"] == 3 and j["sum"] > 0.0
+        text = ServingMetrics(batcher_fn=lambda: batcher).render()
+        assert "mst_join_seconds_count 3" in text
+        assert 'mst_device_empty_seconds_total{phase="harvest_wait"} 0.000000' in text
+        assert f"mst_sched_async {int(mode == 'on')}" in text
+    finally:
+        batcher.close()
+
+
+@hard_timeout(240)
+def test_steady_async_decode_grows_no_empty_seconds(engine):
+    """Between two snapshots taken while one stream decodes under the
+    double-buffered tick, with no join and no drain between them, the host
+    works under a lookahead block: no phase's empty seconds grow."""
+    batcher = ContinuousBatcher(engine, decode_block=BLOCK, async_sched="on")
+    try:
+        assert len(list(batcher.generate_step([3, 4, 5], max_tokens=6))) == 6
+        _settle(batcher)  # compiled; the counters below start from here
+        # every tick waits 30 ms first: the 14 blocks of the stream take
+        # long enough for two snapshots a few ticks apart
+        faults.arm("scheduler.tick", delay=0.03)
+        out, started = [], threading.Event()
+        th = threading.Thread(
+            target=_stream, args=(batcher, [3, 17, 42], 55, out, started))
+        th.start()
+        assert started.wait(timeout=60)  # block 1 harvested, block 2 in flight
+        one = batcher.tick_phase_stats()
+        time.sleep(0.15)
+        two = batcher.tick_phase_stats()
+        faults.disarm()
+        th.join(timeout=120)
+        assert not th.is_alive() and len(out) == 55
+        # the pair brackets steady decode: ticks and blocks went by, and
+        # nothing that drains the pipeline or claims a slot did
+        assert two["ticks"] >= one["ticks"] + 2
+        assert two["blocks_harvested"] >= one["blocks_harvested"] + 2
+        assert two["drains"] == one["drains"]
+        for phase in ("assign_slot", "prefill_chunk", "idle_wait"):
+            assert two["phase_entries"][phase] == one["phase_entries"][phase]
+        assert two["device_empty_seconds"] == one["device_empty_seconds"]
+        assert two["phase_seconds"]["other"] > one["phase_seconds"]["other"]
+    finally:
+        faults.disarm()
+        batcher.close()
+
+
+@hard_timeout(240)
+@pytest.mark.parametrize("mode", ["on", "off"])
+def test_every_join_that_reaches_decode_is_timed_once(engine, mode):
+    """Over-commit with a spill tier: growth preempts, the victim's pages
+    park in the tier and it comes back through a block import. Every slot
+    claim — first admissions and re-admissions, the imported slot among
+    them — is one entry of ``assign_slot`` and one observation of
+    ``mst_join_seconds``."""
+    batcher = ContinuousBatcher(engine, decode_block=BLOCK, async_sched=mode,
+                                overcommit=True, spill_bytes=64 << 20)
+    try:
+        outs = [[], [], []]
+        threads = [
+            threading.Thread(
+                target=_stream,
+                args=(batcher, [3 + i, 17, 42, 5, 9, 11, 2], 50, outs[i]))
+            for i in range(3)
+        ]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+            assert not th.is_alive()
+        assert all(len(o) == 50 for o in outs)
+        _settle(batcher)
+        s = _assert_identities(batcher)
+        spill = batcher.spill_stats()
+        assert batcher.preemptions >= 1 and spill["spill_hits"] >= 1
+        claims = 3 + batcher.preemptions
+        assert s["phase_entries"]["assign_slot"] == claims
+        assert _joins(batcher)["count"] == claims
+        # an import's scatter runs against a drained pipeline
+        empty = s["device_empty_seconds"]
+        assert empty["kv_import"] == s["phase_seconds"]["kv_import"] > 0.0
+        assert empty["harvest_wait"] == 0.0
+    finally:
+        batcher.close()
+
+
+@hard_timeout(240)
+def test_join_cancelled_between_its_chunks_observes_nothing(engine):
+    """A request given up between two of its prefill chunks claimed a slot
+    and never decoded: one more ``assign_slot``, no join observed; and the
+    chunk it left dispatched does not keep the idle wait 'busy'."""
+    batcher = ContinuousBatcher(engine, decode_block=BLOCK, async_sched="on")
+    try:
+        assert len(list(batcher.generate_step([3, 4, 5], max_tokens=6))) == 6
+        _settle(batcher)
+        before = batcher.tick_phase_stats()
+        assert _joins(batcher)["count"] == 1
+        faults.arm("scheduler.tick", delay=0.05)  # time between the chunks
+        out = []
+        th = threading.Thread(  # 20 tokens: three chunks of 8
+            target=_stream, args=(batcher, list(range(1, 21)), 5, out))
+        th.start()
+        deadline = time.monotonic() + 60
+        while time.monotonic() < deadline:
+            reqs = [r for r in batcher._slots
+                    if r is not None and 0 < r.prefill_pos < r.prompt.size]
+            if reqs:
+                reqs[0].cancelled = True  # what a consumer's exit sets
+                break
+            time.sleep(0.002)
+        th.join(timeout=120)
+        faults.disarm()
+        assert not th.is_alive() and out == []
+        _settle(batcher)
+        time.sleep(0.05)
+        s = batcher.tick_phase_stats()
+        assert (s["phase_entries"]["assign_slot"]
+                == before["phase_entries"]["assign_slot"] + 1)
+        assert (s["phase_entries"]["prefill_chunk"]
+                > before["phase_entries"]["prefill_chunk"])
+        assert _joins(batcher)["count"] == 1
+        assert (s["device_empty_seconds"]["idle_wait"]
+                == s["phase_seconds"]["idle_wait"])
+        # the batcher serves on, and the next join is counted
+        assert len(list(batcher.generate_step([3, 4, 5], max_tokens=5))) == 5
+        assert _joins(batcher)["count"] == 2
+    finally:
+        faults.disarm()
+        batcher.close()
+
+
+@hard_timeout(240)
+def test_device_empty_seconds_render_summed_over_replicas(engine):
+    batcher = ContinuousBatcher(engine, decode_block=BLOCK, async_sched="on")
+    try:
+        _drive(batcher)
+        fleet = ReplicaSet([batcher, batcher])  # the same account, twice
+    finally:
+        batcher.close()  # the clock stops: both reads below see one account
+    one, both = batcher.tick_phase_stats(), fleet.tick_phase_stats()
+    assert both["path"] == one["path"] == "async"
+    lines: list = []
+    _render_tick_phases(lines, both)
+    for phase in tracing.TICK_PHASES:
+        assert both["device_empty_seconds"][phase] == pytest.approx(
+            2 * one["device_empty_seconds"][phase])
+        assert (f'mst_device_empty_seconds_total{{phase="{phase}"}} '
+                f'{2 * one["device_empty_seconds"][phase]:.6f}') in lines
+    assert one["device_empty_seconds"]["assign_slot"] > 0.0
+    assert "mst_sched_async 1" in lines
+    # the joins' histogram merges like the other two
+    assert fleet.latency_stats()["join"]["count"] == 2 * _joins(batcher)["count"] == 6

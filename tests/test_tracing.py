@@ -504,6 +504,61 @@ def test_trace_profile_puts_tick_spans_on_the_profilers_clock(tmp_path):
     assert len(nested) >= len(blocks + chunks) - 1 and set(nested) == {1}
 
 
+@hard_timeout(300)
+def test_trace_profile_names_the_join_and_the_device_bit(tmp_path):
+    """A request joining while another decodes, with ``jax.profiler`` open:
+    ``mst.tick`` carries the cumulative device-empty seconds at its entry,
+    the drain's ``mst.harvest_wait`` says why it drained, and
+    ``mst.assign_slot`` shares its ``rid`` with the join's chunks."""
+    model = LlamaModel(LlamaConfig(**TINY))
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    tracing.configure("on", profile=True)
+    batcher = _mk_batcher(model, params, 0, async_sched="on")
+    try:
+        list(batcher.generate_step([3, 4, 5], max_tokens=4))  # compiles
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            first = batcher.generate_step(
+                [3, 4, 5], max_tokens=40, _trace=tracing.begin("r-first"))
+            head = [next(first) for _ in range(5)]  # decoding, a block ahead
+            joined = list(batcher.generate_step(
+                [9, 1, 4, 7, 2, 6, 8, 3, 5, 1], max_tokens=6,
+                _trace=tracing.begin("r-join")))
+            rest = list(first)
+        finally:
+            jax.profiler.stop_trace()
+        snap = batcher.tick_phase_stats()  # later than every captured tick
+        assert len(head) + len(rest) == 40 and len(joined) == 6
+    finally:
+        batcher.close()
+    spans = _host_spans(tmp_path)
+    # the tick's cumulative empty seconds never fall, rise over the join
+    # (its slot claim and the host side of its chunks ran against a drained
+    # pipeline) and stay within the account the batcher reports afterwards
+    empties = [float(s["empty"]) for s in spans["mst.tick"]]
+    assert empties == sorted(empties) and empties[-1] > empties[0]
+    assert empties[-1] <= sum(snap["device_empty_seconds"].values())
+    claims = {s["rid"]: s for s in spans["mst.assign_slot"]}
+    assert set(claims) == {"r-first", "r-join"}
+    assert int(claims["r-join"]["slot"]) != int(claims["r-first"]["slot"])
+    assert int(claims["r-join"]["pages"]) == 2  # 10 + 6 tokens, 8 a page
+    assert int(claims["r-join"]["reused"]) == 0
+    assert [s["rid"] for s in spans["mst.prefill_chunk"]] == [
+        "r-first", "r-join", "r-join"]
+    # the join drained the lookahead block (once to admit, once before its
+    # second chunk); a steady harvest names no drain
+    drains = [s.get("drain") for s in spans["mst.harvest_wait"]]
+    assert {"admit", "prefilling"} <= set(drains) <= {
+        None, "admit", "prefilling", "idle"}
+    assert drains.count(None) >= 3
+    drained = [s for s in spans["mst.harvest_wait"] if s.get("drain") == "admit"]
+    claim = claims["r-join"]
+    assert any(d["_t1"] <= claim["_t0"] for d in drained)
+
+
 @hard_timeout(240)
 def test_trace_off_constructs_no_annotation(monkeypatch):
     """``--trace off`` (and ``--trace on`` without ``--trace-profile``):
@@ -533,4 +588,5 @@ def test_trace_off_constructs_no_annotation(monkeypatch):
         list(batcher.generate_step([3, 4, 5], max_tokens=4))
     finally:
         batcher.close()
-    assert {"mst.tick", "mst.decode_block", "mst.harvest_wait"} <= set(built)
+    assert {"mst.tick", "mst.assign_slot", "mst.decode_block",
+            "mst.harvest_wait"} <= set(built)
