@@ -22,6 +22,13 @@ DeepSeek-V2's packed expert stacks ``w_gate``, ``w_up``, ``w_down`` — which
 stay whole ``(L, E, …)`` beside the layer's index and are read in place by
 ``(layer, expert)``. No other model names any, so their scans are as they
 were.
+
+Where the cache rides: ``scan_layers`` takes it as ``xs`` and returns it as
+``ys`` (a layer's contiguous buffer is copied out of the stack and stacked
+back: prefill chunks, the gathered-page decode step, the dense engines);
+``scan_layers_carried`` keeps a page pool whole in the carry for a body
+that writes a few rows and attends where the pool lies (the ragged decode
+step, ``parallel/pipeline.py:_build_smapped_ragged``).
 """
 
 from __future__ import annotations
@@ -51,6 +58,17 @@ def dense_init(key, in_dim: int, out_dim: int, dtype, scale: float | None = None
 LAYER_INDEX = "layer"
 
 
+def _split_in_place(layer_params, in_place):
+    """``(scanned, whole, index)``: the leaves that ride a layer scan, the
+    ones named ``in_place`` that stay whole beside it, and the layers'
+    indices to read those by (None where nothing is read in place)."""
+    whole = {name: layer_params[name] for name in in_place}
+    if not whole:
+        return layer_params, whole, None
+    scanned = {n: w for n, w in layer_params.items() if n not in whole}
+    return scanned, whole, jnp.arange(jax.tree.leaves(whole)[0].shape[0])
+
+
 def scan_layers(layer_fn, h, layer_params, k, v, mask=None, in_place=()):
     """``lax.scan`` over a stacked layer group with optional per-layer
     active masking.
@@ -71,12 +89,13 @@ def scan_layers(layer_fn, h, layer_params, k, v, mask=None, in_place=()):
     over whole, the scan counts the layers, and the body's ``p`` holds them
     as ``(L, …)`` stacks beside ``p[LAYER_INDEX]``, the index to read them
     by where they lie (``ops.moe.apply_experts(layer=…)``). A padding slot's
-    index is still a valid row, of zero parameters."""
-    whole = {name: layer_params[name] for name in in_place}
-    index = None
-    if whole:
-        layer_params = {n: w for n, w in layer_params.items() if n not in whole}
-        index = jnp.arange(jax.tree.leaves(whole)[0].shape[0])
+    index is still a valid row, of zero parameters.
+
+    The cache rides this scan as ``xs`` and comes back as ``ys``: every
+    layer's buffer is copied out of the stack and stacked back (the module
+    docstring says which bodies want that and which take
+    :func:`scan_layers_carried`)."""
+    layer_params, whole, index = _split_in_place(layer_params, in_place)
 
     def body(h, xs):
         # ``m`` and ``i`` are None (empty pytrees, no scan operands) when
@@ -99,6 +118,40 @@ def scan_layers(layer_fn, h, layer_params, k, v, mask=None, in_place=()):
     # back; the layer body opens deeper scopes for everything it does
     with jax.named_scope("mst.kv_pool.regroup"):
         h, (k, v) = jax.lax.scan(body, h, xs)
+    return h, k, v
+
+
+def scan_layers_carried(layer_fn, h, layer_params, k, v, rows, mask=None,
+                        in_place=()):
+    """:func:`scan_layers` with the cache in the scan's CARRY: ``k`` and
+    ``v`` (each a pytree holding EVERY layer's cache: a page pool) enter the
+    scan whole, go through each layer whole and leave whole, never sliced
+    per layer and never stacked back. ``rows`` is an (L,) int array, each
+    layer's place in the pool, scanned beside the parameters.
+
+    ``layer_fn(h, p, k, v, row, keep) -> (h, k, v)`` writes the layer's new
+    rows into the pools and reads what it attends to where it lies, both by
+    ``row``. ``keep`` is the layer's entry of ``mask`` (None without one): a
+    masked-out padding layer's hidden state is dropped here, but keeping its
+    writes out of the pool's live rows is ``layer_fn``'s own to do, on the
+    few rows it writes (a ``where`` over the pool would read and write all
+    of it, every layer). ``in_place`` as in :func:`scan_layers`."""
+    layer_params, whole, index = _split_in_place(layer_params, in_place)
+
+    def body(carry, xs):
+        h, k, v = carry
+        p, row, m, i = xs
+        if whole:
+            p = {**p, **whole, LAYER_INDEX: i}
+        h2, k, v = layer_fn(h, p, k, v, row, m)
+        return (h2 if m is None else jnp.where(m, h2, h), k, v), None
+
+    # what is left of the scan's own work is the slice of each small
+    # parameter leaf out of its stack
+    with jax.named_scope("mst.kv_pool.regroup"):
+        (h, k, v), _ = jax.lax.scan(
+            body, (h, k, v), (layer_params, rows, mask, index)
+        )
     return h, k, v
 
 

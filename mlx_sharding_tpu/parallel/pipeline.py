@@ -1349,11 +1349,18 @@ class PipelineEngine:
         Rides the sp_layer injected-attention hook with M as the batch dim
         (offsets become an (M,)-vector — apply_rope's per-row form), so one
         forward streams the weights once across all slots, like body_s1.
-        Gated to S==1/tp=1/ep=1/B==1/supports_sp by the constructor."""
+        Gated to S==1/tp=1/ep=1/B==1/supports_sp by the constructor.
+
+        Nor is the pool itself moved. A model without per-slot state
+        carries it whole through its layer scans
+        (``models.base.scan_layers_carried``), a layer an offset into the
+        page table; a model with state walks its own layers and is handed
+        ``pool_attn``; under a share map the group-sized pool is carried
+        (``_scan_layers_shared``)."""
         model, M, B = self.model, self.microbatches, self.batch
         page = self.page_size
         cdt, kv_quant = self.cache_dtype, self.kv_quant
-        from mlx_sharding_tpu.models.base import scan_layers
+        from mlx_sharding_tpu.models.base import scan_layers_carried
         from mlx_sharding_tpu.ops.paged_attention import paged_attention
 
         def body(layer_params, masks, vparts, shared, tokens, k, v,
@@ -1361,13 +1368,23 @@ class PipelineEngine:
             layer_params = jax.tree.map(lambda x: x[0], layer_params)
             masks = jax.tree.map(lambda x: x[0], masks)
             vparts = jax.tree.map(lambda x: x[0], vparts)
-            # (L, P+1, B, page, H, D) — int8 pools are {d, s} leaf pairs
-            k = jax.tree.map(lambda x: x[0], k)
-            v = jax.tree.map(lambda x: x[0], v)
+            share = self._share_active
+            if state is None and not share:
+                # every layer's pool as pages, (1, L, P+1, B, page, H, D) →
+                # (L * (P+1), page, H, D): B == 1, a merge of leading
+                # dimensions, no copy. int8 pools are {d, s} leaf pairs
+                stacked = k, v
+                k, v = jax.tree.map(
+                    lambda x: x.reshape(-1, *x.shape[4:]), stacked
+                )
+            else:  # (L, P+1, B, page, H, D)
+                k = jax.tree.map(lambda x: x[0], k)
+                v = jax.tree.map(lambda x: x[0], v)
             # the barrier keeps the compiler from moving the first layer's
             # read of the pool in front of this reshape: read and in-place
             # write then name two views of one buffer, and the whole pool is
-            # copied in and out of the decode block's carry every step
+            # copied in and out of the decode block's carry every step (the
+            # page pool's view above compiles the same with and without one)
             state = jax.lax.optimization_barrier(
                 jax.tree.map(lambda x: x[0], state)
             )
@@ -1398,7 +1415,8 @@ class PipelineEngine:
             # embed straight to (M, T=1, hidden)
             h = self._vs_embed(s, vparts, tokens).astype(cdt)
 
-            def pool_attn(k_buf, v_buf, ring=None, scope="mst.attn.core"):
+            def pool_attn(k_buf, v_buf, ring=None, scope="mst.attn.core",
+                          layer=None, keep=None):
                 """``(attn_fn, done)`` over one layer's pool: ``attn_fn``
                 scatters the M new rows and attends over the pool in place;
                 the updated pool escapes through ``done`` (sp_decode.py's
@@ -1408,25 +1426,46 @@ class PipelineEngine:
                 layer's rings out and putting them back would copy them
                 (0.35 GB each way a layer at 32 slots of 5120 rows), so the
                 pool is viewed as pages where it lies and the layer is an
-                offset into the ring table. ``scope`` names the attention
-                call."""
+                offset into the ring table. ``layer``: the buffers are the
+                WHOLE page pool, every layer's, already viewed as pages
+                ``(L * (P+1), page, H, D)``, and this is its (traced) row
+                ``layer``: the same offset, into the page table. ``keep``
+                false (a padding layer) sends the M writes to the layer's
+                scratch page. ``scope`` names the attention call."""
                 done = {}
                 quant = kv_quant and ring is None
-                if ring is None:
-                    ids, tbl = page_ids, rows
-                else:
+                as_pages = as_given = attended = lambda x: x  # noqa: E731
+                if layer is not None:
+                    n_pages = self.pool_pages + 1
+                    first = layer * n_pages
+                    ids = page_ids if keep is None else jnp.where(
+                        keep, page_ids, self.pool_pages
+                    )
+                    ids, tbl = ids + first, rows + first
+                    if jax.tree.leaves(k_buf)[0].shape[-2] > 1:
+                        # rows that keep their heads apart reach the
+                        # kernel's (page, Hkv * D) block through a relayout
+                        # of the pool it is handed (a TPU tiles the two
+                        # minor dimensions): hand it this layer's pages, so
+                        # that the slice and the relayout are one copy of
+                        # one layer and not one of all L, every layer
+                        tbl = rows
+                        attended = lambda x: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+                            x, first, n_pages
+                        )
+                elif ring is not None:  # → (layers * (M+1) * R, page, H, D)
                     first = ring * (M + 1) * ring_pages
                     ids, tbl = ring_ids + first, ring_rows_t + first
+                    as_pages = lambda x: x.reshape(-1, page, *x.shape[3:])  # noqa: E731
+                    as_given = lambda x: x.reshape(k_buf.shape)  # noqa: E731
+                else:  # drop the B == 1 axis per leaf → (P+1, page, H, D)
+                    ids, tbl = page_ids, rows
+                    as_pages = lambda x: x[:, 0]  # noqa: E731
+                    as_given = lambda x: x[:, None]  # noqa: E731
 
                 def attn_fn(q, k_new, v_new, logit_softcap=None,
                             sliding_window=None, values_from_k=None,
                             **layout):
-                    if ring is not None:  # → (layers * (M+1) * R, page, H, D)
-                        as_pages = lambda x: x.reshape(-1, page, *x.shape[3:])  # noqa: E731
-                        as_given = lambda x: x.reshape(k_buf.shape)  # noqa: E731
-                    else:  # drop the B == 1 axis per leaf → (P+1, page, H, D)
-                        as_pages = lambda x: x[:, 0]  # noqa: E731
-                        as_given = lambda x: x[:, None]  # noqa: E731
                     kl = jax.tree.map(as_pages, k_buf)
                     vl = jax.tree.map(as_pages, v_buf)
 
@@ -1446,6 +1485,8 @@ class PipelineEngine:
                         done["k"] = jax.tree.map(as_given, kl)
                         done["v"] = jax.tree.map(as_given, vl)
                     with jax.named_scope(scope):
+                        kl = jax.tree.map(attended, kl)
+                        vl = jax.tree.map(attended, vl)
                         out = paged_attention(
                             q[:, 0],
                             kl["d"] if quant else kl,
@@ -1463,9 +1504,10 @@ class PipelineEngine:
                 return attn_fn, done
 
             def make_layer(g):
-                def layer(h, p, k_buf, v_buf):
-                    # updated pool escapes through ``done`` as the scan ys
-                    attn_fn, done = pool_attn(k_buf, v_buf)
+                def layer(h, p, k_buf, v_buf, row=None, keep=None):
+                    # one layer's (a share group's) pool in and out; with
+                    # ``row`` the whole pool as pages, this layer at ``row``
+                    attn_fn, done = pool_attn(k_buf, v_buf, layer=row, keep=keep)
                     h2, _, _ = model.sp_layer(p, h, offset_m, attn_fn, group=g)
                     return h2, done["k"], done["v"]
 
@@ -1494,17 +1536,22 @@ class PipelineEngine:
                     ),
                 )
 
-            # per-group scans over the stacked layer sub-trees: unshared,
-            # the pool slices to each group's layer range (run_layers'
-            # layout, pool as scan xs/ys); under a share map the G-sized
-            # pool rides the scan carry instead and layers dynamic-index
-            # their share-group's buffer out of it
-            share = self._share_active
+            # per-group scans over the stacked layer sub-trees, the pool in
+            # each scan's CARRY. Unshared, it is the view as pages made
+            # above, and a layer is its row's offset into the page table: it
+            # scatters its M rows and attends where the pool lies. A pool
+            # cut into the groups' layer ranges, scanned as xs/ys and
+            # concatenated is MOVED: at 599 MB (14 layers of
+            # DeepSeek-V2-Lite's latent rows, 144 pages) two range slices,
+            # a slice, a select and a stack-back a layer, and the
+            # concatenate's pad + f32 maximum over the whole were a third
+            # of a 20.6 ms step that computed nothing. Under a share map
+            # the G-sized pool is carried and a layer dynamic-indexes its
+            # share-group's buffer out of it.
             if share:
                 gids_all = jnp.asarray(self.kv_share.group_of, jnp.int32)
                 own_all = jnp.asarray(self.kv_share.owner_mask)
             lo = 0
-            k_parts, v_parts = [], []
             for g in model.sp_groups():
                 if g is not None and g not in layer_params:
                     continue
@@ -1518,34 +1565,24 @@ class PipelineEngine:
                         mask_g,
                     )
                 else:
-                    with jax.named_scope("mst.kv_pool.regroup"):
-                        k_in = jax.tree.map(lambda x: x[lo : lo + n_g], k)
-                        v_in = jax.tree.map(lambda x: x[lo : lo + n_g], v)
-                    h, k_g, v_g = scan_layers(
-                        make_layer(g), h, stack, k_in, v_in, mask_g,
+                    h, k, v = scan_layers_carried(
+                        make_layer(g), h, stack, k, v,
+                        jnp.arange(lo, lo + n_g), mask_g,
                         in_place=model.scan_in_place(g, stack),
                     )
-                    k_parts.append(k_g)
-                    v_parts.append(v_g)
                 lo += n_g
-            if not share:
-                cat = lambda *xs: (  # noqa: E731
-                    jnp.concatenate(xs, axis=0) if len(xs) > 1 else xs[0]
+            if share:
+                k, v = jax.tree.map(lambda x: x[None], (k, v))
+            else:
+                k, v = jax.tree.map(
+                    lambda x, was: x.reshape(was.shape), (k, v), stacked
                 )
-                with jax.named_scope("mst.kv_pool.regroup"):
-                    k = jax.tree.map(cat, *k_parts)
-                    v = jax.tree.map(cat, *v_parts)
 
             out = jnp.where(active[:, None, None], h, 0).astype(cdt)
             out = jax.lax.psum(out, AXIS_PP)  # identity at S=1; keeps the
             # body shape identical to the gather one
             logits = self._vs_head(shared, vparts, out)  # (M, B, V) f32
-            return (
-                logits,
-                jax.tree.map(lambda x: x[None], k),
-                jax.tree.map(lambda x: x[None], v),
-                None,
-            )
+            return logits, k, v, None
 
         spec_stage, spec_rep = P(AXIS_PP), P()
         return jax.shard_map(

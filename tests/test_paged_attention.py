@@ -284,6 +284,61 @@ def test_latent_kernel_matches_xla(case, int8):
     assert np.abs(want[1:]).max() > 0.5
 
 
+# (hq, hkv, dk, dv, values_from_k, int8): every layer's pool in ONE array
+POOLED = {
+    "latent": (4, 1, 24, 1, 16, False),
+    "latent-int8": (4, 1, 24, 1, 16, True),
+    "gqa-heads-apart": (4, 2, 16, 16, None, False),
+    "gqa-heads-apart-int8": (4, 2, 16, 16, None, True),
+}
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["xla", "kernel"])
+@pytest.mark.parametrize("case", list(POOLED))
+def test_whole_pool_as_pages_with_a_traced_layer_offset(case, interpret):
+    """The ragged decode body's form: an ``(L, P+1, page, H, D)`` pool viewed
+    as ``L * (P+1)`` pages, layer ``l`` the page table offset by a TRACED
+    ``l * (P+1)`` (a scan index), equals the call on that layer's own slice
+    with the table as it is — a slot at a page boundary, an empty slot,
+    int8 ``{d, s}`` pools viewed leaf by leaf."""
+    hq, hkv, dk, dv, vfk, int8 = POOLED[case]
+    layers, lengths = 3, [0, 1, PAGE, 19, SPG * PAGE]
+    m = len(lengths)
+    n_pages = m * SPG + 1
+    rng = np.random.default_rng(6)
+    q = jnp.asarray(rng.standard_normal((m, hq, dk), np.float32))
+    k = jnp.asarray(rng.standard_normal((layers, n_pages, PAGE, hkv, dk), np.float32))
+    v = (
+        jnp.zeros((layers, n_pages, PAGE, 1, 1), jnp.float32) if vfk
+        else jnp.asarray(rng.standard_normal((layers, n_pages, PAGE, hkv, dv), np.float32))
+    )
+    if int8:
+        k, v = quantize_kv_rows(k), quantize_kv_rows(v)
+    tables = jnp.asarray(_own_pages(lengths, PAGE, SPG))
+    lens = jnp.asarray(lengths, jnp.int32)
+
+    def attend(k, v, tables):
+        scales = dict(k_scale=k["s"], v_scale=v["s"]) if int8 else {}
+        return paged_attention(
+            q, k["d"] if int8 else k, v["d"] if int8 else v, tables, lens,
+            dk ** -0.5, values_from_k=vfk, interpret=interpret, **scales,
+        )
+
+    as_pages = lambda x: x.reshape(-1, *x.shape[2:])  # noqa: E731
+    kp, vp = jax.tree.map(as_pages, (k, v))
+    got = jax.jit(lambda: jax.lax.map(
+        lambda l: attend(kp, vp, tables + l * n_pages), jnp.arange(layers)
+    ))()
+    assert got.shape == (layers, m, hq, vfk or dv)
+    for l in range(layers):
+        want = attend(*jax.tree.map(lambda x: x[l], (k, v)), tables)
+        np.testing.assert_allclose(
+            np.asarray(got[l]), np.asarray(want), atol=1e-6, rtol=1e-6
+        )
+        assert not np.asarray(got[l])[0].any()  # the empty slot
+    assert np.abs(np.asarray(got[0]) - np.asarray(got[1])).max() > 0.1
+
+
 # (dk, dv, softcap, window, values_from_k, hkv) -> the kernel on a chip?
 ELIGIBLE = {
     "gqa-128": ((128, 128, None, None, None, 8), True),

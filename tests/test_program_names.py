@@ -345,9 +345,10 @@ def _scanned(eqn):
 @hard_timeout(420)
 def test_packed_expert_stacks_do_not_ride_the_layer_scan(monkeypatch):
     """The tiny packed DeepSeek decode block, traced as on a TPU: the MoE
-    layer scan's ``xs`` hold the small leaves, the pool and the layer
-    counter — no leaf of the expert stacks — and the expert-indexed kernel's
-    ``q`` operand is the whole stack as ``(L*E, out, words)``."""
+    layer scan's ``xs`` hold the small leaves, the layer's row in the pool
+    and the layer counter — no leaf of the expert stacks — and the
+    expert-indexed kernel's ``q`` operand is the whole stack as
+    ``(L*E, out, words)``."""
     from mlx_sharding_tpu.models import build_model
     from mlx_sharding_tpu.ops.quant import quantize_jax
 
@@ -404,6 +405,78 @@ def test_packed_expert_stacks_do_not_ride_the_layer_scan(monkeypatch):
         )
         whole = [v.aval for v in layer_scan.invars[: layer_scan.params["num_consts"]]]
         assert sum(a.shape == (n_moe, n_exp, width, words) for a in whole) == 3
+
+
+DSV2_TINY = dict(
+    model_type="deepseek_v2", vocab_size=256, hidden_size=32,
+    intermediate_size=64, moe_intermediate_size=16, num_hidden_layers=3,
+    num_attention_heads=4, num_key_value_heads=4, kv_lora_rank=16,
+    q_lora_rank=None, qk_rope_head_dim=8, qk_nope_head_dim=16,
+    v_head_dim=12, n_routed_experts=4, n_shared_experts=1,
+    num_experts_per_tok=2, first_k_dense_replace=1,
+    mla_cache_mode="compressed",
+)
+
+
+@pytest.mark.parametrize(
+    "config", [DSV2_TINY, dict(model_type="llama", **TINY)],
+    ids=["latent-two-groups", "heads-apart"],
+)
+def test_ragged_block_carries_the_pool_through_its_layer_scans(config):
+    """What a CPU run can see of the ragged decode step's cost: in the
+    jaxpr of ``block`` no ``slice``, ``concatenate`` or ``pad`` produces a
+    layer's pool or more, the pool ``(L * (P+1), page, H, D)`` enters and
+    leaves every layer scan through its CARRY and no scan has a pool among
+    its ``xs`` or ``ys``. A ``dynamic_slice`` of one layer's pages feeds the
+    attention call only where the rows keep their heads apart (the call
+    relayouts what it is handed), never more than a layer."""
+    from mlx_sharding_tpu.models import build_model
+
+    model, _ = build_model(config)
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    eng = PipelineEngine(
+        model, params, pipeline_mesh(1), microbatches=3, max_seq=64,
+        cache_dtype=jnp.float32, prefill_chunk=8, pool_pages=10, page_size=8,
+        paged_attention="ragged",
+    )
+    b = ContinuousBatcher(eng, decode_block=3)
+    try:
+        jaxpr = jax.make_jaxpr(b._decode_block_prog(False))(
+            eng.layer_params, eng.layer_masks, eng.vocab_parts,
+            eng.shared_params, b.last_tok, b.cache, b.active, b.recent,
+            b.keys, b.sp, b.rep_sizes, b.table,
+        )
+        pool = b.cache.k  # (S, L, P+1, 1, page, H, D)
+    finally:
+        b.close()
+    layers, pages = pool.shape[1], pool.shape[2]
+
+    def pool_sized(aval):  # a layer's pool or more (tiny weights are larger)
+        return (
+            aval.shape[-3:] == pool.shape[-3:] and aval.size >= pool.size // layers
+        )
+
+    eqns = [eqn for eqn, _ in _walk(jaxpr.jaxpr)]
+    sliced = [
+        (e.primitive.name, v.aval.shape) for e in eqns for v in e.outvars
+        if e.primitive.name in ("slice", "dynamic_slice", "concatenate", "pad")
+        and pool_sized(v.aval)
+    ]
+    n_scans = len(model.sp_groups())  # dense and MoE (DeepSeek), or one stack
+    apart = pool.shape[-2] > 1  # then K's and V's pages of the layer, a scan
+    assert sliced == (
+        [("dynamic_slice", (pages, *pool.shape[4:]))] * 2 * n_scans if apart else []
+    )
+    carrying = 0
+    for e in eqns:
+        if e.primitive.name != "scan":
+            continue
+        n_c, n_k = e.params["num_consts"], e.params["num_carry"]
+        ys = [v.aval for v in e.outvars[n_k:]]
+        assert not any(pool_sized(a) for a in _scanned(e) + ys)
+        carry = [v.aval.shape for v in e.invars[n_c : n_c + n_k]]
+        carrying += (layers * pages, *pool.shape[4:]) in carry
+    assert carrying == n_scans
 
 
 @pytest.mark.parametrize("family", ["llama", "mixtral"])

@@ -639,6 +639,103 @@ def test_ragged_mixed_length_cb_matches_serial():
         batcher.close()
 
 
+# ----------------------------- the pool in the layer scan's carry (ISSUE 40)
+DSV2_TINY = dict(
+    vocab_size=256, hidden_size=32, intermediate_size=64,
+    moe_intermediate_size=16, num_hidden_layers=3,
+    num_attention_heads=4, num_key_value_heads=4, kv_lora_rank=16,
+    q_lora_rank=None, qk_rope_head_dim=8, qk_nope_head_dim=16,
+    v_head_dim=12, n_routed_experts=4, n_shared_experts=1,
+    num_experts_per_tok=2, first_k_dense_replace=1,
+    mla_cache_mode="compressed",
+)
+
+# (family, engine keywords, mask the last layer): a dense + MoE stack with a
+# latent pool, int8 {d, s} pools in the latent and in the heads-apart
+# layout, and a padding layer
+CARRIED = {
+    "two-groups": ("dsv2", {}, False),
+    "two-groups-int8-pages": ("dsv2", {"kv_dtype": "int8"}, False),
+    "gqa-int8-pages": ("llama", {"kv_dtype": "int8"}, False),
+    "two-groups-padding-layer": ("dsv2", {}, True),
+}
+
+
+def _carried_model(family, layers=None):
+    from mlx_sharding_tpu.config import DeepseekV2Config
+    from mlx_sharding_tpu.models.deepseek_v2 import DeepseekV2Model
+
+    if family == "llama":
+        model = LlamaModel(LlamaConfig(**TINY))
+    else:
+        model = DeepseekV2Model(DeepseekV2Config(
+            **{**DSV2_TINY, "num_hidden_layers": layers or 3}
+        ))
+    return model, model.init_params(jax.random.PRNGKey(0), jnp.float32)
+
+
+@pytest.mark.parametrize("case", list(CARRIED))
+def test_ragged_carried_pool_matches_gather_and_serial(case):
+    """The ragged body's layer scans CARRY the whole page pool (a layer is
+    an offset into the page table): mixed-length concurrent streams are
+    token-exact against the gather body, whose scans slice the pool per
+    layer, and against every request's solo serial run. A masked-out
+    padding layer changes neither the hidden state (the streams are those
+    of the model without that layer) nor a live page of its pool rows."""
+    family, kw, padded = CARRIED[case]
+    model, params = _carried_model(family)
+    rng = np.random.default_rng(5)
+    jobs = []
+    for i, plen in enumerate([2, 8, 11, 19]):  # straddle page boundaries
+        prompt = [int(t) for t in rng.integers(1, 256, size=plen)]
+        jobs.append((prompt, dict(max_tokens=5 + 2 * i, seed=i,
+                                  temperature=0.6 if i % 2 else 0.0)))
+    streams, pools = {}, {}
+    for path in ("ragged", "gather"):
+        eng = PipelineEngine(
+            model, params, pipeline_mesh(1), microbatches=3, max_seq=64,
+            cache_dtype=jnp.float32, prefill_chunk=8,
+            pool_pages=10, page_size=8, paged_attention=path, **kw,
+        )
+        assert eng.paged_attention == path
+        if padded:  # the last MoE slot is padding: its parameters unused
+            eng.layer_masks = {
+                **eng.layer_masks,
+                "moe": jax.device_put(
+                    jnp.asarray([[True, False]]), eng.layer_masks["moe"].sharding
+                ),
+            }
+        batcher = ContinuousBatcher(eng, decode_block=3)
+        try:
+            streams[path], _ = _concurrent(batcher, jobs)
+            if path == "gather":  # one request at a time
+                streams["solo"] = [_run(batcher, p, **k) for p, k in jobs]
+            pools[path] = jax.tree.map(
+                np.asarray, (batcher.cache.k, batcher.cache.v)
+            )
+        finally:
+            batcher.close()
+    assert streams["ragged"] == streams["gather"] == streams["solo"]
+    if not kw:  # float pages: the single-request generator is the reference
+        ref_model, ref_params = model, params
+        if padded:  # the same stack without its last layer
+            ref_model, _ = _carried_model(family, layers=2)
+            ref_params = {**params, "layers": {
+                "dense": params["layers"]["dense"],
+                "moe": jax.tree.map(lambda x: x[:1], params["layers"]["moe"]),
+            }}
+        ref = Generator(
+            ref_model, ref_params, max_seq=64, cache_dtype=jnp.float32,
+            prefill_chunk=8,
+        )
+        assert streams["ragged"] == [_run(ref, p, **k) for p, k in jobs]
+    # K, (S, L, P+1, 1, page, H, D): the last page of a layer is its scratch
+    for leaf in jax.tree.leaves(pools["ragged"][0]):
+        live = leaf[0, :, :-1]
+        assert live[:-1].any()
+        assert live[-1].any() != padded  # a padding layer wrote no live page
+
+
 def test_kv_read_accounting_ragged_below_gather():
     """Same short run on both paths: the ragged analytic KV-bytes-read must
     come in strictly below gather's (gather always reads every slot's full
