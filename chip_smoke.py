@@ -503,6 +503,58 @@ def child_kernels(seed: int, rehearse: bool) -> None:
               (q, k_pool, v_pool, jnp.asarray(table, jnp.int32), lens), ATTN_ATOL)
         del k_pool, v_pool
 
+    # ---- the zaya1-8b-bf16-pp2ep2 cell's geometry: 24 slots, 8 query heads on
+    # 2 K/V heads of 128 with merged rows, 512-token pages, every slot 8k-12k
+    # tokens into a 12288-token table
+    if rehearse:
+        z_slots, z_hq, z_hkv, z_page, z_seq = 3, 4, 2, 8, 48
+    else:
+        z_slots, z_hq, z_hkv, z_page, z_seq = 24, 8, 2, 512, 12288
+    z_spg = z_seq // z_page
+    kq, kk, kv, key = jax.random.split(key, 4)
+    k_pool = jax.random.normal(kk, (z_slots * z_spg + 1, z_page, 1, z_hkv * d), bf16)
+    v_pool = jax.random.normal(kv, k_pool.shape, bf16)
+    check(f"paged latent GQA {z_hq} on {z_hkv} merged heads page={z_page}", "paged_attention",
+          functools.partial(paged_attention, scale=scale, kv_heads=z_hkv, interpret=rehearse),
+          lambda q_, k_, v_, tb, ln: _paged_attention_xla(
+              q_, k_, v_, tb, ln, scale, None, None, None, kv_heads=z_hkv),
+          (jax.random.normal(kq, (z_slots, z_hq, d), bf16), k_pool, v_pool,
+           jnp.asarray(np.arange(z_slots * z_spg).reshape(z_slots, z_spg), jnp.int32),
+           jnp.asarray(np.linspace(2 * z_seq // 3, z_seq - z_seq // 42, z_slots), jnp.int32)),
+          ATTN_ATOL)
+    del k_pool, v_pool
+
+    # ---- the same family's convolutions and value shift: a decode step that
+    # starts from the per-slot state 63 rows left, against the full-sequence
+    # form's last row (one layer at the published widths)
+    from mlx_sharding_tpu.models import build_model
+
+    zaya, _ = build_model(dict(
+        model_type="zaya", vocab_size=256, num_hidden_layers=1, num_experts=1,
+        **(dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2, head_dim=d,
+                moe_intermediate_size=32, router_hidden_size=16) if rehearse else
+           dict(hidden_size=2048, num_attention_heads=8, num_key_value_heads=2, head_dim=128,
+                moe_intermediate_size=2048, router_hidden_size=256))))
+    kp, kx, key = jax.random.split(key, 3)
+    layer = jax.tree.map(lambda w: w[0], zaya.init_params(kp, bf16)["layers"])
+    x = jax.random.normal(kx, (z_slots, 64, zaya.config.hidden_size), bf16)
+
+    def cca(x, layer, steps):
+        cache = zaya.make_cache(x.shape[0], 64, bf16)
+        k_buf, v_buf = cache.k[0], cache.v[0]
+        st = jax.tree.map(lambda a: a[0], cache.state)
+        at = 0
+        for n in steps:
+            out, k_buf, v_buf, st = zaya._attn(
+                layer, x[:, at:at + n], st, k_buf, v_buf, jnp.asarray(at, jnp.int32),
+                None, None, None)
+            at += n
+        return out[:, -1]
+
+    check("cca mix: a decode step from the slot state, against the full sequence", None,
+          functools.partial(cca, steps=(63, 1)), functools.partial(cca, steps=(64,)),
+          (x, layer), ATTN_ATOL)
+
     # ---- 4-bit matmuls: the batch kernel at a prefill chunk's rows and a
     # 16-slot decode step's, the GEMV at M=1 and 8
     for out_dim, in_dim in quant_shapes + batch_only:

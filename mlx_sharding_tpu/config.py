@@ -305,6 +305,70 @@ class AfmoeConfig(BaseConfig):
         return self.num_experts * self.moe_expert_share
 
 
+@dataclass
+class ZayaConfig(BaseConfig):
+    """Zyphra ZAYA1 (``zaya``): every layer is an attention sub-layer and a
+    MoE sub-layer. Attention runs in a compressed latent (Compressed
+    Convolutional Attention): queries project to ``num_attention_heads x
+    head_dim`` (half the hidden size), keys and values to
+    ``num_key_value_heads x head_dim``; the packed q, k pass two causal
+    convolutions over time (``cca_time0`` taps a channel, ``cca_time1`` taps
+    mixing each head's channels), half of the value channels come from the
+    previous token, rotary covers ``partial_rotary_factor`` of each head.
+    The MoE picks ``num_experts_per_tok`` of ``num_experts`` by softmax over
+    an MLP router's logits plus a selection bias; the router's
+    ``router_hidden_size``-wide state runs down the layers.
+
+    A layer may hold one chip's SHARE of the routed experts, as
+    :class:`NemotronHConfig` says: ``num_experts`` counts the experts held,
+    ``moe_expert_share`` the holders, ``moe_expert_share_index`` which one
+    this is. A checkpoint's own config (no share keys) is the whole model."""
+
+    model_type: str = "zaya"
+    layer_types: Optional[list] = None
+    cca_time0: int = 2
+    cca_time1: int = 2
+    partial_rotary_factor: float = 0.5
+    rope_parameters: Optional[dict] = None
+    rope_theta: float = 5000000.0
+    moe_intermediate_size: int = 2048
+    num_experts: int = 16
+    num_experts_per_tok: int = 1
+    router_hidden_size: int = 256
+    sliding_window: Optional[int] = None
+    moe_expert_share: int = 1
+    moe_expert_share_index: int = 0
+    tie_word_embeddings: bool = True
+    max_position_embeddings: int = 131072
+
+    def __post_init__(self):
+        if self.layer_types is None:
+            self.layer_types = ["hybrid"] * self.num_hidden_layers
+        self.layer_types = list(self.layer_types)
+        if self.layer_types != ["hybrid"] * self.num_hidden_layers:
+            raise ValueError(
+                "zaya is wired for layer_types of num_hidden_layers x 'hybrid'"
+            )
+        if self.cca_time0 != 2 or self.cca_time1 != 2:
+            raise ValueError("zaya is wired for cca_time0 = cca_time1 = 2")
+        if self.sliding_window is not None:
+            raise ValueError("zaya is wired for sliding_window null")
+        hybrid = (self.rope_parameters or {}).get("hybrid", {})
+        self.rope_theta = float(hybrid.get("rope_theta", self.rope_theta))
+        self.partial_rotary_factor = float(
+            hybrid.get("partial_rotary_factor", self.partial_rotary_factor)
+        )
+        if not 0 <= self.moe_expert_share_index < self.moe_expert_share:
+            raise ValueError("moe_expert_share_index must lie in [0, moe_expert_share)")
+        super().__post_init__()
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("num_key_value_heads must divide num_attention_heads")
+
+    @property
+    def router_width(self) -> int:
+        return self.num_experts * self.moe_expert_share
+
+
 # Arch-name resolution. Mirrors the reference's MODEL_REMAPPING
 # (shard/utils.py:14-17): mistral runs through the llama implementation.
 MODEL_REMAPPING = {
@@ -320,6 +384,7 @@ CONFIG_REGISTRY: dict[str, type] = {
     "mixtral": MixtralConfig,
     "nemotron_h": NemotronHConfig,
     "afmoe": AfmoeConfig,
+    "zaya": ZayaConfig,
 }
 
 

@@ -21,6 +21,7 @@ MODEL_REGISTRY: dict[str, tuple[str, str]] = {
     "mixtral": ("mlx_sharding_tpu.models.mixtral", "MixtralModel"),
     "nemotron_h": ("mlx_sharding_tpu.models.nemotron_h", "NemotronHModel"),
     "afmoe": ("mlx_sharding_tpu.models.afmoe", "AfmoeModel"),
+    "zaya": ("mlx_sharding_tpu.models.zaya", "ZayaModel"),
 }
 
 

@@ -29,7 +29,8 @@ observe — token count, packed or dense stacks, backend, shapes:
 Routing is parameterized so Mixtral (softmax→topk→renorm), DeepSeek-V2
 (softmax scoring→greedy topk, optional renorm + scaling factor) and
 Nemotron-H (sigmoid scoring, a selection bias that chooses but does not
-weigh) share the dispatch machinery. Experts are SwiGLU (``w_gate`` given)
+weigh) and ZAYA (softmax over logits an MLP router hands in, the same kind
+of bias) share the dispatch machinery. Experts are SwiGLU (``w_gate`` given)
 or un-gated ``relu(x W_up)^2 W_down`` (``w_gate=None``), on every path.
 
 A layer that holds only a RANGE of the routed experts — one chip's share
@@ -142,6 +143,18 @@ def nemotron_routing(
     if norm_topk_prob:
         topv = topv / (topv.sum(axis=-1, keepdims=True) + 1e-20)
     return topv * routed_scaling_factor, topi
+
+
+@jax.named_scope("mst.moe.router")
+def biased_softmax_routing(logits, select_bias, k: int):
+    """A gate whose logits the model computes itself (ZAYA's MLP router):
+    softmax over all experts in fp32; the top-k of ``probs + select_bias``
+    CHOOSES, the chosen experts' own probabilities WEIGH (the balancing bias
+    never enters a weight, and nothing is renormalized: top-1 weighs its
+    expert by its probability)."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, topi = jax.lax.top_k(probs + select_bias.astype(jnp.float32), k)
+    return jnp.take_along_axis(probs, topi, axis=-1), topi
 
 
 def _activate(gate, up):

@@ -1355,7 +1355,9 @@ class PipelineEngine:
         carries it whole through its layer scans
         (``models.base.scan_layers_carried``), a layer an offset into the
         page table; a model with state walks its own layers and is handed
-        ``pool_attn``; under a share map the group-sized pool is carried
+        ``pool_attn``, which takes one layer's pool or — from a model whose
+        layers all keep pages — the whole pool in its walk's carry and the
+        layer's row; under a share map the group-sized pool is carried
         (``_scan_layers_shared``)."""
         model, M, B = self.model, self.microbatches, self.batch
         page = self.page_size
@@ -1428,7 +1430,9 @@ class PipelineEngine:
                 pool is viewed as pages where it lies and the layer is an
                 offset into the ring table. ``layer``: the buffers are the
                 WHOLE page pool, every layer's, already viewed as pages
-                ``(L * (P+1), page, H, D)``, and this is its (traced) row
+                ``(L * (P+1), page, H, D)`` — or as a model that walks its
+                own layers was handed it, ``(L, P+1, B, page, H, D)``, and
+                viewed so here — and this is its (traced) row
                 ``layer``: the same offset, into the page table. ``keep``
                 false (a padding layer) sends the M writes to the layer's
                 scratch page. ``scope`` names the attention call."""
@@ -1442,6 +1446,11 @@ class PipelineEngine:
                         keep, page_ids, self.pool_pages
                     )
                     ids, tbl = ids + first, rows + first
+                    if jax.tree.leaves(k_buf)[0].ndim == 6:
+                        as_pages = lambda x: x.reshape(-1, *x.shape[3:])  # noqa: E731
+                        as_given = lambda x: x.reshape(  # noqa: E731
+                            -1, n_pages, B, *x.shape[1:]
+                        )
                     if jax.tree.leaves(k_buf)[0].shape[-2] > 1:
                         # rows that keep their heads apart reach the
                         # kernel's (page, Hkv * D) block through a relayout

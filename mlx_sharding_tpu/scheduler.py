@@ -2436,10 +2436,12 @@ class ContinuousBatcher:
                         amount = self._put(
                             jnp.asarray(self.decode_block, jnp.int32)
                         )
-                    self.cache = self._rewind_offset(
-                        self.cache,
-                        self._put(jnp.asarray(req.slot, jnp.int32)),
-                        amount,
+                    self.cache = self.cache._replace(
+                        offset=self._rewind_offset(
+                            self.cache.offset,
+                            self._put(jnp.asarray(req.slot, jnp.int32)),
+                            amount,
+                        )
                     )
             self._slots[req.slot] = None
             note_release("scheduler.slot", (id(self), req.slot))

@@ -108,6 +108,10 @@ MODEL_SCOPES = (
     "mst.attn.gate",
     "mst.attn.qk_norm",
     "mst.kv_ring.regroup",
+    # a model whose attention runs in a compressed latent (models/zaya.py):
+    # the convolutions over time, the q-k mean, the norms and the value shift
+    # between the projections and the attention call
+    "mst.attn.cca_mix",
     "mst.moe.router",
     "mst.moe.experts",
     "mst.moe.experts.gather_dequant",

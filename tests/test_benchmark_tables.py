@@ -1,0 +1,106 @@
+"""The benchmark's tables, read as files (no server, no JAX): what the
+driver refuses before or after a run and no chip run is needed to see.
+
+PR 41's new long-context closed-loop cell was refused ``output_malformed``:
+``attempted`` (the requests DUE inside the window, ``benchmarks/run.py``) was
+0 in every run. In a closed loop a request is due when its client is free,
+the first wave is due in the lead-in, and no first-wave stream ended before
+the window closed: outputs too long for the step. A closed-loop mix therefore
+records what it was sized for (``sized_for``: the step a token takes a stream
+as the client sees it, the seconds the first wave decodes before the window
+opens, the seconds a rejoin takes before its first token), and this file
+replays ``traffic.plan`` against that on 8 seeds: some first-wave stream ends
+inside the window (as many as the mix says), none before it, and the ready
+requests do not run out. ``traffic/decode-sat.json`` and
+``traffic/longctx-sat.json`` record no ``sized_for`` yet (a ``benchmark``
+issue's to add: a PR may not edit a file the benchmark has).
+"""
+
+import json
+from pathlib import Path
+
+import pytest
+
+from benchmarks import traffic
+from benchmarks.config import server_flag
+
+ROOT = Path(__file__).resolve().parents[1]
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+SEEDS = (1, 77, 2400000101, 2147483659, 3100000301, 3900000602, 4000000101, 4200000007)
+
+
+def _load(cell: dict):
+    config = json.loads((ROOT / next(
+        c["file"] for c in BENCH["configs"] if c["name"] == cell["config"])).read_text())
+    mix = json.loads((ROOT / f"benchmarks/traffic/{cell['traffic']}.json").read_text())
+    load = json.loads((ROOT / f"benchmarks/cells/{cell['name']}.json").read_text())
+    return config, mix, load
+
+
+def _sized(cell: dict) -> bool:
+    mix = _load(cell)[1]
+    return mix.get("arrivals") == "closed" and "sized_for" in mix
+
+
+SIZED = [c["name"] for c in BENCH["workloads"] if _sized(c)]
+
+
+def replay(planned, clients: int, lead: float, seconds: float, sized: dict):
+    """``(ends of the first wave, requests taken by the window's end)`` of a
+    closed loop that decodes as ``sized`` says: the first wave starts
+    decoding together ``decode_s_before_window`` before the window, a token
+    takes a stream ``step_ms``, a rejoin ``join_s`` before its first."""
+    step = sized["step_ms"] / 1e3
+    t0 = lead - sized["decode_s_before_window"]
+    first = [t0 + r.output_tokens * step for r in planned[:clients]]
+    free, taken = sorted(first), clients
+    while free and free[0] < lead + seconds:
+        t = free.pop(0)
+        if taken >= len(planned):
+            return first, len(planned) + 1
+        free.append(t + sized.get("join_s", 0.0) + planned[taken].output_tokens * step)
+        free.sort()
+        taken += 1
+    return first, taken
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+@pytest.mark.parametrize("name", SIZED)
+def test_a_sized_closed_loop_cell_has_requests_due_in_its_window(name, seed):
+    cell = next(c for c in BENCH["workloads"] if c["name"] == name)
+    config, mix, load = _load(cell)
+    seconds = float(BENCH["run_seconds"])
+    planned, lead = traffic.plan(
+        mix, load, seconds, seed, config["vocab_size"],
+        int(server_flag(config, "--max-seq", 4096)))
+    clients = int(load["clients"])
+    assert clients == int(server_flag(config, "--concurrent"))
+    first, taken = replay(planned, clients, lead, seconds, mix["sized_for"])
+    assert min(first) >= lead, "a first-wave stream ends in the lead-in"
+    in_window = sum(lead <= t < lead + seconds for t in first)
+    lo, hi = mix["sized_for"]["first_wave_ends"]
+    # `attempted` counts the requests due in the window: each end frees a client
+    assert 1 <= lo <= in_window <= hi, (in_window, sorted(first))
+    assert taken <= len(planned), "the mix's ready requests would run out"
+
+
+def test_the_new_long_context_cell_is_sized():
+    assert "zaya1-8b-bf16-pp2ep2.longctx8k-sat" in SIZED
+
+
+def _texts():
+    for kind in ("configs", "workloads"):
+        for entry in BENCH[kind]:
+            for key in ("why", "source"):
+                if key in entry:
+                    yield f"{kind}:{entry['name']}:{key}", entry[key]
+    for i, word in enumerate(BENCH["command"]):
+        yield f"command:{i}", word
+    for m in BENCH["per_layer"]:
+        yield f"per_layer:{m['name']}:layer", m["layer"]
+
+
+@pytest.mark.parametrize("where,text", list(_texts()), ids=[w for w, _ in _texts()])
+def test_every_line_of_the_table_is_1_to_200_printable_characters(where, text):
+    assert isinstance(text, str) and 1 <= len(text) <= 200, (where, len(text))
+    assert text.isprintable() and "\t" not in text and "\n" not in text, where

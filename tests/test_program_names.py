@@ -323,6 +323,52 @@ def test_served_afmoe_programs_carry_the_scope_vocabulary():
     assert _scopes_in(block) | _scopes_in(prefill) <= set(tracing.MODEL_SCOPES)
 
 
+@hard_timeout(420)
+def test_served_zaya_programs_carry_the_scope_vocabulary():
+    """The fourth family: the latent attention's convolutions and value
+    shift between the projections and the attention call, the MLP router,
+    the per-slot state's update — under the same program names."""
+    from mlx_sharding_tpu.models import build_model
+
+    model, _ = build_model(dict(
+        model_type="zaya", vocab_size=128, hidden_size=32, num_hidden_layers=2,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        moe_intermediate_size=16, num_experts=2, moe_expert_share=2,
+        router_hidden_size=8,
+    ))
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    eng = PipelineEngine(
+        model, params, pipeline_mesh(1), microbatches=2, max_seq=64,
+        cache_dtype=jnp.float32, prefill_chunk=8, pool_pages=10, page_size=8,
+    )
+    b = ContinuousBatcher(eng, decode_block=3)
+    try:
+        assert len(list(b.generate_step([3, 4, 5, 6], max_tokens=5))) == 5
+        block = b._decode_block_prog(False).lower(
+            eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+            b.last_tok, b.cache, b.active, b.recent, b.keys, b.sp, b.rep_sizes,
+            b.table,
+        ).as_text(debug_info=True)
+        prefill = eng.prefill_slot().lower(
+            eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+            jnp.zeros((1, 8), jnp.int32), jnp.asarray(0, jnp.int32), b.cache,
+            jnp.asarray(8, jnp.int32), b.table,
+        ).as_text(debug_info=True)
+    finally:
+        b.close()
+    assert "module @jit_block " in block
+    assert "module @jit_prefill_chunk " in prefill
+    layers = {"mst.embed", "mst.attn.qkv", "mst.attn.cca_mix", "mst.attn.kv_write",
+              "mst.attn.core", "mst.moe.router", "mst.moe.experts",
+              "mst.moe.experts.scan", "mst.state_pool.regroup", "mst.norm", "mst.head"}
+    # decode carries the page pool through the layer scan: no regroup; the
+    # sampler is in the block
+    assert _scopes_in(block) == layers | {"mst.sample"}
+    # prefill scans each layer's rows of the slot's contiguous view
+    assert _scopes_in(prefill) == layers | {"mst.kv_pool.regroup"}
+    assert _scopes_in(block) | _scopes_in(prefill) <= set(tracing.MODEL_SCOPES)
+
+
 # ------------------------------------------- what rides the layer scan
 
 

@@ -164,6 +164,14 @@ CASES = {
         merged=True),
     "paged-full-page512": _paged(
         512, False, slots=32, hq=48, max_seq=16384, pages=1025, merged=True),
+    # ... and at the zaya1-8b-bf16-pp2ep2 cell's: 24 slots, 8 query heads on
+    # 2 K/V heads of 128 merged on 256 lanes (a 256 KB block a pool), a table
+    # 24 pages wide, ALL 20 layers' pools viewed as one (20 x 577 pages)
+    "paged-latent-gqa-page512": _paged(
+        512, False, slots=24, hq=8, hkv=2, max_seq=12288, pages=20 * 577,
+        merged=True),
+    # the same cell's prefill chunk: 512 rows against a 12288-row view
+    "flash-prefill-latent-gqa": _flash(512, 12288, 8, 2, 128, 128),
     # 4-bit projections of the 3B model: batch kernel at M=256, GEMV at 1, 8
     **{f"quant-M{m}-{i}x{o}": _quant(
         m, o, i, "quant_matmul" if m == 256 else "quant_gemv_pipelined")
