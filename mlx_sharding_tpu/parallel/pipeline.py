@@ -1653,10 +1653,11 @@ class PipelineEngine:
     def _build_decode_cb(self):
         """Decode step for continuous batching: per-slot offsets advance only
         on active slots, per-slot sampler params and PRNG keys (each slot
-        reproduces the solo request with that seed), logits of inactive slots
-        sampled-but-ignored. Reuses the same shard_map body as the uniform
-        decode; only the host-visible wrapper differs. In paged mode the
-        step takes the page table as an extra trailing argument."""
+        reproduces the solo request with that seed), inactive slots' tokens
+        computed-but-ignored (and left out of the sampler's decision whether
+        the step draws or sorts at all). Reuses the same shard_map body as
+        the uniform decode; only the host-visible wrapper differs. In paged
+        mode the step takes the page table as an extra trailing argument."""
         M, B = self.microbatches, self.batch
         if B != 1:
             raise ValueError("continuous batching expects batch=1 per slot")
@@ -1696,7 +1697,7 @@ class PipelineEngine:
                 valid = jnp.arange(W)[None, :] >= (W - rep_sizes)[:, None]
                 tok, logprobs = sample_token_batched(
                     subs, logits.reshape(M, -1), sp,
-                    jnp.where(valid, recent, -1),
+                    jnp.where(valid, recent, -1), active,
                 )
                 recent = update_recent_tokens(recent, tok)
             new_cache = KVCache(

@@ -1,4 +1,4 @@
-"""Token sampling — fully on-device, branchless, jit-fused into the decode step.
+"""Token sampling — fully on-device, jit-fused into the decode step.
 
 Semantic parity with the reference's sampler closure
 (ref: shard/utils.py:126-139 — logit bias, argmax at temperature 0, top-p
@@ -7,6 +7,11 @@ else categorical) and its repetition penalty over a sliding token window
 traced into the same XLA program as the model forward, with temperature /
 top-p / penalty as *dynamic* scalars, so changing sampler settings never
 recompiles and the only per-token host transfer is the sampled token id.
+What a step pays for follows those scalars through ``lax.cond`` on the
+device, never through a host-chosen program: a greedy step takes an argmax,
+a sampled one adds the Gumbel draw, and only ``top_p < 1`` sorts the
+vocabulary (``sample_token`` per request, ``sample_token_batched`` per batch
+of active rows).
 """
 
 from __future__ import annotations
@@ -94,10 +99,16 @@ def top_p_filter(logits: jax.Array, top_p: jax.Array) -> jax.Array:
     at shard/utils.py:136). Keeps the smallest prefix of the sorted
     distribution whose mass reaches ``top_p``; top_p >= 1 keeps everything.
 
-    The full-vocab sort costs ~1ms/token at a 128K vocab on a v5e, so the
-    whole filter sits behind a ``lax.cond`` — requests at the top_p=1
-    default never pay for it. (Under vmap — the batched scheduler sampler —
-    cond lowers to select and both branches run, same as before.)"""
+    The full-vocab sort costs 1.2 ms a million logits on a v5e (a row a
+    little over a power of two is sorted as the next one), so the filter
+    sits behind a ``lax.cond``. Called on one row or one scalar ``top_p``
+    (``sample_token``, the solo path) that is a real conditional and a
+    request at the top_p=1 default never sorts. Under ``jax.vmap``
+    (``nucleus_logits_batched``: the speculative programs, which need the
+    filtered distribution of every row) the cond lowers to a select and
+    every row sorts whatever its ``top_p``. The served decode step
+    (``sample_token_batched``) calls the vmapped filter only inside a
+    batch-level conditional of its own."""
 
     def nucleus(lo):
         sorted_logits = jnp.sort(lo, axis=-1)[..., ::-1]
@@ -240,19 +251,47 @@ def sample_token_batched(
     logits: jax.Array,  # (B, V) f32
     params: SamplerParams,  # every leaf with leading (B,)
     recent_tokens: jax.Array,  # (B, W) int32, -1 padded
+    active: jax.Array,  # (B,) bool — rows whose token somebody reads
 ) -> tuple[jax.Array, jax.Array]:
     """Per-row sampling with per-row params and per-row PRNG keys — each
     continuous-batching slot behaves exactly like a solo request with that
     seed, so draining a slot and re-running the request serially reproduces
-    its tokens."""
+    its tokens.
+
+    What a step runs is decided per BATCH, on the device, by two real
+    ``lax.cond``s outside any vmap: every step transforms the logits and
+    takes the argmax; only if an ACTIVE row has ``temperature > 0`` does it
+    also scale by temperature and draw (B x V Gumbel numbers); only if such
+    a row also has ``top_p < 1`` does it sort the vocabulary (every row's:
+    the filter inside is vmapped). An all-greedy batch — the server's
+    default — pays neither. ``active`` masks both predicates because a
+    freed slot keeps the row of the request that left it until the next
+    claim. An inactive row's token is greedy whenever no active row draws;
+    nobody reads it. The branches hand back (B,) tokens, so nothing of the
+    vocabulary's size crosses a conditional's edge."""
     logits = transform_logits_batched(logits, recent_tokens, params)
 
     logprobs = jax.nn.log_softmax(logits, axis=-1)
-    greedy = jnp.argmax(logits, axis=-1)
-    filtered = nucleus_logits_batched(logits, params)
-    sampled = jax.vmap(lambda k, l: jax.random.categorical(k, l))(keys, filtered)
-    token = jnp.where(params.temperature > 0, sampled, greedy)
-    return token.astype(jnp.int32), logprobs
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    sampled_row = params.temperature > 0
+    draws = active & sampled_row
+    cuts = draws & (params.top_p < 1.0)
+
+    def draw(lo):
+        return jax.vmap(lambda k, l: jax.random.categorical(k, l))(keys, lo)
+
+    def drawn():
+        tempered = logits / jnp.maximum(params.temperature, 1e-6)[:, None]
+        sampled = jax.lax.cond(
+            jnp.any(cuts),
+            lambda lo: draw(jax.vmap(top_p_filter)(lo, params.top_p)),
+            draw,
+            tempered,
+        )
+        return jnp.where(sampled_row, sampled.astype(jnp.int32), greedy)
+
+    token = jax.lax.cond(jnp.any(draws), drawn, lambda: greedy)
+    return token, logprobs
 
 
 @jax.named_scope("mst.sample")

@@ -826,6 +826,14 @@ class ContinuousBatcher:
         self._drains = dict.fromkeys(
             ("admit", "prefilling", "cold", "growth", "migrate", "idle"), 0
         )
+        # plain decode blocks by what their sampler had to run, classed at
+        # dispatch from the live requests' own settings: greedy (argmax),
+        # draw (a live row samples at top_p = 1), nucleus (a live sampled
+        # row cuts at top_p < 1: the vocabulary is sorted). The program
+        # decides the same on the device (sample.sample_token_batched)
+        self._blocks_by_sampler = dict.fromkeys(
+            ("greedy", "draw", "nucleus"), 0
+        )
         # always-on latency histograms (/metrics): inter-token latency at
         # the emit path, admission queue wait at slot assignment. These are
         # the metric itself (a lock + bisect per observation, same grade as
@@ -1404,6 +1412,7 @@ class ContinuousBatcher:
             "tokens_emitted": self._tokens_emitted,
             "tokens_dropped": dict(self._tokens_dropped),
             "drains": dict(self._drains),
+            "blocks_by_sampler": dict(self._blocks_by_sampler),
         }
 
     def state_stats(self) -> Optional[dict]:
@@ -1972,6 +1981,7 @@ class ContinuousBatcher:
             logits.reshape(1, -1),
             jax.tree.map(lambda x: x[slot][None], sp),
             masked[None],
+            jnp.ones((1,), bool),
         )
         keys = keys.at[slot].set(key_new)
         recent = recent.at[slot].set(
@@ -3062,7 +3072,15 @@ class ContinuousBatcher:
         ]
         if not live:
             return None
-        want_lp = any(req.want_logprobs for _, req in live)
+        want_lp, sampler = False, "greedy"
+        for _, req in live:
+            want_lp |= req.want_logprobs
+            if req.temperature > 0:
+                if req.top_p < 1.0:
+                    sampler = "nucleus"
+                elif sampler == "greedy":
+                    sampler = "draw"
+        self._blocks_by_sampler[sampler] += 1
         # analytic gauge; in async mode the lengths are one block stale
         self._account_kv_read(live, self.decode_block)
         # the block's first input token, kept so a draft engine can replay

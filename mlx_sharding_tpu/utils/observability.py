@@ -208,6 +208,8 @@ def _render_tick_phases(lines: list, t: dict):
     labelled("mst_decode_tokens_dropped_total", "reason", t["tokens_dropped"])
     lines.append("# TYPE mst_pipeline_drains_total counter")
     labelled("mst_pipeline_drains_total", "reason", t["drains"])
+    lines.append("# TYPE mst_decode_blocks_total counter")
+    labelled("mst_decode_blocks_total", "sampler", t["blocks_by_sampler"])
 
 
 def _render_spec_family(lines: list, spec: dict):
@@ -1121,6 +1123,10 @@ _HELP = {
         "abandoned_block (futures dropped).",
     "mst_pipeline_drains_total":
         "Pipeline drains (a block was in flight at a quiesce), by call site.",
+    "mst_decode_blocks_total":
+        "Plain decode blocks dispatched, by what their sampler had to run "
+        "for the live requests: greedy (argmax only), draw (a sampled row "
+        "at top_p = 1), nucleus (a sampled row at top_p < 1: the sort).",
     "mst_state_slots_in_use":
         "Slots whose recurrent state (Mamba-2 SSM state and convolution "
         "tail) belongs to an admitted request.",

@@ -104,3 +104,24 @@ def _texts():
 def test_every_line_of_the_table_is_1_to_200_printable_characters(where, text):
     assert isinstance(text, str) and 1 <= len(text) <= 200, (where, len(text))
     assert text.isprintable() and "\t" not in text and "\n" not in text, where
+
+
+def test_greedy_blocks_share_reads_the_sampler_classes_of_the_window():
+    """``mst_decode_blocks_total{sampler}`` between the two scrapes; a
+    program from before the counter (the parent of the PR that added it)
+    exposes nothing and the metric is left out, as in an empty window."""
+    from benchmarks.run import load_reader
+
+    read = load_reader("layer_metrics", "greedy_blocks_share")
+    family = 'mst_decode_blocks_total{sampler="%s"}'
+    before = {family % "greedy": 40.0, family % "draw": 1.0, family % "nucleus": 2.0,
+              "mst_decode_blocks_dispatched_total": 43.0}
+    after = {family % "greedy": 340.0, family % "draw": 26.0, family % "nucleus": 77.0,
+             "mst_decode_blocks_dispatched_total": 443.0}
+    assert read({"before": before, "after": after}) == pytest.approx(75.0)
+    all_greedy = {**before, family % "greedy": 440.0}
+    assert read({"before": before, "after": all_greedy}) == 100.0
+    assert read({"before": before, "after": before}) is None
+    old = {"mst_decode_blocks_dispatched_total": 443.0}
+    assert read({"before": old, "after": old}) is None
+    assert read({"before": None, "after": None}) is None

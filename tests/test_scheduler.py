@@ -1051,3 +1051,62 @@ def test_async_tick_phase_stats_populated(setup):
     assert sum(s for ph, s in secs.items()
                if ph not in ("harvest_wait", "idle_wait")) >= 0.0
     assert secs["kv_import"] == 0.0
+
+
+def test_mixed_batch_sampler_classes_and_solo_tokens(setup):
+    """Greedy streams beside one seeded sampled ``top_p < 1`` request that
+    ends mid-run: each request gets the tokens of its solo run, and the
+    decode blocks are counted by what their sampler had to run
+    (``mst_decode_blocks_total{sampler}``): ``nucleus`` while the sampled
+    request lives, ``greedy`` once its slot is freed — though the freed
+    slot keeps the request's sampler row until the next claim (the
+    stale-``sp`` case the device-side predicate masks by ``active``) —
+    and ``draw`` for a sampled request at ``top_p = 1``."""
+    batcher, ref_gen = setup
+
+    def counts():
+        return dict(batcher.tick_phase_stats()["blocks_by_sampler"])
+
+    long_greedy = ([3, 17, 42], dict(max_tokens=56))
+    nucleus = ([5, 6, 2], dict(temperature=1.1, top_p=0.7, seed=23, max_tokens=6))
+    draw = ([8, 8, 1], dict(temperature=0.9, seed=5, max_tokens=12))
+    refs = [_run(ref_gen, p, **kw) for p, kw in (long_greedy, nucleus, draw)]
+
+    at_start, at_nucleus_end, got = counts(), {}, [None, None]
+
+    def worker(i, prompt, kw):
+        got[i] = _run(batcher, prompt, **kw)
+        if i == 1:
+            # the stream's end is put after the slot is freed: every block
+            # dispatched from here on has only the greedy stream live
+            at_nucleus_end.update(counts())
+
+    threads = [
+        threading.Thread(target=worker, args=(i, p, kw))
+        for i, (p, kw) in enumerate((long_greedy, nucleus))
+    ]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=300)
+        assert not th.is_alive(), "generation thread hung"
+    assert got == refs[:2]
+    at_end = counts()
+    # nobody has claimed the freed slot: its sampler row is still the
+    # sampled request's, beside the greedy stream's
+    temperature, top_p = np.asarray(batcher.sp.temperature), np.asarray(batcher.sp.top_p)
+    assert sorted(zip(temperature.tolist(), top_p.tolist())) == [
+        (0.0, 1.0), (pytest.approx(1.1), pytest.approx(0.7))
+    ]
+    assert not np.asarray(batcher.active).any()
+    assert at_nucleus_end["nucleus"] > at_start["nucleus"]
+    assert at_end["nucleus"] == at_nucleus_end["nucleus"]
+    assert at_end["greedy"] >= at_nucleus_end["greedy"] + 2
+    assert at_end["draw"] == at_start["draw"]
+
+    assert _run(batcher, draw[0], **draw[1]) == refs[2]
+    after_draw = counts()
+    assert after_draw["draw"] > at_end["draw"]
+    assert after_draw["nucleus"] == at_end["nucleus"]
+    s = batcher.tick_phase_stats()
+    assert sum(s["blocks_by_sampler"].values()) == s["blocks_dispatched"]

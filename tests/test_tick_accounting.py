@@ -156,7 +156,7 @@ def test_blocks_and_tokens_are_all_accounted_for(engine, mode):
             "path", "ticks", "phase_seconds", "device_empty_seconds",
             "phase_entries", "blocks_dispatched", "blocks_harvested",
             "blocks_abandoned", "positions_computed", "tokens_emitted",
-            "tokens_dropped", "drains"}
+            "tokens_dropped", "drains", "blocks_by_sampler"}
         assert s["path"] == ("async" if mode == "on" else "sync")
         assert set(s["device_empty_seconds"]) == set(tracing.TICK_PHASES)
     finally:
