@@ -555,6 +555,76 @@ def child_kernels(seed: int, rehearse: bool) -> None:
           functools.partial(cca, steps=(63, 1)), functools.partial(cca, steps=(64,)),
           (x, layer), ATTN_ATOL)
 
+    # ---- the granite4-h-micro-bf16 cell's attention: the first 64-wide heads
+    # on the chip. 48 slots, 32 query heads (4 rows a head) on 8 K/V heads of
+    # 64 merged on 512 lanes (the odd heads start half a lane tile in),
+    # 512-token pages, a 4608-token table, scale attention_multiplier
+    if rehearse:
+        g_slots, g_hq, g_hkv, g_d, g_page, g_seq = 3, 8, 2, 16, 8, 48
+    else:
+        g_slots, g_hq, g_hkv, g_d, g_page, g_seq = 48, 32, 8, 64, 512, 4608
+    g_spg, g_scale = g_seq // g_page, 0.015625
+    kq, kk, kv, key = jax.random.split(key, 4)
+    k_pool = jax.random.normal(kk, (g_slots * g_spg + 1, g_page, 1, g_hkv * g_d), bf16)
+    v_pool = jax.random.normal(kv, k_pool.shape, bf16)
+    check(f"paged GQA {g_hq} on {g_hkv} merged heads of {g_d} page={g_page}", "paged_attention",
+          functools.partial(paged_attention, scale=g_scale, kv_heads=g_hkv, interpret=rehearse),
+          lambda q_, k_, v_, tb, ln: _paged_attention_xla(
+              q_, k_, v_, tb, ln, g_scale, None, None, None, kv_heads=g_hkv),
+          (jax.random.normal(kq, (g_slots, g_hq, g_d), bf16), k_pool, v_pool,
+           jnp.asarray(np.arange(g_slots * g_spg).reshape(g_slots, g_spg), jnp.int32),
+           jnp.asarray(np.linspace(g_seq // 9, g_seq - 7, g_slots), jnp.int32)),
+          ATTN_ATOL)
+    del k_pool, v_pool
+
+    # ---- the same family's Mamba-2 (ops/mamba2.py, nemotron_h's too) at the
+    # published sizes: the chunked form at chunk 256 with a ragged last chunk
+    # against the recurrence one position at a time, float32; then one layer's
+    # mixer, a decode step from the slot state 600 rows left against the
+    # chunked form over all 601
+    from mlx_sharding_tpu.models.base import LayerRow
+    from mlx_sharding_tpu.ops.mamba2 import ssd_chunked, ssm_sequential
+
+    m_h, m_p, m_n, m_chunk, m_t = (4, 8, 16, 8, 21) if rehearse else (64, 64, 128, 256, 600)
+    ks = jax.random.split(key, 7)
+    key = ks[6]
+    m_x = jax.random.normal(ks[0], (2, m_t, m_h, m_p), jnp.float32)
+    m_dt = jax.nn.softplus(jax.random.normal(ks[1], (2, m_t, m_h)) - 3.0)
+    m_a = -jnp.exp(jax.random.uniform(ks[2], (m_h,), jnp.float32, 0.0, 2.5))
+    one_group = lambda k_: jnp.repeat(  # noqa: E731
+        jax.random.normal(k_, (2, m_t, 1, m_n), jnp.float32), m_h, axis=2)
+    m_s0 = jax.random.normal(ks[5], (2, m_h, m_p, m_n), jnp.float32)
+    both = lambda ys: jnp.concatenate([ys[0].reshape(2, -1), ys[1].reshape(2, -1)], axis=1)  # noqa: E731
+    check(f"mamba-2 chunked form, chunk {m_chunk} over {m_t} rows, against the sequential recurrence",
+          None, lambda *a: both(ssd_chunked(*a, m_chunk)), lambda *a: both(ssm_sequential(*a)),
+          (m_x, m_dt, m_a, one_group(ks[3]), one_group(ks[4]), m_s0), 2e-3, relative=True)
+    del m_x, m_s0
+
+    granite, _ = build_model(dict(
+        model_type="granitemoehybrid", vocab_size=256, num_hidden_layers=1,
+        layer_types=["mamba"], mamba_chunk_size=m_chunk, mamba_d_state=m_n,
+        mamba_n_heads=m_h, mamba_d_head=m_p,
+        **(dict(hidden_size=16, num_attention_heads=2, shared_intermediate_size=16)
+           if rehearse else
+           dict(hidden_size=2048, num_attention_heads=32, num_key_value_heads=8,
+                shared_intermediate_size=8192))))
+    kp, kx, key = jax.random.split(key, 3)
+    stack = granite.init_params(kp, bf16)["layers"]["mamba"]
+    x = jax.random.normal(kx, (4, m_t + 1, granite.config.hidden_size), bf16)
+
+    def mamba(x, stack, steps):
+        state = granite.make_cache(x.shape[0], 8, bf16).state
+        at = 0
+        for n in steps:
+            out, state = granite._mamba(
+                LayerRow(stack, 0), x[:, at:at + n], state, 0, None, None)
+            at += n
+        return out[:, -1]
+
+    check("mamba-2 mixer: a decode step from the slot state, against the chunked form", None,
+          functools.partial(mamba, steps=(m_t, 1)), functools.partial(mamba, steps=(m_t + 1,)),
+          (x, stack), ATTN_ATOL)
+
     # ---- 4-bit matmuls: the batch kernel at a prefill chunk's rows and a
     # 16-slot decode step's, the GEMV at M=1 and 8
     for out_dim, in_dim in quant_shapes + batch_only:

@@ -369,6 +369,87 @@ class ZayaConfig(BaseConfig):
         return self.num_experts * self.moe_expert_share
 
 
+@dataclass
+class GraniteMoeHybridConfig(BaseConfig):
+    """IBM Granite 4.0-H (``granitemoehybrid``): every block is a mixer —
+    Mamba-2 or attention, by ``layer_types`` — and a SwiGLU MLP
+    (``shared_intermediate_size`` wide), each behind its RMSNorm and each
+    added to the residual times ``residual_multiplier``. Embeddings are
+    scaled by ``embedding_multiplier``, attention scores by
+    ``attention_multiplier`` (not ``head_dim**-0.5``), logits divided by
+    ``logits_scaling``; the head is the embedding. Attention applies no
+    rotary embedding (``position_embedding_type`` ``nope``). The family's
+    routed experts (``num_local_experts > 0``: ``block_sparse_moe`` beside
+    the shared MLP) are not wired."""
+
+    model_type: str = "granitemoehybrid"
+    layer_types: Optional[list] = None
+    attention_multiplier: float = 1.0
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    shared_intermediate_size: int = 8192
+    num_local_experts: int = 0
+    num_experts_per_tok: int = 0
+    position_embedding_type: str = "nope"
+    attention_bias: bool = False
+    hidden_act: str = "silu"
+    normalization_function: str = "rmsnorm"
+    # Mamba-2
+    mamba_n_heads: int = 64
+    mamba_d_head: int = 64
+    mamba_n_groups: int = 1
+    mamba_d_state: int = 128
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    mamba_expand: int = 2
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    tie_word_embeddings: bool = True
+    max_position_embeddings: int = 131072
+
+    def __post_init__(self):
+        if not self.layer_types:
+            raise ValueError("granitemoehybrid needs layer_types")
+        self.layer_types = list(self.layer_types)
+        if len(self.layer_types) != self.num_hidden_layers:
+            raise ValueError(
+                f"layer_types has {len(self.layer_types)} entries for "
+                f"{self.num_hidden_layers} layers"
+            )
+        bad = set(self.layer_types) - {"mamba", "attention"}
+        if bad:
+            raise ValueError(
+                f"layer_types: kinds {sorted(bad)} are not wired (mamba and "
+                "attention are)"
+            )
+        if self.num_local_experts:
+            raise ValueError(
+                "granitemoehybrid is wired for num_local_experts 0: routed "
+                "experts (block_sparse_moe) beside the shared MLP are not"
+            )
+        wired = {
+            "position_embedding_type": "nope", "attention_bias": False,
+            "hidden_act": "silu", "normalization_function": "rmsnorm",
+            "mamba_conv_bias": True, "mamba_proj_bias": False,
+        }
+        for key, want in wired.items():
+            if getattr(self, key) != want:
+                raise ValueError(
+                    f"granitemoehybrid is wired for {key} = {want!r}, not "
+                    f"{getattr(self, key)!r}"
+                )
+        if self.mamba_n_heads * self.mamba_d_head != self.mamba_expand * self.hidden_size:
+            raise ValueError(
+                "mamba_n_heads * mamba_d_head must be mamba_expand * hidden_size"
+            )
+        if self.mamba_n_heads % self.mamba_n_groups:
+            raise ValueError("mamba_n_groups must divide mamba_n_heads")
+        super().__post_init__()
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError("num_key_value_heads must divide num_attention_heads")
+
+
 # Arch-name resolution. Mirrors the reference's MODEL_REMAPPING
 # (shard/utils.py:14-17): mistral runs through the llama implementation.
 MODEL_REMAPPING = {
@@ -385,6 +466,7 @@ CONFIG_REGISTRY: dict[str, type] = {
     "nemotron_h": NemotronHConfig,
     "afmoe": AfmoeConfig,
     "zaya": ZayaConfig,
+    "granitemoehybrid": GraniteMoeHybridConfig,
 }
 
 

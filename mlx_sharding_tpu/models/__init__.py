@@ -22,6 +22,9 @@ MODEL_REGISTRY: dict[str, tuple[str, str]] = {
     "nemotron_h": ("mlx_sharding_tpu.models.nemotron_h", "NemotronHModel"),
     "afmoe": ("mlx_sharding_tpu.models.afmoe", "AfmoeModel"),
     "zaya": ("mlx_sharding_tpu.models.zaya", "ZayaModel"),
+    "granitemoehybrid": (
+        "mlx_sharding_tpu.models.granitemoehybrid", "GraniteMoeHybridModel",
+    ),
 }
 
 

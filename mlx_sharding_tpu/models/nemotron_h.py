@@ -4,16 +4,11 @@ Every block is ``h = h + mixer(rmsnorm(h))`` with ONE mixer, chosen per
 layer by ``hybrid_override_pattern``; a final RMSNorm; untied head; no bias
 anywhere except the convolution's.
 
-- ``M`` **Mamba-2.** ``[z, xBC, dt] = in_proj(u)``; ``xBC = silu(causal
-  depthwise conv1d(xBC) + bias)`` split into ``x (H, P)``, ``B (G, N)``,
-  ``C (G, N)`` (head ``h`` reads group ``h // (H / G)``); ``dt =
-  softplus(dt + dt_bias)``, ``A = -exp(A_log)``; ``S_t = exp(dt_t A) S_{t-1}
-  + dt_t x_t (x) B_t``, ``y_t = S_t C_t + D x_t``; ``y = groupwise_rmsnorm(y *
-  silu(z)) * norm_weight``; ``out_proj``. A prompt chunk runs the chunked
-  (SSD) form in float32 matrix products, a decode step the one-step
-  recurrence; both give the sequential recurrence's numbers. Per sequence
-  the layer keeps the SSM state ``(H, P, N)`` in float32 and the last
-  ``conv_kernel - 1`` inputs of its convolution in the activation dtype.
+- ``M`` **Mamba-2** (``ops/mamba2.py``, shared with
+  ``models/granitemoehybrid.py``): 128 heads of 64 on 8 groups, state 128, 4
+  taps, chunk 128 at the published sizes. Per sequence the layer keeps the
+  SSM state ``(H, P, N)`` in float32 and the last ``conv_kernel - 1`` inputs
+  of its convolution ``(C, conv_kernel - 1)`` in the activation dtype.
 - ``*`` **Attention.** GQA, scale ``head_dim**-0.5``, causal, NO rotary
   embedding (the family applies none; positions come from the Mamba layers).
 - ``E`` **Latent MoE.** Sigmoid router over all experts with a selection
@@ -54,72 +49,14 @@ from mlx_sharding_tpu.models.base import (
     take_row as _take,
 )
 from mlx_sharding_tpu.ops import causal_attention, rms_norm
+from mlx_sharding_tpu.ops.mamba2 import (  # noqa: F401 — the recurrences stay importable from here
+    mamba2_mixer,
+    ssd_chunked,
+    ssm_sequential,
+)
 from mlx_sharding_tpu.ops.moe import apply_experts, nemotron_routing
 
 GROUP_OF = {"M": "mamba", "*": "attn", "E": "moe"}
-_HI = jax.lax.Precision.HIGHEST
-
-
-def ssd_chunked(x, dt, a_head, b_mat, c_mat, state, chunk: int):
-    """Mamba-2's recurrence over a whole chunk of positions as matrix
-    products (the SSD form), float32. ``x (B,T,H,P)``, ``dt (B,T,H)`` (0 at a
-    row that must not advance the state), ``a_head (H,)`` negative, ``b_mat``
-    / ``c_mat (B,T,H,N)`` already expanded from groups to heads, ``state
-    (B,H,P,N)``. Returns ``(y (B,T,H,P) without the D term, state after the
-    last row)``."""
-    b, t, h, p = x.shape
-    n = b_mat.shape[-1]
-    pad = -t % chunk
-    if pad:  # dt = 0 rows: decay 1, input 0 — the state passes through
-        padt = lambda z: jnp.pad(z, ((0, 0), (0, pad)) + ((0, 0),) * (z.ndim - 2))  # noqa: E731
-        x, dt, b_mat, c_mat = padt(x), padt(dt), padt(b_mat), padt(c_mat)
-    nc = (t + pad) // chunk
-    split = lambda z: z.reshape(b, nc, chunk, *z.shape[2:])  # noqa: E731
-    x, dt, b_mat, c_mat = split(x), split(dt), split(b_mat), split(c_mat)
-    acs = jnp.cumsum(dt * a_head, axis=2)  # (B,nc,Q,H) log-decay from chunk start
-    # within a chunk: y_i += sum_{j<=i} exp(acs_i - acs_j) dt_j (C_i . B_j) x_j
-    scores = jnp.einsum("bcqhn,bckhn->bchqk", c_mat, b_mat, precision=_HI)
-    acs_h = jnp.moveaxis(acs, 3, 2)  # (B,nc,H,Q)
-    seg = acs_h[..., :, None] - acs_h[..., None, :]
-    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
-    decay = jnp.where(causal, jnp.exp(jnp.where(causal, seg, 0.0)), 0.0)
-    w = scores * decay * jnp.moveaxis(dt, 3, 2)[..., None, :]
-    y = jnp.einsum("bchqk,bckhp->bcqhp", w, x, precision=_HI)
-    # what each chunk adds to the state at its own end
-    to_end = jnp.exp(acs[:, :, -1:, :] - acs) * dt  # (B,nc,Q,H)
-    add = jnp.einsum("bcqhn,bcqh,bcqhp->bchpn", b_mat, to_end, x, precision=_HI)
-    total = jnp.exp(acs[:, :, -1, :])  # (B,nc,H) a chunk's whole decay
-
-    def carry(s, xs):
-        add_c, total_c = xs
-        return total_c[..., None, None] * s + add_c, s  # ys: state at chunk start
-
-    state, s_in = jax.lax.scan(
-        carry, state, (jnp.moveaxis(add, 1, 0), jnp.moveaxis(total, 1, 0))
-    )
-    s_in = jnp.moveaxis(s_in, 0, 1)  # (B,nc,H,P,N)
-    y = y + jnp.einsum(
-        "bcqhn,bchpn,bcqh->bcqhp", c_mat, s_in, jnp.exp(acs), precision=_HI
-    )
-    return y.reshape(b, nc * chunk, h, p)[:, :t], state
-
-
-def ssm_sequential(x, dt, a_head, b_mat, c_mat, state):
-    """The recurrence one position at a time (``lax.scan``): what
-    :func:`ssd_chunked` must equal. Same arguments, no chunk."""
-
-    def step(s, xs):
-        x_t, dt_t, b_t, c_t = xs
-        s = jnp.exp(dt_t * a_head)[..., None, None] * s + (
-            (dt_t[..., None] * x_t)[..., None] * b_t[..., None, :]
-        )
-        return s, (s * c_t[..., None, :]).sum(-1)
-
-    t_first = lambda z: jnp.moveaxis(z, 1, 0)  # noqa: E731
-    state, y = jax.lax.scan(
-        step, state, (t_first(x), t_first(dt), t_first(b_mat), t_first(c_mat))
-    )
-    return jnp.moveaxis(y, 0, 1), state
 
 
 class NemotronHModel(BaseModel):
@@ -219,73 +156,22 @@ class NemotronHModel(BaseModel):
     def _mamba(self, p, u, st, n_valid, active):
         """``u (B,T,hidden)`` normed input; ``st`` this layer's ``{"ssm",
         "conv"}``; rows past ``n_valid`` and sequences outside ``active`` do
-        not advance it. Returns ``(out (B,T,hidden), st)``."""
+        not advance it. Returns ``(out (B,T,hidden), st)``. The mixer is
+        ``ops.mamba2``'s; this family keeps the convolution's tail as ``(B,
+        C, k-1)``."""
         cfg = self.config
-        b, t, _ = u.shape
-        nh, hp, g, n = (
-            cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
-            cfg.ssm_state_size,
-        )
-        di, k = self.d_inner, cfg.conv_kernel
-        with jax.named_scope("mst.ssm.in_proj"):
-            zxd = self._linear(u, p["in_proj"])
-            z, xbc, dt = jnp.split(zxd, [di, di + self.conv_dim], axis=-1)
         with jax.named_scope("mst.ssm.conv"):
-            # causal depthwise conv over [the last k-1 inputs, this call's]
-            tail = jnp.swapaxes(st["conv"], 1, 2).astype(xbc.dtype)  # (B,k-1,C)
-            seq = jnp.concatenate([tail, xbc], axis=1).astype(jnp.float32)
-            w = p["conv_w"].astype(jnp.float32)  # (C, k)
-            conv = sum(seq[:, j : j + t] * w[:, j] for j in range(k))
-            xbc_a = jax.nn.silu(conv + p["conv_b"].astype(jnp.float32))
-            # the next call's tail: the k-1 inputs that end at the last valid row
-            end = t if n_valid is None else n_valid
-            new_tail = jax.lax.dynamic_slice_in_dim(
-                jnp.concatenate([tail, xbc], axis=1), end, k - 1, axis=1
-            )
-            new_tail = jnp.swapaxes(new_tail, 1, 2)
-            x = xbc_a[..., :di].reshape(b, t, nh, hp)
-            rep = nh // g
-            b_mat = jnp.repeat(xbc_a[..., di : di + g * n].reshape(b, t, g, n), rep, axis=2)
-            c_mat = jnp.repeat(xbc_a[..., di + g * n :].reshape(b, t, g, n), rep, axis=2)
-            dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
-            a_head = -jnp.exp(p["A_log"].astype(jnp.float32))
-
-        def frozen(s, new_tail):
-            """An inactive sequence keeps what it had. Called INSIDE the
-            scope: this select is the root of the fusion that updates the
-            state, and a fusion's time is its root's scope's."""
-            if active is None:
-                return s, new_tail
-            keep = lambda new, old: jnp.where(  # noqa: E731
-                active.reshape(-1, *([1] * (new.ndim - 1))), new, old.astype(new.dtype)
-            )
-            return keep(s, st["ssm"]), keep(new_tail, st["conv"])
-
-        if t == 1:
-            with jax.named_scope("mst.ssm.step"):
-                dt1, x1 = dt[:, 0], x[:, 0]
-                s = jnp.exp(dt1 * a_head)[..., None, None] * st["ssm"] + (
-                    (dt1[..., None] * x1)[..., None] * b_mat[:, 0][..., None, :]
-                )
-                # elementwise, not a dot: a TPU dot would round S to bf16
-                y = (s * c_mat[:, 0][..., None, :]).sum(-1)[:, None]
-                s, new_tail = frozen(s, new_tail)
-        else:
-            with jax.named_scope("mst.ssm.scan"):
-                if n_valid is not None:
-                    dt = jnp.where((jnp.arange(t) < n_valid)[None, :, None], dt, 0.0)
-                y, s = ssd_chunked(x, dt, a_head, b_mat, c_mat, st["ssm"], cfg.chunk_size)
-                s, new_tail = frozen(s, new_tail)
-        with jax.named_scope("mst.ssm.out_proj"):
-            y = y + p["D"].astype(jnp.float32)[:, None] * x
-            y = y.reshape(b, t, di) * jax.nn.silu(z.astype(jnp.float32))
-            yg = y.reshape(b, t, g, di // g)
-            yg = yg * jax.lax.rsqrt(
-                jnp.mean(jnp.square(yg), axis=-1, keepdims=True) + cfg.layer_norm_epsilon
-            )
-            y = yg.reshape(b, t, di) * p["ssm_norm"].astype(jnp.float32)
-            out = self._linear(y.astype(u.dtype), p["out_proj"])
-        return out, {"ssm": s, "conv": new_tail.astype(st["conv"].dtype)}
+            tail = jnp.swapaxes(st["conv"], 1, 2)  # (B,k-1,C)
+        out, s, tail = mamba2_mixer(
+            self._linear, p, u, st["ssm"], tail, n_valid, active,
+            heads=cfg.mamba_num_heads, head_dim=cfg.mamba_head_dim,
+            groups=cfg.n_groups, state=cfg.ssm_state_size,
+            taps=cfg.conv_kernel, chunk=cfg.chunk_size,
+            eps=cfg.layer_norm_epsilon,
+        )
+        with jax.named_scope("mst.ssm.conv"):
+            tail = jnp.swapaxes(tail, 1, 2).astype(st["conv"].dtype)
+        return out, {"ssm": s, "conv": tail}
 
     def _attn(self, p, u, k_buf, v_buf, offset, paged_attn):
         cfg = self.config

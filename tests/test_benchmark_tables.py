@@ -88,6 +88,16 @@ def test_the_new_long_context_cell_is_sized():
     assert "zaya1-8b-bf16-pp2ep2.longctx8k-sat" in SIZED
 
 
+def test_the_reason_cell_is_sized_for_8_to_16_rejoins():
+    assert "granite4-h-micro-bf16.reason-sat" in SIZED
+    mix = _load(next(c for c in BENCH["workloads"]
+                     if c["name"] == "granite4-h-micro-bf16.reason-sat"))[1]
+    assert mix["sized_for"]["first_wave_ends"] == [8, 16]
+    # every rejoin is one prefill chunk, and prompt + answer fits a slot's pages
+    assert mix["prompt_tokens"]["max"] <= 512
+    assert mix["prompt_tokens"]["max"] + mix["output_tokens"]["max"] <= 4608
+
+
 def _texts():
     for kind in ("configs", "workloads"):
         for entry in BENCH[kind]:

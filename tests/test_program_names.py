@@ -369,6 +369,87 @@ def test_served_zaya_programs_carry_the_scope_vocabulary():
     assert _scopes_in(block) | _scopes_in(prefill) <= set(tracing.MODEL_SCOPES)
 
 
+@hard_timeout(420)
+def test_served_granite_programs_walk_their_periods_with_the_state_pool_in_place():
+    """The fifth family: a Mamba-2 or attention mixer and an MLP in every
+    block, under the same program names and scopes. Two periods of ``MMAM``:
+    the decode block holds the Mamba body TWICE (once per run of a period,
+    whatever the depth), each an in-place update of its rows of the state
+    pool at a traced rank; the pool rides the carry of the period scan and of
+    the run's scan, never a scan's ``xs`` or ``ys``, and nothing of the
+    pool's size is selected, concatenated, padded or sliced out of it."""
+    from mlx_sharding_tpu.models import build_model
+
+    model, _ = build_model(dict(
+        model_type="granitemoehybrid", vocab_size=128, hidden_size=32,
+        num_hidden_layers=8, layer_types=["mamba", "mamba", "attention", "mamba"] * 2,
+        num_attention_heads=4, num_key_value_heads=2, shared_intermediate_size=48,
+        mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8, mamba_chunk_size=8,
+        attention_multiplier=0.0625, embedding_multiplier=12,
+        residual_multiplier=0.22, logits_scaling=8,
+    ))
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    eng = PipelineEngine(
+        model, params, pipeline_mesh(1), microbatches=2, max_seq=64,
+        cache_dtype=jnp.float32, prefill_chunk=8, pool_pages=10, page_size=8,
+    )
+    b = ContinuousBatcher(eng, decode_block=3)
+    try:
+        assert len(list(b.generate_step([3, 4, 5, 6], max_tokens=5))) == 5
+        args = (eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+                b.last_tok, b.cache, b.active, b.recent, b.keys, b.sp, b.rep_sizes,
+                b.table)
+        prog = b._decode_block_prog(False)
+        block = prog.lower(*args).as_text(debug_info=True)
+        jaxpr = jax.make_jaxpr(prog)(*args)
+        prefill = eng.prefill_slot().lower(
+            eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+            jnp.zeros((1, 8), jnp.int32), jnp.asarray(0, jnp.int32), b.cache,
+            jnp.asarray(8, jnp.int32), b.table,
+        ).as_text(debug_info=True)
+        pool = b.cache.state["ssm"].shape[1:]  # (layers, slots + 1, H, P, N)
+    finally:
+        b.close()
+    assert "module @jit_block " in block
+    assert "module @jit_prefill_chunk " in prefill
+    layers = {"mst.embed", "mst.attn.qkv", "mst.attn.kv_write", "mst.attn.core",
+              "mst.mlp.dense", "mst.norm", "mst.head", "mst.ssm.in_proj",
+              "mst.ssm.conv", "mst.ssm.out_proj", "mst.state_pool.regroup"}
+    # decode: the one-step recurrence, the page pool carried (no regroup);
+    # the sampler is in the block
+    assert _scopes_in(block) == layers | {"mst.ssm.step", "mst.sample"}
+    # prefill: the chunked (SSD) form on the slot's contiguous rows
+    assert _scopes_in(prefill) == layers | {"mst.ssm.scan", "mst.kv_pool.regroup"}
+    assert _scopes_in(block) | _scopes_in(prefill) <= set(tracing.MODEL_SCOPES)
+
+    walked = list(_walk(jaxpr.jaxpr))
+    updates = [
+        scans for eqn, scans in walked
+        if eqn.primitive.name == "dynamic_update_slice"
+        and eqn.outvars[0].aval.shape == pool
+    ]
+    assert len(updates) == 2  # the run of two (a scan of its own) and the run of one
+    assert sorted(len(scans) for scans in updates) == [2, 3]  # block > periods [> run]
+    for scans in updates:
+        for scan in scans:
+            n_c, n_k = scan.params["num_consts"], scan.params["num_carry"]
+            # (the block's own scan carries it with the stage axis in front)
+            assert pool in [v.aval.shape[-5:] for v in scan.invars[n_c : n_c + n_k]]
+    for eqn, _ in walked:
+        if eqn.primitive.name == "scan":
+            ys = [v.aval for v in eqn.outvars[eqn.params["num_carry"]:]]
+            assert not any(a.shape[-4:] == pool[-4:] for a in _scanned(eqn) + ys)
+    moved = [
+        eqn.primitive.name for eqn, _ in walked for v in eqn.outvars
+        if eqn.primitive.name in ("select_n", "concatenate", "pad", "slice", "gather", "copy")
+        and getattr(v.aval, "shape", ())[-3:] == pool[-3:] and v.aval.size >= 2 * 4 * 16 * 8
+        # x[0] of the stage axis is a slice that takes everything: no copy
+        and not (eqn.primitive.name == "slice" and v.aval.size == eqn.invars[0].aval.size)
+    ]
+    # the frozen-slot select of each body, over the SLOTS' rows of one layer
+    assert moved == ["select_n"] * 2, moved
+
+
 # ------------------------------------------- what rides the layer scan
 
 

@@ -172,6 +172,15 @@ CASES = {
         merged=True),
     # the same cell's prefill chunk: 512 rows against a 12288-row view
     "flash-prefill-latent-gqa": _flash(512, 12288, 8, 2, 128, 128),
+    # ... and at the granite4-h-micro-bf16 cell's: the first 64-wide heads.
+    # 48 slots, 32 query heads (4 rows a head) on 8 K/V heads of 64 merged on
+    # 512 lanes (odd heads start half a lane tile in), a table 9 pages wide,
+    # the four attention layers' pools viewed as one (4 x 433 pages)
+    "paged-gqa64-merged-page512": _paged(
+        512, False, slots=48, hq=32, hkv=8, d=64, max_seq=4608, pages=4 * 433,
+        merged=True),
+    # the same cell's prefill chunk: 512 rows against a 4608-row view
+    "flash-prefill-gqa64": _flash(512, 4608, 32, 8, 64, 64),
     # 4-bit projections of the 3B model: batch kernel at M=256, GEMV at 1, 8
     **{f"quant-M{m}-{i}x{o}": _quant(
         m, o, i, "quant_matmul" if m == 256 else "quant_gemv_pipelined")
