@@ -600,6 +600,38 @@ def child_kernels(seed: int, rehearse: bool) -> None:
           (m_x, m_dt, m_a, one_group(ks[3]), one_group(ks[4]), m_s0), 2e-3, relative=True)
     del m_x, m_s0
 
+    # ---- a decode step's recurrence in ONE pass over the state pool where it
+    # lies (ops/mamba2.py:ssm_pool_step) against the XLA one-step formula, at
+    # granite-4.0-h-micro's 48 slots x 64 heads on one group and nemotron3's
+    # 32 x 128 heads on 8 groups, the middle layer of three, one slot frozen:
+    # y, the layer's rows, and every row the step must not touch
+    from mlx_sharding_tpu.ops.mamba2 import ssm_pool_step
+
+    def pool_step_ref(pool, dt, x, bm, cm, a, active):
+        nb, rep = x.shape[0], x.shape[1] // bm.shape[1]
+        heads = lambda z: jnp.repeat(z, rep, axis=1)[:, None]  # noqa: E731
+        y, s = ssm_sequential(x[:, None], dt[:, None], a, heads(bm), heads(cm), pool[1, :nb])
+        s = jnp.where(active[:, None, None, None], s, pool[1, :nb])
+        return jnp.concatenate([y.ravel(), pool.at[1, :nb].set(s).ravel()])
+
+    def pool_step(pool, dt, x, bm, cm, a, active):
+        y, pool = ssm_pool_step(pool, 1, dt, x, bm, cm, a, active, interpret=rehearse)
+        return jnp.concatenate([y.ravel(), pool.ravel()])
+
+    for s_b, s_h, s_g in ((3, 4, 1), (3, 8, 2)) if rehearse else ((48, 64, 1), (32, 128, 8)):
+        ks = jax.random.split(key, 7)
+        key = ks[6]
+        check(f"mamba-2 decode step in one pass over the pool, {s_b} slots x {s_h} heads on {s_g} groups",
+              "ssm_pool_step", pool_step, pool_step_ref,
+              (jax.random.normal(ks[0], (3, s_b + 1, s_h, m_p, m_n), jnp.float32),
+               jax.nn.softplus(jax.random.normal(ks[1], (s_b, s_h)) - 3.0),
+               jax.random.normal(ks[2], (s_b, s_h, m_p), jnp.float32),
+               jax.random.normal(ks[3], (s_b, s_g, m_n), jnp.float32),
+               jax.random.normal(ks[4], (s_b, s_g, m_n), jnp.float32),
+               -jnp.exp(jax.random.uniform(ks[5], (s_h,), jnp.float32, 0.0, 2.5)),
+               jnp.ones((s_b,), bool).at[1].set(False)),
+              1e-6, relative=True)
+
     granite, _ = build_model(dict(
         model_type="granitemoehybrid", vocab_size=256, num_hidden_layers=1,
         layer_types=["mamba"], mamba_chunk_size=m_chunk, mamba_d_state=m_n,

@@ -33,9 +33,11 @@ periods whose body runs the period's runs of like layers, each run an inner
 scan: a compiled program holds the Mamba body once per run of a period, not
 once per layer. The recurrent state pool and the K/V (the engine's page pool
 in a ragged decode step, a slot's contiguous rows otherwise) ride the scans'
-CARRY whole; a layer reads its state rows by ``dynamic_slice`` and writes
-them by ``dynamic_update_slice`` at its rank, so the pool is updated where it
-lies. One pipeline stage, no tensor or expert parallelism: the state pool
+CARRY whole, and a layer is its rank in it: a decode step updates its rows of
+the SSM state in one pass where the pool lies (``ops.mamba2.ssm_pool_step``),
+a chunk reads them by ``dynamic_slice`` and writes them by
+``dynamic_update_slice``, as every step does the convolution's tails. One
+pipeline stage, no tensor or expert parallelism: the state pool
 and the scaled residual stream belong to one device.
 """
 
@@ -56,7 +58,7 @@ from mlx_sharding_tpu.models.base import (
     take_row,
 )
 from mlx_sharding_tpu.ops import causal_attention, rms_norm
-from mlx_sharding_tpu.ops.mamba2 import mamba2_mixer
+from mlx_sharding_tpu.ops.mamba2 import mamba2_mixer, put_rows, take_rows
 
 GROUP_OF = {"mamba": "mamba", "attention": "attn"}
 ONE_STAGE = (
@@ -161,28 +163,16 @@ class GraniteMoeHybridModel(BaseModel):
         Returns ``(out, state)``."""
         cfg = self.config
         nb = u.shape[0]
-        with jax.named_scope("mst.state_pool.regroup"):
-            mine = lambda x: jax.lax.dynamic_slice(  # noqa: E731
-                x, (rank,) + (0,) * (x.ndim - 1), (1, nb, *x.shape[2:])
-            )[0]
-            ssm = mine(state["ssm"])
-            tail = mine(state["conv"]).reshape(nb, cfg.mamba_d_conv - 1, self.conv_dim)
+        tail = take_rows(state["conv"], rank, nb)
         out, ssm, tail = mamba2_mixer(
-            self._linear, p, u, ssm, tail, n_valid, active,
+            self._linear, p, u, state["ssm"], rank,
+            tail.reshape(nb, cfg.mamba_d_conv - 1, self.conv_dim), n_valid, active,
             heads=cfg.mamba_n_heads, head_dim=cfg.mamba_d_head,
             groups=cfg.mamba_n_groups, state=cfg.mamba_d_state,
             taps=cfg.mamba_d_conv, chunk=cfg.mamba_chunk_size,
             eps=cfg.rms_norm_eps,
         )
-        with jax.named_scope("mst.state_pool.regroup"):
-            new = {"ssm": ssm, "conv": tail.reshape(nb, -1)}
-            state = {
-                name: jax.lax.dynamic_update_slice(
-                    x, new[name][None].astype(x.dtype),
-                    (rank,) + (0,) * (x.ndim - 1),
-                )
-                for name, x in state.items()
-            }
+        state = {"ssm": ssm, "conv": put_rows(state["conv"], rank, tail.reshape(nb, -1))}
         return out, state
 
     def _attn(self, p, u, k_buf, v_buf, offset, paged):

@@ -51,8 +51,10 @@ from mlx_sharding_tpu.models.base import (
 from mlx_sharding_tpu.ops import causal_attention, rms_norm
 from mlx_sharding_tpu.ops.mamba2 import (  # noqa: F401 — the recurrences stay importable from here
     mamba2_mixer,
+    put_rows,
     ssd_chunked,
     ssm_sequential,
+    take_rows,
 )
 from mlx_sharding_tpu.ops.moe import apply_experts, nemotron_routing
 
@@ -153,25 +155,28 @@ class NemotronHModel(BaseModel):
         return kv._replace(state=self.init_state(self._local_count("mamba"), batch, dtype))
 
     # -- mixers ------------------------------------------------------------
-    def _mamba(self, p, u, st, n_valid, active):
-        """``u (B,T,hidden)`` normed input; ``st`` this layer's ``{"ssm",
-        "conv"}``; rows past ``n_valid`` and sequences outside ``active`` do
-        not advance it. Returns ``(out (B,T,hidden), st)``. The mixer is
-        ``ops.mamba2``'s; this family keeps the convolution's tail as ``(B,
-        C, k-1)``."""
+    def _mamba(self, p, u, state, rank, n_valid, active):
+        """``u (B,T,hidden)`` normed input; ``state`` the pool ``{"ssm",
+        "conv"}: (layers, rows, …)``, this layer's at ``rank``, the ``B``
+        sequences' in its first ``B`` rows (the pool may carry rows past
+        them, an engine's scratch row: neither read nor written); rows past
+        ``n_valid`` and sequences outside ``active`` do not advance it.
+        Returns ``(out (B,T,hidden), state)``. The mixer is ``ops.mamba2``'s;
+        this family keeps the convolution's tail as ``(B, C, k-1)``."""
         cfg = self.config
+        tail = take_rows(state["conv"], rank, u.shape[0])
         with jax.named_scope("mst.ssm.conv"):
-            tail = jnp.swapaxes(st["conv"], 1, 2)  # (B,k-1,C)
-        out, s, tail = mamba2_mixer(
-            self._linear, p, u, st["ssm"], tail, n_valid, active,
+            tail = jnp.swapaxes(tail, 1, 2)  # (B,k-1,C)
+        out, ssm, tail = mamba2_mixer(
+            self._linear, p, u, state["ssm"], rank, tail, n_valid, active,
             heads=cfg.mamba_num_heads, head_dim=cfg.mamba_head_dim,
             groups=cfg.n_groups, state=cfg.ssm_state_size,
             taps=cfg.conv_kernel, chunk=cfg.chunk_size,
             eps=cfg.layer_norm_epsilon,
         )
         with jax.named_scope("mst.ssm.conv"):
-            tail = jnp.swapaxes(tail, 1, 2).astype(st["conv"].dtype)
-        return out, {"ssm": s, "conv": tail}
+            tail = jnp.swapaxes(tail, 1, 2)
+        return out, {"ssm": ssm, "conv": put_rows(state["conv"], rank, tail)}
 
     def _attn(self, p, u, k_buf, v_buf, offset, paged_attn):
         cfg = self.config
@@ -246,20 +251,7 @@ class NemotronHModel(BaseModel):
             p = _LayerRow(layer_params[group], rank)
             u = rms_norm(h, p["norm"], eps)
             if group == "mamba":
-                # the pool may carry rows past the batch (an engine's scratch
-                # row): they are neither read nor written
-                nb = h.shape[0]
-                with jax.named_scope("mst.state_pool.regroup"):
-                    st = jax.tree.map(lambda x: _take(x, rank)[:nb], state)
-                out, st = self._mamba(p, u, st, n_valid, active)
-                with jax.named_scope("mst.state_pool.regroup"):
-                    state = jax.tree.map(
-                        lambda x, new: jax.lax.dynamic_update_slice(
-                            x, new[None].astype(x.dtype),
-                            (rank,) + (0,) * (x.ndim - 1),
-                        ),
-                        state, st,
-                    )
+                out, state = self._mamba(p, u, state, rank, n_valid, active)
             elif group == "attn":
                 take = lambda pool: jax.tree.map(lambda x: _take(x, rank), pool)  # noqa: E731
                 out, k_l, v_l = self._attn(p, u, take(k), take(v), offset, paged_attn)

@@ -385,8 +385,9 @@ class ServingMetrics:
                 ]
             except Exception:  # noqa: BLE001 — scrape must not 500
                 del lines[lmark:]
-            # which path each packed matmul, each ragged paged-attention call
-            # and each routed-expert call took while its program was traced.
+            # which path each packed matmul, each ragged paged-attention call,
+            # each routed-expert call and each decode step's Mamba-2
+            # recurrence took while its program was traced.
             # Only where the ops are loaded: a process that never imported
             # them dispatched nothing, and a scrape imports no JAX.
             for type_line, module in (
@@ -394,6 +395,7 @@ class ServingMetrics:
                 ("# TYPE mst_paged_attention_dispatch_total counter",
                  "paged_attention"),
                 ("# TYPE mst_moe_dispatch_total counter", "moe"),
+                ("# TYPE mst_ssm_dispatch_total counter", "mamba2"),
             ):
                 ops = sys.modules.get(f"mlx_sharding_tpu.ops.{module}")
                 if ops is not None:
@@ -1160,6 +1162,11 @@ _HELP = {
         "expert parallelism); gather_packed and gather copy every pick's "
         "whole expert out of the stacks first (0 on a chip where the decode "
         "step is packed and inside the kernel's contract).",
+    "mst_ssm_dispatch_total":
+        "Decode steps' Mamba-2 recurrences by the path ops/mamba2 chose, one "
+        "count per traced call: kernel updates the layer's rows of the state "
+        "pool where they lie in one pass; xla slices them out, passes over "
+        "them twice and writes them back (0 on a chip).",
     "mst_faults_armed":
         "Currently armed fault-injection sites (should be 0 in prod).",
     "mst_faults_malformed_total":

@@ -134,6 +134,22 @@ def _experts(n, k, e, hidden, width):
     )
 
 
+def _ssm_step(layers, slots, heads, groups, head_dim=64, state=128):
+    """``ops.mamba2.ssm_pool_step`` on a ``(layers, slots + 1, H, P, N)``
+    float32 state pool (the scratch row past the slots), the layer's rank an
+    operand: the cell's decode step of one Mamba-2 layer."""
+    from mlx_sharding_tpu.ops.mamba2 import ssm_pool_step
+
+    return (
+        ssm_pool_step,
+        [((layers, slots + 1, heads, head_dim, state), F32), ((), I32),
+         ((slots, heads), F32), ((slots, heads, head_dim), F32),
+         ((slots, groups, state), F32), ((slots, groups, state), F32),
+         ((heads,), F32), ((slots,), jnp.bool_)],
+        "ssm_pool_step",
+    )
+
+
 LLAMA_3B = [(8192, 3072), (3072, 8192), (128256, 3072)]
 CASES = {
     # flash prefill chunk and T=1 at Llama-3B heads; the MLA shapes
@@ -181,6 +197,13 @@ CASES = {
         merged=True),
     # the same cell's prefill chunk: 512 rows against a 4608-row view
     "flash-prefill-gqa64": _flash(512, 4608, 32, 8, 64, 64),
+    # the one-pass Mamba-2 decode step on the state pool where it lies, at
+    # the granite4-h-micro-bf16 cell's shapes (36 layers, 48 slots, 64 heads
+    # of 64 on ONE group: a slot's 2 MB one block) and at the
+    # nemotron3-super-bf16-ep4 cell's (5 layers, 32 slots, 128 heads on 8
+    # groups: two blocks of 64 heads, four groups each)
+    "ssm-step-granite": _ssm_step(36, 48, 64, 1),
+    "ssm-step-nemotron3": _ssm_step(5, 32, 128, 8),
     # 4-bit projections of the 3B model: batch kernel at M=256, GEMV at 1, 8
     **{f"quant-M{m}-{i}x{o}": _quant(
         m, o, i, "quant_matmul" if m == 256 else "quant_gemv_pipelined")
@@ -336,3 +359,35 @@ def test_latent_attention_gathers_no_table_and_copies_no_pool(chip, monkeypatch)
     made = _arrays_made(_loop_bodies(text), slots * spg * page * dk * 2)
     assert set(made) <= {("dynamic-update-slice", whole), ("fusion", whole)}, made
     assert not re.search(r"\[\d+,4096,", _loop_bodies(text))  # no max_seq-dense view
+
+
+def test_ssm_step_in_a_layer_scan_moves_nothing_but_its_blocks(chip):
+    """A layer scan that carries granite-4.0-h-micro's whole state pool
+    (36 x 49 rows of 64 x 64 x 128 float32, 3.7 GB) and calls
+    ``ssm_pool_step`` at the scanned rank: the pool is the custom call's
+    operand where it lies, aliased to its result — the program makes no
+    array as large as one layer's rows (100 MB; a ``dynamic-slice``, a
+    ``select`` or a ``dynamic-update-slice`` fusion of them is what the XLA
+    formula compiles to) and no copy of the pool (3.7 GB of temporaries: the
+    one way an aliased call goes wrong silently)."""
+    from mlx_sharding_tpu.ops.mamba2 import ssm_pool_step
+
+    _, shapes, kernel = _ssm_step(36, 48, 64, 1)
+
+    def walk(pool, _rank, dt, x, b_mat, c_mat, a_head, active):
+        def layer(carry, rank):
+            pool, acc = carry
+            y, pool = ssm_pool_step(pool, rank, dt, x + acc, b_mat, c_mat, a_head, active)
+            return (pool, y), None
+
+        return jax.lax.scan(layer, (pool, jnp.zeros_like(x)), jnp.arange(pool.shape[0]))[0]
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    compiled = jax.jit(walk, donate_argnums=0).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and kernel in text
+    assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(5, \{\}\)\}", text)
+    rows = 48 * 64 * 64 * 128 * 4
+    # (the call's own result is a tuple, which ``_arrays_made`` does not read)
+    assert _arrays_made(text, rows) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < rows
