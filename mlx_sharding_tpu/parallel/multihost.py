@@ -38,6 +38,7 @@ import numpy as np
 from mlx_sharding_tpu.sample import (
     init_recent_tokens,
     make_sampler_params,
+    sampler_params_host,
 )
 from mlx_sharding_tpu.testing.faults import inject
 from mlx_sharding_tpu.utils.clock import MONOTONIC, Clock
@@ -504,8 +505,10 @@ def _req_from_msg(msg):
     bias = _unpack_bias(msg["bias_idx"], msg["bias_val"], n_bias)
     return _Request(
         prompt=np.asarray(msg["tokens"][:n_prompt], np.int32),
-        sp=make_sampler_params(
-            temperature, top_p, rep_pen if has_pen else None, bias
+        # the row a slot claim writes: numpy, as wide as the batch's
+        sp=sampler_params_host(
+            temperature, top_p, rep_pen if has_pen else None, bias,
+            slots=_BIAS_SLOTS,
         ),
         seed=seed,
         max_tokens=max_tokens,

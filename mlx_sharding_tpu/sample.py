@@ -20,6 +20,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 class SamplerParams(NamedTuple):
@@ -32,6 +33,39 @@ class SamplerParams(NamedTuple):
     bias_values: jax.Array  # (K,) f32, pad with 0 (no-op)
 
 
+def sampler_params_host(
+    temperature: float = 0.0,
+    top_p: float = 1.0,
+    repetition_penalty: Optional[float] = None,
+    logit_bias: Optional[dict[int, float]] = None,
+    *,
+    slots: int,
+) -> SamplerParams:
+    """One request's sampler row as ``numpy`` arrays of fixed dtypes, its
+    bias buffers ``slots`` wide: nothing is dispatched, so a caller that
+    hands the row to a jitted program itself (the scheduler's slot claim,
+    whose row is as wide as the batch's) pays for no eager operation.
+    Every entry of ``logit_bias`` is applied — the reference applies all of
+    them too (shard/utils.py:128-131)."""
+    n = len(logit_bias) if logit_bias else 0
+    if n > slots:
+        raise ValueError(f"logit_bias with {n} entries exceeds {slots} slots")
+    bias_idx = np.zeros((slots,), np.int32)
+    bias_val = np.zeros((slots,), np.float32)
+    if logit_bias:
+        bias_idx[:n] = [int(k) for k in logit_bias]
+        bias_val[:n] = [float(v) for v in logit_bias.values()]
+    return SamplerParams(
+        temperature=np.asarray(temperature, np.float32),
+        top_p=np.asarray(top_p, np.float32),
+        repetition_penalty=np.asarray(
+            1.0 if repetition_penalty is None else repetition_penalty, np.float32
+        ),
+        bias_indices=bias_idx,
+        bias_values=bias_val,
+    )
+
+
 def make_sampler_params(
     temperature: float = 0.0,
     top_p: float = 1.0,
@@ -40,29 +74,23 @@ def make_sampler_params(
     min_bias_slots: int = 16,
 ) -> SamplerParams:
     # Buffer sized to the request (rounded to a power of two so distinct bias
-    # counts reuse a handful of compiled programs); every entry is applied —
-    # the reference applies all of them too (shard/utils.py:128-131).
+    # counts reuse a handful of compiled programs).
     n = len(logit_bias) if logit_bias else 0
     slots = max(min_bias_slots, 1 << (n - 1).bit_length() if n else 0)
-    bias_idx = jnp.zeros((slots,), jnp.int32)
-    bias_val = jnp.zeros((slots,), jnp.float32)
-    if logit_bias:
-        items = list(logit_bias.items())
-        bias_idx = bias_idx.at[: len(items)].set(
-            jnp.asarray([int(k) for k, _ in items], jnp.int32)
-        )
-        bias_val = bias_val.at[: len(items)].set(
-            jnp.asarray([float(v) for _, v in items], jnp.float32)
-        )
-    return SamplerParams(
-        temperature=jnp.asarray(temperature, jnp.float32),
-        top_p=jnp.asarray(top_p, jnp.float32),
-        repetition_penalty=jnp.asarray(
-            1.0 if repetition_penalty is None else repetition_penalty, jnp.float32
-        ),
-        bias_indices=bias_idx,
-        bias_values=bias_val,
-    )
+    return jax.tree.map(jnp.asarray, sampler_params_host(
+        temperature, top_p, repetition_penalty, logit_bias, slots=slots
+    ))
+
+
+def seed_key_row(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)``'s two words, made on the host: the
+    default (threefry) key of an integer seed is its high and low 32 bits,
+    and without ``jax_enable_x64`` the seed is cut to 32 bits first, so the
+    high word is 0. Any integer ``PRNGKey`` takes (64 signed bits) gives the
+    same row bit for bit; a larger one raises ``OverflowError`` as it does."""
+    s = int(np.int64(seed))
+    hi = (s >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([hi, s & 0xFFFFFFFF], np.uint32)
 
 
 def apply_logit_bias(logits: jax.Array, indices: jax.Array, values: jax.Array):
@@ -200,26 +228,6 @@ def stack_sampler_params(params_list: list[SamplerParams]) -> SamplerParams:
     return jax.tree.map(lambda *xs: jnp.stack(xs), *[pad(p) for p in params_list])
 
 
-def set_sampler_slot(
-    batched: SamplerParams, slot: int, one: SamplerParams
-) -> SamplerParams:
-    """Write one request's params into row ``slot`` of a batched pytree
-    (bias buffers truncated/padded to the batched width)."""
-    width = batched.bias_indices.shape[1]
-    n = one.bias_indices.shape[0]
-    if n < width:
-        one = one._replace(
-            bias_indices=jnp.pad(one.bias_indices, (0, width - n)),
-            bias_values=jnp.pad(one.bias_values, (0, width - n)),
-        )
-    elif n > width:
-        raise ValueError(
-            f"logit_bias with {n} entries exceeds the scheduler's per-slot "
-            f"bias width {width}"
-        )
-    return jax.tree.map(lambda full, x: full.at[slot].set(x), batched, one)
-
-
 def transform_logits_batched(
     logits: jax.Array,  # (B, V)
     recent_tokens: jax.Array,  # (B, W) int32, -1 padded
@@ -308,8 +316,6 @@ def init_recent_tokens(batch: int, window: int, prompt=None) -> jax.Array:
     shard/utils.py:152-155). ``prompt``: optional (B, T) array-like."""
     recent = jnp.full((batch, window), -1, jnp.int32)
     if prompt is not None:
-        import numpy as _np
-
-        tail = _np.asarray(prompt, _np.int32)[:, -window:]
+        tail = np.asarray(prompt, np.int32)[:, -window:]
         recent = recent.at[:, window - tail.shape[1] :].set(jnp.asarray(tail))
     return recent

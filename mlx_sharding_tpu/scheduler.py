@@ -49,6 +49,7 @@ from __future__ import annotations
 
 import hashlib
 import logging
+import math
 import queue
 import threading
 import time
@@ -98,6 +99,8 @@ from mlx_sharding_tpu.utils.observability import (
 from mlx_sharding_tpu.sample import (
     SamplerParams,
     make_sampler_params,
+    sampler_params_host,
+    seed_key_row,
     sample_token_batched,
     stack_sampler_params,
 )
@@ -200,6 +203,32 @@ class _Request:
     _t_submit: float = 0.0
     _t_join: float = 0.0  # slot claimed (mst_join_seconds runs from here)
     _t_last_emit: float = 0.0
+
+
+def _pack_i32(*parts) -> np.ndarray:
+    """A program's host-made values as ONE int32 array: every part is a
+    numpy value of a 32-bit dtype, laid end to end by its bits (a float32
+    or uint32 part is read back with ``_Unpack.take(..., dtype)`` bit for
+    bit). One array because each array argument of a dispatch is one more
+    point at which the calling thread lets go of the interpreter lock."""
+    parts = [np.ravel(p) for p in parts]
+    if any(p.dtype.itemsize != 4 for p in parts):
+        raise TypeError(f"32-bit parts only: {[p.dtype for p in parts]}")
+    return np.concatenate([p.view(np.int32) for p in parts])
+
+
+class _Unpack:
+    """Reads a ``_pack_i32`` argument back inside the jitted program, part
+    by part in the order it was packed."""
+
+    def __init__(self, packed):
+        self.packed, self.at = packed, 0
+
+    def take(self, shape=(), dtype=jnp.int32):
+        n = math.prod(shape)
+        part = self.packed[self.at:self.at + n]
+        self.at += n
+        return jax.lax.bitcast_convert_type(part, dtype).reshape(shape)
 
 
 @dataclass
@@ -602,36 +631,82 @@ class ContinuousBatcher:
         # on process-spanning arrays are not executable. Single-host, _put is
         # the identity and the jitted setters behave exactly like the eager
         # .at[].set they replace.
-        self._multi = jax.process_count() > 1
-        if self._multi:
-            from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
-            from mlx_sharding_tpu.parallel.pipeline import put_global
+        from mlx_sharding_tpu.parallel.pipeline import put_global
 
-            # every rank mirrors the same op stream, so the host value being
-            # committed is identical by construction — put_global skips
-            # device_put's cross-host assert broadcast
-            rep = NamedSharding(engine.mesh, P())
-            self._put = lambda x: put_global(x, rep)
-        else:
-            self._put = lambda x: x
+        rep = NamedSharding(engine.mesh, P())
+
+        # the per-slot arrays start where every program hands them back —
+        # committed, replicated over the engine's mesh — so the first call
+        # of a program that takes them is its only compile (an array that
+        # starts uncommitted makes the second call another)
+        def place(x):
+            return put_global(x, rep)
+
+        # every rank mirrors the same op stream, so the host value being
+        # committed is identical by construction — put_global skips
+        # device_put's cross-host assert broadcast
+        self._put = place if jax.process_count() > 1 else (lambda x: x)
         # every jitted helper is a named function: the name is the program's
         # in a profile (``jit_<name>``) and in a compile log, and no two
         # served programs share one (tests/test_program_names.py)
         def row_set(arr, slot, val):
             return arr.at[slot].set(val)
 
-        def sp_set(batched, one, slot):
-            return jax.tree.map(
-                lambda full, x: full.at[slot].set(x), batched, one
+        # A join reaches the device as three programs — the claim, each
+        # prefill chunk, the first token — and what each takes from the
+        # host is ONE numpy array (_pack_i32): after a drain the streams'
+        # threads have tokens to write, and every eager operation, every
+        # dispatch and every array argument of a dispatch is one more point
+        # at which this thread lets go of the interpreter lock and waits to
+        # get it back. None of the three takes the cache: a jitted program
+        # hands back what passes through it as a copy unless donated, so
+        # they take the small per-slot arrays alone and donate the ones
+        # they rewrite. A batcher without a page table or without a draft
+        # passes None there.
+        def claim_slot(packed, table, offset, sp, rep_sizes, doffset):
+            u = _Unpack(packed)
+            slot, start, rep_context = u.take(), u.take(), u.take()
+            if table is not None:
+                table = table.at[slot].set(u.take(table.shape[1:]))
+            offset = offset.at[slot].set(start)
+            sp = jax.tree.map(  # leaf by leaf in the order they were packed
+                lambda full: full.at[slot].set(
+                    u.take(full.shape[1:], full.dtype)
+                ),
+                sp,
             )
+            rep_sizes = rep_sizes.at[slot].set(rep_context)
+            if doffset is not None:  # the draft mirrors the slot from 0
+                doffset = doffset.at[slot].set(0)
+            return table, offset, sp, rep_sizes, doffset
 
-        def set_last(lt, slot, tok):
-            return lt.at[slot, 0].set(tok)
+        def seed_rows(u, keys, recent):
+            slot = u.take()
+            keys = keys.at[slot].set(u.take(keys.shape[1:], keys.dtype))
+            recent = recent.at[slot].set(u.take(recent.shape[1:]))
+            return slot, keys, recent
+
+        def finish_join(logits, packed, keys, recent, sp, rep_sizes,
+                        last_tok, active):
+            slot, keys, recent = seed_rows(_Unpack(packed), keys, recent)
+            tok, logprobs, keys, recent = self.first_sample(
+                logits, keys, sp, recent, rep_sizes, slot
+            )
+            return (tok, logprobs, keys, recent,
+                    last_tok.at[slot, 0].set(tok), active.at[slot].set(True))
+
+        def resume_slot(packed, keys, recent, last_tok, active):
+            u = _Unpack(packed)
+            slot, keys, recent = seed_rows(u, keys, recent)
+            return (keys, recent, last_tok.at[slot, 0].set(u.take()),
+                    active.at[slot].set(True))
 
         self._row_set = jax.jit(row_set)
-        self._sp_set = jax.jit(sp_set)
-        self._set_last = jax.jit(set_last)
+        self._claim_slot = jax.jit(claim_slot, donate_argnums=(1, 2, 3, 4, 5))
+        self._finish_join = jax.jit(finish_join, donate_argnums=(2, 3, 6, 7))
+        self._resume_slot = jax.jit(resume_slot, donate_argnums=(1, 2, 3, 4))
         self._zeros_like = jax.jit(jnp.zeros_like)
         self._rewind_offset = jax.jit(rewind_slot_offset)
 
@@ -834,6 +909,12 @@ class ContinuousBatcher:
         self._blocks_by_sampler = dict.fromkeys(
             ("greedy", "draw", "nucleus"), 0
         )
+        # dispatches a join made between its drain and the slot decoding,
+        # by program: the claim, each prefill chunk (the draft's too), the
+        # first token; other: a block import's resume_slot
+        self._join_programs = dict.fromkeys(
+            ("claim", "chunk", "finish", "other"), 0
+        )
         # always-on latency histograms (/metrics): inter-token latency at
         # the emit path, admission queue wait at slot assignment. These are
         # the metric itself (a lock + bisect per observation, same grade as
@@ -947,26 +1028,21 @@ class ContinuousBatcher:
         else:
             self.cache = engine.init_cache()
             # dummy for the step arg
-            self.table = self._put(jnp.zeros((1, 1), jnp.int32))
-        self.recent = self._put(jnp.full((self.M, self.W), -1, jnp.int32))
-        self.keys = self._put(jnp.stack([jax.random.PRNGKey(0)] * self.M))
+            self.table = place(jnp.zeros((1, 1), jnp.int32))
+        self.recent = place(jnp.full((self.M, self.W), -1, jnp.int32))
+        self.keys = place(jnp.stack([jax.random.PRNGKey(0)] * self.M))
         # bias width 512 covers OpenAI's documented logit_bias cap (300);
         # larger requests are rejected on the submitting thread
-        self.sp = jax.tree.map(
-            self._put,
-            stack_sampler_params(
-                [make_sampler_params(min_bias_slots=512) for _ in range(self.M)]
-            ),
-        )
-        self.rep_sizes = self._put(jnp.full((self.M,), self.W, jnp.int32))
-        self.active = self._put(jnp.zeros((self.M,), bool))
-        self.last_tok = self._put(jnp.zeros((self.M, 1), jnp.int32))
+        self.sp = place(stack_sampler_params(
+            [make_sampler_params(min_bias_slots=512) for _ in range(self.M)]
+        ))
+        self.rep_sizes = place(jnp.full((self.M,), self.W, jnp.int32))
+        self.active = place(jnp.zeros((self.M,), bool))
+        self.last_tok = place(jnp.zeros((self.M, 1), jnp.int32))
 
         # host-side slot table
         self._slots: list[Optional[_Request]] = [None] * self.M
         self._prefill_rr = 0  # round-robin cursor for admission fairness
-
-        self._first_sample = jax.jit(self.first_sample)
 
     # ------------------------------------------------------------- public
     def generate_step(
@@ -1056,13 +1132,17 @@ class ContinuousBatcher:
                 f"pages, pool has {self.engine.pool_pages} — it could never "
                 "be admitted"
             )
-        sp = make_sampler_params(temperature, top_p, repetition_penalty, logit_bias)
-        if sp.bias_indices.shape[0] > self.sp.bias_indices.shape[1]:
+        width = self.sp.bias_indices.shape[1]
+        if logit_bias and len(logit_bias) > width:
             raise ValueError(
                 f"logit_bias with {len(logit_bias)} entries exceeds the "
-                f"scheduler's per-slot bias width "
-                f"{self.sp.bias_indices.shape[1]}"
+                f"scheduler's per-slot bias width {width}"
             )
+        # the request's sampler row, as wide as the batch's and made on
+        # the host: the slot claim hands it to its program as it is
+        sp = sampler_params_host(
+            temperature, top_p, repetition_penalty, logit_bias, slots=width
+        )
         if repetition_penalty is not None and repetition_context_size > self.W:
             # silently shrinking the window would make --concurrent output
             # diverge from the serial path for the same request
@@ -1104,9 +1184,9 @@ class ContinuousBatcher:
             req.history = hist
             req._block = block
             if resume_keys is not None:
-                req.resume_keys = np.asarray(resume_keys)
+                req.resume_keys = np.asarray(resume_keys, np.uint32)
             if resume_recent is not None:
-                req.resume_recent = np.asarray(resume_recent)
+                req.resume_recent = np.asarray(resume_recent, np.int32)
             with self._admission_lock:
                 self.migrations_in += 1
         # Bind (or self-begin) the request's span timeline. The server and
@@ -1413,6 +1493,7 @@ class ContinuousBatcher:
             "tokens_dropped": dict(self._tokens_dropped),
             "drains": dict(self._drains),
             "blocks_by_sampler": dict(self._blocks_by_sampler),
+            "join_programs": dict(self._join_programs),
         }
 
     def state_stats(self) -> Optional[dict]:
@@ -1568,20 +1649,25 @@ class ContinuousBatcher:
             self.prefix_evictions += 1
             _note_pages(self, (p,), acquired=False)
 
-    def _write_table_row(self, slot: int, pages: list):
-        """Publish a slot's page mapping to the device table and bump the
-        pool high-water mark. Unmapped tail entries stay at the scratch
+    def _table_row(self, pages: list) -> np.ndarray:
+        """A slot's page mapping as the device table's row, and the pool's
+        high-water mark bumped. Unmapped tail entries stay at the scratch
         page (index pool_pages): overshoot writes past the mapping land
         there harmlessly."""
         row = np.full((self.engine.slot_pages,), self.engine.pool_pages,
                       np.int32)
         row[: len(pages)] = pages
-        self.table = self._row_set(
-            self.table, self._put(jnp.asarray(slot, jnp.int32)),
-            self._put(jnp.asarray(row)),
-        )
         in_use = self.engine.pool_pages - len(self._free_pages)
         self.pages_high_water = max(self.pages_high_water, in_use)
+        return row
+
+    def _write_table_row(self, slot: int, pages: list):
+        """Publish a decoding slot's grown mapping (a claim writes its row
+        in its own program)."""
+        self.table = self._row_set(
+            self.table, self._put(np.int32(slot)),
+            self._put(self._table_row(pages)),
+        )
 
     def _unref_pages(self, pages):
         for p in pages:
@@ -1990,13 +2076,12 @@ class ContinuousBatcher:
         return tok[0], logprobs, keys, recent
 
     def _assign_slot(self, req: _Request, slot: int):
-        """Claim ``slot`` for ``req`` and reset its device-side state: offset
-        0, repetition window seeded from the prompt tail (same as
-        init_recent_tokens in the serial path), the request's sampler params
-        and PRNG key. Prefill happens incrementally in the loop — one chunk
-        per scheduler tick — so active slots keep decoding during admission."""
-        prompt = req.prompt
-        slot_arr = self._put(jnp.asarray(slot, jnp.int32))
+        """Claim ``slot`` for ``req``: the host's bookkeeping (pages,
+        references, eviction, prefix chain or store plan), then the slot's
+        device-side state in one program (``_claim``). The PRNG key and the
+        repetition window are seeded with the first token, not here.
+        Prefill happens incrementally in the loop — one chunk per scheduler
+        tick — so active slots keep decoding during admission."""
         # queue wait ends here: submit (or re-queue after preempt/wake) →
         # slot assignment. Histogram always; span only when traced.
         now = time.perf_counter()
@@ -2010,10 +2095,12 @@ class ContinuousBatcher:
         req.admit_seq = self._admit_counter
         self._admit_counter += 1
         block = self._take_block(req)
-        if block is not None and self._import_block(req, slot, slot_arr, block):
+        if block is not None and self._import_block(req, slot, block):
             return
+        pages = None
         if self.paged:
             n = self._need_pages(req)
+            got = None
             if self.prefix_store is not None:
                 # one admitted request == one token of insert budget (the
                 # deterministic damping clock — no wall time on this path)
@@ -2024,64 +2111,36 @@ class ContinuousBatcher:
                     self.prefix_store.count_lookup(
                         "miss", self._store_digests(req) or None
                     )
-                if got is not None:
-                    pages, reused_tokens = got
-                    self._pages_of[slot] = pages
-                    self._write_table_row(slot, pages)
-                    self.cache = self.cache._replace(
-                        offset=self._row_set(
-                            self.cache.offset, slot_arr,
-                            self._put(jnp.asarray(reused_tokens, jnp.int32)),
-                        )
-                    )
-                    self._write_sampler_row(req, slot_arr)
-                    self._slots[slot] = req
-                    note_acquire("scheduler.slot", (id(self), slot))
-                    req.slot = slot
-                    # prefill only the uncovered tail; the shared (or
-                    # imported) prefix KV is already mapped to this slot
-                    req.prefill_pos = reused_tokens
-                    return
-            chain = req._chain if req._chain is not None else self._prefix_lookup(req)
-            req._chain = None
-            if self.prefix_cache:
-                self.prefix_queries += 1
-                if chain:
-                    self.prefix_hits += 1
-                    reused_tokens = len(chain) * self.engine.page_size
-                    self.prefix_tokens_reused += reused_tokens
-                for key, _ in chain:
-                    self._prefix_index.move_to_end(key)
-            shared = [p for _, p in chain]
-            # claim the chain BEFORE evicting: at ref 2 its pages are
-            # invisible to _evict_for, which must only reclaim OTHER
-            # index-only pages (matching the _fits exclude accounting)
-            for p in shared:
-                self._page_ref[p] += 1
-            self._evict_for(n - len(shared))
-            pages = shared + [
-                self._free_pages.pop() for _ in range(n - len(shared))
-            ]
-            _note_pages(self, pages[len(shared):], acquired=True)
-            for p in pages[len(shared):]:
-                self._page_ref[p] = 1
+            if got is not None:
+                # the shared (or imported) prefix KV is already mapped
+                pages, reused_tokens = got
+            else:
+                chain = (req._chain if req._chain is not None
+                         else self._prefix_lookup(req))
+                req._chain = None
+                if self.prefix_cache:
+                    self.prefix_queries += 1
+                    if chain:
+                        self.prefix_hits += 1
+                        reused_tokens = len(chain) * self.engine.page_size
+                        self.prefix_tokens_reused += reused_tokens
+                    for key, _ in chain:
+                        self._prefix_index.move_to_end(key)
+                shared = [p for _, p in chain]
+                # claim the chain BEFORE evicting: at ref 2 its pages are
+                # invisible to _evict_for, which must only reclaim OTHER
+                # index-only pages (matching the _fits exclude accounting)
+                for p in shared:
+                    self._page_ref[p] += 1
+                self._evict_for(n - len(shared))
+                pages = shared + [
+                    self._free_pages.pop() for _ in range(n - len(shared))
+                ]
+                _note_pages(self, pages[len(shared):], acquired=True)
+                for p in pages[len(shared):]:
+                    self._page_ref[p] = 1
             self._pages_of[slot] = pages
-            self._write_table_row(slot, pages)
-        self.cache = self.cache._replace(
-            offset=self._row_set(
-                self.cache.offset, slot_arr,
-                self._put(jnp.asarray(reused_tokens, jnp.int32)),
-            )
-        )
-        self._write_sampler_row(req, slot_arr)
-        if self.draft is not None:
-            # the draft mirrors the slot from position 0 (no page sharing)
-            self.dcache = self.dcache._replace(
-                offset=self._row_set(
-                    self.dcache.offset, slot_arr,
-                    self._put(jnp.asarray(0, jnp.int32)),
-                )
-            )
+        self._claim(req, slot, pages, reused_tokens)
         if self.spec_tracker is not None:
             # new stream in the slot: window back to the probe rung, no
             # carried-over acceptance history from the previous occupant
@@ -2092,22 +2151,29 @@ class ContinuousBatcher:
         # prefill starts past the reused prefix — its KV is already mapped
         req.prefill_pos = reused_tokens
 
-    def _write_sampler_row(self, req: _Request, slot_arr):
-        # pad the request's sampler params to the batched width host-side,
-        # then write its row inside jit (set_sampler_slot is eager)
-        width = self.sp.bias_indices.shape[1]
-        one = req.sp
-        n_bias = one.bias_indices.shape[0]
-        if n_bias < width:
-            one = one._replace(
-                bias_indices=jnp.pad(one.bias_indices, (0, width - n_bias)),
-                bias_values=jnp.pad(one.bias_values, (0, width - n_bias)),
-            )
-        self.sp = self._sp_set(self.sp, jax.tree.map(self._put, one), slot_arr)
-        self.rep_sizes = self._row_set(
-            self.rep_sizes, slot_arr,
-            self._put(jnp.asarray(req.rep_context, jnp.int32)),
+    def _claim(self, req: _Request, slot: int, pages: Optional[list],
+               start: int):
+        """The claim's device writes, one dispatch with one host-made
+        argument: the slot's table row (paged), its offset ``start``, the
+        request's sampler row and repetition context, the draft's offset 0
+        (with a draft)."""
+        draft = self.draft is not None
+        table, offset, self.sp, self.rep_sizes, doffset = self._claim_slot(
+            self._put(_pack_i32(
+                np.int32(slot), np.int32(start), np.int32(req.rep_context),
+                *([self._table_row(pages)] if self.paged else []),
+                *req.sp,
+            )),
+            self.table if self.paged else None,
+            self.cache.offset, self.sp, self.rep_sizes,
+            self.dcache.offset if draft else None,
         )
+        self._join_programs["claim"] += 1
+        if self.paged:
+            self.table = table
+        self.cache = self.cache._replace(offset=offset)
+        if draft:
+            self.dcache = self.dcache._replace(offset=doffset)
 
     def _take_block(self, req: _Request) -> Optional[object]:
         """Resolve the request's pending KVPageBlock, if any: one handed in
@@ -2128,7 +2194,7 @@ class ContinuousBatcher:
                 self.spill_fallbacks += 1
         return block
 
-    def _import_block(self, req: _Request, slot: int, slot_arr, block) -> bool:
+    def _import_block(self, req: _Request, slot: int, block) -> bool:
         """Admission via page import: allocate the request's pages and
         scatter the block's payload into them instead of re-prefilling,
         then restore the sampler state the block carries — offset, PRNG
@@ -2200,28 +2266,18 @@ class ContinuousBatcher:
                 self.spill_fallbacks += 1
             return False
         self._pages_of[slot] = pages
-        self._write_table_row(slot, pages)
         # offset = valid KV rows; the next decode step writes row n_tokens
-        self.cache = self.cache._replace(
-            offset=self._row_set(
-                self.cache.offset, slot_arr,
-                self._put(jnp.asarray(block.n_tokens, jnp.int32)),
-            )
+        self._claim(req, slot, pages, block.n_tokens)
+        self.keys, self.recent, self.last_tok, self.active = self._resume_slot(
+            self._put(_pack_i32(
+                np.int32(slot),
+                np.asarray(block.resume_keys, np.uint32),
+                np.asarray(block.resume_recent, np.int32),
+                np.int32(block.last_tok),
+            )),
+            self.keys, self.recent, self.last_tok, self.active,
         )
-        self._write_sampler_row(req, slot_arr)
-        self.recent = self._row_set(
-            self.recent, slot_arr, self._put(jnp.asarray(block.resume_recent))
-        )
-        self.keys = self._row_set(
-            self.keys, slot_arr, self._put(jnp.asarray(block.resume_keys))
-        )
-        self.last_tok = self._set_last(
-            self.last_tok, slot_arr,
-            self._put(jnp.asarray(block.last_tok, jnp.int32)),
-        )
-        self.active = self._row_set(
-            self.active, slot_arr, self._put(jnp.asarray(True))
-        )
+        self._join_programs["other"] += 1
         req.resume_keys = None
         req.resume_recent = None
         req.history = [int(t) for t in block.history]
@@ -2258,11 +2314,14 @@ class ContinuousBatcher:
         """Run ONE prefill chunk for a mid-admission request — on the target
         and, when speculating, the draft, each at its own position (a prefix
         hit advances only the target's start). On the last chunk of BOTH,
-        sample the first token and activate the slot for decode; the
-        target's final-chunk logits are stashed while the draft catches up."""
+        sample the first token and activate the slot for decode, in one
+        program (``finish_join``); the target's final-chunk logits are
+        stashed while the draft catches up. The chunk's tokens, count and
+        slot are numpy arrays of fixed dtypes: nothing eager runs here."""
         eng = self.engine
         c = eng.prefill_chunk
-        slot_arr = self._put(jnp.asarray(req.slot, jnp.int32))
+        put = self._put
+        slot_arr = put(np.int32(req.slot))
         tr = req._trace
         t0 = time.perf_counter() if tr is not None else 0.0
         if req.prefill_pos < req.prompt.size:
@@ -2270,27 +2329,29 @@ class ContinuousBatcher:
             if self._recurrent and req.prefill_pos == 0:
                 self.state_resets += 1
             self._note_ring_page(req.prefill_pos)
-            tokens = self._put(jnp.asarray(chunk[None]))
-            valid = self._put(jnp.asarray(n_valid, jnp.int32))
+            tokens = put(chunk[None])
+            valid = put(np.int32(n_valid))
             self._phases.device(True)  # from here the device has the chunk
             logits, self.cache = eng.prefill_slot()(
                 eng.layer_params, eng.layer_masks, eng.vocab_parts,
                 eng.shared_params, tokens, slot_arr, self.cache, valid,
                 self.table if self.paged else None,
             )
+            self._join_programs["chunk"] += 1
             req.prefill_pos += n_valid
             if req.prefill_pos >= req.prompt.size:
                 req._last_logits = logits
         if self.draft is not None and req.draft_pos < req.prompt.size:
             d = self.draft
             chunk, n_valid = self._chunk_at(req.prompt, req.draft_pos, c)
-            tokens = self._put(jnp.asarray(chunk[None]))
-            valid = self._put(jnp.asarray(n_valid, jnp.int32))
+            tokens = put(chunk[None])
+            valid = put(np.int32(n_valid))
             self._phases.device(True)
             _, self.dcache = d.prefill_slot()(
                 d.layer_params, d.layer_masks, d.vocab_parts, d.shared_params,
                 tokens, slot_arr, self.dcache, valid, None,
             )
+            self._join_programs["chunk"] += 1
             req.draft_pos += n_valid
         if tr is not None:
             tr.add("prefill", t0, time.perf_counter(), slot=req.slot,
@@ -2326,42 +2387,30 @@ class ContinuousBatcher:
         # ALL M rows — setting these at assignment would leave the slot with
         # mangled state by prefill completion and break the deterministic
         # serial-parity guarantee for multi-chunk prompts.
-        W = self.W
         if req.resume_keys is not None:
             # resuming a preempted request: restore the stashed sampler state
             # so the sample below continues the request's exact PRNG chain
             # and repetition window — the token it emits is the one the
             # uninterrupted run would have produced next
-            self.recent = self._row_set(
-                self.recent, slot_arr, self._put(jnp.asarray(req.resume_recent))
-            )
-            self.keys = self._row_set(
-                self.keys, slot_arr, self._put(jnp.asarray(req.resume_keys))
-            )
+            key_row, recent_row = req.resume_keys, req.resume_recent
             req.resume_keys = None
             req.resume_recent = None
         else:
-            row = np.full((W,), -1, np.int32)
+            key_row = seed_key_row(req.seed)  # jax.random.PRNGKey's words
+            recent_row = np.full((self.W,), -1, np.int32)
             tail = (
                 req.prompt[-req.rep_context:] if req.rep_context
                 else req.prompt[:0]
             )
             if tail.size:
-                row[W - tail.size:] = tail
-            self.recent = self._row_set(
-                self.recent, slot_arr, self._put(jnp.asarray(row))
-            )
-            self.keys = self._row_set(
-                self.keys, slot_arr, self._put(jax.random.PRNGKey(req.seed))
-            )
-
-        tok, logprobs, self.keys, self.recent = self._first_sample(
-            logits, self.keys, self.sp, self.recent, self.rep_sizes, slot_arr
+                recent_row[self.W - tail.size:] = tail
+        (tok, logprobs, self.keys, self.recent, self.last_tok,
+         self.active) = self._finish_join(
+            logits, put(_pack_i32(np.int32(req.slot), key_row, recent_row)),
+            self.keys, self.recent, self.sp, self.rep_sizes,
+            self.last_tok, self.active,
         )
-        self.last_tok = self._set_last(self.last_tok, slot_arr, tok)
-        self.active = self._row_set(
-            self.active, slot_arr, self._put(jnp.asarray(True))
-        )
+        self._join_programs["finish"] += 1
         # the blocking read of the chunk and its sample; the pipeline was
         # drained before this chunk, so nothing is left dispatched and unread
         tok = int(tok)

@@ -210,6 +210,8 @@ def _render_tick_phases(lines: list, t: dict):
     labelled("mst_pipeline_drains_total", "reason", t["drains"])
     lines.append("# TYPE mst_decode_blocks_total counter")
     labelled("mst_decode_blocks_total", "sampler", t["blocks_by_sampler"])
+    lines.append("# TYPE mst_join_programs_total counter")
+    labelled("mst_join_programs_total", "program", t["join_programs"])
 
 
 def _render_spec_family(lines: list, spec: dict):
@@ -1129,6 +1131,11 @@ _HELP = {
         "Plain decode blocks dispatched, by what their sampler had to run "
         "for the live requests: greedy (argmax only), draw (a sampled row "
         "at top_p = 1), nucleus (a sampled row at top_p < 1: the sort).",
+    "mst_join_programs_total":
+        "Dispatches joins made between their drain and the slot decoding, "
+        "by program: claim (the slot claim), chunk (a prefill chunk, the "
+        "draft's too), finish (the first token), other (a block import's "
+        "resume). Over mst_join_seconds_count: 3 for a one-chunk join.",
     "mst_state_slots_in_use":
         "Slots whose recurrent state (Mamba-2 SSM state and convolution "
         "tail) belongs to an admitted request.",

@@ -135,3 +135,26 @@ def test_greedy_blocks_share_reads_the_sampler_classes_of_the_window():
     old = {"mst_decode_blocks_dispatched_total": 443.0}
     assert read({"before": old, "after": old}) is None
     assert read({"before": None, "after": None}) is None
+
+
+def test_join_programs_mean_reads_the_joins_dispatches_of_the_window():
+    """``mst_join_programs_total{program}`` over ``mst_join_seconds_count``
+    between the two scrapes; a program from before the counter (the parent
+    of the PR that added it) exposes nothing and the metric is left out, as
+    in a window in which no join reached decode."""
+    from benchmarks.run import load_reader
+
+    read = load_reader("layer_metrics", "join_programs.mean")
+    family = 'mst_join_programs_total{program="%s"}'
+    before = {family % "claim": 40.0, family % "chunk": 41.0, family % "finish": 40.0,
+              family % "other": 0.0, "mst_join_seconds_count": 40.0}
+    one_chunk = {family % "claim": 240.0, family % "chunk": 241.0, family % "finish": 240.0,
+                 family % "other": 0.0, "mst_join_seconds_count": 240.0}
+    assert read({"before": before, "after": one_chunk}) == pytest.approx(3.0)
+    sixteen = {family % "claim": 50.0, family % "chunk": 201.0, family % "finish": 50.0,
+               family % "other": 0.0, "mst_join_seconds_count": 50.0}
+    assert read({"before": before, "after": sixteen}) == pytest.approx(18.0)
+    assert read({"before": before, "after": before}) is None  # no join in the window
+    old = {"mst_join_seconds_count": 240.0}
+    assert read({"before": {"mst_join_seconds_count": 40.0}, "after": old}) is None
+    assert read({"before": None, "after": None}) is None

@@ -116,6 +116,7 @@ def _fake_tick_phase_stats(path="async"):
         "tokens_dropped": {"slot_finished": 12, "abandoned_block": 0},
         "drains": {"admit": 2, "idle": 1},
         "blocks_by_sampler": {"greedy": 6, "draw": 0, "nucleus": 2},
+        "join_programs": {"claim": 4, "chunk": 9, "finish": 4, "other": 0},
     }
 
 
@@ -148,6 +149,8 @@ def test_metrics_expose_tick_timing():
     assert 'mst_pipeline_drains_total{reason="admit"} 2' in text
     assert 'mst_decode_blocks_total{sampler="greedy"} 6' in text
     assert 'mst_decode_blocks_total{sampler="nucleus"} 2' in text
+    assert 'mst_join_programs_total{program="chunk"} 9' in text
+    assert 'mst_join_programs_total{program="other"} 0' in text
     # the one-tick gauges are gone: nothing could read them soundly
     assert "mst_tick_host_ms" not in text
     assert "mst_tick_device_blocked_ms" not in text

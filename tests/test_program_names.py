@@ -83,7 +83,7 @@ def test_every_served_program_has_a_name_of_its_own(monkeypatch):
     # fine; one function OBJECT jitted twice under one name is one program
     # built twice (two engines): fine too. What the benchmark finds by name:
     for name in ("block", "block_lp", "decode_step", "prefill_chunk",
-                 "first_sample", "row_set", "sp_set", "set_last",
+                 "claim_slot", "finish_join", "resume_slot", "row_set",
                  "forward_sample", "forward_logits", "solo_block",
                  "solo_block_lp", "spec_propose_k3", "spec_verify_k3",
                  "spec_replay_k3", "export_pool_pages", "import_pool_pages",

@@ -156,7 +156,12 @@ def test_blocks_and_tokens_are_all_accounted_for(engine, mode):
             "path", "ticks", "phase_seconds", "device_empty_seconds",
             "phase_entries", "blocks_dispatched", "blocks_harvested",
             "blocks_abandoned", "positions_computed", "tokens_emitted",
-            "tokens_dropped", "drains", "blocks_by_sampler"}
+            "tokens_dropped", "drains", "blocks_by_sampler", "join_programs"}
+        # every join that reached decode: one claim, one first token, and
+        # a dispatch for each of its chunks
+        joins = s["join_programs"]
+        assert joins["claim"] == joins["finish"] >= 3 and joins["other"] == 0
+        assert joins["chunk"] == s["phase_entries"]["prefill_chunk"]
         assert s["path"] == ("async" if mode == "on" else "sync")
         assert set(s["device_empty_seconds"]) == set(tracing.TICK_PHASES)
     finally:
