@@ -358,11 +358,7 @@ def child_kernels(seed: int, rehearse: bool) -> None:
         _paged_attention_xla,
         paged_attention,
     )
-    from mlx_sharding_tpu.ops.quant_matmul import (
-        GEMV_MAX_M,
-        quant_gemv_pipelined,
-        quant_matmul_pallas,
-    )
+    from mlx_sharding_tpu.ops.quant_matmul import quant_matmul_pallas
 
     def check(name, kernel_name, fn, ref_fn, args, tol, relative=False,
               temp_below=None):
@@ -404,14 +400,15 @@ def child_kernels(seed: int, rehearse: bool) -> None:
     bf16 = jnp.bfloat16
     if rehearse:
         hq, hkv, d, s_len, t_len, pages = 4, 2, 16, 64, 16, (8, 16)
-        quant_shapes, batch_rows, batch_only = [(128, 256), (256, 128)], (16,), []
+        quant_shapes, quant_rows = [(128, 256), (256, 128)], (16, 1, 8)
     else:
         hq, hkv, d, s_len, t_len, pages = 24, 8, 128, 4096, 256, (256, 128)
-        quant_shapes, batch_rows = [(8192, 3072), (3072, 8192), (128256, 3072)], (256, 16)
-        # DeepSeek-V2-Lite's dense MLP, the benchmark's own shapes: 10944 =
-        # 64 x 171 rows end in a ragged OUT tile, and as an IN they are 1368
-        # word lanes, which no GEMV slice takes: the batch kernel at every M
-        batch_only = [(10944, 2048), (2048, 10944)]
+        # the three Llama-3B projections, and DeepSeek-V2-Lite's dense MLP,
+        # the benchmark's own shapes: 10944 = 64 x 171 rows end in a ragged
+        # OUT tile, and as an IN they are 1368 word lanes in one whole block
+        quant_shapes = [(8192, 3072), (3072, 8192), (128256, 3072),
+                        (10944, 2048), (2048, 10944)]
+        quant_rows = (256, 16, 1, 8)
     scale = d ** -0.5
 
     # ---- flash attention: a prefill chunk deep in the cache, and T=1
@@ -657,26 +654,22 @@ def child_kernels(seed: int, rehearse: bool) -> None:
           functools.partial(mamba, steps=(m_t, 1)), functools.partial(mamba, steps=(m_t + 1,)),
           (x, stack), ATTN_ATOL)
 
-    # ---- 4-bit matmuls: the batch kernel at a prefill chunk's rows and a
-    # 16-slot decode step's, the GEMV at M=1 and 8
-    for out_dim, in_dim in quant_shapes + batch_only:
+    # ---- 4-bit matmuls: the one kernel at a prefill chunk's rows, a
+    # 16-slot decode step's, a single stream's one row and 8 slots'
+    for out_dim, in_dim in quant_shapes:
         kw, key = jax.random.split(key)
         w = jax.random.normal(kw, (out_dim, in_dim), jnp.float32) * 0.02
         qw, sc, bi = jax.jit(quant.quantize_jax)(w)
         del w
-        gemv_rows = () if (out_dim, in_dim) in batch_only else (1, 8)
-        for m in (*batch_rows, *gemv_rows):
+        for m in quant_rows:
             kx, key = jax.random.split(key)
             x = jax.random.normal(kx, (m, in_dim), bf16)
-            batch = m > GEMV_MAX_M
-            kernel = quant_matmul_pallas if batch else quant_gemv_pipelined
-            name = "quant_matmul" if batch else "quant_gemv_pipelined"
             if rehearse:
-                fn = functools.partial(kernel, interpret=True)
+                fn = functools.partial(quant_matmul_pallas, interpret=True)
             else:
                 fn = functools.partial(quant._quant_matmul, group_size=64,
                                        bits=4)
-            check(f"{name} M={m} {in_dim}->{out_dim}", name, fn,
+            check(f"quant_matmul M={m} {in_dim}->{out_dim}", "quant_matmul", fn,
                   functools.partial(quant._quant_matmul_xla, group_size=64,
                                     bits=4),
                   (x, qw, sc, bi), QUANT_RTOL, relative=True)

@@ -21,7 +21,6 @@ TPU-shaped design:
 
 from __future__ import annotations
 
-import os
 import time
 from dataclasses import dataclass, field
 from functools import partial
@@ -153,12 +152,12 @@ class Generator:
         self.model = model
         # Build-time projection fusion (keep-quantized loads, single-chip):
         # concatenate each declared group's packed triples along OUT so
-        # decode runs QKV / gate+up as one fused-GEMV launch each. The
+        # decode runs QKV / gate+up as one fused projection launch each. The
         # caller's params are not mutated (shallow-copied layer stack);
         # sp paths keep the separate projections (long-prefill bound, and
         # their params are placed before fusion would apply).
         self.fused_projections: list[str] = []
-        if sp_mesh is None and os.environ.get("MST_FUSE_PROJ", "1") != "0":
+        if sp_mesh is None:
             from mlx_sharding_tpu.models.base import apply_projection_fusion
 
             layers = params.get("layers")

@@ -23,9 +23,9 @@ bit-exact — into a serializable :class:`KVPageBlock`:
   delivered tokens; the failover replica folds the history into the
   prompt and continues from the last emitted token.
 
-Asynchrony discipline (the PRESERVE-style overlap ``quant_gemv_pipelined``
-practices, arXiv:2501.08192): the tick-hot path only ever *dispatches* the
-device-side page gather — the device→host copy happens on the tier's
+Asynchrony discipline (PRESERVE-style overlap, arXiv:2501.08192): the
+tick-hot path only ever *dispatches* the device-side page gather — the
+device→host copy happens on the tier's
 background flusher thread via :meth:`KVPageBlock.to_host`. A synchronous
 full-block ``device_get`` in a tick-hot function is an mstcheck violation
 (MST106). Drain is the one exception: it runs quiesced, off the decode

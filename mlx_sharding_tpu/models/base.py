@@ -190,10 +190,10 @@ def apply_projection_fusion(model, layer_stack: dict) -> list[str]:
     IN PLACE in ``layer_stack`` (a flat ``{name: w}`` stack, or nested
     ``{group: {name: w}}`` keyed like ``layer_group_ranges``): the group's
     packed triples concatenate along OUT (ops.quant.fuse_packed) and the
-    sources are removed, so decode serves the whole group with one fused-
-    GEMV launch over one pass of the activation planes. Groups with any
-    dense (non-packed) member are left untouched. Returns the fused names
-    added. Callers gate on tp == 1 and the MST_FUSE_PROJ env switch."""
+    sources are removed, so decode serves the whole group with one fused
+    projection launch over one pass of the activation planes. Groups with
+    any dense (non-packed) member are left untouched. Returns the fused
+    names added. Callers gate on tp == 1."""
     from mlx_sharding_tpu.ops.quant import fuse_packed
 
     groups = model.fused_projection_groups()

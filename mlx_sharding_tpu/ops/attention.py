@@ -16,7 +16,6 @@ rather than repeating K/V (no HBM duplication).
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -29,9 +28,7 @@ def _flash_eligible(q, k, v, logit_softcap, sliding_window, sinks) -> bool:
     128 over a cache of S a multiple of 128, head dims 64-aligned (Mosaic
     pads sub-128 lane tails, which admits DeepSeek MLA's dk=192 full-mode
     and dk=rank+rope / dv=rank compressed-mode shapes). Every other call,
-    T=1 decode among them, takes the XLA path. ``MST_FLASH=0`` opts out."""
-    if os.environ.get("MST_FLASH", "1") == "0":
-        return False
+    T=1 decode among them, takes the XLA path."""
     if logit_softcap is not None or sliding_window is not None or sinks is not None:
         return False
     b, t, hq, dk = q.shape

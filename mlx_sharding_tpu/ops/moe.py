@@ -14,9 +14,9 @@ observe — token count, packed or dense stacks, backend, shapes:
   (E, out, in*bits/32) stack, unpacks it in VMEM and multiplies all N rows
   by it. HBM traffic is each chosen expert's packed bytes once, which is
   what decode is bound by; no dense expert tensor is written to HBM.
-- **decode, packed stacks, elsewhere** (off the chip, MST_QMM=0, a shape
-  outside the kernel's contract): gather the packed leaves per pick and
-  dequantize the gathered slices (N x K whole experts, dense, in HBM).
+- **decode, packed stacks, elsewhere** (off the chip, a shape outside the
+  kernel's contract): gather the packed leaves per pick and dequantize the
+  gathered slices (N x K whole experts, dense, in HBM).
 - **decode, dense stacks**: gather the top-k experts' weights per token and
   batch the tiny matmuls.
 - **prefill (many tokens), a resident range and expert-parallel**: a loop
@@ -55,7 +55,6 @@ from __future__ import annotations
 
 import functools
 import logging
-import os
 
 import jax
 import jax.numpy as jnp
@@ -253,12 +252,11 @@ def _apply_gather(x, weights, idx, w_gate, w_up, w_down):
 
 def packed_kernel_ok(n, w_gate, w_up, w_down, gs, bits) -> bool:
     """Whether ``n`` rows over these packed stacks run the expert-indexed
-    4-bit kernel: a TPU backend, 4-bit kernels not switched off (MST_QMM=0,
-    as ops/quant._pallas_ok), and all three projections inside the kernel's
-    own contract (quant_matmul.experts_blocks)."""
+    4-bit kernel: a TPU backend and all three projections inside the
+    kernel's own contract (quant_matmul.experts_blocks)."""
     from mlx_sharding_tpu.ops.quant_matmul import experts_blocks
 
-    if os.environ.get("MST_QMM", "1") == "0" or jax.default_backend() != "tpu":
+    if jax.default_backend() != "tpu":
         return False
     return all(
         experts_blocks(

@@ -50,7 +50,6 @@ kernel-in-interpret vs XLA).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -90,9 +89,7 @@ def kernel_eligible(
     asked) and several heads need a 128-aligned ``dk`` to start each slice
     on a lane tile. int8 pools follow the same rules in both layouts.
     Interpret mode takes any shape so CPU tests exercise the kernel logic
-    itself. Opt out entirely with MST_PAGED_KERNEL=0."""
-    if os.environ.get("MST_PAGED_KERNEL", "1") == "0":
-        return False
+    itself."""
     if logit_softcap is not None:
         return False
     if sliding_window is not None and not isinstance(sliding_window, int):

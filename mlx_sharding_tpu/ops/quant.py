@@ -63,7 +63,7 @@ def fuse_packed(parts):
     Build-time only: the fused param serves N projections (QKV, gate+up)
     with a single kernel invocation, so the activation planes are read
     once instead of N times and decode issues one launch where it issued
-    N. Per output row the fused GEMV computes the exact same sub-dot
+    N. Per output row the fused call computes the exact same sub-dot
     sequence as the separate calls, so results are bit-identical."""
     if not all(is_quantized(p) for p in parts):
         raise ValueError("fuse_packed expects packed {q, scales, biases} triples")
@@ -91,10 +91,6 @@ def linear(x: jax.Array, w, group_size: int = 64, bits: int = 4) -> jax.Array:
 
 
 def _pallas_ok(m, in_dim, out_dim, group_size, bits) -> bool:
-    import os
-
-    if os.environ.get("MST_QMM", "1") == "0":
-        return False
     # single source of truth for the dispatch contract: the kernel's own
     # block defaults and min() clamping
     from mlx_sharding_tpu.ops.quant_matmul import (
@@ -114,54 +110,17 @@ def _pallas_ok(m, in_dim, out_dim, group_size, bits) -> bool:
     )
 
 
-def _gemv_ok(m, in_dim, out_dim, group_size, bits) -> bool:
-    """Decode shapes route to the pipelined GEMV: M ≤ 8, TPU backend (or
-    MST_QMM_GEMV=interpret, which forces the kernel in interpret mode for
-    end-to-end parity tests on CPU), and blocks the kernel's own contract
-    (quant_matmul.gemv_blocks_ok) admits."""
-    import os
-
-    mode = os.environ.get("MST_QMM_GEMV", "1")
-    if mode == "0" or os.environ.get("MST_QMM", "1") == "0":
-        return False
-    from mlx_sharding_tpu.ops.quant_matmul import (
-        GEMV_MAX_M,
-        gemv_blocks_ok,
-        get_gemv_blocks,
-    )
-
-    if m > GEMV_MAX_M:
-        return False
-    if mode != "interpret" and jax.default_backend() != "tpu":
-        return False
-    block_out, block_in = get_gemv_blocks(m, out_dim, in_dim, group_size, bits)
-    return gemv_blocks_ok(
-        m, out_dim, in_dim, block_out, block_in, group_size, bits,
-        hardware=mode != "interpret",
-    )
-
-
 # Which path _quant_matmul chose, once per traced call (ops/dispatch.py).
 # /metrics shows it as ``mst_quant_dispatch_total{path}``: "xla" above 0 on
 # a chip says some packed projection is dequantized in HBM every step.
-_DISPATCHED = DispatchCounter("gemv", "matmul", "xla")
+_DISPATCHED = DispatchCounter("matmul", "xla")
 dispatch_counts = _DISPATCHED.counts
 _count_dispatch = _DISPATCHED.count
 
 
 def _quant_matmul(x2, q, scales, biases, group_size, bits):
-    import os
-
     m, in_dim = x2.shape
     out_dim = q.shape[0]
-    if _gemv_ok(m, in_dim, out_dim, group_size, bits):
-        from mlx_sharding_tpu.ops.quant_matmul import quant_gemv_pipelined
-
-        _count_dispatch("gemv")
-        return quant_gemv_pipelined(
-            x2, q, scales, biases, group_size=group_size, bits=bits,
-            interpret=os.environ.get("MST_QMM_GEMV") == "interpret",
-        )
     if _pallas_ok(m, in_dim, out_dim, group_size, bits):
         from mlx_sharding_tpu.ops.quant_matmul import quant_matmul_pallas
 
@@ -175,10 +134,10 @@ def _quant_matmul(x2, q, scales, biases, group_size, bits):
 
 def _quant_matmul_xla(x2, q, scales, biases, group_size, bits):
     """The plain form: dequantize the whole weight to f32 in HBM, then one
-    matmul. It is what runs off the chip (the CPU tests and rehearsals) and
-    under ``MST_QMM=0``, and the reference ``chip_smoke.py`` checks every
-    kernel against. On a TPU ``_pallas_ok`` refuses only a row count above
-    128 that is no multiple of it, which no program of the served path has:
+    matmul. It is what runs off the chip (the CPU tests and rehearsals), and
+    the reference ``chip_smoke.py`` checks every kernel against. On a TPU
+    ``_pallas_ok`` refuses only a row count above 128 that is no multiple of
+    it, which no program of the served path has:
     ``mst_quant_dispatch_total{path="xla"}`` says if one ever does."""
     # mst: allow(MST105): dense tile is transient inside this one matmul
     w = dequantize(q, scales, biases, group_size, bits, jnp.float32)

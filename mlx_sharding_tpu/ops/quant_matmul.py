@@ -36,9 +36,11 @@ ref shard/utils.py:54-65): ``q`` (out, in*bits/32) LSB-first nibbles,
 ``scales``/``biases`` (out, in/group_size) — validated bit-exactly by
 tests/test_quant_golden.py.
 
-Three kernels share that math:
+Two kernels share that math:
 
-- :func:`quant_matmul_pallas` — the 3-D-grid prefill/batch kernel above.
+- :func:`quant_matmul_pallas` — the 3-D-grid kernel above, for every packed
+  projection at every row count (a prefill chunk, a decode step's slots, a
+  single stream's one row).
 - :func:`quant_matmul_experts` — the same grid with an expert axis, for
   the routed experts of a decode step (ops/moe.py): the weight operand is
   the whole (E, out, in*bits/32) stack as it lies in HBM, and a
@@ -47,36 +49,11 @@ Three kernels share that math:
   no dense expert tensor is written to HBM. Scales and biases (and a
   packed leaf whose word count is not a multiple of 128) are read with OUT
   in the lanes, the orientation a TPU stores them in.
-- :func:`quant_gemv_pipelined` — the decode (M ≤ 8) specialization. At
-  M=1 the 3-D grid's per-program overhead dominates: each (OUT, IN) tile
-  is one tiny MXU burst and the automatic pipeline re-fetches the scale
-  blocks through their relayout. This kernel instead runs ONE grid step
-  per OUT tile and streams the IN reduction through a manual
-  double-buffered HBM→VMEM DMA pipeline (``pltpu.make_async_copy`` into
-  2-slot scratch buffers): while the MXU chews IN-block ``i``, the DMAs
-  for block ``i+1``'s packed words / activation planes are already in
-  flight, so the sub-dots overlap the next tile's weight fetch instead of
-  stalling on it. ``q`` is sliced straight out of its checkpoint layout
-  (no host-side relayout of multi-GB weight stacks); only the tiny
-  activation is pre-permuted to word-major planes. An IN block's scales
-  are ``block_in/group_size`` lanes wide — 16 at the default — and Mosaic
-  refuses a DMA slice that is not 128-lane aligned, so scales/biases are
-  not sliced: each OUT tile's whole (block_out, IN/group_size) rows ride
-  in once through the BlockSpec, and the group→word expansion matrix of
-  block ``i`` picks that block's groups out of them.
-
-Block sizes come from :func:`get_gemv_blocks`: a shape-keyed autotune
-cache (populated by :func:`autotune_gemv` — engines sweep each distinct
-(OUT, IN) once at load on a real TPU and every same-shaped layer reuses
-the winner) with the :func:`pick_decode_blocks` VMEM-fit heuristic as
-the cold/CPU fallback.
 """
 
 from __future__ import annotations
 
 import functools
-import os
-import time
 
 import jax
 import jax.numpy as jnp
@@ -354,7 +331,7 @@ def _experts_kernel(
             return jnp.concatenate([hi, mid, lo], axis=0)
 
         # group→word expansion on the MXU as in _kernel, selecting this IN
-        # block's groups out of the whole rows as _gemv_kernel does
+        # block's groups out of the whole rows
         g0 = ii * (words // wpg)
         s3, b3 = pieces(s_ref[...]), pieces(b_ref[...])
         gi = jax.lax.broadcasted_iota(jnp.int32, (3 * g_total, words), 0)
@@ -484,350 +461,3 @@ def quant_matmul_experts(
         interpret=interpret,
         name="quant_matmul_experts",
     )(ids, live, *operands)
-
-
-# ---------------------------------------------------------------------------
-# Decode GEMV: manual double-buffered DMA pipeline over the IN reduction.
-# ---------------------------------------------------------------------------
-
-#: VMEM ceiling for the decode double buffers (both slots + accumulator +
-#: per-plane temporaries must fit alongside Mosaic's own scratch)
-_GEMV_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
-
-#: decode specialization bound: above this M the 3-D-grid kernel's M-tiling
-#: amortizes per-program overhead better than the single-M GEMV
-GEMV_MAX_M = 8
-
-
-def pick_decode_block_in(in_dim: int) -> int:
-    """IN block for the pipelined GEMV. Prefer ≥ 2 IN blocks (a 1-block
-    run has nothing to overlap) of 128-word-lane-aligned size; an
-    indivisible dim runs as one whole block (correct, unpipelined)."""
-    for cand in (4096, 2048, DEFAULT_BLOCK_IN):
-        if in_dim % cand == 0 and in_dim // cand >= 2:
-            return cand
-    return in_dim
-
-
-def _gemv_vmem_bytes(
-    m: int, block_out: int, block_in: int, in_dim: int,
-    group_size: int = 64, bits: int = 4,
-) -> int:
-    """Working-set estimate of one :func:`quant_gemv_pipelined` grid step.
-    Per out row: both packed-word slots (2·4 bytes per word lane) plus ~8
-    bytes per word lane of nibble-plane and scale-expansion temporaries;
-    and the tile's whole scale + bias rows — lane-padded to 128, double
-    buffered by the block pipeline, plus their fp32 copies (~16 bytes per
-    padded group lane). AOT compiles for a v5e (tests/test_tpu_compile.py)
-    put Mosaic's real ceiling 1.3–2.6x above what this estimate admits
-    under ``_GEMV_VMEM_BUDGET_BYTES``."""
-    per_word = 32 // bits
-    words = block_in // per_word
-    g_pad = -(-(in_dim // group_size) // 128) * 128
-    fixed = 2 * m * per_word * words * 4 + m * 128 * 4  # x slots + acc tile
-    return fixed + block_out * (words * (2 * 4 + 8) + g_pad * 16)
-
-
-def gemv_blocks_ok(
-    m: int, out_dim: int, in_dim: int, block_out: int, block_in: int,
-    group_size: int = 64, bits: int = 4, *, hardware: bool = True,
-) -> bool:
-    """The contract of :func:`quant_gemv_pipelined`: blocks divide the shape
-    on quant-group and nibble-word boundaries and — on ``hardware``, which
-    interpret mode waives — every DMA slice is 128-lane aligned (``words``
-    lanes of packed weights and activation planes; the (M, block_out)
-    output tile) and the working set fits the VMEM budget. The dispatch
-    predicate (ops/quant._gemv_ok) and the autotune sweep both go through
-    here — a pair this admits and Mosaic refuses is a bug in this
-    function."""
-    per_word = 32 // bits
-    divides = (
-        out_dim % block_out == 0
-        and in_dim % block_in == 0
-        and block_in % group_size == 0
-        and block_in % per_word == 0
-    )
-    if not (divides and hardware):
-        return divides
-    return (
-        (block_in // per_word) % 128 == 0
-        and block_out % 128 == 0
-        and _gemv_vmem_bytes(m, block_out, block_in, in_dim, group_size, bits)
-        <= _GEMV_VMEM_BUDGET_BYTES
-    )
-
-
-def pick_decode_blocks(
-    m: int, out_dim: int, in_dim: int, group_size: int = 64, bits: int = 4
-) -> tuple[int, int]:
-    """(block_out, block_in) heuristic for the decode GEMV: block_in from
-    :func:`pick_decode_block_in`, then the largest 128-multiple divisor of
-    OUT whose working set (:func:`_gemv_vmem_bytes`) fits the VMEM budget."""
-    block_in = pick_decode_block_in(in_dim)
-
-    def fits(block_out):
-        return _gemv_vmem_bytes(
-            m, block_out, block_in, in_dim, group_size, bits
-        ) <= _GEMV_VMEM_BUDGET_BYTES
-
-    if fits(out_dim):
-        return out_dim, block_in
-    best = None
-    d = 128
-    while d < out_dim and fits(d):
-        if out_dim % d == 0:
-            best = d
-        d += 128
-    return (best if best is not None else min(out_dim, DEFAULT_BLOCK_OUT),
-            block_in)
-
-
-def _gemv_kernel(
-    x_hbm,  # (M, per_word, W_total) — stays in HBM (memory_space=ANY)
-    q_hbm,  # (OUT, W_total) uint32 — checkpoint layout, HBM
-    s_ref,  # (block_out, G_total) — this OUT tile's whole scale rows, VMEM
-    b_ref,  # (block_out, G_total) — this OUT tile's whole bias rows, VMEM
-    o_ref,  # (M, block_out) output tile
-    xbuf,  # (2, M, per_word, words) VMEM double buffer
-    qbuf,  # (2, block_out, words) VMEM double buffer
-    sems,  # (2, 2) DMA semaphores: one per (operand, slot)
-    *,
-    bits: int,
-    group_size: int,
-    n_in: int,
-    block_out: int,
-):
-    per_word = 32 // bits
-    mask = (1 << bits) - 1
-    words = qbuf.shape[-1]
-    g_total = s_ref.shape[-1]
-    gpb = g_total // n_in
-    wpg = group_size // per_word
-    m = x_hbm.shape[0]
-    o0 = pl.program_id(0) * block_out
-
-    def copies(i, slot):
-        """The HBM→VMEM DMAs that land IN-block ``i`` in ``slot`` — sliced
-        straight from the checkpoint layout (2-D strided DMA), no relayout
-        of the weight stack ever happens. Both slices are ``words`` lanes
-        wide, a multiple of 128 (the dispatch predicate's contract): Mosaic
-        refuses a DMA slice that is not aligned to the 128-lane tiling."""
-        return (
-            pltpu.make_async_copy(
-                x_hbm.at[:, :, pl.ds(i * words, words)],
-                xbuf.at[slot], sems.at[0, slot],
-            ),
-            pltpu.make_async_copy(
-                q_hbm.at[pl.ds(o0, block_out), pl.ds(i * words, words)],
-                qbuf.at[slot], sems.at[1, slot],
-            ),
-        )
-
-    # warm-up: block 0's fetch starts before any compute
-    for c in copies(0, 0):
-        c.start()
-
-    # whole scale rows (see the module docstring): block i's groups are
-    # selected by its expansion matrix, E_i[g, w] = [w // wpg + i·gpb == g]
-    s_all = s_ref[...].astype(jnp.float32)
-    b_all = b_ref[...].astype(jnp.float32)
-    gi = jax.lax.broadcasted_iota(jnp.int32, (g_total, words), 0)
-    wi = jax.lax.broadcasted_iota(jnp.int32, (g_total, words), 1)
-    dot = functools.partial(
-        jax.lax.dot_general, preferred_element_type=jnp.float32
-    )
-    contract_last = (((1,), (1,)), ((), ()))
-    expand_c = (((1,), (0,)), ((), ()))
-
-    def step(i, acc):
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < n_in)
-        def _prefetch():
-            # next block's DMAs go out BEFORE this block's wait: the MXU
-            # sub-dots below overlap the i+1 weight fetch
-            for c in copies(i + 1, jax.lax.rem(i + 1, 2)):
-                c.start()
-
-        for c in copies(i, slot):
-            c.wait()
-
-        expand = (wi // wpg + i * gpb == gi).astype(jnp.float32)
-        s_w = dot(s_all, expand, expand_c)
-        b_w = dot(b_all, expand, expand_c)
-        wq = qbuf[slot]  # (block_out, words) uint32
-        x_sum = jnp.zeros((m, words), jnp.float32)
-        for j in range(per_word):
-            nib = (
-                ((wq >> (j * bits)) & mask)
-                .astype(jnp.int32).astype(jnp.float32)
-            )
-            xj = xbuf[slot][:, j, :].astype(jnp.float32)  # (m, words)
-            acc = acc + dot(xj, nib * s_w, contract_last)
-            x_sum = x_sum + xj
-        return acc + dot(x_sum, b_w, contract_last)
-
-    acc = jax.lax.fori_loop(
-        0, n_in, step, jnp.zeros((m, block_out), jnp.float32)
-    )
-    o_ref[...] = acc.astype(o_ref.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("group_size", "bits", "block_out", "block_in",
-                     "interpret"),
-)
-def quant_gemv_pipelined(
-    x: jax.Array,  # (M, IN), M ≤ GEMV_MAX_M
-    q: jax.Array,  # (OUT, IN * bits / 32) uint32
-    scales: jax.Array,  # (OUT, IN / group_size)
-    biases: jax.Array,  # (OUT, IN / group_size)
-    *,
-    group_size: int = 64,
-    bits: int = 4,
-    block_out: int | None = None,
-    block_in: int | None = None,
-    interpret: bool = False,
-) -> jax.Array:
-    """Decode-shape ``x @ dequant(q, scales, biases).T``: one grid step per
-    OUT tile, IN reduced through the manual double-buffered DMA pipeline.
-    Same nibble-plane math (and so the same float rounding) as
-    :func:`quant_matmul_pallas` with one IN-block-sized sub-dot chain."""
-    m, in_dim = x.shape
-    out_dim = q.shape[0]
-    per_word = 32 // bits
-    if block_out is None or block_in is None:
-        bo, bi = get_gemv_blocks(m, out_dim, in_dim, group_size, bits)
-        block_out = block_out if block_out is not None else bo
-        block_in = block_in if block_in is not None else bi
-    block_out = min(block_out, out_dim)
-    block_in = min(block_in, in_dim)
-    if block_in % group_size or block_in % per_word:
-        raise ValueError(
-            f"block_in {block_in} must be a multiple of group_size "
-            f"{group_size} and {per_word}"
-        )
-    if out_dim % block_out or in_dim % block_in:
-        raise ValueError(
-            f"shapes (OUT={out_dim}, IN={in_dim}) must divide block sizes "
-            f"({block_out}, {block_in})"
-        )
-
-    n_in = in_dim // block_in
-    words = block_in // per_word
-    g_total = in_dim // group_size
-    # only the activation is relayouted: (M, IN) → word-major planes
-    x_r = x.reshape(m, in_dim // per_word, per_word).transpose(0, 2, 1)
-
-    any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    row_spec = pl.BlockSpec((block_out, g_total), lambda oi: (oi, 0))
-    return pl.pallas_call(
-        functools.partial(
-            _gemv_kernel, bits=bits, group_size=group_size, n_in=n_in,
-            block_out=block_out,
-        ),
-        grid=(out_dim // block_out,),
-        in_specs=[any_spec, any_spec, row_spec, row_spec],
-        out_specs=pl.BlockSpec((m, block_out), lambda oi: (0, oi)),
-        out_shape=jax.ShapeDtypeStruct((m, out_dim), x.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((2, m, per_word, words), x_r.dtype),
-            pltpu.VMEM((2, block_out, words), jnp.uint32),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
-        interpret=interpret,
-        name="quant_gemv_pipelined",
-    )(x_r, q, scales, biases)
-
-
-# ---------------------------------------------------------------------------
-# Shape-keyed block autotune: sweep once per (OUT, IN) at load, reuse
-# across every same-shaped layer. Replaces trusting the static VMEM-budget
-# heuristic on real chips — the heuristic stays as the cold/CPU fallback.
-# ---------------------------------------------------------------------------
-
-#: (m_bucket, out_dim, in_dim, group_size, bits) → (block_out, block_in)
-_GEMV_AUTOTUNE: dict[tuple, tuple[int, int]] = {}
-
-
-def _m_bucket(m: int) -> int:
-    """Decode Ms bucket to 1 (single stream) or GEMV_MAX_M (batched slots):
-    block choice is insensitive within a bucket, and bucketing keeps the
-    sweep count per shape at two."""
-    return 1 if m == 1 else GEMV_MAX_M
-
-
-def get_gemv_blocks(
-    m: int, out_dim: int, in_dim: int, group_size: int = 64, bits: int = 4
-) -> tuple[int, int]:
-    """Measured blocks when :func:`autotune_gemv` has swept this shape,
-    else the heuristic. Pure lookup — safe at trace time."""
-    hit = _GEMV_AUTOTUNE.get(
-        (_m_bucket(m), out_dim, in_dim, group_size, bits)
-    )
-    if hit is not None:
-        return hit
-    return pick_decode_blocks(m, out_dim, in_dim, group_size, bits)
-
-
-def _gemv_candidates(
-    m: int, out_dim: int, in_dim: int, group_size: int, bits: int
-) -> list[tuple[int, int]]:
-    h_out, h_in = pick_decode_blocks(m, out_dim, in_dim, group_size, bits)
-    outs = {h_out, h_out // 2, h_out * 2, out_dim}
-    ins = {h_in, 1024, 2048, 4096, in_dim}
-    return [
-        (bo, bi) for bo in sorted(outs) for bi in sorted(ins)
-        if bo and gemv_blocks_ok(m, out_dim, in_dim, bo, bi, group_size, bits)
-    ]
-
-
-def autotune_gemv(
-    m: int, out_dim: int, in_dim: int, group_size: int = 64, bits: int = 4,
-    dtype=jnp.bfloat16, repeats: int = 3,
-) -> tuple[int, int] | None:
-    """Sweep candidate (block_out, block_in) pairs on synthetic operands and
-    cache the fastest for this shape key. Engines call this once per
-    distinct packed-projection shape at load (PipelineEngine.__init__);
-    the decode dispatch then reuses the winner for every layer.
-
-    Measured on a real TPU backend only — timing interpret-mode or CPU
-    runs would tune for the wrong machine; those stay on the heuristic.
-    Every candidate passed :func:`gemv_blocks_ok`, so a compiler refusal
-    here is a bug in that predicate and propagates. Returns the winning
-    pair, or None when not swept (non-TPU backend, MST_QMM_AUTOTUNE=0, or a
-    shape no candidate serves — the dispatch sends that one to XLA)."""
-    key = (_m_bucket(m), out_dim, in_dim, group_size, bits)
-    if key in _GEMV_AUTOTUNE:
-        return _GEMV_AUTOTUNE[key]
-    if os.environ.get("MST_QMM_AUTOTUNE", "1") == "0":
-        return None
-    if jax.default_backend() != "tpu":
-        return None
-    mb = key[0]
-    per_word = 32 // bits
-    x = jnp.zeros((mb, in_dim), dtype)
-    qw = jnp.zeros((out_dim, in_dim // per_word), jnp.uint32)
-    s = jnp.ones((out_dim, in_dim // group_size), jnp.float32)
-    b = jnp.zeros((out_dim, in_dim // group_size), jnp.float32)
-    best, best_t = None, float("inf")
-    for bo, bi in _gemv_candidates(mb, out_dim, in_dim, group_size, bits):
-        run = functools.partial(
-            quant_gemv_pipelined, x, qw, s, b, group_size=group_size,
-            bits=bits, block_out=bo, block_in=bi,
-        )
-        run().block_until_ready()  # compile outside the timed window
-        t0 = time.perf_counter()
-        for _ in range(repeats):
-            out = run()
-        out.block_until_ready()
-        elapsed = time.perf_counter() - t0
-        if elapsed < best_t:
-            best, best_t = (bo, bi), elapsed
-    if best is not None:
-        _GEMV_AUTOTUNE[key] = best
-    return best

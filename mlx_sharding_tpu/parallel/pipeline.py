@@ -29,7 +29,6 @@ Correctness of garbage ticks: devices compute every tick, but
 
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Optional
 
@@ -203,10 +202,10 @@ def stack_stage_params(stage_param_list: list[dict]) -> dict:
 def place_weights(model, params, mesh, *, stage_bounds=None) -> ResidentWeights:
     """Materialize a model's device-resident weight tree on ``mesh``: split
     the stacked layer params per pipeline stage, apply build-time projection
-    fusion and the GEMV autotune sweep, derive per-name PartitionSpecs over
-    pp/tp/ep, place everything with ``put_global``, and vocab-shard the
-    embedding/head over pp. This is the entire per-replica spawn cost that
-    ISN'T slot/cache setup — which is why it is a free function: the
+    fusion, derive per-name PartitionSpecs over pp/tp/ep, place everything
+    with ``put_global``, and vocab-shard the embedding/head over pp. This
+    is the entire per-replica spawn cost that ISN'T slot/cache setup —
+    which is why it is a free function: the
     ``weights.WeightStore`` runs it once per key and every data-parallel
     replica constructs its ``PipelineEngine`` against the returned
     ``ResidentWeights`` (``weights=`` kwarg), aliasing the same arrays
@@ -229,38 +228,16 @@ def place_weights(model, params, mesh, *, stage_bounds=None) -> ResidentWeights:
 
     # Build-time projection fusion (keep-quantized loads): concatenate
     # each declared group's packed triples along OUT so decode runs QKV
-    # (and gate+up) as ONE fused-GEMV launch sharing a single pass over
-    # the activation planes. tp == 1 only — the fused OUT axis
+    # (and gate+up) as ONE fused projection launch sharing a single pass
+    # over the activation planes. tp == 1 only — the fused OUT axis
     # interleaves the group's rows, which the column-parallel slicing
     # wouldn't split correctly. Forward code dispatches on the fused
     # name's presence in the layer pytree (models/llama.py).
     fused_projections: list[str] = []
-    if tp == 1 and os.environ.get("MST_FUSE_PROJ", "1") != "0":
+    if tp == 1:
         from mlx_sharding_tpu.models.base import apply_projection_fusion
 
         fused_projections = apply_projection_fusion(model, split)
-
-    # Shape-keyed GEMV autotune: sweep candidate block sizes once per
-    # distinct packed (OUT, IN) at load time (quant_matmul caches the
-    # winner; every layer with that shape reuses it). No-op off-TPU.
-    if os.environ.get("MST_QMM_AUTOTUNE", "1") != "0":
-        from mlx_sharding_tpu.ops.quant_matmul import autotune_gemv
-
-        gs_a, bits_a = model._quant_args()
-        seen_shapes: set = set()
-
-        def _sweep(stack):
-            for w in stack.values():
-                if isinstance(w, dict) and not is_quantized(w):
-                    _sweep(w)
-                elif is_quantized(w):
-                    out_dim = int(w["q"].shape[-2])
-                    in_dim = int(w["scales"].shape[-1]) * gs_a
-                    if (out_dim, in_dim) not in seen_shapes:
-                        seen_shapes.add((out_dim, in_dim))
-                        autotune_gemv(1, out_dim, in_dim, gs_a, bits_a)
-
-        _sweep(split)
 
     # Per-name shard axes: tp (heads/MLP columns) and ep (expert stacks).
     # Models declare flat maps (homogeneous stacks) or nested
@@ -613,8 +590,8 @@ class PipelineEngine:
 
         # Weight residency. Private path: build this engine's own
         # device-resident tree (the full W-byte upload — split, fuse,
-        # autotune, place). Aliased path (``weights=``): a
-        # ``weights.WeightStore`` lease already holds the resident tree for
+        # place). Aliased path (``weights=``): a ``weights.WeightStore``
+        # lease already holds the resident tree for
         # this exact placement, and N data-parallel replicas execute
         # against the SAME arrays — constructing the engine costs
         # slot/cache setup only. The caller owns the lease and wires its

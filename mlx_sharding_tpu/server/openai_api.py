@@ -607,16 +607,7 @@ class ModelProvider:
                                 if self.stage_bounds else ("auto", stages)
                             ),
                             dtype=jnp.dtype(cache_dtype).name,
-                            # build-time transforms are part of the tree's
-                            # identity: projection fusion rewrites the
-                            # layout, the autotune sweep fixes kernel picks
-                            quant=(
-                                f"tp{self.tp}:ep{self.ep}"
-                                f":fuse="
-                                f"{os.environ.get('MST_FUSE_PROJ', '')}"
-                                f":tune="
-                                f"{os.environ.get('MST_QMM_AUTOTUNE', '')}"
-                            ),
+                            quant=f"tp{self.tp}:ep{self.ep}",
                             placement=mesh_fingerprint(base_mesh),
                         )
 

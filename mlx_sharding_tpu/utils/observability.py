@@ -1155,7 +1155,7 @@ _HELP = {
         "lap (one count stands for every window layer's page).",
     "mst_quant_dispatch_total":
         "Packed 4-bit matmuls by the path ops/quant chose, one count per "
-        "traced call: gemv and matmul are the Pallas kernels; xla "
+        "traced call: matmul is the Pallas kernel; xla "
         "dequantizes the whole weight in HBM every step (0 on a chip).",
     "mst_paged_attention_dispatch_total":
         "Ragged paged-attention calls by the path ops/paged_attention "

@@ -101,5 +101,3 @@ def test_eligibility_gates(monkeypatch):
     assert not _flash_eligible(q192, k192, v128, None, 4096, None)
     # T=1 decode is never eligible: it takes the XLA path
     assert not _flash_eligible(qd, k192, v128, None, None, None)
-    monkeypatch.setenv("MST_FLASH", "0")
-    assert not _flash_eligible(q192, k192, v128, None, None, None)

@@ -397,13 +397,6 @@ def test_dispatch_is_counted_once_a_traced_call_and_shown_on_metrics():
         assert f'mst_paged_attention_dispatch_total{{path="{path}"}} {n}' in text
 
 
-def test_kernel_env_opt_out(monkeypatch):
-    monkeypatch.setenv("MST_PAGED_KERNEL", "0")
-    assert not kernel_eligible(64, 64, None, None, None, interpret=True)
-    monkeypatch.setenv("MST_PAGED_KERNEL", "1")
-    assert kernel_eligible(64, 64, None, None, None, interpret=True)
-
-
 # ---------------------------------------------------------------- engine ---
 
 TINY = dict(

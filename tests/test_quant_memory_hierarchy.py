@@ -1,6 +1,6 @@
 """Quantized memory hierarchy (ISSUE 5 tentpole): bit-exact parity for the
-pipelined decode GEMV and the build-time fused projections against the
-golden dequant reference, and the int8 paged-KV contracts — greedy streams
+4-bit kernel at decode rows and the build-time fused projections against
+the golden dequant reference, and the int8 paged-KV contracts — greedy streams
 token-identical to the bf16 pool on both paged-attention paths, a bounded
 per-element quantization error, and code-exact requantize-on-writeback.
 
@@ -23,10 +23,7 @@ from mlx_sharding_tpu.models.base import apply_projection_fusion
 from mlx_sharding_tpu.models.llama import LlamaModel
 from mlx_sharding_tpu.ops.paged_attention import paged_attention
 from mlx_sharding_tpu.ops.quant import dequantize, fuse_packed, linear
-from mlx_sharding_tpu.ops.quant_matmul import (
-    quant_gemv_pipelined,
-    quant_matmul_pallas,
-)
+from mlx_sharding_tpu.ops.quant_matmul import quant_matmul_pallas
 from mlx_sharding_tpu.parallel.mesh import pipeline_mesh
 from mlx_sharding_tpu.parallel.pipeline import PipelineEngine
 from mlx_sharding_tpu.scheduler import ContinuousBatcher
@@ -59,12 +56,13 @@ def _bitexact_case(rng, m, in_dim, out_dim, bits):
 
 
 @pytest.mark.parametrize("m", [1, 8])
-def test_gemv_pipelined_bitexact_vs_golden(m):
-    """The double-buffered GEMV must reproduce the golden dequant matmul to
-    the last bit (2 IN blocks → the prefetch/wait pipeline actually runs)."""
+def test_matmul_bitexact_vs_golden_at_decode_rows(m):
+    """The one projection kernel at a single stream's row and at 8 slots'
+    must reproduce the golden dequant matmul to the last bit (2 IN blocks →
+    the accumulator carries across grid steps)."""
     rng = np.random.default_rng(20)
     x, q, s, b, want = _bitexact_case(rng, m, in_dim=512, out_dim=256, bits=4)
-    got = quant_gemv_pipelined(
+    got = quant_matmul_pallas(
         jnp.asarray(x), jnp.asarray(q), jnp.asarray(s), jnp.asarray(b),
         group_size=GS, bits=4, block_out=128, block_in=256, interpret=True,
     )
@@ -75,34 +73,35 @@ def test_gemv_pipelined_bitexact_vs_golden(m):
 @pytest.mark.parametrize("bits", [4, 8])
 @pytest.mark.parametrize("m", [1, 2, 4, 8])
 @pytest.mark.parametrize("in_dim,out_dim", [(512, 128), (1024, 256)])
-def test_gemv_parity_matrix(bits, m, in_dim, out_dim):
-    """Full sweep: pipelined GEMV and the 3-D-grid kernel, every decode M,
-    both packed widths — all bit-exact vs the golden reference."""
+def test_matmul_parity_matrix_at_decode_rows(bits, m, in_dim, out_dim):
+    """Full sweep: the 3-D-grid kernel at every decode M, both packed
+    widths — all bit-exact vs the golden reference."""
     rng = np.random.default_rng(21)
     x, q, s, b, want = _bitexact_case(rng, m, in_dim, out_dim, bits)
     ops = [jnp.asarray(a) for a in (x, q, s, b)]
-    gemv = quant_gemv_pipelined(
-        *ops, group_size=GS, bits=bits, block_out=128,
-        block_in=in_dim // 2, interpret=True,
-    )
     grid = quant_matmul_pallas(
         *ops, group_size=GS, bits=bits, block_m=8, block_out=128,
         block_in=in_dim // 2, interpret=True,
     )
-    assert np.array_equal(np.asarray(gemv), want)
     assert np.array_equal(np.asarray(grid), want)
 
 
-def test_linear_gemv_dispatch_bitexact(monkeypatch):
-    """ops.quant.linear with the GEMV dispatch forced through interpret
-    mode (the CPU stand-in for the TPU decode path) stays bit-exact."""
-    monkeypatch.setenv("MST_QMM_GEMV", "interpret")
+def test_linear_one_row_dispatches_to_the_kernel(monkeypatch):
+    """On a TPU ops.quant.linear sends a single stream's one row to the
+    Pallas kernel, counted once. Traced, not run: a TPU kernel does not run
+    on the CPU."""
+    from mlx_sharding_tpu.ops import quant
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     rng = np.random.default_rng(22)
-    x, q, s, b, want = _bitexact_case(rng, 1, in_dim=512, out_dim=256, bits=4)
+    x, q, s, b, _ = _bitexact_case(rng, 1, in_dim=512, out_dim=256, bits=4)
     packed = {"q": jnp.asarray(q), "scales": jnp.asarray(s),
               "biases": jnp.asarray(b)}
-    got = linear(jnp.asarray(x), packed, GS, 4)
-    assert np.array_equal(np.asarray(got), want)
+    before = quant.dispatch_counts()
+    text = str(jax.make_jaxpr(lambda x: linear(x, packed, GS, 4))(jnp.asarray(x)))
+    after = quant.dispatch_counts()
+    assert "quant_matmul" in text
+    assert after == {"matmul": before["matmul"] + 1, "xla": before["xla"]}
 
 
 def test_fused_projection_bitexact():

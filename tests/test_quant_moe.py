@@ -441,7 +441,7 @@ def test_expert_kernel_bf16_rows_round_as_the_gather_path_does():
 def test_apply_experts_dispatch(monkeypatch):
     """What ``apply_experts`` chooses from what it can observe: on the CPU
     the gather fallback; with the backend answered as ``tpu`` (as
-    tests/test_tpu_compile.py does) the kernel; MST_QMM=0, 17 rows, a shape
+    tests/test_tpu_compile.py does) the kernel; 17 rows, a shape
     outside the kernel's contract and ``ep_axis`` keep the paths they had."""
     from mlx_sharding_tpu.ops import moe
 
@@ -507,9 +507,6 @@ def test_apply_experts_dispatch(monkeypatch):
         check_vma=False,
     ))(x, weights, idx, wg, wu, wd))
     assert "quant_matmul_experts" not in text and "psum" in text
-    monkeypatch.setenv("MST_QMM", "0")
-    assert not moe.packed_kernel_ok(16, wg, wu, wd, gs, 4)
-    monkeypatch.delenv("MST_QMM")
     # an OUT of 10944 rows has no 128-row tiling the pickers accept
     odd = _packed_stack(rng, 2, 96, 64, 64)
     assert not moe.packed_kernel_ok(16, odd, odd, _packed_stack(rng, 2, 64, 96, 32), 64, 4)

@@ -10,6 +10,7 @@ minimal known-bad snippet: exactly one finding, with the expected span.
 
 import json
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -108,6 +109,28 @@ def test_one_benchmark_program_and_one_account_of_speed():
     assert not named, named
     for f in ("bench" + ".py", "BENCH" + "_DETAIL.json", "ADVICE.md"):
         assert not (REPO / f).exists(), f
+
+
+def test_which_kernel_runs_is_decided_in_ops_and_by_nobody_else():
+    """A kernel is chosen by one predicate per op, inside ``ops/``, from the
+    backend, the operands' shapes and an ``interpret`` argument (PR 47): no
+    file of ``ops/`` reads the environment, no file of the package names one
+    of the six removed switches, and the 4-bit GEMV that no cell dispatched
+    to is gone with its start-up sweep."""
+    switches = re.compile(
+        r"MST_(QMM|QMM_GEMV|QMM_AUTOTUNE|FUSE_PROJ|PAGED_KERNEL|FLASH)\b")
+    named = []
+    for path in sorted(PACKAGE.rglob("*.py")):
+        text = path.read_text()
+        rel = path.relative_to(REPO)
+        named += [f"{rel}: {m.group(0)}" for m in switches.finditer(text)]
+        if path.parent.name == "ops" and "os.environ" in text:
+            named.append(f"{rel}: os.environ")
+    assert not named, named
+    from mlx_sharding_tpu.ops import quant_matmul
+
+    for name in ("quant_gemv_pipelined", "autotune_gemv"):
+        assert not hasattr(quant_matmul, name), name
 
 
 def test_static_lock_graph_is_acyclic_with_expected_edges():

@@ -204,20 +204,18 @@ CASES = {
     # groups: two blocks of 64 heads, four groups each)
     "ssm-step-granite": _ssm_step(36, 48, 64, 1),
     "ssm-step-nemotron3": _ssm_step(5, 32, 128, 8),
-    # 4-bit projections of the 3B model: batch kernel at M=256, GEMV at 1, 8
-    **{f"quant-M{m}-{i}x{o}": _quant(
-        m, o, i, "quant_matmul" if m == 256 else "quant_gemv_pipelined")
+    # 4-bit projections of the 3B model: a prefill chunk's 256 rows, a
+    # single stream's one row and 8 slots' rows, all on the one kernel
+    **{f"quant-M{m}-{i}x{o}": _quant(m, o, i, "quant_matmul")
        for o, i in LLAMA_3B for m in (256, 1, 8)},
     # scales as a bf16 checkpoint stores them (fp16 widens to f32 at load)
-    "quant-M1-3072x8192-bf16-scales": _quant(
-        1, 8192, 3072, "quant_gemv_pipelined", BF16),
-    # DeepSeek-V2-Lite experts and dense MLP at decode: the GEMV where its
-    # DMA slices align; 1408 inputs are 176 word lanes (not 128-aligned), so
-    # that one takes the 3-D-grid kernel whole-IN; 10944 rows (64 x 171) do
-    # not divide into 128-row tiles, so the 3-D-grid kernel takes them with
-    # a ragged last OUT tile: a partial write of the output's lane dimension
+    "quant-M1-3072x8192-bf16-scales": _quant(1, 8192, 3072, "quant_matmul", BF16),
+    # DeepSeek-V2-Lite experts and dense MLP at one row: 1408 inputs are 176
+    # word lanes (not 128-aligned), so they run as one whole IN block; 10944
+    # rows (64 x 171) have no 128-row tiling, so the kernel takes them with a
+    # ragged last OUT tile: a partial write of the output's lane dimension
     # and edge reads of q, scales and biases, which Mosaic must accept
-    "quant-M1-dsv2-2048x1408": _quant(1, 1408, 2048, "quant_gemv_pipelined"),
+    "quant-M1-dsv2-2048x1408": _quant(1, 1408, 2048, "quant_matmul"),
     "quant-M1-dsv2-1408x2048": _quant(1, 2048, 1408, "quant_matmul"),
     "quant-M1-dsv2-2048x10944": _quant(1, 10944, 2048, "quant_matmul"),
     # ... and at the served rows: a 16-slot decode step and a 256-row chunk,
