@@ -654,6 +654,78 @@ def child_kernels(seed: int, rehearse: bool) -> None:
           functools.partial(mamba, steps=(m_t, 1)), functools.partial(mamba, steps=(m_t + 1,)),
           (x, stack), ATTN_ATOL)
 
+    # ---- the kimi-linear-48b-bf16-ep16 cell's gated delta rule (ops/kda.py)
+    # at the published sizes, float32: the chunked (WY) form at block 64 with
+    # a ragged last block and the strongest decay (exp(A_log) = 16 on head 0)
+    # against the recurrence one position at a time; then a decode step in ONE
+    # pass over the state pool where it lies (kda_pool_step: 40 slots x 32
+    # heads of 128 x 128, the middle layer of three, one slot frozen) against
+    # the same recurrence: o, the layer's rows, every row it must not touch
+    from mlx_sharding_tpu.ops import kda
+
+    k_b, k_h, k_d, k_t = (3, 4, 16, 21) if rehearse else (40, 32, 128, 200)
+    ks = jax.random.split(key, 8)
+    key = ks[7]
+
+    def kda_inputs(b, t):
+        q = kda._l2norm(jax.random.normal(ks[0], (b, t, k_h, k_d))) * k_d**-0.5
+        k_ = kda._l2norm(jax.random.normal(ks[1], (b, t, k_h, k_d)))
+        decay = -jnp.linspace(16.0, 0.01, k_h)[:, None] * jax.nn.softplus(
+            jax.random.normal(ks[3], (b, t, k_h, k_d)) - 3.0)
+        return (q, k_, jax.random.normal(ks[2], (b, t, k_h, k_d)), decay,
+                jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, k_h))))
+
+    check(f"KDA chunked form, block {kda.CHUNK if not rehearse else 8} over {k_t} rows, against the sequential recurrence",
+          None, lambda *a: both(kda.kda_chunked(*a, 8 if rehearse else kda.CHUNK)),
+          lambda *a: both(kda.kda_sequential(*a)),
+          (*kda_inputs(2, k_t), jax.random.normal(ks[5], (2, k_h, k_d, k_d))), 1e-3,
+          relative=True)
+    # (1e-3: it is the SEQUENTIAL side that drifts on the chip, a product of
+    # one exponential a row each off by up to 5e-6: 2.5e-4 over 256 rows
+    # against float64 where the chunked form reads 4e-6; chip, PR 48)
+
+    def kda_step_ref(pool, q, k_, v, g, beta, active):
+        nb = q.shape[0]
+        o, s_new = kda.kda_sequential(
+            q[:, None], k_[:, None], v[:, None], g[:, None], beta[:, None], pool[1, :nb])
+        s_new = jnp.where(active[:, None, None, None], s_new, pool[1, :nb])
+        return jnp.concatenate([o.ravel(), pool.at[1, :nb].set(s_new).ravel()])
+
+    def kda_step(pool, q, k_, v, g, beta, active):
+        o, pool = kda.kda_pool_step(pool, 1, q, k_, v, g, beta, active, interpret=rehearse)
+        return jnp.concatenate([o.ravel(), pool.ravel()])
+
+    check(f"KDA decode step in one pass over the pool, {k_b} slots x {k_h} heads of {k_d} x {k_d}",
+          "kda_pool_step", kda_step, kda_step_ref,
+          (jax.random.normal(ks[6], (3, k_b + 1, k_h, k_d, k_d), jnp.float32),
+           *(z[:, 0] for z in kda_inputs(k_b, 1)),
+           jnp.ones((k_b,), bool).at[1].set(False)),
+          1e-6, relative=True)
+
+    # ---- the same cell's latent attention: 40 slots, 32 query heads on the
+    # ONE shared head of a 576-wide row whose first 512 values are the values
+    # (values_from_k), bf16, 512-token pages, a 6144-token table, every slot
+    # 0.8k-6k tokens in, scale 192**-0.5 (dsv2-lite-q4's kernel at twice its
+    # heads and ten times its contexts)
+    if rehearse:
+        l_slots, l_hq, l_row, l_lat, l_page, l_seq = 3, 4, 24, 16, 8, 48
+    else:
+        l_slots, l_hq, l_row, l_lat, l_page, l_seq = 40, 32, 576, 512, 512, 6144
+    l_spg, l_scale = l_seq // l_page, 192**-0.5
+    kq, kk, key = jax.random.split(key, 3)
+    k_pool = jax.random.normal(kk, (l_slots * l_spg + 1, l_page, 1, l_row), bf16)
+    v_pool = jnp.zeros((*k_pool.shape[:3], 1), bf16)
+    check(f"paged latent MQA {l_hq} heads on a {l_row}-wide row page={l_page}", "paged_attention",
+          functools.partial(paged_attention, scale=l_scale, values_from_k=l_lat,
+                            interpret=rehearse),
+          lambda q_, k_, v_, tb, ln: _paged_attention_xla(
+              q_, k_, v_, tb, ln, l_scale, None, None, l_lat, None, None),
+          (jax.random.normal(kq, (l_slots, l_hq, l_row), bf16), k_pool, v_pool,
+           jnp.asarray(np.arange(l_slots * l_spg).reshape(l_slots, l_spg), jnp.int32),
+           jnp.asarray(np.linspace(l_seq // 8, l_seq - 7, l_slots), jnp.int32)),
+          ATTN_ATOL)
+    del k_pool, v_pool
+
     # ---- 4-bit matmuls: the one kernel at a prefill chunk's rows, a
     # 16-slot decode step's, a single stream's one row and 8 slots'
     for out_dim, in_dim in quant_shapes:

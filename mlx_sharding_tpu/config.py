@@ -450,6 +450,93 @@ class GraniteMoeHybridConfig(BaseConfig):
             raise ValueError("num_key_value_heads must divide num_attention_heads")
 
 
+@dataclass
+class KimiLinearConfig(BaseConfig):
+    """Moonshot Kimi-Linear (``kimi_linear``): every block is a mixer and a
+    feed-forward, each behind its RMSNorm. ``linear_attn_config`` lists, with
+    1-BASED layer numbers, the layers whose mixer is Kimi Delta Attention
+    (``kda_layers``: a gated delta rule, ``num_heads`` heads of ``head_dim``
+    behind a ``short_conv_kernel_size``-tap convolution) and those whose
+    mixer is multi-head latent attention (``full_attn_layers``: DeepSeek-V2's
+    head sizes, no query LoRA, NO rotary embedding, ``mla_use_nope``). The
+    first ``first_k_dense_replace`` layers' feed-forward is a SwiGLU MLP, the
+    others' ``num_experts`` routed SwiGLU experts under a sigmoid router
+    with a selection bias (top ``num_experts_per_token``, renormalised, times
+    ``routed_scaling_factor``) plus one shared expert. ``head_dim`` and
+    ``num_key_value_heads`` are inert: both mixers have their own head sizes.
+
+    A layer may hold one chip's SHARE of the routed experts, as
+    :class:`NemotronHConfig` says: ``num_experts`` counts the experts held,
+    ``moe_expert_share`` the holders, ``moe_expert_share_index`` which one
+    this is. A checkpoint's own config (no share keys) is the whole model."""
+
+    model_type: str = "kimi_linear"
+    linear_attn_config: Optional[dict] = None
+    kv_lora_rank: int = 512
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    mla_use_nope: bool = True
+    first_k_dense_replace: int = 1
+    moe_layer_freq: int = 1
+    moe_intermediate_size: int = 1024
+    num_experts: int = 256
+    num_experts_per_token: int = 8
+    num_shared_experts: int = 1
+    num_expert_group: int = 1
+    topk_group: int = 1
+    moe_renormalize: bool = True
+    moe_router_activation_func: str = "sigmoid"
+    routed_scaling_factor: float = 1.0
+    hidden_act: str = "silu"
+    moe_expert_share: int = 1
+    moe_expert_share_index: int = 0
+    max_position_embeddings: int = 1048576
+
+    def __post_init__(self):
+        lin = dict(self.linear_attn_config or {})
+        n = self.num_hidden_layers
+        kda, mla = set(lin.get("kda_layers", ())), set(lin.get("full_attn_layers", ()))
+        if kda & mla or kda | mla != set(range(1, n + 1)):
+            raise ValueError(
+                "kimi_linear needs linear_attn_config's kda_layers and "
+                f"full_attn_layers to name each of the layers 1..{n} once"
+            )
+        if mla & set(range(1, self.first_k_dense_replace + 1)):
+            raise ValueError(
+                "kimi_linear is wired for leading dense layers that are KDA layers"
+            )
+        wired = {
+            "mla_use_nope": True, "q_lora_rank": None, "moe_layer_freq": 1,
+            "num_expert_group": 1, "topk_group": 1, "num_shared_experts": 1,
+            "moe_router_activation_func": "sigmoid", "hidden_act": "silu",
+            "rope_scaling": None, "tie_word_embeddings": False,
+        }
+        for key, want in wired.items():
+            if getattr(self, key) != want:
+                raise ValueError(
+                    f"kimi_linear is wired for {key} = {want!r}, not "
+                    f"{getattr(self, key)!r}"
+                )
+        if not 0 <= self.first_k_dense_replace <= n:
+            raise ValueError("first_k_dense_replace must lie in [0, num_hidden_layers]")
+        if not 0 <= self.moe_expert_share_index < self.moe_expert_share:
+            raise ValueError("moe_expert_share_index must lie in [0, moe_expert_share)")
+        super().__post_init__()
+        self.linear_attn_config = lin
+
+    @property
+    def layer_kinds(self) -> list:
+        """Each layer's mixer, ``"kda"`` or ``"mla"``, 0-based."""
+        mla = set(self.linear_attn_config["full_attn_layers"])
+        return ["mla" if i + 1 in mla else "kda" for i in range(self.num_hidden_layers)]
+
+    @property
+    def router_width(self) -> int:
+        return self.num_experts * self.moe_expert_share
+
+
 # Arch-name resolution. Mirrors the reference's MODEL_REMAPPING
 # (shard/utils.py:14-17): mistral runs through the llama implementation.
 MODEL_REMAPPING = {
@@ -467,6 +554,7 @@ CONFIG_REGISTRY: dict[str, type] = {
     "afmoe": AfmoeConfig,
     "zaya": ZayaConfig,
     "granitemoehybrid": GraniteMoeHybridConfig,
+    "kimi_linear": KimiLinearConfig,
 }
 
 

@@ -25,6 +25,7 @@ MODEL_REGISTRY: dict[str, tuple[str, str]] = {
     "granitemoehybrid": (
         "mlx_sharding_tpu.models.granitemoehybrid", "GraniteMoeHybridModel",
     ),
+    "kimi_linear": ("mlx_sharding_tpu.models.kimi_linear", "KimiLinearModel"),
 }
 
 

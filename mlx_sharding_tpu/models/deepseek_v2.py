@@ -5,8 +5,9 @@ mlx_lm's DeepseekV2DecoderLayer (ref :8,30), stacks per-expert weights into
 fused switch tensors in sanitize (ref :101-112), and exposes the MLA tuple
 head-dim cache shape (ref :120-125). Here the architecture is first-party:
 
-- **MLA**: queries (optionally LoRA-factored), K/V decompressed from a
-  shared low-rank latent (``kv_a_proj_with_mqa`` → rank + single-head rope
+- **MLA** (the projection math is ``ops/mla.py``'s, which
+  ``models/kimi_linear.py`` calls without rotary): queries (optionally
+  LoRA-factored), K/V decompressed from a shared low-rank latent (``kv_a_proj_with_mqa`` → rank + single-head rope
   part; ``kv_b_proj`` → per-head nope-K and V), interleaved complex-pair
   RoPE with YaRN frequencies/attention-scaling, K dim ≠ V dim in the cache
   (our KVCache carries per-tensor head dims).
@@ -38,10 +39,10 @@ from mlx_sharding_tpu.models.base import (
     stack_layers,
 )
 from mlx_sharding_tpu.ops import causal_attention, rms_norm
+from mlx_sharding_tpu.ops.mla import absorb_values, mla_qkv
 from mlx_sharding_tpu.ops.moe import apply_experts, deepseek_routing
 from mlx_sharding_tpu.ops.quant import is_quantized
 from mlx_sharding_tpu.ops.rope import (
-    apply_rope_interleaved,
     rope_frequencies,
     yarn_frequencies,
     yarn_get_mscale,
@@ -173,52 +174,18 @@ class DeepseekV2Model(BaseModel):
     # ------------------------------------------------------------------
     @jax.named_scope("mst.attn.qkv")
     def _attn_qkv(self, p, h, offset):
-        """Shared MLA projection math of the causal and sequence-parallel
-        attention paths. Compressed mode returns ``(q_cat (B,T,H,rank+rope),
-        k_new (B,T,1,rank+rope), None, w_bv (rank,H,v_d))`` — kv_b absorbed
-        into the query side, values are the latent slice of the keys.
-        Decompressed: ``(q_full, k, v, None)`` with per-head K/V."""
+        """The input norm and ``ops.mla.mla_qkv``, shared by the causal and
+        sequence-parallel attention paths: ``(q_cat, k_new, None, w_bv)`` in
+        compressed mode, ``(q_full, k, v, None)`` decompressed."""
         cfg = self.config
-        b, t, _ = h.shape
-        nope, rope_d, v_d = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        rank = cfg.kv_lora_rank
-
-        r = rms_norm(h, p["input_norm"], cfg.rms_norm_eps)
-        if cfg.q_lora_rank is None:
-            q = self._linear(r, p["q_proj"])
-        else:
-            q = self._linear(
-                rms_norm(self._linear(r, p["q_a_proj"]), p["q_a_norm"], cfg.rms_norm_eps),
-                p["q_b_proj"],
-            )
-        q = q.reshape(b, t, -1, nope + rope_d)
-        q_nope, q_pe = q[..., :nope], q[..., nope:]
-        q_pe = apply_rope_interleaved(q_pe, self.inv_freq, offset, self.rope_scale)
-
-        ckv = self._linear(r, p["kv_a_proj"])  # (B, T, rank + rope_d)
-        compressed, k_pe_raw = ckv[..., :rank], ckv[..., rank:]
-        latent = rms_norm(compressed, p["kv_a_norm"], cfg.rms_norm_eps)
-        k_pe = apply_rope_interleaved(
-            k_pe_raw[:, :, None, :], self.inv_freq, offset, self.rope_scale
-        )  # single shared rope head
-
-        if cfg.mla_cache_mode == "compressed":
-            w_b = p["kv_b_proj"].reshape(rank, -1, nope + v_d)
-            w_bk, w_bv = w_b[..., :nope], w_b[..., nope:]
-            q_lat = jnp.einsum(
-                "bthn,rhn->bthr", q_nope, w_bk, preferred_element_type=jnp.float32
-            ).astype(h.dtype)
-            q_cat = jnp.concatenate([q_lat, q_pe], axis=-1)  # (B,T,H,rank+rope)
-            k_new = jnp.concatenate([latent[:, :, None, :], k_pe], axis=-1)
-            return q_cat, k_new, None, w_bv
-        kv = self._linear(latent, p["kv_b_proj"]).reshape(b, t, -1, nope + v_d)
-        k_nope, v = kv[..., :nope], kv[..., nope:]
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_pe, (*k_nope.shape[:-1], rope_d))],
-            axis=-1,
+        return mla_qkv(
+            self._linear, p, rms_norm(h, p["input_norm"], cfg.rms_norm_eps), offset,
+            nope=cfg.qk_nope_head_dim, rope_d=cfg.qk_rope_head_dim,
+            v_d=cfg.v_head_dim, rank=cfg.kv_lora_rank, eps=cfg.rms_norm_eps,
+            rotary=(self.inv_freq, self.rope_scale),
+            compressed=cfg.mla_cache_mode == "compressed",
+            q_lora=cfg.q_lora_rank is not None,
         )
-        q_full = jnp.concatenate([q_nope, q_pe], axis=-1)
-        return q_full, k, v, None
 
     def _attention(self, h, p, k_buf, v_buf, offset, tp_axis=None):
         """MLA under tensor parallelism: the low-rank latent path
@@ -244,20 +211,11 @@ class DeepseekV2Model(BaseModel):
             out_lat = causal_attention(
                 q, k_buf, k_buf[..., :rank], offset, self.scale
             )  # (B,T,H,rank)
-            attn = self._absorb_values(out_lat, w_bv, h.dtype)
+            attn = absorb_values(out_lat, w_bv, h.dtype)
         else:
             k_buf, v_buf = write_layer_kv(k_buf, v_buf, k_new, v_new, offset)
             attn = causal_attention(q, k_buf, v_buf, offset, self.scale)
         return self._attn_out(h, attn, p, tp_axis), k_buf, v_buf
-
-    @staticmethod
-    @jax.named_scope("mst.attn.core")
-    def _absorb_values(out_lat, w_bv, dtype):
-        """Compressed mode's value side: kv_b's V half applied to the
-        attention output over the latent."""
-        return jnp.einsum(
-            "bthr,rhv->bthv", out_lat, w_bv, preferred_element_type=jnp.float32
-        ).astype(dtype)
 
     @jax.named_scope("mst.attn.core")
     def _attn_out(self, h, attn, p, tp_axis=None):
@@ -285,7 +243,7 @@ class DeepseekV2Model(BaseModel):
         if cfg.mla_cache_mode == "compressed":
             v_new = jnp.zeros((b, t, 1, 1), h.dtype)
             out_lat = attn_fn(q, k_new, v_new, values_from_k=rank)
-            attn = self._absorb_values(out_lat, w_bv, h.dtype)
+            attn = absorb_values(out_lat, w_bv, h.dtype)
         else:
             attn = attn_fn(q, k_new, v_new)
         h = self._attn_out(h, attn, p)

@@ -1,0 +1,379 @@
+"""Kimi Delta Attention (arXiv 2510.26692): a gated delta rule with one decay
+a KEY CHANNEL, the mixer of ``models/kimi_linear.py``'s linear-attention
+layers.
+
+``[q, k, v] = silu(causal depthwise conv1d(qkv_proj(u)))``, ``H`` heads of
+``D`` each; ``q = l2norm(q) * D**-0.5``, ``k = l2norm(k)``; the decay ``g =
+-exp(A_log[h]) * softplus(f_b(f_a(u))[h, d] + dt_bias[h, d])`` (``alpha =
+exp(g)`` in ``(0, 1)``, one a head and key channel), ``beta = sigmoid(b_proj(
+u))[h]``. Per head, with ``S`` a ``(D, D)`` matrix from key to value channels:
+
+    ``S' = alpha_t (.) S_{t-1}`` (rows scaled),
+    ``S_t = S' + beta_t k_t (v_t - S'^T k_t)^T``,   ``o_t = S_t^T q_t``;
+
+``y = rmsnorm_over_D(o) * o_norm * sigmoid(g_b(g_a(u)))``; ``o_proj``. Per
+sequence a layer keeps ``S (H, D, D)`` in float32 and the last ``taps - 1``
+inputs of its convolution in the activation dtype.
+
+Three forms of the recurrence, all float32. :func:`kda_sequential` is the
+definition, one position at a time. A prompt chunk runs :func:`kda_chunked`:
+blocks of ``chunk`` positions as matrix products (the WY form below). A decode
+step runs the one-step form on the state pool ``(layers, rows, H, D, D)``
+where it lies (:func:`kda_pool_step`, a Pallas kernel after
+``ops.mamba2.ssm_pool_step``: one grid step a slot, read the slot's S, scale
+its rows, ``k^T S'`` and ``S^T q`` from the block in VMEM, the rank-1 add, an
+inactive slot's S kept, write over what was read, the pool aliased to the
+result: each byte of S moves once each way). Unlike Mamba-2's step this one
+is no multiply-add: ``k^T S'`` is a reduction over the state BEFORE the
+write, so as array operations (:func:`_kda_step_xla`: a backend without the
+kernel, the lanes of a ``jax.vmap``) it is a slice, a pass for ``k^T S'``, a
+pass that writes ``S_t`` and reads it for ``o``, and the write-back.
+
+The chunked form. With ``u_t = beta_t (v_t - S_{t-1}^T (alpha_t (.) k_t))``
+the recurrence is ``S_t = Diag(alpha_t) S_{t-1} + k_t u_t^T``. Inside a block
+with ``G_t = sum_{i<=t} g_i`` (a key channel's, ``<= 0``) and ``P_ti(x) =
+sum_d x_td k_id exp(G_td - G_id)``:
+
+    ``A_ti = beta_t P_ti(k)`` for ``i < t``;
+    ``U = (I + A)^{-1} (beta (.) V) - [(I + A)^{-1} (beta (.) exp(G) (.) K)] S_0``;
+    ``o_t = S_0^T (exp(G_t) (.) q_t) + sum_{i<=t} P_ti(q) u_i``;
+    ``S_C = Diag(exp(G_C)) S_0 + sum_i (exp(G_C - G_i) (.) k_i) u_i^T``.
+
+Both inverses' products are independent of ``S_0``, so every block's pairwise
+sums and its unit lower triangular solve are made at once and only the three
+products with ``S_0`` run block after block. ``exp(-G_i)`` ALONE is never
+formed: at ``exp(A_log)`` = 16 it overflows float32 inside 64 positions;
+every exponent here is a difference that is ``<= 0``.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from mlx_sharding_tpu.ops.dispatch import DispatchCounter
+from mlx_sharding_tpu.ops.mamba2 import keep_inactive, put_rows, take_rows
+
+_HI = jax.lax.Precision.HIGHEST
+
+# Which path a decode step's recurrence took, once per traced call
+# (ops/dispatch.py). /metrics shows it as ``mst_kda_dispatch_total{path}``:
+# "xla" above 0 on a chip says some layer slices its rows of S out of the
+# pool, passes over them twice and writes them back.
+_DISPATCHED = DispatchCounter("kernel", "xla")
+dispatch_counts = _DISPATCHED.counts
+_count_dispatch = _DISPATCHED.count
+
+#: positions a block of :func:`kda_chunked` holds (the published kernel's)
+CHUNK = 64
+#: bytes of S one grid step of :func:`kda_pool_step` moves each way, at most
+_STEP_BLOCK_BYTES = 2 << 20
+
+
+def kda_sequential(q, k, v, g, beta, state):
+    """The recurrence one position at a time (``lax.scan``): the definition,
+    what the other two forms must equal. ``q`` / ``k (B, T, H, Dk)`` already
+    normalised and scaled, ``v (B, T, H, Dv)``, ``g (B, T, H, Dk)`` the
+    log-decay (``<= 0``; 0 with ``beta`` 0 at a row that must not advance the
+    state), ``beta (B, T, H)``, ``state (B, H, Dk, Dv)``; float32. Returns
+    ``(o (B, T, H, Dv), state after the last row)``."""
+
+    def step(s, xs):
+        q_t, k_t, v_t, g_t, b_t = xs
+        s = jnp.exp(g_t)[..., None] * s
+        r = (s * k_t[..., None]).sum(-2)
+        s = s + (b_t[..., None] * k_t)[..., None] * (v_t - r)[..., None, :]
+        return s, (s * q_t[..., None]).sum(-2)
+
+    t_first = lambda z: jnp.moveaxis(z, 1, 0)  # noqa: E731
+    state, o = jax.lax.scan(
+        step, state, tuple(t_first(z) for z in (q, k, v, g, beta))
+    )
+    return jnp.moveaxis(o, 0, 1), state
+
+
+def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
+    """:func:`kda_sequential` over blocks of ``chunk`` positions as matrix
+    products (the module docstring's WY form): same arguments and result.
+    A ragged last block is padded with rows of ``g = 0``, ``beta = 0``, which
+    pass the state through."""
+    b, t, h, dk = k.shape
+    pad = -t % chunk
+    if pad:
+        padt = lambda z: jnp.pad(z, ((0, 0), (0, pad)) + ((0, 0),) * (z.ndim - 2))  # noqa: E731
+        q, k, v, g, beta = padt(q), padt(k), padt(v), padt(g), padt(beta)
+    nc = (t + pad) // chunk
+    # (nc, B, H, C, …): blocks first for the scans, positions beside channels
+    split = lambda z: jnp.moveaxis(  # noqa: E731
+        z.reshape(b, nc, chunk, *z.shape[2:]), (1, 3), (0, 2)
+    )
+    q, k, v, g = split(q), split(k), split(v), split(g)
+    beta = split(beta[..., None])  # (nc, B, H, C, 1)
+    gc = jnp.cumsum(g, axis=3)  # G_t, from the block's start
+    before = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    upto = jnp.tril(jnp.ones((chunk, chunk), bool))
+
+    def pairwise(xs):
+        """``P_ti(k)`` for ``i < t`` and ``P_ti(q)`` for ``i <= t`` of one
+        block: float32 products summed over the key channels, the exponent a
+        difference masked BEFORE the exponential (one exponential serves
+        both: on the diagonal it is 1, and ``P(k)`` leaves the diagonal out)."""
+        q_c, k_c, g_c = xs  # (B, H, C, Dk)
+        diff = g_c[..., :, None, :] - g_c[..., None, :, :]  # (B, H, C, C, Dk)
+        decayed = k_c[..., None, :, :] * jnp.exp(
+            jnp.where(upto[..., None], diff, -jnp.inf)
+        )
+        kk = jnp.where(before, (k_c[..., :, None, :] * decayed).sum(-1), 0.0)
+        return kk, (q_c[..., :, None, :] * decayed).sum(-1)
+
+    kk, qk = jax.lax.map(pairwise, (q, k, gc))  # (nc, B, H, C, C)
+    eg = jnp.exp(gc)
+    # (I + A)^{-1} [beta V | beta exp(G) K]: one unit lower triangular solve
+    rhs = jnp.concatenate([beta * v, beta * eg * k], axis=-1)
+    with jax.default_matmul_precision("highest"):
+        solved = jax.scipy.linalg.solve_triangular(
+            jnp.eye(chunk, dtype=jnp.float32) + beta * kk, rhs,
+            lower=True, unit_diagonal=True,
+        )
+    u0, w = solved[..., : v.shape[-1]], solved[..., v.shape[-1] :]
+    to_end = jnp.exp(gc[..., -1:, :] - gc) * k  # exp(G_C - G_i) k_i
+    total = eg[..., -1, :]  # (nc, B, H, Dk) a block's whole decay
+
+    def block(s, xs):
+        u0_c, w_c, qe_c, qk_c, to_end_c, total_c = xs
+        mm = functools.partial(jnp.einsum, precision=_HI)
+        u = u0_c - mm("bhcd,bhdv->bhcv", w_c, s)
+        o = mm("bhcd,bhdv->bhcv", qe_c, s) + mm("bhci,bhiv->bhcv", qk_c, u)
+        s = total_c[..., None] * s + mm("bhcd,bhcv->bhdv", to_end_c, u)
+        return s, o
+
+    state, o = jax.lax.scan(block, state, (u0, w, eg * q, qk, to_end, total))
+    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, nc * chunk, h, -1)
+    return o[:, :t], state
+
+
+def _kda_step_kernel(active_ref, rank_ref, cols_ref, rows_ref, s_ref, y_ref, o_ref):
+    """One slot's block of ``hb`` heads: ``s_ref`` / ``o_ref (hb, Dk, Dv)`` the
+    same rows of the pool; ``cols_ref (Dk, 3 hb)`` the heads' ``alpha``, ``k``
+    and ``q`` with the key channels on sublanes as in S; ``rows_ref (2 hb,
+    Dv)`` the heads' ``beta v`` and ``beta`` along the lanes. ``y_ref (hb,
+    Dv)``. The two reductions run over SUBLANES (vector adds and one fold),
+    float32 throughout: a dot would round S to bf16."""
+    del rank_ref
+    slot = pl.program_id(0)
+    hb = s_ref.shape[0]
+    keep = active_ref[slot] != 0
+    cols, rows = cols_ref[...], rows_ref[...]
+    for h in range(hb):
+        old = s_ref[h]
+        k_c = cols[:, hb + h : hb + h + 1]
+        scaled = cols[:, h : h + 1] * old
+        r = jnp.sum(scaled * k_c, axis=0, keepdims=True)  # k^T S' (1, Dv)
+        new = scaled + k_c * (rows[h : h + 1] - rows[hb + h : hb + h + 1] * r)
+        y_ref[h : h + 1, :] = jnp.sum(
+            new * cols[:, 2 * hb + h : 2 * hb + h + 1], axis=0, keepdims=True
+        )
+        o_ref[h] = jnp.where(keep, new, old)
+
+
+def _head_block(heads: int, head_bytes: int) -> int:
+    """Heads of one grid step: the most that divide ``heads`` and keep the
+    block of S within ``_STEP_BLOCK_BYTES``."""
+    return max(
+        (hb for hb in range(1, heads + 1)
+         if heads % hb == 0 and hb * head_bytes <= _STEP_BLOCK_BYTES),
+        default=1,
+    )
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def kda_pool_step(pool, rank, q, k, v, g, beta, active=None, *,
+                  interpret: bool = False):
+    """The one-step recurrence on the state pool where it lies, ONE pass over
+    the layer's rows of S: ``pool (L, rows, H, Dk, Dv)`` float32, ``rank`` the
+    layer's row of it (may be traced), ``q`` / ``k`` / ``g (B, H, Dk)``, ``v
+    (B, H, Dv)``, ``beta (B, H)``, ``active (B,)`` or None. The pool is
+    aliased to the result: rows past ``B`` (an engine's scratch row) and the
+    other layers' rows are never moved. Returns ``(o (B, H, Dv), pool)``.
+    Jitted for the reason ``ops.mamba2.ssm_pool_step`` is: the body, unrolled
+    over a block's heads, is traced once for all the layers that call it."""
+    _, _, nh, dk, dv = pool.shape
+    b = q.shape[0]
+    hb = _head_block(nh, dk * dv * 4)
+    blocks = nh // hb
+    f32 = jnp.float32
+    # key channels on sublanes as in S, a block's heads side by side
+    col = lambda z: jnp.swapaxes(z.astype(f32).reshape(b, blocks, hb, dk), 2, 3)  # noqa: E731
+    cols = jnp.concatenate([col(jnp.exp(g)), col(k), col(q)], axis=-1)
+    beta = beta.astype(f32)[..., None]
+    rows = jnp.concatenate([
+        (beta * v).reshape(b, blocks, hb, dv),
+        jnp.broadcast_to(beta, (b, nh, dv)).reshape(b, blocks, hb, dv),
+    ], axis=2)
+    if active is None:
+        active = jnp.ones((b,), jnp.int32)
+    state_spec = pl.BlockSpec(
+        (None, None, hb, dk, dv), lambda i, j, a, r: (r[0], i, j, 0, 0)
+    )
+    small = lambda *shape: pl.BlockSpec(  # noqa: E731
+        (None, None, *shape), lambda i, j, *_: (i, j, 0, 0)
+    )
+    y, pool = pl.pallas_call(
+        _kda_step_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, blocks),
+            in_specs=[small(dk, 3 * hb), small(2 * hb, dv), state_spec],
+            out_specs=[small(hb, dv), state_spec],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, blocks, hb, dv), f32),
+            jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        ],
+        # operands count the scalar-prefetch ones: the pool is the fifth
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            # S in and out, double-buffered, and room for the small operands
+            vmem_limit_bytes=4 * hb * dk * dv * 4 + (8 << 20),
+        ),
+        interpret=interpret,
+        name="kda_pool_step",
+    )(
+        active.astype(jnp.int32), jnp.asarray(rank, jnp.int32).reshape(1),
+        cols, rows, pool,
+    )
+    return y.reshape(b, nh, dv), pool
+
+
+def step_kernel_eligible(pool, interpret: bool) -> bool:
+    """:func:`kda_pool_step` on a TPU backend (in interpret mode on any) for
+    a float32 pool whose ``(Dk, Dv)`` head tiles are whole sublane and lane
+    tiles; the array operations otherwise."""
+    if interpret:
+        return True
+    return (
+        jax.default_backend() == "tpu"
+        and pool.dtype == jnp.float32
+        and pool.shape[-2] % 8 == 0
+        and pool.shape[-1] % 128 == 0
+    )
+
+
+def _kda_step_xla(pool, rank, q, k, v, g, beta, active):
+    """:func:`kda_pool_step`'s step as array operations, same arguments."""
+    _count_dispatch("xla")
+    old = take_rows(pool, rank, q.shape[0])
+    with jax.named_scope("mst.kda.step"):
+        scaled = jnp.exp(g)[..., None] * old
+        # elementwise, not a dot: a TPU dot would round S to bf16
+        r = (scaled * k[..., None]).sum(-2)
+        new = scaled + (beta[..., None] * k)[..., None] * (v - r)[..., None, :]
+        o = (new * q[..., None]).sum(-2)
+        # the select stands INSIDE the scope: it is the root of the fusion
+        # that updates the state, and a fusion's time is its root's scope's
+        new = keep_inactive(active, new, old)
+    return o, put_rows(pool, rank, new)
+
+
+def _kda_step_lanes(axis_size, in_batched, *args):
+    """The kernel's call under ``jax.vmap`` (an engine's vectorized decode
+    step, one sequence a lane with its own rows of the pool): the lanes take
+    the array operations, as ``ops.mamba2._ssm_step_lanes`` says why."""
+    in_axes = jax.tree.map(lambda batched: 0 if batched else None, in_batched)
+    return jax.vmap(_kda_step_xla, in_axes=in_axes)(*args), (True, True)
+
+
+def kda_step(pool, rank, q, k, v, g, beta, active, interpret: bool = False):
+    """A decode step's recurrence on the pool by the path the operands allow:
+    ``(o (B, H, Dv), pool)``."""
+    if not step_kernel_eligible(pool, interpret):
+        return _kda_step_xla(pool, rank, q, k, v, g, beta, active)
+    _count_dispatch("kernel")
+    kernel = jax.custom_batching.custom_vmap(
+        functools.partial(kda_pool_step, interpret=interpret)
+    )
+    kernel.def_vmap(_kda_step_lanes)
+    with jax.named_scope("mst.kda.step"):
+        return kernel(pool, rank, q, k, v, g, beta, active)
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+
+def kda_mixer(
+    linear, p, u, pool, rank, tail, n_valid, active, *,
+    heads: int, head_dim: int, taps: int, eps: float, chunk: int = CHUNK,
+    interpret: bool = False,
+):
+    """One KDA mixer. ``linear(x, w)``: the model's projection; ``p``: the
+    layer's ``qkv_proj`` (hidden to ``[q, k, v]``, ``3 H D``), ``conv_w (3 H
+    D, taps)``, ``gate_a`` (hidden to the two low-rank gates' inner widths,
+    the decay's then the output's), ``f_b`` / ``g_b`` (inner to ``H D``),
+    ``b_proj`` (hidden to ``H``), ``A_log (H,)``, ``dt_bias (H D,)``,
+    ``o_norm (D,)``, ``o_proj``; ``u (B, T, hidden)`` the normed input;
+    ``pool (L, rows, H, D, D)`` float32 every layer's state, this layer's at
+    ``rank`` (may be traced), these ``B`` sequences' in its first ``B`` rows;
+    ``tail (B, taps - 1, 3 H D)`` the convolution's last inputs. Rows past
+    ``n_valid`` and sequences outside ``active`` advance neither. A decode
+    step (``T == 1``) updates the pool where it lies (:func:`kda_step`), a
+    chunk slices the layer's rows out, runs :func:`kda_chunked` and writes
+    them back. Returns ``(out (B, T, hidden), pool, tail)``."""
+    b, t, _ = u.shape
+    nh, d = heads, head_dim
+    width = nh * d
+    f32 = jnp.float32
+    with jax.named_scope("mst.kda.proj"):
+        qkv = linear(u, p["qkv_proj"])
+    with jax.named_scope("mst.kda.conv"):
+        # causal depthwise conv over [the last taps-1 inputs, this call's]
+        tail = tail.astype(qkv.dtype)
+        seq = jnp.concatenate([tail, qkv], axis=1)
+        w = p["conv_w"].astype(f32)  # (3 H D, taps)
+        conv = sum(seq[:, j : j + t].astype(f32) * w[:, j] for j in range(taps))
+        qkv_a = jax.nn.silu(conv)
+        # the next call's tail: the inputs that end at the last valid row
+        end = t if n_valid is None else n_valid
+        new_tail = jax.lax.dynamic_slice_in_dim(seq, end, taps - 1, axis=1)
+    with jax.named_scope("mst.kda.gate"):
+        heads_of = lambda z: z.reshape(b, t, nh, d)  # noqa: E731
+        q = _l2norm(heads_of(qkv_a[..., :width])) * d**-0.5
+        k = _l2norm(heads_of(qkv_a[..., width : 2 * width]))
+        v = heads_of(qkv_a[..., 2 * width :])
+        inner = linear(u, p["gate_a"])
+        half = inner.shape[-1] // 2
+        g = -jnp.exp(p["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+            heads_of(linear(inner[..., :half], p["f_b"]).astype(f32)
+                     + p["dt_bias"].astype(f32))
+        )
+        beta = jax.nn.sigmoid(linear(u, p["b_proj"]).astype(f32))
+        out_gate = jax.nn.sigmoid(linear(inner[..., half:], p["g_b"]).astype(f32))
+
+    if t == 1:
+        o, pool = kda_step(
+            pool, rank, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], active,
+            interpret,
+        )
+        o = o[:, None]
+    else:
+        old = take_rows(pool, rank, b)
+        with jax.named_scope("mst.kda.scan"):
+            if n_valid is not None:
+                live = (jnp.arange(t) < n_valid)[None, :, None]
+                g = jnp.where(live[..., None], g, 0.0)
+                beta = jnp.where(live, beta, 0.0)
+            o, s = kda_chunked(q, k, v, g, beta, old, chunk)
+            s = keep_inactive(active, s, old)  # inside the scope, as the step's
+        pool = put_rows(pool, rank, s)
+    with jax.named_scope("mst.kda.step" if t == 1 else "mst.kda.scan"):
+        new_tail = keep_inactive(active, new_tail, tail)
+    with jax.named_scope("mst.kda.out"):
+        o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + eps)
+        y = o * p["o_norm"].astype(f32) * heads_of(out_gate)
+        out = linear(y.reshape(b, t, width).astype(u.dtype), p["o_proj"])
+    return out, pool, new_tail

@@ -273,7 +273,7 @@ def step_kernel_eligible(pool, interpret: bool) -> bool:
     )
 
 
-def _keep(active, new, old):
+def keep_inactive(active, new, old):
     """An inactive sequence keeps what it had."""
     if active is None:
         return new
@@ -304,7 +304,7 @@ def _ssm_step_xla(pool, rank, dt, x, b_grp, c_grp, a_head, active):
         y = (s * c_mat[..., None, :]).sum(-1)
         # the select stands INSIDE the scope: it is the root of the fusion
         # that updates the state, and a fusion's time is its root's scope's
-        s = _keep(active, s, ssm)
+        s = keep_inactive(active, s, ssm)
     return y, put_rows(pool, rank, s)
 
 
@@ -394,10 +394,10 @@ def mamba2_mixer(
             if n_valid is not None:
                 dt = jnp.where((jnp.arange(t) < n_valid)[None, :, None], dt, 0.0)
             y, s = ssd_chunked(x, dt, a_head, b_mat, c_mat, ssm, chunk)
-            s = _keep(active, s, ssm)  # inside the scope, as the step's
+            s = keep_inactive(active, s, ssm)  # inside the scope, as the step's
         pool = put_rows(pool, rank, s)
     with jax.named_scope("mst.ssm.step" if t == 1 else "mst.ssm.scan"):
-        new_tail = _keep(active, new_tail, tail)
+        new_tail = keep_inactive(active, new_tail, tail)
     with jax.named_scope("mst.ssm.out_proj"):
         y = y + p["D"].astype(jnp.float32)[:, None] * x
         y = y.reshape(b, t, di) * jax.nn.silu(z.astype(jnp.float32))

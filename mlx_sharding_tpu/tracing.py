@@ -124,6 +124,15 @@ MODEL_SCOPES = (
     "mst.ssm.scan",
     "mst.ssm.step",
     "mst.ssm.out_proj",
+    # a gated delta-rule layer (ops/kda.py): the q, k, v projection, the
+    # convolution, the two low-rank gates with beta and the L2 norms, a
+    # chunk's WY form or a decode step's recurrence, the gated norm and o_proj
+    "mst.kda.proj",
+    "mst.kda.conv",
+    "mst.kda.gate",
+    "mst.kda.scan",
+    "mst.kda.step",
+    "mst.kda.out",
     "mst.state_pool.regroup",
     "mst.mlp.dense",
     "mst.norm",

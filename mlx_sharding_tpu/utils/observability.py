@@ -398,6 +398,7 @@ class ServingMetrics:
                  "paged_attention"),
                 ("# TYPE mst_moe_dispatch_total counter", "moe"),
                 ("# TYPE mst_ssm_dispatch_total counter", "mamba2"),
+                ("# TYPE mst_kda_dispatch_total counter", "kda"),
             ):
                 ops = sys.modules.get(f"mlx_sharding_tpu.ops.{module}")
                 if ops is not None:
@@ -1174,6 +1175,12 @@ _HELP = {
         "count per traced call: kernel updates the layer's rows of the state "
         "pool where they lie in one pass; xla slices them out, passes over "
         "them twice and writes them back (0 on a chip).",
+    "mst_kda_dispatch_total":
+        "Decode steps' gated delta-rule recurrences by the path ops/kda chose, "
+        "one count per traced call: kernel updates the layer's rows of the "
+        "state pool where they lie in one pass; xla slices them out, passes "
+        "over them for k^T S, again for the update and the output, and writes "
+        "them back (0 on a chip).",
     "mst_faults_armed":
         "Currently armed fault-injection sites (should be 0 in prod).",
     "mst_faults_malformed_total":

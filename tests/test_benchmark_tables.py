@@ -98,6 +98,21 @@ def test_the_reason_cell_is_sized_for_8_to_16_rejoins():
     assert mix["prompt_tokens"]["max"] + mix["output_tokens"]["max"] <= 4608
 
 
+def test_the_reason1k_cell_is_sized_for_8_to_16_rejoins():
+    name = "kimi-linear-48b-bf16-ep16.reason1k-sat"
+    assert name in SIZED
+    config, mix, load = _load(next(c for c in BENCH["workloads"] if c["name"] == name))
+    assert mix["sized_for"]["first_wave_ends"] == [8, 16]
+    assert mix["output_tokens"]["max"] - mix["output_tokens"]["min"] == 2304
+    # a join is two or three prefill chunks, and prompt + answer fits a
+    # slot's pages, every slot's at once
+    chunk = int(server_flag(config, "--prefill-chunk"))
+    assert chunk < mix["prompt_tokens"]["min"] and mix["prompt_tokens"]["max"] <= 3 * chunk
+    longest = mix["prompt_tokens"]["max"] + mix["output_tokens"]["max"]
+    assert longest <= int(server_flag(config, "--max-seq"))
+    assert int(load["clients"]) * -(-longest // chunk) <= int(server_flag(config, "--paged-pool"))
+
+
 def _texts():
     for kind in ("configs", "workloads"):
         for entry in BENCH[kind]:

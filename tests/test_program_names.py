@@ -6,6 +6,8 @@ at their layer boundaries — and no ``mst.*`` name outside it."""
 
 import re
 
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -448,6 +450,115 @@ def test_served_granite_programs_walk_their_periods_with_the_state_pool_in_place
     ]
     # the frozen-slot select of each body, over the SLOTS' rows of one layer
     assert moved == ["select_n"] * 2, moved
+
+
+@hard_timeout(420)
+def test_served_kimi_linear_programs_walk_a_head_the_periods_and_a_tail():
+    """The sixth family: a KDA or MLA mixer, then an MLP or experts, under
+    the same program names and scopes. A head layer, two periods of ``K K M
+    K`` and the tail ``K M``: the decode block holds the KDA body FOUR times
+    (the head's, the period's run of two and run of one, the tail's) and the
+    MLA body TWICE (the period's, the tail's), whatever the depth; the state
+    pool and the page pool ride the carry of every scan, never its ``xs`` or
+    ``ys``, and nothing of either pool's size is selected, concatenated,
+    padded, sliced out of it or copied."""
+    from mlx_sharding_tpu.models import build_model
+
+    model, _ = build_model(dict(
+        model_type="kimi_linear", vocab_size=128, hidden_size=32,
+        num_hidden_layers=11, num_attention_heads=2, intermediate_size=48,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+        moe_intermediate_size=16, num_experts=2, moe_expert_share=2,
+        num_experts_per_token=2, routed_scaling_factor=2.446,
+        linear_attn_config=dict(
+            full_attn_layers=[4, 8, 11], kda_layers=[1, 2, 3, 5, 6, 7, 9, 10],
+            head_dim=8, num_heads=2, short_conv_kernel_size=4),
+    ))
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    eng = PipelineEngine(
+        model, params, pipeline_mesh(1), microbatches=2, max_seq=64,
+        cache_dtype=jnp.float32, prefill_chunk=8, pool_pages=10, page_size=8,
+    )
+    b = ContinuousBatcher(eng, decode_block=3)
+    try:
+        assert len(list(b.generate_step([3, 4, 5, 6], max_tokens=5))) == 5
+        args = (eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+                b.last_tok, b.cache, b.active, b.recent, b.keys, b.sp, b.rep_sizes,
+                b.table)
+        prog = b._decode_block_prog(False)
+        block = prog.lower(*args).as_text(debug_info=True)
+        jaxpr = jax.make_jaxpr(prog)(*args)
+        prefill = eng.prefill_slot().lower(
+            eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+            jnp.zeros((1, 8), jnp.int32), jnp.asarray(0, jnp.int32), b.cache,
+            jnp.asarray(8, jnp.int32), b.table,
+        ).as_text(debug_info=True)
+        pool = b.cache.state["kda"].shape[1:]  # (layers, slots + 1, H, D, D)
+        pages = b.cache.k.shape[1:]  # (layers, pages + 1, 1, page, 1, rank + rope)
+    finally:
+        b.close()
+    assert "module @jit_block " in block
+    assert "module @jit_prefill_chunk " in prefill
+    layers = {"mst.embed", "mst.attn.qkv", "mst.attn.kv_write", "mst.attn.core",
+              "mst.mlp.dense", "mst.moe.router", "mst.moe.experts",
+              "mst.moe.experts.scan", "mst.moe.shared", "mst.norm", "mst.head",
+              "mst.kda.proj", "mst.kda.conv", "mst.kda.gate", "mst.kda.out",
+              "mst.state_pool.regroup"}
+    # decode: the one-step recurrence, the page pool carried (no regroup);
+    # the sampler is in the block
+    assert _scopes_in(block) == layers | {"mst.kda.step", "mst.sample"}
+    # prefill: the chunked (WY) form on the slot's contiguous rows
+    assert _scopes_in(prefill) == layers | {"mst.kda.scan", "mst.kv_pool.regroup"}
+    assert _scopes_in(block) | _scopes_in(prefill) <= set(tracing.MODEL_SCOPES)
+
+    walked = list(_walk(jaxpr.jaxpr))
+    updates = [
+        scans for eqn, scans in walked
+        if eqn.primitive.name == "dynamic_update_slice"
+        and eqn.outvars[0].aval.shape == pool
+    ]
+    # block [> periods [> run]]: the head's, the tail's, the run of one, the run of two
+    assert sorted(len(scans) for scans in updates) == [1, 1, 2, 3]
+    writes = [
+        scans for eqn, scans in walked
+        if eqn.primitive.name == "scatter" and eqn.outvars[0].aval.size == math.prod(pages)
+        and eqn.outvars[0].aval.shape[-1] == pages[-1]
+    ]
+    assert sorted(len(scans) for scans in writes) == [1, 2]  # the tail's, the period's
+    def carried(scan):
+        n_c, n_k = scan.params["num_consts"], scan.params["num_carry"]
+        return [v.aval.shape for v in scan.invars[n_c : n_c + n_k]]
+
+    # (the block's own scan carries them with the stage axis in front; a run
+    # of KDA layers leaves the page pool alone, so its scan closes over it)
+    for scans in updates:
+        assert all(pool in [s[-5:] for s in carried(scan)] for scan in scans)
+    for scans in writes:
+        assert all(
+            math.prod(pages) in [math.prod(s) for s in carried(scan) if s[-1:] == pages[-1:]]
+            for scan in scans)
+    for eqn, _ in walked:
+        if eqn.primitive.name == "scan":
+            ys = [v.aval for v in eqn.outvars[eqn.params["num_carry"]:]]
+            assert not any(a.shape[-4:] == pool[-4:] for a in _scanned(eqn) + ys)
+            assert not any(a.shape[-3:] == pages[-3:] for a in _scanned(eqn) + ys)
+    moved = [
+        eqn.primitive.name for eqn, _ in walked for v in eqn.outvars
+        if eqn.primitive.name in ("select_n", "concatenate", "pad", "slice", "gather", "copy")
+        and getattr(v.aval, "shape", ())[-3:] == pool[-3:] and v.aval.size >= 2 * math.prod(pool[-3:])
+        # x[0] of the stage axis is a slice that takes everything: no copy
+        and not (eqn.primitive.name == "slice" and v.aval.size == eqn.invars[0].aval.size)
+    ]
+    # the frozen-slot select of each KDA body, over the SLOTS' rows of one layer
+    assert moved == ["select_n"] * 4, moved
+    moved = [
+        eqn.primitive.name for eqn, _ in walked for v in eqn.outvars
+        if eqn.primitive.name in ("select_n", "concatenate", "pad", "slice", "copy")
+        and getattr(v.aval, "size", 0) >= math.prod(pages[1:])
+        and getattr(v.aval, "shape", ())[-1:] == pages[-1:]
+        and not (eqn.primitive.name == "slice" and v.aval.size == eqn.invars[0].aval.size)
+    ]
+    assert moved == [], moved
 
 
 # ------------------------------------------- what rides the layer scan

@@ -150,6 +150,21 @@ def _ssm_step(layers, slots, heads, groups, head_dim=64, state=128):
     )
 
 
+def _kda_step(layers, slots, heads, head_dim=128):
+    """``ops.kda.kda_pool_step`` on a ``(layers, slots + 1, H, D, D)`` float32
+    state pool (the scratch row past the slots), the layer's rank an operand:
+    the cell's decode step of one KDA layer."""
+    from mlx_sharding_tpu.ops.kda import kda_pool_step
+
+    vec = ((slots, heads, head_dim), F32)
+    return (
+        kda_pool_step,
+        [((layers, slots + 1, heads, head_dim, head_dim), F32), ((), I32),
+         vec, vec, vec, vec, ((slots, heads), F32), ((slots,), jnp.bool_)],
+        "kda_pool_step",
+    )
+
+
 LLAMA_3B = [(8192, 3072), (3072, 8192), (128256, 3072)]
 CASES = {
     # flash prefill chunk and T=1 at Llama-3B heads; the MLA shapes
@@ -204,6 +219,15 @@ CASES = {
     # groups: two blocks of 64 heads, four groups each)
     "ssm-step-granite": _ssm_step(36, 48, 64, 1),
     "ssm-step-nemotron3": _ssm_step(5, 32, 128, 8),
+    # the one-pass gated delta-rule decode step at the
+    # kimi-linear-48b-bf16-ep16 cell's shapes (20 layers, 40 slots, 32 heads
+    # of 128 x 128: a slot's 2 MB one block), and that cell's latent
+    # attention: 40 slots, 32 query heads on the 576-lane latent head, a
+    # table 12 pages wide, the seven MLA layers' pools viewed as one
+    "kda-step-kimi-linear": _kda_step(20, 40, 32),
+    "paged-mla-latent-32-heads-page512": _paged(
+        512, False, slots=40, hq=32, hkv=1, d=576, max_seq=6144, pages=7 * 481,
+        rank=512),
     # 4-bit projections of the 3B model: a prefill chunk's 256 rows, a
     # single stream's one row and 8 slots' rows, all on the one kernel
     **{f"quant-M{m}-{i}x{o}": _quant(m, o, i, "quant_matmul")
@@ -357,6 +381,32 @@ def test_latent_attention_gathers_no_table_and_copies_no_pool(chip, monkeypatch)
     made = _arrays_made(_loop_bodies(text), slots * spg * page * dk * 2)
     assert set(made) <= {("dynamic-update-slice", whole), ("fusion", whole)}, made
     assert not re.search(r"\[\d+,4096,", _loop_bodies(text))  # no max_seq-dense view
+
+
+def test_kda_step_in_a_layer_scan_moves_nothing_but_its_blocks(chip):
+    """The same for Kimi-Linear's state pool (20 x 41 rows of 32 x 128 x 128
+    float32, 1.7 GB) under ``kda_pool_step`` at the scanned rank: no array
+    as large as one layer's rows (84 MB) and no copy of the pool."""
+    from mlx_sharding_tpu.ops.kda import kda_pool_step
+
+    _, shapes, kernel = _kda_step(20, 40, 32)
+
+    def walk(pool, _rank, q, k, v, g, beta, active):
+        def layer(carry, rank):
+            pool, acc = carry
+            o, pool = kda_pool_step(pool, rank, q + acc, k, v, g, beta, active)
+            return (pool, o), None
+
+        return jax.lax.scan(layer, (pool, jnp.zeros_like(q)), jnp.arange(pool.shape[0]))[0]
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    compiled = jax.jit(walk, donate_argnums=0).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and kernel in text
+    assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(4, \{\}\)\}", text)
+    rows = 40 * 32 * 128 * 128 * 4
+    assert _arrays_made(text, rows) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < rows
 
 
 def test_ssm_step_in_a_layer_scan_moves_nothing_but_its_blocks(chip):
