@@ -915,6 +915,16 @@ class ContinuousBatcher:
         self._join_programs = dict.fromkeys(
             ("claim", "chunk", "finish", "other"), 0
         )
+        # a drain's hand-over to the streams, held back until the device has
+        # the joiner's chunk (_tick_async): the (queue, item) pairs in the
+        # order they would have been put, None while no hold is open; when
+        # the first was deferred; items held by the point that let them go,
+        # and the holds' seconds and count (first deferred item to flush)
+        self._held: Optional[list] = None
+        self._held_since = 0.0
+        self._emit_held = dict.fromkeys(("chunk", "tick_end", "fail"), 0)
+        self._emit_hold_seconds = 0.0
+        self._emit_holds = 0
         # always-on latency histograms (/metrics): inter-token latency at
         # the emit path, admission queue wait at slot assignment. These are
         # the metric itself (a lock + bisect per observation, same grade as
@@ -1494,6 +1504,9 @@ class ContinuousBatcher:
             "drains": dict(self._drains),
             "blocks_by_sampler": dict(self._blocks_by_sampler),
             "join_programs": dict(self._join_programs),
+            "emit_held": dict(self._emit_held),
+            "emit_hold_seconds": self._emit_hold_seconds,
+            "emit_holds": self._emit_holds,
         }
 
     def state_stats(self) -> Optional[dict]:
@@ -2357,6 +2370,9 @@ class ContinuousBatcher:
             tr.add("prefill", t0, time.perf_counter(), slot=req.slot,
                    pos=req.prefill_pos, chunk=c)
         if not self._prefill_done(req):
+            # the device has the chunk: the drain's tokens leave now, and
+            # the streams write them out while it computes
+            self._flush_held("chunk")
             return
         logits = req._last_logits
         req._last_logits = None
@@ -2411,6 +2427,9 @@ class ContinuousBatcher:
             self.last_tok, self.active,
         )
         self._join_programs["finish"] += 1
+        # chunk and first token are dispatched, nothing is read yet: the
+        # drain's tokens leave here, ahead of the joiner's first
+        self._flush_held("chunk")
         # the blocking read of the chunk and its sample; the pipeline was
         # drained before this chunk, so nothing is left dispatched and unread
         tok = int(tok)
@@ -2423,6 +2442,34 @@ class ContinuousBatcher:
             # exports its block (off this hot path) before dispatching
             # decode, so the slot never enters a decode block here
             self._handoff_ready.append(req)
+
+    def _hand(self, req: _Request, item):
+        """The scheduler thread's one way onto a request's ``out`` queue: a
+        token, the ``None`` that ends a stream, an error. While a hold is
+        open (a tick that drained for a joiner, until the device has the
+        joiner's chunk: ``_tick_async``) the item waits its turn in the
+        hold, behind everything handed before it — one list for all
+        streams, so each stream's order is the order of the calls."""
+        held = self._held
+        if held is None:
+            req.out.put(item)
+            return
+        if not held:
+            self._held_since = time.perf_counter()
+        held.append((req.out, item))
+
+    def _flush_held(self, point: str):
+        """Close the hold and put what it kept, in order; ``point`` names
+        the call site for ``mst_emit_held_total``. With no hold open, or an
+        empty one, nothing happens and nothing is counted."""
+        held, self._held = self._held, None
+        if not held:
+            return
+        for out, item in held:
+            out.put(item)
+        self._emit_held[point] += len(held)
+        self._emit_hold_seconds += time.perf_counter() - self._held_since
+        self._emit_holds += 1
 
     def _emit(self, req: _Request, token: int, logprobs):
         now = time.perf_counter()
@@ -2449,7 +2496,7 @@ class ContinuousBatcher:
         # decode blocks emit TokenLogprobs summaries (or None); the first
         # token of a request still carries a lazy (1, V) device row from its
         # prefill sample — the server handles both forms
-        req.out.put((token, logprobs))
+        self._hand(req, (token, logprobs))
         if req.produced >= req.max_tokens:
             self._finish(req)
 
@@ -2516,7 +2563,7 @@ class ContinuousBatcher:
                 # a self-begun trace retires here; a server-owned one is
                 # finished by the server after its last SSE write
                 tracing.finish(tr)
-        req.out.put(None)
+        self._hand(req, None)
 
     def _reap_cancelled(self):
         for req in list(self._slots):
@@ -2752,7 +2799,7 @@ class ContinuousBatcher:
         for req in self._parked:
             if req.cancelled:
                 self._drop_spill(req)
-                req.out.put(None)
+                self._hand(req, None)
                 continue
             if req.out.qsize() == 0:
                 woken.append(req)
@@ -2882,7 +2929,7 @@ class ContinuousBatcher:
                 self._release_pages(slot)
                 self._drop_prefix_lease(req)
                 self._drop_spill(req)
-                req.out.put(None)
+                self._hand(req, None)
                 continue
             tr = req._trace
             t0 = time.perf_counter() if tr is not None else 0.0
@@ -2893,7 +2940,7 @@ class ContinuousBatcher:
                        block=state.block is not None)
             self._release_pages(slot)
             self._drop_prefix_lease(req)
-            req.out.put(RequestMigratedError(state))
+            self._hand(req, RequestMigratedError(state))
             with self._admission_lock:
                 self.migrations_out += 1
         if admitted:
@@ -2905,7 +2952,7 @@ class ContinuousBatcher:
         for req in self._waiting + self._parked:
             if req.cancelled:
                 self._drop_spill(req)
-                req.out.put(None)
+                self._hand(req, None)
                 continue
             tr = req._trace
             t0 = time.perf_counter() if tr is not None else 0.0
@@ -2913,7 +2960,7 @@ class ContinuousBatcher:
                 state = self._export_resume_state(req, -1, None, None)
             if tr is not None:
                 tr.add("migration", t0, time.perf_counter(), queued=True)
-            req.out.put(RequestMigratedError(state))
+            self._hand(req, RequestMigratedError(state))
             with self._admission_lock:
                 self.migrations_out += 1
         self._waiting.clear()
@@ -3037,7 +3084,7 @@ class ContinuousBatcher:
             self._slots[slot] = None
             note_release("scheduler.slot", (id(self), slot))
             req.slot = -1
-            req.out.put(HandoffReadyError(state))
+            self._hand(req, HandoffReadyError(state))
             with self._admission_lock:
                 self.handoffs_out += 1
                 self._finish_times.append(self._clock())
@@ -3094,7 +3141,7 @@ class ContinuousBatcher:
                     # accounting drift — but silently continuing would
                     # wedge the request against its scratch-page tail and
                     # emit garbage forever. Fail it loudly instead.
-                    req.out.put(RuntimeError(
+                    self._hand(req, RuntimeError(
                         f"KV page pool exhausted: slot {slot} needs "
                         f"{n_more} more page(s) for its next decode block "
                         f"but only {len(self._free_pages)} are free and no "
@@ -3602,7 +3649,7 @@ class ContinuousBatcher:
                 with self._admission_lock:  # read by resilience_stats()
                     self.shed_deadline += 1
                 req.cancelled = True
-                req.out.put(RequestTimeoutError(
+                self._hand(req, RequestTimeoutError(
                     "queue", now - req.deadlines.submitted_at,
                     req.deadlines.ttft_deadline - req.deadlines.submitted_at,
                 ))
@@ -3611,7 +3658,7 @@ class ContinuousBatcher:
         for req in [r for r in self._waiting if r.cancelled]:
             self._waiting.remove(req)
             self._drop_spill(req)  # its tier block frees with the stream
-            req.out.put(None)
+            self._hand(req, None)
         while None in self._slots and self._waiting:
             pick = None
             for i, req in enumerate(self._waiting):
@@ -3709,7 +3756,22 @@ class ContinuousBatcher:
         and the host-side emit/stop/admission work below runs concurrently
         with it. Admission prefill, growth that could preempt, and the
         idle path quiesce the pipeline first (one-block drain), then the
-        double-buffering resumes on the next tick."""
+        double-buffering resumes on the next tick.
+
+        Where a block's tokens leave for their streams: in a tick with no
+        joiner, in the harvest, which runs under block t+1 — the streams'
+        threads wake, encode and write while the device works. In a tick
+        that drains for a joiner the harvest runs with the device EMPTY,
+        and every ``out.put`` wakes a thread that then competes with this
+        one for the interpreter lock just as it makes the claim and the
+        chunk. So such a tick holds the drain's hand-overs (``_hand``:
+        tokens, the ``None`` of a stream that ended, errors; everything
+        else ``_emit`` and ``_finish`` do happens where it did) and lets
+        them go, in order, once the device has the joiner's chunk — or at
+        the first of: ``_prefill_round`` returning without one, a failure
+        (``_fail_all``), the loop's end, the idle wait. No hold spans a
+        wait on the device or on the submit queue
+        (``mst_emit_held_total{flush}``, ``mst_emit_hold_seconds``)."""
         inject("scheduler.tick", engine=id(self))  # fault harness: wedge/delay/fail a tick (match engine= to target one batcher)
         phase = self._phases.span  # every part of the tick runs in a phase
         if self._migrate_requested:
@@ -3740,10 +3802,15 @@ class ContinuousBatcher:
             ):
                 # prefill (admission or mid-admission chunks) samples the
                 # first token host-side and rewrites slot state: drain the
-                # lookahead block before touching the engine
+                # lookahead block before touching the engine. What the
+                # drain hands its streams is held from here
+                self._held = []
                 self._quiesce("admit" if admitting else "prefilling")
             self._admit_waiting()
         self._prefill_round()
+        # a tick that drained and dispatched no chunk (the fifo head does
+        # not fit, the joiner was cancelled or shed, a block import)
+        self._flush_held("tick_end")
         if self._handoff_ready:
             # prefill-only completions: export + end those streams BEFORE
             # dispatch (pipeline still quiesced from the prefill above)
@@ -3780,6 +3847,7 @@ class ContinuousBatcher:
                 self._idle()
 
     def _idle_wait(self):
+        self._flush_held("tick_end")  # no hold spans a wait on the queue
         # whatever is still dispatched (a cancelled joiner's chunk, a block
         # a failed harvest abandoned) nobody will read: the device is as
         # good as empty for as long as this thread blocks on the queue
@@ -3864,6 +3932,9 @@ class ContinuousBatcher:
             self._idle()
 
     def _fail_all(self, exc: BaseException):
+        # tokens already counted as emitted reach their streams before the
+        # exception does, never the exception in their place
+        self._flush_held("fail")
         # a scheduler-thread failure is an incident: snapshot the flight
         # recorder before the streams die so their timelines survive
         tracing.auto_snapshot("scheduler_fail")
@@ -3878,7 +3949,7 @@ class ContinuousBatcher:
                 self._slots[slot] = None
                 note_release("scheduler.slot", (id(self), slot))
                 failed.append(req)
-                req.out.put(exc)
+                self._hand(req, exc)
         self.active = self._zeros_like(self.active)
         if self.paged:
             # cache contents are unreliable after a failure: reset the pool
@@ -3906,10 +3977,10 @@ class ContinuousBatcher:
             # host DRAM back to the budget
             self.spill.clear()
         for req in self._waiting:
-            req.out.put(exc)
+            self._hand(req, exc)
         self._waiting.clear()
         for req in self._parked:  # cold-spilled sessions die with the rest
-            req.out.put(exc)
+            self._hand(req, exc)
         self._parked.clear()
         while True:
             try:
@@ -3917,7 +3988,7 @@ class ContinuousBatcher:
             except queue.Empty:
                 break
             if req is not None:
-                req.out.put(exc)
+                self._hand(req, exc)
 
     def _loop(self):
         tick = self._tick_async if self._async else self._tick
@@ -3930,6 +4001,7 @@ class ContinuousBatcher:
                 # would hang every consumer; surface the error to them instead
                 self._fail_all(exc)
         self._phases.stop()
+        self._flush_held("tick_end")  # held tokens first, then the sentinels
         # graceful shutdown: end every in-flight and queued request's stream.
         # Host-side only — no device ops here: the engine is being dropped,
         # and in multi-host serving a device op after the final broadcast
@@ -3950,12 +4022,12 @@ class ContinuousBatcher:
                 lease, req._please = req._please, None
                 if lease is not None:
                     lease.release()
-                req.out.put(None)
+                self._hand(req, None)
         for req in self._waiting:
-            req.out.put(None)
+            self._hand(req, None)
         self._waiting.clear()
         for req in self._parked:  # parked streams end, like waiting ones
-            req.out.put(None)
+            self._hand(req, None)
         self._parked.clear()
         while True:
             try:
@@ -3963,4 +4035,4 @@ class ContinuousBatcher:
             except queue.Empty:
                 break
             if req is not None:
-                req.out.put(None)
+                self._hand(req, None)

@@ -212,6 +212,13 @@ def _render_tick_phases(lines: list, t: dict):
     labelled("mst_decode_blocks_total", "sampler", t["blocks_by_sampler"])
     lines.append("# TYPE mst_join_programs_total counter")
     labelled("mst_join_programs_total", "program", t["join_programs"])
+    lines.append("# TYPE mst_emit_held_total counter")
+    labelled("mst_emit_held_total", "flush", t["emit_held"])
+    lines += [
+        "# TYPE mst_emit_hold_seconds summary",
+        f"mst_emit_hold_seconds_sum {t['emit_hold_seconds']:.6f}",
+        f"mst_emit_hold_seconds_count {t['emit_holds']}",
+    ]
 
 
 def _render_spec_family(lines: list, spec: dict):
@@ -1137,6 +1144,14 @@ _HELP = {
         "by program: claim (the slot claim), chunk (a prefill chunk, the "
         "draft's too), finish (the first token), other (a block import's "
         "resume). Over mst_join_seconds_count: 3 for a one-chunk join.",
+    "mst_emit_held_total":
+        "Queue items (tokens, stream ends, errors) of a tick that drained "
+        "the pipeline for a joiner, handed to their streams late, by what "
+        "let them go: chunk (the joiner's prefill chunk was dispatched), "
+        "tick_end (the tick dispatched none), fail (a scheduler failure).",
+    "mst_emit_hold_seconds":
+        "First deferred item to the flush, seconds; one observation a hold: "
+        "what the deferral adds to a token's latency.",
     "mst_state_slots_in_use":
         "Slots whose recurrent state (Mamba-2 SSM state and convolution "
         "tail) belongs to an admitted request.",
@@ -1220,7 +1235,7 @@ def _finalize(lines: list) -> list:
         if ln.startswith("# TYPE "):
             parts = ln.split()
             typed.add(parts[2])
-            if parts[3] == "histogram":
+            if parts[3] in ("histogram", "summary"):  # _sum / _count
                 histograms.add(parts[2])
     out: list = []
     helped: set = set()

@@ -173,3 +173,27 @@ def test_join_programs_mean_reads_the_joins_dispatches_of_the_window():
     old = {"mst_join_seconds_count": 240.0}
     assert read({"before": {"mst_join_seconds_count": 40.0}, "after": old}) is None
     assert read({"before": None, "after": None}) is None
+
+
+def test_emit_hold_ms_mean_reads_the_holds_of_the_window():
+    """``mst_emit_hold_seconds_sum`` over its ``_count`` between the two
+    scrapes, in milliseconds; a program from before the counter (the parent
+    of the PR that added it) exposes nothing and the metric is left out, as
+    in a window that held nothing."""
+    from benchmarks.run import load_reader
+
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == "emit_hold_ms.mean")
+    # no ``workloads`` list: every cell reports it
+    assert entry == {"name": "emit_hold_ms.mean", "unit": "ms", "better": "lower",
+                     "source": "program_counter", "layer": "scheduler",
+                     "moves": "out_tok_s"}
+    read = load_reader("layer_metrics", "emit_hold_ms.mean")
+    before = {"mst_emit_hold_seconds_sum": 0.05, "mst_emit_hold_seconds_count": 40.0,
+              'mst_emit_held_total{flush="chunk"}': 9000.0}
+    after = {"mst_emit_hold_seconds_sum": 0.45, "mst_emit_hold_seconds_count": 240.0,
+             'mst_emit_held_total{flush="chunk"}': 58000.0}
+    assert read({"before": before, "after": after}) == pytest.approx(2.0)
+    assert read({"before": before, "after": before}) is None  # no hold in the window
+    old = {"mst_join_seconds_count": 240.0}
+    assert read({"before": old, "after": old}) is None
+    assert read({"before": None, "after": None}) is None

@@ -117,6 +117,8 @@ def _fake_tick_phase_stats(path="async"):
         "drains": {"admit": 2, "idle": 1},
         "blocks_by_sampler": {"greedy": 6, "draw": 0, "nucleus": 2},
         "join_programs": {"claim": 4, "chunk": 9, "finish": 4, "other": 0},
+        "emit_held": {"chunk": 31, "tick_end": 2, "fail": 0},
+        "emit_hold_seconds": 0.0075, "emit_holds": 5,
     }
 
 
@@ -151,6 +153,10 @@ def test_metrics_expose_tick_timing():
     assert 'mst_decode_blocks_total{sampler="nucleus"} 2' in text
     assert 'mst_join_programs_total{program="chunk"} 9' in text
     assert 'mst_join_programs_total{program="other"} 0' in text
+    assert 'mst_emit_held_total{flush="chunk"} 31' in text
+    assert 'mst_emit_held_total{flush="fail"} 0' in text
+    assert "mst_emit_hold_seconds_sum 0.007500" in text
+    assert "mst_emit_hold_seconds_count 5" in text
     # the one-tick gauges are gone: nothing could read them soundly
     assert "mst_tick_host_ms" not in text
     assert "mst_tick_device_blocked_ms" not in text
@@ -311,7 +317,7 @@ def test_metrics_help_type():
             assert fam in helped, f"# TYPE {fam} without a preceding # HELP"
             assert fam not in typed, f"duplicate # TYPE for {fam}"
             typed[fam] = ln.split()[3]
-            if typed[fam] == "histogram":
+            if typed[fam] in ("histogram", "summary"):
                 hist.add(fam)
             continue
         if not ln or ln.startswith("#"):

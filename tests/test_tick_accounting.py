@@ -156,7 +156,19 @@ def test_blocks_and_tokens_are_all_accounted_for(engine, mode):
             "path", "ticks", "phase_seconds", "device_empty_seconds",
             "phase_entries", "blocks_dispatched", "blocks_harvested",
             "blocks_abandoned", "positions_computed", "tokens_emitted",
-            "tokens_dropped", "drains", "blocks_by_sampler", "join_programs"}
+            "tokens_dropped", "drains", "blocks_by_sampler", "join_programs",
+            "emit_held", "emit_hold_seconds", "emit_holds"}
+        # a join that drained a decoding batch held the drain's tokens until
+        # its chunk was dispatched; the sync tick never holds
+        held = s["emit_held"]
+        assert set(held) == {"chunk", "tick_end", "fail"} and held["fail"] == 0
+        if mode == "on":
+            assert held["chunk"] >= 1 and s["emit_holds"] >= 1
+            assert 0 < s["emit_hold_seconds"] < wall
+        else:
+            assert sum(held.values()) == 0 == s["emit_holds"]
+            assert s["emit_hold_seconds"] == 0
+        assert batcher._held is None
         # every join that reached decode: one claim, one first token, and
         # a dispatch for each of its chunks
         joins = s["join_programs"]
