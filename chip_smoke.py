@@ -745,15 +745,21 @@ def child_kernels(seed: int, rehearse: bool) -> None:
                   functools.partial(quant._quant_matmul_xla, group_size=64,
                                     bits=4),
                   (x, qw, sc, bi), QUANT_RTOL, relative=True)
-    # ---- routed experts at decode: 16 rows x top-6 on 64 packed experts of
-    # DeepSeek-V2-Lite's widths through ops.moe.apply_experts, against the
-    # same experts dequantized by XLA one at a time (_quant_matmul_xla)
+    # ---- routed experts over 64 packed experts of DeepSeek-V2-Lite's widths
+    # through ops.moe.apply_experts, against the same experts dequantized by
+    # XLA one at a time (_quant_matmul_xla): a decode step's 16 rows x top-6
+    # (all rows against each distinct expert) and a prefill chunk's 256 (the
+    # (row, pick) pairs sorted by expert, each expert's own rows in tiles:
+    # the grouped path), both on the expert-indexed kernel
     from mlx_sharding_tpu.ops import moe
 
     if rehearse:
-        n, k, e, hidden, width = 8, 2, 4, 128, 64
+        k, e, hidden, width = 2, 4, 128, 64
+        cases = [(8, "kernel", moe._apply_packed_kernel),
+                 (40, "grouped", functools.partial(moe._apply_grouped_kernel, tile=8))]
     else:
-        n, k, e, hidden, width = 16, 6, 64, 2048, 1408
+        k, e, hidden, width = 6, 64, 2048, 1408
+        cases = [(16, "kernel", None), (256, "grouped", None)]
     stacks = []
     for out_dim, in_dim in ((width, hidden), (width, hidden), (hidden, width)):
         kw, key = jax.random.split(key)
@@ -761,10 +767,6 @@ def child_kernels(seed: int, rehearse: bool) -> None:
         stacks.append(dict(zip(("q", "scales", "biases"),
                                jax.jit(quant.quantize_jax)(w))))
         del w
-    kx, kr, key = jax.random.split(key, 3)
-    x = jax.random.normal(kx, (n, hidden), bf16)
-    topv, idx = jax.lax.top_k(jax.random.uniform(kr, (n, e)), k)
-    weights = topv / topv.sum(-1, keepdims=True)
 
     def experts_ref(x, weights, idx, w_gate, w_up, w_down):
         mm = functools.partial(quant._quant_matmul_xla, group_size=64, bits=4)
@@ -781,14 +783,24 @@ def child_kernels(seed: int, rehearse: bool) -> None:
                               (w_gate, w_up, w_down, jnp.arange(e)))
         return acc
 
-    if rehearse:
-        fn = functools.partial(moe._apply_packed_kernel, gs=64, bits=4,
-                               interpret=True)
-    else:
-        fn = moe.apply_experts
-    check(f"quant_matmul_experts N={n} top-{k} of {e} {hidden}x{width}",
-          "quant_matmul_experts", fn, experts_ref,
-          (x, weights, idx, *stacks), QUANT_RTOL, relative=True)
+    for n, path, interpreted in cases:
+        kx, kr, key = jax.random.split(key, 3)
+        x = jax.random.normal(kx, (n, hidden), bf16)
+        topv, idx = jax.lax.top_k(jax.random.uniform(kr, (n, e)), k)
+        weights = topv / topv.sum(-1, keepdims=True)
+        if rehearse:
+            fn = functools.partial(interpreted, gs=64, bits=4, interpret=True)
+        else:
+            fn = moe.apply_experts
+        taken = moe.dispatch_counts()[path]
+        check(f"quant_matmul_experts ({path}) N={n} top-{k} of {e} {hidden}x{width}",
+              "quant_matmul_experts", fn, experts_ref,
+              (x, weights, idx, *stacks), QUANT_RTOL, relative=True)
+        if not rehearse and moe.dispatch_counts()[path] != taken + 1:
+            raise SystemExit(
+                f"apply_experts did not take the {path} path for {n} rows "
+                f"over packed stacks on the chip: {moe.dispatch_counts()}"
+            )
     # ---- routed experts under a resident range, 32 rows at the two bf16
     # cells' expert widths: bf16 (L, E, ...) stacks read in place by an
     # expert id LOADED from the step's list of distinct picks (plain XLA: the

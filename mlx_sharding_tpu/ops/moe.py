@@ -19,12 +19,23 @@ observe — token count, packed or dense stacks, backend, shapes:
   gathered slices (N x K whole experts, dense, in HBM).
 - **decode, dense stacks**: gather the top-k experts' weights per token and
   batch the tiny matmuls.
-- **prefill (many tokens), a resident range and expert-parallel**: a loop
+- **prefill (more rows than that), packed stacks, on a TPU**: the same
+  kernel fed each expert's OWN rows (``_apply_grouped_kernel``). The chunk's
+  (row, pick) pairs are sorted by expert, each expert's run padded to whole
+  tiles of ``GROUP_TILE`` rows, and every tile is one entry of the kernel's
+  id table with a row block of its own: a row meets the K experts it
+  picked and no other. No capacity, no dropped pair. The decode step hands
+  the kernel all rows against each distinct expert; the chunk hands it
+  each expert's rows.
+- **prefill over dense stacks or packed ones the kernel does not serve, a
+  resident range and expert-parallel**: a loop
   over the DISTINCT held experts the rows picked, ascending, with masked
   accumulation — every matmul is a full-width MXU op with static shapes,
   read out of the stacks where they lie; no sorting, no capacity overflow.
   A held expert no row picked is not read: in a chunk every expert is hit,
-  in a 32-row decode step under a resident range a part of them.
+  in a 32-row decode step under a resident range a part of them. Every
+  visit multiplies ALL rows, also those whose routing mass for the expert
+  is 0 (in a 256-row chunk of top-6 over 64, nine products in ten).
 
 Routing is parameterized so Mixtral (softmax→topk→renorm), DeepSeek-V2
 (softmax scoring→greedy topk, optional renorm + scaling factor) and
@@ -64,13 +75,24 @@ from mlx_sharding_tpu.ops.dispatch import DispatchCounter
 logger = logging.getLogger(__name__)
 
 GATHER_PATH_MAX_TOKENS = 16
+# Rows a tile of the grouped path: one grid step of the expert kernel
+# multiplies this many rows of ONE expert. Chosen on the chip at 256 rows x
+# top-6 over 64 experts of 2048 -> 1408 -> 2048, 13 layers (with the tiles
+# gathered as rows, 3 ms more than now at each): 17.9 ms at 32, 26.2 at 16
+# (twice the grid steps, each with the same unpack), 30.1 at 64 (most
+# groups hold 24 rows: the rest of a tile is idle work); the scan takes
+# 119.9. The served chunk of that model: 30.2, 35.8 and 38.6 ms (PERF.md
+# section 6, PR 50).
+GROUP_TILE = 32
 
 # Which path apply_experts chose, once per traced call (ops/dispatch.py).
 # /metrics shows it as ``mst_moe_dispatch_total{path}``: "gather" or
 # "gather_packed" above 0 on a chip is a decode step that copies N x K whole
 # experts out of the stacks (dense ones; packed ones outside the kernel's
-# contract) before it multiplies.
-_DISPATCHED = DispatchCounter("kernel", "scan", "gather_packed", "gather")
+# contract) before it multiplies; "grouped" is a chunk over packed stacks.
+_DISPATCHED = DispatchCounter(
+    "kernel", "grouped", "scan", "gather_packed", "gather"
+)
 dispatch_counts = _DISPATCHED.counts
 _count_dispatch = _DISPATCHED.count
 
@@ -204,7 +226,24 @@ def apply_experts(
             layer=layer,
         )
         return acc if ep_axis is None else jax.lax.psum(acc, ep_axis)
+    packed = is_quantized(w_up)
     if n > GATHER_PATH_MAX_TOKENS:
+        # a chunk: over packed stacks the kernel serves, each expert
+        # multiplies its own rows, GROUP_TILE of them a grid step; dense
+        # stacks, and packed ones off the chip or outside the kernel's
+        # contract, walk the distinct experts with all rows against each
+        if packed and packed_kernel_ok(
+            GROUP_TILE, w_gate, w_up, w_down, group_size, bits
+        ):
+            _log_path_once(
+                _apply_grouped_kernel.__name__, n, idx.shape[1],
+                tuple(w_up["q"].shape), layer is not None,
+            )
+            _count_dispatch("grouped")
+            return _apply_grouped_kernel(
+                x, weights, idx, w_gate, w_up, w_down, group_size, bits,
+                layer=layer,
+            )
         _count_dispatch("scan")
         return _apply_scan(
             x, weights, idx, w_gate, w_up, w_down, group_size, bits, layer=layer
@@ -213,7 +252,6 @@ def apply_experts(
     # when they are packed: the kernel reads each chosen expert's packed
     # bytes once; off the chip, or at shapes it does not serve, gather
     # the packed leaves and dequantize the gathered slice
-    packed = is_quantized(w_up)
     kernel = packed and packed_kernel_ok(n, w_gate, w_up, w_down, group_size, bits)
     if packed:
         _log_path_once(
@@ -303,6 +341,13 @@ def distinct_experts(idx, num_experts: int):
     return ids, live[None]
 
 
+def _planes(a, per_word: int):
+    """``(..., N, IN) -> (..., per_word, N, IN / per_word)``: the rows as the
+    expert kernel reads them, ``planes[j][n, w] = a[n, per_word * w + j]``."""
+    a = a.reshape(*a.shape[:-1], a.shape[-1] // per_word, per_word)
+    return jnp.moveaxis(a, -1, -3)
+
+
 def _apply_packed_kernel(
     x, weights, idx, w_gate, w_up, w_down, gs, bits, interpret=False,
     layer=None,
@@ -326,10 +371,6 @@ def _apply_packed_kernel(
         w_gate, w_up, w_down = _flat_layers(w_gate, w_up, w_down)
         ids = layer * num_experts + ids
 
-    def planes(a):  # (..., N, IN) -> (..., per_word, N, IN / per_word)
-        a = a.reshape(*a.shape[:-1], a.shape[-1] // per_word, per_word)
-        return jnp.moveaxis(a, -1, -3)
-
     def experts(x_planes, w, coef=None):
         return quant_matmul_experts(
             x_planes, ids, live, w["q"], w["scales"], w["biases"], coef,
@@ -337,11 +378,98 @@ def _apply_packed_kernel(
         )
 
     with jax.named_scope("mst.moe.experts.matmul"):
-        xp = planes(x)[None]
+        xp = _planes(x, per_word)[None]
         g = None if w_gate is None else experts(xp, w_gate)
         h = _activate(g, experts(xp, w_up))  # (T, N, I) f32
-        y = experts(planes(h.astype(x.dtype)), w_down, coef)
+        y = experts(_planes(h.astype(x.dtype), per_word), w_down, coef)
     return y.astype(x.dtype)
+
+
+def group_rows(idx, num_experts: int, tile: int):
+    """The ``N * K`` (row, pick) pairs of ``idx (N, K)`` sorted by expert
+    (stable), each expert's run padded up to whole tiles of ``tile`` rows.
+    ``T`` tile slots, a static bound: an expert some row picked wastes less
+    than one tile, so ``T = (N*K + min(E, N*K) * (tile - 1)) // tile`` holds
+    every routing. Returns
+
+    - ``ids (T,)``: the slot's expert, ascending; slots past ``live`` repeat
+      the last real one, as ``distinct_experts`` does;
+    - ``live (1,)``: the slots that hold a pair;
+    - ``rows (T, tile)``: the row of ``x`` each tile row holds; a padding
+      row points at row 0 and is taken back by no pair;
+    - ``dest (N, K)``: where pair ``(n, k)`` lies among the ``T * tile``
+      tile rows. No capacity, no dropped pair: every pair has one tile row
+      of its own, in a slot of its expert.
+
+    ``idx`` names held experts only (``0 <= idx < num_experts``)."""
+    n, k = idx.shape
+    pairs = n * k
+    slots = (pairs + min(num_experts, pairs) * (tile - 1)) // tile
+    flat = idx.reshape(pairs)
+    order = jnp.argsort(flat, stable=True)  # place among the sorted -> pair
+    counts = (flat[:, None] == jnp.arange(num_experts)).sum(
+        axis=0, dtype=jnp.int32
+    )
+    tiles = -(-counts // tile)
+    tile_end = jnp.cumsum(tiles)
+    first_tile = tile_end - tiles  # an expert's first slot
+    first_pair = jnp.cumsum(counts) - counts  # and its first sorted pair
+    live = tile_end[-1]
+    slot = jnp.arange(slots, dtype=jnp.int32)
+    ids = (slot[:, None] >= tile_end).sum(axis=1, dtype=jnp.int32)
+    ids = jnp.where(slot < live, ids, ids[live - 1])
+    # a tile row's rank within its expert's run; past the run it is padding
+    # (every row of a slot past ``live`` is)
+    rank = (slot - first_tile[ids])[:, None] * tile + jnp.arange(tile)
+    real = rank < counts[ids][:, None]
+    place = jnp.where(real, first_pair[ids][:, None] + rank, 0)
+    rows = jnp.where(real, order[place] // k, 0).astype(jnp.int32)
+    by_expert = flat[order]
+    lies = first_tile[by_expert] * tile + jnp.arange(pairs) - first_pair[by_expert]
+    dest = lies[jnp.argsort(order)].reshape(n, k).astype(jnp.int32)
+    return ids, live[None], rows, dest
+
+
+def _apply_grouped_kernel(
+    x, weights, idx, w_gate, w_up, w_down, gs, bits, interpret=False,
+    layer=None, tile=GROUP_TILE,
+):
+    """A chunk's rows through ``quant_matmul_experts``, each expert against
+    the rows that picked it: ``group_rows`` lays the (row, pick) pairs out
+    in tiles of one expert each, every tile a table entry with its own row
+    block (``lead == T``), through gate, up and down; then each pair takes
+    its row of the result back and a row's K terms are weighed and summed
+    in float32, cast once. The product of a (row, expert) pair is the
+    decode path's — the same unpack, the same float32 accumulation over IN
+    blocks; ``_apply_scan`` adds a row's terms in the rows' dtype one expert
+    at a time. With ``layer`` the stacks are ``(L, E, …)``, read in place
+    as in ``_apply_packed_kernel``."""
+    from mlx_sharding_tpu.ops.quant_matmul import quant_matmul_experts
+
+    per_word = 32 // bits
+    num_experts = w_up["q"].shape[0 if layer is None else 1]
+    ids, live, rows, dest = group_rows(idx, num_experts, tile)
+    if layer is not None:
+        w_gate, w_up, w_down = _flat_layers(w_gate, w_up, w_down)
+        ids = layer * num_experts + ids
+
+    def experts(planes, w):  # (T, per_word, tile, IN / per_word) -> (T, tile, OUT)
+        return quant_matmul_experts(
+            planes, ids, live, w["q"], w["scales"], w["biases"],
+            group_size=gs, bits=bits, interpret=interpret,
+        )
+
+    with jax.named_scope("mst.moe.experts.matmul"):
+        # planes of the N rows first (a megabyte), then the gather: the
+        # tiles are born in the kernel's layout but for one major-dimension
+        # copy; gathered as rows they take two transposing copies (on the
+        # chip 13 layers read 14.9 ms for 17.9)
+        xt = jnp.moveaxis(_planes(x, per_word)[:, rows], 0, 1)
+        g = None if w_gate is None else experts(xt, w_gate)
+        h = _activate(g, experts(xt, w_up))
+        y = experts(_planes(h.astype(x.dtype), per_word), w_down)
+    y = y.reshape(-1, y.shape[-1])[dest]  # (N, K, H) f32, a pair's own row
+    return (y * weights[..., None]).sum(axis=1).astype(x.dtype)
 
 
 def _apply_gather_packed(x, weights, idx, w_gate, w_up, w_down, gs, bits):

@@ -1180,9 +1180,12 @@ _HELP = {
         "chip unless a layer has a softcap or a window).",
     "mst_moe_dispatch_total":
         "Routed-expert calls by the path ops/moe chose, one count per traced "
-        "call: kernel is the expert-indexed 4-bit kernel, scan walks the "
-        "distinct held experts the rows picked (prefill, a resident range, "
-        "expert parallelism); gather_packed and gather copy every pick's "
+        "call: kernel is the expert-indexed 4-bit kernel on a decode step's "
+        "rows, grouped the same kernel on a chunk's rows sorted by expert "
+        "(each expert multiplies the rows that picked it), scan walks the "
+        "distinct held experts the rows picked with all rows against each "
+        "(prefill over dense stacks, a resident range, expert parallelism); "
+        "gather_packed and gather copy every pick's "
         "whole expert out of the stacks first (0 on a chip where the decode "
         "step is packed and inside the kernel's contract).",
     "mst_ssm_dispatch_total":

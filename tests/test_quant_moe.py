@@ -438,11 +438,39 @@ def test_expert_kernel_bf16_rows_round_as_the_gather_path_does():
     assert err(got) <= max(err(want), 2 ** -7)
 
 
+def _only(path: str) -> dict:
+    return {p: p == path for p in ("kernel", "grouped", "gather", "scan")}
+
+
+def _paths_taken(rng, n, e, k, h, stacks, dtype=jnp.float32, **kw) -> dict:
+    """Which of its paths ``apply_experts`` traced for ``n`` rows, read off
+    the jaxpr: the expert kernel behind a sort is the grouped path, without
+    one the decode step's; a loop is the scan; a gather and neither, one of
+    the two gather fallbacks."""
+    from mlx_sharding_tpu.ops import moe
+
+    x = jnp.ones((n, h), dtype)
+    weights, idx = _routing(rng, n, e, k, "random")
+    text = str(jax.make_jaxpr(
+        lambda *a: moe.apply_experts(*a, **kw)
+    )(x, weights, idx, *stacks))
+    kernel = "quant_matmul_experts" in text
+    grouped = kernel and "argsort" in text
+    return {
+        "kernel": kernel and not grouped,
+        "grouped": grouped,
+        "gather": "gather" in text and not grouped,
+        "scan": "scan" in text or "while" in text,
+    }
+
+
 def test_apply_experts_dispatch(monkeypatch):
     """What ``apply_experts`` chooses from what it can observe: on the CPU
     the gather fallback; with the backend answered as ``tpu`` (as
     tests/test_tpu_compile.py does) the kernel; 17 rows, a shape
-    outside the kernel's contract and ``ep_axis`` keep the paths they had."""
+    outside the kernel's contract and ``ep_axis`` keep the paths they had;
+    17 rows over packed stacks the kernel serves are the grouped path's (the
+    next test)."""
     from mlx_sharding_tpu.ops import moe
 
     rng = np.random.default_rng(9)
@@ -451,31 +479,22 @@ def test_apply_experts_dispatch(monkeypatch):
     wd = _packed_stack(rng, e, h, mi, gs)
 
     def paths(n, **kw):
-        x = jnp.ones((n, h), jnp.float32)
-        weights, idx = _routing(rng, n, e, k, "random")
-        text = str(jax.make_jaxpr(
-            lambda *a: moe.apply_experts(*a, group_size=gs, **kw)
-        )(x, weights, idx, wg, wu, wd))
-        return {
-            "kernel": "quant_matmul_experts" in text,
-            "gather": "gather" in text,
-            "scan": "scan" in text or "while" in text,
-        }
+        return _paths_taken(rng, n, e, k, h, (wg, wu, wd), group_size=gs, **kw)
 
-    assert paths(16) == {"kernel": False, "gather": True, "scan": False}
+    assert paths(16) == _only("gather")
+    assert paths(17) == _only("scan")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert moe.packed_kernel_ok(16, wg, wu, wd, gs, 4)
-    assert paths(16) == {"kernel": True, "gather": False, "scan": False}
+    assert paths(16) == _only("kernel")
     assert paths(1)["kernel"]
-    assert paths(17)["kernel"] is False and paths(17)["scan"]
+    assert paths(17) == _only("grouped")
     # whole (L, E, …) stacks and a layer's index: the same choices, and the
     # kernel's operand is the (L*E, …) view of the stack, not a layer's slice
     one = (wg, wu, wd)
     wg, wu, wd = jax.tree.map(lambda a: jnp.stack([a, a, a]), one)
-    assert paths(16, layer=1) == {"kernel": True, "gather": False, "scan": False}
-    assert paths(17, layer=1) == {"kernel": False, "gather": False, "scan": True}
-    assert paths(16, layer=1, expert_base=0) == {
-        "kernel": False, "gather": False, "scan": True}
+    assert paths(16, layer=1) == _only("kernel")
+    assert paths(17, layer=1) == _only("grouped")
+    assert paths(16, layer=1, expert_base=0) == _only("scan")
     text = str(jax.make_jaxpr(
         lambda *a: moe.apply_experts(*a[:-1], group_size=gs, layer=a[-1])
     )(jnp.ones((16, h), jnp.float32), *_routing(rng, 16, e, k, "random"),
@@ -483,7 +502,7 @@ def test_apply_experts_dispatch(monkeypatch):
     assert f"u32[{3 * e},{mi},{h // 8}]" in text
     assert not re.search(r":u32\[[^\]]*\] = (dynamic_slice|gather)", text)
     monkeypatch.undo()  # off the chip: the layer's slice, then the gather
-    assert paths(16, layer=1) == {"kernel": False, "gather": True, "scan": False}
+    assert paths(16, layer=1) == _only("gather")
     x16 = jnp.asarray(rng.normal(size=(16, h)), jnp.float32)
     weights, idx = _routing(rng, 16, e, k, "random")
     np.testing.assert_array_equal(
@@ -541,7 +560,7 @@ def test_moe_dispatch_is_counted_once_a_traced_call_and_shown_on_metrics(monkeyp
                *stacks).block_until_ready()
 
     before = moe.dispatch_counts()
-    assert set(before) == {"kernel", "scan", "gather_packed", "gather"}
+    assert set(before) == {"kernel", "grouped", "scan", "gather_packed", "gather"}
     run(8, packed)
     run(8, dense)
     run(17, packed)
@@ -554,12 +573,126 @@ def test_moe_dispatch_is_counted_once_a_traced_call_and_shown_on_metrics(monkeyp
     after = moe.dispatch_counts()
     assert after == {"kernel": before["kernel"] + 1, "scan": before["scan"] + 2,
                      "gather_packed": before["gather_packed"] + 1,
-                     "gather": before["gather"] + 1}
+                     "gather": before["gather"] + 1, "grouped": before["grouped"]}
     text = ServingMetrics().render()
     assert "# TYPE mst_moe_dispatch_total counter" in text
     assert "# HELP mst_moe_dispatch_total" in text
     for path, n in after.items():
         assert f'mst_moe_dispatch_total{{path="{path}"}} {n}' in text
+
+
+#: what keeps a chunk (17 rows and more) off the grouped path, and what it
+#: takes instead: name -> (backend answered, stacks, extra arguments, path)
+CHUNK_DISPATCH = {
+    "packed-on-a-tpu": ("tpu", "packed", {}, "grouped"),
+    "packed-in-place-on-a-tpu": ("tpu", "layered", {"layer": 1}, "grouped"),
+    "packed-bf16-rows-on-a-tpu": ("tpu", "packed", {"dtype": jnp.bfloat16}, "grouped"),
+    "packed-ungated-on-a-tpu": ("tpu", "ungated", {}, "grouped"),
+    "packed-off-the-chip": ("cpu", "packed", {}, "scan"),
+    "dense-on-a-tpu": ("tpu", "dense", {}, "scan"),
+    "resident-range": ("tpu", "packed", {"expert_base": 0}, "scan"),
+    "resident-range-in-place": ("tpu", "layered", {"layer": 1, "expert_base": 0}, "scan"),
+    "outside-the-kernels-contract": ("tpu", "odd", {}, "scan"),
+}
+
+
+@pytest.mark.parametrize("rows", [17, 256])
+@pytest.mark.parametrize("case", list(CHUNK_DISPATCH))
+def test_apply_experts_dispatch_of_a_chunk(case, rows, monkeypatch):
+    """More rows than the decode kernel takes: packed stacks whose three
+    projections the expert kernel serves at ``GROUP_TILE`` rows, on a TPU,
+    go through the grouped path — gated or not, in place or one layer's —
+    and everything else keeps the scan: off the chip, dense stacks, a
+    resident range, a shape outside the kernel's contract."""
+    from mlx_sharding_tpu.ops import moe
+
+    backend, kind, kw, want = CHUNK_DISPATCH[case]
+    rng = np.random.default_rng(sum(map(ord, case)) + rows)
+    e, k, h, mi, gs = 4, 2, 256, 128, 64
+    if kind == "dense":
+        stacks = tuple(jnp.ones(s, jnp.float32) for s in ((e, h, mi), (e, h, mi), (e, mi, h)))
+    elif kind == "odd":  # 96 rows: no 128-lane OUT tile
+        h, mi, gs = 64, 96, 32
+        stacks = (_packed_stack(rng, e, mi, h, gs), _packed_stack(rng, e, mi, h, gs),
+                  _packed_stack(rng, e, h, mi, gs))
+    else:
+        stacks = (_packed_stack(rng, e, mi, h, gs), _packed_stack(rng, e, mi, h, gs),
+                  _packed_stack(rng, e, h, mi, gs))
+        if kind == "layered":
+            stacks = jax.tree.map(lambda a: jnp.stack([a, a, a]), stacks)
+        if kind == "ungated":
+            stacks = (None, *stacks[1:])
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if kind == "odd":
+        assert not moe.packed_kernel_ok(moe.GROUP_TILE, *stacks, gs, 4)
+    before = moe.dispatch_counts()
+    assert _paths_taken(rng, rows, e, k, h, stacks, group_size=gs, **kw) == _only(want)
+    assert moe.dispatch_counts() == {**before, want: before[want] + 1}
+
+
+def test_grouped_dispatch_under_ep_axis_keeps_the_scan(monkeypatch):
+    """Expert-parallel stacks are sharded: each device scans its residents
+    whatever the rows and the backend, and one psum combines."""
+    from jax.sharding import PartitionSpec as P
+
+    from mlx_sharding_tpu.ops import moe
+    from mlx_sharding_tpu.parallel.mesh import make_mesh
+
+    rng = np.random.default_rng(21)
+    e, k, h, mi, gs, n = 4, 2, 256, 128, 64, 32
+    wg, wu = (_packed_stack(rng, e, mi, h, gs) for _ in range(2))
+    wd = _packed_stack(rng, e, h, mi, gs)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = make_mesh(pp=1, ep=2)
+    rep, split = P(), jax.tree.map(lambda _: P("ep"), wg)
+    before = moe.dispatch_counts()
+    text = str(jax.make_jaxpr(jax.shard_map(
+        lambda *a: moe.apply_experts(*a, ep_axis="ep", group_size=gs),
+        mesh=mesh, in_specs=(rep, rep, rep, split, split, split), out_specs=rep,
+        check_vma=False,
+    ))(jnp.ones((n, h), jnp.float32), *_routing(rng, n, e, k, "random"), wg, wu, wd))
+    assert "quant_matmul_experts" not in text and "psum" in text and "while" in text
+    assert moe.dispatch_counts() == {**before, "scan": before["scan"] + 1}
+
+
+def test_grouped_dispatch_is_counted_once_a_traced_call_and_shown_on_metrics(monkeypatch):
+    """``mst_moe_dispatch_total{path="grouped"}``: one count per traced chunk
+    program, none for its runs, and on ``/metrics`` beside the other paths.
+    The program is traced with the backend answered as ``tpu`` and run in
+    interpret mode, as the CPU runs a Pallas kernel."""
+    import functools
+
+    from mlx_sharding_tpu.ops import moe, quant_matmul
+    from mlx_sharding_tpu.utils.observability import ServingMetrics
+
+    rng = np.random.default_rng(4)
+    e, k, h, mi, gs, n = 4, 2, 64, 32, 16, 24
+    stacks = (_packed_stack(rng, e, mi, h, gs), _packed_stack(rng, e, mi, h, gs),
+              _packed_stack(rng, e, h, mi, gs))
+    x = jnp.asarray(rng.normal(size=(n, h)), jnp.float32)
+    weights, idx = _routing(rng, n, e, k, "random")
+    # off the chip the contract is the interpreter's (no 128-lane tiles) and
+    # the kernel runs interpreted: the dispatcher's choice is what is tested
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        quant_matmul, "experts_blocks",
+        functools.partial(quant_matmul.experts_blocks, hardware=False))
+    grouped = moe._apply_grouped_kernel
+    monkeypatch.setattr(
+        moe, "_apply_grouped_kernel",
+        functools.wraps(grouped)(lambda *a, **kw: grouped(*a, interpret=True, **kw)))
+    fn = jax.jit(lambda *a: moe.apply_experts(*a, group_size=gs))
+    before = moe.dispatch_counts()
+    outs = [np.asarray(fn(x, weights, idx, *stacks)) for _ in range(3)]
+    after = moe.dispatch_counts()
+    assert after == {**before, "grouped": before["grouped"] + 1}
+    monkeypatch.undo()
+    want = moe._apply_scan(x, weights, idx, *stacks, gs, 4)
+    for out in outs:
+        np.testing.assert_allclose(out, np.asarray(want), rtol=1e-4, atol=2e-5)
+    text = ServingMetrics().render()
+    assert f'mst_moe_dispatch_total{{path="grouped"}} {after["grouped"]}' in text
+    assert after["grouped"] > 0
 
 
 # ----------------------- the scan that visits the experts the rows picked

@@ -253,6 +253,10 @@ CASES = {
     "experts-dsv2-16x6of64": _experts(16, 6, 64, 2048, 1408),
     "experts-dsv2-1x6of64": _experts(1, 6, 64, 2048, 1408),
     "experts-mixtral-16x2of8": _experts(16, 2, 8, 4096, 14336),
+    # ... and a prefill chunk's 256 rows: the same kernel, one GROUP_TILE-row
+    # block of its own per table entry (ops/moe.py's grouped path)
+    "experts-dsv2-256x6of64": _experts(256, 6, 64, 2048, 1408),
+    "experts-mixtral-256x2of8": _experts(256, 2, 8, 4096, 14336),
 }
 
 
@@ -303,7 +307,8 @@ def _loop_bodies(text: str) -> str:
     return "\n".join(comps[name] for name in sorted(inside & set(comps)))
 
 
-def test_experts_read_in_place_copy_nothing_of_stack_size(chip, monkeypatch):
+@pytest.mark.parametrize("n", [16, 256], ids=["decode-16-rows", "chunk-256-rows"])
+def test_experts_read_in_place_copy_nothing_of_stack_size(n, chip, monkeypatch):
     """A layer scan that carries the layer's index and calls
     ``apply_experts(layer=i)`` on DeepSeek-V2-Lite's ``(13, 64, …)`` packed
     stacks at the cell's 16 rows: the kernel's operands are the stacks where
@@ -312,10 +317,13 @@ def test_experts_read_in_place_copy_nothing_of_stack_size(chip, monkeypatch):
     is sliced or copied (the scanned-slice form needs a layer's 345 MB a
     step). What is left is XLA's own: ``w_down``'s scales and biases
     (f32[13,64,2048,22], 22 groups in the minor dimension) are laid out
-    once a call, outside the loop, into the orientation the kernel reads."""
+    once a call, outside the loop, into the orientation the kernel reads.
+    A chunk's 256 rows (the grouped path) read the stacks the same way; what
+    the loop makes there is the rows' own: the gathered tiles and the
+    kernel's float32 results (14 to 29 MB), never a packed word."""
     from mlx_sharding_tpu.ops.moe import apply_experts
 
-    layers, e, hidden, width, n, k = 13, 64, 2048, 1408, 16, 6
+    layers, e, hidden, width, k = 13, 64, 2048, 1408, 6
     fn, shapes, kernel = _experts(n, k, e, hidden, width)
     shapes = shapes[:3] + [((layers, *s), d) for s, d in shapes[3:]]
 
@@ -339,11 +347,15 @@ def test_experts_read_in_place_copy_nothing_of_stack_size(chip, monkeypatch):
     # 11.5 MB: nothing of that size is made but the two relayouts named above
     relayout = ("copy", f"f32[{layers},{e},{hidden},{width // 64}]")
     made = _arrays_made(text, e * width * (hidden // 64) * 4)
+    if n > 16:  # the chunk's own temporaries are rows, not leaves
+        assert not [m for m in _arrays_made(text, 2**20) if m[1].startswith("u32")]
+        made = [m for m in made if f"[{layers}," in m[1] or f"[{e}," in m[1]]
     assert set(made) <= {relayout} and len(made) <= 2, made
     # … and those two are made before the loop: inside it, nothing
-    assert _arrays_made(_loop_bodies(text), e * width * (hidden // 64) * 4) == []
+    inside = _arrays_made(_loop_bodies(text), e * width * (hidden // 64) * 4)
+    assert [m for m in inside if n == 16 or f"[{e}," in m[1]] == []
     once = 2 * layers * e * hidden * 24 * 4  # 22 groups on 24 sublanes
-    assert compiled.memory_analysis().temp_size_in_bytes < once + 64 * 2**20
+    assert compiled.memory_analysis().temp_size_in_bytes < once + (64 if n == 16 else 160) * 2**20
 
 
 def test_latent_attention_gathers_no_table_and_copies_no_pool(chip, monkeypatch):
