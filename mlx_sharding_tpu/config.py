@@ -537,6 +537,89 @@ class KimiLinearConfig(BaseConfig):
         return self.num_experts * self.moe_expert_share
 
 
+@dataclass
+class Qwen3NextConfig(BaseConfig):
+    """Qwen3-Next (``qwen3_next``): every block is a mixer and a mixture of
+    experts, each behind a zero-centred RMSNorm (``x_hat * (1 + w)``). Layer
+    ``i`` is full attention when ``(i + 1) % full_attention_interval == 0``
+    — GQA on ``head_dim``-wide heads behind an output gate that comes out of
+    ``q_proj`` itself, per-head Q/K norms, rotary on the first
+    ``partial_rotary_factor`` of each head — and a Gated DeltaNet otherwise:
+    a gated delta rule with one decay a HEAD (``ops/kda.py``),
+    ``linear_num_key_heads`` key heads serving ``linear_num_value_heads``
+    value heads behind one ``linear_conv_kernel_dim``-tap convolution. The
+    experts: softmax over all of them, the top ``num_experts_per_tok``
+    renormalised (``norm_topk_prob``), plus one shared expert behind a
+    sigmoid gate of its own.
+
+    A layer may hold one chip's SHARE of the routed experts, as
+    :class:`NemotronHConfig` says: ``num_experts`` counts the experts held,
+    ``moe_expert_share`` the holders, ``moe_expert_share_index`` which one
+    this is. A checkpoint's own config (no share keys) is the whole model."""
+
+    model_type: str = "qwen3_next"
+    head_dim: Optional[int] = 256
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000000.0
+    partial_rotary_factor: float = 0.25
+    full_attention_interval: int = 4
+    linear_conv_kernel_dim: int = 4
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    decoder_sparse_step: int = 1
+    mlp_only_layers: Optional[list] = None
+    moe_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512
+    num_experts: int = 512
+    num_experts_per_tok: int = 10
+    norm_topk_prob: bool = True
+    use_sliding_window: bool = False
+    hidden_act: str = "silu"
+    moe_expert_share: int = 1
+    moe_expert_share_index: int = 0
+    max_position_embeddings: int = 262144
+
+    def __post_init__(self):
+        self.mlp_only_layers = list(self.mlp_only_layers or [])
+        wired = {
+            "decoder_sparse_step": 1, "mlp_only_layers": [], "norm_topk_prob": True,
+            "use_sliding_window": False, "hidden_act": "silu", "rope_scaling": None,
+            "tie_word_embeddings": False,
+        }
+        for key, want in wired.items():
+            if getattr(self, key) != want:
+                raise ValueError(
+                    f"qwen3_next is wired for {key} = {want!r}, not "
+                    f"{getattr(self, key)!r}"
+                )
+        if self.linear_key_head_dim != self.linear_value_head_dim:
+            raise ValueError(
+                "qwen3_next is wired for linear_key_head_dim == linear_value_head_dim"
+            )
+        if self.linear_num_value_heads % self.linear_num_key_heads:
+            raise ValueError(
+                "linear_num_key_heads must divide linear_num_value_heads"
+            )
+        if self.full_attention_interval < 1:
+            raise ValueError("full_attention_interval must be at least 1")
+        if not 0 <= self.moe_expert_share_index < self.moe_expert_share:
+            raise ValueError("moe_expert_share_index must lie in [0, moe_expert_share)")
+        super().__post_init__()
+
+    @property
+    def layer_kinds(self) -> list:
+        """Each layer's mixer, ``"attn"`` or ``"gdn"``, 0-based."""
+        n = self.full_attention_interval
+        return ["attn" if (i + 1) % n == 0 else "gdn"
+                for i in range(self.num_hidden_layers)]
+
+    @property
+    def router_width(self) -> int:
+        return self.num_experts * self.moe_expert_share
+
+
 # Arch-name resolution. Mirrors the reference's MODEL_REMAPPING
 # (shard/utils.py:14-17): mistral runs through the llama implementation.
 MODEL_REMAPPING = {
@@ -555,6 +638,7 @@ CONFIG_REGISTRY: dict[str, type] = {
     "zaya": ZayaConfig,
     "granitemoehybrid": GraniteMoeHybridConfig,
     "kimi_linear": KimiLinearConfig,
+    "qwen3_next": Qwen3NextConfig,
 }
 
 

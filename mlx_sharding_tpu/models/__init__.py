@@ -26,6 +26,7 @@ MODEL_REGISTRY: dict[str, tuple[str, str]] = {
         "mlx_sharding_tpu.models.granitemoehybrid", "GraniteMoeHybridModel",
     ),
     "kimi_linear": ("mlx_sharding_tpu.models.kimi_linear", "KimiLinearModel"),
+    "qwen3_next": ("mlx_sharding_tpu.models.qwen3_next", "Qwen3NextModel"),
 }
 
 

@@ -103,6 +103,44 @@ def runs_of(kinds: list) -> list:
     return [tuple(r) for r in runs]
 
 
+def run_pattern(walk: tuple, groups: tuple, layer, carry):
+    """The layers of ``walk`` (:func:`pattern_walk`'s ``(head, period,
+    periods, tail)`` over the kinds ``groups``) in order: the head's, one
+    ``lax.scan`` over the periods, the tail's. ``layer(group, rank, carry) ->
+    carry`` runs the layer at row ``rank`` (may be traced) of ``group``'s
+    stacks; inside a stretch each run of like neighbours of more than one is
+    an inner scan."""
+
+    def run(kinds, first, carry):
+        """``kinds`` in order, each layer at its row: ``first[g]`` (may be
+        traced) plus the like layers before it here."""
+        seen = dict.fromkeys(groups, 0)
+        for group, n in runs_of(kinds):
+            at = first[group] + seen[group]
+            seen[group] += n
+            if n == 1:
+                carry = layer(group, at, carry)
+            else:
+                carry, _ = jax.lax.scan(
+                    lambda c, j, g=group, f=at: (layer(g, f + j, c), None),
+                    carry, jnp.arange(n),
+                )
+        return carry
+
+    head, period, periods, tail = walk
+    count = lambda kinds: {g: kinds.count(g) for g in groups}  # noqa: E731
+    in_head, in_period = count(head), count(period)
+    carry = run(head, dict.fromkeys(groups, 0), carry)
+    if periods:
+        carry, _ = jax.lax.scan(
+            lambda c, i: (run(period, {
+                g: in_head[g] + i * in_period[g] for g in groups
+            }, c), None),
+            carry, jnp.arange(periods),
+        )
+    return run(tail, {g: in_head[g] + periods * in_period[g] for g in groups}, carry)
+
+
 class KimiLinearModel(BaseModel):
     #: engines carry a per-slot recurrent state beside the K/V pages
     #: (cache.KVCache.state); whatever rewinds a slot by lowering its offset
@@ -303,36 +341,7 @@ class KimiLinearModel(BaseModel):
                 out = self._moe(p, layer_params[group], rank, u)
             return h + out.astype(h.dtype), k, v, state
 
-        def run(groups, first, carry):
-            """``groups`` in order, each layer at its row: ``first[g]`` (may
-            be traced) plus the like layers before it here."""
-            seen = dict.fromkeys(GROUPS, 0)
-            for group, n in runs_of(groups):
-                at = first[group] + seen[group]
-                seen[group] += n
-                if n == 1:
-                    carry = layer(group, at, carry)
-                else:
-                    carry, _ = jax.lax.scan(
-                        lambda c, j, g=group, f=at: (layer(g, f + j, c), None),
-                        carry, jnp.arange(n),
-                    )
-            return carry
-
-        head, period, periods, tail = self.walk
-        count = lambda groups: {g: groups.count(g) for g in GROUPS}  # noqa: E731
-        in_head, in_period = count(head), count(period)
-        carry = run(head, dict.fromkeys(GROUPS, 0), (h, k, v, state))
-        if periods:
-            carry, _ = jax.lax.scan(
-                lambda c, i: (run(period, {
-                    g: in_head[g] + i * in_period[g] for g in GROUPS
-                }, c), None),
-                carry, jnp.arange(periods),
-            )
-        return run(
-            tail, {g: in_head[g] + periods * in_period[g] for g in GROUPS}, carry
-        )
+        return run_pattern(self.walk, GROUPS, layer, (h, k, v, state))
 
     # -- embed / head ------------------------------------------------------
     def head_input(self, params, h):

@@ -1,6 +1,8 @@
-"""Kimi Delta Attention (arXiv 2510.26692): a gated delta rule with one decay
-a KEY CHANNEL, the mixer of ``models/kimi_linear.py``'s linear-attention
-layers.
+"""The gated delta rule, the mixer of two families' linear-attention layers:
+Kimi Delta Attention (arXiv 2510.26692, ``models/kimi_linear.py``) with one
+decay a KEY CHANNEL, and Gated DeltaNet (arXiv 2412.06464,
+``models/qwen3_next.py``) with one decay a HEAD. One recurrence, three forms,
+shared by both; the per-channel family first.
 
 ``[q, k, v] = silu(causal depthwise conv1d(qkv_proj(u)))``, ``H`` heads of
 ``D`` each; ``q = l2norm(q) * D**-0.5``, ``k = l2norm(k)``; the decay ``g =
@@ -44,6 +46,26 @@ sums and its unit lower triangular solve are made at once and only the three
 products with ``S_0`` run block after block. ``exp(-G_i)`` ALONE is never
 formed: at ``exp(A_log)`` = 16 it overflows float32 inside 64 positions;
 every exponent here is a difference that is ``<= 0``.
+
+The scalar-decay case (:func:`gdn_mixer`). ``g = -exp(A_log[h]) * softplus(
+in_proj_ba(u)[h] + dt_bias[h])`` is one number a position and VALUE head:
+``alpha`` is constant over a head's key channels, and ``g`` comes ``(B, T,
+H)``, one axis short; each form tells the two cases apart by that rank.
+:func:`kda_sequential` and the one-step form take the head's decay broadcast
+over its channels (the kernel's ``cols`` operand carries ``alpha`` a channel
+either way; the state is the same ``(H, Dk, Dv)`` float32). The chunked form
+is where it matters: ``P_ti(x) = (x_t . k_i) exp(G_t - G_i)`` is ONE ``(C,
+Dk) x (Dk, C)`` product times a ``(C, C)`` matrix of exponentials (masked
+before the exponential, every exponent ``<= 0`` as above), where the
+per-channel form builds ``(C, C, Dk)`` exponentials a head — 128 times the
+elements and no matrix unit. The solve and the three products with ``S_0``
+are the same code. That family has fewer KEY heads than value heads (16 under
+32 at the published sizes): ``q`` and ``k`` pass the convolution and the L2
+norm as key heads and are then repeated (value head ``j`` reads key head ``j
+// (Hv / Hk)``), so the recurrence itself sees ``Hv`` heads of each. Its one
+convolution runs over ``[q, k, v]`` joined, its output gate ``z`` comes out of
+the same projection as ``q``, ``k``, ``v`` and gates through ``silu``, and
+``beta`` and the decay's input come out of one small projection.
 """
 
 from __future__ import annotations
@@ -79,8 +101,11 @@ def kda_sequential(q, k, v, g, beta, state):
     what the other two forms must equal. ``q`` / ``k (B, T, H, Dk)`` already
     normalised and scaled, ``v (B, T, H, Dv)``, ``g (B, T, H, Dk)`` the
     log-decay (``<= 0``; 0 with ``beta`` 0 at a row that must not advance the
-    state), ``beta (B, T, H)``, ``state (B, H, Dk, Dv)``; float32. Returns
-    ``(o (B, T, H, Dv), state after the last row)``."""
+    state) or ``(B, T, H)`` one a head, ``beta (B, T, H)``, ``state (B, H,
+    Dk, Dv)``; float32. Returns ``(o (B, T, H, Dv), state after the last
+    row)``."""
+    if g.ndim == beta.ndim:  # one decay a head: the same for its channels
+        g = g[..., None]
 
     def step(s, xs):
         q_t, k_t, v_t, g_t, b_t = xs
@@ -100,8 +125,10 @@ def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
     """:func:`kda_sequential` over blocks of ``chunk`` positions as matrix
     products (the module docstring's WY form): same arguments and result.
     A ragged last block is padded with rows of ``g = 0``, ``beta = 0``, which
-    pass the state through."""
+    pass the state through. With ``g (B, T, H)``, one decay a head, the
+    pairwise sums are matrix products and no ``(C, C, Dk)`` array is built."""
     b, t, h, dk = k.shape
+    per_head = g.ndim == beta.ndim
     pad = -t % chunk
     if pad:
         padt = lambda z: jnp.pad(z, ((0, 0), (0, pad)) + ((0, 0),) * (z.ndim - 2))  # noqa: E731
@@ -130,7 +157,15 @@ def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
         kk = jnp.where(before, (k_c[..., :, None, :] * decayed).sum(-1), 0.0)
         return kk, (q_c[..., :, None, :] * decayed).sum(-1)
 
-    kk, qk = jax.lax.map(pairwise, (q, k, gc))  # (nc, B, H, C, C)
+    if per_head:
+        # the same two sums under one decay a head: ``(x_t . k_i) exp(G_t -
+        # G_i)``, a (C, Dk) x (Dk, C) product times (C, C) exponentials
+        decay = jnp.exp(jnp.where(upto, gc[..., :, None] - gc[..., None, :], -jnp.inf))
+        mm = functools.partial(jnp.einsum, "nbhtd,nbhid->nbhti", precision=_HI)
+        kk, qk = jnp.where(before, mm(k, k) * decay, 0.0), mm(q, k) * decay
+        gc = gc[..., None]  # what follows scales channels: one decay serves each
+    else:
+        kk, qk = jax.lax.map(pairwise, (q, k, gc))  # (nc, B, H, C, C)
     eg = jnp.exp(gc)
     # (I + A)^{-1} [beta V | beta exp(G) K]: one unit lower triangular solve
     rhs = jnp.concatenate([beta * v, beta * eg * k], axis=-1)
@@ -290,7 +325,10 @@ def _kda_step_lanes(axis_size, in_batched, *args):
 
 def kda_step(pool, rank, q, k, v, g, beta, active, interpret: bool = False):
     """A decode step's recurrence on the pool by the path the operands allow:
-    ``(o (B, H, Dv), pool)``."""
+    ``(o (B, H, Dv), pool)``. ``g (B, H)``, one decay a head, is broadcast
+    over the head's key channels."""
+    if g.ndim == beta.ndim:
+        g = jnp.broadcast_to(g[..., None], k.shape)
     if not step_kernel_eligible(pool, interpret):
         return _kda_step_xla(pool, rank, q, k, v, g, beta, active)
     _count_dispatch("kernel")
@@ -304,6 +342,53 @@ def kda_step(pool, rank, q, k, v, g, beta, active, interpret: bool = False):
 
 def _l2norm(x):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+
+def _conv_silu(qkv, tail, conv_w, taps: int, n_valid):
+    """``silu`` of the causal depthwise convolution over ``[the last taps - 1
+    inputs, this call's]`` (``conv_w (C, taps)``, float32 sums), and the next
+    call's tail: the inputs that end at the last valid row."""
+    t = qkv.shape[1]
+    tail = tail.astype(qkv.dtype)
+    seq = jnp.concatenate([tail, qkv], axis=1)
+    w = conv_w.astype(jnp.float32)
+    conv = sum(seq[:, j : j + t].astype(jnp.float32) * w[:, j] for j in range(taps))
+    end = t if n_valid is None else n_valid
+    return jax.nn.silu(conv), tail, jax.lax.dynamic_slice_in_dim(seq, end, taps - 1, axis=1)
+
+
+def _advance(pool, rank, q, k, v, g, beta, tail, new_tail, n_valid, active,
+             chunk: int, interpret: bool):
+    """The recurrence over this call's rows by the form its length asks for:
+    a decode step (``T == 1``) updates the pool where it lies
+    (:func:`kda_step`), a chunk slices the layer's rows out, runs
+    :func:`kda_chunked` and writes them back. Rows past ``n_valid`` and
+    sequences outside ``active`` advance neither the state nor the tail.
+    Returns ``(o (B, T, H, Dv), pool, new_tail)``."""
+    b, t = q.shape[:2]
+    if t == 1:
+        o, pool = kda_step(
+            pool, rank, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], active,
+            interpret,
+        )
+        o = o[:, None]
+    else:
+        old = take_rows(pool, rank, b)
+        with jax.named_scope("mst.kda.scan"):
+            if n_valid is not None:
+                live = (jnp.arange(t) < n_valid)[None, :, None]
+                g = jnp.where(live if g.ndim == beta.ndim else live[..., None], g, 0.0)
+                beta = jnp.where(live, beta, 0.0)
+            o, s = kda_chunked(q, k, v, g, beta, old, chunk)
+            s = keep_inactive(active, s, old)  # inside the scope, as the step's
+        pool = put_rows(pool, rank, s)
+    with jax.named_scope("mst.kda.step" if t == 1 else "mst.kda.scan"):
+        new_tail = keep_inactive(active, new_tail, tail)
+    return o, pool, new_tail
+
+
+def _head_rms(o, eps: float):
+    return o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + eps)
 
 
 def kda_mixer(
@@ -320,10 +405,8 @@ def kda_mixer(
     ``pool (L, rows, H, D, D)`` float32 every layer's state, this layer's at
     ``rank`` (may be traced), these ``B`` sequences' in its first ``B`` rows;
     ``tail (B, taps - 1, 3 H D)`` the convolution's last inputs. Rows past
-    ``n_valid`` and sequences outside ``active`` advance neither. A decode
-    step (``T == 1``) updates the pool where it lies (:func:`kda_step`), a
-    chunk slices the layer's rows out, runs :func:`kda_chunked` and writes
-    them back. Returns ``(out (B, T, hidden), pool, tail)``."""
+    ``n_valid`` and sequences outside ``active`` advance neither
+    (:func:`_advance`). Returns ``(out (B, T, hidden), pool, tail)``."""
     b, t, _ = u.shape
     nh, d = heads, head_dim
     width = nh * d
@@ -331,15 +414,7 @@ def kda_mixer(
     with jax.named_scope("mst.kda.proj"):
         qkv = linear(u, p["qkv_proj"])
     with jax.named_scope("mst.kda.conv"):
-        # causal depthwise conv over [the last taps-1 inputs, this call's]
-        tail = tail.astype(qkv.dtype)
-        seq = jnp.concatenate([tail, qkv], axis=1)
-        w = p["conv_w"].astype(f32)  # (3 H D, taps)
-        conv = sum(seq[:, j : j + t].astype(f32) * w[:, j] for j in range(taps))
-        qkv_a = jax.nn.silu(conv)
-        # the next call's tail: the inputs that end at the last valid row
-        end = t if n_valid is None else n_valid
-        new_tail = jax.lax.dynamic_slice_in_dim(seq, end, taps - 1, axis=1)
+        qkv_a, tail, new_tail = _conv_silu(qkv, tail, p["conv_w"], taps, n_valid)
     with jax.named_scope("mst.kda.gate"):
         heads_of = lambda z: z.reshape(b, t, nh, d)  # noqa: E731
         q = _l2norm(heads_of(qkv_a[..., :width])) * d**-0.5
@@ -353,27 +428,56 @@ def kda_mixer(
         )
         beta = jax.nn.sigmoid(linear(u, p["b_proj"]).astype(f32))
         out_gate = jax.nn.sigmoid(linear(inner[..., half:], p["g_b"]).astype(f32))
-
-    if t == 1:
-        o, pool = kda_step(
-            pool, rank, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], active,
-            interpret,
-        )
-        o = o[:, None]
-    else:
-        old = take_rows(pool, rank, b)
-        with jax.named_scope("mst.kda.scan"):
-            if n_valid is not None:
-                live = (jnp.arange(t) < n_valid)[None, :, None]
-                g = jnp.where(live[..., None], g, 0.0)
-                beta = jnp.where(live, beta, 0.0)
-            o, s = kda_chunked(q, k, v, g, beta, old, chunk)
-            s = keep_inactive(active, s, old)  # inside the scope, as the step's
-        pool = put_rows(pool, rank, s)
-    with jax.named_scope("mst.kda.step" if t == 1 else "mst.kda.scan"):
-        new_tail = keep_inactive(active, new_tail, tail)
+    o, pool, new_tail = _advance(
+        pool, rank, q, k, v, g, beta, tail, new_tail, n_valid, active, chunk, interpret
+    )
     with jax.named_scope("mst.kda.out"):
-        o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + eps)
-        y = o * p["o_norm"].astype(f32) * heads_of(out_gate)
+        y = _head_rms(o, eps) * p["o_norm"].astype(f32) * heads_of(out_gate)
         out = linear(y.reshape(b, t, width).astype(u.dtype), p["o_proj"])
+    return out, pool, new_tail
+
+
+def gdn_mixer(
+    linear, p, u, pool, rank, tail, n_valid, active, *,
+    key_heads: int, value_heads: int, head_dim: int, taps: int, eps: float,
+    chunk: int = CHUNK, interpret: bool = False,
+):
+    """One Gated DeltaNet mixer: the recurrence of :func:`kda_mixer` under one
+    decay a head (the module docstring's scalar-decay case). ``p``: the
+    layer's ``qkvz_proj`` (hidden to ``[q, k, v, z]``: ``Hk D``, ``Hk D``,
+    ``Hv D`` and the output gate's ``Hv D``), ``conv_w ((2 Hk + Hv) D,
+    taps)`` over ``[q, k, v]`` joined, ``ba_proj`` (hidden to ``[b, a]``,
+    ``Hv`` each), ``A_log`` / ``dt_bias (Hv,)``, ``o_norm (D,)`` (a plain
+    weight), ``o_proj``; ``pool (L, rows, Hv, D, D)``; ``tail (B, taps - 1,
+    (2 Hk + Hv) D)``. Everything else as there. Returns ``(out (B, T,
+    hidden), pool, tail)``."""
+    b, t, _ = u.shape
+    d, f32 = head_dim, jnp.float32
+    kw, vw = key_heads * d, value_heads * d
+    with jax.named_scope("mst.kda.proj"):
+        qkvz = linear(u, p["qkvz_proj"])
+        qkv, z = qkvz[..., : 2 * kw + vw], qkvz[..., 2 * kw + vw :]
+    with jax.named_scope("mst.kda.conv"):
+        qkv_a, tail, new_tail = _conv_silu(qkv, tail, p["conv_w"], taps, n_valid)
+    with jax.named_scope("mst.kda.gate"):
+        # normed as key heads, then value head j reads key head j // (Hv / Hk)
+        spread = lambda x: jnp.repeat(  # noqa: E731
+            _l2norm(x.reshape(b, t, key_heads, d)), value_heads // key_heads, axis=2
+        )
+        q = spread(qkv_a[..., :kw]) * d**-0.5
+        k = spread(qkv_a[..., kw : 2 * kw])
+        v = qkv_a[..., 2 * kw :].reshape(b, t, value_heads, d)
+        ba = linear(u, p["ba_proj"]).astype(f32)
+        beta = jax.nn.sigmoid(ba[..., :value_heads])
+        g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
+            ba[..., value_heads:] + p["dt_bias"].astype(f32)
+        )
+    o, pool, new_tail = _advance(
+        pool, rank, q, k, v, g, beta, tail, new_tail, n_valid, active, chunk, interpret
+    )
+    with jax.named_scope("mst.kda.out"):
+        y = _head_rms(o, eps) * p["o_norm"].astype(f32) * jax.nn.silu(
+            z.astype(f32)
+        ).reshape(b, t, value_heads, d)
+        out = linear(y.reshape(b, t, vw).astype(u.dtype), p["o_proj"])
     return out, pool, new_tail

@@ -118,6 +118,9 @@ MODEL_SCOPES = (
     "mst.moe.experts.matmul",
     "mst.moe.experts.scan",
     "mst.moe.shared",
+    # a shared expert behind a gate of its own (models/qwen3_next.py): the
+    # sigmoid of one scalar a row and its product with the expert's output
+    "mst.moe.shared_gate",
     "mst.moe.latent",
     "mst.ssm.in_proj",
     "mst.ssm.conv",

@@ -113,6 +113,25 @@ def test_the_reason1k_cell_is_sized_for_8_to_16_rejoins():
     assert int(load["clients"]) * -(-longest // chunk) <= int(server_flag(config, "--paged-pool"))
 
 
+def test_the_longgen_cell_is_sized_for_8_to_16_rejoins():
+    name = "qwen3-next-80b-bf16-ep4.longgen-sat"
+    assert name in SIZED
+    config, mix, load = _load(next(c for c in BENCH["workloads"] if c["name"] == name))
+    assert mix["sized_for"]["first_wave_ends"] == [8, 16]
+    assert (mix["prompt_tokens"]["min"], mix["prompt_tokens"]["max"]) == (1024, 1536)
+    # a join is two or three prefill chunks, and prompt + answer fits a
+    # slot's pages, every slot's at once
+    chunk = int(server_flag(config, "--prefill-chunk"))
+    assert -(-mix["prompt_tokens"]["min"] // chunk) == 2
+    assert -(-mix["prompt_tokens"]["max"] // chunk) == 3
+    longest = mix["prompt_tokens"]["max"] + mix["output_tokens"]["max"]
+    assert longest <= int(server_flag(config, "--max-seq"))
+    assert int(load["clients"]) * -(-longest // chunk) <= int(server_flag(config, "--paged-pool"))
+    # a first-wave stream's second request cannot end inside the window
+    step = mix["sized_for"]["step_ms"] / 1e3
+    assert 2 * mix["output_tokens"]["min"] * step > mix["lead_in_s"] + BENCH["run_seconds"]
+
+
 def _texts():
     for kind in ("configs", "workloads"):
         for entry in BENCH[kind]:

@@ -452,6 +452,49 @@ def test_served_granite_programs_walk_their_periods_with_the_state_pool_in_place
     assert moved == ["select_n"] * 2, moved
 
 
+def _pools_ride_every_carry_and_nothing_of_their_size_moves(
+        walked, updates, writes, pool, pages, selects):
+    """Of a decode block that walks a pattern of layers: every scan around an
+    update of the state ``pool`` (layers, slots + 1, H, D, D) or a write to
+    the page pool ``pages`` carries that pool (the block's own scan with the
+    stage axis in front; a run of linear layers leaves the page pool alone,
+    so its scan closes over it), no scan has either among its ``xs`` or
+    ``ys``, and nothing of either pool's size is selected, concatenated,
+    padded, sliced out of it or copied — but the ``selects`` frozen-slot
+    selects over the SLOTS' rows of one layer."""
+    def carried(scan):
+        n_c, n_k = scan.params["num_consts"], scan.params["num_carry"]
+        return [v.aval.shape for v in scan.invars[n_c : n_c + n_k]]
+
+    for scans in updates:
+        assert all(pool in [s[-5:] for s in carried(scan)] for scan in scans)
+    for scans in writes:
+        assert all(
+            math.prod(pages) in [math.prod(s) for s in carried(scan) if s[-1:] == pages[-1:]]
+            for scan in scans)
+    for eqn, _ in walked:
+        if eqn.primitive.name == "scan":
+            ys = [v.aval for v in eqn.outvars[eqn.params["num_carry"]:]]
+            assert not any(a.shape[-4:] == pool[-4:] for a in _scanned(eqn) + ys)
+            assert not any(a.shape[-3:] == pages[-3:] for a in _scanned(eqn) + ys)
+    moved = [
+        eqn.primitive.name for eqn, _ in walked for v in eqn.outvars
+        if eqn.primitive.name in ("select_n", "concatenate", "pad", "slice", "gather", "copy")
+        and getattr(v.aval, "shape", ())[-3:] == pool[-3:] and v.aval.size >= 2 * math.prod(pool[-3:])
+        # x[0] of the stage axis is a slice that takes everything: no copy
+        and not (eqn.primitive.name == "slice" and v.aval.size == eqn.invars[0].aval.size)
+    ]
+    assert moved == ["select_n"] * selects, moved
+    moved = [
+        eqn.primitive.name for eqn, _ in walked for v in eqn.outvars
+        if eqn.primitive.name in ("select_n", "concatenate", "pad", "slice", "copy")
+        and getattr(v.aval, "size", 0) >= math.prod(pages[1:])
+        and getattr(v.aval, "shape", ())[-1:] == pages[-1:]
+        and not (eqn.primitive.name == "slice" and v.aval.size == eqn.invars[0].aval.size)
+    ]
+    assert moved == [], moved
+
+
 @hard_timeout(420)
 def test_served_kimi_linear_programs_walk_a_head_the_periods_and_a_tail():
     """The sixth family: a KDA or MLA mixer, then an MLP or experts, under
@@ -525,40 +568,96 @@ def test_served_kimi_linear_programs_walk_a_head_the_periods_and_a_tail():
         and eqn.outvars[0].aval.shape[-1] == pages[-1]
     ]
     assert sorted(len(scans) for scans in writes) == [1, 2]  # the tail's, the period's
-    def carried(scan):
-        n_c, n_k = scan.params["num_consts"], scan.params["num_carry"]
-        return [v.aval.shape for v in scan.invars[n_c : n_c + n_k]]
-
-    # (the block's own scan carries them with the stage axis in front; a run
-    # of KDA layers leaves the page pool alone, so its scan closes over it)
-    for scans in updates:
-        assert all(pool in [s[-5:] for s in carried(scan)] for scan in scans)
-    for scans in writes:
-        assert all(
-            math.prod(pages) in [math.prod(s) for s in carried(scan) if s[-1:] == pages[-1:]]
-            for scan in scans)
-    for eqn, _ in walked:
-        if eqn.primitive.name == "scan":
-            ys = [v.aval for v in eqn.outvars[eqn.params["num_carry"]:]]
-            assert not any(a.shape[-4:] == pool[-4:] for a in _scanned(eqn) + ys)
-            assert not any(a.shape[-3:] == pages[-3:] for a in _scanned(eqn) + ys)
-    moved = [
-        eqn.primitive.name for eqn, _ in walked for v in eqn.outvars
-        if eqn.primitive.name in ("select_n", "concatenate", "pad", "slice", "gather", "copy")
-        and getattr(v.aval, "shape", ())[-3:] == pool[-3:] and v.aval.size >= 2 * math.prod(pool[-3:])
-        # x[0] of the stage axis is a slice that takes everything: no copy
-        and not (eqn.primitive.name == "slice" and v.aval.size == eqn.invars[0].aval.size)
-    ]
     # the frozen-slot select of each KDA body, over the SLOTS' rows of one layer
-    assert moved == ["select_n"] * 4, moved
-    moved = [
-        eqn.primitive.name for eqn, _ in walked for v in eqn.outvars
-        if eqn.primitive.name in ("select_n", "concatenate", "pad", "slice", "copy")
-        and getattr(v.aval, "size", 0) >= math.prod(pages[1:])
-        and getattr(v.aval, "shape", ())[-1:] == pages[-1:]
-        and not (eqn.primitive.name == "slice" and v.aval.size == eqn.invars[0].aval.size)
+    _pools_ride_every_carry_and_nothing_of_their_size_moves(walked, updates, writes, pool, pages, selects=4)
+
+
+@hard_timeout(420)
+def test_served_qwen3_next_programs_walk_three_periods(monkeypatch):
+    """The seventh family: a Gated DeltaNet or gated attention mixer, then
+    softmax-routed experts beside a gated shared expert, under the same
+    program names and scopes and ONE new scope (``mst.moe.shared_gate``).
+    Three periods of ``G G G A``: the decode block holds the linear body ONCE
+    (the period's run of three, an inner scan) and the attention body ONCE,
+    whatever the depth; the state pool and the page pool ride the carry of
+    every scan, never its ``xs`` or ``ys``, and nothing of either pool's size
+    is selected, concatenated, padded, sliced out of it or copied. No
+    program name is used for two programs."""
+    from mlx_sharding_tpu.models import build_model
+
+    log = JitLog(jax.jit)
+    monkeypatch.setattr(jax, "jit", log)
+    model, _ = build_model(dict(
+        model_type="qwen3_next", vocab_size=128, hidden_size=32,
+        num_hidden_layers=12, num_attention_heads=2, num_key_value_heads=1,
+        head_dim=16, partial_rotary_factor=0.5, full_attention_interval=4,
+        linear_conv_kernel_dim=4, linear_key_head_dim=8, linear_value_head_dim=8,
+        linear_num_key_heads=1, linear_num_value_heads=2, moe_intermediate_size=16,
+        shared_expert_intermediate_size=16, num_experts=2, moe_expert_share=2,
+        num_experts_per_tok=2,
+    ))
+    assert model.walk == ([], ["gdn", "gdn", "gdn", "attn"], 3, [])
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    eng = PipelineEngine(
+        model, params, pipeline_mesh(1), microbatches=2, max_seq=64,
+        cache_dtype=jnp.float32, prefill_chunk=8, pool_pages=10, page_size=8,
+    )
+    b = ContinuousBatcher(eng, decode_block=3)
+    try:
+        assert len(list(b.generate_step([3, 4, 5, 6], max_tokens=5))) == 5
+        args = (eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+                b.last_tok, b.cache, b.active, b.recent, b.keys, b.sp, b.rep_sizes,
+                b.table)
+        prog = b._decode_block_prog(False)
+        block = prog.lower(*args).as_text(debug_info=True)
+        jaxpr = jax.make_jaxpr(prog)(*args)
+        prefill = eng.prefill_slot().lower(
+            eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+            jnp.zeros((1, 8), jnp.int32), jnp.asarray(0, jnp.int32), b.cache,
+            jnp.asarray(8, jnp.int32), b.table,
+        ).as_text(debug_info=True)
+        pool = b.cache.state["gdn"].shape[1:]  # (layers, slots + 1, Hv, D, D)
+        pages = b.cache.k.shape[1:]  # (layers, pages + 1, 1, page, 1, Hkv * D)
+    finally:
+        b.close()
+    assert "module @jit_block " in block
+    assert "module @jit_prefill_chunk " in prefill
+    by_name: dict = {}
+    for name, file, line, _ in log.seen:
+        assert name != "<lambda>", f"anonymous program at {file}:{line}"
+        by_name.setdefault(name, set()).add((file, line))
+    assert not {n: sorted(w) for n, w in by_name.items() if len(w) > 1}
+    assert {"block", "prefill_chunk", "claim_slot", "finish_join"} <= set(by_name)
+    layers = {"mst.embed", "mst.attn.qkv", "mst.attn.qk_norm", "mst.attn.gate",
+              "mst.attn.kv_write", "mst.attn.core", "mst.moe.router", "mst.moe.experts",
+              "mst.moe.experts.scan", "mst.moe.shared", "mst.moe.shared_gate", "mst.norm",
+              "mst.head", "mst.kda.proj", "mst.kda.conv", "mst.kda.gate", "mst.kda.out",
+              "mst.state_pool.regroup"}
+    # decode: the one-step recurrence, the page pool carried (no regroup);
+    # the sampler is in the block
+    assert _scopes_in(block) == layers | {"mst.kda.step", "mst.sample"}
+    # prefill: the chunked (WY) form on the slot's contiguous rows
+    assert _scopes_in(prefill) == layers | {"mst.kda.scan", "mst.kv_pool.regroup"}
+    assert _scopes_in(block) | _scopes_in(prefill) <= set(tracing.MODEL_SCOPES)
+
+    walked = list(_walk(jaxpr.jaxpr))
+    updates = [
+        scans for eqn, scans in walked
+        if eqn.primitive.name == "dynamic_update_slice"
+        and eqn.outvars[0].aval.shape == pool
     ]
-    assert moved == [], moved
+    # block > periods > the run of three: ONE linear body
+    assert [len(scans) for scans in updates] == [3]
+    assert [s.params["length"] for s in updates[0]] == [3, 3, 3]  # steps, periods, run
+    writes = [
+        scans for eqn, scans in walked
+        if eqn.primitive.name == "scatter" and eqn.outvars[0].aval.size == math.prod(pages)
+        and eqn.outvars[0].aval.shape[-1] == pages[-1]
+    ]
+    assert [len(scans) for scans in writes] == [2, 2]  # K and V: block > periods
+
+    # the frozen-slot select of the one linear body, over the SLOTS' rows of one layer
+    _pools_ride_every_carry_and_nothing_of_their_size_moves(walked, updates, writes, pool, pages, selects=1)
 
 
 # ------------------------------------------- what rides the layer scan

@@ -228,6 +228,16 @@ CASES = {
     "paged-mla-latent-32-heads-page512": _paged(
         512, False, slots=40, hq=32, hkv=1, d=576, max_seq=6144, pages=7 * 481,
         rank=512),
+    # the same two kernels at the qwen3-next-80b-bf16-ep4 cell's shapes: the
+    # delta-rule step on 9 layers x 32 slots (the head's decay arrives
+    # broadcast over its 128 key channels, so the operands are kimi-linear's),
+    # and the ragged kernel at a shape it had not served: 8 query on each of
+    # 2 K/V heads of 256, a row's two heads merged on the lanes, a table 15
+    # pages wide, the three attention layers' pools viewed as one
+    "kda-step-qwen3-next": _kda_step(9, 32, 32),
+    "paged-gqa256-merged-page512": _paged(
+        512, False, slots=32, hq=16, hkv=2, d=256, max_seq=7680, pages=3 * 481,
+        merged=True),
     # 4-bit projections of the 3B model: a prefill chunk's 256 rows, a
     # single stream's one row and 8 slots' rows, all on the one kernel
     **{f"quant-M{m}-{i}x{o}": _quant(m, o, i, "quant_matmul")

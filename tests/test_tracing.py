@@ -590,3 +590,22 @@ def test_trace_off_constructs_no_annotation(monkeypatch):
         batcher.close()
     assert {"mst.tick", "mst.assign_slot", "mst.decode_block",
             "mst.harvest_wait"} <= set(built)
+
+
+def test_the_shared_experts_gate_has_a_scope_of_its_own_in_the_vocabulary():
+    """``mst.moe.shared_gate`` (a shared expert behind a sigmoid gate of its
+    own, ``models/qwen3_next.py``) stands beside ``mst.moe.shared`` in the
+    flat vocabulary: a sibling, not a refinement — the trace reader goes by
+    the DEEPEST ``mst.*`` component, and ``scope_share.mlp_shared_norm`` reads
+    ``mst.moe.shared`` by its exact name, so neither reads the other's time."""
+    from mlx_sharding_tpu import tracing
+
+    scopes = tracing.MODEL_SCOPES
+    assert len(set(scopes)) == len(scopes)
+    at = scopes.index("mst.moe.shared_gate")
+    assert scopes[at - 1] == "mst.moe.shared" and scopes[at + 1] == "mst.moe.latent"
+    assert not "mst.moe.shared_gate".startswith("mst.moe.experts")
+    # a gated delta-rule layer of either family opens the same six scopes
+    assert [s for s in scopes if s.startswith("mst.kda.")] == [
+        "mst.kda.proj", "mst.kda.conv", "mst.kda.gate", "mst.kda.scan",
+        "mst.kda.step", "mst.kda.out"]
