@@ -78,9 +78,10 @@ ATTN_ATOL = 3e-2
 # matmul; the kernels keep scale·nibble+bias in f32. Over IN ≥ 2048 random
 # terms that is a few 1e-3 of the output scale.
 QUANT_RTOL = 2e-2
-# bf16 routed experts, the loop over the picked experts against the gather
-# path: each rounds every product and the running sum to bf16 (2^-9
-# relative) in its own order over up to 22 terms a row; a wrong expert or
+# bf16 routed experts, the walk over the picked experts (one kernel: float32
+# activation and sum, cast once) against the gather path and against the
+# loop: those round every product and the running sum to bf16 (2^-9
+# relative) in their own order over up to 22 terms a row; a wrong expert or
 # layer read is O(1) of the output scale.
 EXPERTS_RTOL = 4e-2
 # Served logprobs, one serving path against another (paged pool + ragged
@@ -801,17 +802,24 @@ def child_kernels(seed: int, rehearse: bool) -> None:
                 f"apply_experts did not take the {path} path for {n} rows "
                 f"over packed stacks on the chip: {moe.dispatch_counts()}"
             )
-    # ---- routed experts under a resident range, 32 rows at the two bf16
-    # cells' expert widths: bf16 (L, E, ...) stacks read in place by an
-    # expert id LOADED from the step's list of distinct picks (plain XLA: the
-    # matmuls read their operand out of the stack where it lies), against
-    # the gather path over the same layer's experts, four rows at a time
+    # ---- routed experts under a resident range, a decode step's 32 rows at
+    # three bf16 cells' expert widths: bf16 (L, E, ...) stacks read in place
+    # by the dense expert-indexed kernel (ops/dense_experts.py: the step's
+    # distinct picks a scalar-prefetched table, one call for the layer),
+    # against the gather path over the same layer's experts, four rows at a
+    # time, and against the LOOP it replaces (plain XLA, an expert an
+    # iteration), which the same rows take as part of a chunk: tiled to 160
+    # rows they pick the same experts and every row's answer is its own
     if rehearse:
         cases = [("gated", True, 8, 2, 4, 16, 128, 64),
                  ("un-gated", False, 8, 3, 8, 32, 64, 128)]
-    else:  # Trinity-Large: top-4 of 256, 16 held; Nemotron-3: top-22 of 512, 128 held
-        cases = [("gated", True, 32, 4, 16, 256, 3072, 3072),
-                 ("un-gated", False, 32, 22, 128, 512, 1024, 2688)]
+    else:  # Qwen3-Next: top-10 of 512, 128 held; Nemotron-3: top-22 of 512,
+        # 128 held; Trinity-Large: top-4 of 256, 16 held
+        cases = [("gated", True, 32, 10, 128, 512, 2048, 512),
+                 ("un-gated", False, 32, 22, 128, 512, 1024, 2688),
+                 ("gated", True, 32, 4, 16, 256, 3072, 3072)]
+    from mlx_sharding_tpu.ops.dense_experts import MAX_ROWS
+
     for tag, gated, n, k, held, routed, hidden, width in cases:
         kg, ku, kd, kx, kr, key = jax.random.split(key, 6)
         w_up = jax.random.normal(ku, (2, held, hidden, width), bf16) * 0.02
@@ -835,6 +843,10 @@ def child_kernels(seed: int, rehearse: bool) -> None:
             ])
 
         def rows(x, weights, idx, *stacks):
+            if rehearse:  # the walk as a TPU backend takes it, interpreted
+                return moe._distinct_walk(held, 64, 4, True)(
+                    x, weights, idx - base, moe._flat_layers(*stacks),
+                    jnp.asarray(held, jnp.int32))
             return moe.apply_experts(x, weights, idx, *stacks, expert_base=base, layer=1)
 
         def lanes(x, weights, idx, *stacks):
@@ -843,12 +855,29 @@ def child_kernels(seed: int, rehearse: bool) -> None:
             return jax.vmap(lambda *row: rows(*row, *stacks))(
                 x[:, None], weights[:, None], idx[:, None])[:, 0]
 
+        def loop_ref(x, weights, idx, *stacks):
+            reps = MAX_ROWS // n + 1  # more rows than the kernel takes
+            chunk = (jnp.tile(a, (reps, 1)) for a in (x, weights, idx))
+            return moe.apply_experts(*chunk, *stacks, expert_base=base, layer=1)[:n]
+
         # no copy of an expert: less in temporaries than ONE matrix of one
-        for how, fn in (("", rows), (", one row a lane", lanes)):
+        taken = moe.dispatch_counts()
+        for how, fn, ref in (("", rows, held_ref), (", one row a lane", lanes, held_ref),
+                             (" against the loop", rows, loop_ref)):
             check(f"held experts {tag}{how} N={n} top-{k} of {routed}, {held} held "
-                  f"{hidden}x{width}", None, fn, held_ref,
+                  f"{hidden}x{width}", "dense_experts", fn, ref,
                   (x, weights, idx, w_gate, w_up, w_down), EXPERTS_RTOL,
                   relative=True, temp_below=hidden * width * 2)
+        counts = moe.dispatch_counts()
+        if not rehearse and (
+            counts["dense_kernel"] < taken["dense_kernel"] + 2
+            or counts["scan"] != taken["scan"] + 1
+        ):
+            raise SystemExit(
+                f"apply_experts did not take the dense kernel for {n} rows and "
+                f"the loop for {(MAX_ROWS // n + 1) * n} over bf16 stacks on the "
+                f"chip: {taken} -> {counts}"
+            )
         del w_gate, w_up, w_down
     print(json.dumps({"ok": True, "device": device}), flush=True)
 

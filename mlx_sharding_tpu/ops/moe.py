@@ -27,8 +27,19 @@ observe — token count, packed or dense stacks, backend, shapes:
   picked and no other. No capacity, no dropped pair. The decode step hands
   the kernel all rows against each distinct expert; the chunk hands it
   each expert's rows.
+- **decode, dense stacks under a resident range or expert-parallel, on a
+  TPU**: the dense expert-indexed kernel (``dense_experts.dense_experts``;
+  bf16 stacks, at most ``dense_experts.MAX_ROWS`` rows). The step's DISTINCT
+  held experts are the kernel's scalar-prefetched table, as for packed
+  stacks: each grid step holds one tile of one expert's gate, up and down
+  matrices, read out of the ``(E, H, I)`` / ``(E, I, H)`` stacks where they
+  lie while the next one's arrive, multiplies all N rows through the three
+  and adds the rows' routing mass times the result into one float32
+  accumulator. One call a layer; HBM traffic is each picked expert's bytes
+  once.
 - **prefill over dense stacks or packed ones the kernel does not serve, a
-  resident range and expert-parallel**: a loop
+  resident range and expert-parallel elsewhere** (a chunk's rows, float32
+  or packed stacks, off the chip): the same walk as a loop
   over the DISTINCT held experts the rows picked, ascending, with masked
   accumulation — every matmul is a full-width MXU op with static shapes,
   read out of the stacks where they lie; no sorting, no capacity overflow.
@@ -89,9 +100,11 @@ GROUP_TILE = 32
 # /metrics shows it as ``mst_moe_dispatch_total{path}``: "gather" or
 # "gather_packed" above 0 on a chip is a decode step that copies N x K whole
 # experts out of the stacks (dense ones; packed ones outside the kernel's
-# contract) before it multiplies; "grouped" is a chunk over packed stacks.
+# contract) before it multiplies; "grouped" is a chunk over packed stacks;
+# "dense_kernel" and "scan" are the walk over the distinct held experts, as
+# one kernel (a decode step's rows over dense bf16 stacks) or as a loop.
 _DISPATCHED = DispatchCounter(
-    "kernel", "grouped", "scan", "gather_packed", "gather"
+    "kernel", "grouped", "dense_kernel", "scan", "gather_packed", "gather"
 )
 dispatch_counts = _DISPATCHED.counts
 _count_dispatch = _DISPATCHED.count
@@ -220,7 +233,6 @@ def apply_experts(
         base = 0 if expert_base is None else expert_base
         if ep_axis is not None:
             base = base + jax.lax.axis_index(ep_axis) * e_local
-        _count_dispatch("scan")
         acc = _apply_scan(
             x, weights, idx - base, w_gate, w_up, w_down, group_size, bits,
             layer=layer,
@@ -244,7 +256,6 @@ def apply_experts(
                 x, weights, idx, w_gate, w_up, w_down, group_size, bits,
                 layer=layer,
             )
-        _count_dispatch("scan")
         return _apply_scan(
             x, weights, idx, w_gate, w_up, w_down, group_size, bits, layer=layer
         )
@@ -301,6 +312,28 @@ def packed_kernel_ok(n, w_gate, w_up, w_down, gs, bits) -> bool:
             n, w["q"].shape[-2], w["q"].shape[-1] * 32 // bits, gs, bits
         ) is not None
         for w in (w_gate, w_up, w_down) if w is not None
+    )
+
+
+def dense_kernel_block(x, w_gate, w_up, w_down, interpret=False) -> int | None:
+    """The width tile with which the rows ``x`` run the dense expert-indexed
+    kernel over these stacks, or None where they walk the loop: packed
+    stacks, rows of another dtype than the stacks, no TPU backend
+    (``interpret`` stands in for one: the tests), or a shape outside the
+    kernel's own contract (dense_experts.dense_experts_block: float32
+    stacks, more rows than it holds in VMEM — a prefill chunk — or a width
+    off the lanes)."""
+    from mlx_sharding_tpu.ops.dense_experts import dense_experts_block
+    from mlx_sharding_tpu.ops.quant import is_quantized
+
+    if is_quantized(w_up) or x.dtype != w_up.dtype:
+        return None
+    if not interpret and jax.default_backend() != "tpu":
+        return None
+    hidden, inter = w_up.shape[-2:]
+    return dense_experts_block(
+        x.shape[0], hidden, inter, w_up.dtype, gated=w_gate is not None,
+        hardware=not interpret,
     )
 
 
@@ -506,6 +539,10 @@ def _apply_scan(x, weights, idx, w_gate, w_up, w_down, gs=64, bits=4,
     (below 0, at or above E: a resident range, ``ep_axis``); they match no
     held expert and are not visited.
 
+    A decode step's rows over dense bf16 stacks on a TPU take the walk as
+    ONE expert-indexed kernel (``dense_kernel_block`` says which); a chunk's
+    rows, packed or float32 stacks and every other backend take it as a loop.
+
     Under ``jax.vmap`` over the rows (the engine's M decode lanes: ``--ep``,
     ``--paged-attention gather``) the lanes are ONE walk over the experts
     any of them picked (``_distinct_walk``'s batching rule)."""
@@ -514,6 +551,10 @@ def _apply_scan(x, weights, idx, w_gate, w_up, w_down, gs=64, bits=4,
     num_experts = (w_up["q"] if is_quantized(w_up) else w_up).shape[
         0 if layer is None else 1
     ]
+    _count_dispatch(
+        "scan" if dense_kernel_block(x, w_gate, w_up, w_down) is None
+        else "dense_kernel"
+    )
     first = 0  # the stacks' row of this layer's expert 0
     if layer is not None:
         w_gate, w_up, w_down = _flat_layers(w_gate, w_up, w_down)
@@ -524,20 +565,36 @@ def _apply_scan(x, weights, idx, w_gate, w_up, w_down, gs=64, bits=4,
 
 
 @functools.lru_cache(maxsize=None)
-def _distinct_walk(num_experts: int, gs: int, bits: int):
+def _distinct_walk(num_experts: int, gs: int, bits: int, interpret: bool = False):
     """``walk(x (N, H), weights (N, K), idx (N, K), stacks, first)``: the
-    loop of ``_apply_scan`` over rows ``first .. first + num_experts`` of the
-    stacks, with a batching rule of its own. The loop's bound and the row it
-    reads are loaded from the picks, so ``jax.vmap`` as it stands would give
-    every lane its own bound and its own row: a batched ``while`` that
-    gathers one whole expert PER LANE an iteration. The rule folds the
+    walk of ``_apply_scan`` over rows ``first .. first + num_experts`` of the
+    stacks, with a batching rule of its own. Which expert is read is loaded
+    from the picks, so ``jax.vmap`` as it stands would give every lane its
+    own list and its own row: a batched ``while`` that gathers one whole
+    expert PER LANE an iteration. The rule folds the
     lanes into the rows instead — one list of the experts any lane picked,
-    each read once for all lanes, as a scan by the loop's counter was."""
+    each read once for all lanes, as a scan by the loop's counter was.
+
+    Rows and stacks the dense kernel serves (``dense_kernel_block``, asked
+    of the rows in hand: lanes fold to more) walk as
+    ``dense_experts.dense_experts`` — the list and the rows' mass for each
+    entry built once, one call for the layer; all else as a loop with a
+    traced bound, an expert an iteration. ``interpret`` runs the kernel off
+    the chip (the tests)."""
+    from mlx_sharding_tpu.ops.dense_experts import dense_experts
     from mlx_sharding_tpu.ops.quant import linear
 
     @jax.custom_batching.custom_vmap
     def walk(x, weights, idx, stacks, first):
         ids, live = distinct_experts(idx, num_experts)
+        block_i = dense_kernel_block(x, *stacks, interpret)
+        if block_i is not None:
+            coef = ((idx == ids[:, None, None]) * weights).sum(axis=-1)  # (T, N)
+            with jax.named_scope("mst.moe.experts.matmul"):
+                return dense_experts(
+                    x, first + ids, live, coef, *stacks, block_i=block_i,
+                    interpret=interpret,
+                )
 
         def body(t, acc):
             e = ids[t]

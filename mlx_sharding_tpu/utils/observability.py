@@ -1182,10 +1182,12 @@ _HELP = {
         "Routed-expert calls by the path ops/moe chose, one count per traced "
         "call: kernel is the expert-indexed 4-bit kernel on a decode step's "
         "rows, grouped the same kernel on a chunk's rows sorted by expert "
-        "(each expert multiplies the rows that picked it), scan walks the "
-        "distinct held experts the rows picked with all rows against each "
-        "(prefill over dense stacks, a resident range, expert parallelism); "
-        "gather_packed and gather copy every pick's "
+        "(each expert multiplies the rows that picked it), dense_kernel "
+        "walks the distinct held experts a decode step's rows picked as one "
+        "expert-indexed kernel over dense bf16 stacks (a resident range, "
+        "expert parallelism, on a TPU), scan walks them as a loop with all "
+        "rows against each (a chunk's rows there, packed or float32 stacks, "
+        "off the chip); gather_packed and gather copy every pick's "
         "whole expert out of the stacks first (0 on a chip where the decode "
         "step is packed and inside the kernel's contract).",
     "mst_ssm_dispatch_total":

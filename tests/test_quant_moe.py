@@ -560,7 +560,8 @@ def test_moe_dispatch_is_counted_once_a_traced_call_and_shown_on_metrics(monkeyp
                *stacks).block_until_ready()
 
     before = moe.dispatch_counts()
-    assert set(before) == {"kernel", "grouped", "scan", "gather_packed", "gather"}
+    assert set(before) == {"kernel", "grouped", "dense_kernel", "scan",
+                           "gather_packed", "gather"}
     run(8, packed)
     run(8, dense)
     run(17, packed)
@@ -573,7 +574,8 @@ def test_moe_dispatch_is_counted_once_a_traced_call_and_shown_on_metrics(monkeyp
     after = moe.dispatch_counts()
     assert after == {"kernel": before["kernel"] + 1, "scan": before["scan"] + 2,
                      "gather_packed": before["gather_packed"] + 1,
-                     "gather": before["gather"] + 1, "grouped": before["grouped"]}
+                     "gather": before["gather"] + 1, "grouped": before["grouped"],
+                     "dense_kernel": before["dense_kernel"]}
     text = ServingMetrics().render()
     assert "# TYPE mst_moe_dispatch_total counter" in text
     assert "# HELP mst_moe_dispatch_total" in text

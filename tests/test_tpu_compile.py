@@ -134,6 +134,27 @@ def _experts(n, k, e, hidden, width):
     )
 
 
+def _dense_experts(n, k, held, hidden, width, gated=True, layers=2):
+    """``ops.moe.apply_experts`` — the DISPATCHER — for a decode step's
+    ``n`` rows x top-``k`` over dense bf16 ``(L, E, …)`` stacks under a
+    resident range, read in place by ``layer``: it must select the dense
+    expert-indexed kernel (ops/dense_experts.py), one call for the layer."""
+    from mlx_sharding_tpu.ops.moe import apply_experts
+
+    def fn(x, weights, idx, *stacks):
+        if not gated:
+            stacks = (None, *stacks)
+        return apply_experts(x, weights, idx, *stacks, expert_base=held, layer=1)
+
+    up, down = (layers, held, hidden, width), (layers, held, width, hidden)
+    return (
+        fn,
+        [((n, hidden), BF16), ((n, k), F32), ((n, k), I32)]
+        + [(up, BF16)] * (2 if gated else 1) + [(down, BF16)],
+        "dense_experts",
+    )
+
+
 def _ssm_step(layers, slots, heads, groups, head_dim=64, state=128):
     """``ops.mamba2.ssm_pool_step`` on a ``(layers, slots + 1, H, P, N)``
     float32 state pool (the scratch row past the slots), the layer's rank an
@@ -267,6 +288,20 @@ CASES = {
     # block of its own per table entry (ops/moe.py's grouped path)
     "experts-dsv2-256x6of64": _experts(256, 6, 64, 2048, 1408),
     "experts-mixtral-256x2of8": _experts(256, 2, 8, 4096, 14336),
+    # a decode step's dense bf16 experts under a resident range at the five
+    # bf16 MoE cells' rows, top-k, held experts and published widths: the
+    # tile over the width is all 512 (three 2 MB tiles), all 21 x 128 of the
+    # un-gated 2688, all 1024, 1024 of 2048 and 768 of 3072 (the last two
+    # over Mosaic's default 16 MiB of VMEM: the call states its limit); 24
+    # and 40 rows are no whole bf16 sublane tiles of 16
+    "dense-experts-qwen3-next-32x10of128": _dense_experts(32, 10, 128, 2048, 512),
+    "dense-experts-nemotron3-32x22of128": _dense_experts(32, 22, 128, 1024, 2688, gated=False),
+    "dense-experts-kimi-linear-40x8of16": _dense_experts(40, 8, 16, 2304, 1024),
+    "dense-experts-zaya-24x1of8": _dense_experts(24, 1, 8, 2048, 2048),
+    "dense-experts-trinity-32x4of16": _dense_experts(32, 4, 16, 3072, 3072),
+    # ... and at the kernel's bound of 128 rows, and at one
+    "dense-experts-trinity-128-rows": _dense_experts(128, 4, 16, 3072, 3072),
+    "dense-experts-qwen3-next-1-row": _dense_experts(1, 10, 128, 2048, 512),
 }
 
 
@@ -366,6 +401,47 @@ def test_experts_read_in_place_copy_nothing_of_stack_size(n, chip, monkeypatch):
     assert [m for m in inside if n == 16 or f"[{e}," in m[1]] == []
     once = 2 * layers * e * hidden * 24 * 4  # 22 groups on 24 sublanes
     assert compiled.memory_analysis().temp_size_in_bytes < once + (64 if n == 16 else 160) * 2**20
+
+
+@pytest.mark.parametrize("cell", ["qwen3-next", "nemotron3"])
+def test_dense_experts_read_in_place_copy_nothing_of_an_experts_size(
+    cell, chip, monkeypatch
+):
+    """A layer scan that carries the layer's index and calls
+    ``apply_experts(layer=i, expert_base=…)`` on the cell's dense bf16
+    ``(L, 128, …)`` stacks at its 32 rows: the kernel's operands are the
+    stacks where they lie (row-major, the ``(L*E, …)`` view a bitcast), so
+    the program makes nothing as large as ONE matrix of one expert — no
+    copy, transpose or slice of a stack, a layer or an expert — and there
+    is no ``while`` but the layer scan's own: the walk's went."""
+    from mlx_sharding_tpu.ops.moe import apply_experts
+
+    layers = 3
+    n, k, held, hidden, width, gated = {
+        "qwen3-next": (32, 10, 128, 2048, 512, True),
+        "nemotron3": (32, 22, 128, 1024, 2688, False),
+    }[cell]
+    _, shapes, kernel = _dense_experts(n, k, held, hidden, width, gated, layers)
+
+    def scanned(x, weights, idx, *stacks):
+        if not gated:
+            stacks = (None, *stacks)
+
+        def body(h, i):
+            return h + apply_experts(
+                h, weights, idx, *stacks, expert_base=0, layer=i), None
+
+        return jax.lax.scan(body, x, jnp.arange(layers))[0]
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    compiled = jax.jit(scanned).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and kernel in text
+    assert f"bf16[{layers * held},{hidden},{width}]" in text  # the (L*E, …) view
+    assert len(re.findall(r" while\(", text)) == 1
+    assert _arrays_made(text, hidden * width * 2) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < hidden * width * 2
 
 
 def test_latent_attention_gathers_no_table_and_copies_no_pool(chip, monkeypatch):
