@@ -7,7 +7,8 @@
   These run once at trace time and silently freeze into the compiled
   program (or recompile it), the classic "my timestamp never changes" bug.
 - **MST102 sync-in-hot-path** — a blocking device synchronization
-  (``.item()``, ``jax.device_get``, ``np.asarray``/``np.array``) inside a
+  (``.item()``, ``.block_until_ready()``, ``jax.device_get``,
+  ``np.asarray``/``np.array``) inside a
   serving hot path: the continuous-batching scheduler tick and its helpers,
   plus any function annotated ``# mst: hot-path``. Every such call stalls
   the dispatch pipeline for a full device round trip; intentional,
@@ -216,6 +217,8 @@ HOT_PATH_FUNCS = {
         # proposal work — np.asarray is their job) and are covered by the
         # stricter MST114 device-sync rule instead
         "_harvest_spec", "_spec_tick", "_harvest_any",
+        # the wait on a join's middle chunk, in front of both harvests
+        "_chunk_ended",
     },
 }
 
@@ -460,9 +463,10 @@ def _check_hot_syncs(mod: ModuleInfo) -> list[Finding]:
                 what = f"{name}()"
             elif (
                 isinstance(node.func, ast.Attribute)
-                and node.func.attr == "item" and not node.args
+                and node.func.attr in ("item", "block_until_ready")
+                and not node.args
             ):
-                what = ".item()"
+                what = f".{node.func.attr}()"
             if what:
                 findings.append(Finding(
                     "MST102", mod.display_path, node.lineno, node.col_offset,

@@ -48,6 +48,7 @@ in-flight block first.
 from __future__ import annotations
 
 import hashlib
+import json
 import logging
 import math
 import queue
@@ -242,6 +243,7 @@ class _InflightBlock:
     prev_tok: Optional[object] = None  # block's first input (draft replay)
     seq: int = 0                     # block number (mst.decode_block's seq)
     positions: int = 0               # steps x live rows: tokens it computes
+    ticket: int = 0                  # the tick account's name for it
 
 
 @dataclass
@@ -263,6 +265,7 @@ class _InflightSpec:
     # up-to-date tail. A wrong guess only costs that round's acceptance —
     # the verify never trusts proposals, so exactness is unaffected.
     guess: dict = field(default_factory=dict)
+    ticket: int = 0                  # the tick account's name for it
 
 
 # Retry-After clamps for 429 sheds: the estimate comes from the OBSERVED
@@ -867,14 +870,22 @@ class ContinuousBatcher:
         # part of _tick/_tick_async runs inside one phase of
         # tracing.TICK_PHASES; harvest_wait is the harvest device_get (what
         # the async path overlaps), idle_wait the blocking submission wait,
-        # the rest is host work. It also keeps, per phase, the seconds the
-        # device had nothing to run: _phases.device(True) stands right
-        # before the dispatch call of every served program (decode block,
+        # the rest is host work. It also keeps the device's timeline as
+        # this thread sees it: _phases.dispatched(kind) stands right before
+        # the dispatch call of every served program (decode block,
         # speculative round, prefill chunk; its arguments are made first:
-        # they are host work), _phases.device(False) follows a blocking
-        # read that leaves none dispatched and unread — and nothing else
-        # touches the bit
+        # they are host work) and _phases.returned() right after it,
+        # _phases.ready(ticket) follows the blocking read that waited on
+        # it, _phases.drop() stands where nobody will read what is left —
+        # and nothing else touches the queue. From it come, per phase, the
+        # seconds the device had nothing to run, and per kind of program
+        # the seconds the device had it
         self._phases = tracing.TickPhases(profile=self._trace_profile)
+        # (ticket, logits) of the target's prefill chunk dispatched last,
+        # while nobody has read it: a join's middle chunk ends unseen, so
+        # the harvest behind it waits on its logits first (_chunk_ended)
+        self._chunk_unread = None
+        self._account_logged = False  # close() logs the account once
         # plain decode blocks, counted where they happen (tick thread only).
         # Identities: dispatched = harvested + abandoned + in flight, and
         # positions computed = tokens emitted + dropped + positions in
@@ -1507,6 +1518,36 @@ class ContinuousBatcher:
             "emit_held": dict(self._emit_held),
             "emit_hold_seconds": self._emit_hold_seconds,
             "emit_holds": self._emit_holds,
+            "program_device_seconds": snap["device_seconds"],
+            "program_dispatch_exposed_seconds": snap["exposed_seconds"],
+            "program_runs": snap["runs"],
+            "program_late": snap["late"],
+            "program_unread_seconds": snap["unread_seconds"],
+        }
+
+    def _tick_account(self) -> dict:
+        """What :meth:`close` logs, once: the tick thread's seconds and
+        device-empty seconds by phase and the device's programs by kind,
+        with what turns them into a decode step (a block's steps, blocks
+        and positions) — microseconds, phases nobody entered left out."""
+        s = self.tick_phase_stats()
+
+        def us(d):
+            return {k: round(v, 6) for k, v in d.items() if v}
+
+        return {
+            "path": s["path"], "ticks": s["ticks"],
+            "decode_block": self.decode_block,
+            "blocks_harvested": s["blocks_harvested"],
+            "positions_computed": s["positions_computed"],
+            "phase_seconds": us(s["phase_seconds"]),
+            "device_empty_seconds": us(s["device_empty_seconds"]),
+            "program_device_seconds": us(s["program_device_seconds"]),
+            "program_dispatch_exposed_seconds":
+                us(s["program_dispatch_exposed_seconds"]),
+            "program_runs": s["program_runs"],
+            "program_late": s["program_late"],
+            "program_unread_seconds": round(s["program_unread_seconds"], 6),
         }
 
     def state_stats(self) -> Optional[dict]:
@@ -2030,6 +2071,12 @@ class ContinuousBatcher:
                     "is wedged; the thread is abandoned (daemon) and /health "
                     "now reports degraded", timeout,
                 )
+            elif not self._account_logged:
+                # the run nobody traced leaves its whole account in the log
+                self._account_logged = True
+                logging.getLogger(__name__).info(
+                    "tick account: %s", json.dumps(self._tick_account())
+                )
         spill = self.spill
         if spill is not None:
             spill.close()
@@ -2281,15 +2328,17 @@ class ContinuousBatcher:
         self._pages_of[slot] = pages
         # offset = valid KV rows; the next decode step writes row n_tokens
         self._claim(req, slot, pages, block.n_tokens)
+        resume = self._put(_pack_i32(
+            np.int32(slot),
+            np.asarray(block.resume_keys, np.uint32),
+            np.asarray(block.resume_recent, np.int32),
+            np.int32(block.last_tok),
+        ))
+        self._phases.dispatched("other")
         self.keys, self.recent, self.last_tok, self.active = self._resume_slot(
-            self._put(_pack_i32(
-                np.int32(slot),
-                np.asarray(block.resume_keys, np.uint32),
-                np.asarray(block.resume_recent, np.int32),
-                np.int32(block.last_tok),
-            )),
-            self.keys, self.recent, self.last_tok, self.active,
+            resume, self.keys, self.recent, self.last_tok, self.active,
         )
+        self._phases.returned()
         self._join_programs["other"] += 1
         req.resume_keys = None
         req.resume_recent = None
@@ -2344,12 +2393,15 @@ class ContinuousBatcher:
             self._note_ring_page(req.prefill_pos)
             tokens = put(chunk[None])
             valid = put(np.int32(n_valid))
-            self._phases.device(True)  # from here the device has the chunk
+            # from here the device has the chunk
+            ticket = self._phases.dispatched("chunk")
             logits, self.cache = eng.prefill_slot()(
                 eng.layer_params, eng.layer_masks, eng.vocab_parts,
                 eng.shared_params, tokens, slot_arr, self.cache, valid,
                 self.table if self.paged else None,
             )
+            self._phases.returned()
+            self._chunk_unread = (ticket, logits)
             self._join_programs["chunk"] += 1
             req.prefill_pos += n_valid
             if req.prefill_pos >= req.prompt.size:
@@ -2359,11 +2411,12 @@ class ContinuousBatcher:
             chunk, n_valid = self._chunk_at(req.prompt, req.draft_pos, c)
             tokens = put(chunk[None])
             valid = put(np.int32(n_valid))
-            self._phases.device(True)
+            self._phases.dispatched("other")
             _, self.dcache = d.prefill_slot()(
                 d.layer_params, d.layer_masks, d.vocab_parts, d.shared_params,
                 tokens, slot_arr, self.dcache, valid, None,
             )
+            self._phases.returned()
             self._join_programs["chunk"] += 1
             req.draft_pos += n_valid
         if tr is not None:
@@ -2433,7 +2486,8 @@ class ContinuousBatcher:
         # the blocking read of the chunk and its sample; the pipeline was
         # drained before this chunk, so nothing is left dispatched and unread
         tok = int(tok)
-        self._phases.device(False)
+        self._chunk_unread = None
+        self._phases.ready()
         self._emit(req, tok, logprobs)
         self._h_join.observe(time.perf_counter() - req._t_join)
         if req.prefill_only and req.slot >= 0:
@@ -3198,21 +3252,37 @@ class ContinuousBatcher:
                 ) if self.paged else 0,
             )
         with self._phases.span("dispatch", **args):  # mst.decode_block
-            self._phases.device(True)
+            ticket = self._phases.dispatched("block")
             outs, self.last_tok, self.cache, self.recent, self.keys = block(
                 eng.layer_params, eng.layer_masks, eng.vocab_parts,
                 eng.shared_params, self.last_tok, self.cache, self.active,
                 self.recent, self.keys, self.sp, self.rep_sizes, self.table,
             )
+            ret = self._phases.returned()
+            unread = self._chunk_unread
+            if unread is not None and unread[1].is_ready():
+                # the chunk in front ended before this call came back: its
+                # end is this stamp at the latest
+                self._chunk_unread = None
+                self._phases.ready(unread[0], at=ret, late=True)
         return _InflightBlock(outs=outs, live=live, want_lp=want_lp,
-                              prev_tok=prev_tok, seq=seq, positions=positions)
+                              prev_tok=prev_tok, seq=seq, positions=positions,
+                              ticket=ticket)
 
     def _abandon(self, inf):
         """A decode block's futures are dropped unharvested: its positions
         were computed for nobody."""
+        self._forget_dispatched()
         if isinstance(inf, _InflightBlock):
             self._blocks_abandoned += 1
             self._tokens_dropped["abandoned_block"] += inf.positions
+
+    def _forget_dispatched(self):
+        """Whatever is still dispatched (an abandoned block, a cancelled
+        joiner's chunk) nobody will read, so nobody will learn when it
+        ends: the account counts the device empty from here."""
+        self._chunk_unread = None
+        self._phases.drop()
 
     def _harvest(self, inf: Optional[_InflightBlock], **drain):
         """Pull a dispatched block's tokens to the host and run all of its
@@ -3226,23 +3296,38 @@ class ContinuousBatcher:
         try:
             inject("scheduler.harvest")  # fault harness: kill the harvest
             with self._phases.span("harvest_wait", seq=inf.seq, **drain):
+                self._chunk_ended()
                 # mst: allow(MST102): THE tick sync — tokens must reach the host
                 outs, prev = jax.device_get((inf.outs, inf.prev_tok))
         except BaseException:
             self._abandon(inf)
             raise
-        self._harvested()
+        # the wait's return is the block's end; nobody waits on an empty
+        # device, so the span closed first. With no lookahead block behind
+        # it — every quiesce, every harvest of the sync tick — the device
+        # has nothing to run until the next dispatch
+        self._phases.ready(inf.ticket, at=self._phases.last[1])
         self._blocks_harvested += 1
         with self._phases.span("emit"):
             self._emit_block(inf, outs, prev, *self._phases.last)
 
-    def _harvested(self):
-        """A harvest's blocking read has returned, its ``harvest_wait`` span
-        closed (nobody waits on an empty device). With no lookahead block
-        behind it — every quiesce, every harvest of the sync tick — the
-        device has nothing to run until the next dispatch."""
-        if self._inflight is None:
-            self._phases.device(False)
+    def _chunk_ended(self):
+        """Inside a harvest's wait, before its read: a join's middle chunk
+        was dispatched in front of the block and nobody has seen it end.
+        Wait on its logits, so the account has the boundary between the
+        chunk's seconds and the block's. The chunk ends before the block
+        behind it: the device loses nothing, and this thread spends here
+        what it would spend in the read a moment later. A chunk found ended
+        already is counted late, its end this moment at the latest."""
+        unread, self._chunk_unread = self._chunk_unread, None
+        if unread is None:
+            return
+        ticket, logits = unread
+        late = logits.is_ready()
+        if not late:
+            # mst: allow(MST102): ends before the block the harvest below waits on: no wait is added, only split in two
+            logits.block_until_ready()
+        self._phases.ready(ticket, late=late)
 
     def _emit_block(self, inf: _InflightBlock, outs, prev, t0, t1):
         """The host-side consequences of a harvested block. ``t0``/``t1``
@@ -3267,12 +3352,13 @@ class ContinuousBatcher:
             chain = self._put(
                 jnp.asarray(np.concatenate([prev[None], toks[:-1]], 0))
             )
-            self._phases.device(True)
+            self._phases.dispatched("other")
             self.dcache = self.draft.spec_replay_cb(self.decode_block)(
                 self.draft.layer_params, self.draft.layer_masks,
                 self.draft.vocab_parts, self.draft.shared_params,
                 chain, self.dcache, self.active,
             )
+            self._phases.returned()
             self.fallback_ticks += 1
             self.replayed_tokens += self.decode_block * len(live)
         # every position of the block is emitted or dropped, counted here
@@ -3449,7 +3535,7 @@ class ContinuousBatcher:
             self.keys, vkeys = keys2[:, 0], keys2[:, 1]
             drafts = self._put(jnp.asarray(drafts_np))
             caps = self._put(jnp.asarray(wcaps))
-            self._phases.device(True)
+            ticket = self._phases.dispatched("other")
             gs, count, self.last_tok, self.cache, self.recent = \
                 eng.spec_verify_ngram_cb(K)(
                     eng.layer_params, eng.layer_masks, eng.vocab_parts,
@@ -3457,13 +3543,15 @@ class ContinuousBatcher:
                     self.active, self.recent, vkeys, self.sp,
                     self.rep_sizes, caps, self.table,
                 )
+            self._phases.returned()
         else:
             d = self.draft
             for slot, _req in live:
                 wcaps[slot] = max(1, wins.get(slot, 0))
             keys3 = self._split3(self.keys)
             self.keys, dkeys, vkeys = keys3[:, 0], keys3[:, 1], keys3[:, 2]
-            self._phases.device(True)
+            # the proposals and the verify behind them: one entry
+            ticket = self._phases.dispatched("other")
             drafts, qlps, self.dcache = d.spec_propose_cb(K)(
                 d.layer_params, d.layer_masks, d.vocab_parts, d.shared_params,
                 self.last_tok, self.dcache, self.active, self.recent, dkeys,
@@ -3477,6 +3565,7 @@ class ContinuousBatcher:
                     self.rep_sizes, self._put(jnp.asarray(wcaps)),
                     self.table,
                 )
+            self._phases.returned()
             self.dcache = self.dcache._replace(
                 offset=self._drewind(
                     self.dcache.offset, count, self.active,
@@ -3484,7 +3573,7 @@ class ContinuousBatcher:
                 )
             )
         return _InflightSpec(outs=(count, gs), live=live, wins=wins,
-                             wcaps=wcaps, K=K, guess=guess)
+                             wcaps=wcaps, K=K, guess=guess, ticket=ticket)
 
     def _harvest_spec(self, inf: Optional[_InflightSpec], **drain):
         """Pull a dispatched speculative round's (counts, tokens) to the
@@ -3496,9 +3585,10 @@ class ContinuousBatcher:
         if inf is None:
             return
         with self._phases.span("harvest_wait", **drain):
+            self._chunk_ended()
             # mst: allow(MST102): the spec round's one consolidated harvest
             counts, gs_h = jax.device_get(inf.outs)
-        self._harvested()
+        self._phases.ready(inf.ticket, at=self._phases.last[1])
         with self._phases.span("emit"):
             self._emit_spec(inf, counts, gs_h, *self._phases.last)
 
@@ -3848,10 +3938,9 @@ class ContinuousBatcher:
 
     def _idle_wait(self):
         self._flush_held("tick_end")  # no hold spans a wait on the queue
-        # whatever is still dispatched (a cancelled joiner's chunk, a block
-        # a failed harvest abandoned) nobody will read: the device is as
-        # good as empty for as long as this thread blocks on the queue
-        self._phases.device(False)
+        # the device is as good as empty for as long as this thread blocks
+        # on the queue
+        self._forget_dispatched()
         with self._phases.span("idle_wait"):
             self._drain_submissions(block=True)
 
@@ -3887,6 +3976,14 @@ class ContinuousBatcher:
                     pos=req.prefill_pos,
                     n_valid=max(0, min(self.engine.prefill_chunk,
                                        req.prompt.size - req.prefill_pos)),
+                    # the join's closing chunk (the first token's read
+                    # waits on it) or a middle one
+                    last=int(
+                        req.prefill_pos + self.engine.prefill_chunk
+                        >= req.prompt.size
+                        and (self.draft is None or req.draft_pos
+                             + self.engine.prefill_chunk >= req.prompt.size)
+                    ),
                 )
             with self._phases.span("prefill_chunk", **args):
                 self._prefill_one_chunk(req)
@@ -4000,6 +4097,7 @@ class ContinuousBatcher:
             except Exception as exc:  # noqa: BLE001 — a dead scheduler thread
                 # would hang every consumer; surface the error to them instead
                 self._fail_all(exc)
+        self._forget_dispatched()
         self._phases.stop()
         self._flush_held("tick_end")  # held tokens first, then the sentinels
         # graceful shutdown: end every in-flight and queued request's stream.

@@ -563,6 +563,13 @@ def profile_span(name: str, **args):
         return contextlib.nullcontext()
 
 
+# what the tick thread dispatches on the device, as its account tells them
+# apart: ``block`` (a plain decode block), ``chunk`` (a target prefill chunk,
+# with the first-token program behind a join's last one) and ``other`` (a
+# speculative round, a draft's chunk or replay, a block import's resume)
+PROGRAM_KINDS = ("block", "chunk", "other")
+
+
 class TickPhases:
     """Where the scheduler tick thread's wall time goes: cumulative seconds
     and entry counts per phase of :data:`TICK_PHASES`, always on, and — with
@@ -575,11 +582,28 @@ class TickPhases:
     leaving a phase is one ``perf_counter()`` read. Only the tick thread
     writes; :meth:`snapshot` may be called from any thread.
 
-    Beside each phase's seconds stands the part of them the device had
-    nothing to run (``empty_seconds``): the scheduler tells :meth:`device`
-    right before it dispatches a served program and when a blocking read
-    leaves none dispatched and unread. Host work under a lookahead block
-    costs nothing; the same work with the bit clear is capacity lost."""
+    Beside the phases stands the device's timeline as this thread's waits
+    show it: the FIFO of served programs dispatched and not yet known to
+    have ended, each with its kind (:data:`PROGRAM_KINDS`) and the stamps of
+    its dispatch call (:meth:`dispatched` before it, :meth:`returned` after
+    it). A blocking read's return is the end of the program it waited on
+    (:meth:`ready`) and closes every program dispatched before that one too.
+    The device runs what it is handed in order, so a program began at the
+    later of its call and the end before it, and the account adds, by kind:
+    the seconds the device had it (``device_seconds``), the part of its
+    dispatch call made with the device empty (``exposed_seconds``), runs,
+    and the runs whose end was learned ``late`` (found ended when asked:
+    their seconds are an upper bound). A program whose end nobody observed
+    is taken to have ended when the next one was called, or to have had no
+    time yet where the device was still behind; programs nobody will read
+    are dropped (:meth:`drop`) and their time kept apart (``unread_seconds``).
+
+    The device is ``busy`` while that queue holds anything, and beside each
+    phase's seconds stands the part of them it was not (``empty_seconds``):
+    host work under a lookahead block costs nothing; the same work with the
+    queue empty is capacity lost. Busy and empty partition the thread's
+    clock: device seconds, unread seconds, empty seconds and what the
+    programs still queued have had add up to the time since :meth:`start`."""
 
     def __init__(self, profile: bool = False):
         self.profile = bool(profile)
@@ -587,15 +611,29 @@ class TickPhases:
         self.empty_seconds = dict.fromkeys(TICK_PHASES, 0.0)
         self.entries = dict.fromkeys(TICK_PHASES, 0)
         self.ticks = 0
-        # the device has a served program dispatched and unread
-        self.busy = False
+        # [kind, call, ret, ticket] of every program dispatched and not yet
+        # known to have ended, oldest first
+        self._queue: deque = deque()
+        self._tickets = 0
+        self._device_end = 0.0  # the last end the account knows of
+        self.device_seconds = dict.fromkeys(PROGRAM_KINDS, 0.0)
+        self.exposed_seconds = dict.fromkeys(PROGRAM_KINDS, 0.0)
+        self.runs = dict.fromkeys(PROGRAM_KINDS, 0)
+        self.late = dict.fromkeys(PROGRAM_KINDS, 0)
+        self.unread_seconds = 0.0
         # (start, end) of the span that closed last, perf_counter seconds:
         # the harvest reuses its wait's stamps for the per-request spans
+        # and for the end of the block it waited on
         self.last = (0.0, 0.0)
-        # (phase being charged, since when, the device's bit meanwhile); a
-        # fresh tuple at every switch, which is what lets snapshot() detect
-        # a switch under its feet
+        # (phase being charged, since when, whether the device was busy
+        # meanwhile); a fresh tuple at every switch, which is what lets
+        # snapshot() detect a switch under its feet
         self._open = None
+
+    @property
+    def busy(self) -> bool:
+        """The device has a served program dispatched and unread."""
+        return bool(self._queue)
 
     def _switch(self, phase, now: float):
         cur = self._open
@@ -604,18 +642,84 @@ class TickPhases:
             self.seconds[cur[0]] += dt
             if not cur[2]:
                 self.empty_seconds[cur[0]] += dt
-        self._open = None if phase is None else (phase, now, self.busy)
+        self._open = None if phase is None else (phase, now, bool(self._queue))
 
-    def device(self, busy: bool):
-        """The device's state changed (see the class docstring). A change
-        inside a phase closes the open interval first, as a phase switch
-        does; telling it what it knows already costs one comparison."""
-        if busy == self.busy:
-            return
-        self.busy = busy
+    def _busy_changed(self, now: float):
+        """The queue went from empty to not or back inside a phase: close
+        the open interval first, as a phase switch does."""
         cur = self._open
         if cur is not None:
-            self._switch(cur[0], time.perf_counter())
+            self._switch(cur[0], now)
+
+    def dispatched(self, kind: str) -> int:
+        """Right before the dispatch call of a served program (its arguments
+        are made first: they are host work): the ``call`` stamp. Returns the
+        program's ticket, which a later :meth:`ready` names it by."""
+        now = time.perf_counter()
+        queue = self._queue
+        was_empty = not queue
+        self._tickets += 1
+        queue.append([kind, now, now, self._tickets])
+        if was_empty:
+            self._busy_changed(now)
+        return self._tickets
+
+    def returned(self) -> float:
+        """The dispatch call of the program dispatched last came back: the
+        ``ret`` stamp, which is also returned."""
+        now = time.perf_counter()
+        self._queue[-1][2] = now
+        return now
+
+    def ready(self, ticket: Optional[int] = None, at: Optional[float] = None,
+              late: bool = False):
+        """The host learned that program ``ticket`` had ended (none: the one
+        dispatched last), at ``at`` (none: now; a caller that holds the stamp
+        of the read's return passes it and saves the clock read — it may not
+        lie before the open phase began). Closes it and every program
+        dispatched before it; ``late`` says it was found ended when asked,
+        not waited for. A ticket closed or dropped already changes nothing."""
+        queue = self._queue
+        if ticket is None:
+            ticket = self._tickets
+        if not queue or queue[0][3] > ticket:
+            return
+        now = time.perf_counter() if at is None else at
+        end, end_known = self._device_end, True
+        while queue and queue[0][3] <= ticket:
+            kind, call, ret, _ = queue.popleft()
+            begin = call if call > end else end
+            # only a call behind an end the host learned is known exposed
+            if end_known and ret > begin:
+                self.exposed_seconds[kind] += ret - begin
+            if queue and queue[0][3] <= ticket:
+                # its end went unobserved: the next program's call, if the
+                # device had got to this one by then
+                end = queue[0][1] if queue[0][1] > begin else begin
+                end_known = False
+            else:
+                end = now
+            self.device_seconds[kind] += end - begin
+            self.runs[kind] += 1
+        if late:
+            self.late[kind] += 1
+        self._device_end = now
+        if not queue:
+            self._busy_changed(now)
+
+    def drop(self):
+        """Whatever is still dispatched nobody will read (an abandoned
+        block, a cancelled joiner's chunk at the idle wait, a failure): the
+        device is as good as empty from here. The queue's time so far is
+        kept apart from the programs' seconds, and no run is counted."""
+        queue = self._queue
+        if not queue:
+            return
+        now = time.perf_counter()
+        self.unread_seconds += now - max(queue[0][1], self._device_end)
+        self._device_end = now
+        queue.clear()
+        self._busy_changed(now)
 
     def start(self):
         """The tick thread's loop begins: the clock runs from here."""
@@ -633,13 +737,22 @@ class TickPhases:
         """One loop iteration: the ``mst.tick`` span, whose ``pc`` is this
         process's ``perf_counter()`` at entry — the flight recorder's
         timebase, so a ``/admin/trace/dump`` lines up with a profiler
-        capture by one subtraction — and whose ``empty`` is the cumulative
-        empty seconds (all phases) at entry: the difference between two
-        ticks of a capture is what the host says the device idled between
-        them, beside the gaps on the capture's ``XLA Ops`` line."""
+        capture by one subtraction — and which carries the account's
+        cumulative seconds at entry: ``empty`` (all phases), ``dev_block``
+        and ``dev_chunk`` (the device's seconds on blocks and on chunks)
+        and ``exposed`` (dispatch calls made with the device empty, all
+        kinds). The difference between two ticks of a capture is what the
+        host says the device idled and ran between them, beside the gaps on
+        the capture's ``XLA Ops`` line and the programs on its ``XLA
+        Modules`` line."""
         if self.profile:
             now = time.perf_counter()
-            ann = profile_span(TICK_SPAN, pc=now, empty=self._empty_at(now))
+            ann = profile_span(
+                TICK_SPAN, pc=now, empty=self._empty_at(now),
+                dev_block=self.device_seconds["block"],
+                dev_chunk=self.device_seconds["chunk"],
+                exposed=sum(self.exposed_seconds.values()),
+            )
         else:
             ann = _NO_SPAN
         with ann:
@@ -674,12 +787,22 @@ class TickPhases:
     def snapshot(self) -> dict:
         """``{"ticks", "seconds": {phase: s}, "empty_seconds": {phase: s},
         "entries": {phase: n}}`` with the open phase's elapsed part
-        included, so two snapshots bracket a window exactly. Lock-free:
-        re-read when the tick thread switched phases meanwhile."""
+        included, so two snapshots bracket a window exactly, and the
+        programs' account by kind (``device_seconds``, ``exposed_seconds``,
+        ``runs``, ``late``; ``unread_seconds``) as of the last program
+        closed. Lock-free: re-read when the tick thread switched phases
+        meanwhile."""
         for _ in range(8):
             cur = self._open
             seconds = dict(self.seconds)
             empty = dict(self.empty_seconds)
+            programs = {
+                "device_seconds": dict(self.device_seconds),
+                "exposed_seconds": dict(self.exposed_seconds),
+                "runs": dict(self.runs),
+                "late": dict(self.late),
+                "unread_seconds": self.unread_seconds,
+            }
             if self._open is cur:
                 break
         if cur is not None:
@@ -688,4 +811,5 @@ class TickPhases:
             if not cur[2]:
                 empty[cur[0]] += dt
         return {"ticks": self.ticks, "seconds": seconds,
-                "empty_seconds": empty, "entries": dict(self.entries)}
+                "empty_seconds": empty, "entries": dict(self.entries),
+                **programs}

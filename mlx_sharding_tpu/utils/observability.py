@@ -219,6 +219,18 @@ def _render_tick_phases(lines: list, t: dict):
         f"mst_emit_hold_seconds_sum {t['emit_hold_seconds']:.6f}",
         f"mst_emit_hold_seconds_count {t['emit_holds']}",
     ]
+    # the device's timeline as the tick thread's waits show it, by kind of
+    # served program (tracing.TickPhases)
+    lines.append("# TYPE mst_program_device_seconds_total counter")
+    labelled("mst_program_device_seconds_total", "program",
+             t["program_device_seconds"], "{:.6f}")
+    lines.append("# TYPE mst_program_dispatch_exposed_seconds_total counter")
+    labelled("mst_program_dispatch_exposed_seconds_total", "program",
+             t["program_dispatch_exposed_seconds"], "{:.6f}")
+    lines.append("# TYPE mst_program_runs_total counter")
+    labelled("mst_program_runs_total", "program", t["program_runs"])
+    lines.append("# TYPE mst_program_late_total counter")
+    labelled("mst_program_late_total", "program", t["program_late"])
 
 
 def _render_spec_family(lines: list, spec: dict):
@@ -1152,6 +1164,22 @@ _HELP = {
     "mst_emit_hold_seconds":
         "First deferred item to the flush, seconds; one observation a hold: "
         "what the deferral adds to a token's latency.",
+    "mst_program_device_seconds_total":
+        "Seconds the device had each kind of served program (block: a decode "
+        "block; chunk: a prefill chunk, with the first-token program behind a "
+        "join's last; other: a speculative round, a draft's programs, a block "
+        "import's resume): the blocking read that learned its end, less the "
+        "later of its dispatch call and the end before it. An upper bound by "
+        "mst_program_dispatch_exposed_seconds_total.",
+    "mst_program_dispatch_exposed_seconds_total":
+        "The part of each kind's dispatch calls (call to return) that lay "
+        "after the end of the program before: a dispatch made with the "
+        "device empty, which it waits for.",
+    "mst_program_runs_total":
+        "Served programs whose end the tick thread learned, by kind.",
+    "mst_program_late_total":
+        "Of mst_program_runs_total, those found ended already when asked: "
+        "their end, and so their seconds, are an upper bound.",
     "mst_state_slots_in_use":
         "Slots whose recurrent state (Mamba-2 SSM state and convolution "
         "tail) belongs to an admitted request.",

@@ -119,6 +119,12 @@ def _fake_tick_phase_stats(path="async"):
         "join_programs": {"claim": 4, "chunk": 9, "finish": 4, "other": 0},
         "emit_held": {"chunk": 31, "tick_end": 2, "fail": 0},
         "emit_hold_seconds": 0.0075, "emit_holds": 5,
+        "program_device_seconds": {"block": 9.25, "chunk": 0.5, "other": 0.0},
+        "program_dispatch_exposed_seconds":
+            {"block": 0.125, "chunk": 0.0625, "other": 0.0},
+        "program_runs": {"block": 7, "chunk": 9, "other": 0},
+        "program_late": {"block": 0, "chunk": 1, "other": 0},
+        "program_unread_seconds": 0.0,
     }
 
 

@@ -37,6 +37,7 @@ EXPECTED = {
     "mst001_bad_suppression.py": ("MST001", 6, 0),
     "mst101_host_effect.py": ("MST101", 8, 15),
     "mst102_sync_hot_path.py": ("MST102", 7, 11),
+    "mst102_block_until_ready.py": ("MST102", 6, 4),
     "mst103_recompile_hazard.py": ("MST103", 9, 16),
     "mst104_double_harvest.py": ("MST104", 8, 11),
     "mst105_dense_dequant.py": ("MST105", 10, 4),

@@ -157,7 +157,9 @@ def test_blocks_and_tokens_are_all_accounted_for(engine, mode):
             "phase_entries", "blocks_dispatched", "blocks_harvested",
             "blocks_abandoned", "positions_computed", "tokens_emitted",
             "tokens_dropped", "drains", "blocks_by_sampler", "join_programs",
-            "emit_held", "emit_hold_seconds", "emit_holds"}
+            "emit_held", "emit_hold_seconds", "emit_holds",
+            "program_device_seconds", "program_dispatch_exposed_seconds",
+            "program_runs", "program_late", "program_unread_seconds"}
         # a join that drained a decoding batch held the drain's tokens until
         # its chunk was dispatched; the sync tick never holds
         held = s["emit_held"]
@@ -353,14 +355,17 @@ def test_phases_suspend_their_outer_phase_and_leftover_is_other():
 
 # ------------------------------------------ the device's bit, by phase
 def test_empty_seconds_follow_the_device_bit_on_tick_phases_alone():
-    """The rule on its own: a phase's time with the bit clear is empty
-    time; a change of the bit inside a phase closes the open interval, so a
-    phase holds both kinds; a wait entered with the bit set is never empty;
-    a snapshot from inside an open phase carries the part that has passed."""
+    """The rule on its own: a phase's time with nothing dispatched and
+    unread is empty time; the queue filling or emptying inside a phase
+    closes the open interval, so a phase holds both kinds; a wait entered
+    with a program queued is never empty; a snapshot from inside an open
+    phase carries the part that has passed."""
     ph = tracing.TickPhases()
     assert not ph.busy
-    ph.device(True)  # before start(): nothing to close, the bit is kept
-    ph.device(False)
+    ph.dispatched("chunk")  # before start(): nothing to close, the queue is kept
+    assert ph.busy
+    ph.ready()
+    assert not ph.busy
     ph.start()
     with ph.tick():
         with ph.span("admit"):
@@ -369,12 +374,17 @@ def test_empty_seconds_follow_the_device_bit_on_tick_phases_alone():
                 time.sleep(0.01)  # empty
             with ph.span("prefill_chunk"):
                 time.sleep(0.01)  # empty: before the dispatch
-                ph.device(True)
-                ph.device(True)  # told twice: nothing changes
+                ph.dispatched("chunk")
+                ph.returned()
+                block = ph.dispatched("block")  # behind it: nothing changes
+                ph.returned()
                 time.sleep(0.03)  # the device has the chunk
             with ph.span("harvest_wait"):
                 time.sleep(0.02)
-            ph.device(False)  # the read returned: cleared OUTSIDE the wait
+            # the read returned: the queue empties OUTSIDE the wait, at the
+            # wait's own closing stamp
+            ph.ready(block, at=ph.last[1])
+            assert not ph.busy
             time.sleep(0.01)  # empty, in admit again
         with ph.span("idle_wait"):
             mid = ph.snapshot()
@@ -385,7 +395,6 @@ def test_empty_seconds_follow_the_device_bit_on_tick_phases_alone():
     assert empty["harvest_wait"] == 0.0 and secs["harvest_wait"] >= 0.02
     assert empty["assign_slot"] == secs["assign_slot"] >= 0.01
     assert empty["idle_wait"] == secs["idle_wait"] >= 0.02
-    # (admit resumed under the bit for the instant before device(False))
     assert 0.03 <= empty["admit"] < secs["admit"]
     assert 0.01 <= empty["prefill_chunk"] <= secs["prefill_chunk"] - 0.03
     for phase in tracing.TICK_PHASES:
