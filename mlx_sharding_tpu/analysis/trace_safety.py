@@ -219,6 +219,8 @@ HOT_PATH_FUNCS = {
         "_harvest_spec", "_spec_tick", "_harvest_any",
         # the wait on a join's middle chunk, in front of both harvests
         "_chunk_ended",
+        # the read of a join's first token, behind the block or before it
+        "_read_first_tokens", "_block_leads",
     },
 }
 

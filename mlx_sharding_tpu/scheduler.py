@@ -926,6 +926,15 @@ class ContinuousBatcher:
         self._join_programs = dict.fromkeys(
             ("claim", "chunk", "finish", "other"), 0
         )
+        # first tokens sampled on the device and not yet read: (request,
+        # token, log-probabilities, ticket of the program the read waits on)
+        # of every join whose last chunk was dispatched, oldest first. The
+        # async tick reads them once the decode block behind the chunk is
+        # dispatched (_tick_async); joins by where that read fell
+        self._first_unread: list = []
+        self._join_first_reads = dict.fromkeys(
+            ("behind_block", "before_block"), 0
+        )
         # a drain's hand-over to the streams, held back until the device has
         # the joiner's chunk (_tick_async): the (queue, item) pairs in the
         # order they would have been put, None while no hold is open; when
@@ -1515,6 +1524,7 @@ class ContinuousBatcher:
             "drains": dict(self._drains),
             "blocks_by_sampler": dict(self._blocks_by_sampler),
             "join_programs": dict(self._join_programs),
+            "join_first_reads": dict(self._join_first_reads),
             "emit_held": dict(self._emit_held),
             "emit_hold_seconds": self._emit_hold_seconds,
             "emit_holds": self._emit_holds,
@@ -1529,7 +1539,8 @@ class ContinuousBatcher:
         """What :meth:`close` logs, once: the tick thread's seconds and
         device-empty seconds by phase and the device's programs by kind,
         with what turns them into a decode step (a block's steps, blocks
-        and positions) — microseconds, phases nobody entered left out."""
+        and positions) — microseconds, phases nobody entered left out —
+        and the joins by where their first token was read."""
         s = self.tick_phase_stats()
 
         def us(d):
@@ -1548,6 +1559,7 @@ class ContinuousBatcher:
             "program_runs": s["program_runs"],
             "program_late": s["program_late"],
             "program_unread_seconds": round(s["program_unread_seconds"], 6),
+            "join_first_reads": s["join_first_reads"],
         }
 
     def state_stats(self) -> Optional[dict]:
@@ -2379,13 +2391,21 @@ class ContinuousBatcher:
         sample the first token and activate the slot for decode, in one
         program (``finish_join``); the target's final-chunk logits are
         stashed while the draft catches up. The chunk's tokens, count and
-        slot are numpy arrays of fixed dtypes: nothing eager runs here."""
+        slot are numpy arrays of fixed dtypes: nothing eager runs here.
+
+        Nothing of a last chunk is read here either: the first token stays
+        on the device, noted in ``_first_unread``, and the drain's held
+        tokens stay held. The async tick dispatches the decode block behind
+        the chunk and only then lets the hold go and reads
+        (``_read_first_tokens``); the sync tick, whose mirrors replay this
+        very call, reads before it returns."""
         eng = self.engine
         c = eng.prefill_chunk
         put = self._put
         slot_arr = put(np.int32(req.slot))
         tr = req._trace
         t0 = time.perf_counter() if tr is not None else 0.0
+        ticket = None  # of the program dispatched last
         if req.prefill_pos < req.prompt.size:
             chunk, n_valid = self._chunk_at(req.prompt, req.prefill_pos, c)
             if self._recurrent and req.prefill_pos == 0:
@@ -2411,7 +2431,7 @@ class ContinuousBatcher:
             chunk, n_valid = self._chunk_at(req.prompt, req.draft_pos, c)
             tokens = put(chunk[None])
             valid = put(np.int32(n_valid))
-            self._phases.dispatched("other")
+            ticket = self._phases.dispatched("other")
             _, self.dcache = d.prefill_slot()(
                 d.layer_params, d.layer_masks, d.vocab_parts, d.shared_params,
                 tokens, slot_arr, self.dcache, valid, None,
@@ -2480,22 +2500,61 @@ class ContinuousBatcher:
             self.last_tok, self.active,
         )
         self._join_programs["finish"] += 1
-        # chunk and first token are dispatched, nothing is read yet: the
-        # drain's tokens leave here, ahead of the joiner's first
-        self._flush_held("chunk")
-        # the blocking read of the chunk and its sample; the pipeline was
-        # drained before this chunk, so nothing is left dispatched and unread
-        tok = int(tok)
-        self._chunk_unread = None
-        self._phases.ready()
-        self._emit(req, tok, logprobs)
-        self._h_join.observe(time.perf_counter() - req._t_join)
-        if req.prefill_only and req.slot >= 0:
-            # disaggregated handoff: the first token is the prefill
-            # replica's whole deliverable — park the request; the tick
-            # exports its block (off this hot path) before dispatching
-            # decode, so the slot never enters a decode block here
-            self._handoff_ready.append(req)
+        # chunk and first token are dispatched and nothing is read: the
+        # slot decodes from the next block on, whoever reads the token when
+        self._first_unread.append((req, tok, logprobs, ticket))
+        if not self._async:
+            self._read_first_tokens("before_block")
+
+    def _block_leads(self) -> bool:
+        """Whether the decode block this tick dispatches may go in front of
+        the read of the first tokens its joins left on the device. Nothing
+        on the device needs the host's copy of them (``finish_join`` leaves
+        ``last_tok``, ``active``, ``keys`` and ``recent`` there, and
+        ``_dispatch_block`` reads none of it back), so it may unless the
+        host has to act on a token first: a ``prefill_only`` joiner's slot
+        is handed off and never enters a block here; a batcher that
+        speculates builds its next round's guess from host history; page
+        growth that might preempt would fold a history the token is not in
+        yet."""
+        return (
+            self._spec_mode == "off"
+            and not any(f[0].prefill_only for f in self._first_unread)
+            and self._growth_fits()
+        )
+
+    def _read_first_tokens(self, order: str):
+        """Read and emit, oldest first, the first tokens that joins closed
+        since the last call left on the device; ``order`` says on which side
+        of the tick's decode block the read fell
+        (``mst_join_first_reads_total``). The drain's held tokens leave
+        first, ahead of any joiner's first, and before the blocking read: no
+        hold spans a wait on the device. Each read returns at the end of
+        that join's last chunk and first-token program, which the account
+        learns here, by the chunk's ticket: a block dispatched behind it
+        stays queued."""
+        firsts, self._first_unread = self._first_unread, []
+        if not firsts:
+            return
+        with self._phases.span("first_token"):
+            self._flush_held("chunk")
+            for req, tok, logprobs, ticket in firsts:
+                # the blocking read of the chunk and its sample
+                tok = int(tok)
+                unread = self._chunk_unread
+                if unread is not None and unread[0] <= ticket:
+                    self._chunk_unread = None
+                self._phases.ready(ticket)
+                self._join_first_reads[order] += 1
+                self._emit(req, tok, logprobs)
+                self._h_join.observe(time.perf_counter() - req._t_join)
+                if req.prefill_only and req.slot >= 0:
+                    # disaggregated handoff: the first token is the prefill
+                    # replica's whole deliverable — park the request; the
+                    # tick exports its block (off this hot path) before
+                    # dispatching decode, so the slot never enters a decode
+                    # block here
+                    self._handoff_ready.append(req)
 
     def _hand(self, req: _Request, item):
         """The scheduler thread's one way onto a request's ``out`` queue: a
@@ -3861,7 +3920,20 @@ class ContinuousBatcher:
         the first of: ``_prefill_round`` returning without one, a failure
         (``_fail_all``), the loop's end, the idle wait. No hold spans a
         wait on the device or on the submit queue
-        (``mst_emit_held_total{flush}``, ``mst_emit_hold_seconds``)."""
+        (``mst_emit_held_total{flush}``, ``mst_emit_hold_seconds``).
+
+        A join's closing tick runs quiesce, claim, last chunk,
+        ``finish_join``, the decode block, and only then lets the hold go,
+        reads the joiner's first token and emits it
+        (``_read_first_tokens``): the device goes from the chunk to the
+        block with nothing of the host between them, and the streams'
+        burst and the read's wake-up run under both. The slot is live in
+        that block as any slot is in a lookahead block: a first token that
+        ends its stream (``max_tokens`` 1) finishes it after the dispatch,
+        and the block's positions for it are dropped as ``slot_finished``.
+        Where the host must act on the token first the read stays in front
+        of the block (``_block_leads``;
+        ``mst_join_first_reads_total{order}`` counts both)."""
         inject("scheduler.tick", engine=id(self))  # fault harness: wedge/delay/fail a tick (match engine= to target one batcher)
         phase = self._phases.span  # every part of the tick runs in a phase
         if self._migrate_requested:
@@ -3898,9 +3970,14 @@ class ContinuousBatcher:
                 self._quiesce("admit" if admitting else "prefilling")
             self._admit_waiting()
         self._prefill_round()
-        # a tick that drained and dispatched no chunk (the fifo head does
-        # not fit, the joiner was cancelled or shed, a block import)
-        self._flush_held("tick_end")
+        if self._first_unread and not self._block_leads():
+            self._read_first_tokens("before_block")
+        if not self._first_unread:
+            # a tick that drained and dispatched no chunk (the fifo head
+            # does not fit, the joiner was cancelled or shed, a block
+            # import); with a first token to read behind the block the
+            # hold stays until the block is dispatched
+            self._flush_held("tick_end")
         if self._handoff_ready:
             # prefill-only completions: export + end those streams BEFORE
             # dispatch (pipeline still quiesced from the prefill above)
@@ -3929,6 +4006,9 @@ class ContinuousBatcher:
             if nxt is None:
                 nxt = self._dispatch_block()
             self._inflight = nxt
+            # a join closed in this tick: the device has its chunk and the
+            # block behind it, and this thread nothing more to hand it
+            self._read_first_tokens("behind_block")
             self._harvest_any(prev)
         else:
             # leftover lookahead block of finished slots
@@ -4030,8 +4110,10 @@ class ContinuousBatcher:
 
     def _fail_all(self, exc: BaseException):
         # tokens already counted as emitted reach their streams before the
-        # exception does, never the exception in their place
+        # exception does, never the exception in their place; a first token
+        # nobody read was counted nowhere, and its joiner is in a slot
         self._flush_held("fail")
+        self._first_unread.clear()
         # a scheduler-thread failure is an incident: snapshot the flight
         # recorder before the streams die so their timelines survive
         tracing.auto_snapshot("scheduler_fail")

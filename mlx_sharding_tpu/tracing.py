@@ -72,6 +72,9 @@ TICK_PHASES = (
     # sampler rows), nested in ``admit``
     "assign_slot",
     "prefill_chunk",
+    # the read of a join's first token (the wait on its last chunk) and its
+    # emit, with the flush of the drain's held tokens in front of it
+    "first_token",
     "handoff",
     "dispatch",
     "harvest_wait",

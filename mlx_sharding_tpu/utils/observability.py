@@ -212,6 +212,8 @@ def _render_tick_phases(lines: list, t: dict):
     labelled("mst_decode_blocks_total", "sampler", t["blocks_by_sampler"])
     lines.append("# TYPE mst_join_programs_total counter")
     labelled("mst_join_programs_total", "program", t["join_programs"])
+    lines.append("# TYPE mst_join_first_reads_total counter")
+    labelled("mst_join_first_reads_total", "order", t["join_first_reads"])
     lines.append("# TYPE mst_emit_held_total counter")
     labelled("mst_emit_held_total", "flush", t["emit_held"])
     lines += [
@@ -1156,6 +1158,13 @@ _HELP = {
         "by program: claim (the slot claim), chunk (a prefill chunk, the "
         "draft's too), finish (the first token), other (a block import's "
         "resume). Over mst_join_seconds_count: 3 for a one-chunk join.",
+    "mst_join_first_reads_total":
+        "Joins by where the host's read of their first token fell: "
+        "behind_block (the decode block behind the last chunk was dispatched "
+        "first: the device went from chunk to block with no gap) or "
+        "before_block (the host had to act on the token first: a "
+        "prefill_only request, a speculating batcher, growth that might "
+        "preempt, the sync tick).",
     "mst_emit_held_total":
         "Queue items (tokens, stream ends, errors) of a tick that drained "
         "the pipeline for a joiner, handed to their streams late, by what "

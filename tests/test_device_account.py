@@ -210,9 +210,11 @@ def _two_chunks_nothing_decoding():
 
 
 def _last_chunk_then_block():
-    """A one-chunk join: the chunk is a last chunk, ``int(tok)`` waits on
-    it, and the block behind it is dispatched with the queue EMPTY: that
-    call is exposed to its last microsecond (the burst's tail)."""
+    """A one-chunk join whose first token is read BEFORE the block (the
+    sync tick, a ``prefill_only`` or speculating joiner): the chunk is a
+    last chunk, ``int(tok)`` waits on it, and the block behind it is
+    dispatched with the queue EMPTY: that call is exposed to its last
+    microsecond (the burst's tail)."""
     steps = [(0.0, "start"),
              (1.0, "in", "prefill_chunk"), (1.0, "call", "chunk", "c"),
              (1.25, "ret"), (6.0, "ready", None, True), (6.0, "out"),
@@ -224,6 +226,33 @@ def _last_chunk_then_block():
             "runs": {"chunk": 1, "block": 1},
             "exposed": {"chunk": 0.25, "block": 2.0},
             "empty": {"other": 1.0 + 1.0}}
+    return steps, want
+
+
+def _last_chunk_block_behind(chunk_end):
+    """A one-chunk join in the async tick: the block is dispatched behind
+    the unread last chunk, then the hold lets go and ``int(tok)`` waits on
+    the chunk, whose end it names by the chunk's ticket (the program
+    dispatched last is the block by then). The block's call lay under the
+    chunk: nothing of it is exposed, and the device is never empty between
+    the two (``late_at_ret``: the chunk was found ended when the block's
+    call came back, and the read's own ``ready`` changes nothing)."""
+    steps = [(0.0, "start"),
+             (1.0, "in", "prefill_chunk"), (1.0, "call", "chunk", "c"),
+             (1.25, "ret"), (1.5, "out"),
+             (2.0, "in", "dispatch"), (2.0, "call", "block", "b"), (4.0, "ret")]
+    if chunk_end == "late_at_ret":
+        steps += [(4.0, "ready", "c", False, "late")]
+    steps += [(4.0, "out"),
+              (4.5, "in", "first_token"), (6.0, "ready", "c", False), (6.5, "out"),
+              (10.0, "in", "harvest_wait"), (20.0, "out"),
+              (20.0, "harvest", "b", True), (20.0, "stop")]
+    end = 4.0 if chunk_end == "late_at_ret" else 6.0
+    want = {"device": {"chunk": end - 1.0, "block": 20.0 - end},
+            "runs": {"chunk": 1, "block": 1},
+            "late": {"chunk": int(chunk_end == "late_at_ret")},
+            "exposed": {"chunk": 0.25, "block": 0.0},
+            "empty": {"other": 1.0}}
     return steps, want
 
 
@@ -289,6 +318,8 @@ SCRIPTS = {
     "join_chunk_late_at_the_blocks_return": lambda: _join("late_at_ret"),
     "two_chunks_nothing_decoding": _two_chunks_nothing_decoding,
     "last_chunk_read_by_int_tok": _last_chunk_then_block,
+    "last_chunk_read_behind_its_block": lambda: _last_chunk_block_behind("seen"),
+    "last_chunk_late_at_its_blocks_return": lambda: _last_chunk_block_behind("late_at_ret"),
     "abandoned_block": lambda: _abandoned("abandon"),
     "fail_all": lambda: _abandoned("fail_all"),
     "cancelled_chunk_at_idle_wait": lambda: _abandoned("idle_wait"),
@@ -420,7 +451,16 @@ def test_every_chunk_and_block_is_closed_once_on_a_batcher(engine, mode, caplog)
         busy = sum(s["phase_seconds"].values()) - sum(s["device_empty_seconds"].values())
         assert sum(secs.values()) + s["program_unread_seconds"] == pytest.approx(busy, abs=2e-3)
         assert s["device_empty_seconds"]["harvest_wait"] == 0.0
-        assert batcher._chunk_unread is None
+        assert batcher._chunk_unread is None and not batcher._first_unread
+        # both joins closed in front of a block: the async tick read their
+        # first tokens behind it, so no block's call ever found the device
+        # empty; the sync tick reads first and every block's call is exposed
+        reads = s["join_first_reads"]
+        assert reads == ({"behind_block": 2, "before_block": 0} if mode == "on"
+                         else {"behind_block": 0, "before_block": 2})
+        assert s["phase_entries"]["first_token"] == 2
+        assert s["device_empty_seconds"]["first_token"] == 0.0 or mode == "off"
+        assert (exposed["block"] == 0.0) == (mode == "on")
         text = ServingMetrics(batcher_fn=lambda: batcher).render()
         for family in FAMILIES:
             assert f"# HELP {family} " in text and f"# TYPE {family} counter" in text

@@ -2,12 +2,18 @@
 tokens to their streams only once the device has the joiner's chunk
 (``scheduler._hand`` / ``_flush_held``, opened in ``_tick_async``).
 
+A join's closing tick dispatches the decode block behind the last chunk
+before anything of the join is read: the hold lets go after that dispatch,
+then the first token is read and emitted (``_read_first_tokens``); where the
+host must act on the token first, the read stays in front of the block.
+
 The tick is driven by hand on the test's own thread wherever the order of
 events matters (the scheduler thread never starts), with a tape of what it
-ran: drains, slot claims, chunk dispatches, the blocking read of a first
-token, and every ``put`` on a request's queue. The plain reference for what
-a stream receives is the same request served alone, no joiner interleaved:
-the hold moves when an item is handed over, never which or in what order."""
+ran: drains, slot claims, chunk dispatches, block and speculative
+dispatches, the blocking read of a first token, and every ``put`` on a
+request's queue. The plain reference for what a stream receives is the same
+request served alone, no joiner interleaved: the hold and the read's place
+move when an item is handed over, never which or in what order."""
 
 import queue
 import threading
@@ -140,6 +146,16 @@ def tap(b, tape, monkeypatch):
         prefill_round()
         tape.add("round_end")
 
+    def taped_block():
+        tape.add("block")
+        return dispatch_block()
+
+    def taped_spec(*args):
+        tape.add("spec")
+        return dispatch_spec(*args)
+
+    dispatch_block, b._dispatch_block = b._dispatch_block, taped_block
+    dispatch_spec, b._dispatch_spec = b._dispatch_spec, taped_spec
     prefill_round, b._prefill_round = b._prefill_round, taped_round
     b._quiesce, b._assign_slot, b._claim_slot, b._finish_join = (
         taped_quiesce, taped_assign, taped_claim, taped_finish)
@@ -149,7 +165,7 @@ def tap(b, tape, monkeypatch):
 
 def make_batcher(engines, paged, tape=None, monkeypatch=None, **kw):
     b = ContinuousBatcher(engines[paged], decode_block=BLOCK, **kw)
-    assert b._async
+    assert b._async == (kw.get("async_sched") != "off")
     return b if tape is None else tap(b, tape, monkeypatch)
 
 
@@ -166,10 +182,11 @@ def tick(b, tape):
     """One iteration of ``_loop``'s body, on this thread."""
     tape.add("tick")
     try:
-        b._tick_async()
+        (b._tick_async if b._async else b._tick)()
     except Exception as exc:  # noqa: BLE001 — as _loop does
         b._fail_all(exc)
     assert b._held is None, "a hold outlived its tick"
+    assert not b._first_unread, "a first token outlived its tick"
 
 
 def ended(req):
@@ -210,14 +227,16 @@ def received(req):
                    item if isinstance(item, BaseException) else int(item[0]))
 
 
-def held_ticks(tape):
+def held_ticks(tape, behind=True):
     """The ticks that drained for a joiner and dispatched its chunk: for
     each, ``(events, drain index, chunk index)``; asserts the rule on every
     tick that drained for one — no put between the drain and the chunk's
     dispatch (``_prefill_round``'s return where it dispatched none), the
-    hold closed before the blocking read."""
+    hold closed before the blocking read, and every read behind the tick's
+    decode block wherever the block leads (``behind``)."""
     found = []
     for events in tape.ticks():
+        closing_tick(events, behind)
         drains = [i for i, e in enumerate(events)
                   if e[0] == "drain" and e[1] in ("admit", "prefilling")]
         chunks = [i for i, e in enumerate(events) if e[0] == "chunk"]
@@ -226,12 +245,46 @@ def held_ticks(tape):
         d = drains[0]
         c = chunks[0] if chunks else events.index(("round_end",))
         assert not [e for e in events[d:c] if e[0] == "put"], events
-        for e in events:
-            if e[0] == "read":
-                assert e[1], "a hold was open at the blocking read"
         if chunks:
             found.append((events, d, c))
     return found
+
+
+def closing_tick(events, behind=True):
+    """The rule of a tick in which joins closed (it ran ``finish_join``):
+    every first token is read with no hold open, its ``put`` follows its
+    read (and the stream's end, where that token was its last), and —
+    ``behind`` — claim, chunk and ``finish_join`` of every such join, then
+    the block's dispatch, then the held tokens' puts, then the reads; else
+    every read lies in front of the tick's block or speculative round.
+    Returns the joins closed."""
+    events = [e for i, e in enumerate(events)  # a one-token stream's end
+              if not (e[0] == "put" and e[2] is END and i > 1
+                      and events[i - 2][0] == "read")]
+    kinds = [e[0] for e in events]
+    closed = kinds.count("finish_join")
+    reads = [i for i, k in enumerate(kinds) if k == "read"]
+    assert len(reads) == closed, events
+    for i in reads:
+        assert events[i][1], "a hold was open at the blocking read"
+        assert kinds[i + 1] == "put", events  # the token just read
+    if not closed:
+        return 0
+    dispatch = [i for i, k in enumerate(kinds) if k in ("block", "spec")]
+    assert len(dispatch) == 1 or not behind, events
+    last_finish = len(kinds) - 1 - kinds[::-1].index("finish_join")
+    if behind:
+        (blk,) = dispatch
+        # nothing leaves and nothing is read until the device has the block
+        assert last_finish < blk < reads[0], events
+        assert "put" not in kinds[kinds.index("finish_join"):blk], events
+        # then the held tokens, and after the first read first tokens only
+        tail = kinds[blk + 1:]
+        assert set(tail) <= {"put", "read"}, events
+        assert tail[reads[0] - blk - 1:] == ["read", "put"] * closed, events
+    else:
+        assert dispatch and reads[-1] < dispatch[0], events
+    return closed
 
 
 # --------------------------------------------------------- the plain reference
@@ -301,10 +354,13 @@ def test_a_drains_tokens_leave_after_the_joiners_chunk_is_dispatched(
     after = events[c + 1:]
     if after[0] == ("finish_join",):
         # the join's last chunk (E's, or D's one): the first token is
-        # dispatched too, the hold lets go, and only then the blocking read
+        # sampled on the device, the block behind the chunk is dispatched,
+        # the hold lets go, and only then the blocking read and its put
+        assert after[1:3] == [("round_end",), ("block",)]
         read = after.index(("read", True))
         assert after[read + 1][0] == "put" and after[read + 1][1] in "DE"
-        after = after[1:read]
+        assert after[read + 2:] == []  # the harvest is of nothing
+        after = after[3:read]
     flushed = [e for e in after if e[0] == "put"]
     assert after[:len(flushed)] == flushed and len(flushed) == 5
     assert [e[1] for e in flushed].count("A") == 3
@@ -318,8 +374,24 @@ def test_a_drains_tokens_leave_after_the_joiners_chunk_is_dispatched(
     assert stats["emit_held"] == {"chunk": deferred, "tick_end": 0, "fail": 0}
     assert stats["emit_holds"] == len(held)
     assert 0 < stats["emit_hold_seconds"] < 30
+    # tick 0: A and C join with nothing decoding, both close in it, and one
+    # block goes out in front of both reads; every join of the run read its
+    # first token behind its block, and each stream got that token in the
+    # closing tick and its block's tokens a tick later at the earliest
+    first = tape.ticks()[0]
+    assert [e[0] for e in first if e[0] != "assign"] == [
+        "claim", "claim", "chunk", "finish_join", "chunk", "finish_join",
+        "round_end", "block", "read", "put", "read", "put"]
+    assert [e[1] for e in first if e[0] == "put"] == ["A", "C"]
+    assert stats["join_first_reads"] == {"behind_block": 4, "before_block": 0}
+    for events in tape.ticks():
+        reads = [i for i, e in enumerate(events) if e[0] == "read"]
+        for i in reads:
+            joiner = events[i + 1][1]
+            assert [e for e in events if e[:2] == ("put", joiner)] == [events[i + 1]]
     lines = []
     _render_tick_phases(lines, stats)
+    assert 'mst_join_first_reads_total{order="behind_block"} 4' in lines
     assert f'mst_emit_held_total{{flush="chunk"}} {deferred}' in lines
     assert f"mst_emit_hold_seconds_count {len(held)}" in lines
     # every decode-block token a stream got was counted, and no other
@@ -359,7 +431,8 @@ def test_a_drain_that_dispatches_no_chunk_lets_go_at_the_ticks_end(
         drain = events.index(("drain", "admit", "block"))
         assert not any(e[0] in ("chunk", "claim") for e in events)
         puts = [e for e in events[drain:] if e[0] == "put"]
-        assert puts == events[events.index(("round_end",)) + 1:]
+        # let go at the round's end, in front of the tick's block
+        assert puts + [("block",)] == events[events.index(("round_end",)) + 1:]
         assert [e[1] for e in puts[:BLOCK]] == ["A"] * BLOCK
         stats = b.tick_phase_stats()
         if exit_ == "head_does_not_fit":
@@ -385,10 +458,13 @@ def test_a_drain_that_dispatches_no_chunk_lets_go_at_the_ticks_end(
     held_ticks(tape)
 
 
-@pytest.mark.parametrize("where", ["claim", "chunk"])
+@pytest.mark.parametrize("where", ["claim", "chunk", "block"])
 @hard_timeout(300)
 def test_a_failure_under_a_hold_sends_the_tokens_first_then_the_exception(
         engines, monkeypatch, where):
+    """``block``: the dispatch between ``finish_join`` and the first
+    token's read fails — the hold is still open, the token unread and
+    counted nowhere: the joiner gets the exception alone."""
     tape = Tape()
     b = make_batcher(engines, True, tape, monkeypatch)
     boom = RuntimeError("the join broke")
@@ -401,6 +477,8 @@ def test_a_failure_under_a_hold_sends_the_tokens_first_then_the_exception(
         decoding(b, tape, a)
         if where == "claim":
             b._claim_slot = broken
+        elif where == "block":
+            b._dispatch_block = broken
         else:
             monkeypatch.setattr(b.engine, "prefill_slot", lambda: broken)
         b._waiting.append(d)
@@ -415,14 +493,17 @@ def test_a_failure_under_a_hold_sends_the_tokens_first_then_the_exception(
     assert len(got_a) - 2 == stats["tokens_emitted"] == 2 * BLOCK
     # (a request whose claim raised is in no list _fail_all walks, as on
     # the parent: its consumer's deadline ends it)
-    assert got_d == ([boom] if where == "chunk" else [])
+    assert got_d == ([] if where == "claim" else [boom])
+    assert stats["join_first_reads"] == {"behind_block": 1, "before_block": 0}
     assert stats["emit_held"] == {"chunk": 0, "tick_end": 0, "fail": BLOCK}
     assert stats["emit_holds"] == 1
     events = tape.ticks()[-1]
     drain = events.index(("drain", "admit", "block"))
     puts = [e[1:] for e in events[drain:] if e[0] == "put"]
     assert puts[:BLOCK] == [("A", t) for t in got_a[-1 - BLOCK:-1]]
-    assert puts[BLOCK:] == [("A", boom), ("D", boom)][:1 + (where == "chunk")]
+    assert puts[BLOCK:] == [("A", boom), ("D", boom)][:1 + (where != "claim")]
+    assert (("finish_join",) in events) == (where == "block")
+    assert not any(e[0] == "read" for e in events)
 
 
 @hard_timeout(300)
@@ -565,6 +646,227 @@ def test_a_speculative_rounds_harvest_is_held_alike(engines, monkeypatch):
     assert together == apart
     assert [len(v) for v in together.values()] == [31, 13]
     assert spec["rounds"] > 0
-    held = held_ticks(tape)
+    # the next round's guess is built from host history: every first token
+    # is read in front of the round (or the plain block in its place)
+    held = held_ticks(tape, behind=False)
     assert held and any(events[d][2] == "spec" for events, d, _ in held)
+    assert any(e == ("spec",) for events, _, _ in held for e in events)
     assert stats["emit_held"]["chunk"] > 0 and stats["emit_held"]["fail"] == 0
+    assert stats["join_first_reads"] == {"behind_block": 0, "before_block": 2}
+
+
+# ------------------------------------------------ the parent's order, to the bit
+def everything(req):
+    """A stream as its consumer finds it, log-probabilities and all: the
+    first token's lazy device row, a block token's summary, END."""
+    out = []
+    while True:
+        try:
+            item = req.out.get_nowait()
+        except queue.Empty:
+            return out
+        if item is None or isinstance(item, BaseException):
+            out.append(END if item is None else item)
+            continue
+        tok, lp = item
+        if lp is not None and not isinstance(lp, jax.Array):
+            lp = (lp.chosen, lp.top_indices, lp.top_values)
+        out.append((int(tok), None if lp is None else jax.tree.map(np.asarray, lp)))
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("chunks", [1, 3])
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+@hard_timeout(300)
+def test_token_ids_and_log_probabilities_are_those_of_the_read_in_front(
+        engines, monkeypatch, paged, chunks, sampled):
+    """The same arrivals under both orders: the block in front of the read,
+    and the parent's (every first token read, emitted and, where it ends
+    its stream, finished before the block is dispatched), which
+    ``_block_leads`` answering no gives back. A and C join in one tick with
+    nothing decoding; D (``chunks`` chunks), E and F (``max_tokens`` 1)
+    join a decoding batch. Every stream, item for item: token ids, the
+    first token's log-probability row, each block token's summary."""
+    sampler = SAMPLED if sampled else {}
+    prompts = dict(PROMPTS, D=list(range(40, 40 + chunks * CHUNK - 3)), F=[8, 8, 1])
+    max_tokens = dict(MAX_TOKENS, F=1)
+    got = {}
+    for order in ("behind_block", "before_block"):
+        tape = Tape()
+        b = make_batcher(engines, paged, tape, monkeypatch)
+        if order == "before_block":
+            b._block_leads = lambda: False
+        try:
+            reqs = {n: request(b, tape, n, prompts[n], max_tokens[n], **sampler)
+                    for n in "ACDEF"}
+            for req in reqs.values():
+                req.want_logprobs = True
+            drive(b, tape, {0: [reqs["A"], reqs["C"]],
+                            2: [reqs["D"], reqs["E"], reqs["F"]]})
+            stats = b.tick_phase_stats()
+        finally:
+            b.close()
+        assert stats["join_first_reads"][order] == 5 == sum(
+            stats["join_first_reads"].values())
+        closed = [closing_tick(t, behind=order == "behind_block") for t in tape.ticks()]
+        assert sum(closed) == 5 and closed[0] == 2
+        got[order] = {n: everything(req) for n, req in reqs.items()}
+    new, old = got["behind_block"], got["before_block"]
+    for n in "ACDEF":
+        assert len(new[n]) == len(old[n]) == max_tokens[n] + 1 and new[n][-1] is END, n
+        for (tok, lp), (tok0, lp0) in zip(new[n][:-1], old[n][:-1]):
+            assert tok == tok0, n
+            assert jax.tree.structure(lp) == jax.tree.structure(lp0), n
+            for x, x0 in zip(jax.tree.leaves(lp), jax.tree.leaves(lp0)):
+                np.testing.assert_array_equal(x, x0, err_msg=n)
+        assert new[n][0][1].shape[-1] == TINY["vocab_size"]  # the first token's row
+        if len(new[n]) > 2:
+            assert len(new[n][1][1]) == 3  # a block token's summary
+
+
+# ------------------------------------------------- the read's place, and exits
+@pytest.mark.parametrize("why", ["prefill_only", "sync_tick", "growth_may_preempt"])
+@hard_timeout(300)
+def test_a_read_the_host_must_act_on_stays_in_front_of_the_block(
+        engines, alone, monkeypatch, why):
+    """A decodes, D joins: D's first token is read before the tick's block
+    where the slot must never enter one (``prefill_only``), where the tick
+    is the synchronous one, and where page growth might preempt."""
+    from mlx_sharding_tpu.scheduler import HandoffReadyError
+
+    tape = Tape()
+    kw = {"sync_tick": dict(async_sched="off"),
+          "growth_may_preempt": dict(overcommit=True)}.get(why, {})
+    b = make_batcher(engines, True, tape, monkeypatch, **kw)
+    try:
+        a = request(b, tape, "A", PROMPTS["A"], 20)
+        d = request(b, tape, "D", PROMPTS["E"], 7)
+        d.prefill_only = why == "prefill_only"
+        b._waiting.append(a)
+        tick(b, tape)
+        tick(b, tape)
+        if why == "growth_may_preempt":
+            b._growth_fits = lambda: False  # what the tick observes
+        b._waiting.append(d)
+        tick(b, tape)
+        events = tape.ticks()[-1]
+        assert closing_tick(events, behind=False) == 1
+        if why == "growth_may_preempt":
+            del b._growth_fits
+        drive(b, tape, {}, admitted=[a, d])
+        stats = b.tick_phase_stats()
+    finally:
+        b.close()
+    assert received(a) == alone(True, False, PROMPTS["A"], 20)
+    got = received(d)
+    want = alone(True, False, PROMPTS["E"], 7)
+    if why == "prefill_only":
+        # its first token, then the handoff: the slot never entered a block
+        assert got[:1] == want[:1] and isinstance(got[1], HandoffReadyError)
+        assert len(got) == 2
+    else:
+        assert got == want
+    # A's own join, alone in tick 0, read behind its block but in the sync tick
+    assert closing_tick(tape.ticks()[0], behind=why != "sync_tick") == 1
+    assert stats["join_first_reads"] == {
+        "behind_block": int(why != "sync_tick"),
+        "before_block": 1 + (why == "sync_tick")}
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+@hard_timeout(300)
+def test_a_first_token_that_ends_its_stream_finishes_it_behind_the_block(
+        engines, alone, monkeypatch, paged, sampled):
+    """``max_tokens`` 1: the slot is live in the block dispatched in front
+    of the read, ``_finish`` runs after it, the block's positions for the
+    slot are dropped as ``slot_finished`` and its pages are back at once."""
+    tape = Tape()
+    b = make_batcher(engines, paged, tape, monkeypatch)
+    sampler = SAMPLED if sampled else {}
+    try:
+        a = request(b, tape, "A", PROMPTS["A"], 20, **sampler)
+        d = request(b, tape, "D", PROMPTS["E"], 1, **sampler)
+        e = request(b, tape, "E", PROMPTS["C"], 5, **sampler)
+        decoding(b, tape, a)
+        before = b.tick_phase_stats()
+        free = len(b._free_pages) if paged else 0
+        b._waiting.append(d)
+        tick(b, tape)
+        events = tape.ticks()[-1]
+        assert closing_tick(events) == 1
+        assert events[-2:] == [("put", "D", events[-2][2]), ("put", "D", END)]
+        assert d.slot == -1 and b._inflight is not None
+        assert [slot for slot, r in b._inflight.live if r is d]  # live in it
+        if paged:
+            assert len(b._free_pages) == free
+        # the next joiner takes the slot while that block is still unread
+        drive(b, tape, {0: [e]}, admitted=[a, d])
+        stats = b.tick_phase_stats()
+    finally:
+        b.close()
+    for req, n in ((a, "A"), (d, "E"), (e, "C")):
+        assert received(req) == alone(paged, sampled, PROMPTS[n], req.max_tokens), n
+    dropped = (stats["tokens_dropped"]["slot_finished"]
+               - before["tokens_dropped"]["slot_finished"])
+    assert dropped >= BLOCK and stats["tokens_dropped"]["cancelled"] == 0
+    assert stats["join_first_reads"] == {"behind_block": 3, "before_block": 0}
+    held_ticks(tape)
+
+
+@pytest.mark.parametrize("chunks", [1, 3])
+@hard_timeout(300)
+def test_a_joiner_cancelled_between_the_dispatch_and_the_read_loses_no_token(
+        engines, alone, monkeypatch, chunks):
+    """The consumer walks away while the block is being dispatched: the
+    first token was sampled and is emitted as on the parent, the next
+    tick reaps the slot, and the block's positions for it are dropped."""
+    tape = Tape()
+    b = make_batcher(engines, True, tape, monkeypatch)
+    prompt = list(range(40, 40 + chunks * CHUNK - 3))
+    try:
+        a = request(b, tape, "A", PROMPTS["A"], 20)
+        d = request(b, tape, "D", prompt, 9)
+        decoding(b, tape, a)
+        dispatch = b._dispatch_block
+
+        def cancel_behind():
+            inf = dispatch()
+            d.cancelled = d.cancelled or b._prefill_done(d)
+            return inf
+        b._dispatch_block = cancel_behind
+        drive(b, tape, {0: [d]}, admitted=[a])
+        stats = b.tick_phase_stats()
+    finally:
+        b.close()
+    assert received(a) == alone(True, False, PROMPTS["A"], 20)
+    assert received(d) == [alone(True, False, prompt, 9)[0], END]
+    assert stats["tokens_dropped"]["cancelled"] >= BLOCK
+    assert stats["join_first_reads"] == {"behind_block": 2, "before_block": 0}
+    assert len(held_ticks(tape)) == chunks
+
+
+@hard_timeout(300)
+def test_a_fault_at_the_harvest_behind_a_join_sends_the_tokens_first(engines, monkeypatch):
+    """``scheduler.harvest`` fires at the read of the block that was
+    dispatched in front of the joiner's first token: both streams have
+    every token counted (the joiner its first), then the exception."""
+    tape = Tape()
+    b = make_batcher(engines, True, tape, monkeypatch)
+    try:
+        a = request(b, tape, "A", PROMPTS["A"], 20)
+        d = request(b, tape, "D", PROMPTS["E"], 7)
+        decoding(b, tape, a)
+        b._waiting.append(d)
+        tick(b, tape)
+        assert closing_tick(tape.ticks()[-1]) == 1
+        faults.arm("scheduler.harvest", exc=faults.FaultError, times=1)
+        tick(b, tape)
+        stats = b.tick_phase_stats()
+    finally:
+        b.close()
+    got_a, got_d = received(a), received(d)
+    assert isinstance(got_a[-1], faults.FaultError) and got_d[-1] is got_a[-1]
+    assert len(got_d) == 2 and len(got_a) - 2 == stats["tokens_emitted"] == 2 * BLOCK
+    assert stats["blocks_abandoned"] == 2  # the one read, the one behind it
+    assert stats["emit_held"] == {"chunk": BLOCK, "tick_end": 0, "fail": 0}

@@ -80,6 +80,7 @@ def join(batcher, req, slot):
     batcher._assign_slot(req, slot)
     while not batcher._prefill_done(req):
         batcher._prefill_one_chunk(req)
+    batcher._read_first_tokens("before_block")  # the async tick's own call
     tok, logprobs = req.out.get_nowait()
     return tok, np.asarray(logprobs)
 

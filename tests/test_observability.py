@@ -117,6 +117,7 @@ def _fake_tick_phase_stats(path="async"):
         "drains": {"admit": 2, "idle": 1},
         "blocks_by_sampler": {"greedy": 6, "draw": 0, "nucleus": 2},
         "join_programs": {"claim": 4, "chunk": 9, "finish": 4, "other": 0},
+        "join_first_reads": {"behind_block": 3, "before_block": 1},
         "emit_held": {"chunk": 31, "tick_end": 2, "fail": 0},
         "emit_hold_seconds": 0.0075, "emit_holds": 5,
         "program_device_seconds": {"block": 9.25, "chunk": 0.5, "other": 0.0},
@@ -159,6 +160,11 @@ def test_metrics_expose_tick_timing():
     assert 'mst_decode_blocks_total{sampler="nucleus"} 2' in text
     assert 'mst_join_programs_total{program="chunk"} 9' in text
     assert 'mst_join_programs_total{program="other"} 0' in text
+    # joins by where their first token's read fell: both labels, always
+    assert "# TYPE mst_join_first_reads_total counter" in text
+    assert "# HELP mst_join_first_reads_total Joins by where" in text
+    assert 'mst_join_first_reads_total{order="behind_block"} 3' in text
+    assert 'mst_join_first_reads_total{order="before_block"} 1' in text
     assert 'mst_emit_held_total{flush="chunk"} 31' in text
     assert 'mst_emit_held_total{flush="fail"} 0' in text
     assert "mst_emit_hold_seconds_sum 0.007500" in text

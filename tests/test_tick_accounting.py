@@ -157,7 +157,7 @@ def test_blocks_and_tokens_are_all_accounted_for(engine, mode):
             "phase_entries", "blocks_dispatched", "blocks_harvested",
             "blocks_abandoned", "positions_computed", "tokens_emitted",
             "tokens_dropped", "drains", "blocks_by_sampler", "join_programs",
-            "emit_held", "emit_hold_seconds", "emit_holds",
+            "join_first_reads", "emit_held", "emit_hold_seconds", "emit_holds",
             "program_device_seconds", "program_dispatch_exposed_seconds",
             "program_runs", "program_late", "program_unread_seconds"}
         # a join that drained a decoding batch held the drain's tokens until
@@ -176,6 +176,12 @@ def test_blocks_and_tokens_are_all_accounted_for(engine, mode):
         joins = s["join_programs"]
         assert joins["claim"] == joins["finish"] >= 3 and joins["other"] == 0
         assert joins["chunk"] == s["phase_entries"]["prefill_chunk"]
+        # each counted once by where its first token was read: behind the
+        # block its last chunk was followed by, or (the sync tick) before it
+        reads = s["join_first_reads"]
+        assert reads == {"behind_block": joins["finish"] * (mode == "on"),
+                         "before_block": joins["finish"] * (mode != "on")}
+        assert 1 <= s["phase_entries"]["first_token"] <= joins["finish"]
         assert s["path"] == ("async" if mode == "on" else "sync")
         assert set(s["device_empty_seconds"]) == set(tracing.TICK_PHASES)
     finally:
