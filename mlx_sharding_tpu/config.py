@@ -620,6 +620,88 @@ class Qwen3NextConfig(BaseConfig):
         return self.num_experts * self.moe_expert_share
 
 
+@dataclass
+class SdarMoeConfig(BaseConfig):
+    """SDAR-MoE (``sdar_moe``): Qwen3-MoE's decoder — GQA with per-head Q/K
+    RMSNorm before rotary, softmax-routed SwiGLU experts in every layer, the
+    top ``num_experts_per_tok`` renormalised, no shared expert — generating
+    by DIFFUSION OVER BLOCKS: the sequence is blocks of ``block_length``
+    positions, a query sees every key up to the end of its own block, a
+    forward computes a whole block whose unknown positions hold
+    ``mask_token_id``, and ``denoising_steps`` forwards fill them in by
+    ``remasking_strategy`` (``sequential`` | ``low_confidence_static`` |
+    ``low_confidence_dynamic`` with ``confidence_threshold``) before one more
+    forward commits the block's K/V (``mlx_sharding_tpu/diffusion.py``).
+
+    A layer may hold one chip's SHARE of the routed experts, as
+    :class:`NemotronHConfig` says: ``num_experts`` counts the experts held,
+    ``moe_expert_share`` the holders, ``moe_expert_share_index`` which one
+    this is. A checkpoint's own config (no share keys) is the whole model.
+    ``intermediate_size``, ``sliding_window`` and ``max_window_layers`` are
+    read and unused, as the published model leaves them."""
+
+    model_type: str = "sdar_moe"
+    head_dim: Optional[int] = 128
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1000000.0
+    max_position_embeddings: int = 32768
+    attention_bias: bool = False
+    decoder_sparse_step: int = 1
+    mlp_only_layers: Optional[list] = None
+    moe_intermediate_size: int = 768
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True
+    use_sliding_window: bool = False
+    hidden_act: str = "silu"
+    moe_expert_share: int = 1
+    moe_expert_share_index: int = 0
+    # generation (the family's generate.py; the catalog gives none of them)
+    block_length: int = 4
+    denoising_steps: int = 4
+    remasking_strategy: str = "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
+    mask_token_id: int = 151669
+
+    STRATEGIES = ("sequential", "low_confidence_static", "low_confidence_dynamic")
+
+    def __post_init__(self):
+        self.mlp_only_layers = list(self.mlp_only_layers or [])
+        wired = {
+            "decoder_sparse_step": 1, "mlp_only_layers": [], "norm_topk_prob": True,
+            "use_sliding_window": False, "hidden_act": "silu", "rope_scaling": None,
+            "tie_word_embeddings": False, "attention_bias": False,
+        }
+        for key, want in wired.items():
+            if getattr(self, key) != want:
+                raise ValueError(
+                    f"sdar_moe is wired for {key} = {want!r}, not "
+                    f"{getattr(self, key)!r}"
+                )
+        if self.remasking_strategy not in self.STRATEGIES:
+            raise ValueError(
+                f"remasking_strategy must be one of {self.STRATEGIES}, not "
+                f"{self.remasking_strategy!r}"
+            )
+        if self.block_length < 1 or self.block_length & (self.block_length - 1):
+            raise ValueError("block_length must be a power of two")
+        if not 1 <= self.denoising_steps <= self.block_length \
+                or self.block_length % self.denoising_steps:
+            raise ValueError("denoising_steps must divide block_length")
+        if not 0 <= self.mask_token_id < self.vocab_size:
+            raise ValueError(
+                f"mask_token_id {self.mask_token_id} lies outside the "
+                f"vocabulary of {self.vocab_size}"
+            )
+        if not 0 <= self.moe_expert_share_index < self.moe_expert_share:
+            raise ValueError("moe_expert_share_index must lie in [0, moe_expert_share)")
+        super().__post_init__()
+
+    @property
+    def router_width(self) -> int:
+        return self.num_experts * self.moe_expert_share
+
+
 # Arch-name resolution. Mirrors the reference's MODEL_REMAPPING
 # (shard/utils.py:14-17): mistral runs through the llama implementation.
 MODEL_REMAPPING = {
@@ -639,6 +721,7 @@ CONFIG_REGISTRY: dict[str, type] = {
     "granitemoehybrid": GraniteMoeHybridConfig,
     "kimi_linear": KimiLinearConfig,
     "qwen3_next": Qwen3NextConfig,
+    "sdar_moe": SdarMoeConfig,
 }
 
 

@@ -149,6 +149,9 @@ class Generator:
         decode_block: int = DEFAULT_DECODE_BLOCK,
         prompt_cache: bool = False,
     ):
+        from mlx_sharding_tpu import diffusion  # (it imports this module)
+
+        diffusion.refuse(model, diffusion.SINGLE_STREAM)
         self.model = model
         # Build-time projection fusion (keep-quantized loads, single-chip):
         # concatenate each declared group's packed triples along OUT so

@@ -53,18 +53,23 @@ def attend(
     logit_softcap: Optional[float] = None,  # gemma2.py attn softcapping
     sliding_window=None,  # int or traced scalar — gemma-2 alternating layers
     sinks: Optional[jax.Array] = None,  # reserved for attention-sink variants
+    block: Optional[int] = None,  # static: block-causal over blocks of it
 ) -> jax.Array:
     """Returns (B, T, Hq, Dv). Keys at positions > query position (or outside
     the sliding window, or beyond the valid prefix) contribute nothing.
+    With ``block`` (a model that generates by diffusion over blocks:
+    ``models/sdar_moe.py``) a query sees every key up to the END of its own
+    block of ``block`` positions, ``k_pos <= q_pos - q_pos % block + block -
+    1``; blocks start at multiples of ``block``.
 
     Prefill chunks that qualify route to the Pallas flash kernel
     (ops/flash_attention.py); everything else takes the fused-XLA path below."""
     if _flash_eligible(q, k, v, logit_softcap, sliding_window, sinks):
         from mlx_sharding_tpu.ops.flash_attention import flash_attention
 
-        return flash_attention(q, k, v, offset, scale)
+        return flash_attention(q, k, v, offset, scale, block=block)
     return _causal_attention_xla(
-        q, k, v, offset, scale, logit_softcap, sliding_window
+        q, k, v, offset, scale, logit_softcap, sliding_window, block
     )
 
 
@@ -74,8 +79,14 @@ def attend(
 causal_attention = jax.named_scope("mst.attn.core")(attend)
 
 
+def block_end(q_pos, block: Optional[int]):
+    """The last key position a query at ``q_pos`` sees: itself, or with
+    ``block`` the last position of its block."""
+    return q_pos if block is None else q_pos - q_pos % block + (block - 1)
+
+
 def _causal_attention_xla(
-    q, k, v, offset, scale, logit_softcap=None, sliding_window=None
+    q, k, v, offset, scale, logit_softcap=None, sliding_window=None, block=None
 ):
     """The fused-XLA path: every backend's fallback and the reference the
     flash kernel is checked against on the chip (chip_smoke.py)."""
@@ -95,7 +106,7 @@ def _causal_attention_xla(
 
     q_pos = offset + jnp.arange(t)[:, None]  # (T, 1)
     k_pos = jnp.arange(s)[None, :]  # (1, S)
-    allowed = k_pos <= q_pos
+    allowed = k_pos <= block_end(q_pos, block)
     if sliding_window is not None:
         allowed &= k_pos > q_pos - sliding_window
     scores = jnp.where(allowed[None, None, None], scores, -jnp.inf)

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mlx_sharding_tpu.ops.attention import block_end
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -65,7 +67,8 @@ def pick_block_q(t: int, s: int, dk: int, dv: int, itemsize: int,
     return best if best is not None else min(t, DEFAULT_BLOCK_Q)
 
 
-def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *, scale, block_q, block_k, s_len):
+def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *, scale, block_q, block_k, s_len,
+            block=None):
     q = q_ref[0, 0].astype(jnp.float32)  # (bq, dk)
     offset = off_ref[0]
     iq = pl.program_id(2)
@@ -74,6 +77,9 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *, scale, block_q, block_k, s_l
     q_pos = offset + iq * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, 1), 0
     )  # (bq, 1)
+    # the last key each query sees: itself, or the end of its block of
+    # ``block`` positions (ops/attention.py: block-causal)
+    q_end = block_end(q_pos, block)
     num_k_blocks = s_len // block_k
 
     def body(ik, carry):
@@ -90,7 +96,7 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *, scale, block_q, block_k, s_l
             k_pos = ik * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_k), 1
             )
-            s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+            s = jnp.where(k_pos <= q_end, s, NEG_INF)
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
             corr = jnp.exp(m - m_new)
@@ -102,7 +108,7 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *, scale, block_q, block_k, s_l
             return m_new, l, acc
 
         # skip K blocks entirely beyond this query tile's last position
-        last_q_pos = offset + (iq + 1) * block_q - 1
+        last_q_pos = block_end(offset + (iq + 1) * block_q - 1, block)
         return jax.lax.cond(
             ik * block_k <= last_q_pos, attend, lambda c: c, (m, l, acc)
         )
@@ -115,7 +121,7 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *, scale, block_q, block_k, s_l
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "block_q", "block_k", "interpret")
+    jax.jit, static_argnames=("scale", "block_q", "block_k", "interpret", "block")
 )
 def flash_attention(
     q: jax.Array,  # (B, T, Hq, Dk)
@@ -127,9 +133,10 @@ def flash_attention(
     block_q: int | None = None,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
+    block: int | None = None,
 ) -> jax.Array:
     """Drop-in for ops.attention.causal_attention on the standard causal/GQA
-    case. T must divide block_q*n and S must divide block_k*n (the callers'
+    case (``block``: its block-causal form). T must divide block_q*n and S must divide block_k*n (the callers'
     chunked-prefill invariants guarantee this for multiples of 128).
     ``block_q=None`` → the adaptive VMEM-budget picker."""
     b, t, hq, dk = q.shape
@@ -149,7 +156,8 @@ def flash_attention(
     grid = (b, hq, t // block_q)
     out = pl.pallas_call(
         functools.partial(
-            _kernel, scale=scale, block_q=block_q, block_k=block_k, s_len=s
+            _kernel, scale=scale, block_q=block_q, block_k=block_k, s_len=s,
+            block=block,
         ),
         grid=grid,
         in_specs=[

@@ -47,6 +47,7 @@ from mlx_sharding_tpu.cache import (
     refuse_recurrent,
     window_ring_rows,
 )
+from mlx_sharding_tpu import diffusion
 from mlx_sharding_tpu.ops.quant import dequantize, is_quantized
 from mlx_sharding_tpu.parallel.mesh import (
     AXIS_EP,
@@ -396,6 +397,20 @@ def place_weights(model, params, mesh, *, stage_bounds=None) -> ResidentWeights:
     )
 
 
+def fold_block_queries(attend, q, kv_heads: int):
+    """A block's ``T`` queries a slot through an attention that takes ONE
+    query a slot: ``q (M, T, Hq, D)`` becomes ``(M, Hkv * T * G, D)``, each
+    K/V head's ``T * G`` queries side by side — to the kernel a query group
+    ``T`` times as large, every member seeing the same keys — and the result
+    ``(M, Hkv * T * G, Dv)`` goes back to ``(M, T, Hq, Dv)``."""
+    m, t, hq, d = q.shape
+    g = hq // kv_heads
+    folded = q.reshape(m, t, kv_heads, g, d).transpose(0, 2, 1, 3, 4)
+    out = attend(folded.reshape(m, kv_heads * t * g, d))
+    out = out.reshape(m, kv_heads, t, g, -1).transpose(0, 2, 1, 3, 4)
+    return out.reshape(m, t, hq, -1)
+
+
 class PipelineEngine:
     """Runs a full (unsharded-config) model across a ``pp`` mesh axis.
 
@@ -491,6 +506,18 @@ class PipelineEngine:
         # weights resolve the stage split.
         self.has_state = has_slot_state(model)
         self.has_recurrent = has_recurrent_state(model)
+        # a model that generates by diffusion over blocks: a decode step is
+        # a forward over every slot's whole block (diffusion.py)
+        self.diffusion_block = diffusion.block_of(model)
+        for flag, on in (
+            ("--num-stages", self.num_stages > 1),
+            ("--tp", self.tp > 1),
+            ("--ep", mesh.shape.get(AXIS_EP, 1) > 1),
+            ("--kv-share-map", kv_share_map is not None),
+            ("--kv-compress-map", kv_compress_map is not None),
+        ):
+            if on:
+                diffusion.refuse(model, flag)
         # window layers keep their K/V as one ring per slot in the state
         # pool (cache.py): rows that do not grow with the context
         self.ring_rows = (
@@ -571,6 +598,14 @@ class PipelineEngine:
             "ragged" if paged_attention in ("auto", "ragged") and ragged_ok
             else "gather"
         )
+        if self.diffusion_block:
+            if self.paged_attention != "ragged":
+                diffusion.refuse(model, "--paged-pool")
+            if self.page_size % self.diffusion_block:
+                raise ValueError(
+                    f"a block of {self.diffusion_block} must divide the page "
+                    f"of {self.page_size}: a block's rows lie in one page"
+                )
         # run_layers parallelism kwargs, shared by every step body
         self._rl_kwargs = {}
         if self.tp > 1:
@@ -688,6 +723,8 @@ class PipelineEngine:
         self._sample = jax.jit(self._sample_fn, donate_argnums=(1,))
         # continuous-batching programs, built on first use by the scheduler
         self._decode_cb = None
+        self._diffusion_cbs: dict = {}  # want_lp → forward + epilogue
+        self._diffusion_body = None  # the ragged body at T = L, built once
         self._prefill_slot = None
         self._decode_blocks: dict = {}  # (k_steps, want_lp) → jitted block
         self._spec_progs: dict = {}  # ("propose"|"verify", K) → jitted prog
@@ -1342,6 +1379,15 @@ class PipelineEngine:
         from mlx_sharding_tpu.models.base import scan_layers_carried
         from mlx_sharding_tpu.ops.paged_attention import paged_attention
 
+        # rows a slot computes a step: 1, or the whole block of a model that
+        # generates by diffusion over blocks (diffusion.py). Its T rows are
+        # written at offset .. offset + T - 1 — offsets are multiples of T
+        # and T divides the page, so one page, never two — every one of its
+        # T queries sees the same keys [0, offset + T), and the queries are
+        # folded into the kernel's query-group axis: the kernel is called as
+        # it is, with a group T times as large and no mask of its own
+        T = self.diffusion_block or 1
+
         def body(layer_params, masks, vparts, shared, tokens, k, v,
                  offsets, active, n_valid, table, state):
             layer_params = jax.tree.map(lambda x: x[0], layer_params)
@@ -1379,7 +1425,9 @@ class PipelineEngine:
             row_pos = offset_m % page
             # valid prefix incl. the row written this tick; 0 zeroes the
             # garbage lanes' attention outright
-            lengths = jnp.where(active, offset_m + 1, 0).astype(jnp.int32)
+            lengths = jnp.where(active, offset_m + T, 0).astype(jnp.int32)
+            if T > 1:  # (M, T): slot m's T rows of its write page
+                rows_at = row_pos[:, None] + jnp.arange(T)[None, :]
             if self.ring_rows:
                 # a window layer's ring pool viewed as pages: slot m's ring
                 # is pages m * R .. (m + 1) * R, logical page j its page
@@ -1458,21 +1506,28 @@ class PipelineEngine:
                     def put(pool, new):
                         if quant:  # quantize the M rows, scatter both
                             new = quantize_kv_rows(new)
+                        at = (ids, row_pos) if T == 1 else (ids[:, None], rows_at)
                         return jax.tree.map(
-                            lambda p, n: p.at[ids, row_pos].set(
-                                n.astype(p.dtype)
-                            ),
+                            lambda p, n: p.at[at].set(n.astype(p.dtype)),
                             pool, new,
                         )
 
                     with jax.named_scope("mst.attn.kv_write"):
-                        kl = put(kl, k_new[:, 0])
-                        vl = put(vl, v_new[:, 0])
+                        kl = put(kl, k_new[:, 0] if T == 1 else k_new)
+                        vl = put(vl, v_new[:, 0] if T == 1 else v_new)
                         done["k"] = jax.tree.map(as_given, kl)
                         done["v"] = jax.tree.map(as_given, vl)
                     with jax.named_scope(scope):
                         kl = jax.tree.map(attended, kl)
                         vl = jax.tree.map(attended, vl)
+                        if T > 1:
+                            return fold_block_queries(
+                                lambda q1: paged_attention(
+                                    q1, kl, vl, tbl, lengths, model.scale,
+                                    **layout,
+                                ),
+                                q, layout["kv_heads"],
+                            )
                         out = paged_attention(
                             q[:, 0],
                             kl["d"] if quant else kl,
@@ -1684,6 +1739,37 @@ class PipelineEngine:
             return tok.reshape(M, B), logprobs, new_cache, recent, keys
 
         return jax.jit(decode_step, donate_argnums=(5, 7, 8))
+
+    def diffusion_cb(self, want_lp: bool):
+        """``decode_cb`` of a model that generates by diffusion over blocks:
+        one forward over every slot's whole block through the ragged body,
+        then ``diffusion.block_forward``. Takes the batcher's block state
+        where ``decode_cb``'s step takes the last tokens, and returns ``(out,
+        blk, cache, recent, keys)``: a slot's offset advances by a block
+        where the forward committed one."""
+        if want_lp not in self._diffusion_cbs:
+            cfg = self.model.config
+            if self._diffusion_body is None:  # one body for both variants
+                self._diffusion_body = self._build_smapped_ragged()
+            inner = self._diffusion_body
+            length = jnp.asarray(self.diffusion_block, jnp.int32)
+
+            def diffusion_step(layer_params, masks, vparts, shared, blk, cache,
+                               active, recent, keys, sp, rep_sizes, table):
+                logits, k, v, state = inner(
+                    layer_params, masks, vparts, shared, blk["ids"], cache.k,
+                    cache.v, cache.offset, active, length, table, cache.state,
+                )
+                out, blk, offset, recent, keys = diffusion.block_forward(
+                    blk, logits, cache.offset, active, recent, keys, sp,
+                    rep_sizes, cfg=cfg, want_lp=want_lp,
+                )
+                return out, blk, KVCache(
+                    k=k, v=v, offset=offset, state=state
+                ), recent, keys
+
+            self._diffusion_cbs[want_lp] = diffusion_step
+        return self._diffusion_cbs[want_lp]
 
     # ------------------------------------ speculative continuous batching
     def spec_propose_cb(self, K: int):

@@ -62,7 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from mlx_sharding_tpu import tracing
+from mlx_sharding_tpu import diffusion, tracing
 from mlx_sharding_tpu.analysis import runtime as mst_runtime
 from mlx_sharding_tpu.analysis.runtime import (
     make_lock,
@@ -79,7 +79,11 @@ from mlx_sharding_tpu.cache import (
     refuse_recurrent,
     rewind_slot_offset,
 )
-from mlx_sharding_tpu.generate import block_lp_outputs, block_token_logprobs
+from mlx_sharding_tpu.generate import (
+    TokenLogprobs,
+    block_lp_outputs,
+    block_token_logprobs,
+)
 from mlx_sharding_tpu.kv_transfer import KVSpillTier, export_block, import_block
 from mlx_sharding_tpu.resilience import (
     Deadlines,
@@ -204,6 +208,9 @@ class _Request:
     _t_submit: float = 0.0
     _t_join: float = 0.0  # slot claimed (mst_join_seconds runs from here)
     _t_last_emit: float = 0.0
+    # diffusion over blocks: positions of the slot's NEXT committed block
+    # that are prompt (the P mod L tokens no whole block held; 0 after it)
+    _block_skip: int = 0
 
 
 def _pack_i32(*parts) -> np.ndarray:
@@ -356,6 +363,21 @@ class ContinuousBatcher:
         self._slot_state = has_slot_state(engine.model)
         self._ring_pages = getattr(engine, "ring_rows", 0) // engine.page_size
         self.ring_wraps = 0  # ring pages overwritten (one per slot and page)
+        # A model that generates by diffusion over blocks (diffusion.py): a
+        # decode step is a forward over every slot's whole block, the carry
+        # between blocks' programs is the block and its mask where it was
+        # the last token, a prefill yields no token, and a harvest hands a
+        # slot the blocks its forwards committed.
+        self._diffusion = diffusion.block_of(engine.model)
+        for flag, on in (
+            ("--prompt-cache", prefix_cache),
+            ("--prefix-store", prefix_store is not None),
+            ("--spill-bytes", spill_bytes is not None),
+            ("--overcommit", overcommit),
+            ("--draft", draft not in ("auto", "off") or draft_engine is not None),
+        ):
+            if on:
+                diffusion.refuse(engine.model, flag)
         for flag, on, why in (
             ("--prompt-cache", prefix_cache,
              "a prefix hit starts a slot past pages whose state nobody kept"),
@@ -700,6 +722,24 @@ class ContinuousBatcher:
             return (tok, logprobs, keys, recent,
                     last_tok.at[slot, 0].set(tok), active.at[slot].set(True))
 
+        def finish_join_block(logits, packed, keys, recent, sp, rep_sizes,
+                              blk, active):
+            """``finish_join`` of a family whose prefill yields no token:
+            the slot's first decode block [prompt tail | masks] takes the
+            last token's place. What it hands back as the "token" is no
+            token (negative): its read is the wait for the join's last
+            chunk, as a first token's is."""
+            u = _Unpack(packed)
+            slot, keys, recent = seed_rows(u, keys, recent)
+            length = blk["ids"].shape[1]
+            blk = dict(blk)
+            blk["ids"] = blk["ids"].at[slot].set(u.take((length,)))
+            blk["masked"] = blk["masked"].at[slot].set(u.take((length,)) > 0)
+            none = jnp.asarray(-1, jnp.int32) if logits is None else jnp.where(
+                jnp.isnan(logits.reshape(-1)[0]), -2, -1
+            ).astype(jnp.int32)
+            return none, None, keys, recent, blk, active.at[slot].set(True)
+
         def resume_slot(packed, keys, recent, last_tok, active):
             u = _Unpack(packed)
             slot, keys, recent = seed_rows(u, keys, recent)
@@ -708,7 +748,10 @@ class ContinuousBatcher:
 
         self._row_set = jax.jit(row_set)
         self._claim_slot = jax.jit(claim_slot, donate_argnums=(1, 2, 3, 4, 5))
-        self._finish_join = jax.jit(finish_join, donate_argnums=(2, 3, 6, 7))
+        self._finish_join = jax.jit(
+            finish_join_block if self._diffusion else finish_join,
+            donate_argnums=(2, 3, 6, 7),
+        )
         self._resume_slot = jax.jit(resume_slot, donate_argnums=(1, 2, 3, 4))
         self._zeros_like = jax.jit(jnp.zeros_like)
         self._rewind_offset = jax.jit(rewind_slot_offset)
@@ -904,6 +947,16 @@ class ContinuousBatcher:
         self._tokens_dropped = {
             "slot_finished": 0, "cancelled": 0, "abandoned_block": 0,
         }
+        if self._diffusion:
+            # ... and denoise: a forward's rows that were not a commit's new
+            # tokens. The family's own account, counted at the harvest: live
+            # slots x forwards, blocks committed, positions transferred by
+            # rank (the strategy's n a forward) or by passing the threshold
+            self._tokens_dropped["denoise"] = 0
+            self._diffusion_stats = dict.fromkeys(
+                ("slot_forwards", "blocks_committed", "by_rank",
+                 "by_confidence"), 0
+            )
         # first prefill chunks dispatched for a model with recurrent state:
         # each starts its slot's state from zero (inside the chunk's program)
         self.state_resets = 0
@@ -1068,7 +1121,12 @@ class ContinuousBatcher:
         ))
         self.rep_sizes = place(jnp.full((self.M,), self.W, jnp.int32))
         self.active = place(jnp.zeros((self.M,), bool))
-        self.last_tok = place(jnp.zeros((self.M, 1), jnp.int32))
+        # a decode block's first input: every slot's last token — or, for a
+        # family that generates by diffusion over blocks, its block
+        self.last_tok = place(
+            diffusion.init_block(self.M, self._diffusion) if self._diffusion
+            else jnp.zeros((self.M, 1), jnp.int32)
+        )
 
         # host-side slot table
         self._slots: list[Optional[_Request]] = [None] * self.M
@@ -1137,7 +1195,9 @@ class ContinuousBatcher:
                     f"history carries {len(hist)} tokens"
                 )
             block = _resume.block
-            if block is not None and (not self.paged or self.draft is not None):
+            if block is not None and (
+                not self.paged or self.draft is not None or self._diffusion
+            ):
                 block = None  # no pool to import into; fall back to fold
             # Capture the stashed sampler rows even when a block rides along:
             # if its import fails on this engine the admission path degrades
@@ -1205,6 +1265,7 @@ class ContinuousBatcher:
             prefill_only=bool(_prefill_only),
         )
         if _prefill_only:
+            diffusion.refuse(self.engine.model, "--disagg")
             refuse_recurrent(
                 self.engine.model, "--disagg",
                 "the prefill-to-decode hand-off moves pages of K/V only",
@@ -1485,7 +1546,12 @@ class ContinuousBatcher:
 
     def _pages_needed(self, n_prompt: int, max_tokens: int) -> int:
         page = self.engine.page_size
-        return -(-(n_prompt + max_tokens) // page)
+        total = n_prompt + max_tokens
+        if self._diffusion:
+            # a slot writes whole blocks: rows up to the end of the block
+            # that holds its last token
+            total = -(-total // self._diffusion) * self._diffusion
+        return -(-total // page)
 
     def kv_read_stats(self) -> Optional[tuple[str, int, int]]:
         """(attention path, KV bytes read last tick, total) for /metrics;
@@ -1533,6 +1599,8 @@ class ContinuousBatcher:
             "program_runs": snap["runs"],
             "program_late": snap["late"],
             "program_unread_seconds": snap["unread_seconds"],
+            **({"diffusion": dict(self._diffusion_stats)}
+               if self._diffusion else {}),
         }
 
     def _tick_account(self) -> dict:
@@ -2376,6 +2444,15 @@ class ContinuousBatcher:
             chunk = np.pad(chunk, (0, c - n_valid))
         return chunk, n_valid
 
+    def _prefill_rows(self, req: _Request) -> int:
+        """Prompt tokens the join's chunks prefill: all of them — or, for a
+        family that generates by diffusion over blocks, the prompt's whole
+        blocks (the ``P mod L`` tokens left start the first decode block:
+        ``finish_join`` puts them there and moves ``prefill_pos`` to the
+        prompt's end)."""
+        n = req.prompt.size
+        return n - n % self._diffusion if self._diffusion else n
+
     def _prefill_done(self, req: _Request) -> bool:
         """Admission prefill complete on EVERY engine: the target (which may
         start past a reused prefix) and, when speculating, the draft (which
@@ -2406,8 +2483,9 @@ class ContinuousBatcher:
         tr = req._trace
         t0 = time.perf_counter() if tr is not None else 0.0
         ticket = None  # of the program dispatched last
-        if req.prefill_pos < req.prompt.size:
-            chunk, n_valid = self._chunk_at(req.prompt, req.prefill_pos, c)
+        rows = self._prefill_rows(req)
+        if req.prefill_pos < rows:
+            chunk, n_valid = self._chunk_at(req.prompt[:rows], req.prefill_pos, c)
             if self._recurrent and req.prefill_pos == 0:
                 self.state_resets += 1
             self._note_ring_page(req.prefill_pos)
@@ -2424,7 +2502,7 @@ class ContinuousBatcher:
             self._chunk_unread = (ticket, logits)
             self._join_programs["chunk"] += 1
             req.prefill_pos += n_valid
-            if req.prefill_pos >= req.prompt.size:
+            if req.prefill_pos >= rows:
                 req._last_logits = logits
         if self.draft is not None and req.draft_pos < req.prompt.size:
             d = self.draft
@@ -2442,7 +2520,9 @@ class ContinuousBatcher:
         if tr is not None:
             tr.add("prefill", t0, time.perf_counter(), slot=req.slot,
                    pos=req.prefill_pos, chunk=c)
-        if not self._prefill_done(req):
+        if req.prefill_pos < rows or (
+            self.draft is not None and req.draft_pos < req.prompt.size
+        ):
             # the device has the chunk: the drain's tokens leave now, and
             # the streams write them out while it computes
             self._flush_held("chunk")
@@ -2487,15 +2567,24 @@ class ContinuousBatcher:
         else:
             key_row = seed_key_row(req.seed)  # jax.random.PRNGKey's words
             recent_row = np.full((self.W,), -1, np.int32)
-            tail = (
-                req.prompt[-req.rep_context:] if req.rep_context
-                else req.prompt[:0]
-            )
+            seen = req.prompt[:rows]  # a first block's prompt tail enters
+            # the window with its block, at the commit
+            tail = seen[-req.rep_context:] if req.rep_context else seen[:0]
             if tail.size:
                 recent_row[self.W - tail.size:] = tail
+        first = ()
+        if self._diffusion:
+            # the first decode block: the prompt's tail, then masks
+            first = diffusion.first_block(
+                req.prompt[rows:], self._diffusion,
+                self.engine.model.config.mask_token_id,
+            )
+            req._block_skip = req.prompt.size - rows
+            req.prefill_pos = req.prompt.size  # the slot decodes from here
         (tok, logprobs, self.keys, self.recent, self.last_tok,
          self.active) = self._finish_join(
-            logits, put(_pack_i32(np.int32(req.slot), key_row, recent_row)),
+            logits,
+            put(_pack_i32(np.int32(req.slot), key_row, recent_row, *first)),
             self.keys, self.recent, self.sp, self.rep_sizes,
             self.last_tok, self.active,
         )
@@ -2541,12 +2630,14 @@ class ContinuousBatcher:
             for req, tok, logprobs, ticket in firsts:
                 # the blocking read of the chunk and its sample
                 tok = int(tok)
-                unread = self._chunk_unread
-                if unread is not None and unread[0] <= ticket:
-                    self._chunk_unread = None
-                self._phases.ready(ticket)
+                if ticket is not None:  # (None: a join that ran no chunk)
+                    unread = self._chunk_unread
+                    if unread is not None and unread[0] <= ticket:
+                        self._chunk_unread = None
+                    self._phases.ready(ticket)
                 self._join_first_reads[order] += 1
-                self._emit(req, tok, logprobs)
+                if tok >= 0:  # (negative: this family's prefill yields none)
+                    self._emit(req, tok, logprobs)
                 self._h_join.observe(time.perf_counter() - req._t_join)
                 if req.prefill_only and req.slot >= 0:
                     # disaggregated handoff: the first token is the prefill
@@ -2640,7 +2731,11 @@ class ContinuousBatcher:
                 # last release demotes the prefix to the host tier
                 # (dispatch-only export; the flusher does the host copy)
                 self._drop_prefix_lease(req)
-                if self._inflight is not None:
+                if self._inflight is not None and not self._diffusion:
+                    # (a diffusion slot's offset is only ever read through
+                    # the ragged body, which sends a dead slot's rows to
+                    # the scratch page whatever its offset says, and the
+                    # next claim sets it)
                     # the in-flight block's frozen active mask advances this
                     # dead slot's offset one block past its true end; queue
                     # a rewind CHAINED AFTER it (self.cache is its output
@@ -2691,12 +2786,25 @@ class ContinuousBatcher:
         streams are unaffected and serial parity holds)."""
         if want_lp not in self._decode_block_progs:
             eng = self.engine
-            step, M = eng.decode_cb(), self.M
+            M = self.M
+            step = (
+                eng.diffusion_cb(want_lp) if self._diffusion else eng.decode_cb()
+            )
 
             def block(layer_params, masks, vparts, shared, tok, cache, active,
                       recent, keys, sp, rep_sizes, table):
                 def body(carry, _):
                     tok, cache, recent, keys = carry
+                    if self._diffusion:
+                        # a step is one forward over every slot's block;
+                        # ``tok`` is the blocks and their masks, and what
+                        # the host reads of a forward is a tree
+                        # (diffusion.block_forward)
+                        out, tok, cache, recent, keys = step(
+                            layer_params, masks, vparts, shared, tok, cache,
+                            active, recent, keys, sp, rep_sizes, table,
+                        )
+                        return (tok, cache, recent, keys), out
                     tok, logprobs, cache, recent, keys = step(
                         layer_params, masks, vparts, shared, tok, cache,
                         active, recent, keys, sp, rep_sizes, table,
@@ -3099,6 +3207,7 @@ class ContinuousBatcher:
             block = self.spill.take(req) if self.spill is not None else None
         if (block is None and slot >= 0 and self.paged
                 and self.draft is None and not self._slot_state
+                and not self._diffusion  # (blockless: the fold re-prefills)
                 and self._prefill_done(req) and req.history):
             page = self.engine.page_size
             n_tokens = req.prompt.size + max(0, len(req.history) - 1)
@@ -3298,7 +3407,9 @@ class ContinuousBatcher:
         block = self._decode_block_prog(want_lp)
         seq = self._blocks_dispatched
         self._blocks_dispatched += 1
-        positions = self.decode_block * len(live)
+        # a forward computes a position a live row — a block of them where
+        # the family generates by diffusion over blocks
+        positions = self.decode_block * len(live) * (self._diffusion or 1)
         self._positions_computed += positions
         args = {}
         if self._trace_profile:
@@ -3392,6 +3503,9 @@ class ContinuousBatcher:
         """The host-side consequences of a harvested block. ``t0``/``t1``
         are the harvest wait's own stamps, reused for the traced requests'
         spans — no extra clock reads on this path."""
+        if self._diffusion:
+            self._emit_committed(inf, outs, t0, t1)
+            return
         toks = outs[0]  # (K, M, 1)
         live = inf.live
         for _, _req in live:
@@ -3445,6 +3559,68 @@ class ContinuousBatcher:
             self._tokens_dropped["cancelled"] += cancelled
             # an emit that raised leaves the rest of the block undelivered
             self._tokens_dropped["abandoned_block"] += left
+
+    def _emit_committed(self, inf: _InflightBlock, outs, t0, t1):
+        """``_emit_block`` for a family that generates by diffusion over
+        blocks: ``outs`` is ``diffusion.block_forward``'s tree over the
+        program's forwards, and a slot is handed the blocks its forwards
+        committed — none, or a few of ``L`` tokens each — in order. A
+        position's log-probabilities are those of the forward that
+        transferred it. Every position a forward computed is emitted or
+        dropped, counted here: ``denoise`` is a forward's rows that were not
+        a commit's new tokens (a denoise forward's, a first block's prompt
+        tail), the other reasons as ever."""
+        L = self._diffusion
+        commit, ids = outs["commit"], outs["ids"]  # (K, M), (K, M, L)
+        live = inf.live
+        stats = self._diffusion_stats
+        stats["slot_forwards"] += commit.shape[0] * len(live)
+        slots = [slot for slot, _ in live]
+        stats["by_rank"] += int(outs["by_rank"][:, slots].sum())
+        stats["by_confidence"] += int(outs["by_confidence"][:, slots].sum())
+        left, emitted, denoise, finished, cancelled = inf.positions, 0, 0, 0, 0
+        commits = dict.fromkeys(slots, 0)
+        try:
+            for j in range(commit.shape[0]):
+                for slot, req in live:
+                    left -= L
+                    if req.slot != slot:  # the slot was left earlier
+                        if req.produced >= req.max_tokens:
+                            finished += L
+                        else:
+                            cancelled += L
+                        continue
+                    if not commit[j, slot]:
+                        denoise += L
+                        continue
+                    stats["blocks_committed"] += 1
+                    commits[slot] += 1
+                    skip, req._block_skip = req._block_skip, 0
+                    denoise += skip
+                    for i in range(skip, L):
+                        if req.slot != slot:  # max_tokens fell inside the block
+                            finished += 1
+                            continue
+                        lp = None
+                        if inf.want_lp and req.want_logprobs:
+                            lp = TokenLogprobs(
+                                float(outs["lp_chosen"][j, slot, i]),
+                                outs["lp_top_i"][j, slot, i],
+                                outs["lp_top_v"][j, slot, i],
+                            )
+                        emitted += 1
+                        self._emit(req, int(ids[j, slot, i]), lp)
+        finally:
+            self._tokens_emitted += emitted
+            self._tokens_dropped["denoise"] += denoise
+            self._tokens_dropped["slot_finished"] += finished
+            self._tokens_dropped["cancelled"] += cancelled
+            self._tokens_dropped["abandoned_block"] += left
+        for slot, req in live:
+            tr = req._trace
+            if tr is not None:
+                tr.add("denoise", t0, t1, slot=slot,
+                       forwards=int(commit.shape[0]), commits=commits[slot])
 
     def _decode_once(self):
         # the sync composition point — MultiHostBatcher overrides THIS to
@@ -4055,12 +4231,13 @@ class ContinuousBatcher:
                     rid=req.slot if tr is None else tr.request_id,
                     pos=req.prefill_pos,
                     n_valid=max(0, min(self.engine.prefill_chunk,
-                                       req.prompt.size - req.prefill_pos)),
+                                       self._prefill_rows(req)
+                                       - req.prefill_pos)),
                     # the join's closing chunk (the first token's read
                     # waits on it) or a middle one
                     last=int(
                         req.prefill_pos + self.engine.prefill_chunk
-                        >= req.prompt.size
+                        >= self._prefill_rows(req)
                         and (self.draft is None or req.draft_pos
                              + self.engine.prefill_chunk >= req.prompt.size)
                     ),

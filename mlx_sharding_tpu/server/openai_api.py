@@ -35,7 +35,7 @@ from typing import Optional
 
 import numpy as np
 
-from mlx_sharding_tpu import tracing
+from mlx_sharding_tpu import diffusion, tracing
 from mlx_sharding_tpu.analysis.runtime import make_lock
 from mlx_sharding_tpu.cache import refuse_recurrent
 from mlx_sharding_tpu.generate import TokenLogprobs
@@ -509,6 +509,9 @@ class ModelProvider:
                         model, "--disagg",
                         "the prefill-to-decode hand-off moves pages of K/V only",
                     )
+                    diffusion.refuse(model, "--disagg")
+                if self.concurrent > 1 and not self.paged_pool:
+                    diffusion.refuse(model, "--paged-pool")
                 if (
                     stages > 1 or self.concurrent > 1 or self.tp > 1
                     or self.ep > 1 or self.replicas > 1 or self.disagg

@@ -53,6 +53,10 @@ SPAN_TYPES = (
     "handoff_transfer",
     "handoff_import",
     "decode_tick",
+    # diffusion over blocks: a harvested decode program of such a family, in
+    # decode_tick's place (``forwards`` it ran, ``commits``: blocks of the
+    # request it committed and handed on)
+    "denoise",
     "spill",
     "wake",
     "prefetch",
@@ -145,6 +149,11 @@ MODEL_SCOPES = (
     "mst.kv_pool.regroup",
     "mst.head",
     "mst.sample",
+    # a decode forward's epilogue where the family generates by diffusion
+    # over blocks (diffusion.py): which masked positions take their sampled
+    # token, the block's next ids and mask, the commit (behind mst.sample,
+    # which samples every position of the block)
+    "mst.diffusion.unmask",
 )
 
 # hard bound per trace: a runaway stream degrades to a truncated timeline

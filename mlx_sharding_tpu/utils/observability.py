@@ -233,6 +233,19 @@ def _render_tick_phases(lines: list, t: dict):
     labelled("mst_program_runs_total", "program", t["program_runs"])
     lines.append("# TYPE mst_program_late_total counter")
     labelled("mst_program_late_total", "program", t["program_late"])
+    d = t.get("diffusion")
+    if d is not None:
+        # a family that generates by diffusion over blocks (diffusion.py):
+        # its forwards, commits and transfers, counted at the harvest
+        lines += [
+            "# TYPE mst_diffusion_slot_forwards_total counter",
+            f"mst_diffusion_slot_forwards_total {d['slot_forwards']}",
+            "# TYPE mst_diffusion_blocks_committed_total counter",
+            f"mst_diffusion_blocks_committed_total {d['blocks_committed']}",
+            "# TYPE mst_diffusion_tokens_transferred_total counter",
+        ]
+        labelled("mst_diffusion_tokens_transferred_total", "by",
+                 {"rank": d["by_rank"], "confidence": d["by_confidence"]})
 
 
 def _render_spec_family(lines: list, spec: dict):
@@ -1145,8 +1158,20 @@ _HELP = {
     "mst_decode_tokens_dropped_total":
         "Computed positions no stream received: slot_finished (past the "
         "last token of a stream that reached max_tokens), cancelled (the "
-        "slot was given up earlier: consumer gone, preempted) or "
-        "abandoned_block (futures dropped).",
+        "slot was given up earlier: consumer gone, preempted), "
+        "abandoned_block (futures dropped) or, where the family generates by "
+        "diffusion over blocks, denoise (a forward's rows that were not a "
+        "commit's new tokens).",
+    "mst_diffusion_slot_forwards_total":
+        "Diffusion over blocks: forwards x live slots of harvested decode "
+        "programs (a forward computes a block of positions a slot).",
+    "mst_diffusion_blocks_committed_total":
+        "Diffusion over blocks: blocks committed (each by one commit "
+        "forward of its slot) and handed to their streams.",
+    "mst_diffusion_tokens_transferred_total":
+        "Diffusion over blocks: masked positions that took their sampled "
+        "token, by what chose them: rank (the strategy's n a forward) or "
+        "confidence (above the threshold).",
     "mst_pipeline_drains_total":
         "Pipeline drains (a block was in flight at a quiesce), by call site.",
     "mst_decode_blocks_total":

@@ -37,9 +37,12 @@ def _load(cell: dict):
     return config, mix, load
 
 
-def _sized(cell: dict) -> bool:
+def _sized(cell: dict, key: str = "first_wave_ends") -> bool:
+    """A closed-loop mix that records what it was sized for: long answers,
+    of which the FIRST WAVE's end inside the window (``first_wave_ends``), or
+    short ones, whose clients turn over several times (``ends_in_window``)."""
     mix = _load(cell)[1]
-    return mix.get("arrivals") == "closed" and "sized_for" in mix
+    return mix.get("arrivals") == "closed" and key in mix.get("sized_for", {})
 
 
 SIZED = [c["name"] for c in BENCH["workloads"] if _sized(c)]
@@ -82,6 +85,64 @@ def test_a_sized_closed_loop_cell_has_requests_due_in_its_window(name, seed):
     # `attempted` counts the requests due in the window: each end frees a client
     assert 1 <= lo <= in_window <= hi, (in_window, sorted(first))
     assert taken <= len(planned), "the mix's ready requests would run out"
+
+
+TURNING = [c["name"] for c in BENCH["workloads"] if _sized(c, "ends_in_window")]
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+@pytest.mark.parametrize("name", TURNING)
+def test_a_cell_whose_clients_turn_over_has_ends_in_its_window(name, seed):
+    """``chatgen-sat``: answers of 1-1.5k tokens at a token time under 20 ms,
+    so a client ends several requests in the lead-in and the window. The
+    replay of ``traffic.plan`` at the recorded token time: as many requests
+    END inside the window as the mix says (each frees a client: ``attempted``),
+    and the ready requests do not run out — nor at a token time HALF the
+    recorded one (a later PR's faster program)."""
+    cell = next(c for c in BENCH["workloads"] if c["name"] == name)
+    config, mix, load = _load(cell)
+    seconds, sized = float(BENCH["run_seconds"]), mix["sized_for"]
+    planned, lead = traffic.plan(
+        mix, load, seconds, seed, config["vocab_size"],
+        int(server_flag(config, "--max-seq", 4096)))
+    clients = int(load["clients"])
+    assert clients == int(server_flag(config, "--concurrent"))
+
+    def ends(step_ms):
+        step, t0 = step_ms / 1e3, lead - sized["decode_s_before_window"]
+        free = sorted(t0 + r.output_tokens * step for r in planned[:clients])
+        taken, out = clients, []
+        while free and free[0] < lead + seconds:
+            out.append(free.pop(0))
+            if taken >= len(planned):
+                return out, len(planned) + 1
+            free.append(out[-1] + sized["join_s"] + planned[taken].output_tokens * step)
+            free.sort()
+            taken += 1
+        return out, taken
+
+    out, taken = ends(sized["step_ms"])
+    lo, hi = sized["ends_in_window"]
+    assert lo <= sum(lead <= t < lead + seconds for t in out) <= hi
+    assert taken <= len(planned), "the mix's ready requests would run out"
+    assert ends(sized["step_ms"] / 2)[1] <= len(planned), "... at half the token time"
+
+
+def test_the_chatgen_cell_is_sized_and_fits_its_pool():
+    name = "sdar-30b-a3b-bf16-ep16.chatgen-sat"
+    assert name in TURNING and name not in SIZED
+    config, mix, load = _load(next(c for c in BENCH["workloads"] if c["name"] == name))
+    # a join is one prefill chunk, and prompt + answer fits a slot's pages,
+    # every slot's at once, to the row
+    chunk = int(server_flag(config, "--prefill-chunk"))
+    assert mix["prompt_tokens"]["max"] <= chunk
+    longest = mix["prompt_tokens"]["max"] + mix["output_tokens"]["max"]
+    assert longest == int(server_flag(config, "--max-seq"))
+    assert int(load["clients"]) * -(-longest // chunk) == int(server_flag(config, "--paged-pool"))
+    # the check's prompts start the first decode block with two prompt tokens
+    block = config["block_length"]
+    assert [p % block for p in config["bench"]["check"]["prompt_tokens"]] == [2, 2]
+    assert config["mask_token_id"] == 0  # an id the harness never draws
 
 
 def test_the_new_long_context_cell_is_sized():

@@ -594,7 +594,8 @@ def test_the_five_readers_over_a_pair_of_scrapes(capsys):
            "chunk_device_share": ("%", "lower", "scheduler"),
            "dispatch_exposed_share": ("%", "lower", "scheduler"),
            "slots_decoding.mean": ("slots", "higher", "scheduler")}
-    entries = bench["per_layer"][-5:]
+    at = [m["name"] for m in bench["per_layer"]].index("prefill_chunk_ms.window")
+    entries = bench["per_layer"][at:at + 5]  # (later PRs append behind them)
     assert [m["name"] for m in entries] == list(new)  # appended, in the issue's order
     for m in entries:
         unit, better, layer = new[m["name"]]

@@ -835,3 +835,71 @@ def test_other_models_layer_scans_are_as_they_were(family):
     assert len(outer) == 1
     assert outer[0].params["num_carry"] == 1
     assert len(_scanned(outer[0])) == len(jax.tree.leaves(params["layers"])) + 2
+
+
+@hard_timeout(420)
+def test_served_sdar_moe_programs_keep_the_names_and_add_one_scope(monkeypatch):
+    """The eighth family generates by diffusion over blocks: its decode
+    program is compiled under the name ``block`` all the same (a step of it
+    is one forward over every slot's block of 4), its join ends in a program
+    of its own name (``finish_join_block``: no first token), and ONE scope is
+    new, ``mst.diffusion.unmask``. The page pool rides the layer scan's carry:
+    the only pool-sized things the block makes are each layer's scatter of
+    the slots' rows. No program name is used for two programs."""
+    from mlx_sharding_tpu.models import build_model
+
+    log = JitLog(jax.jit)
+    monkeypatch.setattr(jax, "jit", log)
+    model, _ = build_model(dict(
+        model_type="sdar_moe", vocab_size=128, hidden_size=32, num_hidden_layers=3,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        moe_intermediate_size=16, num_experts=2, moe_expert_share=2,
+        num_experts_per_tok=2, block_length=4, denoising_steps=2,
+        remasking_strategy="low_confidence_dynamic", mask_token_id=0,
+    ))
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    eng = PipelineEngine(
+        model, params, pipeline_mesh(1), microbatches=2, max_seq=64,
+        cache_dtype=jnp.float32, prefill_chunk=8, pool_pages=10, page_size=8,
+    )
+    b = ContinuousBatcher(eng, decode_block=3)
+    try:
+        assert len(list(b.generate_step([3, 4, 5, 6, 7, 8], max_tokens=5))) == 5
+        args = (eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+                b.last_tok, b.cache, b.active, b.recent, b.keys, b.sp, b.rep_sizes,
+                b.table)
+        prog = b._decode_block_prog(False)
+        block = prog.lower(*args).as_text(debug_info=True)
+        jaxpr = jax.make_jaxpr(prog)(*args)
+        prefill = eng.prefill_slot().lower(
+            eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+            jnp.zeros((1, 8), jnp.int32), jnp.asarray(0, jnp.int32), b.cache,
+            jnp.asarray(8, jnp.int32), b.table,
+        ).as_text(debug_info=True)
+        pages = b.cache.k.shape[1:]  # (layers, pages + 1, 1, page, 1, Hkv * D)
+    finally:
+        b.close()
+    assert "module @jit_block " in block
+    assert "module @jit_prefill_chunk " in prefill
+    by_name: dict = {}
+    for name, file, line, _ in log.seen:
+        assert name != "<lambda>", f"anonymous program at {file}:{line}"
+        by_name.setdefault(name, set()).add((file, line))
+    assert not {n: sorted(w) for n, w in by_name.items() if len(w) > 1}
+    assert {"block", "prefill_chunk", "claim_slot", "finish_join_block"} <= set(by_name)
+    assert "finish_join" not in by_name
+    layers = {"mst.embed", "mst.attn.qkv", "mst.attn.qk_norm", "mst.attn.core",
+              "mst.moe.router", "mst.moe.experts", "mst.moe.experts.scan", "mst.norm",
+              "mst.head", "mst.kv_pool.regroup"}
+    assert _scopes_in(block) == layers | {
+        "mst.attn.kv_write", "mst.sample", "mst.diffusion.unmask"}
+    assert _scopes_in(prefill) == layers | {"mst.attn.kv_write"}
+    # the pool is written by scatter, in place on the carry, and never copied
+    walked = list(_walk(jaxpr.jaxpr))
+    size = math.prod(pages)
+    made = [eqn.primitive.name for eqn, _ in walked
+            if any(getattr(v.aval, "size", 0) == size for v in eqn.outvars)]
+    assert set(made) <= {"scatter", "scan", "while", "pjit", "shard_map", "reshape",
+                         "squeeze", "broadcast_in_dim", "jit", "closed_call",
+                         "custom_jvp_call"}, sorted(set(made))
+    assert made.count("scatter") == 2  # K and V, once: the layer scan's body

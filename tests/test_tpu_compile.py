@@ -302,6 +302,16 @@ CASES = {
     # ... and at the kernel's bound of 128 rows, and at one
     "dense-experts-trinity-128-rows": _dense_experts(128, 4, 16, 3072, 3072),
     "dense-experts-qwen3-next-1-row": _dense_experts(1, 10, 128, 2048, 512),
+    # the sdar-30b-a3b-bf16-ep16 cell's decode block (diffusion over blocks
+    # of 4: a forward is 32 slots x 4 rows): the ragged kernel with each K/V
+    # head's 4 x 8 queries folded into a query group of 32 (128 "heads" on 4
+    # K/V heads of 128 merged on 512 lanes, a table 4 pages wide, two layers'
+    # pools viewed as one), and the 8 held experts of 768 x 2048 at the
+    # kernel's bound of 128 rows, top-8
+    "paged-sdar-group32-merged-page512": _paged(
+        512, False, slots=32, hq=128, hkv=4, max_seq=2048, pages=2 * 129,
+        merged=True),
+    "dense-experts-sdar-128x8of8": _dense_experts(128, 8, 8, 2048, 768),
 }
 
 
@@ -537,3 +547,70 @@ def test_ssm_step_in_a_layer_scan_moves_nothing_but_its_blocks(chip):
     # (the call's own result is a tuple, which ``_arrays_made`` does not read)
     assert _arrays_made(text, rows) == []
     assert compiled.memory_analysis().temp_size_in_bytes < rows
+
+
+def test_the_diffusion_decode_block_compiles_for_v5e(chip, monkeypatch):
+    """``sdar_moe``'s decode forward at the published widths, two layers, as
+    the ragged body runs it (``parallel/pipeline.py`` at ``T = L = 4``): 32
+    slots x 4 rows through ``sp_layer``; each layer scatters a slot's 4 rows
+    into one page of the pool it is handed whole (two layers' pages viewed as
+    one, a row's 4 K/V heads merged on 512 lanes), folds the 4 queries into
+    the kernel's query group (Mosaic takes a group of 32) and multiplies its
+    128 rows through the 8 held experts of 128 on ``dense_experts`` at the
+    kernel's bound of rows. No copy of the pool, no gathered table."""
+    from mlx_sharding_tpu.models import build_model
+    from mlx_sharding_tpu.models.base import scan_layers_carried
+    from mlx_sharding_tpu.parallel.pipeline import fold_block_queries
+
+    layers, slots, L, page, spg, pages = 2, 32, 4, 512, 4, 129
+    model, cfg = build_model(dict(
+        model_type="sdar_moe", vocab_size=19072, hidden_size=2048,
+        num_hidden_layers=layers, num_attention_heads=32, num_key_value_heads=4,
+        head_dim=128, intermediate_size=6144, moe_intermediate_size=768,
+        num_experts=8, num_experts_per_tok=8, moe_expert_share=16,
+        moe_expert_share_index=0, mask_token_id=0,
+    ))
+    kv = cfg.num_key_value_heads * cfg.head_dim
+
+    def step(stack, h, k, v, rows, page_ids, row_pos, offsets):
+        lengths = offsets + L
+        at = row_pos[:, None] + jnp.arange(L)[None, :]
+
+        def layer(h, p, k, v, row, keep):
+            first = row * pages
+
+            def attn_fn(q, k_new, v_new, kv_heads):
+                kl = k.at[page_ids[:, None] + first, at].set(k_new)
+                vl = v.at[page_ids[:, None] + first, at].set(v_new)
+                layer.done = kl, vl
+                return fold_block_queries(
+                    lambda q1: paged_attention(
+                        q1, kl, vl, rows + first, lengths, model.scale,
+                        kv_heads=kv_heads,
+                    ), q, kv_heads)
+
+            h, _, _ = model.sp_layer(p, h, offsets, attn_fn)
+            return h, *layer.done
+
+        return scan_layers_carried(
+            layer, h, stack, k, v, jnp.arange(layers),
+            in_place=model.scan_in_place(None, stack),
+        )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    stack = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))["layers"]
+    pool = ((layers * pages, page, 1, kv), BF16)
+    shapes = [((slots, L, cfg.hidden_size), BF16), pool, pool, ((slots, spg), I32),
+              ((slots,), I32), ((slots,), I32), ((slots,), I32)]
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=chip)  # noqa: E731
+    args = [jax.tree.map(lambda x: sds(x.shape, x.dtype), stack),
+            *(sds(s, d) for s, d in shapes)]
+    text = jax.jit(step, donate_argnums=(2, 3)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "paged_attention" in text and "dense_experts" in text
+    # the only pool-sized things in the loop: each pool's scatter of the
+    # slots' rows (a fusion around a scatter, in place on the carry)
+    whole = f"bf16[{layers * pages},{page},{kv}]"
+    made = _arrays_made(_loop_bodies(text), slots * spg * page * kv * 2)
+    assert set(made) <= {("scatter", whole), ("fusion", whole)}, made
+    assert len(made) <= 4, made
