@@ -630,8 +630,9 @@ class SdarMoeConfig(BaseConfig):
     forward computes a whole block whose unknown positions hold
     ``mask_token_id``, and ``denoising_steps`` forwards fill them in by
     ``remasking_strategy`` (``sequential`` | ``low_confidence_static`` |
-    ``low_confidence_dynamic`` with ``confidence_threshold``) before one more
-    forward commits the block's K/V (``mlx_sharding_tpu/diffusion.py``).
+    ``low_confidence_dynamic`` with ``confidence_threshold``); the block's
+    K/V is then committed by the next block's first forward, which carries
+    both (``mlx_sharding_tpu/diffusion.py``).
 
     A layer may hold one chip's SHARE of the routed experts, as
     :class:`NemotronHConfig` says: ``num_experts`` counts the experts held,

@@ -12,19 +12,40 @@ its probability. ``n = L / denoising_steps`` masked positions take their
 ``x0`` a forward (:func:`unmask`): ``sequential`` the first ``n``,
 ``low_confidence_static`` the ``n`` of largest ``c``,
 ``low_confidence_dynamic`` every one with ``c > confidence_threshold`` or the
-top ``n`` if fewer than ``n`` pass. When no position is masked, one **commit
-forward** over the final ids stores the block's K/V; the block's tokens are
-the output and ``off += L``. A forward writes its rows' K/V either way: a
-denoise forward's are overwritten by the next forward of the block.
+top ``n`` if fewer than ``n`` pass. The forward that leaves no position masked
+FINISHES the block: its ids are the output. What is left is the **commit**:
+the K/V of the final ids, which later blocks read. A forward writes its rows'
+K/V either way: a denoise forward's are overwritten by the next forward of
+the block, the commit's stay.
 
-:func:`block_forward` is one such forward's epilogue for every slot of a
-batch at once, branch-free: slots are at different phases in one forward.
-Two departures from the family's published loop, both noted in the
-benchmark's configuration under ``assumed``: which positions are masked is a
-boolean carried beside the ids (never inferred from an id, so a prompt that
-contains the mask id is served right), and the mask id's logit is ``-inf``
-before sampling (the published loop can sample the mask id and then
-denoises that position again: a block that never ends).
+**The commit rides the next block's first denoise forward.** The published
+loop runs the commit as a forward of its own, which reads every weight and the
+slot's whole K/V to compute no token. Here a finished block waits beside the
+next one (``done_ids``, ``pending``), and a **wide forward** carries two lanes
+of ``L`` rows a slot at ``off .. off + 2L - 1``: lane 1 the finished block's
+final ids (or, where the slot has none, its current block, denoising), lane 2
+the next block's denoise rows where lane 1 is a commit. Lane 1 sees the keys
+``[0, off + L)`` and lane 2 ``[0, off + 2L)``: every row computes what the
+published loop's forward computes for it, and the next block reads the
+committed rows — both lanes are written before either attends. ``off``
+advances when a block's K/V is stored; a stream's last block is never stored
+(nothing reads it). A **narrow forward** carries one lane, a block denoising.
+A block program alternates the two (``scheduler._decode_block_prog``): under
+a strategy that transfers by rank a block takes a wide forward (the commit
+before it | its first denoise) and a narrow one (its second), two where the
+loop takes three. A slot that holds a finished block at a narrow forward
+stands still for it (a first block of one forward, a block that passed the
+threshold whole): it falls in step with the others, so that a narrow forward
+is narrow for every slot.
+
+:func:`block_forward` is one forward's epilogue for every slot of a batch at
+once, branch-free: slots are at different phases in one forward. Two
+departures from the family's published loop, both noted in the benchmark's
+configuration under ``assumed``: which positions are masked is a boolean
+carried beside the ids (never inferred from an id, so a prompt that contains
+the mask id is served right), and the mask id's logit is ``-inf`` before
+sampling (the published loop can sample the mask id and then denoises that
+position again: a block that never ends).
 
 :func:`refuse` is the one start-up error of every feature that assumes one
 token a step or re-enters a sequence at a position of its own choosing.
@@ -81,16 +102,34 @@ def refuse(model, flag: str) -> None:
 
 
 def init_block(m: int, length: int) -> dict:
-    """A batcher's per-slot block state: the block's ids, which of them are
-    masked, and the log-probability summary each position had at the forward
-    that transferred it (read at the commit, by a request that asked)."""
+    """A batcher's per-slot block state: the block that denoises (its ids,
+    which of them are masked, and the log-probability summary each position
+    had at the forward that transferred it, read by a request that asked
+    when the block is finished) and the finished block in front of it whose
+    K/V no forward has stored yet (``pending``: there is one)."""
     return {
         "ids": jnp.zeros((m, length), jnp.int32),
         "masked": jnp.zeros((m, length), bool),
+        "done_ids": jnp.zeros((m, length), jnp.int32),
+        "pending": jnp.zeros((m,), bool),
         "lp_chosen": jnp.zeros((m, length), jnp.float32),
         "lp_top_v": jnp.zeros((m, length, LOGPROB_TOPK), jnp.float32),
         "lp_top_i": jnp.zeros((m, length, LOGPROB_TOPK), jnp.int32),
     }
+
+
+def forward_input(blk, active, wide: bool):
+    """``(tokens, live, second)`` of one forward. Wide: ``tokens (M, 2L)``,
+    lane 1 the finished block where the slot holds one (``second``: lane 2,
+    the block that denoises, is then computed too) and else the block that
+    denoises; every active slot is ``live``. Narrow: ``tokens (M, L)`` the
+    block that denoises, and a slot that holds a finished block is not
+    ``live``: it waits for the wide forward that commits it."""
+    pending = active & blk["pending"]
+    if not wide:
+        return blk["ids"], active & ~pending, jnp.zeros_like(pending)
+    lane1 = jnp.where(pending[:, None], blk["done_ids"], blk["ids"])
+    return jnp.concatenate([lane1, blk["ids"]], axis=1), active, pending
 
 
 def first_block(prompt_tail, length: int, mask_id: int):
@@ -127,22 +166,22 @@ def unmask(masked, conf, *, strategy: str, n: int, tau: float):
     return jnp.where(enough[:, None], high, top), enough
 
 
-def block_forward(blk, logits, offset, active, recent, keys, sp, rep_sizes, *,
-                  cfg, want_lp: bool):
+def block_forward(blk, logits, offset, live, second, recent, keys, sp,
+                  rep_sizes, *, cfg, want_lp: bool):
     """One forward's epilogue for every slot. ``blk``: :func:`init_block`'s
-    tree; ``logits (M, L, V)`` float32; ``offset (M,)``. Where a slot has
-    masked positions this forward denoised: sample every position, transfer
-    by the strategy. Where none, this forward WAS the commit: the block's ids
-    are its tokens, ``offset += L``, the next block starts all masked.
+    tree; ``logits (M, L, V)`` float32, of the lane that denoises; ``offset
+    (M,)``; ``live`` and ``second``: :func:`forward_input`'s. A live slot
+    denoised: sample every position, transfer by the strategy; where that
+    leaves none masked the block is finished — its ids are its tokens, it
+    waits for its commit and the next block starts all masked. Where
+    ``second``, lane 1 stored the block finished before: ``offset += L``.
     Returns ``(out, blk, offset, recent, keys)``; ``out`` is what the host
-    reads of the forward: ``commit (M,)``, ``ids (M, L)`` (the block as the
-    forward saw it: a committed block's tokens), ``by_rank`` and
+    reads of the forward: ``done (M,)``, ``ids (M, L)`` (the block after the
+    forward's transfers: a finished block's tokens), ``by_rank`` and
     ``by_confidence (M,)`` counts of positions transferred, and with
     ``want_lp`` the summaries of the block's positions."""
     m, length, vocab = logits.shape
     ids, masked = blk["ids"], blk["masked"]
-    commit = active & ~masked.any(axis=1)
-    denoise = active & ~commit
     with jax.named_scope("mst.sample"):
         split = jax.vmap(lambda k: jax.random.split(k, length + 1))(keys)
         keys, subs = split[:, 0], split[:, 1:].reshape(m * length, -1)
@@ -153,7 +192,7 @@ def block_forward(blk, logits, offset, active, recent, keys, sp, rep_sizes, *,
         sp_rows = jax.tree.map(per_row, sp)
         x0, logprobs = sample_token_batched(
             subs, flat, sp_rows, per_row(jnp.where(valid, recent, -1)),
-            per_row(denoise),
+            per_row(live),
         )
         # c: the sampled token's probability under the row's temperature
         # (top-p's renormalisation left out: it only raises every kept
@@ -171,10 +210,13 @@ def block_forward(blk, logits, offset, active, recent, keys, sp, rep_sizes, *,
         n=cfg.block_length // cfg.denoising_steps, tau=cfg.confidence_threshold,
     )
     with jax.named_scope("mst.diffusion.unmask"):
-        transfer &= denoise[:, None]
+        transfer &= live[:, None]
         moved = transfer.sum(axis=1).astype(jnp.int32)
+        ids = jnp.where(transfer, x0, ids)
+        masked &= ~transfer
+        done = live & ~masked.any(axis=1)
         out = {
-            "commit": commit, "ids": ids,
+            "done": done, "ids": ids,
             "by_rank": jnp.where(by_conf, 0, moved),
             "by_confidence": jnp.where(by_conf, moved, 0),
         }
@@ -189,12 +231,13 @@ def block_forward(blk, logits, offset, active, recent, keys, sp, rep_sizes, *,
                 sel = transfer.reshape(transfer.shape + (1,) * (val.ndim - 2))
                 new[name] = jnp.where(sel, val, blk[name])
                 out[name] = new[name]
-        new["ids"] = jnp.where(
-            commit[:, None], cfg.mask_token_id, jnp.where(transfer, x0, ids)
-        )
-        new["masked"] = jnp.where(commit[:, None], True, masked & ~transfer)
-        offset = offset + jnp.where(commit, length, 0).astype(offset.dtype)
-        # a committed block's tokens enter the repetition window
+        # a live slot's finished block, if it held one, was lane 1: stored
+        new["done_ids"] = jnp.where(done[:, None], ids, blk["done_ids"])
+        new["pending"] = jnp.where(live, done, blk["pending"])
+        new["ids"] = jnp.where(done[:, None], cfg.mask_token_id, ids)
+        new["masked"] = done[:, None] | masked
+        offset = offset + jnp.where(second, length, 0).astype(offset.dtype)
+        # a finished block's tokens enter the repetition window
         shifted = jnp.concatenate([recent[:, length:], ids[:, -w:]], axis=1)
-        recent = jnp.where(commit[:, None], shifted, recent)
+        recent = jnp.where(done[:, None], shifted, recent)
     return out, new, offset, recent, keys

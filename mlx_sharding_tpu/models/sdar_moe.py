@@ -27,8 +27,12 @@ What makes the family different is not in this file: a forward yields a
 block's logits, row ``i`` predicting position ``i`` ITSELF, and
 ``mlx_sharding_tpu/diffusion.py`` decides which of them become tokens.
 ``diffusion_block`` is what tells the engines (``parallel/pipeline.py``,
-``scheduler.py``) that a decode step is such a forward. One pipeline stage,
-no tensor or expert parallelism.
+``scheduler.py``) that a decode step is such a forward — over one block a
+slot, or over two: a finished block's commit with the next block's denoise
+rows behind it, each lane masked from the other as the published loop's two
+forwards are (``sp_layer`` sees ``2L`` rows a slot then, and the engine's
+attention bounds each lane's keys). One pipeline stage, no tensor or expert
+parallelism.
 """
 
 from __future__ import annotations
@@ -102,30 +106,41 @@ class SdarMoeModel(BaseModel):
             k = apply_rope(k, self.inv_freq, offset).reshape(b, t, 1, hkv * d)
         return q, k, v
 
-    def layer_finish(self, p, h, attn):
-        """Output projection, residual, then the routed experts."""
+    def layer_finish(self, p, h, attn, lanes: int = 1):
+        """Output projection, residual, then the routed experts. ``lanes``:
+        the rows are that many blocks a slot side by side (a wide decode
+        forward, ``diffusion.py``) and go to the experts a lane a call, each
+        the rows of a narrow forward — the count ``ops/dense_experts.py``'s
+        kernel holds, where all of them at once would walk the loop."""
         cfg = self.config
         b, t, hidden = h.shape
         with jax.named_scope("mst.attn.qkv"):
             h = h + self._linear(attn.reshape(b, t, -1), p["o_proj"]).astype(h.dtype)
+
+        def moe(r):  # (B, rows, hidden)
+            flat = r.reshape(-1, hidden)
+            weights, idx = mixtral_routing(flat, p["router"], cfg.num_experts_per_tok)
+            return apply_experts(
+                flat, weights, idx, p["w_gate"], p["w_up"], p["w_down"],
+                group_size=self._gs, bits=self._bits,
+                expert_base=(
+                    cfg.moe_expert_share_index * cfg.num_experts
+                    if cfg.moe_expert_share > 1 else None
+                ),
+                layer=p.get(LAYER_INDEX),
+            ).reshape(r.shape)
+
         r = rms_norm(h, p["post_norm"], cfg.rms_norm_eps)
-        flat = r.reshape(b * t, hidden)
-        weights, idx = mixtral_routing(flat, p["router"], cfg.num_experts_per_tok)
-        moe = apply_experts(
-            flat, weights, idx, p["w_gate"], p["w_up"], p["w_down"],
-            group_size=self._gs, bits=self._bits,
-            expert_base=(
-                cfg.moe_expert_share_index * cfg.num_experts
-                if cfg.moe_expert_share > 1 else None
-            ),
-            layer=p.get(LAYER_INDEX),
+        out = moe(r) if lanes == 1 else jnp.concatenate(
+            [moe(x) for x in jnp.split(r, lanes, axis=1)], axis=1
         )
-        return h + moe.reshape(b, t, hidden).astype(h.dtype)
+        return h + out.astype(h.dtype)
 
     def sp_layer(self, p, h, offset, attn_fn, group=None):
         q, k, v = self.layer_attn_inputs(p, h, offset)
         attn = attn_fn(q, k, v, kv_heads=self.config.num_key_value_heads)
-        return self.layer_finish(p, h, attn), k, v
+        lanes = h.shape[1] // self.config.block_length
+        return self.layer_finish(p, h, attn, lanes), k, v
 
     def _layer(self, h, p, k_buf, v_buf, offset):
         """Over a sequence's contiguous rows ``(B, S, 1, Hkv * D)``: a prefill

@@ -34,7 +34,12 @@ Two paths, selected the same way ops/flash_attention.py picks its path:
   neighbour's and fetches nothing new. The table may map positions to a
   ring of pages (``cache.window_ring_rows``): logical page ``j`` is then
   ring page ``j % R`` of the slot, and the clamp keeps the walk off the
-  ring pages that hold positions outside the window.
+  ring pages that hold positions outside the window. An optional second
+  length a slot (``lead_lengths``, one more scalar-prefetch operand) bounds
+  the leading ``lead_rows`` rows of every K/V head's query group: two
+  blocks of a sequence in one call, the earlier blind to the later's keys
+  (``diffusion.py``'s wide forward); absent, the body, the operands and
+  the grid are what they were.
 - a fused-XLA fallback for CPU / odd shapes / softcap / a traced window,
   mirroring ops/attention.py's masking semantics. It gathers every slot's
   WHOLE table row (slot_pages × page rows, i.e. max_seq) and masks it, so
@@ -44,7 +49,7 @@ Two paths, selected the same way ops/flash_attention.py picks its path:
 Both are token-exact vs the gather path; tests/test_paged_attention.py holds
 the parity matrix (uneven lengths, page-boundary offsets, empty slots, GQA/
 MQA head counts, the latent layout and windows over plain and ring tables,
-kernel-in-interpret vs XLA).
+kernel-in-interpret vs XLA, two lengths a query group).
 """
 
 from __future__ import annotations
@@ -110,8 +115,9 @@ def kernel_eligible(
 def _kernel(
     tables_ref,  # (M, SPG) int32 — scalar-prefetch
     lens_ref,  # (M,) int32 — scalar-prefetch
-    q_ref,  # (1, Hkv, G, Dk) block — one slot's query heads
     *refs,
+    # lead_ref (M,) int32 — scalar-prefetch, only with ``lead_rows``
+    # q_ref (1, Hkv, G, Dk) block — one slot's query heads
     # k_ref (1, page, Hkv*Dk) block — the page named by tables[m, j]
     # v_ref (1, page, Hkv*Dv) block — absent where ``latent``
     # ks_ref, vs_ref (1, page, Hkv) per-row scales — int8 pools only, and
@@ -129,8 +135,12 @@ def _kernel(
     quant: bool,
     latent: bool,
     window=None,
+    lead_rows: int = 0,
 ):
-    *pages, o_ref, m_scr, l_scr, acc_scr = refs
+    lead_ref = None
+    if lead_rows:
+        lead_ref, *refs = refs
+    q_ref, *pages, o_ref, m_scr, l_scr, acc_scr = refs
     pages = iter(pages)
     k_ref = next(pages)
     v_ref = None if latent else next(pages)
@@ -139,6 +149,13 @@ def _kernel(
     m = pl.program_id(0)
     j = pl.program_id(1)
     length = lens_ref[m]
+
+    def row_bound(shape, axis):
+        # the leading ``lead_rows`` rows (along ``axis``) of every K/V
+        # head's query group see keys below ``lead``, the others below
+        # ``length``
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+        return jnp.where(row < lead_rows, lead_ref[m], length)
 
     @pl.when(j == 0)
     def _init():
@@ -161,6 +178,8 @@ def _kernel(
         )
         if window is not None:
             visible = (k_pos < length) & (k_pos >= length - window)
+        elif lead_ref is not None:
+            visible = k_pos < row_bound((q_ref.shape[2], page_size), 0)
         # the page block carries every KV head side by side on the lane
         # axis; head h is the static lane slice [h*D, (h+1)*D)
         for h in range(hkv):
@@ -184,7 +203,8 @@ def _kernel(
                 preferred_element_type=jnp.float32,
             ) * scale  # (G, page)
             s = jnp.where(
-                k_pos < length if window is None else visible, s, NEG_INF
+                k_pos < length if window is None and lead_ref is None
+                else visible, s, NEG_INF,
             )
             m_prev = m_scr[h, :, :1]  # (G, 1)
             l_prev = l_scr[h, :, :1]
@@ -203,13 +223,18 @@ def _kernel(
     def _finish():
         # empty slot (length 0, the garbage lane): l stays 0 → zeros out
         l = jnp.maximum(l_scr[:, :, :1], 1e-30)
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        out = acc_scr[...] / l
+        if lead_ref is not None:
+            # a row bounded at 0 beside a live longer length saw whole pages
+            # of masked keys with no maximum yet: zeros, as an empty slot's
+            out = jnp.where(row_bound(out.shape, 1) > 0, out, 0.0)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _paged_attention_kernel(
     q, k_pool, v_pool, tables, lengths, scale, interpret,
     k_scale=None, v_scale=None, values_from_k=None, window=None,
-    kv_heads=None,
+    kv_heads=None, lead_lengths=None, lead_rows=0,
 ):
     m, hq, dk = q.shape
     pages, page_size, hkv = k_pool.shape[:3]
@@ -222,7 +247,7 @@ def _paged_attention_kernel(
     qg = q.reshape(m, hkv, g, dk)
     quant = k_scale is not None
 
-    def page_id(mi, ji, t, ln):
+    def page_id(mi, ji, t, ln, *_):
         if window is None:
             return t[mi, ji]
         # a step outside [first, last] visible page names its neighbour
@@ -240,7 +265,7 @@ def _paged_attention_kernel(
         # one-head-of-Hkv block in the pool's native layout is not.
         return pl.BlockSpec(
             (1, page_size, width),
-            lambda mi, ji, t, ln: (page_id(mi, ji, t, ln), 0, 0),
+            lambda mi, ji, t, ln, *_: (page_id(mi, ji, t, ln), 0, 0),
         )
 
     # every operand after q is a pool fetched page by page through the
@@ -251,19 +276,22 @@ def _paged_attention_kernel(
         scales = (k_scale,) if latent else (k_scale, v_scale)
         kv += [(s.astype(jnp.float32), 1) for s in scales]
     in_specs = [
-        pl.BlockSpec((1, hkv, g, dk), lambda mi, ji, t, ln: (mi, 0, 0, 0)),
+        pl.BlockSpec((1, hkv, g, dk), lambda mi, ji, *_: (mi, 0, 0, 0)),
         *(page_spec(hkv * width) for _, width in kv),
     ]
     operands = [
         qg, *(x.reshape(pages, page_size, hkv * width) for x, width in kv)
     ]
 
+    # scalar-prefetch operands: the table, the lengths, and with
+    # ``lead_rows`` the shorter bound of each group's leading rows
+    scalars = [tables, lengths] + ([lead_lengths] if lead_rows else [])
     spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(m, spg),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, hkv, g, dv), lambda mi, ji, t, ln: (mi, 0, 0, 0)
+            (1, hkv, g, dv), lambda mi, ji, *_: (mi, 0, 0, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((hkv, g, 128), jnp.float32),
@@ -276,19 +304,20 @@ def _paged_attention_kernel(
             _kernel,
             scale=scale, page_size=page_size, pages_per_slot=spg,
             hkv=hkv, dk=dk, dv=dv, quant=quant, latent=latent, window=window,
+            lead_rows=lead_rows,
         ),
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((m, hkv, g, dv), q.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
+    )(*(x.astype(jnp.int32) for x in scalars), *operands)
     return out.reshape(m, hq, dv)
 
 
 def _paged_attention_xla(
     q, k_pool, v_pool, tables, lengths, scale,
     logit_softcap, sliding_window, values_from_k,
-    k_scale=None, v_scale=None, kv_heads=None,
+    k_scale=None, v_scale=None, kv_heads=None, lead_lengths=None, lead_rows=0,
 ):
     if kv_heads is not None:  # heads merged on the lane axis: split them
         split = lambda x: x.reshape(*x.shape[:2], kv_heads, -1)  # noqa: E731
@@ -324,11 +353,17 @@ def _paged_attention_xla(
     if sliding_window is not None:
         # the single query sits at position lengths-1
         allowed &= k_pos > (lengths[:, None] - 1) - sliding_window
-    scores = jnp.where(allowed[:, None, None, :], scores, NEG_INF)
+    allowed = allowed[:, None, None, :]
+    if lead_rows:  # (M, 1, G, S_virt): a group's leading rows see less
+        allowed = jnp.where(
+            (jnp.arange(g) < lead_rows)[None, None, :, None],
+            (k_pos < lead_lengths[:, None])[:, None, None, :], allowed,
+        )
+    scores = jnp.where(allowed, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     # an all-masked row (length 0, an inactive slot) softmaxes to uniform
     # garbage, not zeros — clamp it so the contract matches the kernel
-    probs = probs * allowed[:, None, None, :]
+    probs = probs * allowed
     out = jnp.einsum(
         "mhgs,mshd->mhgd",
         probs.astype(v.dtype),
@@ -352,6 +387,8 @@ def paged_attention(
     k_scale: Optional[jax.Array] = None,  # (P+1, page, Hkv, 1) int8-pool scales
     v_scale: Optional[jax.Array] = None,
     kv_heads: Optional[int] = None,  # pools are (P+1, page, 1, Hkv * D)
+    lead_lengths: Optional[jax.Array] = None,  # (M,) int32, <= lengths
+    lead_rows: int = 0,  # leading rows of a query group under lead_lengths
     interpret: bool = False,
 ) -> jax.Array:
     """Ragged decode attention over one layer's page pool. Returns
@@ -366,7 +403,14 @@ def paged_attention(
     page block. On a TPU an array is tiled over its two minor dimensions,
     so the ``(page, Hkv, D)`` pool's view as ``(page, Hkv * D)`` blocks is
     a relayout of the whole pool, every call, once ``Hkv > 1``; a model
-    that stores its rows merged pays none (bf16 pools only)."""
+    that stores its rows merged pays none (bf16 pools only).
+    ``lead_lengths`` with ``lead_rows``: the first ``lead_rows`` query rows
+    of every K/V head's group see keys ``[0, lead_lengths[m])`` and the rest
+    ``[0, lengths[m])`` — two blocks of a sequence in one call, the earlier
+    blind to the later's rows (``parallel/pipeline.py``: the queries are
+    folded into the group by block, then by query, then by head). The page
+    walk follows ``lengths``, the longer; a row bounded at 0 yields zeros.
+    No window beside it."""
     dk, dv = q.shape[-1], v_pool.shape[-1]
     hkv = k_pool.shape[2]
     if (k_scale is None) != (v_scale is None):
@@ -378,6 +422,13 @@ def paged_attention(
                 "int8 scales and no values_from_k"
             )
         hkv, dv = kv_heads, dv // kv_heads
+    if bool(lead_rows) != (lead_lengths is not None) or (
+        lead_rows and sliding_window is not None
+    ):
+        raise ValueError(
+            "lead_lengths and lead_rows (above 0) go together, and without "
+            "a sliding_window"
+        )
     if kernel_eligible(
         dk, dv, logit_softcap, sliding_window, values_from_k, interpret,
         hkv=hkv,
@@ -386,10 +437,11 @@ def paged_attention(
         return _paged_attention_kernel(
             q, k_pool, v_pool, tables, lengths, scale, interpret,
             k_scale, v_scale, values_from_k, sliding_window, kv_heads,
+            lead_lengths, lead_rows,
         )
     _count_dispatch("xla")
     return _paged_attention_xla(
         q, k_pool, v_pool, tables, lengths, scale,
         logit_softcap, sliding_window, values_from_k, k_scale, v_scale,
-        kv_heads,
+        kv_heads, lead_lengths, lead_rows,
     )

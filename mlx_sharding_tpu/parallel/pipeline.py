@@ -723,8 +723,8 @@ class PipelineEngine:
         self._sample = jax.jit(self._sample_fn, donate_argnums=(1,))
         # continuous-batching programs, built on first use by the scheduler
         self._decode_cb = None
-        self._diffusion_cbs: dict = {}  # want_lp → forward + epilogue
-        self._diffusion_body = None  # the ragged body at T = L, built once
+        self._diffusion_cbs: dict = {}  # (want_lp, wide) → forward + epilogue
+        self._diffusion_bodies: dict = {}  # lanes → the ragged body at T = L
         self._prefill_slot = None
         self._decode_blocks: dict = {}  # (k_steps, want_lp) → jitted block
         self._spec_progs: dict = {}  # ("propose"|"verify", K) → jitted prog
@@ -1351,7 +1351,7 @@ class PipelineEngine:
             )
         return h, k_pool, v_pool
 
-    def _build_smapped_ragged(self):
+    def _build_smapped_ragged(self, lanes: int = 1):
         """T=1 paged decode body attending over the page pool IN PLACE
         (ops/paged_attention.py). Where the gather body materializes every
         live slot's full (max_seq) KV view and scatters the dirty page back
@@ -1385,11 +1385,18 @@ class PipelineEngine:
         # and T divides the page, so one page, never two — every one of its
         # T queries sees the same keys [0, offset + T), and the queries are
         # folded into the kernel's query-group axis: the kernel is called as
-        # it is, with a group T times as large and no mask of its own
+        # it is, with a group T times as large and no mask of its own.
+        # ``lanes`` 2 (that family's wide forward): a slot computes two
+        # blocks at offset .. offset + 2T - 1, the block whose K/V is being
+        # committed and, where ``second``, the next one denoising. Each lane
+        # is written to its own page (offset + T may open one; a lane 2 that
+        # is not ``second`` goes to the scratch page), lane 1's queries see
+        # [0, offset + T) and lane 2's [0, offset + 2T) — the kernel's
+        # ``lead_lengths`` — and the head reads the lane that denoises
         T = self.diffusion_block or 1
 
         def body(layer_params, masks, vparts, shared, tokens, k, v,
-                 offsets, active, n_valid, table, state):
+                 offsets, active, n_valid, table, state, second=None):
             layer_params = jax.tree.map(lambda x: x[0], layer_params)
             masks = jax.tree.map(lambda x: x[0], masks)
             vparts = jax.tree.map(lambda x: x[0], vparts)
@@ -1428,6 +1435,27 @@ class PipelineEngine:
             lengths = jnp.where(active, offset_m + T, 0).astype(jnp.int32)
             if T > 1:  # (M, T): slot m's T rows of its write page
                 rows_at = row_pos[:, None] + jnp.arange(T)[None, :]
+            lead = None  # lane 1's bound under a longer ``lengths``
+            if lanes == 2:
+                # (M, 2): each lane's page, and (M, 2, T) its rows there. A
+                # lane 2 past the table's end (a stream that filled max_seq
+                # has ended: the host drops what its slot computes) is
+                # written to the scratch page too
+                at_page = (offset_m + T) // page
+                wide = active & second
+                page_ids = jnp.stack([page_ids, jnp.where(
+                    wide & (at_page < rows.shape[1]),
+                    jnp.take_along_axis(
+                        rows, jnp.minimum(at_page, rows.shape[1] - 1)[:, None],
+                        axis=1,
+                    )[:, 0],
+                    self.pool_pages,
+                )], axis=1)
+                rows_at = jnp.stack(
+                    [rows_at, (offset_m + T)[:, None] % page + jnp.arange(T)],
+                    axis=1,
+                )
+                lead, lengths = lengths, jnp.where(wide, lengths + T, lengths)
             if self.ring_rows:
                 # a window layer's ring pool viewed as pages: slot m's ring
                 # is pages m * R .. (m + 1) * R, logical page j its page
@@ -1506,25 +1534,38 @@ class PipelineEngine:
                     def put(pool, new):
                         if quant:  # quantize the M rows, scatter both
                             new = quantize_kv_rows(new)
-                        at = (ids, row_pos) if T == 1 else (ids[:, None], rows_at)
+                        at = (ids, row_pos) if T == 1 else (ids[..., None], rows_at)
                         return jax.tree.map(
                             lambda p, n: p.at[at].set(n.astype(p.dtype)),
                             pool, new,
                         )
 
+                    def rows_of(new):  # as ``put`` indexes them
+                        if T == 1:
+                            return new[:, 0]
+                        if lanes == 2:  # (M, 2T, …) → (M, 2, T, …)
+                            return new.reshape(*rows_at.shape, *new.shape[2:])
+                        return new
+
                     with jax.named_scope("mst.attn.kv_write"):
-                        kl = put(kl, k_new[:, 0] if T == 1 else k_new)
-                        vl = put(vl, v_new[:, 0] if T == 1 else v_new)
+                        kl = put(kl, rows_of(k_new))
+                        vl = put(vl, rows_of(v_new))
                         done["k"] = jax.tree.map(as_given, kl)
                         done["v"] = jax.tree.map(as_given, vl)
                     with jax.named_scope(scope):
                         kl = jax.tree.map(attended, kl)
                         vl = jax.tree.map(attended, vl)
                         if T > 1:
+                            two = {} if lead is None else dict(
+                                # a group is folded by query, then by head:
+                                # lane 1 is its leading T x G rows
+                                lead_lengths=lead,
+                                lead_rows=T * q.shape[2] // layout["kv_heads"],
+                            )
                             return fold_block_queries(
                                 lambda q1: paged_attention(
                                     q1, kl, vl, tbl, lengths, model.scale,
-                                    **layout,
+                                    **layout, **two,
                                 ),
                                 q, layout["kv_heads"],
                             )
@@ -1619,6 +1660,8 @@ class PipelineEngine:
                     lambda x, was: x.reshape(was.shape), (k, v), stacked
                 )
 
+            if lanes == 2:  # the head reads the lane that denoises
+                h = jnp.where(second[:, None, None], h[:, T:], h[:, :T])
             out = jnp.where(active[:, None, None], h, 0).astype(cdt)
             out = jax.lax.psum(out, AXIS_PP)  # identity at S=1; keeps the
             # body shape identical to the gather one
@@ -1642,6 +1685,7 @@ class PipelineEngine:
                 spec_rep,  # n_valid
                 spec_rep,  # page table
                 spec_stage,  # recurrent state pool (None: no leaves)
+                *([spec_rep] if lanes == 2 else []),  # second (M,)
             ),
             out_specs=(spec_rep, self._kv_spec, self._kv_spec, spec_stage),
             check_vma=False,
@@ -1740,36 +1784,42 @@ class PipelineEngine:
 
         return jax.jit(decode_step, donate_argnums=(5, 7, 8))
 
-    def diffusion_cb(self, want_lp: bool):
+    def diffusion_cb(self, want_lp: bool, wide: bool):
         """``decode_cb`` of a model that generates by diffusion over blocks:
-        one forward over every slot's whole block through the ragged body,
-        then ``diffusion.block_forward``. Takes the batcher's block state
-        where ``decode_cb``'s step takes the last tokens, and returns ``(out,
-        blk, cache, recent, keys)``: a slot's offset advances by a block
-        where the forward committed one."""
-        if want_lp not in self._diffusion_cbs:
+        one forward over every slot's block through the ragged body, then
+        ``diffusion.block_forward``. ``wide``: the forward of two lanes a
+        slot — a finished block's commit with the next block's denoise
+        behind it — where the narrow one computes one block a slot
+        (``diffusion.py``; a block program alternates the two). Takes the
+        batcher's block state where ``decode_cb``'s step takes the last
+        tokens, and returns ``(out, blk, cache, recent, keys)``: a slot's
+        offset advances by a block where the forward stored one."""
+        if (want_lp, wide) not in self._diffusion_cbs:
             cfg = self.model.config
-            if self._diffusion_body is None:  # one body for both variants
-                self._diffusion_body = self._build_smapped_ragged()
-            inner = self._diffusion_body
+            lanes = 2 if wide else 1
+            if lanes not in self._diffusion_bodies:  # one for both variants
+                self._diffusion_bodies[lanes] = self._build_smapped_ragged(lanes)
+            inner = self._diffusion_bodies[lanes]
             length = jnp.asarray(self.diffusion_block, jnp.int32)
 
             def diffusion_step(layer_params, masks, vparts, shared, blk, cache,
                                active, recent, keys, sp, rep_sizes, table):
+                tokens, live, second = diffusion.forward_input(blk, active, wide)
                 logits, k, v, state = inner(
-                    layer_params, masks, vparts, shared, blk["ids"], cache.k,
-                    cache.v, cache.offset, active, length, table, cache.state,
+                    layer_params, masks, vparts, shared, tokens, cache.k,
+                    cache.v, cache.offset, live, length, table, cache.state,
+                    *([second] if wide else []),
                 )
                 out, blk, offset, recent, keys = diffusion.block_forward(
-                    blk, logits, cache.offset, active, recent, keys, sp,
+                    blk, logits, cache.offset, live, second, recent, keys, sp,
                     rep_sizes, cfg=cfg, want_lp=want_lp,
                 )
                 return out, blk, KVCache(
                     k=k, v=v, offset=offset, state=state
                 ), recent, keys
 
-            self._diffusion_cbs[want_lp] = diffusion_step
-        return self._diffusion_cbs[want_lp]
+            self._diffusion_cbs[want_lp, wide] = diffusion_step
+        return self._diffusion_cbs[want_lp, wide]
 
     # ------------------------------------ speculative continuous batching
     def spec_propose_cb(self, K: int):

@@ -735,6 +735,8 @@ class ContinuousBatcher:
             blk = dict(blk)
             blk["ids"] = blk["ids"].at[slot].set(u.take((length,)))
             blk["masked"] = blk["masked"].at[slot].set(u.take((length,)) > 0)
+            # (the stream that left the slot never had its last block stored)
+            blk["pending"] = blk["pending"].at[slot].set(False)
             none = jnp.asarray(-1, jnp.int32) if logits is None else jnp.where(
                 jnp.isnan(logits.reshape(-1)[0]), -2, -1
             ).astype(jnp.int32)
@@ -2787,24 +2789,31 @@ class ContinuousBatcher:
         if want_lp not in self._decode_block_progs:
             eng = self.engine
             M = self.M
-            step = (
-                eng.diffusion_cb(want_lp) if self._diffusion else eng.decode_cb()
-            )
+            if self._diffusion:
+                # a step is one forward over every slot's block — wide (a
+                # finished block's commit, the next block's denoise behind
+                # it) then narrow, by turns; ``tok`` is the blocks and their
+                # masks, and what the host reads of a forward is a tree
+                # (diffusion.block_forward)
+                steps = [eng.diffusion_cb(want_lp, wide) for wide in (True, False)]
+            else:
+                step = eng.decode_cb()
 
             def block(layer_params, masks, vparts, shared, tok, cache, active,
                       recent, keys, sp, rep_sizes, table):
-                def body(carry, _):
-                    tok, cache, recent, keys = carry
-                    if self._diffusion:
-                        # a step is one forward over every slot's block;
-                        # ``tok`` is the blocks and their masks, and what
-                        # the host reads of a forward is a tree
-                        # (diffusion.block_forward)
-                        out, tok, cache, recent, keys = step(
+                def forwards(carry, steps):
+                    outs = []
+                    for step in steps:
+                        tok, cache, recent, keys = carry
+                        out, *carry = step(
                             layer_params, masks, vparts, shared, tok, cache,
                             active, recent, keys, sp, rep_sizes, table,
                         )
-                        return (tok, cache, recent, keys), out
+                        outs.append(out)
+                    return tuple(carry), outs
+
+                def body(carry, _):
+                    tok, cache, recent, keys = carry
                     tok, logprobs, cache, recent, keys = step(
                         layer_params, masks, vparts, shared, tok, cache,
                         active, recent, keys, sp, rep_sizes, table,
@@ -2815,11 +2824,33 @@ class ContinuousBatcher:
                         out = (tok,)
                     return (tok, cache, recent, keys), out
 
-                (tok, cache, recent, keys), outs = jax.lax.scan(
-                    body, (tok, cache, recent, keys), None,
-                    length=self.decode_block,
-                )
-                return outs, tok, cache, recent, keys
+                carry = (tok, cache, recent, keys)
+                if self._diffusion:
+                    # pairs of a wide and a narrow forward, scanned; an odd
+                    # block ends on one more wide forward
+                    pairs, odd = divmod(self.decode_block, 2)
+                    carry, outs = jax.lax.scan(
+                        lambda c, _: forwards(c, steps), carry, None,
+                        length=pairs,
+                    )
+                    # (pairs, …) twice → (2 * pairs, …), forward by forward
+                    outs = jax.tree.map(
+                        lambda a, b: jnp.stack([a, b], axis=1).reshape(
+                            -1, *a.shape[1:]
+                        ),
+                        *outs,
+                    )
+                    if odd:
+                        carry, (last,) = forwards(carry, steps[:1])
+                        outs = jax.tree.map(
+                            lambda a, b: jnp.concatenate([a, b[None]]),
+                            outs, last,
+                        )
+                else:
+                    carry, outs = jax.lax.scan(
+                        body, carry, None, length=self.decode_block
+                    )
+                return (outs, *carry)
 
             # The CPU client executes donated computations inline at
             # dispatch (no async stream to alias on), which would serialize
@@ -3564,24 +3595,27 @@ class ContinuousBatcher:
         """``_emit_block`` for a family that generates by diffusion over
         blocks: ``outs`` is ``diffusion.block_forward``'s tree over the
         program's forwards, and a slot is handed the blocks its forwards
-        committed — none, or a few of ``L`` tokens each — in order. A
+        finished — none, or a few of ``L`` tokens each — in order, each at
+        the forward that transferred its last masked position (its K/V is
+        stored by a later forward, or never: a stream's last block). A
         position's log-probabilities are those of the forward that
-        transferred it. Every position a forward computed is emitted or
-        dropped, counted here: ``denoise`` is a forward's rows that were not
-        a commit's new tokens (a denoise forward's, a first block's prompt
-        tail), the other reasons as ever."""
+        transferred it. Every position a forward gave logits for is emitted
+        or dropped, counted here: ``denoise`` is a forward's rows that were
+        not a finished block's new tokens (a forward that left positions
+        masked, a slot that stood still for a narrow forward, a first
+        block's prompt tail), the other reasons as ever."""
         L = self._diffusion
-        commit, ids = outs["commit"], outs["ids"]  # (K, M), (K, M, L)
+        done, ids = outs["done"], outs["ids"]  # (K, M), (K, M, L)
         live = inf.live
         stats = self._diffusion_stats
-        stats["slot_forwards"] += commit.shape[0] * len(live)
+        stats["slot_forwards"] += done.shape[0] * len(live)
         slots = [slot for slot, _ in live]
         stats["by_rank"] += int(outs["by_rank"][:, slots].sum())
         stats["by_confidence"] += int(outs["by_confidence"][:, slots].sum())
         left, emitted, denoise, finished, cancelled = inf.positions, 0, 0, 0, 0
-        commits = dict.fromkeys(slots, 0)
+        blocks = dict.fromkeys(slots, 0)
         try:
-            for j in range(commit.shape[0]):
+            for j in range(done.shape[0]):
                 for slot, req in live:
                     left -= L
                     if req.slot != slot:  # the slot was left earlier
@@ -3590,11 +3624,11 @@ class ContinuousBatcher:
                         else:
                             cancelled += L
                         continue
-                    if not commit[j, slot]:
+                    if not done[j, slot]:
                         denoise += L
                         continue
                     stats["blocks_committed"] += 1
-                    commits[slot] += 1
+                    blocks[slot] += 1
                     skip, req._block_skip = req._block_skip, 0
                     denoise += skip
                     for i in range(skip, L):
@@ -3620,7 +3654,7 @@ class ContinuousBatcher:
             tr = req._trace
             if tr is not None:
                 tr.add("denoise", t0, t1, slot=slot,
-                       forwards=int(commit.shape[0]), commits=commits[slot])
+                       forwards=int(done.shape[0]), blocks=blocks[slot])
 
     def _decode_once(self):
         # the sync composition point — MultiHostBatcher overrides THIS to

@@ -54,8 +54,8 @@ SPAN_TYPES = (
     "handoff_import",
     "decode_tick",
     # diffusion over blocks: a harvested decode program of such a family, in
-    # decode_tick's place (``forwards`` it ran, ``commits``: blocks of the
-    # request it committed and handed on)
+    # decode_tick's place (``forwards`` it ran, ``blocks``: blocks of the
+    # request its forwards finished and handed on)
     "denoise",
     "spill",
     "wake",

@@ -236,7 +236,7 @@ def _render_tick_phases(lines: list, t: dict):
     d = t.get("diffusion")
     if d is not None:
         # a family that generates by diffusion over blocks (diffusion.py):
-        # its forwards, commits and transfers, counted at the harvest
+        # its forwards, finished blocks and transfers, counted at the harvest
         lines += [
             "# TYPE mst_diffusion_slot_forwards_total counter",
             f"mst_diffusion_slot_forwards_total {d['slot_forwards']}",
@@ -1161,13 +1161,19 @@ _HELP = {
         "slot was given up earlier: consumer gone, preempted), "
         "abandoned_block (futures dropped) or, where the family generates by "
         "diffusion over blocks, denoise (a forward's rows that were not a "
-        "commit's new tokens).",
+        "finished block's new tokens).",
     "mst_diffusion_slot_forwards_total":
         "Diffusion over blocks: forwards x live slots of harvested decode "
-        "programs (a forward computes a block of positions a slot).",
+        "programs. A slot-forward is one lane (a block of positions, "
+        "denoising) or two (a finished block's commit and the next block's "
+        "first denoise behind it); one a slot stood still for (it held a "
+        "finished block at a narrow forward) is counted too.",
     "mst_diffusion_blocks_committed_total":
-        "Diffusion over blocks: blocks committed (each by one commit "
-        "forward of its slot) and handed to their streams.",
+        "Diffusion over blocks: blocks finished and handed to their "
+        "streams, each at the forward that transferred its last masked "
+        "position; its K/V is stored by its slot's next wide forward, a "
+        "stream's last block's never. Two slot-forwards a block at 2 "
+        "denoising steps under a rank order.",
     "mst_diffusion_tokens_transferred_total":
         "Diffusion over blocks: masked positions that took their sampled "
         "token, by what chose them: rank (the strategy's n a forward) or "

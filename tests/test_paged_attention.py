@@ -114,6 +114,70 @@ def test_op_parity_matrix(hq, hkv, interpret, window):
     assert not np.asarray(got)[LENGTHS.index(0)].any()  # the empty slot
 
 
+# (lengths, lead lengths) of a call with two bounds a slot: the leading rows of
+# every K/V head's query group see ``[0, lead)``, the rest ``[0, length)`` —
+# a wide forward of a family that generates by diffusion over blocks of 4
+# (``parallel/pipeline.py``): lane 1 a block whose K/V is being committed,
+# lane 2 the next block, denoising
+TWO_LENGTHS = {
+    # both lanes live, a block apart, mid-page and across pages
+    "uneven": ([9 + 4, 20 + 4, 31 + 1, 4 + 4], [9, 20, 31, 4]),
+    # lane 1 ends on a page border, lane 2 lies in the next page
+    "straddles-a-page": ([PAGE + 4, 2 * PAGE + 4, 3 * PAGE + 4], [PAGE, 2 * PAGE, 3 * PAGE]),
+    # lane 1 sees nothing (a bound of 0 beside a live length): zeros
+    "lane-1-inactive": ([4, 12, 27], [0, 0, 0]),
+    # lane 2 is not computed: both bounds are lane 1's
+    "lane-2-inactive": ([8, 13, SPG * PAGE], [8, 13, SPG * PAGE]),
+    # an empty slot between live ones
+    "length-0": ([12, 0, 0, 24], [8, 0, 0, 20]),
+}
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["xla", "kernel"])
+@pytest.mark.parametrize("merged", [False, True], ids=["heads-apart", "heads-merged"])
+@pytest.mark.parametrize("case", list(TWO_LENGTHS))
+def test_two_lengths_a_query_group(case, merged, interpret):
+    """``lead_lengths`` / ``lead_rows``: both paths against plain attention
+    a row, each row under the bound of its place in its group (4 K/V heads'
+    groups of 2 lanes x 2 queries x 2 heads: the leading 4 rows are lane 1);
+    rows bounded at 0 are zeros whatever the slot's other bound."""
+    lengths, lead = TWO_LENGTHS[case]
+    rng = np.random.default_rng(4)
+    hkv, g, lead_rows, d = 2, 8, 4, 16
+    q, k_pool, v_pool, tables, dense_k, dense_v = _make_case(
+        rng, lengths, hkv * g, hkv, d, d
+    )
+    first = (np.arange(hkv * g) % g) < lead_rows  # (Hq,): a lane-1 row
+    want = np.where(
+        first[None, :, None],
+        _ref(q, dense_k, dense_v, lead, d ** -0.5),
+        _ref(q, dense_k, dense_v, lengths, d ** -0.5),
+    )
+    pools = [jnp.asarray(x) for x in (k_pool, v_pool)]
+    if merged:
+        pools = [x.reshape(*x.shape[:2], 1, -1) for x in pools]
+    got = paged_attention(
+        jnp.asarray(q), *pools, jnp.asarray(tables),
+        jnp.asarray(lengths, jnp.int32), d ** -0.5,
+        kv_heads=hkv if merged else None,
+        lead_lengths=jnp.asarray(lead, jnp.int32), lead_rows=lead_rows,
+        interpret=interpret,
+    )
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
+    for i, (ln, ld) in enumerate(zip(lengths, lead)):
+        assert ld or not np.asarray(got)[i][first].any()
+        assert ln or not np.asarray(got)[i].any()
+
+
+def test_two_lengths_go_together_and_without_a_window():
+    q, k, v = jnp.zeros((1, 4, 16)), jnp.zeros((5, PAGE, 2, 16)), jnp.zeros((5, PAGE, 2, 16))
+    tables, lens = jnp.zeros((1, SPG), jnp.int32), jnp.ones((1,), jnp.int32)
+    for kw in ({"lead_rows": 2}, {"lead_lengths": lens},
+               {"lead_rows": 2, "lead_lengths": lens, "sliding_window": 4}):
+        with pytest.raises(ValueError, match="lead_lengths and lead_rows"):
+            paged_attention(q, k, v, tables, lens, 0.25, **kw)
+
+
 # (ring pages R, window, lengths): position p of slot m lives at ring page
 # m * R + (p // PAGE) % R, as the engine lays a window layer out; the table
 # is the ring repeated over the slot's logical pages. Lengths that wrap the

@@ -5,7 +5,9 @@ benchmark's seeded weights on both sides.
 
 Sizes: pages and chunks of 8 (two blocks a chunk), two layers, 4 query heads
 on 2 K/V heads of 32 merged on 64 lanes, 8 experts, 2 a token, blocks of 4 at 2
-denoising steps (3 forwards a block), mask id 0. Prompts of every ``P mod L``:
+denoising steps (2 forwards a block on the served path: a block's commit rides
+the next block's first denoise forward, where the published loop and the
+reference take 3), mask id 0. Prompts of every ``P mod L``:
 0 (the first decode block is all masks), 1, 2 and 3 (it starts with prompt
 tokens), and one shorter than a block (no prefill chunk at all).
 
@@ -104,6 +106,43 @@ def tiny():
     return model, seeded_params(TINY)
 
 
+def transferred_at(forwards, n_prompt, n):
+    """``[(log-probabilities (V,), block, forward of the block)]`` of the
+    ``n`` generated positions, each at the forward of ``ref.generate``'s
+    published loop that transferred it."""
+    out, seen = {}, {}
+    for b, _, _, lp, move in forwards:
+        step = seen[b] = seen.get(b, -1) + 1
+        for i in np.flatnonzero(move):
+            out[b * L + int(i) - n_prompt] = (lp[i], b, step)
+    return [out[j] for j in range(n)]
+
+
+@pytest.fixture(scope="module", params=ref.STRATEGIES)
+def by_strategy(request, tiny, batcher):
+    """``(cfg, batcher)`` for each ``remasking_strategy``: one slot and sync
+    ticks (a harvest's counters are then exactly its stream's), but for
+    ``sequential``, which is the module's batcher."""
+    if request.param == TINY["remasking_strategy"]:
+        yield TINY, batcher
+        return
+    cfg = {**TINY, "remasking_strategy": request.param}
+    model, _ = build_model(cfg)
+    b = ContinuousBatcher(make_engine(model, tiny[1], slots=1), decode_block=4,
+                          async_sched="off")
+    yield cfg, b
+    b.close()
+
+
+@pytest.fixture(scope="module")
+def sync_batcher(tiny):
+    """One slot, sync ticks, blocks of 4 forwards: what a harvest counts is
+    what its one stream did."""
+    b = ContinuousBatcher(make_engine(*tiny, slots=1), decode_block=4, async_sched="off")
+    yield b
+    b.close()
+
+
 @pytest.fixture(scope="module")
 def batcher(tiny):
     b = ContinuousBatcher(make_engine(*tiny), decode_block=4)
@@ -194,11 +233,12 @@ def test_the_rule_that_transfers():
 @pytest.mark.parametrize("name", list(PROMPTS))
 def test_prefill_denoise_and_commit_through_the_pages_match_the_reference(batcher, name):
     """The prompt's whole blocks prefilled in chunks under the block mask,
-    the first decode block ``[prompt tail | masks]``, then 3 forwards a block
-    through the page pool (each writing its 4 rows, the commit's kept): the
-    tokens are the published loop's, and each position's log-probabilities
-    those of the forward that transferred it. 14 tokens: ``max_tokens`` falls
-    inside a block for every ``P mod L`` but 2, and the tail is dropped."""
+    the first decode block ``[prompt tail | masks]``, then a wide and a narrow
+    forward a block through the page pool (each writing its rows, the
+    commit's kept): the tokens are the published loop's, and each position's
+    log-probabilities those of the forward that transferred it. 14 tokens:
+    ``max_tokens`` falls inside a block for every ``P mod L`` but 2, and the
+    tail is dropped."""
     prompt = PROMPTS[name]
     dropped0 = batcher.tick_phase_stats()["tokens_dropped"]["slot_finished"]
     got = served(batcher, prompt, 14)
@@ -238,10 +278,13 @@ def test_a_prompt_that_contains_the_mask_id_is_served_right(batcher):
 @hard_timeout(900)
 def test_slots_at_different_phases_share_a_forward(batcher):
     """Three requests on two slots with prompts of different ``P mod L``
-    (first blocks of 2 and 3 forwards) and lengths: the slots denoise and
-    commit in different forwards of one program, the third joins while
-    another decodes and takes a left slot; each against the reference,
-    LOGITS not tokens. The counters add up and ``/metrics`` shows them."""
+    (first blocks of 2 and 1 forwards: slots a forward out of phase until the
+    one ahead stands still for a narrow forward) and lengths: one slot's
+    commit and next denoise share a wide forward with the other's first
+    denoise, the third joins while another decodes and takes a left slot —
+    with the block its last stream never had stored still pending there; each
+    against the reference, LOGITS not tokens. The counters add up and
+    ``/metrics`` shows them."""
     from mlx_sharding_tpu.utils.observability import ServingMetrics
 
     jobs = {1: 9, 0: 14, 3: 11}
@@ -285,11 +328,33 @@ def test_slots_at_different_phases_share_a_forward(batcher):
 
 
 @hard_timeout(900)
+@pytest.mark.parametrize("name", [0, 1, 3])
+def test_every_strategy_is_the_published_loop_token_and_log_probability(by_strategy, name):
+    """All three strategies at ``P mod L`` of 0, 1 and 3 (first blocks of 2,
+    2 and 1 forwards: the last stands still for the narrow forward behind it)
+    against the plain loop, ``want_lp`` on, 14 tokens (the stream ends inside
+    a block): the same tokens, and every position's served top
+    log-probabilities are the loop's at the forward that transferred it — the
+    confidence orders too, which the check's one pass cannot replay."""
+    cfg, b = by_strategy
+    prompt, n = PROMPTS[name], 14
+    got = served(b, prompt, n)
+    want, forwards = ref.generate(cfg, "bf16", SEED, prompt, n, pad_to=MAX_SEQ)
+    assert [t for t, _ in got] == want
+    for (tok, top), (lp, _, _) in zip(got, transferred_at(forwards, len(prompt), n)):
+        assert 0 not in top and tok == max(top, key=top.get)
+        np.testing.assert_allclose(
+            [top[i] for i in top], [lp[i] for i in top], atol=LP_TOL)
+
+
+@hard_timeout(900)
 @pytest.mark.parametrize("strategy", ["low_confidence_static", "low_confidence_dynamic"])
-def test_the_confidence_orders_are_the_published_loop_s(tiny, strategy):
+def test_the_confidence_orders_move_what_the_published_loop_moves(tiny, strategy):
     """The two strategies the check cannot replay, against the plain loop:
     the same tokens, and the same positions moved by rank and by passing the
-    threshold (set at the confidences' median: module docstring)."""
+    threshold (set at the confidences' median: module docstring). A block
+    that passed the threshold whole took one forward in the loop; here its
+    slot then stands still for the narrow forward behind it."""
     cfg = {**TINY, "remasking_strategy": strategy}
     model, _ = build_model(cfg)
     b = ContinuousBatcher(make_engine(model, tiny[1], slots=1), decode_block=4)
@@ -318,6 +383,89 @@ def test_the_confidence_orders_are_the_published_loop_s(tiny, strategy):
         assert stats["by_confidence"] == 0
 
 
+def _stream(b, prompt, n):
+    """``(tokens, what the stream added to the batcher's diffusion counters,
+    the slot's offset behind it)`` of one stream on a sync batcher of one
+    slot."""
+    s0 = b.tick_phase_stats()["diffusion"]
+    toks = [t for t, _ in b.generate_step(prompt, max_tokens=n)]
+    s1 = b.tick_phase_stats()["diffusion"]
+    return toks, {k: s1[k] - s0[k] for k in s1}, int(b.cache.offset[0])
+
+
+# (P mod L, tokens) -> (programs of 4 forwards, blocks handed on, blocks whose
+# K/V a forward stored before the stream's last harvest)
+COUNTED = {
+    # [d1 | d2 b0 | commit b0, d1 b1 | d2 b1]: 8 tokens out of ONE program, where the
+    # three-forward loop hands on b0 at its 3rd forward and b1 at its 6th; b1 is never stored
+    "two-blocks-one-program": (0, 8, 1, 2, 1),
+    # 4 blocks, 8 forwards: two slot-forwards a block
+    "two-forwards-a-block": (0, 16, 2, 4, 3),
+    # a first block of one forward: [d b0 | stands still | commit b0, d1 b1 | d2 b1]
+    "a-first-block-of-one-forward-waits": (3, 5, 1, 2, 1),
+    # the stream ends inside its fourth block, at its second program's last forward
+    "ends-inside-a-block": (1, 13, 2, 4, 3),
+    # ... and inside its third, at the second forward of a program: the device runs the
+    # slot on to the harvest, and the wide forward behind stores a block nobody reads
+    "runs-on-to-its-harvest": (1, 9, 2, 3, 3),
+}
+
+
+@hard_timeout(900)
+@pytest.mark.parametrize("case", list(COUNTED))
+def test_the_counters_of_one_stream(sync_batcher, case):
+    """Slot-forwards a block 2 under ``sequential``, a block's tokens handed
+    on at the forward that transferred its last masked position (one forward
+    earlier than the commit that used to emit them), and the stream's LAST
+    block never stored where the stream ends with its program: the slot's
+    offset stops in front of it, and every token arrived all the same."""
+    name, n, programs, blocks, stored = COUNTED[case]
+    prompt = PROMPTS[name]
+    toks, d, offset = _stream(sync_batcher, prompt, n)
+    assert toks == ref.generate(TINY, "bf16", SEED, prompt, n, pad_to=MAX_SEQ)[0]
+    assert d["slot_forwards"] == 4 * programs and d["blocks_committed"] == blocks
+    assert offset == len(prompt) // L * L + stored * L
+    assert d["by_rank"] >= n and d["by_confidence"] == 0
+
+
+@hard_timeout(900)
+def test_later_blocks_read_the_committed_rows(sync_batcher, tiny):
+    """What a wide forward's first lane stores: after a stream of five blocks
+    the slot's pages hold, for every block whose K/V was stored, the rows the
+    model's own forward over the CLEAN sequence writes (final ids under the
+    block mask) — not a denoise forward's (the fault ``commit_stale_kv``
+    stands for: rows computed from ids still masked), and nothing of the
+    block behind, whose denoise rows rode the same forward."""
+    model, params = tiny
+    prompt, n = PROMPTS[2], 18  # P mod L = 2: blocks at 8, 12, 16, 20, 24
+    toks, _, offset = _stream(sync_batcher, prompt, n)
+    assert offset in (24, 28)  # the four blocks in front of the last, or all five
+    table = np.asarray(sync_batcher.table[0])  # (a slot's row outlives its release)
+    clean = (prompt + toks)[:offset]
+    _, cache = model(params, jnp.asarray(clean)[None], model.make_cache(1, MAX_SEQ, jnp.float32))
+    stale = (prompt + [0] * n)[:offset]  # ids still masked, as a first denoise forward has them
+    _, wrong = model(params, jnp.asarray(stale)[None], model.make_cache(1, MAX_SEQ, jnp.float32))
+    for have, want, other in ((sync_batcher.cache.k, cache.k, wrong.k),
+                              (sync_batcher.cache.v, cache.v, wrong.v)):
+        pool = np.asarray(have)[0]  # (layers, pages + 1, 1, page, 1, 64)
+        rows = pool[:, table[: -(-offset // PAGE)], 0].reshape(pool.shape[0], -1, 64)[:, :offset]
+        np.testing.assert_allclose(rows, np.asarray(want)[:, 0, :offset, 0], atol=1e-5)
+        assert np.abs(rows - np.asarray(other)[:, 0, :offset, 0]).max() > 1e-2
+
+
+@hard_timeout(900)
+def test_a_stream_that_fills_max_seq_ends_right(sync_batcher):
+    """``prompt + max_tokens == max_seq``: the wide forward that commits the
+    last-but-one block writes the last block's rows into the slot's last
+    page, and the one behind it (the device runs a finished stream's slot
+    until its harvest) has no page for its second lane: those rows go to the
+    scratch page, and the stream is the published loop's to its last token."""
+    prompt, n = PROMPTS[0], MAX_SEQ - len(PROMPTS[0])
+    toks, d, _ = _stream(sync_batcher, prompt, n)
+    assert toks == ref.generate(TINY, "bf16", SEED, prompt, n, pad_to=MAX_SEQ)[0]
+    assert d["blocks_committed"] == n // L and d["slot_forwards"] == 2 * n // L
+
+
 @hard_timeout(600)
 def test_sampled_rows_draw_under_their_own_keys_and_never_the_mask(batcher):
     """Temperature and top-p go through ``sample.py``'s per-row transforms: a
@@ -333,7 +481,9 @@ def test_sampled_rows_draw_under_their_own_keys_and_never_the_mask(batcher):
 @hard_timeout(600)
 def test_a_request_s_trace_shows_its_denoise_spans(batcher):
     """One ``denoise`` span a harvested program, with its forwards and the
-    blocks they committed for the request; TTFT is the first block's commit."""
+    blocks they finished for the request; TTFT is the first block's last
+    transfer. The first program of 4 forwards hands on TWO blocks (its 2nd and
+    4th forward; the three-forward loop's handed on one, at its 3rd)."""
     tracer = tracing.configure("on", buffer=8)
     try:
         tr = tracing.begin("sdar-1")
@@ -345,35 +495,44 @@ def test_a_request_s_trace_shows_its_denoise_spans(batcher):
     assert n == 10
     spans = [s for s in frozen["spans"] if s[0] == "denoise"]
     assert spans and all(s[3]["forwards"] == 4 for s in spans)
-    assert sum(s[3]["commits"] for s in spans) == 3  # ceil((1 + 10) / 4) blocks handed on
+    assert sum(s[3]["blocks"] for s in spans) == 3  # ceil((1 + 10) / 4) blocks handed on
+    assert spans[0][3]["blocks"] == 2
     assert "decode_tick" not in {s[0] for s in frozen["spans"]}
     first = next(t for name, t, _ in frozen["marks"] if name == "first_token")
-    committing = [s for s in spans if s[3]["commits"]]
-    assert committing[0][1] <= first  # stamped while the first committing program is emitted
+    assert spans[0][1] <= first  # stamped while the first program is emitted
 
 
 # ------------------------------------------------ kernel, share, refusals
 
 
 @pytest.mark.parametrize("lengths", [[8, 20, 0, 44], [4, 4, 64, 12]])
-def test_the_kernel_at_a_folded_group_of_32_is_the_xla_path(lengths):
+@pytest.mark.parametrize("per_lane,lanes", [(4, 1), (2, 2), (4, 2)],
+                         ids=["group-32", "group-32-two-lengths", "group-64-two-lengths"])
+def test_the_kernel_at_a_folded_group_of_32_is_the_xla_path(lengths, per_lane, lanes):
     """``ops/paged_attention.py``'s kernel in interpret mode with a block's 4
     queries folded into the query group (4 x 8 = 32 a K/V head, 4 K/V heads
     merged on the lanes) against ``_paged_attention_xla`` a query at a time:
-    every query of a slot sees the same keys, so no mask is new."""
+    every query of a slot sees the same keys, so no mask is new. With two
+    lanes (two blocks of 2, or of 4: a group of 64) the fold puts lane 1 in
+    the group's leading rows, which see a block less (``lead_lengths``): each
+    query against the XLA path under its own lane's length."""
     rs = np.random.default_rng(7)
     m, hq, hkv, d, page, spg = 4, 32, 4, 16, 8, 8
-    q = jnp.asarray(rs.normal(size=(m, L, hq, d)), jnp.float32)
+    t = per_lane * lanes
+    q = jnp.asarray(rs.normal(size=(m, t, hq, d)), jnp.float32)
     k = jnp.asarray(rs.normal(size=(m * spg + 1, page, 1, hkv * d)), jnp.float32)
     v = jnp.asarray(rs.normal(size=(m * spg + 1, page, 1, hkv * d)), jnp.float32)
     tables = jnp.asarray(rs.permutation(m * spg).reshape(m, spg), jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
+    lead = jnp.maximum(lens - per_lane, 0)  # lane 1 sees a block less
+    two = {} if lanes == 1 else dict(lead_lengths=lead, lead_rows=per_lane * hq // hkv)
     have = fold_block_queries(
         lambda q1: paged_attention(q1, k, v, tables, lens, d ** -0.5, kv_heads=hkv,
-                                   interpret=True), q, hkv)
-    assert have.shape == (m, L, hq, d)
-    for i in range(L):
-        want = _paged_attention_xla(q[:, i], k, v, tables, lens, d ** -0.5, None, None,
+                                   interpret=True, **two), q, hkv)
+    assert have.shape == (m, t, hq, d)
+    for i in range(t):
+        seen = lead if lanes == 2 and i < per_lane else lens
+        want = _paged_attention_xla(q[:, i], k, v, tables, seen, d ** -0.5, None, None,
                                     None, kv_heads=hkv)
         np.testing.assert_allclose(have[:, i], want, atol=2e-5)
 

@@ -70,29 +70,35 @@ def _flash(t, s, hq, hkv, dk, dv, via_dispatch=True, kernel="flash_attention"):
 
 
 def _paged(page, int8, slots=8, hq=24, hkv=8, d=128, max_seq=4096,
-           pages=None, rank=None, window=None, merged=False):
+           pages=None, rank=None, window=None, merged=False, lead=0):
     """``rank``: MLA's latent layout — the values are the first ``rank``
     lanes of the ``d``-wide key rows (``values_from_k``) and V is the dummy
     ``(…, 1, 1)`` pool the kernel is never handed. ``window``: a sliding
     window known at trace time (the block index clamps to the visible
     pages). ``merged``: the pools keep a row's heads on the lane axis,
-    ``(pages, page, 1, Hkv * D)`` (``kv_heads``)."""
+    ``(pages, page, 1, Hkv * D)`` (``kv_heads``). ``lead``: that many leading
+    rows of each K/V head's query group are bounded by a second length
+    (``lead_lengths``, one more scalar-prefetch operand)."""
     pages = pages or slots * max_seq // page + 1
     dtype = jnp.int8 if int8 else BF16
     k_pool = ((pages, page, 1, hkv * d) if merged else (pages, page, hkv, d), dtype)
     v_pool = k_pool if rank is None else ((pages, page, 1, 1), dtype)
     scales = [((pages, page, p[0][2], 1), F32) for p in (k_pool, v_pool)]
 
-    def fn(q, k, v, tables, lengths, ks=None, vs=None):
+    def fn(q, k, v, tables, lengths, *more):
+        ks, vs = more[-2:] if int8 else (None, None)
         return paged_attention(q, k, v, tables, lengths, d ** -0.5,
                                values_from_k=rank, k_scale=ks, v_scale=vs,
                                sliding_window=window,
-                               kv_heads=hkv if merged else None)
+                               kv_heads=hkv if merged else None,
+                               **({"lead_lengths": more[0], "lead_rows": lead}
+                                  if lead else {}))
 
     return (
         fn,
         [((slots, hq, d), BF16), k_pool, v_pool,
          ((slots, max_seq // page), I32), ((slots,), I32)]
+        + ([((slots,), I32)] if lead else [])
         + (scales if int8 else []),
         "paged_attention",
     )
@@ -312,6 +318,12 @@ CASES = {
         512, False, slots=32, hq=128, hkv=4, max_seq=2048, pages=2 * 129,
         merged=True),
     "dense-experts-sdar-128x8of8": _dense_experts(128, 8, 8, 2048, 768),
+    # ... and its wide forward (two blocks a slot: the commit and the next
+    # block's denoise): a group of 2 x 4 x 8 = 64, the leading 32 rows under
+    # a length of their own
+    "paged-sdar-group64-two-lengths-page512": _paged(
+        512, False, slots=32, hq=256, hkv=4, max_seq=2048, pages=2 * 129,
+        merged=True, lead=32),
 }
 
 
@@ -549,7 +561,8 @@ def test_ssm_step_in_a_layer_scan_moves_nothing_but_its_blocks(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < rows
 
 
-def test_the_diffusion_decode_block_compiles_for_v5e(chip, monkeypatch):
+@pytest.mark.parametrize("lanes", [1, 2], ids=["narrow", "wide"])
+def test_the_diffusion_decode_block_compiles_for_v5e(lanes, chip, monkeypatch):
     """``sdar_moe``'s decode forward at the published widths, two layers, as
     the ragged body runs it (``parallel/pipeline.py`` at ``T = L = 4``): 32
     slots x 4 rows through ``sp_layer``; each layer scatters a slot's 4 rows
@@ -557,7 +570,10 @@ def test_the_diffusion_decode_block_compiles_for_v5e(chip, monkeypatch):
     one, a row's 4 K/V heads merged on 512 lanes), folds the 4 queries into
     the kernel's query group (Mosaic takes a group of 32) and multiplies its
     128 rows through the 8 held experts of 128 on ``dense_experts`` at the
-    kernel's bound of rows. No copy of the pool, no gathered table."""
+    kernel's bound of rows. No copy of the pool, no gathered table. Wide: two
+    lanes of 4 rows a slot, each scattered to a page of its own, a group of
+    64 whose leading 32 rows see a block less, and the experts a lane a call
+    (256 rows at once would leave the kernel for the loop)."""
     from mlx_sharding_tpu.models import build_model
     from mlx_sharding_tpu.models.base import scan_layers_carried
     from mlx_sharding_tpu.parallel.pipeline import fold_block_queries
@@ -573,20 +589,25 @@ def test_the_diffusion_decode_block_compiles_for_v5e(chip, monkeypatch):
     kv = cfg.num_key_value_heads * cfg.head_dim
 
     def step(stack, h, k, v, rows, page_ids, row_pos, offsets):
-        lengths = offsets + L
-        at = row_pos[:, None] + jnp.arange(L)[None, :]
+        lengths = offsets + lanes * L
+        two = {} if lanes == 1 else dict(lead_lengths=offsets + L, lead_rows=L * 8)
+        # (slots, lanes): lane 2's page is the one behind lane 1's here
+        ids = page_ids[:, None] + jnp.arange(lanes)[None, :]
+        at = jnp.broadcast_to(
+            row_pos[:, None, None] + jnp.arange(L)[None, None, :], (slots, lanes, L))
 
         def layer(h, p, k, v, row, keep):
             first = row * pages
 
             def attn_fn(q, k_new, v_new, kv_heads):
-                kl = k.at[page_ids[:, None] + first, at].set(k_new)
-                vl = v.at[page_ids[:, None] + first, at].set(v_new)
+                put = lambda pool, new: pool.at[ids[..., None] + first, at].set(  # noqa: E731
+                    new.reshape(slots, lanes, L, *new.shape[2:]))
+                kl, vl = put(k, k_new), put(v, v_new)
                 layer.done = kl, vl
                 return fold_block_queries(
                     lambda q1: paged_attention(
                         q1, kl, vl, rows + first, lengths, model.scale,
-                        kv_heads=kv_heads,
+                        kv_heads=kv_heads, **two,
                     ), q, kv_heads)
 
             h, _, _ = model.sp_layer(p, h, offsets, attn_fn)
@@ -600,14 +621,15 @@ def test_the_diffusion_decode_block_compiles_for_v5e(chip, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     stack = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))["layers"]
     pool = ((layers * pages, page, 1, kv), BF16)
-    shapes = [((slots, L, cfg.hidden_size), BF16), pool, pool, ((slots, spg), I32),
-              ((slots,), I32), ((slots,), I32), ((slots,), I32)]
+    shapes = [((slots, lanes * L, cfg.hidden_size), BF16), pool, pool,
+              ((slots, spg), I32), ((slots,), I32), ((slots,), I32), ((slots,), I32)]
     sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=chip)  # noqa: E731
     args = [jax.tree.map(lambda x: sds(x.shape, x.dtype), stack),
             *(sds(s, d) for s, d in shapes)]
     text = jax.jit(step, donate_argnums=(2, 3)).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     assert "paged_attention" in text and "dense_experts" in text
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", _loop_bodies(text))) == 1 + lanes
     # the only pool-sized things in the loop: each pool's scatter of the
     # slots' rows (a fusion around a scatter, in place on the carry)
     whole = f"bf16[{layers * pages},{page},{kv}]"
