@@ -710,6 +710,80 @@ MODEL_REMAPPING = {
     "qwen2": "llama",
 }
 
+
+@dataclass
+class OlmoHybridConfig(BaseConfig):
+    """Olmo Hybrid (``olmo_hybrid``): ``layer_types[i]`` picks each layer's
+    mixer — ``linear_attention``, a Gated DeltaNet (``ops/kda.py``) whose keys
+    are ``linear_key_head_dim`` wide and whose values ``linear_value_head_dim``
+    (a rectangular state a head), with ``beta`` in ``(0, 2)`` under
+    ``linear_allow_neg_eigval``; or ``full_attention``, multi-head attention
+    behind an RMSNorm over the WHOLE query and key projections and with NO
+    positional encoding (``rope_parameters.rope_theta`` null; a number is
+    refused: no rotary is wired here). Every layer has a dense SwiGLU MLP,
+    and Olmo 2's reordered norm: a sub-layer reads the residual stream
+    un-normed and its OUTPUT is normed before it is added."""
+
+    model_type: str = "olmo_hybrid"
+    rms_norm_eps: float = 1e-6
+    layer_types: Optional[list] = None
+    linear_num_key_heads: int = 30
+    linear_num_value_heads: int = 30
+    linear_key_head_dim: int = 96
+    linear_value_head_dim: int = 192
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = True
+    rope_parameters: Optional[dict] = None
+    attention_bias: bool = False
+    hidden_act: str = "silu"
+    max_position_embeddings: int = 65536
+
+    KINDS = {"linear_attention": "gdn", "full_attention": "attn"}
+
+    def __post_init__(self):
+        wired = {
+            "attention_bias": False, "hidden_act": "silu", "rope_scaling": None,
+            "tie_word_embeddings": False,
+        }
+        for key, want in wired.items():
+            if getattr(self, key) != want:
+                raise ValueError(
+                    f"olmo_hybrid is wired for {key} = {want!r}, not "
+                    f"{getattr(self, key)!r}"
+                )
+        if (self.rope_parameters or {}).get("rope_theta") is not None:
+            raise ValueError(
+                "olmo_hybrid is wired for rope_parameters.rope_theta = None "
+                "(no positional encoding on the full-attention layers)"
+            )
+        if self.layer_types is None:  # the published period: three linear, one full
+            self.layer_types = [
+                "full_attention" if (i + 1) % 4 == 0 else "linear_attention"
+                for i in range(self.num_hidden_layers)
+            ]
+        self.layer_types = list(self.layer_types)
+        if len(self.layer_types) != self.num_hidden_layers:
+            raise ValueError("layer_types must name num_hidden_layers layers")
+        unknown = sorted(set(self.layer_types) - set(self.KINDS))
+        if unknown:
+            raise ValueError(f"unknown layer_types {unknown}")
+        if self.linear_num_value_heads % self.linear_num_key_heads:
+            raise ValueError(
+                "linear_num_key_heads must divide linear_num_value_heads"
+            )
+        super().__post_init__()
+        if self.head_dim * self.num_attention_heads != self.hidden_size:
+            raise ValueError(
+                "olmo_hybrid norms q and k over hidden_size channels: "
+                "num_attention_heads * head_dim must equal it"
+            )
+
+    @property
+    def layer_kinds(self) -> list:
+        """Each layer's mixer, ``"attn"`` or ``"gdn"``, 0-based."""
+        return [self.KINDS[t] for t in self.layer_types]
+
+
 CONFIG_REGISTRY: dict[str, type] = {
     "llama": LlamaConfig,
     "qwen3": Qwen3Config,
@@ -723,6 +797,7 @@ CONFIG_REGISTRY: dict[str, type] = {
     "kimi_linear": KimiLinearConfig,
     "qwen3_next": Qwen3NextConfig,
     "sdar_moe": SdarMoeConfig,
+    "olmo_hybrid": OlmoHybridConfig,
 }
 
 

@@ -28,6 +28,7 @@ MODEL_REGISTRY: dict[str, tuple[str, str]] = {
     "kimi_linear": ("mlx_sharding_tpu.models.kimi_linear", "KimiLinearModel"),
     "qwen3_next": ("mlx_sharding_tpu.models.qwen3_next", "Qwen3NextModel"),
     "sdar_moe": ("mlx_sharding_tpu.models.sdar_moe", "SdarMoeModel"),
+    "olmo_hybrid": ("mlx_sharding_tpu.models.olmo_hybrid", "OlmoHybridModel"),
 }
 
 

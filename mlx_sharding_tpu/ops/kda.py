@@ -66,6 +66,26 @@ norm as key heads and are then repeated (value head ``j`` reads key head ``j
 convolution runs over ``[q, k, v]`` joined, its output gate ``z`` comes out of
 the same projection as ``q``, ``k``, ``v`` and gates through ``silu``, and
 ``beta`` and the decay's input come out of one small projection.
+
+A third family (``models/olmo_hybrid.py``) runs the scalar-decay case with
+keys and values of DIFFERENT widths, a rectangular ``(Dk, Dv)`` state a head
+(96 x 192 at the published sizes), and ``beta = 2 sigmoid(b)`` in ``(0, 2)``
+(:func:`gdn_mixer`'s ``value_dim`` and ``beta_scale``): the step's ``I - beta
+k k^T`` then has the eigenvalue ``1 - beta`` in ``(-1, 1)`` along ``k``, still
+a contraction, and none of the three forms asks ``beta <= 1``. No form reads
+``Dk == Dv`` off anything: the einsums and the kernel's blocks take each from
+its operand. What the width of the values does decide is the state pool's
+LAYOUT. A TPU tiles an array over its two minor dimensions in ``(8, 128)``
+tiles, so a ``(…, 96, 192)`` pool lies in HBM padded to 256 lanes and every
+step would move a third more bytes than the state has. The pool therefore
+keeps :func:`lane_pack` heads side by side on its lane axis, ``(L, rows, H /
+P, Dk, P Dv)``: at 192 two heads are 384 lanes, three whole tiles, and no byte
+is padded (``P`` is 1 at 128, where a head's tile is whole). A lane's column
+sum is its own head's, so the kernel differs only in its per-head columns
+(``alpha``, ``k``, ``q``), which each lane takes from its own head; the forms
+that want heads apart (:func:`kda_chunked`, :func:`_kda_step_xla`) view the
+slots' rows of one layer through :func:`unpack_heads`. How many heads share a
+lane group is read off the shapes (``q``'s heads over the pool's groups).
 """
 
 from __future__ import annotations
@@ -192,26 +212,41 @@ def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
 
 
 def _kda_step_kernel(active_ref, rank_ref, cols_ref, rows_ref, s_ref, y_ref, o_ref):
-    """One slot's block of ``hb`` heads: ``s_ref`` / ``o_ref (hb, Dk, Dv)`` the
-    same rows of the pool; ``cols_ref (Dk, 3 hb)`` the heads' ``alpha``, ``k``
-    and ``q`` with the key channels on sublanes as in S; ``rows_ref (2 hb,
-    Dv)`` the heads' ``beta v`` and ``beta`` along the lanes. ``y_ref (hb,
-    Dv)``. The two reductions run over SUBLANES (vector adds and one fold),
-    float32 throughout: a dot would round S to bf16."""
+    """One slot's block of ``hb`` lane groups, each ``P`` heads side by side
+    on the lanes (``P`` 1: a group is a head): ``s_ref`` / ``o_ref (hb, Dk, P
+    Dv)`` the same rows of the pool; ``cols_ref (Dk, 3 hb P)`` the heads'
+    ``alpha``, ``k`` and ``q`` with the key channels on sublanes as in S;
+    ``rows_ref (2 hb, P Dv)`` the groups' ``beta v`` and ``beta`` along the
+    lanes. ``y_ref (hb, P Dv)``. The two reductions run over SUBLANES (vector
+    adds and one fold), float32 throughout: a dot would round S to bf16. A
+    lane's column sum is its own head's whatever shares the tile, so a group
+    of ``P > 1`` differs only in its columns: each lane takes its head's
+    (:func:`column`)."""
     del rank_ref
     slot = pl.program_id(0)
     hb = s_ref.shape[0]
+    pack = cols_ref.shape[1] // (3 * hb)
     keep = active_ref[slot] != 0
     cols, rows = cols_ref[...], rows_ref[...]
+    if pack > 1:
+        dv = s_ref.shape[2] // pack
+        lane = jax.lax.broadcasted_iota(jnp.int32, s_ref.shape[1:], 1)
+
+    def column(part, h):
+        """Group ``h``'s ``alpha`` (``part`` 0), ``k`` (1) or ``q`` (2)."""
+        first = (part * hb + h) * pack
+        col = cols[:, first : first + 1]
+        for j in range(1, pack):
+            col = jnp.where(lane >= j * dv, cols[:, first + j : first + j + 1], col)
+        return col
+
     for h in range(hb):
         old = s_ref[h]
-        k_c = cols[:, hb + h : hb + h + 1]
-        scaled = cols[:, h : h + 1] * old
-        r = jnp.sum(scaled * k_c, axis=0, keepdims=True)  # k^T S' (1, Dv)
+        k_c = column(1, h)
+        scaled = column(0, h) * old
+        r = jnp.sum(scaled * k_c, axis=0, keepdims=True)  # k^T S' (1, P Dv)
         new = scaled + k_c * (rows[h : h + 1] - rows[hb + h : hb + h + 1] * r)
-        y_ref[h : h + 1, :] = jnp.sum(
-            new * cols[:, 2 * hb + h : 2 * hb + h + 1], axis=0, keepdims=True
-        )
+        y_ref[h : h + 1, :] = jnp.sum(new * column(2, h), axis=0, keepdims=True)
         o_ref[h] = jnp.where(keep, new, old)
 
 
@@ -225,34 +260,72 @@ def _head_block(heads: int, head_bytes: int) -> int:
     )
 
 
+def lane_pack(heads: int, dv: int) -> int:
+    """How many heads the state pool keeps side by side on the lane axis:
+    the fewest that divide ``heads`` and make ``P dv`` whole 128-lane tiles
+    (1 where ``dv`` already is, or where no such count exists). A TPU tiles
+    an array over its two minor dimensions, so a ``(Dk, 192)`` head tile
+    would lie in HBM padded to 256 lanes, a third more bytes every step; two
+    such heads side by side are 384 lanes, three whole tiles."""
+    return next(
+        (p for p in range(1, heads + 1) if heads % p == 0 and (p * dv) % 128 == 0), 1
+    )
+
+
+def pack_heads(s, pack: int):
+    """``(…, H, Dk, Dv)`` as the pool keeps it, ``(…, H / P, Dk, P Dv)``."""
+    if pack == 1:
+        return s
+    *lead, h, dk, dv = s.shape
+    with jax.named_scope("mst.state_pool.regroup"):
+        s = s.reshape(*lead, h // pack, pack, dk, dv)
+        return jnp.swapaxes(s, -3, -2).reshape(*lead, h // pack, dk, pack * dv)
+
+
+def unpack_heads(s, pack: int):
+    """:func:`pack_heads` undone: the pool's rows as ``(…, H, Dk, Dv)``."""
+    if pack == 1:
+        return s
+    *lead, groups, dk, width = s.shape
+    with jax.named_scope("mst.state_pool.regroup"):
+        s = s.reshape(*lead, groups, dk, pack, width // pack)
+        return jnp.swapaxes(s, -3, -2).reshape(*lead, groups * pack, dk, width // pack)
+
+
 @functools.partial(jax.jit, static_argnames="interpret")
 def kda_pool_step(pool, rank, q, k, v, g, beta, active=None, *,
                   interpret: bool = False):
     """The one-step recurrence on the state pool where it lies, ONE pass over
-    the layer's rows of S: ``pool (L, rows, H, Dk, Dv)`` float32, ``rank`` the
-    layer's row of it (may be traced), ``q`` / ``k`` / ``g (B, H, Dk)``, ``v
-    (B, H, Dv)``, ``beta (B, H)``, ``active (B,)`` or None. The pool is
+    the layer's rows of S: ``pool (L, rows, H / P, Dk, P Dv)`` float32, ``P``
+    heads side by side on the lanes (:func:`lane_pack`; ``P`` 1 is ``(L,
+    rows, H, Dk, Dv)``), ``rank`` the layer's row of it (may be traced), ``q``
+    / ``k`` / ``g (B, H, Dk)``, ``v (B, H, Dv)``, ``Dk`` and ``Dv`` each their
+    own, ``beta (B, H)``, ``active (B,)`` or None. The pool is
     aliased to the result: rows past ``B`` (an engine's scratch row) and the
     other layers' rows are never moved. Returns ``(o (B, H, Dv), pool)``.
     Jitted for the reason ``ops.mamba2.ssm_pool_step`` is: the body, unrolled
     over a block's heads, is traced once for all the layers that call it."""
-    _, _, nh, dk, dv = pool.shape
-    b = q.shape[0]
-    hb = _head_block(nh, dk * dv * 4)
-    blocks = nh // hb
+    _, _, groups, dk, width = pool.shape
+    b, nh = q.shape[:2]
+    pack = nh // groups  # heads a lane group
+    dv = width // pack
+    hb = _head_block(groups, dk * width * 4)
+    blocks = groups // hb
     f32 = jnp.float32
     # key channels on sublanes as in S, a block's heads side by side
-    col = lambda z: jnp.swapaxes(z.astype(f32).reshape(b, blocks, hb, dk), 2, 3)  # noqa: E731
+    col = lambda z: jnp.swapaxes(  # noqa: E731
+        z.astype(f32).reshape(b, blocks, hb * pack, dk), 2, 3
+    )
     cols = jnp.concatenate([col(jnp.exp(g)), col(k), col(q)], axis=-1)
     beta = beta.astype(f32)[..., None]
     rows = jnp.concatenate([
-        (beta * v).reshape(b, blocks, hb, dv),
-        jnp.broadcast_to(beta, (b, nh, dv)).reshape(b, blocks, hb, dv),
+        (beta * v).reshape(b, blocks, hb, width),
+        jnp.broadcast_to(beta, (b, nh, dv)).reshape(b, blocks, hb, width),
     ], axis=2)
     if active is None:
         active = jnp.ones((b,), jnp.int32)
     state_spec = pl.BlockSpec(
-        (None, None, hb, dk, dv), lambda i, j, a, r: (r[0], i, j, 0, 0)
+        (None, None, hb, dk, width), lambda i, j, a, r: (r[0], i, j, 0, 0)
     )
     small = lambda *shape: pl.BlockSpec(  # noqa: E731
         (None, None, *shape), lambda i, j, *_: (i, j, 0, 0)
@@ -262,11 +335,11 @@ def kda_pool_step(pool, rank, q, k, v, g, beta, active=None, *,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, blocks),
-            in_specs=[small(dk, 3 * hb), small(2 * hb, dv), state_spec],
-            out_specs=[small(hb, dv), state_spec],
+            in_specs=[small(dk, 3 * hb * pack), small(2 * hb, width), state_spec],
+            out_specs=[small(hb, width), state_spec],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, blocks, hb, dv), f32),
+            jax.ShapeDtypeStruct((b, blocks, hb, width), f32),
             jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         ],
         # operands count the scalar-prefetch ones: the pool is the fifth
@@ -274,7 +347,7 @@ def kda_pool_step(pool, rank, q, k, v, g, beta, active=None, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             # S in and out, double-buffered, and room for the small operands
-            vmem_limit_bytes=4 * hb * dk * dv * 4 + (8 << 20),
+            vmem_limit_bytes=4 * hb * dk * width * 4 + (8 << 20),
         ),
         interpret=interpret,
         name="kda_pool_step",
@@ -287,8 +360,9 @@ def kda_pool_step(pool, rank, q, k, v, g, beta, active=None, *,
 
 def step_kernel_eligible(pool, interpret: bool) -> bool:
     """:func:`kda_pool_step` on a TPU backend (in interpret mode on any) for
-    a float32 pool whose ``(Dk, Dv)`` head tiles are whole sublane and lane
-    tiles; the array operations otherwise."""
+    a float32 pool whose minor dimensions ``(Dk, P Dv)`` — a head's tile, or
+    ``P`` heads' side by side (:func:`lane_pack`) — are whole sublane and
+    lane tiles; the array operations otherwise."""
     if interpret:
         return True
     return (
@@ -302,7 +376,8 @@ def step_kernel_eligible(pool, interpret: bool) -> bool:
 def _kda_step_xla(pool, rank, q, k, v, g, beta, active):
     """:func:`kda_pool_step`'s step as array operations, same arguments."""
     _count_dispatch("xla")
-    old = take_rows(pool, rank, q.shape[0])
+    pack = q.shape[1] // pool.shape[2]
+    old = unpack_heads(take_rows(pool, rank, q.shape[0]), pack)
     with jax.named_scope("mst.kda.step"):
         scaled = jnp.exp(g)[..., None] * old
         # elementwise, not a dot: a TPU dot would round S to bf16
@@ -312,7 +387,7 @@ def _kda_step_xla(pool, rank, q, k, v, g, beta, active):
         # the select stands INSIDE the scope: it is the root of the fusion
         # that updates the state, and a fusion's time is its root's scope's
         new = keep_inactive(active, new, old)
-    return o, put_rows(pool, rank, new)
+    return o, put_rows(pool, rank, pack_heads(new, pack))
 
 
 def _kda_step_lanes(axis_size, in_batched, *args):
@@ -324,9 +399,9 @@ def _kda_step_lanes(axis_size, in_batched, *args):
 
 
 def kda_step(pool, rank, q, k, v, g, beta, active, interpret: bool = False):
-    """A decode step's recurrence on the pool by the path the operands allow:
-    ``(o (B, H, Dv), pool)``. ``g (B, H)``, one decay a head, is broadcast
-    over the head's key channels."""
+    """A decode step's recurrence on the pool ``(L, rows, H / P, Dk, P Dv)``
+    by the path the operands allow: ``(o (B, H, Dv), pool)``. ``g (B, H)``,
+    one decay a head, is broadcast over the head's key channels."""
     if g.ndim == beta.ndim:
         g = jnp.broadcast_to(g[..., None], k.shape)
     if not step_kernel_eligible(pool, interpret):
@@ -361,8 +436,9 @@ def _advance(pool, rank, q, k, v, g, beta, tail, new_tail, n_valid, active,
              chunk: int, interpret: bool):
     """The recurrence over this call's rows by the form its length asks for:
     a decode step (``T == 1``) updates the pool where it lies
-    (:func:`kda_step`), a chunk slices the layer's rows out, runs
-    :func:`kda_chunked` and writes them back. Rows past ``n_valid`` and
+    (:func:`kda_step`), a chunk slices the layer's rows out (heads apart,
+    whatever the pool's :func:`lane_pack`), runs :func:`kda_chunked` and
+    writes them back. Rows past ``n_valid`` and
     sequences outside ``active`` advance neither the state nor the tail.
     Returns ``(o (B, T, H, Dv), pool, new_tail)``."""
     b, t = q.shape[:2]
@@ -373,7 +449,8 @@ def _advance(pool, rank, q, k, v, g, beta, tail, new_tail, n_valid, active,
         )
         o = o[:, None]
     else:
-        old = take_rows(pool, rank, b)
+        pack = q.shape[2] // pool.shape[2]  # heads a lane group of the pool
+        old = unpack_heads(take_rows(pool, rank, b), pack)
         with jax.named_scope("mst.kda.scan"):
             if n_valid is not None:
                 live = (jnp.arange(t) < n_valid)[None, :, None]
@@ -381,7 +458,7 @@ def _advance(pool, rank, q, k, v, g, beta, tail, new_tail, n_valid, active,
                 beta = jnp.where(live, beta, 0.0)
             o, s = kda_chunked(q, k, v, g, beta, old, chunk)
             s = keep_inactive(active, s, old)  # inside the scope, as the step's
-        pool = put_rows(pool, rank, s)
+        pool = put_rows(pool, rank, pack_heads(s, pack))
     with jax.named_scope("mst.kda.step" if t == 1 else "mst.kda.scan"):
         new_tail = keep_inactive(active, new_tail, tail)
     return o, pool, new_tail
@@ -440,20 +517,26 @@ def kda_mixer(
 def gdn_mixer(
     linear, p, u, pool, rank, tail, n_valid, active, *,
     key_heads: int, value_heads: int, head_dim: int, taps: int, eps: float,
+    value_dim: int | None = None, beta_scale: float = 1.0,
     chunk: int = CHUNK, interpret: bool = False,
 ):
     """One Gated DeltaNet mixer: the recurrence of :func:`kda_mixer` under one
-    decay a head (the module docstring's scalar-decay case). ``p``: the
-    layer's ``qkvz_proj`` (hidden to ``[q, k, v, z]``: ``Hk D``, ``Hk D``,
-    ``Hv D`` and the output gate's ``Hv D``), ``conv_w ((2 Hk + Hv) D,
+    decay a head (the module docstring's scalar-decay case). Keys are
+    ``head_dim`` wide (``Dk``), values ``value_dim`` (``Dv``; None: ``Dk``);
+    ``beta = beta_scale * sigmoid(b)``: at 2 the step's ``I - beta k k^T`` has
+    an eigenvalue in ``(-1, 1)`` where 1 keeps it in ``(0, 1)``. ``p``: the
+    layer's ``qkvz_proj`` (hidden to ``[q, k, v, z]``: ``Hk Dk``, ``Hk Dk``,
+    ``Hv Dv`` and the output gate's ``Hv Dv``), ``conv_w (2 Hk Dk + Hv Dv,
     taps)`` over ``[q, k, v]`` joined, ``ba_proj`` (hidden to ``[b, a]``,
-    ``Hv`` each), ``A_log`` / ``dt_bias (Hv,)``, ``o_norm (D,)`` (a plain
-    weight), ``o_proj``; ``pool (L, rows, Hv, D, D)``; ``tail (B, taps - 1,
-    (2 Hk + Hv) D)``. Everything else as there. Returns ``(out (B, T,
+    ``Hv`` each), ``A_log`` / ``dt_bias (Hv,)``, ``o_norm (Dv,)`` (a plain
+    weight), ``o_proj``; ``pool (L, rows, Hv / P, Dk, P Dv)`` (:func:`lane_pack`
+    heads side by side; ``P`` 1: ``(L, rows, Hv, Dk, Dv)``); ``tail (B, taps -
+    1, 2 Hk Dk + Hv Dv)``. Everything else as there. Returns ``(out (B, T,
     hidden), pool, tail)``."""
     b, t, _ = u.shape
     d, f32 = head_dim, jnp.float32
-    kw, vw = key_heads * d, value_heads * d
+    dv = d if value_dim is None else value_dim
+    kw, vw = key_heads * d, value_heads * dv
     with jax.named_scope("mst.kda.proj"):
         qkvz = linear(u, p["qkvz_proj"])
         qkv, z = qkvz[..., : 2 * kw + vw], qkvz[..., 2 * kw + vw :]
@@ -466,9 +549,11 @@ def gdn_mixer(
         )
         q = spread(qkv_a[..., :kw]) * d**-0.5
         k = spread(qkv_a[..., kw : 2 * kw])
-        v = qkv_a[..., 2 * kw :].reshape(b, t, value_heads, d)
+        v = qkv_a[..., 2 * kw :].reshape(b, t, value_heads, dv)
         ba = linear(u, p["ba_proj"]).astype(f32)
         beta = jax.nn.sigmoid(ba[..., :value_heads])
+        if beta_scale != 1.0:
+            beta = beta_scale * beta
         g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
             ba[..., value_heads:] + p["dt_bias"].astype(f32)
         )
@@ -478,6 +563,6 @@ def gdn_mixer(
     with jax.named_scope("mst.kda.out"):
         y = _head_rms(o, eps) * p["o_norm"].astype(f32) * jax.nn.silu(
             z.astype(f32)
-        ).reshape(b, t, value_heads, d)
+        ).reshape(b, t, value_heads, dv)
         out = linear(y.reshape(b, t, vw).astype(u.dtype), p["o_proj"])
     return out, pool, new_tail

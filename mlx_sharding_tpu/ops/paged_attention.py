@@ -65,6 +65,8 @@ from jax.experimental.pallas import tpu as pltpu
 from mlx_sharding_tpu.ops.dispatch import DispatchCounter
 
 NEG_INF = -1e30
+#: what Mosaic lets a kernel use of VMEM unasked, on every generation so far
+_DEFAULT_SCOPED_VMEM = 16 << 20
 
 # Which path paged_attention chose, once per traced call (ops/dispatch.py).
 # /metrics shows it as ``mst_paged_attention_dispatch_total{path}``: "xla"
@@ -93,8 +95,13 @@ def kernel_eligible(
     a multiple of 128 inside ``dk`` (``dv``, the dummy V pool's, is not
     asked) and several heads need a 128-aligned ``dk`` to start each slice
     on a lane tile. int8 pools follow the same rules in both layouts.
-    Interpret mode takes any shape so CPU tests exercise the kernel logic
-    itself."""
+    No count of K/V heads and no query group is refused: the body walks
+    the heads, each a ``(G, Dk)`` product from a query group of one up
+    (multi-head attention, ``models/olmo_hybrid.py``: 30 heads of one row),
+    and a call whose double-buffered page blocks pass Mosaic's default 16
+    MiB of VMEM (30 heads of 128 merged on a 512-row page: 15.7 MB) states
+    its own limit. Interpret mode takes any shape so CPU tests exercise the
+    kernel logic itself."""
     if logit_softcap is not None:
         return False
     if sliding_window is not None and not isinstance(sliding_window, int):
@@ -283,6 +290,20 @@ def _paged_attention_kernel(
         qg, *(x.reshape(pages, page_size, hkv * width) for x, width in kv)
     ]
 
+    # the page blocks, double-buffered, are nearly all a call holds in VMEM:
+    # 15.7 MB at 30 K/V heads of 128 merged on a page of 512 rows (3.9 MB a
+    # block), where the largest before were 2 MB. Only a call whose blocks
+    # leave no room under the default states a limit: every other compiles
+    # to what it did.
+    blocks = 2 * sum(
+        page_size * hkv * width * x.dtype.itemsize for x, width in kv
+    )
+    params = {}
+    if blocks > _DEFAULT_SCOPED_VMEM - (4 << 20):
+        params["compiler_params"] = pltpu.CompilerParams(
+            # + a head's values and the scores in float32, the scratch
+            vmem_limit_bytes=blocks + (16 << 20)
+        )
     # scalar-prefetch operands: the table, the lengths, and with
     # ``lead_rows`` the shorter bound of each group's leading rows
     scalars = [tables, lengths] + ([lead_lengths] if lead_rows else [])
@@ -308,6 +329,7 @@ def _paged_attention_kernel(
         ),
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((m, hkv, g, dv), q.dtype),
+        **params,
         interpret=interpret,
         name="paged_attention",
     )(*(x.astype(jnp.int32) for x in scalars), *operands)

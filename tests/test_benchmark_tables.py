@@ -193,6 +193,34 @@ def test_the_longgen_cell_is_sized_for_8_to_16_rejoins():
     assert 2 * mix["output_tokens"]["min"] * step > mix["lead_in_s"] + BENCH["run_seconds"]
 
 
+def test_the_shortchat_cell_is_sized_and_fits_its_pool():
+    """``olmo-hybrid-7b-bf16-pp2.shortchat-sat``: clients that turn over
+    (``ends_in_window``, replayed on 8 seeds above), one prefill chunk a join,
+    prompt + answer at most the 1024 tokens a slot's two pages hold, every
+    slot's at once, to the page; ``--max-seq`` is a page longer, for the
+    check's three-chunk prompt alone."""
+    name = "olmo-hybrid-7b-bf16-pp2.shortchat-sat"
+    assert name in TURNING and name not in SIZED
+    config, mix, load = _load(next(c for c in BENCH["workloads"] if c["name"] == name))
+    chunk = int(server_flag(config, "--prefill-chunk"))
+    assert int(load["clients"]) == int(server_flag(config, "--concurrent")) == 48
+    assert mix["prompt_tokens"]["max"] <= chunk
+    longest = mix["prompt_tokens"]["max"] + mix["output_tokens"]["max"]
+    assert longest == 1024 == 2 * chunk
+    assert int(load["clients"]) * 2 == int(server_flag(config, "--paged-pool"))
+    check = config["bench"]["check"]
+    # prompts of one and of three chunks, each with its answer inside --max-seq
+    assert [-(-p // chunk) for p in check["prompt_tokens"]] == [1, 3]
+    assert max(check["prompt_tokens"]) + check["generate"] <= int(server_flag(config, "--max-seq"))
+    assert int(server_flag(config, "--max-seq")) == 3 * chunk
+    pool = config["bench"]["pool"]
+    assert pool["bytes"] == (pool["pages"] + 1) * pool["page_tokens"] * pool["kv_layers"] * 15360
+    assert pool["state_bytes"] == 49 * 12 * (2211840 + 69120)
+    assert set(check["controls"]) <= {
+        "gdn_state_reset", "beta_unscaled", "qk_norm_per_head", "linear_prenorm",
+        "rope_on", "weights_fp8"}
+
+
 def _texts():
     for kind in ("configs", "workloads"):
         for entry in BENCH[kind]:

@@ -114,6 +114,36 @@ def test_op_parity_matrix(hq, hkv, interpret, window):
     assert not np.asarray(got)[LENGTHS.index(0)].any()  # the empty slot
 
 
+@pytest.mark.parametrize("merged", [False, True], ids=["heads-apart", "heads-merged"])
+@pytest.mark.parametrize("interpret", [False, True], ids=["xla", "kernel"])
+def test_thirty_heads_at_a_query_group_of_one(interpret, merged):
+    """Multi-head attention as ``olmo_hybrid`` serves it: 30 K/V heads under
+    30 queries, a row's heads apart or merged on the lane axis, uneven
+    lengths (mid-page, page borders, an empty slot, a full one): the body's
+    walk over heads at one query row each (scratch ``(30, 1, 128)``) gives
+    the numbers of the per-head reference."""
+    rng = np.random.default_rng(4)
+    hq = hkv = 30
+    dk = dv = 16
+    scale = dk ** -0.5
+    q, k_pool, v_pool, tables, dense_k, dense_v = _make_case(
+        rng, LENGTHS, hq, hkv, dk, dv
+    )
+    want = _ref(q, dense_k, dense_v, LENGTHS, scale)
+    pools = [jnp.asarray(x) for x in (k_pool, v_pool)]
+    layout = {}
+    if merged:
+        pools = [x.reshape(*x.shape[:2], 1, -1) for x in pools]
+        layout = dict(kv_heads=hkv)
+    got = paged_attention(
+        jnp.asarray(q), *pools, jnp.asarray(tables),
+        jnp.asarray(LENGTHS, jnp.int32), scale, interpret=interpret, **layout,
+    )
+    assert got.shape == (len(LENGTHS), hq, dv)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
+    assert not np.asarray(got)[LENGTHS.index(0)].any()  # the empty slot
+
+
 # (lengths, lead lengths) of a call with two bounds a slot: the leading rows of
 # every K/V head's query group see ``[0, lead)``, the rest ``[0, length)`` —
 # a wide forward of a family that generates by diffusion over blocks of 4
@@ -418,6 +448,7 @@ ELIGIBLE = {
     "latent-window": ((576, 1, None, 128, 512, 1), True),
     "gqa-window": ((128, 128, None, 4096, None, 8), True),
     "gqa-window-softcap": ((128, 128, 50.0, 4096, None, 8), False),
+    "mha-30-heads-group-1": ((128, 128, None, None, None, 30), True),
 }
 
 

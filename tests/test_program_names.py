@@ -906,3 +906,89 @@ def test_served_sdar_moe_programs_keep_the_names_and_add_one_scope(monkeypatch):
     # (a wide forward, both lanes in ONE scatter a pool, and a narrow one) and
     # one more wide forward behind it
     assert made.count("scatter") == 2 * 3
+
+
+@hard_timeout(420)
+def test_served_olmo_hybrid_programs_walk_two_periods(monkeypatch):
+    """The thirteenth family: a Gated DeltaNet on a rectangular state or
+    multi-head attention behind a full-width QK norm, then a dense MLP, each
+    sub-layer's OUTPUT normed, under the same program names and scopes and NO
+    new one. Two periods of ``G G G A``: the decode block holds the linear
+    body ONCE (the period's run of three, an inner scan) and the attention
+    body ONCE; the state pool — two heads side by side on its lanes — and the
+    page pool ride the carry of every scan, never its ``xs`` or ``ys``. No
+    program name is used for two programs."""
+    from mlx_sharding_tpu.models import build_model
+
+    log = JitLog(jax.jit)
+    monkeypatch.setattr(jax, "jit", log)
+    model, _ = build_model(dict(
+        model_type="olmo_hybrid", vocab_size=128, hidden_size=32,
+        num_hidden_layers=8, num_attention_heads=2, num_key_value_heads=2,
+        intermediate_size=48, linear_conv_kernel_dim=4, linear_key_head_dim=8,
+        linear_value_head_dim=64, linear_num_key_heads=2, linear_num_value_heads=2,
+    ))
+    assert model.walk == ([], ["gdn", "gdn", "gdn", "attn"], 2, [])
+    assert model.state_pack == 2
+    params = model.init_params(jax.random.PRNGKey(0), jnp.float32)
+    eng = PipelineEngine(
+        model, params, pipeline_mesh(1), microbatches=2, max_seq=64,
+        cache_dtype=jnp.float32, prefill_chunk=8, pool_pages=10, page_size=8,
+    )
+    b = ContinuousBatcher(eng, decode_block=3)
+    try:
+        assert len(list(b.generate_step([3, 4, 5, 6], max_tokens=5))) == 5
+        args = (eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+                b.last_tok, b.cache, b.active, b.recent, b.keys, b.sp, b.rep_sizes,
+                b.table)
+        prog = b._decode_block_prog(False)
+        block = prog.lower(*args).as_text(debug_info=True)
+        jaxpr = jax.make_jaxpr(prog)(*args)
+        prefill = eng.prefill_slot().lower(
+            eng.layer_params, eng.layer_masks, eng.vocab_parts, eng.shared_params,
+            jnp.zeros((1, 8), jnp.int32), jnp.asarray(0, jnp.int32), b.cache,
+            jnp.asarray(8, jnp.int32), b.table,
+        ).as_text(debug_info=True)
+        pool = b.cache.state["gdn"].shape[1:]  # (layers, slots + 1, Hv / 2, Dk, 2 Dv)
+        pages = b.cache.k.shape[1:]  # (layers, pages + 1, 1, page, 1, Hkv * D)
+    finally:
+        b.close()
+    assert pool == (6, 3, 1, 8, 128) and pages[-1] == 32
+    assert "module @jit_block " in block
+    assert "module @jit_prefill_chunk " in prefill
+    by_name: dict = {}
+    for name, file, line, _ in log.seen:
+        assert name != "<lambda>", f"anonymous program at {file}:{line}"
+        by_name.setdefault(name, set()).add((file, line))
+    assert not {n: sorted(w) for n, w in by_name.items() if len(w) > 1}
+    assert {"block", "prefill_chunk", "claim_slot", "finish_join"} <= set(by_name)
+    layers = {"mst.embed", "mst.attn.qkv", "mst.attn.qk_norm", "mst.attn.kv_write",
+              "mst.attn.core", "mst.mlp.dense", "mst.norm", "mst.head",
+              "mst.kda.proj", "mst.kda.conv", "mst.kda.gate", "mst.kda.out",
+              "mst.state_pool.regroup"}
+    # decode: the one-step recurrence, the page pool carried (no regroup);
+    # the sampler is in the block
+    assert _scopes_in(block) == layers | {"mst.kda.step", "mst.sample"}
+    # prefill: the chunked (WY) form on the slot's contiguous rows
+    assert _scopes_in(prefill) == layers | {"mst.kda.scan", "mst.kv_pool.regroup"}
+    assert _scopes_in(block) | _scopes_in(prefill) <= set(tracing.MODEL_SCOPES)
+
+    walked = list(_walk(jaxpr.jaxpr))
+    updates = [
+        scans for eqn, scans in walked
+        if eqn.primitive.name == "dynamic_update_slice"
+        and eqn.outvars[0].aval.shape == pool
+    ]
+    # block > periods > the run of three: ONE linear body
+    assert [len(scans) for scans in updates] == [3]
+    assert [s.params["length"] for s in updates[0]] == [3, 2, 3]  # steps, periods, run
+    writes = [
+        scans for eqn, scans in walked
+        if eqn.primitive.name == "scatter" and eqn.outvars[0].aval.size == math.prod(pages)
+        and eqn.outvars[0].aval.shape[-1] == pages[-1]
+    ]
+    assert [len(scans) for scans in writes] == [2, 2]  # K and V: block > periods
+    # nothing in the POOL's layout is selected or copied: off the chip the
+    # one-step form views the slots' rows of one layer heads-apart, and its
+    # frozen-slot select is over that view
+    _pools_ride_every_carry_and_nothing_of_their_size_moves(walked, updates, writes, pool, pages, selects=0)

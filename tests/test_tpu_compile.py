@@ -177,17 +177,22 @@ def _ssm_step(layers, slots, heads, groups, head_dim=64, state=128):
     )
 
 
-def _kda_step(layers, slots, heads, head_dim=128):
+def _kda_step(layers, slots, heads, head_dim=128, value_dim=None):
     """``ops.kda.kda_pool_step`` on a ``(layers, slots + 1, H, D, D)`` float32
     state pool (the scratch row past the slots), the layer's rank an operand:
-    the cell's decode step of one KDA layer."""
-    from mlx_sharding_tpu.ops.kda import kda_pool_step
+    the cell's decode step of one KDA layer. With ``value_dim`` the tile is
+    ``(D, value_dim)`` and the pool keeps ``ops.kda.lane_pack`` heads side by
+    side on the lanes, ``(layers, slots + 1, H / P, D, P value_dim)``."""
+    from mlx_sharding_tpu.ops.kda import kda_pool_step, lane_pack
 
+    dv = value_dim or head_dim
+    pack = lane_pack(heads, dv)
     vec = ((slots, heads, head_dim), F32)
     return (
         kda_pool_step,
-        [((layers, slots + 1, heads, head_dim, head_dim), F32), ((), I32),
-         vec, vec, vec, vec, ((slots, heads), F32), ((slots,), jnp.bool_)],
+        [((layers, slots + 1, heads // pack, head_dim, pack * dv), F32), ((), I32),
+         vec, vec, ((slots, heads, dv), F32), vec, ((slots, heads), F32),
+         ((slots,), jnp.bool_)],
         "kda_pool_step",
     )
 
@@ -264,6 +269,17 @@ CASES = {
     "kda-step-qwen3-next": _kda_step(9, 32, 32),
     "paged-gqa256-merged-page512": _paged(
         512, False, slots=32, hq=16, hkv=2, d=256, max_seq=7680, pages=3 * 481,
+        merged=True),
+    # ... and at the olmo-hybrid-7b-bf16-pp2 cell's: the delta-rule step on a
+    # RECTANGULAR tile, 12 layers x 48 slots x 30 heads of 96 x 192 kept two
+    # side by side on 384 lanes (15 lane groups, walked in blocks of 5), and
+    # the ragged kernel at a query group of ONE on 30 K/V heads of 128 merged
+    # on 3840 lanes: a 3.9 MB block a pool, 15.7 MB double-buffered, over
+    # Mosaic's default 16 MiB with the body's own (the call states its limit);
+    # a table 3 pages wide, the four attention layers' pools viewed as one
+    "kda-step-olmo-hybrid": _kda_step(12, 48, 30, 96, 192),
+    "paged-mha30-group1-merged-page512": _paged(
+        512, False, slots=48, hq=30, hkv=30, max_seq=1536, pages=4 * 97,
         merged=True),
     # 4-bit projections of the 3B model: a prefill chunk's 256 rows, a
     # single stream's one row and 8 slots' rows, all on the one kernel
