@@ -19,20 +19,28 @@ Two paths, selected the same way ops/flash_attention.py picks its path:
   pool viewed as (P+1, page, Hkv·D), a free reshape): Mosaic takes a block
   whose last two dims are tile-aligned or whole, and one head out of Hkv in
   the pool's native (page, Hkv, D) layout is neither. The kernel walks the
-  heads as static 128-aligned lane slices. A slot's
-  scratch-page tail (table rows past its length all point at the same
-  scratch id) collapses to one redundant fetch: consecutive grid steps with
-  an identical block index skip the DMA. Online-softmax state (running max,
-  normalizer, fp32 accumulator, per head) lives in VMEM scratch across the
-  page walk. MLA's latent-as-values (``values_from_k``) is the same kernel
-  with one operand fewer: the value block is the first ``values_from_k``
+  heads as static 128-aligned lane slices. The walk's block indices follow
+  the slots' LENGTHS, not their table rows (``walk_page``): consecutive
+  grid steps with an identical block index skip the DMA, and a step past a
+  slot's last page names the NEXT slot's first visible page, so it fetches
+  nothing of its own and the next slot finds its first page in the buffer —
+  fetched under this slot's last live page's arithmetic (the pipeline
+  issues step k+1's fetch when step k starts). What lies in the row past
+  the length is never read, be it the scratch id or, under the default
+  RESERVE admission, the pages a stream has claimed and not yet filled
+  (1.2-3 times the pages a slot holds, in the benchmark's cells). A call
+  fetches ``max(1, live pages)`` blocks a slot. Online-softmax state
+  (running max, normalizer, fp32 accumulator, per head) lives in VMEM
+  scratch across the page walk. MLA's latent-as-values
+  (``values_from_k``) is the same kernel with one operand fewer: the value block is the first ``values_from_k``
   lanes of the key block already in VMEM, and the latent pool's dummy
   (…, 1, 1) V is never fetched. A static ``sliding_window`` w masks keys at
   ``k_pos <= length - 1 - w``; a grid step whose page lies wholly behind
-  the window or past the length computes nothing, and its block index is
-  clamped to the slot's first and last visible page, so it repeats a
-  neighbour's and fetches nothing new. The table may map positions to a
-  ring of pages (``cache.window_ring_rows``): logical page ``j`` is then
+  the window or past the length computes nothing: a step behind the window
+  names the slot's first visible page, as the step past the length names
+  the next slot's, so it repeats a neighbour's block index and fetches
+  nothing new. The table may map positions to a ring of pages
+  (``cache.window_ring_rows``): logical page ``j`` is then
   ring page ``j % R`` of the slot, and the clamp keeps the walk off the
   ring pages that hold positions outside the window. An optional second
   length a slot (``lead_lengths``, one more scalar-prefetch operand) bounds
@@ -44,12 +52,17 @@ Two paths, selected the same way ops/flash_attention.py picks its path:
   mirroring ops/attention.py's masking semantics. It gathers every slot's
   WHOLE table row (slot_pages × page rows, i.e. max_seq) and masks it, so
   its cost follows max_seq and not the caches' lengths: 75 MB a layer at
-  16 slots × 16 pages of 256 × 576 bf16, whatever the slots hold.
+  16 slots × 16 pages of 256 × 576 bf16, whatever the slots hold. A row
+  past the length is weighted by a probability of exactly 0, which only a
+  finite value survives: the pool holds numbers everywhere (it does: zeros
+  or a finished stream's rows), where the kernel never reads those pages.
 
 Both are token-exact vs the gather path; tests/test_paged_attention.py holds
 the parity matrix (uneven lengths, page-boundary offsets, empty slots, GQA/
 MQA head counts, the latent layout and windows over plain and ring tables,
-kernel-in-interpret vs XLA, two lengths a query group).
+kernel-in-interpret vs XLA, two lengths a query group, rows whose tails are
+claimed pages full of NaN) and the walk replayed on the host under the
+pipeline's rule.
 """
 
 from __future__ import annotations
@@ -119,6 +132,33 @@ def kernel_eligible(
     )
 
 
+def walk_page(mi, ji, t, ln, *, page_size: int, window=None):
+    """The pool page grid step ``(mi, ji)`` names: the K/V BlockSpecs' index
+    map, a plain function of the prefetched table ``t`` (M, SPG) and lengths
+    ``ln`` (M,) so that a test can replay the walk on the host (scalars or
+    index arrays alike). With ``first`` / ``last`` the slot's first and last
+    visible page: a step in ``[first, last]`` names its own table entry, a
+    step before ``first`` the ``first`` page, and a step past ``last`` the
+    NEXT slot's ``first`` page (the last slot's: its own ``last``) — the
+    same block index as its neighbour's, so no fetch of its own, and the
+    next slot starts on a page already in the buffer. An empty slot is its
+    entry 0 alone."""
+
+    def span(x):
+        first = 0
+        if window is not None:
+            first = jnp.maximum(ln[x] - window, 0) // page_size
+        return first, jnp.maximum(ln[x] - 1, 0) // page_size
+
+    first, last = span(mi)
+    nxt = jnp.minimum(mi + 1, t.shape[0] - 1)
+    ahead = (ji > last) & (mi < nxt)
+    return t[
+        jnp.where(ahead, nxt, mi),
+        jnp.where(ahead, span(nxt)[0], jnp.clip(ji, first, last)),
+    ]
+
+
 def _kernel(
     tables_ref,  # (M, SPG) int32 — scalar-prefetch
     lens_ref,  # (M,) int32 — scalar-prefetch
@@ -170,10 +210,9 @@ def _kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # pages entirely past this slot's length are scratch-table tails: skip
-    # all compute (their DMA already collapsed to the repeated scratch id);
-    # so are pages wholly behind the window (the first visible key is
-    # ``length - window``)
+    # a step past this slot's length or wholly behind the window (the first
+    # visible key is ``length - window``) computes nothing; ``walk_page``
+    # gave it a neighbour's block index, so it fetched nothing either
     live = j * page_size < length
     if window is not None:
         live &= (j + 1) * page_size > length - window
@@ -254,18 +293,12 @@ def _paged_attention_kernel(
     qg = q.reshape(m, hkv, g, dk)
     quant = k_scale is not None
 
-    def page_id(mi, ji, t, ln, *_):
-        if window is None:
-            return t[mi, ji]
-        # a step outside [first, last] visible page names its neighbour
-        # inside: the same block index as the step beside it, so no DMA
-        first = jnp.maximum(ln[mi] - window, 0) // page_size
-        last = jnp.maximum(ln[mi] - 1, 0) // page_size
-        return t[mi, jnp.clip(ji, first, last)]
+    page_id = functools.partial(walk_page, page_size=page_size, window=window)
 
     def page_spec(width):
         # data-dependent page fetch: the block index comes from the
-        # prefetched table row — this is the whole point of the kernel.
+        # prefetched table and lengths — this is the whole point of the
+        # kernel.
         # The block is one whole pool page with (Hkv, D) flattened onto the
         # lane axis (a free reshape of the contiguous pool): Mosaic wants
         # the last two block dims tile-aligned or whole, which a
@@ -399,7 +432,7 @@ def paged_attention(
     q: jax.Array,  # (M, Hq, Dk) — one query token per slot
     k_pool: jax.Array,  # (P+1, page, Hkv, Dk) — one layer's pool, scratch last
     v_pool: jax.Array,  # (P+1, page, Hkv, Dv)
-    tables: jax.Array,  # (M, SPG) int32 pool-page ids (scratch id past length)
+    tables: jax.Array,  # (M, SPG) int32 pool-page ids (past length: never read)
     lengths: jax.Array,  # (M,) int32 — valid positions incl. the new token
     scale: float,
     *,

@@ -1080,6 +1080,7 @@ class ContinuousBatcher:
             self.kv_path = getattr(engine, "paged_attention", "gather")
             self.kv_bytes_read_last_tick = 0
             self.kv_bytes_read_total = 0
+            self.kv_bytes_claimed_total = 0
             # per-decoded-token HBM traffic, split by side (weights vs KV):
             # the headline numbers of the quantized memory hierarchy
             self.weight_bytes_per_token_last = 0.0
@@ -1555,18 +1556,24 @@ class ContinuousBatcher:
             total = -(-total // self._diffusion) * self._diffusion
         return -(-total // page)
 
-    def kv_read_stats(self) -> Optional[tuple[str, int, int]]:
-        """(attention path, KV bytes read last tick, total) for /metrics;
-        None on dense engines. Analytic, not measured: ragged counts the
-        page-rounded rows each live slot actually occupies, gather counts
-        the full slot_pages-wide contiguous view `_paged_read` materializes
-        per slot per step — the gap between the two numbers is the traffic
-        the ragged kernel deletes."""
+    def kv_read_stats(self) -> Optional[tuple[str, int, int, int]]:
+        """(attention path, KV bytes read last tick, total, CLAIMED total)
+        for /metrics; None on dense engines. Analytic, not measured: ragged
+        counts the page-rounded rows each live slot HOLDS — what the kernel's
+        walk fetches, since it follows the lengths (``ops/paged_attention.
+        walk_page``) — and gather the full slot_pages-wide contiguous view
+        `_paged_read` materializes per slot per step; the gap between the
+        two is the traffic the ragged kernel deletes. CLAIMED is the same
+        sum over every page in the slots' table rows (under the default
+        RESERVE admission a stream's whole prompt + max_tokens need from
+        its first token, and the scratch entry where the row is wider):
+        what a walk that names its table row would fetch. read / claimed
+        is the share of that the kernel's walk fetches."""
         if not self.paged:
             return None
         return (
             self.kv_path, self.kv_bytes_read_last_tick,
-            self.kv_bytes_read_total,
+            self.kv_bytes_read_total, self.kv_bytes_claimed_total,
         )
 
     def tick_phase_stats(self) -> dict:
@@ -1688,16 +1695,22 @@ class ContinuousBatcher:
         if not self.paged or not live:
             return
         page = self.engine.page_size
+        width = self.engine.slot_pages
         if (path or self.kv_path) == "ragged":
-            rows = 0
-            for _, req in live:
+            rows = claimed = 0
+            for slot, req in live:
                 length = req.prompt.size + max(0, req.produced - 1) + 1
                 rows += -(-length // page) * page
+                # the row's distinct entries: the claim, and the scratch
+                # page where the row is wider
+                claimed += min(len(self._pages_of.get(slot, ())) + 1, width)
+            claimed *= page
         else:
-            rows = len(live) * self.engine.slot_pages * page
+            rows = claimed = len(live) * width * page
         b = rows * self._kv_row_bytes * steps
         self.kv_bytes_read_last_tick = b
         self.kv_bytes_read_total += b
+        self.kv_bytes_claimed_total += claimed * self._kv_row_bytes * steps
         # weights stream once per step regardless of slot count, so per
         # token they amortize over the live slots; KV does not amortize
         tokens = len(live) * steps

@@ -570,7 +570,7 @@ class ServingMetrics:
                             ]
                     kv = getattr(b, "kv_read_stats", lambda: None)()
                     if kv is not None:
-                        path, _last_tick, total_bytes = kv
+                        path, _last_tick, total_bytes, claimed_bytes = kv
                         lines += [
                             # 1 = ragged in-place paged attention, 0 = the
                             # gather/scatter path — which kernel decode is on
@@ -578,6 +578,8 @@ class ServingMetrics:
                             f"mst_paged_attention_ragged {int(path == 'ragged')}",
                             "# TYPE mst_kv_bytes_read_total counter",
                             f"mst_kv_bytes_read_total {total_bytes}",
+                            "# TYPE mst_kv_bytes_claimed_total counter",
+                            f"mst_kv_bytes_claimed_total {claimed_bytes}",
                         ]
                     hbm = getattr(b, "hbm_bytes_per_token_stats", lambda: None)()
                     if hbm is not None:
@@ -1127,6 +1129,15 @@ class ServingMetrics:
 # the name; everything else gets a generated one-liner (coverage contract:
 # EVERY emitted family carries # HELP and # TYPE — test_metrics_help_type)
 _HELP = {
+    "mst_kv_bytes_read_total":
+        "K/V bytes the decode steps' attention reads, analytic: the "
+        "page-rounded rows the live slots hold (ragged) or every slot's "
+        "whole table row (gather).",
+    "mst_kv_bytes_claimed_total":
+        "K/V bytes of every page in the live slots' table rows, claimed "
+        "and scratch entries included: what a walk that names its table "
+        "row would read. read / claimed is the share the ragged walk "
+        "fetches.",
     "mst_requests_total": "Requests served (including failures).",
     "mst_requests_failed_total": "Requests that ended in an error.",
     "mst_ttft_seconds": "Time to first token, seconds (histogram).",

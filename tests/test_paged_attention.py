@@ -35,31 +35,71 @@ PAGE = 8
 SPG = 4  # slot pages — virtual max of 32 positions per slot
 
 
-def _own_pages(lengths, page, spg):
-    """A page table as init_cache_paged lays it out: each slot owns distinct
-    pages for its live prefix, the scratch page (last pool id) past it."""
+def _own_pages(lengths, page, spg, claimed=False):
+    """A page table as the scheduler lays a row out (``_table_row``): slot
+    ``i`` owns the distinct pages ``i * spg + j``; past them the row holds
+    the scratch page (last pool id). ``claimed=False``: a slot owns the
+    pages of its live prefix alone, as ``--overcommit`` admission and an
+    idle slot leave a row. ``claimed=True``: the default RESERVE admission,
+    which claims a stream's whole prompt + max_tokens need at its first
+    token — distinct real ids PAST the length (every other slot's claim one
+    page short of the row, so the row is wider than the claim)."""
     n_pages = len(lengths) * spg
     tables = np.full((len(lengths), spg), n_pages, np.int32)
     for i, ln in enumerate(lengths):
-        used = -(-ln // page)
+        used = max(spg - i % 2, -(-ln // page)) if claimed else -(-ln // page)
         tables[i, :used] = np.arange(i * spg, i * spg + used)
     return tables
 
 
-def _make_case(rng, lengths, hq, hkv, dk, dv):
-    """Build a pool where each slot owns distinct pages for its live prefix
-    and the scratch page (last pool id) past it, exactly like
-    init_cache_paged lays tables out. Returns arrays plus a dense per-slot
-    (S, Hkv, D) view for the reference."""
+def _poison(lengths, page, spg, *pools):
+    """NaN in every page no query may read: a slot's pages wholly past its
+    length (claimed, not yet written) and the scratch page."""
+    for pool in pools:
+        pool[-1] = np.nan
+        for i, ln in enumerate(lengths):
+            pool[i * spg + -(-ln // page):(i + 1) * spg] = np.nan
+
+
+def _numbers(*pools):
+    """The pools as the XLA fallback may be handed them: it gathers a slot's
+    whole row and weights a dead row by a probability of exactly 0, which
+    only a finite value survives — a dead page holds 1e30 where the
+    kernel's holds NaN."""
+    return [jax.tree.map(lambda x: jnp.nan_to_num(x, nan=1e30), p) for p in pools]
+
+
+TAILS = pytest.mark.parametrize(
+    "claimed", [False, True], ids=["scratch-tail", "claimed-tail"]
+)
+
+
+def _make_case(rng, lengths, hq, hkv, dk, dv, claimed=False):
+    """Build a pool where each slot owns distinct pages (``_own_pages``);
+    ``claimed``: the rows name claimed pages past the lengths, and those
+    pages and the scratch page hold NaN. Returns arrays plus a dense
+    per-slot (S, Hkv, D) view for the reference."""
     m = len(lengths)
     n_pages = m * SPG
     k_pool = rng.standard_normal((n_pages + 1, PAGE, hkv, dk), np.float32)
     v_pool = rng.standard_normal((n_pages + 1, PAGE, hkv, dv), np.float32)
-    tables = _own_pages(lengths, PAGE, SPG)
+    if claimed:
+        _poison(lengths, PAGE, SPG, k_pool, v_pool)
+    tables = _own_pages(lengths, PAGE, SPG, claimed)
     q = rng.standard_normal((m, hq, dk), np.float32)
-    dense_k = k_pool[tables].reshape(m, SPG * PAGE, hkv, dk)
-    dense_v = v_pool[tables].reshape(m, SPG * PAGE, hkv, dv)
+    own = np.arange(n_pages).reshape(m, SPG)
+    dense_k = k_pool[own].reshape(m, SPG * PAGE, hkv, dk)
+    dense_v = v_pool[own].reshape(m, SPG * PAGE, hkv, dv)
     return q, k_pool, v_pool, tables, dense_k, dense_v
+
+
+def _same_as_scratch_tail(got, attend, lengths, page=PAGE, spg=SPG):
+    """What lies in a row past the length changes no bit of the result:
+    ``attend(tables)`` over the scratch-tail table equals ``got``."""
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        np.asarray(attend(jnp.asarray(_own_pages(lengths, page, spg)))),
+    )
 
 
 def _ref(q, dense_k, dense_v, lengths, scale, window=None):
@@ -83,8 +123,9 @@ def _ref(q, dense_k, dense_v, lengths, scale, window=None):
 
 
 # lengths hit: mid-page, exact one-page boundary, exact two-page boundary,
-# empty slot, uneven multi-page, completely full slot
-LENGTHS = [5, PAGE, 2 * PAGE, 0, 27, SPG * PAGE]
+# empty slot between live ones, uneven multi-page, completely full slot,
+# empty slot last
+LENGTHS = [5, PAGE, 2 * PAGE, 0, 27, SPG * PAGE, 0]
 
 
 @pytest.mark.parametrize(
@@ -97,26 +138,36 @@ LENGTHS = [5, PAGE, 2 * PAGE, 0, 27, SPG * PAGE]
     "window", [None, 5, PAGE, 11, SPG * PAGE, 100],
     ids=["full", "w5", "w-page", "w11", "w-longest", "w-longer"],
 )
-def test_op_parity_matrix(hq, hkv, interpret, window):
+@TAILS
+def test_op_parity_matrix(hq, hkv, interpret, window, claimed):
     rng = np.random.default_rng(0)
     dk = dv = 16
     scale = dk ** -0.5
     q, k_pool, v_pool, tables, dense_k, dense_v = _make_case(
-        rng, LENGTHS, hq, hkv, dk, dv
+        rng, LENGTHS, hq, hkv, dk, dv, claimed
     )
     want = _ref(q, dense_k, dense_v, LENGTHS, scale, window)
-    got = paged_attention(
-        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-        jnp.asarray(tables), jnp.asarray(LENGTHS, jnp.int32), scale,
-        sliding_window=window, interpret=interpret,
-    )
+    pools = [jnp.asarray(k_pool), jnp.asarray(v_pool)]
+    if not interpret:
+        pools = _numbers(*pools)
+
+    def attend(tables):
+        return paged_attention(
+            jnp.asarray(q), *pools, tables, jnp.asarray(LENGTHS, jnp.int32),
+            scale, sliding_window=window, interpret=interpret,
+        )
+
+    got = attend(jnp.asarray(tables))
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
-    assert not np.asarray(got)[LENGTHS.index(0)].any()  # the empty slot
+    assert not np.asarray(got)[np.array(LENGTHS) == 0].any()  # the empty slots
+    if claimed:
+        _same_as_scratch_tail(got, attend, LENGTHS)
 
 
 @pytest.mark.parametrize("merged", [False, True], ids=["heads-apart", "heads-merged"])
 @pytest.mark.parametrize("interpret", [False, True], ids=["xla", "kernel"])
-def test_thirty_heads_at_a_query_group_of_one(interpret, merged):
+@TAILS
+def test_thirty_heads_at_a_query_group_of_one(interpret, merged, claimed):
     """Multi-head attention as ``olmo_hybrid`` serves it: 30 K/V heads under
     30 queries, a row's heads apart or merged on the lane axis, uneven
     lengths (mid-page, page borders, an empty slot, a full one): the body's
@@ -127,21 +178,29 @@ def test_thirty_heads_at_a_query_group_of_one(interpret, merged):
     dk = dv = 16
     scale = dk ** -0.5
     q, k_pool, v_pool, tables, dense_k, dense_v = _make_case(
-        rng, LENGTHS, hq, hkv, dk, dv
+        rng, LENGTHS, hq, hkv, dk, dv, claimed
     )
     want = _ref(q, dense_k, dense_v, LENGTHS, scale)
     pools = [jnp.asarray(x) for x in (k_pool, v_pool)]
+    if not interpret:
+        pools = _numbers(*pools)
     layout = {}
     if merged:
         pools = [x.reshape(*x.shape[:2], 1, -1) for x in pools]
         layout = dict(kv_heads=hkv)
-    got = paged_attention(
-        jnp.asarray(q), *pools, jnp.asarray(tables),
-        jnp.asarray(LENGTHS, jnp.int32), scale, interpret=interpret, **layout,
-    )
+
+    def attend(tables):
+        return paged_attention(
+            jnp.asarray(q), *pools, tables, jnp.asarray(LENGTHS, jnp.int32),
+            scale, interpret=interpret, **layout,
+        )
+
+    got = attend(jnp.asarray(tables))
     assert got.shape == (len(LENGTHS), hq, dv)
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
-    assert not np.asarray(got)[LENGTHS.index(0)].any()  # the empty slot
+    assert not np.asarray(got)[np.array(LENGTHS) == 0].any()  # the empty slots
+    if claimed:
+        _same_as_scratch_tail(got, attend, LENGTHS)
 
 
 # (lengths, lead lengths) of a call with two bounds a slot: the leading rows of
@@ -158,15 +217,16 @@ TWO_LENGTHS = {
     "lane-1-inactive": ([4, 12, 27], [0, 0, 0]),
     # lane 2 is not computed: both bounds are lane 1's
     "lane-2-inactive": ([8, 13, SPG * PAGE], [8, 13, SPG * PAGE]),
-    # an empty slot between live ones
-    "length-0": ([12, 0, 0, 24], [8, 0, 0, 20]),
+    # empty slots between live ones, and last
+    "length-0": ([12, 0, 0, 24, 0], [8, 0, 0, 20, 0]),
 }
 
 
 @pytest.mark.parametrize("interpret", [False, True], ids=["xla", "kernel"])
 @pytest.mark.parametrize("merged", [False, True], ids=["heads-apart", "heads-merged"])
 @pytest.mark.parametrize("case", list(TWO_LENGTHS))
-def test_two_lengths_a_query_group(case, merged, interpret):
+@TAILS
+def test_two_lengths_a_query_group(case, merged, interpret, claimed):
     """``lead_lengths`` / ``lead_rows``: both paths against plain attention
     a row, each row under the bound of its place in its group (4 K/V heads'
     groups of 2 lanes x 2 queries x 2 heads: the leading 4 rows are lane 1);
@@ -175,7 +235,7 @@ def test_two_lengths_a_query_group(case, merged, interpret):
     rng = np.random.default_rng(4)
     hkv, g, lead_rows, d = 2, 8, 4, 16
     q, k_pool, v_pool, tables, dense_k, dense_v = _make_case(
-        rng, lengths, hkv * g, hkv, d, d
+        rng, lengths, hkv * g, hkv, d, d, claimed
     )
     first = (np.arange(hkv * g) % g) < lead_rows  # (Hq,): a lane-1 row
     want = np.where(
@@ -184,19 +244,27 @@ def test_two_lengths_a_query_group(case, merged, interpret):
         _ref(q, dense_k, dense_v, lengths, d ** -0.5),
     )
     pools = [jnp.asarray(x) for x in (k_pool, v_pool)]
+    if not interpret:
+        pools = _numbers(*pools)
     if merged:
         pools = [x.reshape(*x.shape[:2], 1, -1) for x in pools]
-    got = paged_attention(
-        jnp.asarray(q), *pools, jnp.asarray(tables),
-        jnp.asarray(lengths, jnp.int32), d ** -0.5,
-        kv_heads=hkv if merged else None,
-        lead_lengths=jnp.asarray(lead, jnp.int32), lead_rows=lead_rows,
-        interpret=interpret,
-    )
+
+    def attend(tables):
+        return paged_attention(
+            jnp.asarray(q), *pools, tables,
+            jnp.asarray(lengths, jnp.int32), d ** -0.5,
+            kv_heads=hkv if merged else None,
+            lead_lengths=jnp.asarray(lead, jnp.int32), lead_rows=lead_rows,
+            interpret=interpret,
+        )
+
+    got = attend(jnp.asarray(tables))
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
     for i, (ln, ld) in enumerate(zip(lengths, lead)):
         assert ld or not np.asarray(got)[i][first].any()
         assert ln or not np.asarray(got)[i].any()
+    if claimed:
+        _same_as_scratch_tail(got, attend, lengths)
 
 
 def test_two_lengths_go_together_and_without_a_window():
@@ -222,13 +290,15 @@ RINGS = {
 @pytest.mark.parametrize("case", list(RINGS))
 @pytest.mark.parametrize("merged", [False, True], ids=["heads-apart", "heads-merged"])
 @pytest.mark.parametrize("hq,hkv", [(6, 1), (12, 2)], ids=["gqa6", "gqa6x2"])
-def test_window_kernel_over_a_ring_matches_xla(case, hq, hkv, merged):
+@TAILS
+def test_window_kernel_over_a_ring_matches_xla(case, hq, hkv, merged, claimed):
     """The kernel (interpret mode) with a window over a RING table equals
     the fallback over the same table, and both equal plain attention over
     the last ``window`` positions of a dense history: a ring page that has
     been overwritten holds positions no query can see. ``merged``: the
     pools keep a row's heads on the lane axis, ``(pages, page, 1, Hkv *
-    D)``, and ``kv_heads`` says how many."""
+    D)``, and ``kv_heads`` says how many. ``claimed``: the ring pages no
+    position has reached yet, and the scratch page, hold NaN."""
     ring, window, lengths = RINGS[case]
     rng = np.random.default_rng(5)
     m, d, spg = len(lengths), 16, 9
@@ -237,6 +307,11 @@ def test_window_kernel_over_a_ring_matches_xla(case, hq, hkv, merged):
     hist_v = rng.standard_normal((m, spg * PAGE, hkv, d), np.float32)
     k_pool = np.zeros((m * ring + 1, PAGE, hkv, d), np.float32)
     v_pool = np.zeros_like(k_pool)
+    if claimed:
+        for pool in (k_pool, v_pool):
+            pool[-1] = np.nan
+            for i, ln in enumerate(lengths):
+                pool[i * ring + -(-ln // PAGE):(i + 1) * ring] = np.nan
     for i, ln in enumerate(lengths):
         for p in range(ln):  # later positions overwrite earlier ones
             page = i * ring + (p // PAGE) % ring
@@ -256,7 +331,9 @@ def test_window_kernel_over_a_ring_matches_xla(case, hq, hkv, merged):
     before = paged_ops.dispatch_counts()
     got = paged_attention(*args, sliding_window=window, interpret=True, **layout)
     assert paged_ops.dispatch_counts()["kernel"] == before["kernel"] + 1
-    xla = _paged_attention_xla(*args, None, window, None, **layout)
+    xla = _paged_attention_xla(
+        args[0], *_numbers(*args[1:3]), *args[3:], None, window, None, **layout
+    )
     want = _ref(q, hist_k, hist_v, lengths, scale, window)
     np.testing.assert_allclose(np.asarray(xla), want, atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
@@ -318,64 +395,78 @@ def test_op_softcap_and_traced_window_stay_xla():
 # ---------------------------------------------------------------- latent ---
 
 
-def _latent_case(rng, lengths, hq, hkv, dk, page, spg, dtype):
+def _latent_case(rng, lengths, hq, hkv, dk, page, spg, dtype, claimed=False):
     """An MLA-shaped pool: ``hkv`` latent heads of width ``dk`` in K, the
-    dummy ``(…, 1, 1)`` V the compressed cache mode allocates."""
+    dummy ``(…, 1, 1)`` V the compressed cache mode allocates; ``claimed``
+    as in ``_make_case``."""
     m = len(lengths)
     n_pages = m * spg
     k_pool = rng.standard_normal((n_pages + 1, page, hkv, dk), np.float32)
+    if claimed:
+        _poison(lengths, page, spg, k_pool)
     q = rng.standard_normal((m, hq, dk), np.float32)
     return (
         jnp.asarray(q, dtype), jnp.asarray(k_pool, dtype),
         jnp.zeros((n_pages + 1, page, 1, 1), dtype),
-        jnp.asarray(_own_pages(lengths, page, spg)),
+        jnp.asarray(_own_pages(lengths, page, spg, claimed)),
         jnp.asarray(lengths, jnp.int32),
     )
 
 
 # lengths: empty, one row, a page boundary, mid third page / 770 (the
-# cell's longest cache), a full table row
+# cell's longest cache), a full table row, empty again
 LATENT = {
     # DeepSeek-V2-Lite's latent head as published: 16 query heads on one
     # latent head, 512 + 64 rope lanes, values the first 512, 256-token pages
-    "published-f32": (16, 1, 576, 512, 256, 4, [0, 1, 256, 770, 1024], jnp.float32, 1e-5),
-    "published-bf16": (16, 1, 576, 512, 256, 4, [0, 1, 256, 770, 1024], jnp.bfloat16, 2e-2),
-    "small": (4, 1, 24, 16, 8, 4, [0, 1, 8, 19, 32], jnp.float32, 1e-5),
-    "small-two-heads": (4, 2, 24, 16, 8, 4, [0, 1, 8, 19, 32], jnp.float32, 1e-5),
+    "published-f32": (16, 1, 576, 512, 256, 4, [0, 1, 256, 770, 1024, 0], jnp.float32, 1e-5),
+    "published-bf16": (16, 1, 576, 512, 256, 4, [0, 1, 256, 770, 1024, 0], jnp.bfloat16, 2e-2),
+    "small": (4, 1, 24, 16, 8, 4, [0, 1, 8, 19, 32, 0], jnp.float32, 1e-5),
+    "small-two-heads": (4, 2, 24, 16, 8, 4, [0, 1, 8, 19, 32, 0], jnp.float32, 1e-5),
 }
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["pool", "int8-pool"])
 @pytest.mark.parametrize("case", list(LATENT))
-def test_latent_kernel_matches_xla(case, int8):
+@TAILS
+def test_latent_kernel_matches_xla(case, int8, claimed):
     """``values_from_k``: the kernel (interpret mode) takes its value block
     from the key block's first lanes and is handed no V pool; it must equal
     the fallback, which slices the gathered keys. int8 pools take the same
-    kernel: the key block is scaled, then sliced."""
+    kernel: the key block is scaled, then sliced (``claimed``: a dead
+    page's codes are whatever NaN casts to, its scales NaN)."""
     hq, hkv, dk, vfk, page, spg, lengths, dtype, tol = LATENT[case]
     q, k_pool, v_pool, tables, lens = _latent_case(
-        np.random.default_rng(3), lengths, hq, hkv, dk, page, spg, dtype
+        np.random.default_rng(3), lengths, hq, hkv, dk, page, spg, dtype,
+        claimed,
     )
     ks = vs = None
     if int8:
         kq, vq = quantize_kv_rows(k_pool), quantize_kv_rows(v_pool)
         k_pool, ks, v_pool, vs = kq["d"], kq["s"], vq["d"], vq["s"]
+        assert not claimed or np.isnan(np.asarray(ks[-1])).all()
     scale = dk ** -0.5
+
+    def attend(tables):
+        return paged_attention(
+            q, k_pool, v_pool, tables, lens, scale, values_from_k=vfk,
+            k_scale=ks, v_scale=vs, interpret=True,
+        )
+
     before = paged_ops.dispatch_counts()
-    got = paged_attention(
-        q, k_pool, v_pool, tables, lens, scale, values_from_k=vfk,
-        k_scale=ks, v_scale=vs, interpret=True,
-    )
+    got = attend(tables)
     after = paged_ops.dispatch_counts()
     assert after == {**before, "kernel": before["kernel"] + 1}
     want = _paged_attention_xla(
-        q, k_pool, v_pool, tables, lens, scale, None, None, vfk, ks, vs
+        q, *_numbers(k_pool, v_pool), tables, lens, scale, None, None, vfk,
+        *_numbers(ks, vs),
     )
     assert got.shape == want.shape == (len(lengths), hq, vfk)
+    if claimed:
+        _same_as_scratch_tail(got, attend, lengths, page, spg)
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
-    assert not got[0].any()  # the empty slot: zeros, on both paths
-    assert np.abs(want[1:]).max() > 0.5
+    assert not got[[0, -1]].any()  # the empty slots: zeros, on both paths
+    assert np.abs(want[1:-1]).max() > 0.5
 
 
 # (hq, hkv, dk, dv, values_from_k, int8): every layer's pool in ONE array
@@ -389,26 +480,33 @@ POOLED = {
 
 @pytest.mark.parametrize("interpret", [False, True], ids=["xla", "kernel"])
 @pytest.mark.parametrize("case", list(POOLED))
-def test_whole_pool_as_pages_with_a_traced_layer_offset(case, interpret):
+@TAILS
+def test_whole_pool_as_pages_with_a_traced_layer_offset(case, interpret, claimed):
     """The ragged decode body's form: an ``(L, P+1, page, H, D)`` pool viewed
     as ``L * (P+1)`` pages, layer ``l`` the page table offset by a TRACED
     ``l * (P+1)`` (a scan index), equals the call on that layer's own slice
     with the table as it is — a slot at a page boundary, an empty slot,
-    int8 ``{d, s}`` pools viewed leaf by leaf."""
+    int8 ``{d, s}`` pools viewed leaf by leaf; ``claimed``: every layer's
+    claimed pages and scratch page hold NaN (an int8 pool's scales)."""
     hq, hkv, dk, dv, vfk, int8 = POOLED[case]
     layers, lengths = 3, [0, 1, PAGE, 19, SPG * PAGE]
     m = len(lengths)
     n_pages = m * SPG + 1
     rng = np.random.default_rng(6)
     q = jnp.asarray(rng.standard_normal((m, hq, dk), np.float32))
-    k = jnp.asarray(rng.standard_normal((layers, n_pages, PAGE, hkv, dk), np.float32))
+    k = rng.standard_normal((layers, n_pages, PAGE, hkv, dk), np.float32)
     v = (
-        jnp.zeros((layers, n_pages, PAGE, 1, 1), jnp.float32) if vfk
-        else jnp.asarray(rng.standard_normal((layers, n_pages, PAGE, hkv, dv), np.float32))
+        np.zeros((layers, n_pages, PAGE, 1, 1), np.float32) if vfk
+        else rng.standard_normal((layers, n_pages, PAGE, hkv, dv), np.float32)
     )
+    if claimed:
+        _poison(lengths, PAGE, SPG, *k, *([] if vfk else v))
+    k, v = jnp.asarray(k), jnp.asarray(v)
     if int8:
         k, v = quantize_kv_rows(k), quantize_kv_rows(v)
-    tables = jnp.asarray(_own_pages(lengths, PAGE, SPG))
+    if not interpret:
+        k, v = _numbers(k, v)
+    tables = jnp.asarray(_own_pages(lengths, PAGE, SPG, claimed))
     lens = jnp.asarray(lengths, jnp.int32)
 
     def attend(k, v, tables):
@@ -431,6 +529,109 @@ def test_whole_pool_as_pages_with_a_traced_layer_offset(case, interpret):
         )
         assert not np.asarray(got[l])[0].any()  # the empty slot
     assert np.abs(np.asarray(got[0]) - np.asarray(got[1])).max() > 0.1
+    if claimed:
+        _same_as_scratch_tail(
+            got[0], lambda t: attend(*jax.tree.map(lambda x: x[0], (k, v)), t),
+            lengths,
+        )
+
+
+# ------------------------------------------------------------ the walk ---
+
+
+def _replay_walk(tables, lengths, page, window=None):
+    """The grid of one call replayed on the host under the pipeline's rule:
+    a step fetches where its block index differs from the step's before it,
+    and that fetch is issued when the step before it starts. Returns the
+    steps that fetch, and by step its slot and whether it has arithmetic
+    (the body's ``live``)."""
+    tables, lengths = np.asarray(tables), np.asarray(lengths)
+    mi, ji = np.divmod(np.arange(tables.size), tables.shape[1])
+    pages = np.asarray(paged_ops.walk_page(
+        mi, ji, tables, lengths, page_size=page, window=window
+    ))
+    live = ji * page < lengths[mi]
+    if window is not None:
+        live &= (ji + 1) * page > lengths[mi] - window
+    fetches = [0] + [k for k in range(1, len(mi)) if pages[k] != pages[k - 1]]
+    return fetches, mi, live, pages
+
+
+# (table, window, lengths): rows whose tails are CLAIMED pages, every id
+# distinct; lengths mid-page, on a page border, 0 (between live slots, twice
+# in a row, first and last) and full
+WALKS = {
+    "plain": ("plain", None, [5, PAGE, 0, 27, SPG * PAGE, 0, 0, 2 * PAGE + 1, 0]),
+    "plain-empty-first": ("plain", None, [0, 12, SPG * PAGE, 1]),
+    "plain-last-slot-short": ("plain", None, [SPG * PAGE, 3]),
+    "window": ("plain", 11, [5, PAGE, 0, 27, SPG * PAGE, 0, 2 * PAGE + 1]),
+    "window-is-a-page": ("plain", PAGE, [30, 0, 0, 17, PAGE, 3]),
+    "ring": ("ring", 12, [70, 0, 53, 24, 9, 0]),
+    "ring-never-wrapped": ("ring", 20, [32, 0, 21, 8, 1]),
+}
+
+
+@pytest.mark.parametrize("case", list(WALKS))
+def test_walk_fetches_the_pages_a_slot_holds_under_arithmetic(case):
+    """What no parity test can see (a dead step computes nothing, whatever
+    it fetched): (a) a call fetches ``max(1, live pages)`` blocks a slot —
+    not the pages its row names past the length; (b) every fetch but the
+    call's first and the one issued from an EMPTY slot's single step is
+    issued from a step with arithmetic to hide it under: a step past the
+    length names the next slot's first visible page, which that slot then
+    finds in the buffer. The walk that named its table row failed (a) on
+    claimed tails; one that clamps a dead step to the slot's OWN last page
+    fails (b) once a slot."""
+    kind, window, lengths = WALKS[case]
+    m, spg = len(lengths), (9 if kind == "ring" else SPG)
+    if kind == "ring":
+        ring = 4
+        tables = np.arange(m)[:, None] * ring + np.arange(spg)[None, :] % ring
+    else:
+        tables = np.arange(m * spg).reshape(m, spg)  # the whole row claimed
+    fetches, slot, live, pages = _replay_walk(tables, lengths, PAGE, window)
+    live_pages = np.bincount(slot[live], minlength=m)
+    assert len(fetches) == np.maximum(live_pages, 1).sum()
+    # and they are each slot's visible pages in turn, an empty slot's entry 0
+    visible = [
+        tables[i, j]
+        for i, ln in enumerate(lengths)
+        for j in range(
+            max(ln - (window or ln), 0) // PAGE, max(ln - 1, 0) // PAGE + 1
+        )
+    ]
+    assert pages[fetches].tolist() == visible
+    for k in fetches[1:]:
+        issued_from = k - 1
+        assert live[issued_from] or lengths[slot[issued_from]] == 0, (
+            f"step {k}'s fetch is issued from step {issued_from}, which "
+            f"computes nothing (slot {slot[issued_from]})"
+        )
+
+
+def test_walk_replay_tells_the_old_walks_apart(monkeypatch):
+    """The replay itself, held against the two walks it was written to
+    catch: the table row as it stands (every claimed page fetched) and the
+    backward clamp (a dead step repeats its slot's own last page: the next
+    slot's first page is then issued from a step with no arithmetic)."""
+    lengths = [5, PAGE, 17, SPG * PAGE, 3]
+    m = len(lengths)
+    tables = np.arange(m * SPG).reshape(m, SPG)
+
+    def row_as_it_stands(mi, ji, t, ln, **_):
+        return t[mi, ji]
+
+    def backward(mi, ji, t, ln, *, page_size, **_):
+        return t[mi, np.minimum(ji, np.maximum(ln[mi] - 1, 0) // page_size)]
+
+    must = sum(-(-ln // PAGE) for ln in lengths)
+    monkeypatch.setattr(paged_ops, "walk_page", row_as_it_stands)
+    assert len(_replay_walk(tables, lengths, PAGE)[0]) == m * SPG > must
+    monkeypatch.setattr(paged_ops, "walk_page", backward)
+    fetches, slot, live, _ = _replay_walk(tables, lengths, PAGE)
+    assert len(fetches) == must
+    from_dead = [k for k in fetches[1:] if not live[k - 1]]
+    assert [int(slot[k]) for k in from_dead] == [1, 2, 3]  # behind each short slot
 
 
 # (dk, dv, softcap, window, values_from_k, hkv) -> the kernel on a chip?

@@ -633,8 +633,11 @@ def test_ragged_mixed_length_cb_matches_serial():
         want = [_run(ref, p, **kw) for p, kw in jobs]
         got, _ = _concurrent(batcher, jobs)
         assert got == want
-        path, last_tick, total = batcher.kv_read_stats()
+        path, last_tick, total, claimed = batcher.kv_read_stats()
         assert path == "ragged" and total > 0
+        # RESERVE admission: a stream's whole need is in its row from its
+        # first token, so the rows name more than the slots hold
+        assert claimed > total
     finally:
         batcher.close()
 
@@ -740,16 +743,23 @@ def test_kv_read_accounting_ragged_below_gather():
     """Same short run on both paths: the ragged analytic KV-bytes-read must
     come in strictly below gather's (gather always reads every slot's full
     slot_pages regardless of true length)."""
-    totals, per_token = {}, {}
+    totals, claimed, per_token = {}, {}, {}
     for path in ("ragged", "gather"):
         batcher, _ = _ragged_batcher(path)
         try:
             _run(batcher, [5, 3], max_tokens=8)
-            totals[path] = batcher.kv_read_stats()[2]
+            totals[path], claimed[path] = batcher.kv_read_stats()[2:]
             per_token[path] = batcher.hbm_bytes_per_token_stats()
         finally:
             batcher.close()
     assert 0 < totals["ragged"] < totals["gather"]
+    # beside it, the bytes of every page the slots' rows name: 2 + 8 tokens
+    # claim 2 pages of 8 at admission and the row is 8 wide, so a ragged step
+    # is charged 3 pages (the claim and the scratch entry) where the slot
+    # holds 1 and then 2; gather reads the whole row, claimed or not
+    assert totals["ragged"] < claimed["ragged"] < claimed["gather"]
+    assert claimed["gather"] == totals["gather"]
+    assert claimed["ragged"] * 8 == claimed["gather"] * 3
     # the per-token gauges /metrics exports: the same weights either way
     # (one live slot, so the whole stream is its token's), less KV when ragged
     assert per_token["ragged"]["weights"] == per_token["gather"]["weights"] > 0

@@ -318,7 +318,11 @@ def test_metrics_render_the_tick_families_summed_over_replicas(engine):
         for gone in ("mst_tick_host_ms", "mst_tick_device_blocked_ms",
                      "mst_kv_bytes_read_last_tick"):
             assert gone not in text
-        assert "mst_kv_bytes_read_total" in text
+        read, claimed = (
+            int(text.split(f"\nmst_kv_bytes_{kind}_total ")[1].split()[0])
+            for kind in ("read", "claimed")
+        )
+        assert 0 < read <= claimed  # held and claimed K/V bytes side by side
     finally:
         batcher.close()
 
