@@ -130,13 +130,16 @@ REGISTRY: tuple = (
     ),
     ResourceSpec(
         kind="scheduler.page",
-        module="scheduler.py",
-        acquire=(),
-        release=(),
-        static=False,  # pool pops are covered by MST302; the ledger
-        # balances _free_pages pops against _unref_pages/_evict returns
-        notes="KV pool page; _page_ref counts slot claims + index/store "
-        "entry claims; every pop must return via the free list",
+        module="page_pool.py",
+        acquire=("take",),
+        release=("unref", "release", "reset"),
+        static=False,  # a page is held by COUNT (slots mapping it + an
+        # index/store entry), given back by whoever holds it last and
+        # named by slot as often as by page: no one handle for MST40x to
+        # follow. take() pops in one place (MST302 covers it) and the
+        # ledger balances it against unref's returns
+        notes="KV pool page; PagePool counts slot claims + index/store "
+        "entry claims; every take must return via the free list",
     ),
 )
 

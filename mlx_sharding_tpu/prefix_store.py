@@ -18,7 +18,7 @@ composes the pieces the stack already has into the shared subsystem:
   them (writes land in its private pages — the same immutability argument
   as the engine prompt cache). Entries are refcounted WeightStore-style:
   one :class:`PrefixLease` per slot mapping the pages, plus the entry's
-  own +1 on each page in the batcher's ``_page_ref`` accounting.
+  own +1 on each page in the batcher's pool (``PagePool.share``).
 - **host tier** — a digest-keyed :class:`~mlx_sharding_tpu.kv_transfer.
   KVSpillTier` of host-materialized ``KVPageBlock``s. On LAST lease
   release the entry demotes: the batcher exports the pages (dispatch-only

@@ -63,12 +63,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from mlx_sharding_tpu import diffusion, tracing
-from mlx_sharding_tpu.analysis import runtime as mst_runtime
 from mlx_sharding_tpu.analysis.runtime import (
     make_lock,
     note_acquire,
     note_release,
-    note_reset,
 )
 from mlx_sharding_tpu.cache import (
     KVCache,
@@ -85,6 +83,7 @@ from mlx_sharding_tpu.generate import (
     block_token_logprobs,
 )
 from mlx_sharding_tpu.kv_transfer import KVSpillTier, export_block, import_block
+from mlx_sharding_tpu.page_pool import PagePool
 from mlx_sharding_tpu.resilience import (
     Deadlines,
     HandoffReadyError,
@@ -113,21 +112,6 @@ from mlx_sharding_tpu.speculative import (
     AcceptanceTracker,
     NgramDraftProposer,
 )
-
-
-def _note_pages(owner, pages, *, acquired: bool):
-    """Leak-ledger shadow of a batch of free-list pops (acquired=True) or
-    returns. One global read when the ledger is off — the per-page loop
-    only runs under instrument_resources()."""
-    led = mst_runtime._RESOURCES
-    if led is None:
-        return
-    oid = id(owner)
-    for p in pages:
-        if acquired:
-            led.note_acquire("scheduler.page", (oid, p))
-        else:
-            led.note_release("scheduler.page", (oid, p))
 
 
 @dataclass(eq=False)  # identity semantics: requests key the spill tier
@@ -1092,20 +1076,17 @@ class ContinuousBatcher:
                     jax.tree.leaves(self.cache.k) + jax.tree.leaves(self.cache.v)
                 )
             )
-            self._free_pages = list(range(engine.pool_pages - 1, -1, -1))
-            self._pages_of: dict[int, list[int]] = {}  # slot → mapped pages
-            self.pages_high_water = 0
+            self.pool = PagePool(engine.pool_pages, engine.slot_pages)
             # Prompt-prefix sharing (vLLM-style content-addressed pages):
             # a FULL page of prompt KV is registered under the hash of the
             # whole token prefix it closes; a later request whose prompt
             # matches a chain of registered pages maps them read-only and
             # prefills only the suffix (its slot offset starts past them).
-            # Refcount = #slots mapping the page + 1 if the index holds it;
+            # A page's holders = #slots mapping it + 1 if the index holds it;
             # index-only pages are "cached": not free, evictable LRU when
             # admission runs short. The reference resets remote caches per
             # request (ref: shard/utils.py:122-124) — this is the beaten
             # semantics; Generator._pc is the single-stream analogue.
-            self._page_ref: dict[int, int] = {}
             self._prefix_index: "OrderedDict[bytes, int]" = OrderedDict()
             self.prefix_queries = 0
             self.prefix_hits = 0
@@ -1544,8 +1525,7 @@ class ContinuousBatcher:
         KV-HBM story of a paged pool; None on dense engines."""
         if not self.paged:
             return None
-        total = self.engine.pool_pages
-        return (total, total - len(self._free_pages), self.pages_high_water)
+        return (self.pool.total, self.pool.in_use, self.pool.high_water)
 
     def _pages_needed(self, n_prompt: int, max_tokens: int) -> int:
         page = self.engine.page_size
@@ -1703,7 +1683,7 @@ class ContinuousBatcher:
                 rows += -(-length // page) * page
                 # the row's distinct entries: the claim, and the scratch
                 # page where the row is wider
-                claimed += min(len(self._pages_of.get(slot, ())) + 1, width)
+                claimed += min(len(self.pool.pages(slot)) + 1, width)
             claimed *= page
         else:
             rows = claimed = len(live) * width * page
@@ -1778,58 +1758,34 @@ class ContinuousBatcher:
         ex = set(exclude)
         return sum(
             1 for p in self._prefix_index.values()
-            if self._page_ref.get(p) == 1 and p not in ex
+            if self.pool.refs(p) == 1 and p not in ex
         )
+
+    def _pool_covers(self, need: int, exclude: tuple = ()) -> bool:
+        """Can ``need`` pages come from the free list and eviction?"""
+        return need <= self.pool.free + self._evictable_pages(exclude)
 
     def _evict_for(self, n_needed: int):
         """Drop LRU index entries whose page no live slot maps until the
         free list can cover ``n_needed`` pages."""
-        while len(self._free_pages) < n_needed:
+        while self.pool.free < n_needed:
             victim = next(
                 (k for k, p in self._prefix_index.items()
-                 if self._page_ref.get(p) == 1),
+                 if self.pool.refs(p) == 1),
                 None,
             )
             if victim is None:
                 return
-            p = self._prefix_index.pop(victim)
-            self._page_ref.pop(p, None)
-            self._free_pages.append(p)
+            self.pool.unref((self._prefix_index.pop(victim),))
             self.prefix_evictions += 1
-            _note_pages(self, (p,), acquired=False)
 
-    def _table_row(self, pages: list) -> np.ndarray:
-        """A slot's page mapping as the device table's row, and the pool's
-        high-water mark bumped. Unmapped tail entries stay at the scratch
-        page (index pool_pages): overshoot writes past the mapping land
-        there harmlessly."""
-        row = np.full((self.engine.slot_pages,), self.engine.pool_pages,
-                      np.int32)
-        row[: len(pages)] = pages
-        in_use = self.engine.pool_pages - len(self._free_pages)
-        self.pages_high_water = max(self.pages_high_water, in_use)
-        return row
-
-    def _write_table_row(self, slot: int, pages: list):
+    def _write_table_row(self, slot: int):
         """Publish a decoding slot's grown mapping (a claim writes its row
         in its own program)."""
         self.table = self._row_set(
             self.table, self._put(np.int32(slot)),
-            self._put(self._table_row(pages)),
+            self._put(self.pool.row(self.pool.pages(slot))),
         )
-
-    def _unref_pages(self, pages):
-        for p in pages:
-            r = self._page_ref.get(p, 1) - 1
-            if r <= 0:
-                self._page_ref.pop(p, None)
-                self._free_pages.append(p)
-                _note_pages(self, (p,), acquired=False)
-            else:
-                self._page_ref[p] = r
-
-    def _release_pages(self, slot: int):
-        self._unref_pages(self._pages_of.pop(slot, []))
 
     # ------------------------------------------ prefix store (fleet-wide)
     def _store_digests(self, req: _Request) -> list:
@@ -1882,47 +1838,30 @@ class ContinuousBatcher:
         if len(digests) < cover:
             return None  # prompt changed since the plan was computed
         if kind == "device":
+            # the tail BEFORE the lease: if the headroom _fits saw has
+            # evaporated, take raises with nothing taken and no lease out
+            # (a lease never released is an entry that can never demote)
+            self._evict_for(n - cover)
+            tail = self.pool.take(n - cover)
             lease = store.acquire(self, digests, cover)
             if lease is None:
+                self.pool.unref(tail[::-1])  # the free list as it was
                 store.count_lookup("miss", digests)
                 return None  # entry demoted since _fits; plain prefill
             store.count_lookup("device")
-            for p in lease.pages:
-                # the slot's own claim on each shared page, released by
-                # _release_pages like any mapped page; the entry's claim
-                # (+1 at registration) outlives the slot
-                self._page_ref[p] += 1
-            tail: list[int] = []
-            try:
-                self._evict_for(n - cover)
-                for _ in range(n - cover):
-                    tail.append(self._free_pages.pop())
-            except BaseException:
-                # overcommit race: the headroom _fits saw evaporated
-                # before the tail allocation — give back the partial
-                # pops, the slot's claims and the COW lease, or the
-                # entry can never demote
-                self._free_pages.extend(tail)
-                for p in lease.pages:
-                    self._page_ref[p] -= 1
-                lease.release()
-                raise
-            _note_pages(self, tail, acquired=True)
-            pages = list(lease.pages) + tail
-            for p in tail:
-                self._page_ref[p] = 1
+            # the slot's own claim on each shared page, released with its
+            # mapping like any mapped page; the entry's claim (+1 at
+            # registration) outlives the slot
             req._please = lease
-            return pages, lease.n_tokens
+            self.pool.share(lease.pages)
+            return list(lease.pages) + tail, lease.n_tokens
         block = store.host_block(digests[cover - 1])
         if block is None:
             store.count_lookup("miss", digests)
             return None  # evicted since _fits; plain prefill
         store.count_lookup("host")
         self._evict_for(n)
-        pages = [self._free_pages.pop() for _ in range(n)]
-        _note_pages(self, pages, acquired=True)
-        for p in pages:
-            self._page_ref[p] = 1
+        pages = self.pool.take(n)
         page = self.engine.page_size
         try:
             was_staged = block.is_prefetched
@@ -1960,9 +1899,8 @@ class ContinuousBatcher:
             force=True,
         )
         if lease is not None:
-            for p in lease.pages:
-                self._page_ref[p] += 1  # the promoted entry's own claim
             req._please = lease
+            self.pool.share(lease.pages)  # the promoted entry's own claim
         return pages, cover * page
 
     def _store_insert(self, req: _Request):
@@ -1978,7 +1916,7 @@ class ContinuousBatcher:
         if not digests:
             return
         k = len(digests)
-        pages = self._pages_of.get(req.slot, [])[:k]
+        pages = self.pool.pages(req.slot)[:k]
         if len(pages) < k:
             return
         page = self.engine.page_size
@@ -1988,9 +1926,8 @@ class ContinuousBatcher:
         )
         if lease is None:
             return
-        for p in lease.pages:
-            self._page_ref[p] += 1  # the entry's own claim on each page
         req._please = lease
+        self.pool.share(lease.pages)  # the entry's own claim on each page
 
     def _drop_prefix_lease(self, req: _Request):
         """Release ``req``'s prefix lease exactly once (idempotent via the
@@ -2032,7 +1969,7 @@ class ContinuousBatcher:
             logging.getLogger(__name__).debug(
                 "prefix demotion export failed (prefix dropped): %s", e
             )
-        self._unref_pages(entry.pages)
+        self.pool.unref(entry.pages)
 
     def _pod_fetch_waiting(self):
         """Consult the pod view for head-of-line waiting requests whose
@@ -2186,8 +2123,8 @@ class ContinuousBatcher:
         # the page pool dies with the engine: index-resident prefix pages
         # (legitimately out of the free list while the batcher lives) are
         # discarded wholesale, so retire them from the leak ledger too
-        oid = id(self)
-        note_reset("scheduler.page", lambda k: k[0] == oid)
+        if self.paged:
+            self.pool.forget()
         # release engine-held resources (a shared-weight store lease drops
         # its ref here — drain/retire/hot-swap all funnel through close())
         eng_close = getattr(self.engine, "close", None)
@@ -2285,16 +2222,10 @@ class ContinuousBatcher:
                 # claim the chain BEFORE evicting: at ref 2 its pages are
                 # invisible to _evict_for, which must only reclaim OTHER
                 # index-only pages (matching the _fits exclude accounting)
-                for p in shared:
-                    self._page_ref[p] += 1
+                self.pool.share(shared)
                 self._evict_for(n - len(shared))
-                pages = shared + [
-                    self._free_pages.pop() for _ in range(n - len(shared))
-                ]
-                _note_pages(self, pages[len(shared):], acquired=True)
-                for p in pages[len(shared):]:
-                    self._page_ref[p] = 1
-            self._pages_of[slot] = pages
+                pages = shared + self.pool.take(n - len(shared))
+            self.pool.bind(slot, pages)
         self._claim(req, slot, pages, reused_tokens)
         if self.spec_tracker is not None:
             # new stream in the slot: window back to the probe rung, no
@@ -2316,7 +2247,7 @@ class ContinuousBatcher:
         table, offset, self.sp, self.rep_sizes, doffset = self._claim_slot(
             self._put(_pack_i32(
                 np.int32(slot), np.int32(start), np.int32(req.rep_context),
-                *([self._table_row(pages)] if self.paged else []),
+                *([self.pool.row(pages)] if self.paged else []),
                 *req.sp,
             )),
             self.table if self.paged else None,
@@ -2375,15 +2306,12 @@ class ContinuousBatcher:
             data_pages = block.n_pages
             need = max(self._need_pages(req, block=block), data_pages)
             self._evict_for(need)
-            if len(self._free_pages) < need:
+            if self.pool.free < need:
                 raise RuntimeError(
                     f"target pool exhausted mid-import: need {need} pages, "
-                    f"{len(self._free_pages)} free"
+                    f"{self.pool.free} free"
                 )
-            pages = [self._free_pages.pop() for _ in range(need)]
-            _note_pages(self, pages, acquired=True)
-            for p in pages:
-                self._page_ref[p] = 1
+            pages = self.pool.take(need)
             # residency accounting, read BEFORE the import consumes the
             # stage: a host block with device-staged pages is the overlapped
             # path (prefetch hit); host without a stage is the demand import
@@ -2413,14 +2341,12 @@ class ContinuousBatcher:
             logging.getLogger(__name__).debug(
                 "KV block import failed (falling back to re-prefill): %s", e
             )
-            if pages:
-                self._pages_of[slot] = pages
-                self._release_pages(slot)
+            self.pool.unref(pages)
             self._fold_history(req)
             with self._admission_lock:
                 self.spill_fallbacks += 1
             return False
-        self._pages_of[slot] = pages
+        self.pool.bind(slot, pages)
         # offset = valid KV rows; the next decode step writes row n_tokens
         self._claim(req, slot, pages, block.n_tokens)
         resume = self._put(_pack_i32(
@@ -2550,13 +2476,13 @@ class ContinuousBatcher:
             # key. Decode writes start at prompt.size, past all of them, so a
             # registered page is immutable for its pool lifetime. Pages a
             # concurrent identical prompt registered first just get touched.
-            pages = self._pages_of.get(req.slot, [])
+            pages = self.pool.pages(req.slot)
             for i, key in enumerate(self._prefix_keys(req)):
                 if key in self._prefix_index:
                     self._prefix_index.move_to_end(key)
                     continue
                 self._prefix_index[key] = pages[i]
-                self._page_ref[pages[i]] = self._page_ref.get(pages[i], 0) + 1
+                self.pool.share((pages[i],))  # the index's own claim
         elif self.prefix_store is not None and req._please is None:
             # fleet-store insertion (bookkeeping only — refcounts and dict
             # entries, no device work on this hot path): the freshly
@@ -2740,9 +2666,9 @@ class ContinuousBatcher:
                 # (registration covers only full PROMPT pages; decode
                 # writes start past them). Index-registered pages survive
                 # as cache entries until LRU eviction needs them back.
-                self._release_pages(req.slot)
+                self.pool.release(req.slot)
                 # the slot's claim on any store-shared prefix pages is gone
-                # with _release_pages; the lease is the ENTRY's lifetime —
+                # with pool.release; the lease is the ENTRY's lifetime —
                 # last release demotes the prefix to the host tier
                 # (dispatch-only export; the flusher does the host copy)
                 self._drop_prefix_lease(req)
@@ -2914,7 +2840,7 @@ class ContinuousBatcher:
         # is last_tok / history[-1], fed as the next decode input)
         n_tokens = req.prompt.size + max(0, len(req.history) - 1)
         n_pages = -(-max(1, n_tokens) // page)
-        pages = self._pages_of.get(slot, [])[:n_pages]
+        pages = self.pool.pages(slot)[:n_pages]
         ok = False
         if len(pages) == n_pages:
             try:
@@ -2972,7 +2898,7 @@ class ContinuousBatcher:
             self.active, self._put(jnp.asarray(slot, jnp.int32)),
             self._put(jnp.asarray(False)),
         )
-        self._release_pages(slot)
+        self.pool.release(slot)
         # suspend runs quiesced, so a last-release demotion's export
         # dispatch is safe here; re-admission re-plans against the store
         self._drop_prefix_lease(req)
@@ -3191,7 +3117,7 @@ class ContinuousBatcher:
             note_release("scheduler.slot", (id(self), slot))
             req.slot = -1
             if req.cancelled:
-                self._release_pages(slot)
+                self.pool.release(slot)
                 self._drop_prefix_lease(req)
                 self._drop_spill(req)
                 self._hand(req, None)
@@ -3203,7 +3129,7 @@ class ContinuousBatcher:
             if tr is not None:
                 tr.add("migration", t0, time.perf_counter(), slot=slot,
                        block=state.block is not None)
-            self._release_pages(slot)
+            self.pool.release(slot)
             self._drop_prefix_lease(req)
             self._hand(req, RequestMigratedError(state))
             with self._admission_lock:
@@ -3256,7 +3182,7 @@ class ContinuousBatcher:
             page = self.engine.page_size
             n_tokens = req.prompt.size + max(0, len(req.history) - 1)
             n_pages = -(-max(1, n_tokens) // page)
-            pages = self._pages_of.get(slot, [])[:n_pages]
+            pages = self.pool.pages(slot)[:n_pages]
             if len(pages) == n_pages:
                 try:
                     block = export_block(
@@ -3341,7 +3267,7 @@ class ContinuousBatcher:
                 self.active, self._put(jnp.asarray(slot, jnp.int32)),
                 self._put(jnp.asarray(False)),
             )
-            self._release_pages(slot)
+            self.pool.release(slot)
             # a prefill-only request's insertion lease drops HERE: last
             # release demotes the freshly prefilled prefix to the host
             # tier, which is exactly what lets the disagg coordinator skip
@@ -3355,6 +3281,22 @@ class ContinuousBatcher:
                 self.handoffs_out += 1
                 self._finish_times.append(self._clock())
 
+    def _growth_wanted(self, slot: int, req: _Request) -> int:
+        """Pages a decoding slot lacks for its next block's KV writes
+        (<= 0: it holds them all)."""
+        emitted = len(req.history)
+        # next KV write lands at prompt + emitted - 1 (the first sampled
+        # token writes no KV; each block step writes one)
+        offset = req.prompt.size + max(0, emitted - 1)
+        # total pages this request can ever touch — same quantity
+        # generate_step bounded by the pool size at submission
+        cap = self._pages_needed(
+            req.prompt.size, emitted + (req.max_tokens - req.produced)
+        )
+        want = min(-(-(offset + self._grow_ahead) // self.engine.page_size),
+                   cap)
+        return want - len(self.pool.pages(slot))
+
     def _grow_for_decode(self):
         """Over-commit page growth: before a decode block runs, every
         decoding slot must have pages covering the block's KV writes. Grow
@@ -3363,8 +3305,6 @@ class ContinuousBatcher:
         The oldest admitted request is never preempted, and generate_step's
         absolute capacity check proves a lone request's full need fits the
         pool, so it can always grow to completion — progress is guaranteed."""
-        page = self.engine.page_size
-        K = self._grow_ahead
         decoding = sorted(
             (
                 (slot, req)
@@ -3375,29 +3315,13 @@ class ContinuousBatcher:
         )
         for slot, req in decoding:
             while self._slots[slot] is req:  # a victim skips its own growth
-                have = len(self._pages_of.get(slot, ()))
-                emitted = len(req.history)
-                # next KV write lands at prompt + emitted - 1 (the first
-                # sampled token writes no KV; each block step writes one)
-                offset = req.prompt.size + max(0, emitted - 1)
-                # total pages this request can ever touch — same quantity
-                # generate_step bounded by the pool size at submission
-                cap = self._pages_needed(
-                    req.prompt.size, emitted + (req.max_tokens - req.produced)
-                )
-                want = min(-(-(offset + K) // page), cap)
-                n_more = want - have
+                n_more = self._growth_wanted(slot, req)
                 if n_more <= 0:
                     break
                 self._evict_for(n_more)
-                if len(self._free_pages) >= n_more:
-                    fresh = [self._free_pages.pop() for _ in range(n_more)]
-                    _note_pages(self, fresh, acquired=True)
-                    for p in fresh:
-                        self._page_ref[p] = 1
-                    pages = self._pages_of[slot]
-                    pages.extend(fresh)
-                    self._write_table_row(slot, pages)
+                if self.pool.free >= n_more:
+                    self.pool.extend(slot, self.pool.take(n_more))
+                    self._write_table_row(slot)
                     break
                 victims = [r for r in self._slots if r is not None]
                 if len(victims) <= 1:
@@ -3410,7 +3334,7 @@ class ContinuousBatcher:
                     self._hand(req, RuntimeError(
                         f"KV page pool exhausted: slot {slot} needs "
                         f"{n_more} more page(s) for its next decode block "
-                        f"but only {len(self._free_pages)} are free and no "
+                        f"but only {self.pool.free} are free and no "
                         "other request remains to preempt"
                     ))
                     self._finish(req)
@@ -3462,7 +3386,7 @@ class ContinuousBatcher:
             args = dict(
                 seq=seq, live=len(live), want_lp=int(want_lp),
                 pages=max(
-                    len(self._pages_of.get(slot, ())) for slot, _ in live
+                    len(self.pool.pages(slot)) for slot, _ in live
                 ) if self.paged else 0,
             )
         with self._phases.span("dispatch", **args):  # mst.decode_block
@@ -3957,7 +3881,7 @@ class ContinuousBatcher:
             # block import allocates its whole need fresh (no page sharing
             # with the prefix index), so the chain doesn't discount it
             req._chain = None
-            return need <= len(self._free_pages) + self._evictable_pages()
+            return self._pool_covers(need)
         if self.prefix_store is not None:
             # fleet-store LPM instead of the slot-local chain (mutually
             # exclusive by construction): a device hit discounts the
@@ -3978,7 +3902,7 @@ class ContinuousBatcher:
             req._splan = None
             plan = self._store_lookup(req)
             discount = plan[1] if plan is not None and plan[0] == "device" else 0
-            ok = need - discount <= len(self._free_pages) + self._evictable_pages()
+            ok = self._pool_covers(need - discount)
             if ok and plan is not None:
                 # only a fitting request carries its plan into _assign_slot
                 # (same admission pass, same thread — no staleness window
@@ -3988,9 +3912,7 @@ class ContinuousBatcher:
         chain = self._prefix_lookup(req)
         # the chain's own pages must not double as eviction fodder: they're
         # about to be mapped, so only OTHER cached pages can be reclaimed
-        ok = need - len(chain) <= len(self._free_pages) + self._evictable_pages(
-            exclude=[p for _, p in chain]
-        )
+        ok = self._pool_covers(need - len(chain), [p for _, p in chain])
         # only a fitting request hands its chain to _assign_slot (same
         # admission pass); a stale chain could reference since-evicted pages
         req._chain = chain if ok else None
@@ -4096,30 +4018,20 @@ class ContinuousBatcher:
     def _growth_fits(self) -> bool:
         """True iff the next ``_grow_for_decode`` is guaranteed to cover
         every decoding slot's block from free + evictable pages alone, i.e.
-        growth cannot preempt. Mirrors _grow_for_decode's want/cap math
-        exactly; the aggregate bound is exact because evictions only free
-        index-only pages (never counted in any slot's ``have``) and nothing
-        else allocates between the check and the growth. The emitted/
-        produced counts are one block stale in async mode — which the
-        doubled ``_grow_ahead`` already covers — and ``cap`` is
-        staleness-invariant (history and produced increment together)."""
+        growth cannot preempt. The aggregate bound is exact because
+        evictions only free index-only pages (never counted in any slot's
+        mapping) and nothing else allocates between the check and the
+        growth. The emitted/produced counts are one block stale in async
+        mode — which the doubled ``_grow_ahead`` already covers — and the
+        cap in ``_growth_wanted`` is staleness-invariant (history and
+        produced increment together)."""
         if not (self.paged and self.overcommit):
             return True
-        page = self.engine.page_size
-        K = self._grow_ahead
-        need = 0
-        for slot, req in enumerate(self._slots):
-            if req is None or not self._prefill_done(req):
-                continue
-            have = len(self._pages_of.get(slot, ()))
-            emitted = len(req.history)
-            offset = req.prompt.size + max(0, emitted - 1)
-            cap = self._pages_needed(
-                req.prompt.size, emitted + (req.max_tokens - req.produced)
-            )
-            want = min(-(-(offset + K) // page), cap)
-            need += max(0, want - have)
-        return need <= len(self._free_pages) + self._evictable_pages()
+        return self._pool_covers(sum(
+            max(0, self._growth_wanted(slot, req))
+            for slot, req in enumerate(self._slots)
+            if req is not None and self._prefill_done(req)
+        ))
 
     def _tick_async(self):
         """One double-buffered scheduler iteration: dispatch decode block
@@ -4357,12 +4269,8 @@ class ContinuousBatcher:
         if self.paged:
             # cache contents are unreliable after a failure: reset the pool
             # wholesale (all pages free, index dropped)
-            self._pages_of.clear()
-            self._page_ref.clear()
+            self.pool.reset()
             self._prefix_index.clear()
-            self._free_pages = list(range(self.engine.pool_pages - 1, -1, -1))
-            oid = id(self)
-            note_reset("scheduler.page", lambda k: k[0] == oid)
             if self.prefix_store is not None:
                 # the fleet store's device entries for THIS engine point at
                 # pages the wholesale reset just freed — drop them (marking

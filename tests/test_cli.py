@@ -41,7 +41,7 @@ def test_generate_cli(ckpt):
     assert "TTFT" in r.stderr
 
 
-@pytest.mark.run_last
+@pytest.mark.run_first
 def test_chip_smoke_rehearsal():
     """chip_smoke.py --rehearse: the chip run's own control flow (children
     one at a time, the checkpoint writer, the full-vocabulary tokenizer, both
@@ -55,7 +55,7 @@ def test_chip_smoke_rehearsal():
     assert last["device"]["platform"] == "cpu"
 
 
-@pytest.mark.run_last
+@pytest.mark.run_first
 def test_chip_smoke_refuses_without_a_chip():
     """Without --rehearse a platform other than tpu fails before any model
     is built: non-zero exit, "ok": false, no checkpoint written."""

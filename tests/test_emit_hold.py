@@ -413,7 +413,7 @@ def test_a_drain_that_dispatches_no_chunk_lets_go_at_the_ticks_end(
         decoding(b, tape, a)
         stolen = []
         if exit_ == "head_does_not_fit":
-            stolen, b._free_pages[:] = b._free_pages[:], []
+            stolen = b.pool.take(b.pool.free)
         elif exit_ == "shed":
             d.deadlines = Deadlines(submitted_at=b._clock() - 2.0,
                                     ttft_deadline=b._clock() - 1.0)
@@ -443,7 +443,7 @@ def test_a_drain_that_dispatches_no_chunk_lets_go_at_the_ticks_end(
         assert stats["emit_held"] == {"chunk": 0, "tick_end": len(puts), "fail": 0}
         assert stats["emit_holds"] == 1
         # the line moves on once the pages are back
-        b._free_pages[:] = stolen or b._free_pages
+        b.pool.unref(stolen[::-1])  # the free list as it was
         drive(b, tape, {}, admitted=[a, d])
     finally:
         b.close()
@@ -790,7 +790,7 @@ def test_a_first_token_that_ends_its_stream_finishes_it_behind_the_block(
         e = request(b, tape, "E", PROMPTS["C"], 5, **sampler)
         decoding(b, tape, a)
         before = b.tick_phase_stats()
-        free = len(b._free_pages) if paged else 0
+        free = b.pool.free if paged else 0
         b._waiting.append(d)
         tick(b, tape)
         events = tape.ticks()[-1]
@@ -799,7 +799,7 @@ def test_a_first_token_that_ends_its_stream_finishes_it_behind_the_block(
         assert d.slot == -1 and b._inflight is not None
         assert [slot for slot, r in b._inflight.live if r is d]  # live in it
         if paged:
-            assert len(b._free_pages) == free
+            assert b.pool.free == free
         # the next joiner takes the slot while that block is still unread
         drive(b, tape, {0: [e]}, admitted=[a, d])
         stats = b.tick_phase_stats()

@@ -7,6 +7,7 @@ engine, slots are reclaimed and reused, and batched decode beats serial
 throughput.
 """
 
+import sys
 import threading
 import time
 
@@ -350,7 +351,38 @@ def test_overcommit_preempt_resume_seeded_exact(oc_setup):
     assert batcher.preemptions > before
     # pool accounting intact after the churn: everything back on the free list
     total, in_use, _ = batcher.page_stats()
-    assert in_use == 0 and len(batcher._free_pages) == total
+    assert in_use == 0 and batcher.pool.free == total
+
+
+def test_growth_and_its_forecast_share_one_computation_of_the_want(oc_setup):
+    """``_growth_fits`` must promise exactly what ``_grow_for_decode`` will
+    take, so both ask ONE method for the pages a slot's next block wants:
+    neither spells the arithmetic (``have``, ``offset``, ``cap``) itself."""
+    batcher, ref = oc_setup
+    cls = type(batcher)
+    callers = set()
+    wanted = batcher._growth_wanted
+
+    def recording(slot, req):
+        frame = sys._getframe(1)
+        for _ in range(2):  # a caller, or the caller of its comprehension
+            callers.add(frame.f_code.co_name)
+            frame = frame.f_back
+        return wanted(slot, req)
+
+    batcher._growth_wanted = recording
+    try:
+        assert _run(batcher, [7, 7, 2, 1], max_tokens=20) == _run(
+            ref, [7, 7, 2, 1], max_tokens=20)
+    finally:
+        del batcher._growth_wanted
+    assert {"_grow_for_decode", "_growth_fits"} <= callers
+    for fn in (cls._grow_for_decode, cls._growth_fits):
+        names = set(fn.__code__.co_names)
+        for const in fn.__code__.co_consts:  # a comprehension's own code
+            names |= set(getattr(const, "co_names", ()))
+        assert "_growth_wanted" in names
+        assert not names & {"_pages_needed", "page_size", "_grow_ahead"}
 
 
 # (Heavier over-commit / speculation composition cases — each building its
@@ -549,7 +581,7 @@ def test_prefix_cache_eviction_and_no_leaks():
         assert evictions > 0
         total, in_use, _ = batcher.page_stats()
         assert in_use == cached  # only cache entries hold pages now
-        assert len(batcher._free_pages) + cached == total
+        assert batcher.pool.free + cached == total
         # and a cached prompt still hits after the shuffle
         hits_before = batcher.prefix_stats()[1]
         assert _run(batcher, prompts[-1], max_tokens=4) == _run(
@@ -775,7 +807,7 @@ def test_overcommit_pool_exhaustion_errors_not_wedges():
     try:
         gen = batcher.generate_step([5, 9], max_tokens=24)  # 4-page full need
         next(gen)  # prefill done, decode under way
-        batcher._free_pages = []  # simulate the drift: pool gone
+        batcher.pool.take(batcher.pool.free)  # simulate the drift: pool gone
         with pytest.raises(RuntimeError, match="pool exhausted"):
             for _ in gen:
                 pass
@@ -939,7 +971,7 @@ def test_async_mid_stream_cancellation_sheds_lookahead():
             ref, [1, 2], max_tokens=3
         )
         total, in_use, _ = batcher.page_stats()
-        assert in_use == 0 and len(batcher._free_pages) == total
+        assert in_use == 0 and batcher.pool.free == total
         assert all(r is None for r in batcher._slots)
     finally:
         batcher.close()
@@ -982,12 +1014,12 @@ def test_async_harvest_fault_sheds_cleanly():
         deadline = time.monotonic() + 10
         while time.monotonic() < deadline:
             total, in_use, _ = batcher.page_stats()
-            if in_use == 0 and len(batcher._free_pages) == total:
+            if in_use == 0 and batcher.pool.free == total:
                 break
             time.sleep(0.01)
         assert all(r is None for r in batcher._slots)
         total, in_use, _ = batcher.page_stats()
-        assert in_use == 0 and len(batcher._free_pages) == total
+        assert in_use == 0 and batcher.pool.free == total
         # and the scheduler thread survived to serve the next request
         assert _run(batcher, [3, 4], max_tokens=4) == _run(
             ref, [3, 4], max_tokens=4
@@ -1041,7 +1073,7 @@ def test_async_overcommit_preemption_matches_sync():
             got, _ = _concurrent(batcher, jobs)
             assert batcher.preemptions > before
             total, in_use, _ = batcher.page_stats()
-            assert in_use == 0 and len(batcher._free_pages) == total
+            assert in_use == 0 and batcher.pool.free == total
             streams[mode] = got
         finally:
             batcher.close()

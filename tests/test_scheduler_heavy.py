@@ -109,7 +109,7 @@ def test_spec_cb_paged_overcommit_compose():
         assert got == refs
         assert batcher.preemptions > before
         total, in_use, _ = batcher.page_stats()
-        assert in_use == 0 and len(batcher._free_pages) == total
+        assert in_use == 0 and batcher.pool.free == total
     finally:
         batcher.close()
 
