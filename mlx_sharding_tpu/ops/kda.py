@@ -19,7 +19,8 @@ inputs of its convolution in the activation dtype.
 
 Three forms of the recurrence, all float32. :func:`kda_sequential` is the
 definition, one position at a time. A prompt chunk runs :func:`kda_chunked`:
-blocks of ``chunk`` positions as matrix products (the WY form below). A decode
+blocks of ``chunk`` positions as matrix products (the WY form below), on a TPU
+as ONE Pallas pass (:func:`kda_chunk_call`, further down). A decode
 step runs the one-step form on the state pool ``(layers, rows, H, D, D)``
 where it lies (:func:`kda_pool_step`, a Pallas kernel after
 ``ops.mamba2.ssm_pool_step``: one grid step a slot, read the slot's S, scale
@@ -46,6 +47,33 @@ sums and its unit lower triangular solve are made at once and only the three
 products with ``S_0`` run block after block. ``exp(-G_i)`` ALONE is never
 formed: at ``exp(A_log)`` = 16 it overflows float32 inside 64 positions;
 every exponent here is a difference that is ``<= 0``.
+
+The chunked form's kernel. As array operations (:func:`_kda_chunked_xla`: a
+backend without the kernel, the lanes of a ``jax.vmap``) a layer's chunk is a
+cumulative sum, two masked exponentials, two einsums, a concatenate, a
+``triangular_solve`` custom call that walks each 64 x 64 system row after row
+from HBM, three transposing copies in and one out, and a scan of three small
+products a block: a dozen float32 operations each too small to fill the chip.
+:func:`kda_chunk_call` runs a block of a few heads whole in VMEM, grid
+``(sequence, heads / hb, blocks)`` with the blocks in order and ``S`` resident
+in the result's block over them: the pairwise sums on the matrix unit (one
+decay a head; with one a key channel they stay :func:`_chunk_pairwise_xla`'s
+and come in as operands), ``(I + A) X = [beta exp(G) K | beta V]`` by
+SUBSTITUTION, and the three products with ``S``. The substitution: ``A``'s 16 x
+16 diagonal blocks are inverted row after row (row ``r`` of an inverse is
+``e_r`` less ``A``'s row ``r`` times the rows above it; a head's four blocks
+stacked on sublanes and advanced together), then ``X``'s four row blocks follow
+one another, ``X_I = T_I (R_I - A_{I,<I} X_{<I})``, by products. No power of
+``A`` is formed: at ``beta`` near 2 and repeated keys ``A`` is nearly 2
+everywhere below the diagonal, its inverse is bounded (entries of about 2 in
+alternating signs) and its powers grow by binomials to ``1e28`` before they
+cancel, which no float32 Neumann or repeated-squaring product survives. Keys
+96 wide (no lane tile) are laid out by the kernel in VMEM; heads go first in
+HBM (``(B, H, T, …)``, a block of a head whole rows); ``n_valid`` rides in as a
+scalar-prefetch operand and a block wholly past it costs neither arithmetic nor
+a fetch (its block index repeats the last live block's) — a 512-row chunk of a
+200-token prompt runs four of its eight blocks. Products are float32 at
+``Precision.HIGHEST`` as the array form's are.
 
 The scalar-decay case (:func:`gdn_mixer`). ``g = -exp(A_log[h]) * softplus(
 in_proj_ba(u)[h] + dt_bias[h])`` is one number a position and VALUE head:
@@ -102,11 +130,14 @@ from mlx_sharding_tpu.ops.mamba2 import keep_inactive, put_rows, take_rows
 
 _HI = jax.lax.Precision.HIGHEST
 
-# Which path a decode step's recurrence took, once per traced call
-# (ops/dispatch.py). /metrics shows it as ``mst_kda_dispatch_total{path}``:
-# "xla" above 0 on a chip says some layer slices its rows of S out of the
-# pool, passes over them twice and writes them back.
-_DISPATCHED = DispatchCounter("kernel", "xla")
+# Which path a recurrence took, once per traced call (ops/dispatch.py).
+# /metrics shows it as ``mst_kda_dispatch_total{path}``. A decode step:
+# "kernel", or "xla" — above 0 on a chip it says some layer slices its rows
+# of S out of the pool, passes over them twice and writes them back. A
+# chunk's chunked form: "chunk_kernel", or "chunk_xla" — above 0 on a chip
+# it says some layer's chunk ran as array operations around a triangular
+# solve's custom call.
+_DISPATCHED = DispatchCounter("kernel", "xla", "chunk_kernel", "chunk_xla")
 dispatch_counts = _DISPATCHED.counts
 _count_dispatch = _DISPATCHED.count
 
@@ -114,6 +145,10 @@ _count_dispatch = _DISPATCHED.count
 CHUNK = 64
 #: bytes of S one grid step of :func:`kda_pool_step` moves each way, at most
 _STEP_BLOCK_BYTES = 2 << 20
+#: positions a diagonal block of the chunk kernel's triangular system holds
+_SUB = 16
+#: heads one grid step of the chunk kernel holds, at most
+_CHUNK_HEADS = 4
 
 
 def kda_sequential(q, k, v, g, beta, state):
@@ -141,34 +176,35 @@ def kda_sequential(q, k, v, g, beta, state):
     return jnp.moveaxis(o, 0, 1), state
 
 
-def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
-    """:func:`kda_sequential` over blocks of ``chunk`` positions as matrix
-    products (the module docstring's WY form): same arguments and result.
-    A ragged last block is padded with rows of ``g = 0``, ``beta = 0``, which
-    pass the state through. With ``g (B, T, H)``, one decay a head, the
-    pairwise sums are matrix products and no ``(C, C, Dk)`` array is built."""
-    b, t, h, dk = k.shape
-    per_head = g.ndim == beta.ndim
-    pad = -t % chunk
-    if pad:
-        padt = lambda z: jnp.pad(z, ((0, 0), (0, pad)) + ((0, 0),) * (z.ndim - 2))  # noqa: E731
-        q, k, v, g, beta = padt(q), padt(k), padt(v), padt(g), padt(beta)
-    nc = (t + pad) // chunk
-    # (nc, B, H, C, …): blocks first for the scans, positions beside channels
-    split = lambda z: jnp.moveaxis(  # noqa: E731
-        z.reshape(b, nc, chunk, *z.shape[2:]), (1, 3), (0, 2)
+def _split_blocks(z, chunk: int):
+    """``(B, nc * chunk, H, …)`` as ``(nc, B, H, chunk, …)``: blocks first for
+    the scan, positions beside channels."""
+    b, t = z.shape[:2]
+    return jnp.moveaxis(
+        z.reshape(b, t // chunk, chunk, *z.shape[2:]), (1, 3), (0, 2)
     )
-    q, k, v, g = split(q), split(k), split(v), split(g)
-    beta = split(beta[..., None])  # (nc, B, H, C, 1)
-    gc = jnp.cumsum(g, axis=3)  # G_t, from the block's start
+
+
+def _chunk_pairwise_xla(q, k, gc):
+    """``(P_ti(k)`` for ``i < t``, ``P_ti(q)`` for ``i <= t)`` of every block
+    as array operations: ``q`` / ``k (nc, B, H, C, Dk)``, ``gc`` the decay's
+    running sum from each block's start, ``(nc, B, H, C, Dk)`` or, one decay
+    a head, ``(nc, B, H, C)``. Both ``(nc, B, H, C, C)``."""
+    chunk = k.shape[-2]
     before = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
     upto = jnp.tril(jnp.ones((chunk, chunk), bool))
+    if gc.ndim < k.ndim:
+        # one decay a head: ``(x_t . k_i) exp(G_t - G_i)``, a (C, Dk) x (Dk,
+        # C) product times (C, C) exponentials
+        decay = jnp.exp(jnp.where(upto, gc[..., :, None] - gc[..., None, :], -jnp.inf))
+        mm = functools.partial(jnp.einsum, "nbhtd,nbhid->nbhti", precision=_HI)
+        return jnp.where(before, mm(k, k) * decay, 0.0), mm(q, k) * decay
 
     def pairwise(xs):
-        """``P_ti(k)`` for ``i < t`` and ``P_ti(q)`` for ``i <= t`` of one
-        block: float32 products summed over the key channels, the exponent a
-        difference masked BEFORE the exponential (one exponential serves
-        both: on the diagonal it is 1, and ``P(k)`` leaves the diagonal out)."""
+        """One block's: float32 products summed over the key channels, the
+        exponent a difference masked BEFORE the exponential (one exponential
+        serves both: on the diagonal it is 1, and ``P(k)`` leaves the
+        diagonal out)."""
         q_c, k_c, g_c = xs  # (B, H, C, Dk)
         diff = g_c[..., :, None, :] - g_c[..., None, :, :]  # (B, H, C, C, Dk)
         decayed = k_c[..., None, :, :] * jnp.exp(
@@ -177,15 +213,19 @@ def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
         kk = jnp.where(before, (k_c[..., :, None, :] * decayed).sum(-1), 0.0)
         return kk, (q_c[..., :, None, :] * decayed).sum(-1)
 
-    if per_head:
-        # the same two sums under one decay a head: ``(x_t . k_i) exp(G_t -
-        # G_i)``, a (C, Dk) x (Dk, C) product times (C, C) exponentials
-        decay = jnp.exp(jnp.where(upto, gc[..., :, None] - gc[..., None, :], -jnp.inf))
-        mm = functools.partial(jnp.einsum, "nbhtd,nbhid->nbhti", precision=_HI)
-        kk, qk = jnp.where(before, mm(k, k) * decay, 0.0), mm(q, k) * decay
+    return jax.lax.map(pairwise, (q, k, gc))
+
+
+def _chunk_local_xla(q, k, v, g, beta):
+    """The block-local half of :func:`kda_chunked` as array operations, every
+    operand split into blocks ``(nc, B, H, C, …)`` (``beta`` with a last axis
+    of 1): what the block scan reads, ``(u0, w, exp(G) q, P(q), exp(G_C - G)
+    k, exp(G_C))``."""
+    chunk = k.shape[-2]
+    gc = jnp.cumsum(g, axis=3)  # G_t, from the block's start
+    kk, qk = _chunk_pairwise_xla(q, k, gc)
+    if gc.ndim < k.ndim:
         gc = gc[..., None]  # what follows scales channels: one decay serves each
-    else:
-        kk, qk = jax.lax.map(pairwise, (q, k, gc))  # (nc, B, H, C, C)
     eg = jnp.exp(gc)
     # (I + A)^{-1} [beta V | beta exp(G) K]: one unit lower triangular solve
     rhs = jnp.concatenate([beta * v, beta * eg * k], axis=-1)
@@ -196,7 +236,12 @@ def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
         )
     u0, w = solved[..., : v.shape[-1]], solved[..., v.shape[-1] :]
     to_end = jnp.exp(gc[..., -1:, :] - gc) * k  # exp(G_C - G_i) k_i
-    total = eg[..., -1, :]  # (nc, B, H, Dk) a block's whole decay
+    return u0, w, eg * q, qk, to_end, eg[..., -1, :]
+
+
+def _chunk_scan_xla(state, local):
+    """The three products with the carried state, block after block:
+    ``(o (nc, B, H, C, Dv), state)`` from :func:`_chunk_local_xla`'s six."""
 
     def block(s, xs):
         u0_c, w_c, qe_c, qk_c, to_end_c, total_c = xs
@@ -206,8 +251,260 @@ def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
         s = total_c[..., None] * s + mm("bhcd,bhcv->bhdv", to_end_c, u)
         return s, o
 
-    state, o = jax.lax.scan(block, state, (u0, w, eg * q, qk, to_end, total))
-    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, nc * chunk, h, -1)
+    state, o = jax.lax.scan(block, state, local)
+    return o, state
+
+
+def _kda_chunked_xla(q, k, v, g, beta, state, chunk: int):
+    """:func:`kda_chunked` as array operations (a backend without the
+    kernel, the lanes of a ``jax.vmap``); ``T`` whole blocks."""
+    _count_dispatch("chunk_xla")
+    b, t, h, _ = k.shape
+    split = functools.partial(_split_blocks, chunk=chunk)
+    local = _chunk_local_xla(
+        split(q), split(k), split(v), split(g), split(beta[..., None])
+    )
+    o, state = _chunk_scan_xla(state, local)
+    return jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, t, h, -1), state
+
+
+def _dot(a, b, dims=((1,), (0,))):
+    """A float32 product on the matrix unit inside a kernel, at the
+    precision the array form's einsums ask for."""
+    return jax.lax.dot_general(
+        a, b, (dims, ((), ())), precision=_HI, preferred_element_type=jnp.float32
+    )
+
+
+_NT = ((1,), (1,))  # a b^T
+_TN = ((0,), (0,))  # a^T b
+
+
+def _kda_chunk_kernel(nv_ref, *refs, per_head: bool):
+    """One block of ``C`` positions of ``hb`` heads, the whole of
+    :func:`kda_chunked`'s arithmetic for it in VMEM. Grid ``(B, H / hb,
+    blocks)``, the blocks in order: ``s_ref (hb, Dk, Dv)``, the result's
+    state, stays resident over them and carries S. ``q_ref`` / ``k_ref (hb,
+    C, Dk)``, ``v_ref`` / ``o_ref (hb, C, Dv)``, ``beta_ref (hb, blocks, C)``
+    every block's ``beta`` as rows. One decay a head (``per_head``): ``g_ref``
+    as ``beta_ref``, and the pairwise sums are made here. One a key channel:
+    ``g_ref (hb, C, Dk)`` and ``kk_ref`` / ``qk_ref (hb, C, C)`` the sums as
+    :func:`_chunk_pairwise_xla` made them. ``x_ref (hb, C, Dk up to whole
+    lane tiles + Dv)`` holds ``[beta exp(G) K | beta V]`` and then the
+    system's solution in its place.
+
+    ``(I + A) X = R`` by substitution: the ``_SUB``-wide diagonal blocks are
+    inverted row after row (all of a head's at once, stacked on sublanes:
+    row ``r`` of each inverse is ``e_r`` less ``A``'s row ``r`` times the
+    rows above), then ``X``'s row blocks follow one another by products. No
+    power of ``A`` is ever formed. A block at or past ``nv_ref[0]`` passes
+    the state through: zeros to ``o_ref`` and no arithmetic."""
+    if per_head:
+        q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, s_ref, x_ref = refs
+    else:
+        (q_ref, k_ref, v_ref, g_ref, beta_ref, kk_ref, qk_ref,
+         s0_ref, o_ref, s_ref, x_ref) = refs
+    c = pl.program_id(2)
+    hb, chunk, dk = k_ref.shape
+    dv = v_ref.shape[-1]
+    at_v = x_ref.shape[-1] - dv  # the values' first lane in x_ref
+    sub = _SUB if chunk % _SUB == 0 else 8
+    f32, i32 = jnp.float32, jnp.int32
+
+    @pl.when(c == 0)
+    def _():
+        s_ref[...] = s0_ref[...]
+
+    live = c * chunk < nv_ref[0]
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live)
+    def _():
+        row = jax.lax.broadcasted_iota(i32, (chunk, chunk), 0)
+        col = jax.lax.broadcasted_iota(i32, (chunk, chunk), 1)
+        eye = (row == col).astype(f32)
+        same = row // sub == col // sub  # inside a diagonal block
+        sub_row = jax.lax.broadcasted_iota(i32, (chunk, sub), 0) % sub
+        # lane ``sub J + t`` to lane ``t``; and the inverses' start, identities
+        pick = (sub_row == jax.lax.broadcasted_iota(i32, (chunk, sub), 1)).astype(f32)
+        lane = jax.lax.broadcasted_iota(i32, (sub, chunk), 1)
+
+        def solve(h, a):
+            """``x_ref[h] <- (I + a)^{-1} x_ref[h]``, ``a`` strictly lower."""
+            # the diagonal blocks TRANSPOSED and stacked, (C, sub): row r of
+            # block J down a column, where the rows it multiplies lie
+            dt = _dot(jnp.where(same, _dot(eye, a, _NT), 0.0), pick)
+            inv = pick
+            for r in range(1, sub):
+                above = (dt[:, r : r + 1] * inv).reshape(chunk // sub, sub, sub)
+                above = jnp.broadcast_to(
+                    above.sum(axis=1, keepdims=True), above.shape
+                ).reshape(chunk, sub)
+                inv = jnp.where(sub_row == r, inv - above, inv)
+            for i in range(chunk // sub):
+                rows = slice(i * sub, (i + 1) * sub)
+                rhs = x_ref[h, rows, :]
+                if i:
+                    left = jnp.where(lane < i * sub, a[rows], 0.0)
+                    rhs = rhs - _dot(left, x_ref[h])
+                x_ref[h, rows, :] = _dot(inv[rows], rhs)
+
+        if at_v > dk:  # lanes no column of the system lies on
+            x_ref[:, :, dk:at_v] = jnp.zeros((hb, chunk, at_v - dk), f32)
+        for h in range(hb):
+            q, k, v = q_ref[h], k_ref[h], v_ref[h]
+            beta = beta_ref[h, pl.ds(c, 1), :]  # (1, C)
+            beta = jnp.sum(jnp.where(row == col, beta, 0.0), axis=1, keepdims=True)
+            if per_head:
+                g = g_ref[h, pl.ds(c, 1), :]  # (1, C)
+                upto = jnp.where(row >= col, g, 0.0)  # [t, j]: g_j, j <= t
+                gc = jnp.sum(upto, axis=1, keepdims=True)  # G_t (C, 1)
+                tail = jnp.sum(jnp.where(row < col, g, 0.0), axis=1, keepdims=True)
+                total = jnp.exp(jnp.sum(g, axis=1, keepdims=True))  # (1, 1)
+                # G_t - G_i as the sum it is, g_j over i < j <= t
+                diff = _dot(upto, (row > col).astype(f32))
+                decay = jnp.exp(jnp.where(row >= col, diff, -jnp.inf))
+                kk = jnp.where(row > col, _dot(k, k, _NT) * decay, 0.0)
+                qk = _dot(q, k, _NT) * decay
+            else:
+                g = g_ref[h]  # (C, Dk)
+                gc = _dot((row >= col).astype(f32), g)  # G_t (C, Dk)
+                tail = _dot((row < col).astype(f32), g)  # G_C - G_t
+                # a block's whole decay down a column, as S's rows lie
+                total = jnp.exp(_dot(g, jnp.ones((chunk, 128), f32), _TN)[:, :1])
+                kk, qk = kk_ref[h], qk_ref[h]
+            eg = jnp.exp(gc)
+            x_ref[h, :, :dk] = beta * eg * k
+            x_ref[h, :, at_v:] = beta * v
+            solve(h, beta * kk)
+            s = s_ref[h]
+            u = x_ref[h, :, at_v:] - _dot(x_ref[h, :, :dk], s)
+            o_ref[h] = _dot(eg * q, s) + _dot(qk, u)
+            s_ref[h] = total * s + _dot(jnp.exp(tail) * k, u, _TN)
+
+
+def _chunk_head_block(heads: int) -> int:
+    """Heads of one grid step of :func:`kda_chunk_call`: the most that
+    divide ``heads``, up to ``_CHUNK_HEADS`` (the body is unrolled over
+    them: independent chains for the scheduler to interleave)."""
+    return max(hb for hb in range(1, _CHUNK_HEADS + 1) if heads % hb == 0)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def kda_chunk_call(q, k, v, g, beta, state, n_valid, *, chunk: int = CHUNK,
+                   interpret: bool = False):
+    """:func:`kda_chunked` over whole blocks as ONE Pallas pass
+    (:func:`_kda_chunk_kernel`): ``T`` a multiple of ``chunk``, ``n_valid``
+    an int32 scalar (may be traced): blocks wholly at or past it are not
+    computed (zeros in ``o``, the state passed through) nor fetched (their
+    block index repeats the last live one). Heads go first in HBM, ``(B, H,
+    T, …)``, so a block of a head is whole rows. With a decay a key channel
+    the pairwise sums stay array operations and come in as operands. Jitted:
+    traced once for all the layers that call it."""
+    b, t, h, dk = k.shape
+    dv = v.shape[-1]
+    nc = t // chunk
+    per_head = g.ndim == beta.ndim
+    hb = _chunk_head_block(h)
+    f32 = jnp.float32
+    heads_first = lambda z: jnp.swapaxes(z.astype(f32), 1, 2)  # noqa: E731
+    as_rows = lambda z: heads_first(z).reshape(b, h, nc, chunk)  # noqa: E731
+    last = lambda nv: jnp.maximum(nv[0] - 1, 0) // chunk  # noqa: E731
+    block = lambda width: pl.BlockSpec(  # noqa: E731
+        (None, hb, chunk, width),
+        lambda i, j, c, nv: (i, j, jnp.minimum(c, last(nv)), 0),
+    )
+    whole = lambda *shape: pl.BlockSpec(  # noqa: E731
+        (None, hb, *shape), lambda i, j, c, nv: (i, j, 0, 0)
+    )
+    operands = [heads_first(q), heads_first(k), heads_first(v)]
+    in_specs = [block(dk), block(dk), block(dv)]
+    if per_head:
+        operands += [as_rows(g), as_rows(beta)]
+        in_specs += [whole(nc, chunk), whole(nc, chunk)]
+    else:
+        split = functools.partial(_split_blocks, chunk=chunk)
+        kk, qk = _chunk_pairwise_xla(split(q), split(k), jnp.cumsum(split(g), axis=3))
+        pair = pl.BlockSpec(
+            (None, None, hb, chunk, chunk),
+            lambda i, j, c, nv: (jnp.minimum(c, last(nv)), i, j, 0, 0),
+        )
+        operands += [heads_first(g), as_rows(beta), kk, qk]
+        in_specs += [block(dk), whole(nc, chunk), pair, pair]
+    width = -(-dk // 128) * 128 + dv
+    o, state = pl.pallas_call(
+        functools.partial(_kda_chunk_kernel, per_head=per_head),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h // hb, nc),
+            in_specs=[*in_specs, whole(dk, dv)],
+            out_specs=[
+                pl.BlockSpec((None, hb, chunk, dv), lambda i, j, c, nv: (i, j, c, 0)),
+                whole(dk, dv),
+            ],
+            scratch_shapes=[pltpu.VMEM((hb, chunk, width), f32)],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, t, dv), f32),
+            jax.ShapeDtypeStruct((b, h, dk, dv), f32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+        name="kda_chunk_local",
+    )(jnp.asarray(n_valid, jnp.int32).reshape(1), *operands, state.astype(f32))
+    return jnp.swapaxes(o, 1, 2), state
+
+
+def chunk_kernel_eligible(k, state, chunk: int, interpret: bool) -> bool:
+    """:func:`kda_chunk_call` on a TPU backend (in interpret mode on any)
+    for float32 operands and blocks of whole sublane tiles; the array
+    operations otherwise. ``Dk`` and ``Dv`` are the kernel's to lay out."""
+    return (
+        (interpret or jax.default_backend() == "tpu")
+        and k.dtype == jnp.float32
+        and state.dtype == jnp.float32
+        and chunk % 8 == 0
+    )
+
+
+def _kda_chunk_lanes(axis_size, in_batched, *args, chunk: int):
+    """The kernel's call under ``jax.vmap``: the lanes take the array
+    operations, as :func:`_kda_step_lanes` does for the step."""
+    in_axes = jax.tree.map(lambda batched: 0 if batched else None, in_batched)
+    lane = lambda q, k, v, g, beta, state, n_valid: _kda_chunked_xla(  # noqa: E731
+        q, k, v, g, beta, state, chunk
+    )
+    return jax.vmap(lane, in_axes=in_axes)(*args), (True, True)
+
+
+def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK, n_valid=None,
+                interpret: bool = False):
+    """:func:`kda_sequential` over blocks of ``chunk`` positions as matrix
+    products (the module docstring's WY form): same arguments and result, by
+    the path the operands allow (:func:`chunk_kernel_eligible`). A ragged
+    last block is padded with rows of ``g = 0``, ``beta = 0``, which pass the
+    state through. ``n_valid`` (None: ``T``) promises that rows at or past
+    it carry such ``g`` and ``beta`` already: the kernel then skips the
+    blocks wholly past it, whose rows of ``o`` nobody may read."""
+    t = k.shape[1]
+    pad = -t % chunk
+    if pad:
+        padt = lambda z: jnp.pad(z, ((0, 0), (0, pad)) + ((0, 0),) * (z.ndim - 2))  # noqa: E731
+        q, k, v, g, beta = padt(q), padt(k), padt(v), padt(g), padt(beta)
+    if chunk_kernel_eligible(k, state, chunk, interpret):
+        _count_dispatch("chunk_kernel")
+        kernel = jax.custom_batching.custom_vmap(
+            functools.partial(kda_chunk_call, chunk=chunk, interpret=interpret)
+        )
+        kernel.def_vmap(functools.partial(_kda_chunk_lanes, chunk=chunk))
+        o, state = kernel(q, k, v, g, beta, state, t if n_valid is None else n_valid)
+    else:
+        o, state = _kda_chunked_xla(q, k, v, g, beta, state, chunk)
     return o[:, :t], state
 
 
@@ -456,7 +753,7 @@ def _advance(pool, rank, q, k, v, g, beta, tail, new_tail, n_valid, active,
                 live = (jnp.arange(t) < n_valid)[None, :, None]
                 g = jnp.where(live if g.ndim == beta.ndim else live[..., None], g, 0.0)
                 beta = jnp.where(live, beta, 0.0)
-            o, s = kda_chunked(q, k, v, g, beta, old, chunk)
+            o, s = kda_chunked(q, k, v, g, beta, old, chunk, n_valid, interpret)
             s = keep_inactive(active, s, old)  # inside the scope, as the step's
         pool = put_rows(pool, rank, pack_heads(s, pack))
     with jax.named_scope("mst.kda.step" if t == 1 else "mst.kda.scan"):

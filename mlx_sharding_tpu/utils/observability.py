@@ -1275,11 +1275,16 @@ _HELP = {
         "pool where they lie in one pass; xla slices them out, passes over "
         "them twice and writes them back (0 on a chip).",
     "mst_kda_dispatch_total":
-        "Decode steps' gated delta-rule recurrences by the path ops/kda chose, "
-        "one count per traced call: kernel updates the layer's rows of the "
-        "state pool where they lie in one pass; xla slices them out, passes "
-        "over them for k^T S, again for the update and the output, and writes "
-        "them back (0 on a chip).",
+        "Gated delta-rule recurrences by the path ops/kda chose, one count "
+        "per traced call. A decode step: kernel updates the layer's rows of "
+        "the state pool where they lie in one pass; xla slices them out, "
+        "passes over them for k^T S, again for the update and the output, and "
+        "writes them back (0 on a chip). A prefill chunk's chunked form: "
+        "chunk_kernel runs each block of 64 positions as one Pallas pass in "
+        "VMEM (pairwise sums, the unit lower triangular system by "
+        "substitution, the products with the carried state); chunk_xla makes "
+        "them float32 array operations and a triangular-solve custom call "
+        "(0 on a chip).",
     "mst_faults_armed":
         "Currently armed fault-injection sites (should be 0 in prod).",
     "mst_faults_malformed_total":

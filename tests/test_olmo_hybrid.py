@@ -193,6 +193,134 @@ def test_beta_near_two_stays_bounded_over_two_thousand_positions():
     np.testing.assert_allclose(s, s_seq, atol=2e-3, rtol=2e-3)
 
 
+_CHUNK_SHAPES = {
+    # name: (heads, Dk, Dv, a decay a key channel?, beta's scale)
+    "scalar-24x48-beta2": (3, 24, 48, False, 2.0),
+    "scalar-square": (2, 32, 32, False, 1.0),
+    "per-channel": (2, 32, 32, True, 1.0),
+}
+_CHUNK_ROWS = {
+    # name: (T, n_valid, the sequences that advance)
+    "whole-blocks": (128, None, None),
+    "ragged-last-block": (136, None, None),
+    "n_valid-inside-a-block": (192, 100, None),
+    "whole-blocks-past-n_valid": (256, 70, None),
+    "an-inactive-sequence": (128, 128, [True, False]),
+}
+
+
+@hard_timeout(600)
+@pytest.mark.parametrize("rows", list(_CHUNK_ROWS))
+@pytest.mark.parametrize("shape", list(_CHUNK_SHAPES))
+def test_the_chunk_kernel_is_the_sequential_recurrence(shape, rows):
+    """``ops.kda._advance`` over a chunk with the Pallas pass in interpret
+    mode (``kda_chunk_call``: a block's pairwise sums, its unit lower
+    triangular system by substitution and the three products with the carried
+    state in one kernel) against the definition run on the valid rows alone:
+    2e-5 on the valid rows of ``o`` and on the state, the array form's
+    tolerance. Rows past ``n_valid`` advance nothing (whole blocks past it
+    are not even computed), and an inactive sequence keeps its state."""
+    h, dk, dv, per_channel, beta_scale = _CHUNK_SHAPES[shape]
+    t, n_valid, active = _CHUNK_ROWS[rows]
+    q, k, v, g, beta, s0 = _inputs(2, t, h, dk, dv, 16.0, beta_scale, seed=7)
+    if per_channel:
+        g = g[..., None] * jax.random.uniform(jax.random.PRNGKey(8), k.shape, minval=0.2)
+    pool = jnp.stack([jnp.zeros_like(s0), s0])  # layer 1 of two, heads apart
+    tail = jnp.zeros((2, 3, 8))
+    before = kda.dispatch_counts()
+    o, new, _ = kda._advance(
+        pool, jnp.asarray(1), q, k, v, g, beta, tail, tail + 1.0,
+        None if n_valid is None else jnp.asarray(n_valid),
+        None if active is None else jnp.asarray(active), kda.CHUNK, True,
+    )
+    after = kda.dispatch_counts()
+    assert after["chunk_kernel"] == before["chunk_kernel"] + 1
+    assert after["chunk_xla"] == before["chunk_xla"]
+    n = t if n_valid is None else n_valid
+    o_seq, s_seq = kda.kda_sequential(q[:, :n], k[:, :n], v[:, :n], g[:, :n], beta[:, :n], s0)
+    assert o.shape == (2, t, h, dv) and np.isfinite(o).all()
+    np.testing.assert_allclose(o[:, :n], o_seq, atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(new[0], pool[0])
+    if active is None:
+        np.testing.assert_allclose(new[1], s_seq, atol=2e-5, rtol=2e-5)
+    else:
+        np.testing.assert_allclose(new[1, 0], s_seq[0], atol=2e-5, rtol=2e-5)
+        np.testing.assert_array_equal(new[1, 1], s0[1])
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["xla", "kernel"])
+def test_a_block_of_one_repeated_key_at_beta_near_two(interpret):
+    """The block only substitution survives: 64 keys that are ONE unit
+    vector, ``beta`` in (1.96, 2), almost no decay. ``I + A`` is then 1 on the
+    diagonal and nearly 2 everywhere below it: its inverse has entries of
+    about 2 in alternating signs, while ``A``'s powers grow by binomials
+    (``C(63, 31) 2^32``) before they cancel, so a Neumann or repeated-squaring
+    product loses every float32 digit (shown below on the block's own
+    matrix). Both forms solve row after row (the kernel inside 16 x 16
+    diagonal blocks, then block after block) and give the sequential form's
+    numbers, outputs up to 6 in magnitude, to 1e-4, over a second block of
+    ordinary keys as well."""
+    q, k, v, g, _, s0 = _inputs(1, 128, 2, 24, 48, 1.0, seed=11)
+    k = k.at[:, :64].set(jnp.broadcast_to(k[:, :1], k[:, :64].shape))
+    g = 1e-4 * g
+    beta = 2.0 - 0.04 * jax.random.uniform(jax.random.PRNGKey(12), g.shape)
+    o_seq, s_seq = kda.kda_sequential(q, k, v, g, beta, s0)
+    assert float(jnp.abs(o_seq).max()) < 50 and float(jnp.abs(s_seq).max()) < 50
+    o, s = kda.kda_chunked(q, k, v, g, beta, s0, kda.CHUNK, None, interpret)
+    np.testing.assert_allclose(o, o_seq, atol=1e-4, rtol=2e-5)
+    np.testing.assert_allclose(s, s_seq, atol=1e-4, rtol=2e-5)
+    # the same system by repeated squaring, (I - A)(I + A^2)(I + A^4)...:
+    # exact in exact arithmetic (A^64 = 0), useless in float32
+    a = np.tril(np.asarray(beta[0, :64, 0])[:, None] * np.ones((64, 64), np.float32), -1)
+    eye = np.eye(64, dtype=np.float32)
+    series, power = eye - a, a @ a
+    for _ in range(5):
+        series, power = series @ (eye + power), power @ power
+    exact = np.linalg.inv((eye + a).astype(np.float64))
+    assert np.abs(exact).max() < 2.5
+    assert not np.abs(series - exact).max() < 1.0  # off by more than the answer, or not finite
+
+
+def test_the_chunked_form_counts_its_path_and_the_kernel_holds_no_solve():
+    """One count a traced call of the chunked form, ``chunk_kernel`` or
+    ``chunk_xla`` (a jitted call traced once counts once however often it
+    runs); ``/metrics`` carries both paths; and the kernel path's program
+    holds no ``triangular_solve`` (nor the ``custom_call`` XLA makes of it),
+    where the array form's does."""
+    from mlx_sharding_tpu.utils.observability import ServingMetrics
+
+    q, k, v, g, beta, s0 = _inputs(1, 128, 2, 24, 48, 4.0, seed=13)
+    jaxprs = {}
+    for interpret, path, other in ((False, "chunk_xla", "chunk_kernel"),
+                                   (True, "chunk_kernel", "chunk_xla")):
+        form = jax.jit(lambda *a, i=interpret: kda.kda_chunked(*a, kda.CHUNK, None, i))
+        before = kda.dispatch_counts()
+        form(q, k, v, g, beta, s0)
+        form(q, k, v, g, beta, s0)
+        after = kda.dispatch_counts()
+        assert after[path] == before[path] + 1 and after[other] == before[other]
+        assert after["kernel"] == before["kernel"] and after["xla"] == before["xla"]
+        jaxprs[path] = str(jax.make_jaxpr(form)(q, k, v, g, beta, s0))
+    assert "triangular_solve" in jaxprs["chunk_xla"]
+    assert "triangular_solve" not in jaxprs["chunk_kernel"]
+    assert "kda_chunk_local" in jaxprs["chunk_kernel"]
+    # the lanes of a ``jax.vmap`` (an engine's micro-batches) take the array
+    # form, bit for bit, as the step's do
+    lanes = jax.vmap(lambda *a: kda.kda_chunked(
+        *(z[None] for z in a), kda.CHUNK, jnp.asarray(128), True))
+    before = kda.dispatch_counts()
+    two = _inputs(2, 128, 2, 24, 48, 4.0, seed=14)
+    o, s = lanes(*two)
+    assert kda.dispatch_counts()["chunk_xla"] == before["chunk_xla"] + 1
+    o_xla, s_xla = kda.kda_chunked(*two)
+    np.testing.assert_array_equal(o[:, 0], o_xla)
+    np.testing.assert_array_equal(s[:, 0], s_xla)
+    text = ServingMetrics().render()
+    counts = kda.dispatch_counts()
+    for path in ("chunk_kernel", "chunk_xla"):
+        assert f'mst_kda_dispatch_total{{path="{path}"}} {counts[path]}' in text
+
+
 def test_lane_pack_and_its_two_views():
     assert kda.lane_pack(30, 192) == 2  # 384 lanes: three whole tiles
     assert kda.lane_pack(32, 128) == 1 and kda.lane_pack(6, 64) == 2

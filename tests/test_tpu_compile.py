@@ -197,6 +197,23 @@ def _kda_step(layers, slots, heads, head_dim=128, value_dim=None):
     )
 
 
+def _kda_chunk(heads, head_dim=128, value_dim=None, per_channel=False):
+    """``ops.kda.kda_chunked`` through its dispatcher on one sequence's
+    512-row prefill chunk of one linear layer: the cell's chunked delta rule
+    as the one Pallas pass ``kda_chunk_local``, ``n_valid`` an operand."""
+    from mlx_sharding_tpu.ops.kda import CHUNK, kda_chunked
+
+    dv = value_dim or head_dim
+    vec = ((1, 512, heads, head_dim), F32)
+    return (
+        lambda q, k, v, g, beta, s, n: kda_chunked(q, k, v, g, beta, s, CHUNK, n),
+        [vec, vec, ((1, 512, heads, dv), F32),
+         vec if per_channel else ((1, 512, heads), F32), ((1, 512, heads), F32),
+         ((1, heads, head_dim, dv), F32), ((), I32)],
+        "kda_chunk_local",
+    )
+
+
 LLAMA_3B = [(8192, 3072), (3072, 8192), (128256, 3072)]
 CASES = {
     # flash prefill chunk and T=1 at Llama-3B heads; the MLA shapes
@@ -281,6 +298,14 @@ CASES = {
     "paged-mha30-group1-merged-page512": _paged(
         512, False, slots=48, hq=30, hkv=30, max_seq=1536, pages=4 * 97,
         merged=True),
+    # the three delta-rule cells' prefill chunk (512 rows, blocks of 64) as
+    # one Pallas pass: a decay a key channel with the pairwise sums as
+    # operands (kimi-linear), a decay a head (qwen3-next), and a decay a head
+    # on keys 96 wide — three quarters of a lane tile, laid out in VMEM — and
+    # values 192, 30 heads walked three a grid step (olmo-hybrid)
+    "kda-chunk-kimi-linear": _kda_chunk(32, per_channel=True),
+    "kda-chunk-qwen3-next": _kda_chunk(32),
+    "kda-chunk-olmo-hybrid": _kda_chunk(30, 96, 192),
     # 4-bit projections of the 3B model: a prefill chunk's 256 rows, a
     # single stream's one row and 8 slots' rows, all on the one kernel
     **{f"quant-M{m}-{i}x{o}": _quant(m, o, i, "quant_matmul")
